@@ -21,6 +21,7 @@ from pytorch_distributed_tpu.compilecache.aot import (
     export_program,
     load_exported,
     persistent_cache_dir,
+    process_compile_totals,
     save_exported,
 )
 from pytorch_distributed_tpu.compilecache.registry import (
@@ -46,6 +47,7 @@ __all__ = [
     "jit_cache_size",
     "load_exported",
     "persistent_cache_dir",
+    "process_compile_totals",
     "run_fingerprint",
     "save_exported",
     "serving_registry",

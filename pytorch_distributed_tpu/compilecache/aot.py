@@ -55,14 +55,10 @@ def _reset_jax_cache_state() -> None:
     NEXT compile re-reads ``jax_compilation_cache_dir``. jax binds the
     cache object on first use — without this, enabling (or re-pointing)
     the directory in a process that already compiled something is a
-    silent no-op. Private jax API, so best-effort: on a jax that moved
-    it, the worst case is the old behavior (first-compile binding)."""
-    try:
-        from jax._src.compilation_cache import reset_cache
+    silent no-op."""
+    from jax.experimental.compilation_cache import compilation_cache
 
-        reset_cache()
-    except Exception:
-        pass
+    compilation_cache.reset_cache()
 
 
 def enable_persistent_cache(cache_dir: str) -> str:
@@ -120,6 +116,17 @@ def _install_listener() -> None:
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _listener_installed = True
+
+
+def process_compile_totals() -> tuple:
+    """``(persistent-cache hits, backend-compile seconds)`` summed over
+    every thread since the listener went in (the first call installs
+    it) — for a caller whose compiles happen on threads it does not own
+    (a gateway's driver thread), where the per-thread counters below
+    cannot see them."""
+    _install_listener()
+    with _listener_lock:
+        return sum(_hit_counts.values()), sum(_compile_secs.values())
 
 
 class CacheHitCounter:

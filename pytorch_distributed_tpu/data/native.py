@@ -9,6 +9,7 @@ the pure-Python reader takes over.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,27 +20,38 @@ import numpy as np
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "csrc")
 _SRC = os.path.join(_CSRC, "recordio.cpp")
 _BUILD_DIR = os.path.join(_CSRC, "build")
-_SO = os.path.join(_BUILD_DIR, "librecordio.so")
 
 _lock = threading.Lock()
 _lib = None
 _lib_error: str | None = None
 
 
+def _so_path() -> str:
+    """The library's path, keyed on the source's content: a library is
+    reused only if it was built from exactly this ``recordio.cpp``. (An
+    mtime comparison is not that test — a copy of the tree can carry a
+    binary newer than a source it was never built from.)"""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"librecordio-{digest}.so")
+
+
 def _build() -> str | None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.tmp.{os.getpid()}"
     cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread", _SRC,
-           "-o", _SO + ".tmp"]
+           "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
     except (OSError, subprocess.SubprocessError) as e:
         global _lib_error
         _lib_error = f"native recordio build failed: {e}"
         return None
-    os.replace(_SO + ".tmp", _SO)
-    return _SO
+    os.replace(tmp, so)
+    return so
 
 
 def _load():

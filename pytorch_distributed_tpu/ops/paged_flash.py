@@ -16,10 +16,18 @@ Structure (per the in-tree FlashAttention kernel,
 ``ops/flash_attention.py``, and the TPU Pallas playbook
 ``/opt/skills/guides/pallas_guide.md``):
 
-- grid ``(B, H_kv, W)`` with the block-chain sweep innermost and
-  sequential ("arbitrary" semantics — it carries the online-softmax
-  recurrence); the running (m, l, acc) state lives in VMEM scratch,
-  persisting across the chain for each (batch row, narrow head);
+- grid ``(B, W)`` with the block-chain sweep innermost and sequential
+  ("arbitrary" semantics — it carries the online-softmax recurrence);
+  the running (m, l, acc) state lives in VMEM scratch, persisting across
+  the chain for each batch row, one slab per narrow head;
+- every block's last two dims equal its array's, the one block shape
+  Mosaic's tiling rule accepts at H_kv=12, D=64 (the interpreter does
+  not check it — every shape was refused on the chip until PR 21): a
+  staged K/V block is the whole ``[block_len, H_kv, D]`` pool block and
+  a static loop over narrow heads slices ``k_ref[0, :, h, :]``; scale
+  blocks are the block's whole ``[block_len, H_kv]`` sibling; positions
+  ride as a ``[B, r_pad, 1]`` column and each row's query frontier as a
+  second scalar-prefetch operand;
 - GQA is folded into the row dimension: queries regroup to
   ``[B, H_kv, G·C, D]`` so each narrow head's whole query group shares
   one staged KV block — the widened K/V never exists, mirroring the
@@ -49,10 +57,12 @@ Structure (per the in-tree FlashAttention kernel,
   auto-enables via ``auto_split_s`` when W/B crosses the threshold;
   ``pl.when`` frontier skipping applies per worker unchanged;
 - the write side has a fused twin: ``paged_quantize_scatter`` computes
-  per-row-per-head scales and writes quantized rows + scale siblings
-  inside the scatter (``input_output_aliases`` keeps unvisited pool
-  blocks in place), sharing ``serving.kv_pool.quantize_rows`` with the
-  jnp spelling so the two are bit-equivalent by construction;
+  per-row-per-head scales and writes the quantized rows inside the
+  scatter (``input_output_aliases`` keeps unvisited pool blocks in
+  place), sharing ``serving.kv_pool.quantize_rows`` with the jnp
+  spelling, so in the interpreter the two are bit-equivalent by
+  construction (on the v5e: int8 and fp8 e4m3 bit-equal, e5m2 not —
+  CHANGES.md PR 21);
 - ``interpret=None`` auto-detects non-TPU backends and runs the Pallas
   interpreter, so CPU tier-1 executes the same call sites unmodified
   (the ``flash_attention`` convention).
@@ -74,14 +84,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from pytorch_distributed_tpu.ops.attention import NEG_INF
-
-# jax 0.4.3x names the param class TPUCompilerParams; newer releases
-# CompilerParams (which ops/flash_attention.py uses). Resolve once so the
-# non-interpret branch works on either.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 #: flash-decoding auto policy (``split_s=None``): split when one batch
 #: row's chain is at least this many blocks per batch row — the shape
@@ -106,137 +108,114 @@ def auto_split_s(w: int, b: int, *, threshold: int = SPLIT_THRESHOLD,
 
 
 def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
-                  m_scr, l_scr, acc_scr, *, scale, k_start,
+                  m_scr, l_scr, acc_scr, *, scale, k_start, h_kv,
                   quantized, fp8_scales):
-    """One chain block's online-softmax update — the shared inner body
-    of the single-worker and split-S kernels (one spelling, so the
-    split path cannot drift from the sweep it partitions)."""
-    # Fold the softmax scale into Q (one [R, D] multiply, the flash
-    # kernel's trick), fp32 logits on the MXU.
-    q = q_ref[0, 0]  # [R, D]
-    k = k_ref[0, :, 0, :]  # [block_len, D]
-    v = v_ref[0, :, 0, :]
+    """One chain block's online-softmax update for every narrow head —
+    the shared inner body of the single-worker and split-S kernels (one
+    spelling, so the split path cannot drift from the sweep it
+    partitions). The staged K/V block spans all of ``H_kv`` (the only
+    pool block shape Mosaic's tiling rule accepts without changing the
+    pool layout); the static head loop reads each head's
+    ``[block_len, D]`` slice out of it."""
     if quantized:
         # dequantize THIS block only, in VMEM: per-(slot, head) scale
         # siblings gathered by the same table-driven index map. fp8
         # pools carry int8 exponents — multiplier 2**e, exact in fp32
         # (kv_pool.scale_factors spelling).
-        ks = ks_ref[0, :, 0]
-        vs = vs_ref[0, :, 0]
+        ks_all = ks_ref[0].astype(jnp.float32)  # [block_len, H_kv]
+        vs_all = vs_ref[0].astype(jnp.float32)
         if fp8_scales:
-            ks = jnp.exp2(ks.astype(jnp.float32))
-            vs = jnp.exp2(vs.astype(jnp.float32))
-        k = k.astype(jnp.float32) * ks[:, None]
-        v = v.astype(jnp.float32) * vs[:, None]
-    s = jax.lax.dot_general(
-        q * jnp.asarray(scale, q.dtype), k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [R, block_len]
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # Frontier mask: key position j visible iff j <= the row's query
-    # position. Trash-table entries (unallocated tail) carry logical
-    # positions past every live frontier → fully masked, exactly the
-    # dense spelling's argument. Padding rows (qpos == -1) mask
-    # everything → l stays 0 → zeros out, sliced away by the caller.
-    mask = k_pos <= qpos[:, None]
-    s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = p * mask  # fully-masked rows stay all-zero (l == 0 → out 0)
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[:] = jnp.broadcast_to(
-        l_scr[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
-        l_scr.shape,
-    )
-    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+            ks_all = jnp.exp2(ks_all)
+            vs_all = jnp.exp2(vs_all)
+    for h in range(h_kv):
+        # Fold the softmax scale into Q (one [R, D] multiply, the flash
+        # kernel's trick), fp32 logits on the MXU.
+        q = q_ref[0, h]  # [R, D]
+        q = q * jnp.asarray(scale, q.dtype)
+        k = k_ref[0, :, h, :]  # [block_len, D]
+        v = v_ref[0, :, h, :]
+        if quantized:
+            k = k.astype(jnp.float32) * ks_all[:, h:h + 1]
+            v = v.astype(jnp.float32) * vs_all[:, h:h + 1]
+            q = q.astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [R, block_len]
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        # Frontier mask: key position j visible iff j <= the row's query
+        # position. Trash-table entries (unallocated tail) carry logical
+        # positions past every live frontier → fully masked, exactly the
+        # dense spelling's argument. Padding rows (qpos == -1) mask
+        # everything → l stays 0 → zeros out, sliced away by the caller.
+        mask = k_pos <= qpos
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[h, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # fully-masked rows stay all-zero (l == 0 → out 0)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[h] = jnp.broadcast_to(
+            l_scr[h, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
+            l_scr.shape[1:],
+        )
+        acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
 
 def _paged_kernel(
     tables_ref,  # scalar-prefetch [B, W] int32 (SMEM)
+    front_ref,  # scalar-prefetch [B] int32: each row's query frontier
     q_ref, qpos_ref, k_ref, v_ref,  # + (ks_ref, vs_ref) when quantized
     *refs,
-    scale: float, block_len: int, quantized: bool, fp8_scales: bool,
+    scale: float, block_len: int, h_kv: int, quantized: bool,
+    fp8_scales: bool, w: int, wc: int, split: bool,
 ):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = refs
-    j = pl.program_id(2)
-    n_w = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    qpos = qpos_ref[0]  # [R] per-row absolute query positions (pad = -1)
-    k_start = j * block_len
-
-    def _block():
-        _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
-                      m_scr, l_scr, acc_scr, scale=scale, k_start=k_start,
-                      quantized=quantized, fp8_scales=fp8_scales)
-
-    # A chain block entirely past this batch row's query frontier
-    # contributes nothing — skip its FLOPs (and its dequant) entirely.
-    pl.when(k_start <= jnp.max(qpos))(_block)
-
-    @pl.when(j == n_w - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :1], 1e-37)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
-
-
-def _paged_split_kernel(
-    tables_ref,  # scalar-prefetch [B, W] int32 (SMEM)
-    q_ref, qpos_ref, k_ref, v_ref,  # + (ks_ref, vs_ref) when quantized
-    *refs,
-    scale: float, block_len: int, quantized: bool, fp8_scales: bool,
-    w: int, wc: int,
-):
-    """Flash-decoding worker kernel: grid ``(B, H_kv, S, ceil(W/S))``,
-    worker s sweeps chain blocks ``[s*wc, min((s+1)*wc, W))`` with its
-    own (m, l, acc) partials and emits them UN-normalized — the caller's
-    fp32 log-sum-exp merge combines workers. Same ``_attend_block``
-    inner body as the single-worker sweep, same ``pl.when`` frontier
-    skip per worker (plus the ceil-split tail guard ``j < W``: past-end
-    grid steps clamp their index map to a real block and skip)."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
-    jj = pl.program_id(3)
+    """Grid ``(B, S, ceil(W/S))``: worker s sweeps chain blocks
+    ``[s*wc, min((s+1)*wc, W))`` with its own (m, l, acc) state. The
+    single-worker sweep (``split=False``, S == 1) normalizes in place;
+    flash-decoding workers (``split=True``) emit their partials UN-
+    normalized — the caller's fp32 log-sum-exp merge combines them. One
+    kernel, so the split path cannot drift from the sweep it partitions.
+    Past-end grid steps of a ceil split clamp their index map to a real
+    block and are skipped by the ``j < W`` guard."""
+    del tables_ref  # consumed by the index maps
+    ks_ref, vs_ref = refs[:2] if quantized else (None, None)
+    *out_refs, m_scr, l_scr, acc_scr = refs[2:] if quantized else refs
+    jj = pl.program_id(2)
 
     @pl.when(jj == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    qpos = qpos_ref[0]  # [R] per-row absolute query positions (pad = -1)
-    j = pl.program_id(2) * wc + jj  # logical chain index of this step
+    j = pl.program_id(1) * wc + jj  # logical chain index of this step
     k_start = j * block_len
 
+    # A chain block entirely past this batch row's query frontier
+    # contributes nothing — skip its FLOPs (and its dequant) entirely.
+    @pl.when((j < w) & (k_start <= front_ref[pl.program_id(0)]))
     def _block():
-        _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
+        _attend_block(q_ref, qpos_ref[0], k_ref, v_ref, ks_ref, vs_ref,
                       m_scr, l_scr, acc_scr, scale=scale, k_start=k_start,
-                      quantized=quantized, fp8_scales=fp8_scales)
-
-    pl.when((j < w) & (k_start <= jnp.max(qpos)))(_block)
+                      h_kv=h_kv, quantized=quantized,
+                      fp8_scales=fp8_scales)
 
     @pl.when(jj == wc - 1)
     def _finalize():
-        o_ref[0, 0, 0] = acc_scr[:]
-        m_ref[0, 0, 0] = m_scr[:]
-        l_ref[0, 0, 0] = l_scr[:]
+        if split:
+            o_ref, m_ref, l_ref = out_refs
+            o_ref[0, 0] = acc_scr[...]
+            m_ref[0, 0] = m_scr[...]
+            l_ref[0, 0] = l_scr[...]
+        else:
+            (o_ref,) = out_refs
+            l = jnp.maximum(l_scr[:, :, :1], 1e-37)
+            o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def paged_flash_attention(
@@ -313,6 +292,8 @@ def paged_flash_attention(
         raise ValueError(f"split_s must be >= 1, got {split_s}")
     s_workers = split_s if split_s is not None else auto_split_s(w, b)
     s_workers = min(s_workers, w)  # every worker owns >= 1 chain block
+    split = s_workers > 1
+    wc = -(-w // s_workers)  # chain blocks per worker (ceil split)
 
     # GQA fold: query head h = kv·group + g reads narrow head kv, so the
     # per-narrow-head row block is its whole query group × chunk. Rows
@@ -322,137 +303,97 @@ def paged_flash_attention(
     r_pad = -(-r // 8) * 8
     q4 = jnp.moveaxis(q.reshape(b, c, h_kv, group, d), 1, 3)  # [B,Hkv,G,C,D]
     q4 = q4.reshape(b, h_kv, r, d)
+    q_positions = q_positions.astype(jnp.int32)
     qpos = jnp.broadcast_to(
-        q_positions.astype(jnp.int32)[:, None, :], (b, group, c)
+        q_positions[:, None, :], (b, group, c)
     ).reshape(b, r)
     if r_pad != r:
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
         qpos = jnp.pad(qpos, ((0, 0), (0, r_pad - r)), constant_values=-1)
 
-    out_dtype = q.dtype
-    scratch_shapes = [
-        pltpu.VMEM((r_pad, 128), jnp.float32),  # running row max m
-        pltpu.VMEM((r_pad, 128), jnp.float32),  # running row sum l
-        pltpu.VMEM((r_pad, d), jnp.float32),  # un-normalized output
-    ]
-    kern_kw = dict(scale=scale, block_len=block_len,
-                   quantized=bool(quantized), fp8_scales=fp8_scales)
+    # Every block's last two dims equal its array's (Mosaic's tiling
+    # rule; the interpreter does not check it): positions ride as a
+    # [B, r_pad, 1] column, pool blocks span all of H_kv, scale blocks
+    # are the pool block's whole [block_len, H_kv] sibling. Index maps
+    # take the grid position (b, s, j) and the two scalar-prefetch refs.
+    def pool_block(b, s, j, tables, front):
+        # the fused gather: the block table entry IS the index map —
+        # the pipeline DMAs pool block tables[b, chain index] straight
+        # into VMEM, no gathered copy in HBM. Grid steps past the real
+        # chain (ceil-split tail) clamp to its last block; the kernel's
+        # ``j < w`` guard keeps them out of the statistics.
+        return tables[b, jnp.minimum(s * wc + j, w - 1)]
 
-    if s_workers == 1:
-        in_specs = [
-            pl.BlockSpec((1, 1, r_pad, d), lambda b, h, j, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, r_pad), lambda b, h, j, t: (b, 0)),
-            # the fused gather: the block table entry IS the index map —
-            # the pipeline DMAs pool block tables[b, j] (this narrow
-            # head's slice) straight into VMEM, no gathered copy in HBM
-            pl.BlockSpec((1, block_len, 1, d),
-                         lambda b, h, j, t: (t[b, j], 0, h, 0)),
-            pl.BlockSpec((1, block_len, 1, d),
-                         lambda b, h, j, t: (t[b, j], 0, h, 0)),
-        ]
-        operands = [q4, qpos, k_pool, v_pool]
-        if quantized:
-            in_specs += [
-                pl.BlockSpec((1, block_len, 1),
-                             lambda b, h, j, t: (t[b, j], 0, h)),
-                pl.BlockSpec((1, block_len, 1),
-                             lambda b, h, j, t: (t[b, j], 0, h)),
-            ]
-            operands += [k_scale, v_scale]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h_kv, w),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, r_pad, d),
-                                   lambda b, h, j, t: (b, h, 0, 0)),
-            scratch_shapes=scratch_shapes,
-        )
-        kwargs = {}
-        if not interpret:
-            kwargs["compiler_params"] = _COMPILER_PARAMS(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        out4 = pl.pallas_call(
-            functools.partial(_paged_kernel, **kern_kw),
-            out_shape=jax.ShapeDtypeStruct((b, h_kv, r_pad, d), out_dtype),
-            grid_spec=grid_spec,
-            interpret=interpret,
-            **kwargs,
-        )(block_tables.astype(jnp.int32), *operands)
-        out4 = out4[:, :, :r]  # drop row padding
-        return jnp.moveaxis(
-            out4.reshape(b, h_kv, group, c, d), 3, 1
-        ).reshape(b, c, h, d)
-
-    # ---- flash-decoding split: S workers over the chain, LSE merge ----
-    wc = -(-w // s_workers)  # chain blocks per worker (ceil split)
-
-    def _kj(s, jj):
-        # ceil-split tail: grid steps past the real chain clamp to the
-        # last block — the kernel's ``j < w`` guard skips them, so the
-        # clamped DMA target is never read into the statistics
-        return jnp.minimum(s * wc + jj, w - 1)
-
+    row_spec = pl.BlockSpec((1, h_kv, r_pad, d),
+                            lambda b, s, j, *_: (b, 0, 0, 0))
+    pool_spec = pl.BlockSpec((1, block_len, h_kv, d),
+                             lambda *a: (pool_block(*a), 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, r_pad, d), lambda b, h, s, j, t: (b, h, 0, 0)),
-        pl.BlockSpec((1, r_pad), lambda b, h, s, j, t: (b, 0)),
-        pl.BlockSpec((1, block_len, 1, d),
-                     lambda b, h, s, j, t: (t[b, _kj(s, j)], 0, h, 0)),
-        pl.BlockSpec((1, block_len, 1, d),
-                     lambda b, h, s, j, t: (t[b, _kj(s, j)], 0, h, 0)),
+        row_spec,
+        pl.BlockSpec((1, r_pad, 1), lambda b, s, j, *_: (b, 0, 0)),
+        pool_spec, pool_spec,
     ]
-    operands = [q4, qpos, k_pool, v_pool]
+    operands = [q4, qpos[:, :, None], k_pool, v_pool]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, block_len, 1),
-                         lambda b, h, s, j, t: (t[b, _kj(s, j)], 0, h)),
-            pl.BlockSpec((1, block_len, 1),
-                         lambda b, h, s, j, t: (t[b, _kj(s, j)], 0, h)),
-        ]
+        in_specs += [pl.BlockSpec((1, block_len, h_kv),
+                                  lambda *a: (pool_block(*a), 0, 0))] * 2
         operands += [k_scale, v_scale]
-    part_spec = pl.BlockSpec((1, 1, 1, r_pad, d),
-                             lambda b, h, s, j, t: (b, h, s, 0, 0))
-    stat_spec = pl.BlockSpec((1, 1, 1, r_pad, 128),
-                             lambda b, h, s, j, t: (b, h, s, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, h_kv, s_workers, wc),
-        in_specs=in_specs,
-        out_specs=[part_spec, stat_spec, stat_spec],
-        scratch_shapes=scratch_shapes,
-    )
+    if split:
+        # each worker's un-normalized (acc, m, l), merged below
+        parts = [(h_kv, r_pad, d), (h_kv, r_pad, 128), (h_kv, r_pad, 128)]
+        out_specs = [
+            pl.BlockSpec((1, 1) + p, lambda b, s, j, *_: (b, s, 0, 0, 0))
+            for p in parts
+        ]
+        out_shape = [jax.ShapeDtypeStruct((b, s_workers) + p, jnp.float32)
+                     for p in parts]
+    else:
+        out_specs = row_spec
+        out_shape = jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype)
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = _COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         )
-    acc_p, m_p, l_p = pl.pallas_call(
-        functools.partial(_paged_split_kernel, w=w, wc=wc, **kern_kw),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h_kv, s_workers, r_pad, d),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((b, h_kv, s_workers, r_pad, 128),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((b, h_kv, s_workers, r_pad, 128),
-                                 jnp.float32),
-        ],
-        grid_spec=grid_spec,
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_kernel, scale=scale, block_len=block_len, h_kv=h_kv,
+            quantized=bool(quantized), fp8_scales=fp8_scales, w=w, wc=wc,
+            split=split,
+        ),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, s_workers, wc),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((h_kv, r_pad, 128), jnp.float32),  # row max m
+                pltpu.VMEM((h_kv, r_pad, 128), jnp.float32),  # row sum l
+                pltpu.VMEM((h_kv, r_pad, d), jnp.float32),  # un-normalized
+            ],
+        ),
         interpret=interpret,
         **kwargs,
-    )(block_tables.astype(jnp.int32), *operands)
-    # Second stage: cross-worker log-sum-exp merge, fp32. A worker whose
-    # every block was masked/skipped holds (m=NEG_INF, l=0, acc=0):
-    # NEG_INF is finite, so exp(m - m_star) is exp(0)=1 at worst and its
-    # zero l/acc contribute nothing — all-masked rows (padding) keep the
-    # single-sweep convention l=0 → out 0 via the epsilon.
-    m_w = m_p[..., 0]  # [B, H_kv, S, R] (broadcast columns, take one)
-    l_w = l_p[..., 0]
-    m_star = jnp.max(m_w, axis=2)
-    alpha = jnp.exp(m_w - m_star[:, :, None])  # [B, H_kv, S, R]
-    l_tot = jnp.sum(l_w * alpha, axis=2)  # [B, H_kv, R]
-    acc = jnp.sum(acc_p * alpha[..., None], axis=2)  # [B, H_kv, R, D]
-    out4 = (acc / jnp.maximum(l_tot, 1e-37)[..., None]).astype(out_dtype)
+    )(block_tables.astype(jnp.int32), jnp.max(q_positions, axis=1),
+      *operands)
+    if not split:
+        out4 = out
+    else:
+        acc_p, m_p, l_p = out
+        # Second stage: cross-worker log-sum-exp merge, fp32. A worker
+        # whose every block was masked/skipped holds (m=NEG_INF, l=0,
+        # acc=0): NEG_INF is finite, so exp(m - m_star) is exp(0)=1 at
+        # worst and its zero l/acc contribute nothing — all-masked rows
+        # (padding) keep the single-sweep convention l=0 → out 0 via the
+        # epsilon.
+        m_w = m_p[..., 0]  # [B, S, H_kv, R] (broadcast columns, take one)
+        l_w = l_p[..., 0]
+        m_star = jnp.max(m_w, axis=1)
+        alpha = jnp.exp(m_w - m_star[:, None])  # [B, S, H_kv, R]
+        l_tot = jnp.sum(l_w * alpha, axis=1)  # [B, H_kv, R]
+        acc = jnp.sum(acc_p * alpha[..., None], axis=1)  # [B, H_kv, R, D]
+        out4 = (acc / jnp.maximum(l_tot, 1e-37)[..., None]).astype(q.dtype)
     out4 = out4[:, :, :r]  # drop row padding
     return jnp.moveaxis(
         out4.reshape(b, h_kv, group, c, d), 3, 1
@@ -484,11 +425,18 @@ def paged_quantize_scatter(
     destination pair rides in as a scalar-prefetch operand and the pool
     OUTPUT BlockSpec index map resolves it — the scatter analogue of the
     gather's table-driven index map. ``input_output_aliases`` pins each
-    pool/scale output to its input buffer, so the write is in place and
+    pool output to its input buffer, so the write is in place and
     unvisited blocks keep their rows (required for correctness, not
     just speed — the pools are donated engine state). Duplicate
     destinations exist only for trash-block writes (inactive lanes),
     where any write order is harmless garbage.
+
+    The scale siblings are written outside the kernel: one scale row
+    ``[H_kv]`` of a ``[n_blocks, block_len, H_kv]`` array is not a block
+    Mosaic's tiling rule accepts (second-to-last block dim 1 against
+    ``block_len``), so the kernel emits the row scales it computed as a
+    dense ``[B·L, H_kv, 1]`` output and a plain ``.at[rows].set`` — the
+    jnp spelling's own scale write — places them.
 
     Args:
       k, v: ``[B, L, H_kv, D]`` rows to write (post-RoPE, compute
@@ -523,55 +471,55 @@ def paged_quantize_scatter(
         interpret = jax.default_backend() != "tpu"
     # one [2, N] scalar-prefetch operand: row i writes pool block
     # idx[0, i] at in-block offset idx[1, i]
-    idx = jnp.stack(
-        [blk.reshape(-1), off.reshape(-1)]
-    ).astype(jnp.int32)
+    rows = (blk.reshape(-1).astype(jnp.int32),
+            off.reshape(-1).astype(jnp.int32))
+    idx = jnp.stack(rows)
     kf = k.reshape(n, h_kv, d)
     vf = v.reshape(n, h_kv, d)
 
-    def _kernel(idx_ref, k_ref, v_ref, kp_in, vp_in, ks_in, vs_in,
+    def _kernel(idx_ref, k_ref, v_ref, kp_in, vp_in,
                 kp_out, vp_out, ks_out, vs_out):
-        del idx_ref, kp_in, vp_in, ks_in, vs_in  # aliased with outputs
+        del idx_ref, kp_in, vp_in  # index maps / aliased with outputs
         qk, sk = quantize_rows(k_ref[0].astype(jnp.float32), pool_dt)
         qv, sv = quantize_rows(v_ref[0].astype(jnp.float32), pool_dt)
         kp_out[0, 0] = qk
         vp_out[0, 0] = qv
-        ks_out[0, 0] = sk
-        vs_out[0, 0] = sv
+        ks_out[0] = sk[:, None]
+        vs_out[0] = sv[:, None]
 
     row_spec = pl.BlockSpec((1, h_kv, d), lambda i, idx: (i, 0, 0))
     pool_spec = pl.BlockSpec(
         (1, 1, h_kv, d), lambda i, idx: (idx[0, i], idx[1, i], 0, 0)
     )
-    sc_spec = pl.BlockSpec(
-        (1, 1, h_kv), lambda i, idx: (idx[0, i], idx[1, i], 0)
-    )
+    sc_spec = pl.BlockSpec((1, h_kv, 1), lambda i, idx: (i, 0, 0))
+    sc_rows = jax.ShapeDtypeStruct((n, h_kv, 1), k_scale.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[row_spec, row_spec,
-                  pool_spec, pool_spec, sc_spec, sc_spec],
+        in_specs=[row_spec, row_spec, pool_spec, pool_spec],
         out_specs=[pool_spec, pool_spec, sc_spec, sc_spec],
     )
     kwargs = {}
     if not interpret:
         # trash-block duplicates make write order observable in garbage
         # only; still, "arbitrary" keeps the sweep sequential
-        kwargs["compiler_params"] = _COMPILER_PARAMS(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         )
-    return pl.pallas_call(
+    k_pool, v_pool, sk_rows, sv_rows = pl.pallas_call(
         _kernel,
         out_shape=[
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
             jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-            jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-            jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
+            sc_rows, sc_rows,
         ],
         grid_spec=grid_spec,
         # operand index space includes the scalar-prefetch arg: 0=idx,
-        # 1=k rows, 2=v rows, 3..6=the four pools -> outputs 0..3
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
+        # 1=k rows, 2=v rows, 3..4=the two pools -> outputs 0..1
+        input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
         **kwargs,
-    )(idx, kf, vf, k_pool, v_pool, k_scale, v_scale)
+    )(idx, kf, vf, k_pool, v_pool)
+    return (k_pool, v_pool,
+            k_scale.at[rows].set(sk_rows[..., 0]),
+            v_scale.at[rows].set(sv_rows[..., 0]))

@@ -21,23 +21,8 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401  (re-exported: call sites import it from here)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8: top-level export; older: experimental module
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from functools import wraps
-
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    @wraps(_shard_map_legacy)
-    def shard_map(f=None, /, **kwargs):
-        # pre-0.8 signature spells check_vma as check_rep; every call site
-        # here uses the modern keyword, so translate (pyproject pins
-        # jax>=0.8 — this fallback only cushions older interpreters).
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_legacy(f, **kwargs)
 
 DATA_AXIS = "data"
 SEQ_AXIS = "seq"

@@ -1,6 +1,6 @@
 """Pipeline parallelism: a GPipe-style microbatch executor under shard_map.
 
-Completes the framework's parallelism taxonomy (dp/sp/tp/ep in
+Completes the framework's parallelism catalogue (dp/sp/tp/ep in
 ``mesh``/``sequence``/``tensor``/``models.moe``; pp here — all absent from
 the reference, SURVEY.md §2c). TPU-first shape discipline:
 

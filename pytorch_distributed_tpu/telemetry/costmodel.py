@@ -199,15 +199,12 @@ def swap_vs_recompute(
 def extract_costs(compiled) -> dict:
     """Static cost fields from a ``jax.stages.Compiled`` (or ``Lowered``).
 
-    ``cost_analysis()`` has returned both a bare dict and a per-device
-    list of dicts across jax versions — both shapes are handled. Any
-    backend that cannot produce an analysis yields an empty dict: a cost
-    card with unknown FLOPs is still a card."""
+    ``cost_analysis()`` is one dict (jax 0.9.0). Any backend that cannot
+    produce an analysis yields an empty dict: a cost card with unknown
+    FLOPs is still a card."""
     out: dict = {}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         if ca:
             if ca.get("flops") is not None:
                 out["flops"] = float(ca["flops"])

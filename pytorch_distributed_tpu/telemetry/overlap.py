@@ -113,7 +113,7 @@ FENCE_BLOCK_EPS_S = 2e-4
 #: (request traces use the rid as pid; this keeps the spaces disjoint)
 DEVICE_PID_BASE = 1_000_000_000
 
-#: the bubble-cause taxonomy (host marks use these names verbatim)
+#: the bubble-cause vocabulary (host marks use these names verbatim)
 CAUSE_OTHER_REPLICA = "other-replica-tick"
 #: round 16: the other replica's program EXECUTING on the shared device
 #: while this replica's gap is open — distinct from other-replica-tick,

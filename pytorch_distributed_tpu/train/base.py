@@ -179,23 +179,19 @@ class SuspendableTrainer:
     # _run_warmup after resume. ----
 
     def _init_compilecache(self) -> None:
-        """Point jax's persistent compilation cache at the configured
-        directory (config.compile_cache_dir, env PDT_COMPILE_CACHE_DIR
-        fallback) — a relaunched/resumed run with the same fingerprint
-        then loads its executables from disk instead of recompiling."""
-        from pytorch_distributed_tpu.utils.env import (
-            resolve_compile_cache_dir,
-        )
-
-        cache_dir = resolve_compile_cache_dir(
-            getattr(self.config, "compile_cache_dir", None)
-        )
-        if cache_dir:
-            from pytorch_distributed_tpu.compilecache import (
-                enable_persistent_cache,
+        """Turn jax's persistent compilation cache on when the config
+        asks for one (config.compile_cache_dir; the directory itself is
+        ``utils.env.compile_cache_dir``'s call — an exported
+        JAX_COMPILATION_CACHE_DIR wins) — a relaunched/resumed run with
+        the same fingerprint then loads its executables from disk
+        instead of recompiling."""
+        requested = getattr(self.config, "compile_cache_dir", None)
+        if requested:
+            from pytorch_distributed_tpu.utils.env import (
+                enable_compile_cache,
             )
 
-            enable_persistent_cache(cache_dir)
+            enable_compile_cache(requested)
 
     def _registry_entries(self):
         """Subclass hook: ``[(name, jit_fn, avals_list_thunk,

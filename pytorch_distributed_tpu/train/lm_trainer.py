@@ -149,12 +149,12 @@ class LMTrainerConfig:
     flush_every: int = 32
     # Compile cache (compilecache/, ANALYSIS.md "Cold start & compile
     # cache"): compile_cache_dir points jax's persistent compilation
-    # cache at a directory (env fallback PDT_COMPILE_CACHE_DIR) so a
-    # relaunched or preemption-resumed run loads its step executables
-    # from disk; warmup AOT-compiles the program registry (train + eval
-    # step) before the first step, with the wall time attributed to the
-    # goodput ledger's compile category and kind="warmup" manifest
-    # records in the metrics JSONL.
+    # cache at a directory (an exported JAX_COMPILATION_CACHE_DIR wins —
+    # utils.env.compile_cache_dir) so a relaunched or preemption-resumed
+    # run loads its step executables from disk; warmup AOT-compiles the
+    # program registry (train + eval step) before the first step, with
+    # the wall time attributed to the goodput ledger's compile category
+    # and kind="warmup" manifest records in the metrics JSONL.
     compile_cache_dir: Optional[str] = None
     warmup: bool = False
     # Elastic resume — see TrainerConfig: a run killed on mesh (4,2)

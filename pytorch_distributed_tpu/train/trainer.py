@@ -109,7 +109,8 @@ class TrainerConfig:
     flush_every: int = 32
     # Compile cache (compilecache/, ANALYSIS.md "Cold start & compile
     # cache"): compile_cache_dir points jax's persistent compilation
-    # cache at a directory (env fallback PDT_COMPILE_CACHE_DIR);
+    # cache at a directory (an exported JAX_COMPILATION_CACHE_DIR wins —
+    # utils.env.compile_cache_dir);
     # warmup AOT-compiles the train/eval program registry before the
     # first step (ledger compile attribution + kind="warmup" manifest).
     compile_cache_dir: Optional[str] = None

@@ -3,8 +3,9 @@
 TPU-native replacement for the reference's cluster environment pinning
 (``import hf_env; hf_env.set_env('202111')`` — the first two lines of every
 reference script). Instead of swapping a container image, we verify the
-installed JAX/flax/optax stack against a named manifest and configure
-TPU-friendly process-level defaults (compilation cache, preallocation).
+installed JAX/flax/optax stack against a named manifest. The one rule
+for where the persistent compilation cache lives is here too
+(``compile_cache_dir``), shared by every entry point.
 """
 
 from __future__ import annotations
@@ -22,22 +23,15 @@ class EnvManifest:
 
     name: str
     min_versions: dict = field(default_factory=dict)
-    env_defaults: dict = field(default_factory=dict)
 
 
 # Manifests are named by YYYYMM like the reference's '202111'.
 MANIFESTS = {
     "202607": EnvManifest(
         name="202607",
-        min_versions={"jax": (0, 5), "flax": (0, 10), "optax": (0, 2)},
-        env_defaults={
-            # Persistent XLA compilation cache: first compile of a big step
-            # function is ~20-40s on TPU; cache makes relaunches (and the
-            # suspend/resume cycle) cheap.
-            "JAX_COMPILATION_CACHE_DIR": os.path.expanduser(
-                "~/.cache/pytorch_distributed_tpu/xla"
-            ),
-        },
+        # the one installation this tree is written for (pyproject.toml
+        # carries the same pins)
+        min_versions={"jax": (0, 9), "flax": (0, 12), "optax": (0, 2)},
     ),
 }
 
@@ -69,12 +63,6 @@ def set_env(name: str = "202607", strict: bool = False) -> EnvManifest:
             f"unknown environment manifest {name!r}; known: {sorted(MANIFESTS)}"
         )
 
-    for key, value in manifest.env_defaults.items():
-        os.environ.setdefault(key, value)
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if cache_dir:
-        os.makedirs(cache_dir, exist_ok=True)
-
     import importlib
 
     for mod_name, min_version in manifest.min_versions.items():
@@ -104,17 +92,32 @@ def active_env() -> str | None:
     return _active_env
 
 
-def resolve_compile_cache_dir(cli_value: str | None = None) -> str | None:
-    """The compile-cache directory a run should use: an explicit value
-    (``--compile-cache-dir`` / ``TrainerConfig.compile_cache_dir``) wins,
-    else the ``PDT_COMPILE_CACHE_DIR`` environment fallback, else None
-    (persistent caching off — unless ``set_env`` already established the
-    process-wide ``JAX_COMPILATION_CACHE_DIR`` default).
+#: the checkout this package was imported from
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
-    This is the one resolution rule every entry point shares (recipes,
-    trainers, ``scripts/warmup.py``, ``scripts/bench_coldstart.py``), so
-    a cluster can point every job at a shared cache with one env var.
+
+def compile_cache_dir(requested: str | None = None) -> str:
+    """The directory this run keeps jax's persistent compilation cache
+    in — the one rule every entry point shares (recipes, trainers,
+    ``scripts/warmup.py``, ``chip_smoke.py``).
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory, and code
+    sets no other: the machine that runs the program decides where its
+    cache lives, and ``requested`` (``--compile-cache-dir`` /
+    ``TrainerConfig.compile_cache_dir``) loses to it. Unset, ``requested``
+    is used, and with neither the cache goes to ``<repo>/.jax_cache`` —
+    a fixed path, because the path is part of the cache key and a
+    directory that moves (a home, a tempdir, a pid) never hits.
     """
-    if cli_value:
-        return cli_value
-    return os.environ.get("PDT_COMPILE_CACHE_DIR") or None
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR") or requested
+            or os.path.join(REPO_ROOT, ".jax_cache"))
+
+
+def enable_compile_cache(requested: str | None = None) -> str:
+    """Turn jax's persistent compilation cache on at
+    ``compile_cache_dir(requested)``; returns the directory."""
+    from pytorch_distributed_tpu.compilecache import enable_persistent_cache
+
+    return enable_persistent_cache(compile_cache_dir(requested))

@@ -36,6 +36,7 @@ from pytorch_distributed_tpu.parallel import (  # noqa: E402
     make_mesh,
 )
 from pytorch_distributed_tpu.train import LMTrainer, LMTrainerConfig  # noqa: E402
+from pytorch_distributed_tpu.utils.env import compile_cache_dir  # noqa: E402
 from pytorch_distributed_tpu.utils.logging import rank0_print  # noqa: E402
 from pytorch_distributed_tpu.utils.suspend import SuspendWatcher  # noqa: E402
 
@@ -46,7 +47,7 @@ def build_token_datasets(args):
 
         vocab = 128 if args.tiny else args.vocab_size
         seq = 32 if args.tiny else args.seq_len
-        n = 64 if args.tiny else 4096
+        n = args.synthetic_size or (64 if args.tiny else 4096)
         return (
             SyntheticTokens(n, seq, vocab),
             SyntheticTokens(max(n // 8, 8), seq, vocab, seed=1),
@@ -69,9 +70,10 @@ def build_token_datasets(args):
     )
 
 
-def main() -> None:
-    args = parse_lm_args(__doc__)
-    init_process_group()
+def build_trainer(args, devices=None) -> LMTrainer:
+    """The recipe's mesh, model config and trainer from parsed flags, over
+    ``devices`` (default: all of them)."""
+    devices = list(devices) if devices is not None else jax.devices()
     train_ds, val_ds, seq_len, vocab = build_token_datasets(args)
 
     sp = args.seq_parallel
@@ -101,13 +103,13 @@ def main() -> None:
             )
         sp = 1
     mesh_mp = args.pipeline_stages or tp
-    n = jax.device_count()
+    n = len(devices)
     if n % (sp * mesh_mp):
         raise SystemExit(
             f"{n} devices not divisible by sp*mp={sp * mesh_mp}"
         )
-    mesh = make_mesh(data_parallel=n // (sp * mesh_mp), seq_parallel=sp,
-                     model_parallel=mesh_mp)
+    mesh = make_mesh(devices, data_parallel=n // (sp * mesh_mp),
+                     seq_parallel=sp, model_parallel=mesh_mp)
 
     # seq-sharded runs need a global (ring) attention; honor an explicit
     # ring variant from --attention, otherwise default to the Pallas-kernel
@@ -121,7 +123,7 @@ def main() -> None:
     if args.tiny:
         model_cfg = tiny_config(
             # tiny exists for CPU smoke runs, where the Pallas kernels
-            # can't compile: pin the XLA paths
+            # would run in the (slow) interpreter: pin the XLA paths
             attention="ring" if sp > 1 else "dense",
             model_axis="model" if tp > 1 else None,
             tp_size=tp,
@@ -170,6 +172,7 @@ def main() -> None:
         lr=args.lr,
         warmup_steps=0 if args.tiny else 2000,
         save_dir=args.save_dir,
+        log_every=args.log_every,
         num_workers=0 if args.tiny else 4,
         grad_clip_norm=args.grad_clip_norm,
         fsdp=args.fsdp,
@@ -183,7 +186,7 @@ def main() -> None:
         metrics_out=args.metrics_out,
         trace_dir=args.trace_dir,
         flush_every=args.flush_every,
-        compile_cache_dir=args.compile_cache_dir,
+        compile_cache_dir=compile_cache_dir(args.compile_cache_dir),
         warmup=args.warmup,
         cost_cards=args.cost_cards,
         anomaly_threshold=args.anomaly_threshold,
@@ -192,12 +195,18 @@ def main() -> None:
     trainer = LMTrainer(model_cfg, train_ds, val_ds, cfg, mesh=mesh,
                         suspend_watcher=SuspendWatcher())
     rank0_print(
-        f"devices: {jax.device_count()} ({jax.process_count()} hosts), "
+        f"devices: {n} ({jax.process_count()} hosts), "
         f"mesh {dict(mesh.shape)}, global batch "
         f"{global_batch_size(mesh, cfg.batch_size)} seqs × {seq_len} tokens, "
         f"attention {model_cfg.attention}, tp {tp}"
     )
-    summary = trainer.fit()
+    return trainer
+
+
+def main() -> None:
+    args = parse_lm_args(__doc__)
+    init_process_group()
+    summary = build_trainer(args).fit()
     rank0_print(f"done: best ppl {summary.get('best_ppl', float('inf')):.3f}")
 
 

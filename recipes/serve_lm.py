@@ -116,7 +116,7 @@ from pytorch_distributed_tpu.serving import Scheduler  # noqa: E402
 from pytorch_distributed_tpu.utils.logging import rank0_print  # noqa: E402
 
 
-def _parse() -> argparse.Namespace:
+def _parse(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--tiny", action="store_true",
                    help="tiny config (CPU smoke)")
@@ -199,10 +199,11 @@ def _parse() -> argparse.Namespace:
     #   python scripts/warmup.py --tiny --compile-cache-dir /tmp/cc
     #   python recipes/serve_lm.py --tiny --warmup --compile-cache-dir /tmp/cc
     p.add_argument("--compile-cache-dir", default=None,
-                   help="persistent XLA compilation cache directory (env "
-                        "fallback PDT_COMPILE_CACHE_DIR): a relaunched "
-                        "server loads its bucket programs from disk "
-                        "instead of recompiling mid-traffic")
+                   help="persistent XLA compilation cache directory "
+                        "(default <repo>/.jax_cache; an exported "
+                        "JAX_COMPILATION_CACHE_DIR wins over this flag): "
+                        "a relaunched server loads its bucket programs "
+                        "from disk instead of recompiling mid-traffic")
     p.add_argument("--warmup", action="store_true",
                    help="compile every registry program (decode tick + "
                         "all prefill buckets) before admitting traffic — "
@@ -272,12 +273,12 @@ def _parse() -> argparse.Namespace:
     p.add_argument("--http-duration", type=float, default=10.0,
                    help="seconds to keep the front door up "
                         "(--http-port)")
-    return p.parse_args()
+    return p.parse_args(argv)
 
 
 def _model(args):
     tp = dict(model_axis="model", tp_size=args.tp) if args.tp > 1 else {}
-    if args.tiny or jax.default_backend() == "cpu":
+    if args.tiny:
         cfg = tiny_config(attention="dense", max_seq_len=128, **tp)
     else:
         cfg = TransformerConfig(
@@ -319,16 +320,10 @@ GATEWAY = None
 def main() -> None:
     global GATEWAY
     args = _parse()
-    from pytorch_distributed_tpu.utils.env import resolve_compile_cache_dir
+    from pytorch_distributed_tpu.utils.env import enable_compile_cache
 
-    cache_dir = resolve_compile_cache_dir(args.compile_cache_dir)
-    if cache_dir:
-        from pytorch_distributed_tpu.compilecache import (
-            enable_persistent_cache,
-        )
-
-        # before the model init below: its programs land in the cache too
-        enable_persistent_cache(cache_dir)
+    # before the model init below: its programs land in the cache too
+    enable_compile_cache(args.compile_cache_dir)
     cfg, params, mesh = _model(args)
     prompts = _prompts(args, cfg)
     from pytorch_distributed_tpu.telemetry import (

@@ -32,9 +32,6 @@ if "host_platform_device_count" not in flags:
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -70,8 +70,6 @@ def main() -> int:
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-
     from pytorch_distributed_tpu.parallel.mesh import MESH_AXES
     from pytorch_distributed_tpu.reshard import (
         assert_rules_cover,

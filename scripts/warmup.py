@@ -32,7 +32,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from pytorch_distributed_tpu.utils.env import (  # noqa: E402
-    resolve_compile_cache_dir,
+    enable_compile_cache,
     set_env,
 )
 
@@ -40,8 +40,9 @@ from pytorch_distributed_tpu.utils.env import (  # noqa: E402
 def _parse() -> argparse.Namespace:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--compile-cache-dir", default=None,
-                   help="persistent compilation cache directory (env "
-                        "fallback PDT_COMPILE_CACHE_DIR); required")
+                   help="persistent compilation cache directory (default "
+                        "<repo>/.jax_cache; an exported "
+                        "JAX_COMPILATION_CACHE_DIR wins over this flag)")
     p.add_argument("--manifest", default=None,
                    help="warmup manifest JSONL path (default "
                         "<cache-dir>/warmup_manifest.jsonl, appended)")
@@ -64,21 +65,12 @@ def _parse() -> argparse.Namespace:
 
 def main() -> int:
     args = _parse()
-    cache_dir = resolve_compile_cache_dir(args.compile_cache_dir)
-    if not cache_dir:
-        print("--compile-cache-dir (or PDT_COMPILE_CACHE_DIR) is required:"
-              " warming a cache needs somewhere to put it",
-              file=sys.stderr)
-        return 2
-
     set_env("202607")
+    cache_dir = enable_compile_cache(args.compile_cache_dir)
     from pytorch_distributed_tpu.compilecache import (
         WarmupRunner,
-        enable_persistent_cache,
         serving_registry,
     )
-
-    enable_persistent_cache(cache_dir)
 
     import jax
     import jax.numpy as jnp
@@ -91,7 +83,7 @@ def main() -> int:
     from pytorch_distributed_tpu.serving.engine import PagedEngine
     from pytorch_distributed_tpu.utils.profiling import MetricsLogger
 
-    if args.tiny or jax.default_backend() == "cpu":
+    if args.tiny:
         cfg = tiny_config(attention="dense",
                           max_seq_len=args.max_seq_len or 128)
     else:

@@ -88,8 +88,8 @@ def test_tpu_pod_autodetect_multi_worker(fresh):
     assert dist._initialized is True
 
 
-def test_single_worker_tunnel_stays_local(fresh):
-    """A tunneled dev chip advertising TPU_WORKER_HOSTNAMES=localhost must
+def test_single_worker_host_stays_local(fresh):
+    """A one-host machine advertising TPU_WORKER_HOSTNAMES=localhost must
     NOT try to rendezvous."""
     calls, mp = fresh
     mp.setenv("TPU_WORKER_HOSTNAMES", "localhost")

@@ -8,8 +8,8 @@ TWO real ``jax.distributed`` processes on the CPU backend (4 virtual
 devices each → an 8-device global mesh) and run the actual Trainer/DDP
 code path end to end.
 
-Slow (~2 min each: two CPU compiles per launch); marked ``multihost`` so
-they can be deselected with ``-m 'not multihost'``.
+Slow (16-37 s each here, ~110 s together: two CPU compiles per launch);
+marked ``multihost`` and ``slow``, so the fast tier leaves them out.
 """
 
 import json
@@ -22,25 +22,11 @@ import time
 
 import pytest
 
-# jaxlint triage (ANALYSIS.md, "multihost triage"): every case below spawns
-# a real 2-process jax.distributed run on the CPU backend, and this
-# jaxlib's CPU client cannot compile cross-process programs at all — the
-# first multihost-sharded device_put in the child dies with
-# "XlaRuntimeError: INVALID_ARGUMENT: Multiprocess computations aren't
-# implemented on the CPU backend" (see
-# analysis.guards.backend_supports_multiprocess). The collective-axis and
-# rendezvous lints come back clean on parallel/ and train/, so this is an
-# environment capability gap, not a code defect: xfail (not skip) so a
-# collectives-capable backend reports loudly via XPASS.
-_MULTIPROCESS_XFAIL = pytest.mark.xfail(
-    reason="jaxlint triage: jaxlib CPU backend lacks multiprocess "
-    "collectives ('Multiprocess computations aren't implemented on the "
-    "CPU backend'); rendezvous/collective-axis lints clean — see "
-    "ANALYSIS.md",
-    strict=False,
-)
-
-pytestmark = [pytest.mark.multihost, _MULTIPROCESS_XFAIL]
+# Every case below spawns a real 2-process jax.distributed run on the CPU
+# backend. An earlier jaxlib's CPU client could not compile cross-process
+# programs and these were xfail; jaxlib 0.9.0's can, and they pass — as
+# real runs, 16-37 s each, which the fast tier (870 s cap) has no room for.
+pytestmark = [pytest.mark.multihost, pytest.mark.slow]
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHILD = os.path.join(REPO, "tests", "multihost_child.py")

@@ -173,7 +173,7 @@ def test_extract_costs_dedupes_aliased_operand_bytes():
 
     class FakeCompiled:
         def cost_analysis(self):
-            return [{"flops": 4000.0, "bytes accessed": 2000.0}]
+            return {"flops": 4000.0, "bytes accessed": 2000.0}
 
         def memory_analysis(self):
             return FakeMem()

@@ -642,52 +642,3 @@ def test_kill_matrix_sigkill_then_resume(tmp_path, site):
     # and the full run completed: 2 epochs x 2 steps at the child config
     assert result["final_step"] == 4
     assert all(np.isfinite(r["loss"]) for r in records if r["pid"] == pid2)
-
-
-def test_bench_retry_transient(monkeypatch):
-    """VERDICT r5 ``lm_error``: one transient remote-compile HTTP 500
-    erased a round's headline number. ``bench.retry_transient`` retries
-    transient markers on the deterministic backoff schedule, propagates
-    non-transient errors immediately, and re-raises after exhaustion."""
-    import os
-    import sys
-    import time as time_mod
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-
-    assert bench._is_transient(RuntimeError("HTTP/1.1 500 oops"))
-    assert bench._is_transient(RuntimeError("UNAVAILABLE: socket"))
-    # real OOM is handled by batch halving, never retried
-    assert not bench._is_transient(RuntimeError("RESOURCE_EXHAUSTED"))
-    assert not bench._is_transient(ValueError("shape mismatch"))
-
-    sleeps = []
-    monkeypatch.setattr(time_mod, "sleep", sleeps.append)
-
-    calls = {"n": 0}
-
-    def flaky():
-        calls["n"] += 1
-        if calls["n"] < 3:
-            raise RuntimeError("Internal Server Error")
-        return 41
-
-    assert bench.retry_transient(flaky, retries=2) == 41
-    assert calls["n"] == 3 and len(sleeps) == 2
-
-    def hard():
-        raise ValueError("not transient")
-
-    with pytest.raises(ValueError):
-        bench.retry_transient(hard, retries=2)
-
-    def always():
-        raise RuntimeError("Bad Gateway")
-
-    with pytest.raises(RuntimeError, match="Bad Gateway"):
-        bench.retry_transient(always, retries=1)
