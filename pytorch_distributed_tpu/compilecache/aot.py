@@ -34,6 +34,8 @@ import threading
 import time
 from typing import Dict, Optional
 
+from pytorch_distributed_tpu.telemetry import spans
+
 logger = logging.getLogger("pytorch_distributed_tpu")
 
 #: jax monitoring event recorded on every persistent-cache executable hit.
@@ -167,6 +169,53 @@ class BackendCompileTimer:
     def __exit__(self, *exc) -> None:
         with _listener_lock:
             self.seconds = _compile_secs.get(self._ident, 0.0) - self._start
+
+
+class program_load:
+    """The ``program.load`` span around the first compile (or load from
+    the persistent cache) of the program ``name``: the one place a layer
+    says "this call may stall for a program". After the block
+    ``cache_hit`` and ``compile_s`` (this thread's backend-compile
+    seconds: a disk load when the cache hit) are attributes, and the
+    span's args. A load opened inside a load on the same
+    thread (a registry thunk that calls the engine's ``warm_*``) is the
+    same load: it measures, and records no second span."""
+
+    def __init__(self, name: str):
+        self.name = name
+        self.cache_hit = False
+        self.compile_s = 0.0
+
+    def __enter__(self) -> "program_load":
+        tr = spans.tracer()
+        outer = tr.current()
+        nested = outer is not None and outer.name == "program.load"
+        self._hits = CacheHitCounter().__enter__()
+        self._timer = BackendCompileTimer().__enter__()
+        self._span = None if nested else tr.span(
+            "program.load", program=self.name).__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._timer.__exit__(*exc)
+        self._hits.__exit__(*exc)
+        self.cache_hit = self._hits.hits > 0
+        self.compile_s = self._timer.seconds
+        if self._span is None:
+            return False
+        self._span.args.update(cache_hit=self.cache_hit,
+                               compile_s=self.compile_s)
+        return self._span.__exit__(*exc)
+
+
+_HOT = contextlib.nullcontext()  # reusable: it holds no state
+
+
+def program_load_if(cold: bool, name: str):
+    """``program_load(name)`` around a call whose program has never run on
+    this call path (it compiles, or loads from the persistent cache);
+    nothing once the path is hot."""
+    return program_load(name) if cold else _HOT
 
 
 @contextlib.contextmanager

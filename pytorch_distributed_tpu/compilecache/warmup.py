@@ -13,9 +13,10 @@ each program compiled via its ``warm`` thunk:
   populates the persistent compilation cache so the first real use of a
   large bucket pays a disk load, not an XLA compile. ``wait()`` joins.
 
-Every compile emits a ``warmup_compile`` span through the shared
-``SpanTracer``, adds its wall time to the goodput ledger's ``compile``
-category (foreground only — background compiles don't stall the run), and
+Every compile is a ``program.load`` span in the process's span stream
+(``aot.program_load``), adds its wall time to the goodput ledger's
+``compile`` category (foreground only — background compiles don't stall
+the run), and
 appends one ``kind="warmup"`` manifest record (program, seconds,
 ``cache_hit`` from jax's persistent-cache monitoring events, fingerprint,
 priority, background) to a ``MetricsLogger`` JSONL —
@@ -30,24 +31,19 @@ import threading
 import time
 from typing import List, Optional
 
-from pytorch_distributed_tpu.compilecache.aot import (
-    BackendCompileTimer,
-    CacheHitCounter,
-)
+from pytorch_distributed_tpu.compilecache.aot import program_load
 from pytorch_distributed_tpu.compilecache.registry import (
     ProgramRegistry,
     ProgramSpec,
 )
-from pytorch_distributed_tpu.telemetry import NULL_TRACER
 
 
 class WarmupRunner:
     """Drives one registry through compilation; reusable stats object."""
 
-    def __init__(self, registry: ProgramRegistry, *, tracer=None,
-                 ledger=None, manifest=None):
+    def __init__(self, registry: ProgramRegistry, *, ledger=None,
+                 manifest=None):
         self.registry = registry
-        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.ledger = ledger
         self.manifest = manifest  # a MetricsLogger (or None)
         self.records: List[dict] = []
@@ -88,11 +84,10 @@ class WarmupRunner:
     def _compile_one(self, spec: ProgramSpec, *, execute: bool,
                      foreground: bool) -> None:
         t0 = time.perf_counter()
-        with CacheHitCounter() as hits, BackendCompileTimer() as bc, \
-                self.tracer.span("warmup_compile", program=spec.name):
+        with program_load(spec.name) as load:
             spec.warm(execute)
         seconds = time.perf_counter() - t0
-        backend_s = min(bc.seconds, seconds)
+        backend_s = min(load.compile_s, seconds)
         if foreground and self.ledger is not None:
             # split: "compile" is the XLA backend portion (collapses to a
             # disk load on a warm start), "trace" the Python residual
@@ -102,7 +97,7 @@ class WarmupRunner:
             "program": spec.name,
             "seconds": round(seconds, 6),
             "backend_compile_s": round(backend_s, 6),
-            "cache_hit": hits.hits > 0,
+            "cache_hit": load.cache_hit,
             "fingerprint": self.registry.fingerprint,
             "priority": spec.priority,
             "background": not foreground,

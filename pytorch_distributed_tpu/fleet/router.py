@@ -104,7 +104,11 @@ from pytorch_distributed_tpu.fleet.admission import (
 from pytorch_distributed_tpu.resilience.retry import backoff_delays
 from pytorch_distributed_tpu.resilience.watchdog import FleetWatchdog
 from pytorch_distributed_tpu.serving.scheduler import Scheduler
-from pytorch_distributed_tpu.telemetry import LatencySeries, percentiles
+from pytorch_distributed_tpu.telemetry import (
+    LatencySeries,
+    percentiles,
+    spans,
+)
 
 logger = logging.getLogger("pytorch_distributed_tpu")
 
@@ -136,7 +140,7 @@ class FleetRouter:
                  decode_slots: Optional[int] = None,
                  handoffs_per_tick: Optional[int] = None,
                  slo: Optional[SLOConfig] = None, devices=None,
-                 seed: int = 0, metrics_log=None, tracer=None,
+                 seed: int = 0, metrics_log=None,
                  flightrec=None, reqtrace=None, ledger=None,
                  async_host: bool = False, host_threads: int = 2,
                  affinity_cap: int = 4096,
@@ -210,20 +214,22 @@ class FleetRouter:
         self._params = params
         self._devices = devices
         self._seed = seed
-        self._tracer = tracer
         self._disaggregate = disaggregate
         self._n_prefill = n_prefill
         self._decode_slots = decode_slots
         self._scheduler_kwargs = scheduler_kwargs
         self.replicas: List[Scheduler] = []
         self.roles: List[str] = []
-        for i in range(n_replicas):
-            role = (
-                ("prefill" if i < n_prefill else "decode")
-                if disaggregate else "mixed"
-            )
-            self.roles.append(role)
-            self.replicas.append(self._make_replica(i))
+        # everything else in this constructor is assignments: the build
+        # is the replicas' (weights placed, pools allocated)
+        with spans.tracer().span("router.build", replicas=n_replicas):
+            for i in range(n_replicas):
+                role = (
+                    ("prefill" if i < n_prefill else "decode")
+                    if disaggregate else "mixed"
+                )
+                self.roles.append(role)
+                self.replicas.append(self._make_replica(i))
         self.disaggregated = disaggregate
         #: max KV handoffs per tick (None = unbounded). The handoff's
         #: host-driven gather/put/scatter runs between decode ticks in
@@ -389,15 +395,16 @@ class FleetRouter:
         kw = dict(self._scheduler_kwargs)
         if role == "decode" and self._decode_slots is not None:
             kw["n_slots"] = self._decode_slots
-        s = Scheduler(
-            self._config, self._params, replica_id=i,
-            seed=self._seed + i, prefill_only=(role == "prefill"),
-            device=dev, handoff=self._disaggregate,
-            metrics_log=self.metrics_log, tracer=self._tracer,
-            flightrec=self.flightrec, reqtrace=self.reqtrace,
-            ledger=self.ledger, host_pool=self.host_pool,
-            blocksan=self.blocksan, **kw,
-        )
+        with spans.tracer().span("sched.build", replica=i):
+            s = Scheduler(
+                self._config, self._params, replica_id=i,
+                seed=self._seed + i, prefill_only=(role == "prefill"),
+                device=dev, handoff=self._disaggregate,
+                metrics_log=self.metrics_log,
+                flightrec=self.flightrec, reqtrace=self.reqtrace,
+                ledger=self.ledger, host_pool=self.host_pool,
+                blocksan=self.blocksan, **kw,
+            )
         s.on_retire = self._note_retire
         return s
 
@@ -860,7 +867,8 @@ class FleetRouter:
         if not alive:
             decision = Decision(SHED, -1, "fleet-unavailable")
         else:
-            with self.ledger.host("admission/gate"):
+            with spans.tracer().span("router.gate", rid=rid), \
+                    self.ledger.host("admission/gate"):
                 decision = self.gate.route(
                     self._group_metrics(alive), preferred,
                     deadline_s=deadline_s,
@@ -1074,30 +1082,31 @@ class FleetRouter:
         synchronous schedule, so greedy token streams are bit-identical
         between modes; only cross-replica interleaving (and the wall
         clock) changes."""
-        if self._start_time is None:
-            self._start_time = time.perf_counter()
-        out: List[Tuple[int, int]] = []
-        # harvested requests replay FIRST, so a request re-dispatched at
-        # tick N starts prefilling at tick N (once its backoff elapses)
-        # — no extra tick of dead air between death and recovery
-        self._pump_redispatch()
-        # note: interleaved collect/dispatch in the async loop — while
-        # replica i's freshly dispatched tick N is in flight, the loop
-        # is already collecting replica i+1's tick N−1 and building its
-        # tick N, so every replica's dispatch-side host work overlaps
-        # some OTHER replica's device work
-        for i in self._alive(self.decode_group + self.entry_group):
-            out.extend(self._run_tick(i))
-        if self.decode_group:
-            with self.ledger.host("handoff-pump"):
-                self._pump_handoffs()
-        for rid, tok in out:
-            self.results.setdefault(rid, []).append(tok)
-        self._drop_retired()
-        self._tick += 1
-        if self._tick % 16 == 0:  # sampled: metrics() per tick is waste
-            self._recommend_peak = max(self._recommend_peak,
-                                       self.recommend_replicas())
+        with spans.tracer().span("router.step"):
+            if self._start_time is None:
+                self._start_time = time.perf_counter()
+            out: List[Tuple[int, int]] = []
+            # harvested requests replay FIRST, so a request re-dispatched at
+            # tick N starts prefilling at tick N (once its backoff elapses)
+            # — no extra tick of dead air between death and recovery
+            self._pump_redispatch()
+            # note: interleaved collect/dispatch in the async loop — while
+            # replica i's freshly dispatched tick N is in flight, the loop
+            # is already collecting replica i+1's tick N−1 and building its
+            # tick N, so every replica's dispatch-side host work overlaps
+            # some OTHER replica's device work
+            for i in self._alive(self.decode_group + self.entry_group):
+                out.extend(self._run_tick(i))
+            if self.decode_group:
+                with self.ledger.host("handoff-pump"):
+                    self._pump_handoffs()
+            for rid, tok in out:
+                self.results.setdefault(rid, []).append(tok)
+            self._drop_retired()
+            self._tick += 1
+            if self._tick % 16 == 0:  # sampled: metrics() per tick is waste
+                self._recommend_peak = max(self._recommend_peak,
+                                           self.recommend_replicas())
         return out
 
     @property

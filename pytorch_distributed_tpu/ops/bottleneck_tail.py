@@ -79,6 +79,7 @@ def moments(z: jax.Array):
             jax.ShapeDtypeStruct((f, f), jnp.float32),
         ],
         interpret=_interpret(),
+        name="bottleneck_moments",
     )(z)
     return s[0], m2
 
@@ -130,6 +131,7 @@ def tail_bwd_reduce(z: jax.Array, g: jax.Array, out: jax.Array):
             jax.ShapeDtypeStruct((1, e), jnp.float32),
         ],
         interpret=_interpret(),
+        name="bottleneck_bwd_reduce",
     )(z, g, out)
     return gp, p, sb[0]
 
@@ -170,4 +172,5 @@ def tail_bwd_dz(gp: jax.Array, z: jax.Array, wa: jax.Array, c: jax.Array,
         out_specs=pl.BlockSpec((1, h, w, f), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(z.shape, z.dtype),
         interpret=_interpret(),
+        name="bottleneck_bwd_dz",
     )(gp, z, wa, c, dmn)

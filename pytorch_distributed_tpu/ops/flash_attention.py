@@ -157,6 +157,7 @@ def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, kv_len, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),  # un-normalized output
         ],
         interpret=interpret,
+        name="flash_fwd",
         **kwargs,
     )(q3, k3, v3)
 
@@ -392,6 +393,7 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse3, do3, scale, causal, blocks,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_fused",
         **kwargs,
     )(q3, k3, v3, do3, lse3, delta3)
     dq3 = jnp.sum(dqp3.astype(jnp.float32), axis=1).astype(q3.dtype)
@@ -440,6 +442,7 @@ def _flash_bwd(q3, k3, v3, o3, lse3, do3, scale, causal, dq_blocks,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
         **kwargs,
     )(q3, k3, v3, do3, lse3, delta3)
 
@@ -463,6 +466,7 @@ def _flash_bwd(q3, k3, v3, o3, lse3, do3, scale, causal, dq_blocks,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
         **kwargs,
     )(q3, k3, v3, do3, lse3, delta3)
     return dq3, dk3, dv3

@@ -94,6 +94,7 @@ def _local_labels(labels, v_local, vocab_axis):
     return labels.astype(jnp.int32) - off
 
 
+@jax.named_scope("fused_ce")
 def _fused_ce_fwd(block_n, cdt, vocab_axis, x, kernel, labels, weights):
     n, e = x.shape
     v_local = kernel.shape[1]
@@ -115,6 +116,7 @@ def _fused_ce_fwd(block_n, cdt, vocab_axis, x, kernel, labels, weights):
     return total, (x, kernel, labels, weights, lse.reshape(n), z.reshape(n))
 
 
+@jax.named_scope("fused_ce")
 def _fused_ce_bwd(block_n, cdt, vocab_axis, res, g):
     x, kernel, labels, weights, lse, z = res
     n, e = x.shape

@@ -374,6 +374,7 @@ def paged_flash_attention(
             ],
         ),
         interpret=interpret,
+        name="paged_decode_attn",
         **kwargs,
     )(block_tables.astype(jnp.int32), jnp.max(q_positions, axis=1),
       *operands)
@@ -518,6 +519,7 @@ def paged_quantize_scatter(
         # 1=k rows, 2=v rows, 3..4=the two pools -> outputs 0..1
         input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
+        name="paged_kv_write",
         **kwargs,
     )(idx, kf, vf, k_pool, v_pool)
     return (k_pool, v_pool,

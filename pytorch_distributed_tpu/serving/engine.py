@@ -35,6 +35,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import os
+import re
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -54,6 +55,11 @@ from pytorch_distributed_tpu.serving.kv_pool import (
     init_paged_cache,
     paged_cache_specs,
 )
+from pytorch_distributed_tpu.compilecache.aot import (
+    program_load,
+    program_load_if,
+)
+from pytorch_distributed_tpu.telemetry import spans
 from pytorch_distributed_tpu.telemetry.overlap import NULL_LEDGER
 
 
@@ -242,9 +248,13 @@ class PagedEngine:
             dataclasses.replace(config, model_axis=None, tp_size=1)
             if tp else config
         )
-        self.cache = init_paged_cache(init_cfg, params, n_blocks, block_len,
-                                      kv_dtype=kv_dtype)
-        self.logits = jnp.zeros((n_slots, config.vocab_size), jnp.float32)
+        # (the span is here and not in kv_pool: pool_block_bytes traces
+        # init_paged_cache under eval_shape)
+        with spans.tracer().span("pool.alloc", blocks=n_blocks):
+            self.cache = init_paged_cache(init_cfg, params, n_blocks,
+                                          block_len, kv_dtype=kv_dtype)
+            self.logits = jnp.zeros((n_slots, config.vocab_size),
+                                    jnp.float32)
 
         self._chunk_fns: Dict[Tuple[int, int], callable] = {}
         self._decode_fn = None
@@ -300,11 +310,12 @@ class PagedEngine:
             self._param_specs = match_partition_rules(_tp_rules(config),
                                                       params)
             self._cache_specs = paged_cache_specs(config, self.cache)
-            self.params = jax.device_put(
-                params,
-                jax.tree.map(lambda s: NamedSharding(mesh, s),
-                             self._param_specs),
-            )
+            with spans.tracer().span("weights.place"):
+                self.params = jax.device_put(
+                    params,
+                    jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                 self._param_specs),
+                )
             self.cache = jax.device_put(
                 self.cache,
                 jax.tree.map(lambda s: NamedSharding(mesh, s),
@@ -322,7 +333,8 @@ class PagedEngine:
         # wherever the committed arguments already live.
         self.device = device
         if device is not None:
-            self.params = jax.device_put(self.params, device)
+            with spans.tracer().span("weights.place"):
+                self.params = jax.device_put(self.params, device)
             self.cache = jax.device_put(self.cache, device)
             self.logits = jax.device_put(self.logits, device)
 
@@ -401,7 +413,8 @@ class PagedEngine:
                 out_specs=(self._cache_specs, P()),
                 check_vma=False,
             )
-        fn = jax.jit(body, donate_argnums=(1, 2))
+        fn = jax.jit(self._named(body, self.chunk_program_name(k_pad, wp)),
+                     donate_argnums=(1, 2))
         self._chunk_fns[key] = fn
         return fn
 
@@ -442,7 +455,8 @@ class PagedEngine:
                 out_specs=(self._cache_specs, P(), P(), P()),
                 check_vma=False,
             )
-        self._decode_fn = jax.jit(body, donate_argnums=(1, 2))
+        self._decode_fn = jax.jit(self._named(body, self.DECODE_PROGRAM),
+                                  donate_argnums=(1, 2))
         return self._decode_fn
 
     # ---- program enumeration + warmup (compilecache.serving_registry) ----
@@ -451,6 +465,16 @@ class PagedEngine:
     def chunk_program_name(k_pad: int, wp: int) -> str:
         """Stable registry identity of one chunk-prefill bucket."""
         return f"chunk_prefill[k={k_pad},w={wp}]"
+
+    @staticmethod
+    def _named(body, program: str):
+        """``body`` under the registry name ``program`` spelt as an
+        identifier (``chunk_prefill[k=8,w=4]`` -> ``chunk_prefill_k8_w4``):
+        the profiler calls a jitted module ``jit_<function name>``, so a
+        trace says which program ran."""
+        body.__name__ = re.sub(r"[^0-9A-Za-z]+", "_",
+                               program.replace("=", "")).strip("_")
+        return body
 
     def bucket_for(self, jobs: List["ChunkJob"]) -> Tuple[int, int]:
         """The (padded job count, table-slice width) bucket ``run_chunks``
@@ -630,7 +654,8 @@ class PagedEngine:
                 out_specs=self._cache_specs,
                 check_vma=False,
             )
-        self._copy_fn = jax.jit(body, donate_argnums=(0,))
+        self._copy_fn = jax.jit(
+            self._named(body, self.BLOCK_COPY_PROGRAM), donate_argnums=(0,))
         return self._copy_fn
 
     def _require_prefix(self):
@@ -717,18 +742,21 @@ class PagedEngine:
         slots = jnp.full((k_pad,), self.n_slots, jnp.int32)
         is_last = jnp.zeros((k_pad,), bool)
         last_idx = jnp.zeros((k_pad,), jnp.int32)
+        name = self.chunk_program_name(k_pad, wp)
         if execute:
-            self.cache, self.logits = fn(
-                self.params, self.cache, self.logits, tokens, starts,
-                tables, slots, is_last, last_idx,
-            )
+            with program_load_if((k_pad, wp) not in self._hot_chunks, name):
+                self.cache, self.logits = fn(
+                    self.params, self.cache, self.logits, tokens, starts,
+                    tables, slots, is_last, last_idx,
+                )
             self._hot_chunks.add((k_pad, wp))
             return None
         cache_aval, logits_aval = self._cache_logits_avals()
-        return fn.lower(
-            self.params, cache_aval, logits_aval, tokens, starts,
-            tables, slots, is_last, last_idx,
-        ).compile()
+        with program_load(name):
+            return fn.lower(
+                self.params, cache_aval, logits_aval, tokens, starts,
+                tables, slots, is_last, last_idx,
+            ).compile()
 
     def warm_decode(self, execute: bool = True):
         """Force the decode tick compiled — same contract (and return
@@ -745,17 +773,19 @@ class PagedEngine:
         if self.device is not None:
             rng = jax.device_put(rng, self.device)
         if execute:
-            self.cache, self.logits, _, _ = fn(
-                self.params, self.cache, self.logits, positions, active,
-                tables, rng,
-            )
+            with program_load_if(not self._hot_decode, self.DECODE_PROGRAM):
+                self.cache, self.logits, _, _ = fn(
+                    self.params, self.cache, self.logits, positions, active,
+                    tables, rng,
+                )
             self._hot_decode = True
             return None
         cache_aval, logits_aval = self._cache_logits_avals()
-        return fn.lower(
-            self.params, cache_aval, logits_aval, positions, active,
-            tables, rng,
-        ).compile()
+        with program_load(self.DECODE_PROGRAM):
+            return fn.lower(
+                self.params, cache_aval, logits_aval, positions, active,
+                tables, rng,
+            ).compile()
 
     # ---- slot-level operations ----
 
@@ -981,7 +1011,8 @@ class PagedEngine:
             blocks = jax.tree.map(lambda pool: pool[idx], cache)
             return blocks, logits[slot]
 
-        fn = jax.jit(body)  # pure read: nothing donated
+        # pure read: nothing donated
+        fn = jax.jit(self._named(body, self.export_program_name(n_pad)))
         self._export_fns[n_pad] = fn
         return fn
 
@@ -998,7 +1029,8 @@ class PagedEngine:
             # scatter — same inert trick as the chunk program's padding
             return cache, logits.at[slot].set(row)
 
-        fn = jax.jit(body, donate_argnums=(0, 1))
+        fn = jax.jit(self._named(body, self.import_program_name(n_pad)),
+                     donate_argnums=(0, 1))
         self._import_fns[n_pad] = fn
         return fn
 
@@ -1113,7 +1145,8 @@ class PagedEngine:
             blocks = jax.tree.map(lambda pool: pool[idx], cache)
             return blocks, logits[slot]
 
-        fn = jax.jit(body)  # pure read: nothing donated
+        # pure read: nothing donated
+        fn = jax.jit(self._named(body, self.swap_out_program_name(n_pad)))
         self._swap_out_fns[n_pad] = fn
         return fn
 
@@ -1128,7 +1161,8 @@ class PagedEngine:
             )
             return cache, logits.at[slot].set(row)
 
-        fn = jax.jit(body, donate_argnums=(0, 1))
+        fn = jax.jit(self._named(body, self.swap_in_program_name(n_pad)),
+                     donate_argnums=(0, 1))
         self._swap_in_fns[n_pad] = fn
         return fn
 
@@ -1323,8 +1357,11 @@ class PagedEngine:
         # no fence handle: both outputs are donated into later programs,
         # so completion rides the t1 lower bound tightened by the next
         # sync launch on this replica stream (the decode tick).
-        with self.ledger.launch(self.ledger_replica,
-                                self.chunk_program_name(k_pad, wp)):
+        name = self.chunk_program_name(k_pad, wp)
+        with spans.tracer().span("engine.chunk.launch", jobs=len(jobs),
+                                 bucket=(k_pad, wp)), \
+                program_load_if((k_pad, wp) not in self._hot_chunks, name), \
+                self.ledger.launch(self.ledger_replica, name):
             # ONE batched explicit transfer for the six host-built
             # operands, inside the launch window (dispatch cost; see
             # the decode call's note on the per-operand asarray tax)
@@ -1358,19 +1395,25 @@ class PagedEngine:
             # loop's host wall, round-16 profile), and a bare-np jit
             # call would be an IMPLICIT transfer the no_recompile guard
             # rightly rejects.
-            positions, active, masked = jax.device_put(
-                (np.asarray(positions, np.int32), active, masked)
-            )
-            self.cache, self.logits, positions, tokens = fn(
-                self.params, self.cache, self.logits,
-                positions, active, masked, rng,
-            )
+            with spans.tracer().span("engine.decode.launch",
+                                     lanes=int(np.count_nonzero(active))), \
+                    program_load_if(not self._hot_decode,
+                                    self.DECODE_PROGRAM):
+                positions, active, masked = jax.device_put(
+                    (np.asarray(positions, np.int32), active, masked)
+                )
+                self.cache, self.logits, positions, tokens = fn(
+                    self.params, self.cache, self.logits,
+                    positions, active, masked, rng,
+                )
             if sync:
                 # the token fetch inside the window materializes the
                 # program's result, so t1 IS device completion — the
                 # exact anchor the chunk launches' lower bounds tighten
-                # against
-                tokens = np.asarray(tokens)
+                # against. It is where the host waits for the tick: the
+                # same span the async path books in decode_collect
+                with spans.tracer().span("engine.collect.wait"):
+                    tokens = np.asarray(tokens)
             else:
                 lt.handle = tokens  # non-donated output: fence target
         self._hot_decode = True
@@ -1402,5 +1445,7 @@ class PagedEngine:
         work is usually done and the wait is a no-op), then fetches
         tokens and positions to host. Returns the same
         ``(tokens [n_slots], new_positions)`` as ``decode``."""
-        self.ledger.complete(launch_token)
-        return np.asarray(tokens), np.array(positions)
+        with spans.tracer().span("engine.collect.wait"):
+            self.ledger.complete(launch_token)
+            tokens = np.asarray(tokens)
+        return tokens, np.array(positions)

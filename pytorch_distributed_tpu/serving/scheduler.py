@@ -30,9 +30,11 @@ and queue wait (submit → admit), all exact host-side series from
 timestamps the scheduler already holds (``telemetry.LatencySeries``).
 Pass ``metrics_log`` (a ``MetricsLogger``) to stream one ``kind=
 "request"`` JSONL record per retirement — the raw material
-``scripts/telemetry_report.py`` computes percentiles from — and
-``tracer`` (a ``telemetry.SpanTracer``) for admission / prefill_chunk /
-decode_tick spans.
+``scripts/telemetry_report.py`` computes percentiles from. A tick's
+phases (``sched.expire``, ``sched.admit``, ``sched.chunk_plan``, the
+engine's launches, ``engine.collect.wait``, ``sched.collect.process``)
+and each request's queue wait (``req.queue``) are spans in the process's
+stream (``telemetry.spans.tracer()``).
 
 KV pressure tier (round 13; ANALYSIS.md "KV pressure & preemption"):
 ``offload=True`` arms the second tier — ``preempt(rid)`` parks a
@@ -128,12 +130,12 @@ from pytorch_distributed_tpu.telemetry import (
     NULL_LEDGER,
     NULL_RECORDER,
     NULL_REQTRACER,
-    NULL_TRACER,
     AnomalySentinel,
     GoodputLedger,
     LatencySeries,
     ProgramTimes,
     percentiles,
+    spans,
 )
 
 
@@ -249,7 +251,7 @@ class Scheduler:
                  prefill_chunk: int = 64, admit_per_step: int = 4,
                  temperature: float = 0.0, top_k: Optional[int] = None,
                  seed: int = 0, eos_id: Optional[int] = None, mesh=None,
-                 tracer=None, metrics_log=None, replica_id: int = 0,
+                 metrics_log=None, replica_id: int = 0,
                  prefill_only: bool = False, device=None,
                  handoff: bool = False, flightrec=None,
                  anomaly_threshold: float = 8.0,
@@ -358,7 +360,6 @@ class Scheduler:
         self._occupancy_sum = 0.0  # mean-able over steps
         self._start_time: Optional[float] = None
         # ---- latency series (telemetry/latency.py; exact, host-side) ----
-        self.tracer = tracer if tracer is not None else NULL_TRACER
         self.metrics_log = metrics_log
         self.ttft = LatencySeries("ttft")
         # warm-only TTFT: requests whose lifetime saw no compile stall —
@@ -511,7 +512,6 @@ class Scheduler:
 
         runner = WarmupRunner(
             serving_registry(self.engine),
-            tracer=self.tracer,
             ledger=self.goodput,
             manifest=self.metrics_log,
         )
@@ -672,6 +672,8 @@ class Scheduler:
             self._adm_latency_steps += self._step_count - req.submit_step
             self._adm_latency_s += now - req.submit_time
             self.queue_wait.observe(now - req.submit_time)
+            spans.tracer().record("req.queue", req.submit_time, now,
+                                  rid=req.rid)
             self._admitted_prefill_tokens += req.length - req.prefill_done
             if hit is not None:
                 self._prefix_covered_tokens += hit.covered
@@ -1117,7 +1119,9 @@ class Scheduler:
         if self._start_time is None:
             self._start_time = time.perf_counter()
         t_step0 = time.perf_counter()
-        self._expire_deadlines()
+        tr = spans.tracer()
+        with tr.span("sched.expire"):
+            self._expire_deadlines()
         if self.offload:
             # pressure tier: close last tick's swap-out windows (their
             # blocks return to the pool), then restore parked requests
@@ -1125,10 +1129,11 @@ class Scheduler:
             # ahead of the queue, before its next decode tick
             self._finalize_swaps()
             self._restore_parked()
-        with self.tracer.span("admission", queued=len(self.queue)), \
+        with tr.span("sched.admit", queued=len(self.queue)), \
                 self.ledger.host("admission/gate", self.replica_id):
             self._admit()
-        jobs = self._chunk_jobs()
+        with tr.span("sched.chunk_plan"):
+            jobs = self._chunk_jobs()
         if jobs:
             # cold bucket: this batch's (k_pad, wp) program has never
             # executed — the call below stalls for its compile (or a
@@ -1140,9 +1145,7 @@ class Scheduler:
                 for j in jobs:
                     self.resident[j.slot].cold = True
             t_chunk = time.perf_counter()
-            with self.tracer.span("prefill_chunk", jobs=len(jobs)), \
-                    attribute_compile(self.goodput if cold_bucket
-                                      else None):
+            with attribute_compile(self.goodput if cold_bucket else None):
                 self.engine.run_chunks(jobs)
             if not cold_bucket:
                 # cost-card join: warm dispatch wall attributed to THIS
@@ -1237,8 +1240,7 @@ class Scheduler:
             for slot in np.nonzero(active)[0]:
                 self.resident[int(slot)].cold = True
         t_dec = time.perf_counter()
-        with self.tracer.span("decode_tick", lanes=int(active.sum())), \
-                attribute_compile(self.goodput if cold_decode else None):
+        with attribute_compile(self.goodput if cold_decode else None):
             if sync:
                 tokens, positions = self.engine.decode(
                     self.positions, active, sub
@@ -1310,15 +1312,10 @@ class Scheduler:
         out: List[Tuple[int, int]] = []
         # collect-side host work under its own mark: the one-loop async
         # A/B needs "processing replica i's tokens" visible as a cause
-        # when it serializes another replica's gap. Entered manually so
-        # the 50-line loop below keeps its indentation; the finally at
-        # the end of this method closes it on every path.
-        collect_mark = self.ledger.host("tick-collect", self.replica_id)
-        collect_mark.__enter__()
-        try:
+        # when it serializes another replica's gap
+        with spans.tracer().span("sched.collect.process"), \
+                self.ledger.host("tick-collect", self.replica_id):
             self._process_collected(h, tokens, now, out)
-        finally:
-            collect_mark.__exit__(None, None, None)
         self._collected.extend(out)
         if (self.host_pool is not None and out
                 and self._step_count - self._gate_refreshed_step

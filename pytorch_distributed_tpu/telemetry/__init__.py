@@ -12,11 +12,12 @@ closes:
   per-log-interval blocking ``float()`` sync that stalled the dispatch
   pipeline in both trainers; the logged series is bit-identical to the
   blocking path (same f32 scalars, one hop through the buffer).
-- ``spans`` — nested host-side span tracing (data_wait, step_dispatch,
-  ckpt_save, rollback_replay, admission, prefill_chunk, decode_tick)
-  emitted as Chrome-trace JSON and mirrored into
-  ``jax.profiler.TraceAnnotation`` so host phases line up with XLA op
-  timelines in xprof.
+- ``spans`` — the process's ONE span stream
+  (``spans.tracer()``): every component records into it by default, into
+  a bounded ring, on the ``time.perf_counter`` clock; each span is also a
+  ``jax.profiler.TraceAnnotation("pdt:<name>")``, so under a profiler
+  session it lies beside the XLA operations. Nothing is threaded through
+  constructors; ``--trace-dir`` only says where ``save()`` writes.
 - ``goodput`` — a run-level ledger classifying wall time into
   productive-step vs compile, data wait, checkpoint stall, rollback
   replay, and watchdog stall; fractions sum to 1 by construction.
@@ -158,7 +159,7 @@ from pytorch_distributed_tpu.telemetry.schema import (
     validate_record,
     validate_stream,
 )
-from pytorch_distributed_tpu.telemetry.spans import NULL_TRACER, SpanTracer
+from pytorch_distributed_tpu.telemetry.spans import SpanTracer, tracer
 
 __all__ = [
     "AnomalySentinel",
@@ -210,6 +211,6 @@ __all__ = [
     "REQUIRED_KEYS",
     "validate_record",
     "validate_stream",
-    "NULL_TRACER",
     "SpanTracer",
+    "tracer",
 ]

@@ -177,6 +177,6 @@ def read_mirror(path: str) -> List[dict]:
     return events
 
 
-#: Shared no-op recorder (the NULL_TRACER pattern): call sites thread a
+#: Shared no-op recorder: call sites thread a
 #: recorder through without caring whether anyone is listening.
 NULL_RECORDER = FlightRecorder(enabled=False)

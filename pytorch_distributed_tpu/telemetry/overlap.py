@@ -490,7 +490,7 @@ class DispatchLedger:
         ]
 
 
-#: Shared no-op ledger (the NULL_TRACER pattern): call sites thread one
+#: Shared no-op ledger: call sites thread one
 #: through unconditionally.
 NULL_LEDGER = DispatchLedger(enabled=False)
 

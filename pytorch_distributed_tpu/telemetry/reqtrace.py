@@ -80,7 +80,7 @@ class ReqTracer:
 
     ``sink`` needs one method, ``log(**record)`` (``None`` keeps records
     in memory only — ``self.records``). A disabled tracer costs one
-    truthiness check per call site (the ``NULL_TRACER`` pattern), so
+    truthiness check per call site, so
     every lifecycle owner threads one through unconditionally.
 
     Thread-safe: id/seq allocation, open-span bookkeeping, and the sink
@@ -304,7 +304,7 @@ class ReqTracer:
         ]
 
 
-#: Shared no-op tracer (the NULL_TRACER pattern): lifecycle owners thread
+#: Shared no-op request tracer: lifecycle owners thread
 #: one through without caring whether anyone is listening.
 NULL_REQTRACER = ReqTracer(enabled=False)
 

@@ -1,125 +1,217 @@
-"""Nested host-side span tracing → Chrome trace JSON + xprof annotations.
+"""The process's one span stream.
 
-Wall-clock phases of a run (data_wait, step_dispatch, ckpt_save,
-rollback_replay, admission, prefill_chunk, decode_tick) as nested spans:
+Every component records into ``tracer()``: host spans at the layer
+boundaries (trainer build, program load, the serving tick's phases, the
+admission gate). Nothing is threaded through constructors and nothing
+switches it off. Counts stay where the program already keeps them
+(``Scheduler.metrics()``, ``FleetRouter.metrics()``, the ``cache_hit``
+argument of every ``program.load``):
 
-- collected host-side with ``time.perf_counter`` (microsecond Chrome
-  trace convention), one complete ("X") event per span, ``tid`` = the
-  recording thread — ``chrome://tracing`` / Perfetto load the output
-  directly;
-- mirrored into ``jax.profiler.TraceAnnotation`` when a jax profiler
-  trace is active, so the host phases line up with the XLA op/fusion
-  timelines in xprof (the reference has no tracing story at all,
-  PAPER.md §5).
+- a record is ``(id, parent_id, name, t0, t1, tid, rid, args)``: ``id``
+  unique in the process, ``parent_id`` the enclosing span on that thread
+  (or the explicit ``cause=``), ``rid`` the request where there is one,
+  ``t0``/``t1`` absolute ``time.perf_counter()`` seconds: the clock of
+  ``telemetry.overlap``, ``telemetry.reqtrace`` and of whoever drives
+  the program, so intervals can be laid beside theirs;
+- records live in a ring of ``RING_RECORDS`` (the flight-recorder
+  pattern of ``telemetry/flightrec.py``): a server that runs for days
+  holds a bounded window, the newest;
+- every span is also entered as ``jax.profiler.TraceAnnotation
+  ("pdt:<name>")``: a no-op without a profiler session, and with one the
+  span lies on the device trace's clock, beside the XLA operations. The
+  trainers' step span is a ``StepTraceAnnotation("train", step_num=)``.
 
-A disabled tracer (``NULL_TRACER``, the default everywhere) costs one
-truthiness check per span — components thread a tracer through without
-caring whether anyone is listening.
+"Off" is therefore: recording into the ring, no profiler session,
+nothing written. "On" is a profiler session (the mirror is live) and
+``save()``, which writes the ring as Chrome-trace JSON (the recipes'
+``--trace-dir`` says where). ``tests/test_spans.py`` holds the cost:
+under 3 us a span, at most 24 spans a serving tick and 4 a training step.
 """
 
 from __future__ import annotations
 
-import contextlib
+import collections
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, List, NamedTuple, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+#: records the ring holds. A chat-backlog tick is 0.22 s: an hour is
+#: 16,400 ticks at up to 24 spans, and 13,000 requests at 3 (the budget
+#: tests/test_spans.py holds). Full, the ring is about 100 MB of tuples.
+RING_RECORDS = 1 << 19
+
+#: prefix of the spans' names in the profiler's trace
+MIRROR_PREFIX = "pdt:"
+
+
+class Record(NamedTuple):
+    """One finished span (or one interval booked by ``record``)."""
+
+    id: int
+    parent_id: Optional[int]
+    name: str
+    t0: float
+    t1: float
+    tid: int
+    rid: Optional[int]
+    args: Optional[dict]
+
+
+class Span:
+    """One open span: a context manager that books itself on exit.
+    ``args`` may be filled in while it is open (``program.load`` learns
+    ``cache_hit`` only when the load is over)."""
+
+    __slots__ = ("_tracer", "_mirror", "_stack", "id", "parent_id", "name",
+                 "rid", "args", "t0")
+
+    def __init__(self, tracer, mirror, name, rid, cause, args):
+        self._tracer = tracer
+        self._mirror = mirror
+        self.id = next(tracer._ids)
+        self.parent_id = cause
+        self.name = name
+        self.rid = rid
+        self.args = args
+
+    def __enter__(self) -> "Span":
+        stack = self._stack = self._tracer._open()
+        if self.parent_id is None and stack:
+            self.parent_id = stack[-1].id
+        stack.append(self)
+        self._mirror.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._mirror.__exit__(*exc)
+        self._stack.pop()  # spans are context managers: exits are LIFO
+        self._tracer._ring.append(
+            (self.id, self.parent_id, self.name, self.t0, t1,
+             threading.get_ident(), self.rid, self.args or None))
+        return False
 
 
 class SpanTracer:
-    """Collects nested spans; ``save()`` writes Chrome-trace JSON.
+    """The recorder. One per process (``tracer()``); tests may build
+    their own, e.g. with a small ``maxlen``."""
 
-    ``span(name, **args)`` is a context manager; spans may nest freely
-    (the Chrome trace format reconstructs the stack from containment per
-    ``tid``). Thread-safe: events append under a lock, ``tid`` is the
-    recording thread's ident, and the open-span stack is PER-THREAD
-    (keyed by ``threading.get_ident()``) — concurrent emitters (the
-    background warmup compiler today; ROADMAP item 3's worker threads)
-    each nest within their own stack, so one thread's open span can
-    never become another thread's parent. Each event records its
-    ``depth`` and ``parent`` from that stack.
-    """
+    def __init__(self, maxlen: int = RING_RECORDS):
+        self._ring: collections.deque = collections.deque(maxlen=maxlen)
+        self._ids = itertools.count(1)  # next() is atomic under the GIL
+        self._local = threading.local()  # .stack: this thread's open spans
 
-    def __init__(self, enabled: bool = True, mirror_jax: bool = True):
-        self.enabled = bool(enabled)
-        self.mirror_jax = bool(mirror_jax)
-        self._events: List[dict] = []
-        self._lock = threading.Lock()
-        # thread ident -> stack of open span names. Mutated only by the
-        # owning thread, but always under self._lock: the dict itself is
-        # shared, and stack() may read another thread's entry.
-        self._stacks: Dict[int, List[str]] = {}
-        self._t0 = time.perf_counter()
-
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
-
-    def stack(self) -> List[str]:
-        """The CALLING thread's open span names, outermost first."""
-        with self._lock:
-            return list(self._stacks.get(threading.get_ident(), ()))
-
-    @contextlib.contextmanager
-    def span(self, name: str, **args) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        tid = threading.get_ident()
-        with self._lock:
-            stack = self._stacks.setdefault(tid, [])
-            depth = len(stack)
-            parent = stack[-1] if stack else None
-            stack.append(name)
-        ctx = contextlib.nullcontext()
-        if self.mirror_jax:
-            try:
-                import jax
-
-                ctx = jax.profiler.TraceAnnotation(name)
-            except Exception:  # no jax / no profiler: host-only spans
-                ctx = contextlib.nullcontext()
-        t0 = self._now_us()
+    def _open(self) -> list:
         try:
-            with ctx:
-                yield
-        finally:
-            dur = self._now_us() - t0
-            ev = {
-                "name": name,
-                "ph": "X",
-                "ts": t0,
-                "dur": dur,
-                "pid": os.getpid(),
-                "tid": tid,
-            }
-            if depth:
-                args = dict(args, depth=depth, parent=parent)
-            if args:
-                ev["args"] = args
-            with self._lock:
-                # this thread's innermost open span is necessarily ours:
-                # spans are context managers, so per-thread exits are LIFO
-                stack.pop()
-                if not stack:
-                    self._stacks.pop(tid, None)
-                self._events.append(ev)
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
-    # ---- output ----------------------------------------------------------
+    # ---- recording -------------------------------------------------------
+
+    def span(self, name: str, *, rid: Optional[int] = None,
+             cause: Optional[int] = None, **args) -> Span:
+        """A span around a ``with`` block. Its parent is the enclosing
+        span on this thread, or ``cause`` (a span's ``id``) where the
+        work was caused elsewhere: on another thread, or earlier."""
+        return Span(self, TraceAnnotation(MIRROR_PREFIX + name), name, rid,
+                    cause, args)
+
+    def step(self, name: str, step: int) -> Span:
+        """A trainer's step span: in the profiler it is the step marker
+        (``StepTraceAnnotation``), so xprof groups the device's work by
+        step."""
+        return Span(self, StepTraceAnnotation("train", step_num=step), name,
+                    None, None, {"step": step})
+
+    def record(self, name: str, t0: float, t1: float, *,
+               rid: Optional[int] = None, cause: Optional[int] = None,
+               **args) -> int:
+        """Book an interval after the fact (a queue wait is known only
+        at admission). ``t0``/``t1`` are ``time.perf_counter()`` seconds.
+        Its parent is ``cause``, else the span open on this thread."""
+        sid = next(self._ids)
+        if cause is None:
+            stack = self._open()
+            cause = stack[-1].id if stack else None
+        self._ring.append((sid, cause, name, t0, t1, threading.get_ident(),
+                           rid, args or None))
+        return sid
+
+    def current(self) -> Optional[Span]:
+        """The innermost span open on the calling thread."""
+        stack = self._open()
+        return stack[-1] if stack else None
+
+    # ---- reading ---------------------------------------------------------
+
+    def events(self, name: Optional[str] = None,
+               t_lo: Optional[float] = None,
+               t_hi: Optional[float] = None) -> List[Record]:
+        """Finished records, oldest first; with ``name`` only that name,
+        with ``t_lo``/``t_hi`` only those that overlap the interval."""
+        while True:
+            try:
+                raw = list(self._ring)
+                break
+            except RuntimeError:  # an append raced the copy
+                continue
+        return [Record(*r) for r in raw
+                if (name is None or r[2] == name)
+                and (t_lo is None or r[4] > t_lo)
+                and (t_hi is None or r[3] < t_hi)]
+
+    def self_time(self, name: str, t_lo: Optional[float] = None,
+                  t_hi: Optional[float] = None) -> float:
+        """Seconds inside ``[t_lo, t_hi]`` that spans called ``name``
+        were open less what their child spans cover there."""
+        lo = float("-inf") if t_lo is None else t_lo
+        hi = float("inf") if t_hi is None else t_hi
+        events = self.events(t_lo=t_lo, t_hi=t_hi)
+        children: Dict[int, list] = {}
+        for e in events:
+            if e.parent_id is not None:
+                children.setdefault(e.parent_id, []).append(e)
+        total = 0.0
+        for e in events:
+            if e.name != name:
+                continue
+            a, b = max(e.t0, lo), min(e.t1, hi)
+            total += b - a
+            end = a  # the union of the children, clipped to [a, b]
+            for c in sorted(children.get(e.id, ()), key=lambda c: c.t0):
+                s, t = max(c.t0, end), min(c.t1, b)
+                if t > s:
+                    total -= t - s
+                    end = t
+        return total
 
     def chrome_trace(self) -> dict:
-        """The Chrome trace dict: metadata + every completed span."""
-        with self._lock:
-            events = list(self._events)
-        meta = [
-            {
-                "name": "process_name",
-                "ph": "M",
-                "pid": os.getpid(),
-                "args": {"name": "pytorch_distributed_tpu host"},
-            }
-        ]
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+        """The ring as a Chrome trace: one complete ("X") event a
+        record, microseconds since the oldest record, ``tid`` the
+        recording thread; ``id``, ``parent_id`` and ``rid`` in ``args``."""
+        events = self.events()
+        base = min((e.t0 for e in events), default=0.0)
+        pid = os.getpid()
+        out = [{"name": "process_name", "ph": "M", "pid": pid,
+                "args": {"name": "pytorch_distributed_tpu host"}}]
+        for e in events:
+            args = dict(e.args or {}, id=e.id)
+            if e.parent_id is not None:
+                args["parent_id"] = e.parent_id
+            if e.rid is not None:
+                args["rid"] = e.rid
+            out.append({"name": e.name, "ph": "X", "ts": (e.t0 - base) * 1e6,
+                        "dur": (e.t1 - e.t0) * 1e6, "pid": pid, "tid": e.tid,
+                        "args": args})
+        return {"traceEvents": out, "displayTimeUnit": "ms"}
 
     def save(self, path: str) -> str:
         """Write the Chrome trace JSON to ``path`` (dirs created)."""
@@ -128,18 +220,14 @@ class SpanTracer:
             json.dump(self.chrome_trace(), f)
         return path
 
-    def events(self, name: Optional[str] = None) -> List[dict]:
-        with self._lock:
-            evs = list(self._events)
-        if name is not None:
-            evs = [e for e in evs if e["name"] == name]
-        return evs
-
     def clear(self) -> None:
-        with self._lock:
-            self._events.clear()
+        """Empty the ring."""
+        self._ring.clear()
 
 
-#: Shared no-op tracer: components default to it so span call sites never
-#: need a None check.
-NULL_TRACER = SpanTracer(enabled=False)
+_TRACER = SpanTracer()
+
+
+def tracer() -> SpanTracer:
+    """The process's one recorder."""
+    return _TRACER

@@ -32,11 +32,10 @@ from pytorch_distributed_tpu.resilience.watchdog import Watchdog
 from pytorch_distributed_tpu.telemetry import (
     NULL_LEDGER,
     NULL_RECORDER,
-    NULL_TRACER,
     AnomalySentinel,
     GoodputLedger,
     ProgramTimes,
-    SpanTracer,
+    spans,
 )
 from pytorch_distributed_tpu.utils.logging import rank0_print
 
@@ -50,9 +49,9 @@ class SuspendableTrainer:
     rollbacks = 0
     # telemetry attributes; _init_resilience overrides them per config
     goodput = None
-    tracer = NULL_TRACER
     _ring = None
     _dispatched = 0
+    _evaluated = 0
     # attribution & forensics (ISSUE 8); _init_resilience overrides
     sentinel = None
     flightrec = NULL_RECORDER
@@ -72,7 +71,7 @@ class SuspendableTrainer:
         """Build the step guard and watchdog the config asks for. The
         guard exists whenever the compiled step emits ``step_good``
         (``nan_guard=True``); ``max_bad_steps=0`` means skip-only, no
-        rollback. The goodput ledger and span tracer (telemetry/) are
+        rollback. The goodput ledger (telemetry/) is
         built here too — the watchdog feeds the ledger its stall time —
         plus (ISSUE 8) the anomaly sentinel, flight recorder, and
         per-program time accumulator; the metrics JSONL is created after
@@ -81,11 +80,9 @@ class SuspendableTrainer:
 
         cfg = self.config
         self.goodput = GoodputLedger()
-        self.tracer = (
-            SpanTracer() if getattr(cfg, "trace_dir", None) else NULL_TRACER
-        )
         self._ring = None  # built lazily from the first metrics dict
         self._dispatched = 0  # run-level step-dispatch count (compile attr)
+        self._evaluated = 0  # eval-step calls (the first loads the program)
         self.prog_times = ProgramTimes()
         self._last_step_t = None
         threshold = getattr(cfg, "anomaly_threshold", 8.0)
@@ -265,7 +262,6 @@ class SuspendableTrainer:
 
         runner = WarmupRunner(
             self.program_registry(),
-            tracer=self.tracer,
             ledger=self.goodput,
             manifest=getattr(self, "metrics_log", None),
         )
@@ -334,14 +330,11 @@ class SuspendableTrainer:
         )
 
     def _save_traces(self) -> None:
-        """Write the span tracer's Chrome trace (rank 0, fit end)."""
+        """Write the process's span stream as a Chrome trace where
+        ``config.trace_dir`` says (rank 0, fit end)."""
         trace_dir = getattr(self.config, "trace_dir", None)
-        if (
-            trace_dir
-            and self.tracer.enabled
-            and jax.process_index() == 0
-        ):
-            self.tracer.save(os.path.join(trace_dir, "spans.trace.json"))
+        if trace_dir and jax.process_index() == 0:
+            spans.tracer().save(os.path.join(trace_dir, "spans.trace.json"))
 
     def _pre_step(self, host_batch):
         """Once per train step, before device dispatch: apply any
@@ -417,7 +410,7 @@ class SuspendableTrainer:
         # replay re-logs the same steps (keeps the JSONL ordered)
         self._drain_train_records(self._telemetry_flush())
         with self.goodput.timed("rollback"), \
-                self.tracer.span("rollback_replay"):
+                spans.tracer().span("train.rollback_replay"):
             self.ckpt.wait()  # commit/join any in-flight save first
             if not self.try_resume():
                 raise RuntimeError(
@@ -559,7 +552,7 @@ class SuspendableTrainer:
             return
         self.flightrec.record("ckpt_save", epoch=epoch, step=step)
         with self.goodput.timed("checkpoint"), \
-                self.tracer.span("ckpt_save", step=step):
+                spans.tracer().span("ckpt.save", step=step):
             gstep = int(np.asarray(jax.device_get(self.state.step)))
             self.ckpt.save_step_sharded(
                 self._payload_live(epoch, step + 1), gstep,
@@ -606,7 +599,7 @@ class SuspendableTrainer:
         # full-state host copy on any rank); rank 0 adds the manifest; the
         # save's internal barrier guarantees all files landed before yield.
         with self.goodput.timed("checkpoint"), \
-                self.tracer.span("ckpt_save", step=step, suspend=True):
+                spans.tracer().span("ckpt.save", step=step, suspend=True):
             self.ckpt.save_latest_sharded(
                 self._payload_live(epoch, step + 1)
             )

@@ -625,6 +625,8 @@ def make_lm_train_step(
         out_specs=(state_spec, P()),
         check_vma=False,
     )
+    # the profiler's module is jit_<__name__>: the registry's name
+    sharded.__name__ = "lm_train_step"
     return jax.jit(sharded, donate_argnums=(0,))
 
 
@@ -722,6 +724,8 @@ def make_lm_eval_step(
         out_specs=P(),
         check_vma=False,
     )
+    # the profiler's module is jit_<__name__>: the registry's name
+    sharded.__name__ = "lm_eval_step"
     return jax.jit(sharded, donate_argnums=(2,))
 
 

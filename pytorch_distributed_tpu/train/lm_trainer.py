@@ -34,10 +34,14 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from pytorch_distributed_tpu.compilecache.aot import attribute_compile
+from pytorch_distributed_tpu.compilecache.aot import (
+    attribute_compile,
+    program_load_if,
+)
 from pytorch_distributed_tpu.ops.optim import build_optimizer
 from pytorch_distributed_tpu.ops.schedules import warmup_cosine
 from pytorch_distributed_tpu.parallel import mesh as mesh_lib
+from pytorch_distributed_tpu.telemetry import spans
 from pytorch_distributed_tpu.train.base import SuspendableTrainer
 from pytorch_distributed_tpu.train.lm import (
     create_lm_state,
@@ -143,7 +147,7 @@ class LMTrainerConfig:
     # path (rank-0 gated in MetricsLogger); flush_every sizes the
     # on-device metrics ring (sync-free log path, drained lagged one
     # transfer per window; 0 = legacy blocking float() per log
-    # interval); trace_dir writes the host span Chrome trace.
+    # interval); trace_dir is where the span stream is written.
     metrics_out: Optional[str] = None
     trace_dir: Optional[str] = None
     flush_every: int = 32
@@ -189,6 +193,12 @@ class LMTrainer(SuspendableTrainer):
         mesh: Optional[jax.sharding.Mesh] = None,
         suspend_watcher: Optional[SuspendWatcher] = None,
     ):
+        with spans.tracer().span("trainer.build", trainer="lm"):
+            self._build(model_config, train_dataset, val_dataset, config,
+                        mesh, suspend_watcher)
+
+    def _build(self, model_config, train_dataset, val_dataset, config, mesh,
+               suspend_watcher) -> None:
         from pytorch_distributed_tpu.data import DataLoader, DistributedSampler
 
         self.config = config
@@ -200,24 +210,25 @@ class LMTrainer(SuspendableTrainer):
 
         n_local = mesh_lib.local_replica_count(self.mesh)
         local_batch = config.batch_size * n_local
-        self.train_sampler = DistributedSampler(
-            len(train_dataset), num_replicas=jax.process_count(),
-            rank=jax.process_index(), shuffle=True, seed=config.seed,
-        )
-        self.val_sampler = DistributedSampler(
-            len(val_dataset), num_replicas=jax.process_count(),
-            rank=jax.process_index(), shuffle=False, seed=config.seed,
-        )
-        self.train_loader = DataLoader(
-            train_dataset, batch_size=local_batch, sampler=self.train_sampler,
-            num_workers=config.num_workers, drop_last=True,
-            prefetch=config.prefetch, seed=config.seed, collate_fn=lm_collate,
-        )
-        self.val_loader = DataLoader(
-            val_dataset, batch_size=local_batch, sampler=self.val_sampler,
-            num_workers=config.num_workers, drop_last=False,
-            prefetch=config.prefetch, seed=config.seed, collate_fn=lm_collate,
-        )
+        with spans.tracer().span("loader.build"):
+            self.train_sampler = DistributedSampler(
+                len(train_dataset), num_replicas=jax.process_count(),
+                rank=jax.process_index(), shuffle=True, seed=config.seed,
+            )
+            self.val_sampler = DistributedSampler(
+                len(val_dataset), num_replicas=jax.process_count(),
+                rank=jax.process_index(), shuffle=False, seed=config.seed,
+            )
+            self.train_loader = DataLoader(
+                train_dataset, batch_size=local_batch, sampler=self.train_sampler,
+                num_workers=config.num_workers, drop_last=True,
+                prefetch=config.prefetch, seed=config.seed, collate_fn=lm_collate,
+            )
+            self.val_loader = DataLoader(
+                val_dataset, batch_size=local_batch, sampler=self.val_sampler,
+                num_workers=config.num_workers, drop_last=False,
+                prefetch=config.prefetch, seed=config.seed, collate_fn=lm_collate,
+            )
         self._local_batch = local_batch
 
         steps_per_epoch = len(self.train_loader)
@@ -299,12 +310,13 @@ class LMTrainer(SuspendableTrainer):
                     "fsdp does not compose with pipeline_stages in the "
                     "trainer (stage stacks already shard the model axis)"
                 )
-            state = create_pp_lm_state(
-                model_config, s, tx, jax.random.key(config.seed)
-            )
-            self.state, self.state_specs = shard_pp_state(
-                self.mesh, state, axis=stage_axis, config=model_config
-            )
+            with spans.tracer().span("state.init"):
+                state = create_pp_lm_state(
+                    model_config, s, tx, jax.random.key(config.seed)
+                )
+                self.state, self.state_specs = shard_pp_state(
+                    self.mesh, state, axis=stage_axis, config=model_config
+                )
             # microbatches divide the PER-DATA-SHARD batch, which is
             # config.batch_size by definition; clamp for small runs
             if config.pp_microbatches < 1:
@@ -333,12 +345,13 @@ class LMTrainer(SuspendableTrainer):
                 axis=stage_axis,
             )
         else:
-            state = create_lm_state(
-                model_config, tx, jax.random.key(config.seed)
-            )
-            self.state, self.state_specs = shard_lm_state(
-                self.mesh, state, model_config, fsdp=config.fsdp
-            )
+            with spans.tracer().span("state.init"):
+                state = create_lm_state(
+                    model_config, tx, jax.random.key(config.seed)
+                )
+                self.state, self.state_specs = shard_lm_state(
+                    self.mesh, state, model_config, fsdp=config.fsdp
+                )
             self.train_step = make_lm_train_step(
                 self.mesh, state_specs=self.state_specs, config=model_config,
                 dropout_seed=config.seed,
@@ -359,7 +372,6 @@ class LMTrainer(SuspendableTrainer):
         self.start_epoch = 0
         self.start_step = 0
         self._init_resilience()  # stepguard + watchdog + telemetry
-        self.ckpt.tracer = self.tracer  # ckpt snapshot/commit spans
         # rank-0 gating lives inside MetricsLogger now
         self.metrics_log = MetricsLogger(
             config.metrics_out
@@ -453,7 +465,7 @@ class LMTrainer(SuspendableTrainer):
         while True:
             t_wait = time.perf_counter()
             with self.goodput.timed("data_wait"), \
-                    self.tracer.span("data_wait"):
+                    spans.tracer().span("train.data_wait"):
                 pair = next(it, None)
             self._observe_data_wait(time.perf_counter() - t_wait)
             if pair is None:
@@ -469,7 +481,8 @@ class LMTrainer(SuspendableTrainer):
             # (Python lowering) so a warm start's ledger shows the cache
             # win; later recompiles are a guarded hazard, not steady state
             first = self._dispatched == 0
-            with self.tracer.span("step_dispatch", step=step), \
+            with spans.tracer().step("train.step_dispatch", step), \
+                    program_load_if(first, "lm_train_step"), \
                     attribute_compile(self.goodput if first else None), \
                     self.ledger.launch(0, "lm_train_step") as launch:
                 self.state, metrics = self.train_step(self.state, batch)
@@ -545,13 +558,15 @@ class LMTrainer(SuspendableTrainer):
                 }
             # no fence handle: the accumulator is donated into the next
             # eval call, so completion rides the t1 lower bound
-            with self.ledger.launch(0, "lm_eval_step"):
+            with program_load_if(self._evaluated == 0, "lm_eval_step"), \
+                    self.ledger.launch(0, "lm_eval_step"):
                 acc = self.eval_step(
                     self.state,
                     shard_lm_batch(self.mesh, host_batch,
                                    layout=self.model_config.ring_layout),
                     acc
                 )
+            self._evaluated += 1
         acc = jax.device_get(acc)
         tokens = float(acc["tokens"])
         if tokens == 0.0:
@@ -591,7 +606,7 @@ class LMTrainer(SuspendableTrainer):
             # overlapped this epoch's training; all ranks reach this point
             # together, so the commit barrier is safely ordered
             with self.goodput.timed("checkpoint"), \
-                    self.tracer.span("ckpt_save", commit=True):
+                    spans.tracer().span("ckpt.save", commit=True):
                 self.ckpt.wait()
             summary = self.validate()
             rank0_print(
@@ -606,7 +621,7 @@ class LMTrainer(SuspendableTrainer):
                 # every rank reaches in the same order because the psum'd
                 # ppl gives all ranks the same improvement decision
                 with self.goodput.timed("checkpoint"), \
-                        self.tracer.span("ckpt_save", best=True):
+                        spans.tracer().span("ckpt.save", best=True):
                     self.ckpt.save_best_sharded(
                         self._payload_live(epoch + 1, 0), block=False
                     )

@@ -225,6 +225,8 @@ def make_train_step(
         out_specs=(state_spec, metrics_spec),
         check_vma=False,
     )
+    # the profiler's module is jit_<__name__>: the registry's name
+    sharded.__name__ = "train_step"
     return jax.jit(sharded, donate_argnums=(0,))
 
 
@@ -269,4 +271,6 @@ def make_eval_step(
         out_specs=P(),
         check_vma=False,
     )
+    # the profiler's module is jit_<__name__>: the registry's name
+    sharded.__name__ = "eval_step"
     return jax.jit(sharded, donate_argnums=(2,))

@@ -31,12 +31,16 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from pytorch_distributed_tpu.compilecache.aot import attribute_compile
+from pytorch_distributed_tpu.compilecache.aot import (
+    attribute_compile,
+    program_load_if,
+)
 from pytorch_distributed_tpu.ops.metrics import ClassificationMetrics
 from pytorch_distributed_tpu.ops.optim import sgd_with_weight_decay
 from pytorch_distributed_tpu.ops.precision import DynamicLossScaler, NoOpLossScaler
 from pytorch_distributed_tpu.ops.schedules import step_lr
 from pytorch_distributed_tpu.parallel import mesh as mesh_lib
+from pytorch_distributed_tpu.telemetry import spans
 from pytorch_distributed_tpu.train.base import SuspendableTrainer
 from pytorch_distributed_tpu.train.state import TrainState
 from pytorch_distributed_tpu.train.step import make_eval_step, make_train_step
@@ -102,8 +106,9 @@ class TrainerConfig:
     # scalars are pushed by a donated compiled program and drained with
     # ONE lagged host transfer per window, so logging never stalls the
     # dispatch pipeline (0 = the legacy blocking float() sync, kept for
-    # bit-identity A/B); trace_dir writes the host span Chrome trace
-    # (spans.trace.json — data_wait/step_dispatch/ckpt_save/...).
+    # bit-identity A/B); trace_dir says where the process's span
+    # stream (telemetry.spans; always recorded) is written at fit end
+    # (spans.trace.json — train.data_wait/train.step_dispatch/ckpt.*).
     metrics_out: Optional[str] = None
     trace_dir: Optional[str] = None
     flush_every: int = 32
@@ -158,6 +163,12 @@ class Trainer(SuspendableTrainer):
         suspend_watcher: Optional[SuspendWatcher] = None,
         input_shape=(1, 224, 224, 3),
     ):
+        with spans.tracer().span("trainer.build", trainer="image"):
+            self._build(model, train_dataset, val_dataset, config, mesh,
+                        suspend_watcher, input_shape)
+
+    def _build(self, model, train_dataset, val_dataset, config, mesh,
+               suspend_watcher, input_shape) -> None:
         from pytorch_distributed_tpu.data import DataLoader, DistributedSampler
 
         self.config = config
@@ -171,38 +182,39 @@ class Trainer(SuspendableTrainer):
         # splits by host (D10 semantics), loader batches local_replicas × bs.
         n_local = mesh_lib.local_replica_count(self.mesh)
         local_batch = config.batch_size * n_local
-        self.train_sampler = DistributedSampler(
-            len(train_dataset),
-            num_replicas=jax.process_count(),
-            rank=jax.process_index(),
-            shuffle=True,
-            seed=config.seed,
-        )
-        self.val_sampler = DistributedSampler(
-            len(val_dataset),
-            num_replicas=jax.process_count(),
-            rank=jax.process_index(),
-            shuffle=False,
-            seed=config.seed,
-        )
-        self.train_loader = DataLoader(
-            train_dataset,
-            batch_size=local_batch,
-            sampler=self.train_sampler,
-            num_workers=config.num_workers,
-            drop_last=True,
-            prefetch=config.prefetch,
-            seed=config.seed,
-        )
-        self.val_loader = DataLoader(
-            val_dataset,
-            batch_size=local_batch,
-            sampler=self.val_sampler,
-            num_workers=config.num_workers,
-            drop_last=False,
-            prefetch=config.prefetch,
-            seed=config.seed,
-        )
+        with spans.tracer().span("loader.build"):
+            self.train_sampler = DistributedSampler(
+                len(train_dataset),
+                num_replicas=jax.process_count(),
+                rank=jax.process_index(),
+                shuffle=True,
+                seed=config.seed,
+            )
+            self.val_sampler = DistributedSampler(
+                len(val_dataset),
+                num_replicas=jax.process_count(),
+                rank=jax.process_index(),
+                shuffle=False,
+                seed=config.seed,
+            )
+            self.train_loader = DataLoader(
+                train_dataset,
+                batch_size=local_batch,
+                sampler=self.train_sampler,
+                num_workers=config.num_workers,
+                drop_last=True,
+                prefetch=config.prefetch,
+                seed=config.seed,
+            )
+            self.val_loader = DataLoader(
+                val_dataset,
+                batch_size=local_batch,
+                sampler=self.val_sampler,
+                num_workers=config.num_workers,
+                drop_last=False,
+                prefetch=config.prefetch,
+                seed=config.seed,
+            )
 
         steps_per_epoch = len(self.train_loader)
         schedule = step_lr(
@@ -219,20 +231,21 @@ class Trainer(SuspendableTrainer):
             if config.precision == "fp16"
             else NoOpLossScaler.create()
         )
-        state = TrainState.create(
-            model, tx, jax.random.key(config.seed), input_shape, scaler=scaler
-        )
-        if config.fsdp:
-            from pytorch_distributed_tpu.parallel.fsdp import shard_fsdp_state
-
-            self.state, self.state_specs = shard_fsdp_state(self.mesh, state)
-        else:
-            # Replicated placement ≙ DDP's broadcast-from-rank-0
-            # (restnet_ddp.py:99).
-            self.state = jax.device_put(
-                state, mesh_lib.replicated_sharding(self.mesh)
+        with spans.tracer().span("state.init"):
+            state = TrainState.create(
+                model, tx, jax.random.key(config.seed), input_shape, scaler=scaler
             )
-            self.state_specs = None
+            if config.fsdp:
+                from pytorch_distributed_tpu.parallel.fsdp import shard_fsdp_state
+
+                self.state, self.state_specs = shard_fsdp_state(self.mesh, state)
+            else:
+                # Replicated placement ≙ DDP's broadcast-from-rank-0
+                # (restnet_ddp.py:99).
+                self.state = jax.device_put(
+                    state, mesh_lib.replicated_sharding(self.mesh)
+                )
+                self.state_specs = None
 
         self.train_step = make_train_step(
             self.mesh,
@@ -251,7 +264,6 @@ class Trainer(SuspendableTrainer):
         self.start_epoch = 0
         self.start_step = 0
         self._init_resilience()  # stepguard + watchdog + telemetry
-        self.ckpt.tracer = self.tracer  # ckpt snapshot/commit spans
 
         # Observability (SURVEY.md §5: the reference has only time.time()
         # prints; we keep those AND stream machine-readable metrics).
@@ -373,7 +385,7 @@ class Trainer(SuspendableTrainer):
         while True:
             t_wait = time.perf_counter()
             with self.goodput.timed("data_wait"), \
-                    self.tracer.span("data_wait"):
+                    spans.tracer().span("train.data_wait"):
                 pair = next(it, None)
             self._observe_data_wait(time.perf_counter() - t_wait)
             if pair is None:
@@ -386,7 +398,8 @@ class Trainer(SuspendableTrainer):
             # (Python lowering) so a warm start's ledger shows the cache
             # win; later recompiles are a guarded hazard, not steady state
             first = self._dispatched == 0
-            with self.tracer.span("step_dispatch", step=step), \
+            with spans.tracer().step("train.step_dispatch", step), \
+                    program_load_if(first, "train_step"), \
                     attribute_compile(self.goodput if first else None), \
                     self.ledger.launch(0, "train_step") as launch:
                 self.state, metrics = self.train_step(self.state, batch)
@@ -458,8 +471,10 @@ class Trainer(SuspendableTrainer):
             batch = mesh_lib.shard_batch(self.mesh, host_batch)
             # no fence handle: the accumulator is donated into the next
             # eval call, so completion rides the t1 lower bound
-            with self.ledger.launch(0, "eval_step"):
+            with program_load_if(self._evaluated == 0, "eval_step"), \
+                    self.ledger.launch(0, "eval_step"):
                 metrics = self.eval_step(self.state, batch, metrics)
+            self._evaluated += 1
         return jax.device_get(metrics).summary()
 
     def fit(self) -> dict:
@@ -501,7 +516,7 @@ class Trainer(SuspendableTrainer):
             # overlapped this epoch's training; all ranks reach this point
             # together, so the commit barrier is safely ordered
             with self.goodput.timed("checkpoint"), \
-                    self.tracer.span("ckpt_save", commit=True):
+                    spans.tracer().span("ckpt.save", commit=True):
                 self.ckpt.wait()
             summary = self.validate()
             rank0_print(
@@ -516,7 +531,7 @@ class Trainer(SuspendableTrainer):
                 # every rank reaches in the same order because the psum'd
                 # acc gives all ranks the same improvement decision
                 with self.goodput.timed("checkpoint"), \
-                        self.tracer.span("ckpt_save", best=True):
+                        spans.tracer().span("ckpt.save", best=True):
                     self.ckpt.save_best_sharded(
                         self._payload_live(epoch + 1, 0), block=False
                     )

@@ -34,6 +34,7 @@ from flax import serialization
 
 from pytorch_distributed_tpu.resilience.faults import fault_point
 from pytorch_distributed_tpu.resilience.retry import retry_call
+from pytorch_distributed_tpu.telemetry import spans
 
 LATEST = "latest.ckpt"
 BEST = "best.ckpt"
@@ -940,18 +941,12 @@ class Checkpointer:
     """
 
     def __init__(self, save_dir: str | os.PathLike):
-        from pytorch_distributed_tpu.telemetry import NULL_TRACER
-
         self.save_dir = os.fspath(save_dir)
         self._thread: Optional[threading.Thread] = None
         self._pending: Optional[_ShardedSave] = None
         self._arena = _Arena()  # snapshot pages reused across saves
         self._warm_thread: Optional[threading.Thread] = None
         self._step_keep: Optional[int] = None  # GC request, runs at wait()
-        # span hook (telemetry/spans.py): trainers point this at their
-        # tracer so snapshot/commit phases show up in the Chrome trace
-        # next to data_wait/step_dispatch; default no-op
-        self.tracer = NULL_TRACER
 
     def _path(self, name: str) -> str:
         return os.path.join(self.save_dir, name)
@@ -1002,9 +997,15 @@ class Checkpointer:
         # scalars the caller doesn't pass here — leave aligned headroom so
         # ensure() never discards the pre-faulted map over a few leaves
         nbytes += 64 * 1024
-        self._warm_thread = threading.Thread(
-            target=self._arena.warm, args=(nbytes,), daemon=True
-        )
+        tr = spans.tracer()
+        here = tr.current()  # the build that asked: the span's cause
+
+        def warm() -> None:
+            with tr.span("ckpt.warm_for", cause=here.id if here else None,
+                         bytes=nbytes):
+                self._arena.warm(nbytes)
+
+        self._warm_thread = threading.Thread(target=warm, daemon=True)
         self._warm_thread.start()
 
     def has_latest(self) -> bool:
@@ -1034,13 +1035,13 @@ class Checkpointer:
         if block:
             # blocking: stream from the live buffers — no snapshot copy,
             # no arena (the caller waits, so donation can't race)
-            with self.tracer.span("ckpt_write", blocking=True):
+            with spans.tracer().span("ckpt.write", blocking=True):
                 s = _ShardedSave(path, payload, snapshot=False)
                 s.write()
                 s.finalize()
         else:
             # snapshot only (fast: bulk copy into the reused arena)
-            with self.tracer.span("ckpt_snapshot"):
+            with spans.tracer().span("ckpt.snapshot"):
                 s = _ShardedSave(path, payload, arena=self._arena)
             s.start()  # file write on a thread
             self._pending = s  # commit deferred to wait()
@@ -1227,11 +1228,11 @@ class Checkpointer:
             self._warm_thread.join()  # never race a save into the arena
             self._warm_thread = None
         if self._thread is not None:
-            with self.tracer.span("ckpt_commit_wait"):
+            with spans.tracer().span("ckpt.commit_wait"):
                 self._thread.join()
             self._thread = None
         if self._pending is not None:
             pending, self._pending = self._pending, None
-            with self.tracer.span("ckpt_commit"):
+            with spans.tracer().span("ckpt.commit"):
                 pending.finalize()
         self._gc_steps()  # retention only after the new manifest landed

@@ -95,11 +95,12 @@ def _base_parser(description: str, save_dir: str,
                         "with scripts/telemetry_report.py — train series, "
                         "epoch timing, and the run's goodput breakdown")
     p.add_argument("--trace-dir", default=None,
-                   help="write the host span Chrome trace (data_wait/"
-                        "step_dispatch/ckpt_save/...) to "
-                        "<dir>/spans.trace.json; spans also mirror into "
-                        "jax.profiler annotations when PDT_TRACE_DIR "
-                        "captures an xprof trace")
+                   help="where the span stream (always recorded; "
+                        "trainer.build/program.load/train.data_wait/"
+                        "train.step_dispatch/ckpt.*) is written at fit "
+                        "end: <dir>/spans.trace.json. Under a profiler "
+                        "session (PDT_TRACE_DIR) the same spans are "
+                        "pdt:<name> annotations in the xprof trace")
     p.add_argument("--flush-every", type=int, default=32,
                    help="device metrics ring window: log-interval metric "
                         "scalars accumulate on device and drain with one "

@@ -20,8 +20,9 @@ Telemetry (round 7; ANALYSIS.md "Observability & goodput"):
 retirement (queue wait, TTFT, inter-token gaps) plus a final
 ``kind="serving_summary"`` with the scheduler's percentile metrics —
 feed it to ``scripts/telemetry_report.py`` for TTFT/per-token p50/p95;
-``--trace-dir DIR`` writes the host span Chrome trace
-(admission/prefill_chunk/decode_tick) to ``DIR/spans.trace.json``.
+``--trace-dir DIR`` says where the process's span stream (always
+recorded: ``telemetry.spans``) is written at exit, as a Chrome trace,
+``DIR/spans.trace.json``.
 
 Elastic load (round 9; ANALYSIS.md "Elastic topology & reshard"):
 ``--restore CKPT`` serves a TRAINER checkpoint — sharded directory or
@@ -191,9 +192,9 @@ def _parse(argv=None) -> argparse.Namespace:
                         "records + a serving_summary (read with "
                         "scripts/telemetry_report.py)")
     p.add_argument("--trace-dir", default=None,
-                   help="write the host span Chrome trace "
-                        "(admission/prefill_chunk/decode_tick) to "
-                        "<dir>/spans.trace.json")
+                   help="where the span stream (always recorded; "
+                        "router.*/sched.*/engine.*/program.load/req.queue) "
+                        "is written at exit: <dir>/spans.trace.json")
     # Compile cache (compilecache/; ANALYSIS.md "Cold start & compile
     # cache"). Example — prewarm once, then every server start is warm:
     #   python scripts/warmup.py --tiny --compile-cache-dir /tmp/cc
@@ -328,13 +329,11 @@ def main() -> None:
     prompts = _prompts(args, cfg)
     from pytorch_distributed_tpu.telemetry import (
         NULL_REQTRACER,
-        NULL_TRACER,
         ReqTracer,
-        SpanTracer,
+        spans,
     )
     from pytorch_distributed_tpu.utils.profiling import MetricsLogger
 
-    tracer = SpanTracer() if args.trace_dir else NULL_TRACER
     mlog = MetricsLogger(args.metrics_out)
     # request-lifecycle tracing (round 14): whenever the JSONL stream is
     # on, every request's causal span tree rides along as kind="span"
@@ -385,7 +384,7 @@ def main() -> None:
             if args.disaggregate else args.replicas,
             disaggregate=args.disaggregate,
             n_prefill=args.prefill_replicas, slo=slo, seed=args.seed,
-            metrics_log=mlog, tracer=tracer, reqtrace=reqtrace,
+            metrics_log=mlog, reqtrace=reqtrace,
             # the front door streams: async host loop, results dropped
             # at retire (the connection consumed them token by token)
             async_host=args.async_host or http_mode,
@@ -454,7 +453,8 @@ def main() -> None:
         if args.trace_dir:
             import os
 
-            tracer.save(os.path.join(args.trace_dir, "spans.trace.json"))
+            spans.tracer().save(
+                os.path.join(args.trace_dir, "spans.trace.json"))
         rank0_print(json.dumps(metrics, indent=2))
         return
     if args.dense:
@@ -492,7 +492,7 @@ def main() -> None:
             cfg, params, n_slots=args.slots, block_len=args.block_len,
             prefill_chunk=args.prefill_chunk, n_blocks=args.n_blocks,
             admit_per_step=args.admit_per_step, seed=args.seed,
-            mesh=mesh, tracer=tracer, metrics_log=mlog,
+            mesh=mesh, metrics_log=mlog,
             reqtrace=reqtrace,
             gather_impl=args.gather_impl, kv_dtype=args.kv_dtype,
             offload=args.preempt, preempt_on_oom=args.preempt,
@@ -531,7 +531,8 @@ def main() -> None:
     if args.trace_dir:
         import os
 
-        tracer.save(os.path.join(args.trace_dir, "spans.trace.json"))
+        spans.tracer().save(
+            os.path.join(args.trace_dir, "spans.trace.json"))
     rank0_print(json.dumps(metrics, indent=2))
 
 

@@ -32,12 +32,12 @@ from pytorch_distributed_tpu.telemetry import (
     AnomalySentinel,
     MetricsExporter,
     ReqTracer,
-    SpanTracer,
     build_tree,
     chrome_trace,
     validate_stream,
     validate_trace,
 )
+from pytorch_distributed_tpu.telemetry import spans as span_stream
 from pytorch_distributed_tpu.telemetry.reqtrace import span_records
 from pytorch_distributed_tpu.utils.profiling import MetricsLogger
 
@@ -431,7 +431,8 @@ def test_schema_registry_flags_drift():
 
 
 def test_spantracer_per_thread_stacks_do_not_interleave():
-    tracer = SpanTracer(mirror_jax=False)
+    tracer = span_stream.tracer()
+    tracer.clear()
     barrier = threading.Barrier(2)
     errors = []
 
@@ -439,12 +440,10 @@ def test_spantracer_per_thread_stacks_do_not_interleave():
         try:
             with tracer.span(f"outer_{name}"):
                 barrier.wait(timeout=5)  # both outers open concurrently
-                assert tracer.stack() == [f"outer_{name}"]
+                assert tracer.current().name == f"outer_{name}"
                 with tracer.span(f"inner_{name}"):
                     barrier.wait(timeout=5)
-                    assert tracer.stack() == [
-                        f"outer_{name}", f"inner_{name}"
-                    ]
+                    assert tracer.current().name == f"inner_{name}"
         except Exception as e:  # surfaced below; a thread must not die mute
             errors.append(e)
 
@@ -455,16 +454,14 @@ def test_spantracer_per_thread_stacks_do_not_interleave():
     for t in threads:
         t.join()
     assert errors == []
-    assert tracer.stack() == []  # main thread never opened a span
-    events = {e["name"]: e for e in tracer.events()}
+    assert tracer.current() is None  # main thread never opened a span
+    events = {e.name: e for e in tracer.events()}
     assert len(events) == 4
     for name in ("a", "b"):
-        inner = events[f"inner_{name}"]
+        inner, outer = events[f"inner_{name}"], events[f"outer_{name}"]
         # each inner's parent comes from ITS OWN thread's stack
-        assert inner["args"]["parent"] == f"outer_{name}"
-        assert inner["args"]["depth"] == 1
-        assert "args" not in events[f"outer_{name}"] or \
-            "parent" not in events[f"outer_{name}"].get("args", {})
+        assert inner.parent_id == outer.id and inner.tid == outer.tid
+        assert outer.parent_id is None
 
 
 def test_rules_threads_passes_telemetry_modules_clean():

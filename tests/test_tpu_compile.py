@@ -138,9 +138,67 @@ CASES = {
 }
 
 
+#: the kernels' ``name=``, as each case's compiled program must show them
+#: (autodiff wraps a name: ``jvp_flash_fwd_``, ``transpose_jvp_flash_bwd_
+#: fused__``), so that a device trace says which kernel an operation is
+KERNELS = {
+    "flash_fwd": ("flash_fwd",),
+    "flash_fwd_bwd": ("flash_fwd", "flash_bwd_fused"),
+    "ring_flash_fwd_bwd_seq4": ("flash_fwd", "flash_bwd_fused"),
+    "paged_decode_bf16": ("paged_decode_attn",),
+    "paged_chunk_bf16": ("paged_decode_attn",),
+    "paged_decode_int8": ("paged_decode_attn",),
+    "paged_decode_fp8": ("paged_decode_attn",),
+    "paged_decode_split2": ("paged_decode_attn",),
+    "quantize_scatter_int8": ("paged_kv_write",),
+    "quantize_scatter_fp8": ("paged_kv_write",),
+}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_v5e(v5e, case):
     text = CASES[case](v5e).compile().as_text()
     assert "tpu_custom_call" in text, f"{case}: no Mosaic kernel in program"
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for kernel in KERNELS[case]:
+        assert any(kernel in c for c in calls), (case, kernel, calls)
     if case.startswith("ring_flash"):
         assert "collective-permute" in text
+
+
+def test_split_backward_kernels_are_named(v5e):
+    """The two-kernel backward (``bwd_impl="split"``) names both."""
+    x = jax.ShapeDtypeStruct((4, SEQ, H, D), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e.devices[0]))
+    fn = functools.partial(flash_attention, causal=True, interpret=False,
+                           bwd_impl="split")
+    text = jax.jit(_loss_grad(fn)).lower(x, x, x).compile().as_text()
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+
+
+def test_decode_tick_module_is_named(v5e):
+    """The profiler calls a program ``jit_<function name>``: the paged
+    engine's decode tick compiles for the chip as ``jit_decode_tick``."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerLM,
+        tiny_config,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+
+    cfg = tiny_config(attention="dense", max_seq_len=64)
+    params = TransformerLM(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    eng = PagedEngine(cfg, params, 4, block_len=8, prefill_chunk=8)
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    args = jax.tree.map(on_chip, (
+        eng.params, eng.cache, eng.logits, jnp.zeros((4,), jnp.int32),
+        jnp.zeros((4,), bool), jnp.zeros((4, eng.table_width), jnp.int32),
+        jax.random.key(0)))
+    lowered = eng._decode().lower(*args)
+    assert lowered.as_text().startswith("module @jit_decode_tick ")
+    assert lowered.compile().as_text().startswith("HloModule jit_decode_tick,")
