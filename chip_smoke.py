@@ -21,7 +21,12 @@ recipes have it, weights random from a seed):
   token against an in-process ``Scheduler``, then the same prompts
   through the ``gather_impl="pallas"`` kernel, which must agree with the
   dense spelling on every first token and on 90% of tokens decoded from
-  the same context.
+  the same context;
+- ``pool``: the server's K/V pool alone. What a bf16, an int8 and an fp8
+  pool take of the device's memory beside their logical size (a leaf the chip
+  pads or keeps in another layout shows here), and the fused gather
+  against the dense one on random bf16, int8 and fp8 pools, decode and
+  chunk rows: they must agree to one bf16 ulp.
 
 ``--multichip`` runs the paths that exist only across chips, each beside
 what it is compared with: data-parallel ResNet against one device,
@@ -369,6 +374,88 @@ def server_phase() -> None:
                     "kernel ran interpreted or not at all")
 
 
+def pool_phase() -> None:
+    """The K/V pool by itself, at the server's geometry and capacity."""
+    import gc
+
+    import jax.numpy as jnp
+
+    from pytorch_distributed_tpu.ops.attention import paged_attention
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    with phase("pool") as rec:
+        cfg, params, kw = serve_setup()
+        bl, slots = kw["block_len"], kw["n_slots"]
+        width = cfg.max_seq_len // bl
+        n_blocks = slots * width + 1
+        head_dim = cfg.embed_dim // cfg.num_heads
+        device = jax.devices()[0]
+        rec.update(n_blocks=n_blocks, block_len=bl, heads=cfg.num_heads,
+                   head_dim=head_dim, bytes={}, pallas_max_abs_diff={})
+
+        def in_use():
+            gc.collect()
+            stats = device.memory_stats()  # None on the CPU backend
+            return stats["bytes_in_use"] if stats else None
+
+        def fill(name, z):
+            """Random contents for one leaf: values near N(0, 1) once
+            dequantized."""
+            if not name.endswith("_scale"):
+                top = 127 if z.dtype == jnp.int8 else 1
+                x = rng.normal(size=z.shape) * top / 4
+                return jnp.asarray(x.clip(-top, top)).astype(z.dtype)
+            if z.dtype == jnp.int8:  # fp8: power-of-two exponents
+                return jnp.asarray(rng.integers(1, 3, z.shape), z.dtype)
+            return jnp.asarray(rng.uniform(0.02, 0.04, z.shape), z.dtype)
+
+        rng = np.random.default_rng(ARGS.seed)
+        shapes = {"decode": (slots, 1), "chunk": (4, kw["prefill_chunk"])}
+        for kv_dtype in (None, "int8", "fp8"):
+            name = kv_dtype or jnp.dtype(cfg.dtype).name
+            before = in_use()
+            cache = jax.block_until_ready(
+                init_paged_cache(cfg, params, n_blocks, bl, kv_dtype))
+            held = in_use()
+            logical = sum(x.size * x.dtype.itemsize
+                          for x in jax.tree.leaves(cache))
+            rec["bytes"][name] = {"logical": logical}
+            if held is not None:
+                ratio = (held - before) / logical
+                rec["bytes"][name].update(device=held - before,
+                                          ratio=round(ratio, 4))
+                require(rec, ratio <= 1.01, f"a {name} pool of {logical} "
+                        f"bytes takes {held - before} of the device")
+
+            # the two gather spellings on one layer of it, random contents
+            layer = {k: fill(k, z) for k, z in cache["block0"]["attn"].items()}
+            del cache
+            scales = ({} if kv_dtype is None else
+                      dict(k_scale=layer["key_scale"],
+                           v_scale=layer["value_scale"]))
+            for what, (b, c) in shapes.items():
+                q = jnp.asarray(
+                    rng.normal(size=(b, c, cfg.num_heads, head_dim)),
+                    cfg.dtype)
+                tables = jnp.asarray(
+                    rng.permutation(np.arange(1, n_blocks))[:b * width]
+                    .reshape(b, width).astype(np.int32))
+                start = rng.integers(0, width * bl - c, (b, 1))
+                pos = jnp.asarray((start + np.arange(c)).astype(np.int32))
+                dense, fused = (
+                    np.asarray(paged_attention(
+                        q, layer["key"], layer["value"], tables, pos,
+                        gather_impl=impl, **scales), np.float32)
+                    for impl in ("dense", "pallas"))
+                diff = float(np.abs(dense - fused).max())
+                # bf16 keeps 8 bits: one ulp of the largest output
+                ulp = 2.0 ** (math.floor(math.log2(np.abs(dense).max())) - 7)
+                key = f"{kv_dtype or 'raw'}_{what}"
+                rec["pallas_max_abs_diff"][key] = diff
+                require(rec, diff <= ulp, f"{key}: pallas and dense differ "
+                        f"by {diff}, over one bf16 ulp ({ulp})")
+
+
 # ---- four chips ------------------------------------------------------------
 
 
@@ -547,6 +634,7 @@ def main() -> None:
             resnet_phase(workdir)
             lm_phase(workdir)
             server_phase()
+            pool_phase()
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     hits, compile_s = process_compile_totals()

@@ -662,7 +662,7 @@ class ContinuousBatcher:
     @property
     def cache(self):
         """The KV cache pytree: the block POOL under the paged layout
-        (leaves ``[n_blocks, block_len, H_kv, D]``), per-slot dense rows
+        (leaves ``[n_blocks, block_len, H_kv·D]``), per-slot dense rows
         (``[n_slots, max_seq_len, H_kv, D]``) under the dense one."""
         return self.engine.cache if self.engine is not None else self._cache
 

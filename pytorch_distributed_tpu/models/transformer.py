@@ -298,7 +298,9 @@ class Attention(nn.Module):
 
         if block_tables is not None:
             # Paged serving (serving/): the cache is a block POOL
-            # [n_blocks, block_len, H_kv, D] shared by every request, and
+            # [n_blocks, block_len, H_kv*D] shared by every request (a
+            # row is a position's heads side by side: kv_pool.
+            # pool_leaf_shape — the leaf the chip keeps row-major), and
             # this request's logical positions map to pool blocks through
             # its block-table row. One path serves BOTH chunked prefill
             # (l == chunk) and decode (l == 1): write the chunk at its
@@ -355,10 +357,11 @@ class Attention(nn.Module):
                 # also reads quantized KV — the same values every later
                 # chunk and decode tick will see, so the stream has ONE
                 # consistent quantization, not an exact-then-quantized
-                # seam. With gather_impl="pallas" the scatter fuses too
-                # (ops.paged_flash.paged_quantize_scatter computes the
-                # scales inside the write); the jnp spelling below is
-                # the dense/interpret reference — both call
+                # seam. With gather_impl="pallas" the quantization is
+                # one kernel (ops.paged_flash.paged_quantize_scatter:
+                # rows and scales together, placed by the same in-place
+                # .at[].set); the jnp spelling below is the dense/
+                # interpret reference — both call
                 # kv_pool.quantize_rows, so the pools are bit-identical
                 # across spellings.
                 cks = self.variable("cache", "key_scale", _need_pool)
@@ -382,10 +385,10 @@ class Attention(nn.Module):
                     vq, vs_rows = quantize_kv(v, cv.value.dtype)
                     rows = (blk.reshape(-1), off.reshape(-1))
                     ck.value = ck.value.at[rows].set(
-                        kq.reshape(b * l, kv_heads, head_dim)
+                        kq.reshape(b * l, kv_heads * head_dim)
                     )
                     cv.value = cv.value.at[rows].set(
-                        vq.reshape(b * l, kv_heads, head_dim)
+                        vq.reshape(b * l, kv_heads * head_dim)
                     )
                     cks.value = cks.value.at[rows].set(
                         ks_rows.reshape(b * l, kv_heads)
@@ -400,10 +403,10 @@ class Attention(nn.Module):
                 )
             else:
                 ck.value = ck.value.at[blk.reshape(-1), off.reshape(-1)].set(
-                    k.astype(cfg.dtype).reshape(b * l, kv_heads, head_dim)
+                    k.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim)
                 )
                 cv.value = cv.value.at[blk.reshape(-1), off.reshape(-1)].set(
-                    v.astype(cfg.dtype).reshape(b * l, kv_heads, head_dim)
+                    v.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim)
                 )
                 out = paged_attention(
                     q, ck.value, cv.value, block_tables, p,

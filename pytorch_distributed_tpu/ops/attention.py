@@ -149,6 +149,23 @@ def dense_attention(
     ).astype(q.dtype)
 
 
+def pool_heads(k_pool: jax.Array, h: int, d: int):
+    """``(block_len, H_kv)`` of a ``[n_blocks, block_len, H_kv·D]`` pool
+    leaf read with ``h`` query heads of size ``d`` — the one place both
+    gather spellings check a pool against their queries."""
+    if k_pool.ndim != 3 or k_pool.shape[2] % d:
+        raise ValueError(
+            f"pool leaf {k_pool.shape} is not [n_blocks, block_len, "
+            f"H_kv*{d}] (serving.kv_pool.pool_leaf_shape)"
+        )
+    block_len, h_kv = k_pool.shape[1], k_pool.shape[2] // d
+    if h % h_kv:
+        raise ValueError(
+            f"query heads {h} not a multiple of pool KV heads {h_kv}"
+        )
+    return block_len, h_kv
+
+
 def paged_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -175,7 +192,12 @@ def paged_attention(
       q: ``[B, C, H, D]`` queries — C == 1 for a decode tick, C == chunk
         length for chunked prefill (both use this one op, so the two can
         never diverge on masking).
-      k_pool, v_pool: ``[n_blocks, block_len, H_kv, D]`` pooled cache.
+      k_pool, v_pool: ``[n_blocks, block_len, H_kv·D]`` pooled cache
+        (``serving.kv_pool.pool_leaf_shape``: narrow head ``h`` at lanes
+        ``[h·D, (h+1)·D)`` of a row; ``H_kv`` is the last axis over
+        ``q``'s ``D``). Nothing here reshapes a pool: only the GATHERED
+        rows are split into heads, so the leaf stays row-major on the
+        chip and no pool-sized copy enters the program.
         ``H_kv < H`` is the GQA layout; query head h reads narrow head
         ``h // (H // H_kv)`` via a grouped einsum — the widened K/V never
         materializes (same trick as the dense decode path).
@@ -243,15 +265,12 @@ def paged_attention(
             k_scale=k_scale, v_scale=v_scale, split_s=split_s,
         )
     b, c, h, d = q.shape
-    n_blocks, block_len, h_kv, _ = k_pool.shape
-    if h % h_kv:
-        raise ValueError(
-            f"query heads {h} not a multiple of pool KV heads {h_kv}"
-        )
+    block_len, h_kv = pool_heads(k_pool, h, d)
     group = h // h_kv
     w = block_tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
-    # Gather the per-request logical KV sequences: [B, W*block_len, H_kv, D].
+    # Gather the per-request logical KV sequences, then split the
+    # GATHERED rows into heads: [B, W*block_len, H_kv, D].
     kg = jnp.take(k_pool, block_tables, axis=0).reshape(
         b, w * block_len, h_kv, d
     )

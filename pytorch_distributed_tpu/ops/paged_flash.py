@@ -23,9 +23,10 @@ Structure (per the in-tree FlashAttention kernel,
 - every block's last two dims equal its array's, the one block shape
   Mosaic's tiling rule accepts at H_kv=12, D=64 (the interpreter does
   not check it — every shape was refused on the chip until PR 21): a
-  staged K/V block is the whole ``[block_len, H_kv, D]`` pool block and
-  a static loop over narrow heads slices ``k_ref[0, :, h, :]``; scale
-  blocks are the block's whole ``[block_len, H_kv]`` sibling; positions
+  staged K/V block is the whole ``[block_len, H_kv·D]`` pool block and
+  a static loop over narrow heads takes each head's D lanes,
+  ``k_ref[0, :, h·D:(h+1)·D]``; scale blocks are the block's whole
+  ``[block_len, H_kv]`` sibling; positions
   ride as a ``[B, r_pad, 1]`` column and each row's query frontier as a
   second scalar-prefetch operand;
 - GQA is folded into the row dimension: queries regroup to
@@ -56,20 +57,22 @@ Structure (per the in-tree FlashAttention kernel,
   of serializing on the innermost grid axis. ``split_s=None``
   auto-enables via ``auto_split_s`` when W/B crosses the threshold;
   ``pl.when`` frontier skipping applies per worker unchanged;
-- the write side has a fused twin: ``paged_quantize_scatter`` computes
-  per-row-per-head scales and writes the quantized rows inside the
-  scatter (``input_output_aliases`` keeps unvisited pool blocks in
-  place), sharing ``serving.kv_pool.quantize_rows`` with the jnp
-  spelling, so in the interpreter the two are bit-equivalent by
-  construction (on the v5e: int8 and fp8 e4m3 bit-equal, e5m2 not —
-  CHANGES.md PR 21);
+- the write side has a twin: ``paged_quantize_scatter`` computes
+  per-row-per-head scales and the quantized rows in one kernel and
+  places them with the in-place ``.at[rows].set`` the raw pools use (a
+  single row of a row-major leaf is not a block Mosaic accepts),
+  sharing ``serving.kv_pool.quantize_rows`` with the jnp spelling, so
+  in the interpreter the two are bit-equivalent by construction (on
+  the v5e: int8 and fp8 e4m3 bit-equal, e5m2 not — CHANGES.md PR 21);
 - ``interpret=None`` auto-detects non-TPU backends and runs the Pallas
   interpreter, so CPU tier-1 executes the same call sites unmodified
   (the ``flash_attention`` convention).
 
 Shapes follow the framework convention: q ``[B, C, H, D]``, pools
-``[n_blocks, block_len, H_kv, D]``, tables ``[B, W]``, positions
-``[B, C]``.
+``[n_blocks, block_len, H_kv·D]`` (``serving.kv_pool.pool_leaf_shape``:
+the leaf the chip keeps row-major, so a pool block is one contiguous
+DMA and no copy of the pool enters the program), tables ``[B, W]``,
+positions ``[B, C]``.
 """
 # jaxlint: disable-file=precision-cast -- the kernel's softmax state (m, l, acc) is fp32 by the attention-path contract and int8 pool blocks dequantize to fp32 in VMEM; every cast here feeds that fp32 recurrence
 
@@ -83,7 +86,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pytorch_distributed_tpu.ops.attention import NEG_INF
+from pytorch_distributed_tpu.ops.attention import NEG_INF, pool_heads
 
 #: flash-decoding auto policy (``split_s=None``): split when one batch
 #: row's chain is at least this many blocks per batch row — the shape
@@ -108,15 +111,14 @@ def auto_split_s(w: int, b: int, *, threshold: int = SPLIT_THRESHOLD,
 
 
 def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
-                  m_scr, l_scr, acc_scr, *, scale, k_start, h_kv,
+                  m_scr, l_scr, acc_scr, *, scale, k_start, h_kv, d,
                   quantized, fp8_scales):
     """One chain block's online-softmax update for every narrow head —
     the shared inner body of the single-worker and split-S kernels (one
     spelling, so the split path cannot drift from the sweep it
     partitions). The staged K/V block spans all of ``H_kv`` (the only
-    pool block shape Mosaic's tiling rule accepts without changing the
-    pool layout); the static head loop reads each head's
-    ``[block_len, D]`` slice out of it."""
+    pool block shape Mosaic's tiling rule accepts); the static head
+    loop reads each head's ``[block_len, D]`` lanes out of it."""
     if quantized:
         # dequantize THIS block only, in VMEM: per-(slot, head) scale
         # siblings gathered by the same table-driven index map. fp8
@@ -132,8 +134,8 @@ def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
         # kernel's trick), fp32 logits on the MXU.
         q = q_ref[0, h]  # [R, D]
         q = q * jnp.asarray(scale, q.dtype)
-        k = k_ref[0, :, h, :]  # [block_len, D]
-        v = v_ref[0, :, h, :]
+        k = k_ref[0, :, h * d:(h + 1) * d]  # [block_len, D]
+        v = v_ref[0, :, h * d:(h + 1) * d]
         if quantized:
             k = k.astype(jnp.float32) * ks_all[:, h:h + 1]
             v = v.astype(jnp.float32) * vs_all[:, h:h + 1]
@@ -171,7 +173,7 @@ def _paged_kernel(
     front_ref,  # scalar-prefetch [B] int32: each row's query frontier
     q_ref, qpos_ref, k_ref, v_ref,  # + (ks_ref, vs_ref) when quantized
     *refs,
-    scale: float, block_len: int, h_kv: int, quantized: bool,
+    scale: float, block_len: int, h_kv: int, d: int, quantized: bool,
     fp8_scales: bool, w: int, wc: int, split: bool,
 ):
     """Grid ``(B, S, ceil(W/S))``: worker s sweeps chain blocks
@@ -202,7 +204,7 @@ def _paged_kernel(
     def _block():
         _attend_block(q_ref, qpos_ref[0], k_ref, v_ref, ks_ref, vs_ref,
                       m_scr, l_scr, acc_scr, scale=scale, k_start=k_start,
-                      h_kv=h_kv, quantized=quantized,
+                      h_kv=h_kv, d=d, quantized=quantized,
                       fp8_scales=fp8_scales)
 
     @pl.when(jj == wc - 1)
@@ -237,9 +239,9 @@ def paged_flash_attention(
     Args:
       q: ``[B, C, H, D]`` — C == 1 for a decode tick, C == chunk for
         chunked prefill.
-      k_pool, v_pool: ``[n_blocks, block_len, H_kv, D]`` pooled cache
-        (``H_kv <= H``, GQA); float dtypes, or int8 with ``k_scale``/
-        ``v_scale`` set.
+      k_pool, v_pool: ``[n_blocks, block_len, H_kv·D]`` pooled cache
+        (``H_kv <= H``, GQA); float dtypes, or int8/fp8 with
+        ``k_scale``/``v_scale`` set.
       block_tables: ``[B, W]`` int32 — request b's logical positions
         ``[w·block_len, (w+1)·block_len)`` live in pool block
         ``block_tables[b, w]``.
@@ -264,11 +266,7 @@ def paged_flash_attention(
     from pytorch_distributed_tpu.serving.kv_pool import is_quantized_pool
 
     b, c, h, d = q.shape
-    n_blocks, block_len, h_kv, _ = k_pool.shape
-    if h % h_kv:
-        raise ValueError(
-            f"query heads {h} not a multiple of pool KV heads {h_kv}"
-        )
+    block_len, h_kv = pool_heads(k_pool, h, d)
     quantized = is_quantized_pool(k_pool.dtype)
     if quantized != (k_scale is not None):
         raise ValueError(
@@ -326,8 +324,8 @@ def paged_flash_attention(
 
     row_spec = pl.BlockSpec((1, h_kv, r_pad, d),
                             lambda b, s, j, *_: (b, 0, 0, 0))
-    pool_spec = pl.BlockSpec((1, block_len, h_kv, d),
-                             lambda *a: (pool_block(*a), 0, 0, 0))
+    pool_spec = pl.BlockSpec((1, block_len, h_kv * d),
+                             lambda *a: (pool_block(*a), 0, 0))
     in_specs = [
         row_spec,
         pl.BlockSpec((1, r_pad, 1), lambda b, s, j, *_: (b, 0, 0)),
@@ -358,8 +356,8 @@ def paged_flash_attention(
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, scale=scale, block_len=block_len, h_kv=h_kv,
-            quantized=bool(quantized), fp8_scales=fp8_scales, w=w, wc=wc,
-            split=split,
+            d=d, quantized=bool(quantized), fp8_scales=fp8_scales, w=w,
+            wc=wc, split=split,
         ),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -413,31 +411,29 @@ def paged_quantize_scatter(
     *,
     interpret: bool | None = None,
 ):
-    """Fused quantize-on-scatter: write a chunk's KV rows into a
-    quantized pool, computing each row's per-head scale and casting to
-    the pool dtype INSIDE the scatter — the write-side twin of the
-    fused gather above. The jnp spelling (``serving.kv_pool.
-    quantize_kv`` + four ``.at[rows].set``) stays the dense/interpret
-    reference; both call ``kv_pool.quantize_rows`` for the row math, so
-    the two spellings produce bit-identical pools and greedy streams
-    cannot diverge across the scatter implementation.
+    """Quantize-on-scatter: write a chunk's KV rows into a quantized
+    pool, computing each row's per-head scale and casting to the pool
+    dtype in ONE kernel — the write-side twin of the fused gather above.
+    The jnp spelling (``serving.kv_pool.quantize_kv`` + four
+    ``.at[rows].set``) stays the dense/interpret reference; both call
+    ``kv_pool.quantize_rows`` for the row math, so the two spellings
+    produce bit-identical pools and greedy streams cannot diverge across
+    the scatter implementation.
 
-    Grid ``(B·L,)``: one step per written row. The (block, offset)
-    destination pair rides in as a scalar-prefetch operand and the pool
-    OUTPUT BlockSpec index map resolves it — the scatter analogue of the
-    gather's table-driven index map. ``input_output_aliases`` pins each
-    pool output to its input buffer, so the write is in place and
-    unvisited blocks keep their rows (required for correctness, not
-    just speed — the pools are donated engine state). Duplicate
-    destinations exist only for trash-block writes (inactive lanes),
-    where any write order is harmless garbage.
-
-    The scale siblings are written outside the kernel: one scale row
-    ``[H_kv]`` of a ``[n_blocks, block_len, H_kv]`` array is not a block
-    Mosaic's tiling rule accepts (second-to-last block dim 1 against
-    ``block_len``), so the kernel emits the row scales it computed as a
-    dense ``[B·L, H_kv, 1]`` output and a plain ``.at[rows].set`` — the
-    jnp spelling's own scale write — places them.
+    Grid ``(B·L,)``: one step per written row, K and V together. The
+    kernel emits the quantized rows and their scales DENSE
+    (``[B·L, H_kv, D]`` and ``[B·L, H_kv, 1]``) and four plain
+    ``.at[rows].set`` — in place on the donated pools, the same scatter
+    the raw-pool path uses — put them at their (block, offset). The
+    kernel cannot place them itself: one row of a
+    ``[n_blocks, block_len, H_kv·D]`` leaf (or one ``[H_kv]`` scale row)
+    is a block whose second-to-last dim is 1 against ``block_len``,
+    which Mosaic's tiling rule refuses, and a leaf shaped so that the
+    rule accepts a row (``[..., H_kv, D]``) is the one the chip lays
+    out ``n_blocks``-minor and copies whole around every scatter
+    (``kv_pool.pool_leaf_shape``). Duplicate destinations exist only
+    for trash-block writes (inactive lanes), where any write order is
+    harmless garbage.
 
     Args:
       k, v: ``[B, L, H_kv, D]`` rows to write (post-RoPE, compute
@@ -445,7 +441,7 @@ def paged_quantize_scatter(
       blk, off: ``[B, L]`` int32 destination block ids / in-block
         offsets (``models.transformer.Attention`` derives them from the
         block table and ``position_offset``).
-      k_pool, v_pool: ``[n_blocks, block_len, H_kv, D]`` quantized
+      k_pool, v_pool: ``[n_blocks, block_len, H_kv·D]`` quantized
         pools (int8 or fp8).
       k_scale, v_scale: ``[n_blocks, block_len, H_kv]`` scale siblings
         (fp32 multipliers for int8, int8 exponents for fp8 —
@@ -470,58 +466,36 @@ def paged_quantize_scatter(
     pool_dt = k_pool.dtype
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # one [2, N] scalar-prefetch operand: row i writes pool block
-    # idx[0, i] at in-block offset idx[1, i]
-    rows = (blk.reshape(-1).astype(jnp.int32),
-            off.reshape(-1).astype(jnp.int32))
-    idx = jnp.stack(rows)
-    kf = k.reshape(n, h_kv, d)
-    vf = v.reshape(n, h_kv, d)
 
-    def _kernel(idx_ref, k_ref, v_ref, kp_in, vp_in,
-                kp_out, vp_out, ks_out, vs_out):
-        del idx_ref, kp_in, vp_in  # index maps / aliased with outputs
+    def _kernel(k_ref, v_ref, kq_out, vq_out, ks_out, vs_out):
         qk, sk = quantize_rows(k_ref[0].astype(jnp.float32), pool_dt)
         qv, sv = quantize_rows(v_ref[0].astype(jnp.float32), pool_dt)
-        kp_out[0, 0] = qk
-        vp_out[0, 0] = qv
+        kq_out[0] = qk
+        vq_out[0] = qv
         ks_out[0] = sk[:, None]
         vs_out[0] = sv[:, None]
 
-    row_spec = pl.BlockSpec((1, h_kv, d), lambda i, idx: (i, 0, 0))
-    pool_spec = pl.BlockSpec(
-        (1, 1, h_kv, d), lambda i, idx: (idx[0, i], idx[1, i], 0, 0)
-    )
-    sc_spec = pl.BlockSpec((1, h_kv, 1), lambda i, idx: (i, 0, 0))
+    row_spec = pl.BlockSpec((1, h_kv, d), lambda i: (i, 0, 0))
+    sc_spec = pl.BlockSpec((1, h_kv, 1), lambda i: (i, 0, 0))
+    q_rows = jax.ShapeDtypeStruct((n, h_kv, d), pool_dt)
     sc_rows = jax.ShapeDtypeStruct((n, h_kv, 1), k_scale.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n,),
-        in_specs=[row_spec, row_spec, pool_spec, pool_spec],
-        out_specs=[pool_spec, pool_spec, sc_spec, sc_spec],
-    )
     kwargs = {}
     if not interpret:
-        # trash-block duplicates make write order observable in garbage
-        # only; still, "arbitrary" keeps the sweep sequential
         kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("parallel",)
         )
-    k_pool, v_pool, sk_rows, sv_rows = pl.pallas_call(
+    qk_rows, qv_rows, sk_rows, sv_rows = pl.pallas_call(
         _kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
-            sc_rows, sc_rows,
-        ],
-        grid_spec=grid_spec,
-        # operand index space includes the scalar-prefetch arg: 0=idx,
-        # 1=k rows, 2=v rows, 3..4=the two pools -> outputs 0..1
-        input_output_aliases={3: 0, 4: 1},
+        out_shape=[q_rows, q_rows, sc_rows, sc_rows],
+        grid=(n,),
+        in_specs=[row_spec, row_spec],
+        out_specs=[row_spec, row_spec, sc_spec, sc_spec],
         interpret=interpret,
         name="paged_kv_write",
         **kwargs,
-    )(idx, kf, vf, k_pool, v_pool)
-    return (k_pool, v_pool,
+    )(k.reshape(n, h_kv, d), v.reshape(n, h_kv, d))
+    rows = (blk.reshape(-1), off.reshape(-1))
+    return (k_pool.at[rows].set(qk_rows.reshape(n, h_kv * d)),
+            v_pool.at[rows].set(qv_rows.reshape(n, h_kv * d)),
             k_scale.at[rows].set(sk_rows[..., 0]),
             v_scale.at[rows].set(sv_rows[..., 0]))

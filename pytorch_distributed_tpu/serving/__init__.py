@@ -52,6 +52,7 @@ from pytorch_distributed_tpu.serving.kv_pool import (
     init_paged_cache,
     paged_cache_specs,
     pool_block_bytes,
+    pool_leaf_shape,
     quantize_kv,
 )
 from pytorch_distributed_tpu.serving.engine import (
@@ -83,6 +84,7 @@ __all__ = [
     "init_paged_cache",
     "paged_cache_specs",
     "pool_block_bytes",
+    "pool_leaf_shape",
     "quantize_kv",
     "KVExport",
     "PagedEngine",

@@ -112,7 +112,7 @@ class KVExport(NamedTuple):
     "Serving fleet").
 
     ``blocks`` is the pool pytree sliced to the request's chain (each
-    leaf ``[n_blocks, block_len, H_kv, D]``, logical positions in chain
+    leaf ``[n_blocks, block_len, H_kv·D]``, logical positions in chain
     order) and ``logits_row`` the final-chunk logits — the distribution
     of the request's first decoded token, which the importing engine's
     decode tick samples from. Block ids do NOT travel: the importer

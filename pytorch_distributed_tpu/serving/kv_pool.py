@@ -2,9 +2,12 @@
 
 The paged layout (Kwon et al., SOSP 2023) stores every resident request's
 KV in fixed-size blocks drawn from one shared pool
-``[n_blocks, block_len, H_kv, D]`` per layer. A request's logical
-positions ``[w*block_len, (w+1)*block_len)`` live in the pool block its
-block-table row names at column ``w`` — so admission allocates fresh
+``[n_blocks, block_len, H_kv·D]`` per layer (``pool_leaf_shape``: a
+row is one position's heads side by side, so a block is contiguous on
+the chip; a 4-D ``[..., H_kv, D]`` leaf is laid out ``n_blocks``-minor
+there and every scatter and gather copies the whole pool). A request's
+logical positions ``[w*block_len, (w+1)*block_len)`` live in the pool
+block its block-table row names at column ``w`` — so admission allocates fresh
 blocks and writes ONLY the new prompt's KV (O(prompt)), never touching
 resident requests' blocks, where the dense layout wrote a full
 ``max_seq_len`` row per admission (O(per-slot cache)).
@@ -114,6 +117,20 @@ def pool_scale_dtype(pool_dtype):
     2D/(D+1) capacity (vs int8's 2D/(D+4)) comes from."""
     return (jnp.float32 if jnp.dtype(pool_dtype) == jnp.dtype(jnp.int8)
             else jnp.int8)
+
+
+def pool_leaf_shape(n_blocks: int, block_len: int, h_kv: int,
+                    head_dim: int, *, scale: bool = False):
+    """THE shape of a pool leaf, written once: a ``key``/``value`` leaf
+    is ``[n_blocks, block_len, H_kv·D]`` — heads flattened into the row,
+    head ``h`` at lanes ``[h·D, (h+1)·D)`` — and its scale sibling
+    (``scale=True``, quantized pools) ``[n_blocks, block_len, H_kv]``.
+    Axis 0 is the block axis and axis 2 the (contiguous) head axis TP
+    shards, for both. The TPU compiler keeps a leaf of this shape
+    row-major; given ``[n_blocks, block_len, H_kv, D]`` it picks
+    ``n_blocks`` minor-most (D=64 would pad to 128 lanes) and wraps
+    every scatter and gather in copies of the whole leaf."""
+    return (n_blocks, block_len, h_kv if scale else h_kv * head_dim)
 
 
 def scale_factors(scales: jax.Array) -> jax.Array:
@@ -440,9 +457,11 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     Shapes come from ``eval_shape`` on the dense decode cache at batch 1
     (nothing is traced into a compiled program), then every
     ``[1, max_seq_len, H_kv, D]`` leaf is re-shaped into a
-    ``[n_blocks, block_len, H_kv, D]`` pool — the per-layer head count
-    and dtype (GQA narrows H_kv; TP shards it by placement) carry over
-    unchanged, so the pool works for every config the dense cache does.
+    ``[n_blocks, block_len, H_kv·D]`` pool (``pool_leaf_shape``) — the
+    per-layer head count and dtype (GQA narrows H_kv; TP shards the
+    flattened head axis by placement, ``H_kv/tp·D`` contiguous lanes a
+    shard) carry over, so the pool works for every config the dense
+    cache does.
 
     ``kv_dtype="int8"`` stores the pools quantized: each ``key``/
     ``value`` leaf becomes int8 and gains a ``key_scale``/``value_scale``
@@ -471,8 +490,9 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     )
     if kv_dtype is None:
         return jax.tree.map(
-            lambda s: jnp.zeros((n_blocks, block_len) + s.shape[2:],
-                                s.dtype),
+            lambda s: jnp.zeros(
+                pool_leaf_shape(n_blocks, block_len, *s.shape[2:]), s.dtype
+            ),
             shapes,
         )
 
@@ -490,10 +510,13 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
             for name in ("key", "value"):
                 s = node[name]
                 out[name] = jnp.zeros(
-                    (n_blocks, block_len) + s.shape[2:], pool_dt
+                    pool_leaf_shape(n_blocks, block_len, *s.shape[2:]),
+                    pool_dt,
                 )
                 out[name + "_scale"] = jnp.zeros(
-                    (n_blocks, block_len, s.shape[2]), sc_dt
+                    pool_leaf_shape(n_blocks, block_len, *s.shape[2:],
+                                    scale=True),
+                    sc_dt,
                 )
             return out
         if isinstance(node, Mapping):
@@ -525,24 +548,15 @@ def pool_block_bytes(config, params, block_len: int,
 
 
 def paged_cache_specs(config, cache):
-    """TP placement for the pool: the HEAD dim (axis 2 — same leaf rank
-    as the dense cache) shards over the model axis, exactly the slice
-    each shard's Attention computes. Reuses the dense serving rule
-    (``models.generate._cache_specs``) so the two layouts cannot drift.
-    A quantized pool's (int8 or fp8) rank-3 scale leaves
-    ``[n_blocks, block_len, H_kv]``
-    shard the same head dim (now the LAST axis): their spec is the
-    rank-4 rule with its trailing D entry dropped — derived, so it
-    cannot drift either."""
+    """TP placement for the pool: the HEAD axis — axis 2 of every leaf,
+    ``H_kv·D`` lanes of a value leaf (heads contiguous, so a shard holds
+    ``H_kv/tp`` whole heads) and ``H_kv`` of a scale sibling — shards
+    over the model axis, exactly the slice each shard's Attention
+    computes; the dense cache's rule (``models.generate._cache_specs``)
+    with its trailing D entry folded into the head axis."""
     from jax.sharding import PartitionSpec as P
 
-    from pytorch_distributed_tpu.models.generate import _cache_specs
-
-    specs = _cache_specs(config, cache)
-    return jax.tree.map(
-        lambda leaf, spec: spec if leaf.ndim == 4 else P(*tuple(spec)[:3]),
-        cache, specs,
-    )
+    return jax.tree.map(lambda _: P(None, None, config.model_axis), cache)
 
 
 # ---------------------------------------------------------------------------

@@ -39,6 +39,7 @@ from pytorch_distributed_tpu.serving.kv_pool import (
     init_paged_cache,
     kv_pool_dtype,
     pool_block_bytes,
+    pool_leaf_shape,
     pool_scale_dtype,
     quantize_kv,
 )
@@ -77,11 +78,16 @@ def random_pool(rng, b, h_kv, d, bl, w, quantize=False):
     args = [jnp.asarray(pool_k), jnp.asarray(pool_v)]
     scales = {}
     if quantize:
-        kq, ks = quantize_kv(args[0])
-        vq, vs = quantize_kv(args[1])
+        # ``quantize`` is True (int8) or the pool dtype itself
+        pool_dt = jnp.int8 if quantize is True else quantize
+        kq, ks = quantize_kv(args[0], pool_dt)
+        vq, vs = quantize_kv(args[1], pool_dt)
         args = [kq, vq]
         scales = dict(k_scale=ks, v_scale=vs)
-    return args[0], args[1], jnp.asarray(tables), scales
+    # rows were drawn per head; the pool stores them flattened
+    leaf = pool_leaf_shape(n_blocks, bl, h_kv, d)
+    return (args[0].reshape(leaf), args[1].reshape(leaf),
+            jnp.asarray(tables), scales)
 
 
 # ---------------------------------------------------------------------------
@@ -148,9 +154,9 @@ def test_quantize_kv_roundtrip_bound():
 
 def test_paged_attention_scale_arg_validation():
     z = jnp.zeros((1, 1, 2, 4))
-    pool = jnp.zeros((2, 4, 2, 4))
-    pool8 = jnp.zeros((2, 4, 2, 4), jnp.int8)
-    sc = jnp.ones((2, 4, 2))
+    pool = jnp.zeros(pool_leaf_shape(2, 4, 2, 4))
+    pool8 = jnp.zeros(pool_leaf_shape(2, 4, 2, 4), jnp.int8)
+    sc = jnp.ones(pool_leaf_shape(2, 4, 2, 4, scale=True))
     t = jnp.zeros((1, 1), jnp.int32)
     p = jnp.zeros((1, 1), jnp.int32)
     for impl in ("dense", "pallas"):
@@ -301,7 +307,8 @@ def test_init_paged_cache_int8_layout():
     assert set(layer) == {"key", "value", "key_scale", "value_scale"}
     assert layer["key"].dtype == jnp.int8
     assert layer["key_scale"].dtype == jnp.float32
-    assert layer["key"].shape == (4, 8, 2, 8)  # head_dim 32/4
+    # 2 narrow heads of head_dim 32/4 side by side in a row
+    assert layer["key"].shape == pool_leaf_shape(4, 8, 2, 8) == (4, 8, 16)
     assert layer["key_scale"].shape == (4, 8, 2)
     with pytest.raises(ValueError, match="kv_dtype"):
         init_paged_cache(cfg, params, 4, 8, kv_dtype="fp4")
@@ -318,8 +325,9 @@ def test_init_paged_cache_fp8_layout():
     assert set(layer) == {"key", "value", "key_scale", "value_scale"}
     assert layer["key"].dtype == jnp.float8_e4m3fn
     assert layer["key_scale"].dtype == jnp.int8
-    assert layer["key"].shape == (4, 8, 2, 8)
-    assert layer["key_scale"].shape == (4, 8, 2)
+    assert layer["key"].shape == pool_leaf_shape(4, 8, 2, 8)
+    assert layer["key_scale"].shape == pool_leaf_shape(4, 8, 2, 8,
+                                                       scale=True)
     e5 = init_paged_cache(cfg, params, n_blocks=4, block_len=8,
                           kv_dtype="fp8_e5m2")
     assert e5["block0"]["attn"]["value"].dtype == jnp.float8_e5m2
@@ -351,18 +359,18 @@ def test_quantize_scatter_bit_equivalence(kv_dtype):
     off = jnp.asarray((flat % bl).reshape(b, l).astype(np.int32))
 
     def pools():
-        return (jnp.zeros((nb, bl, h_kv, d), pool_dt),
-                jnp.zeros((nb, bl, h_kv, d), pool_dt),
-                jnp.zeros((nb, bl, h_kv), scale_dt),
-                jnp.zeros((nb, bl, h_kv), scale_dt))
+        leaf = pool_leaf_shape(nb, bl, h_kv, d)
+        sc = pool_leaf_shape(nb, bl, h_kv, d, scale=True)
+        return (jnp.zeros(leaf, pool_dt), jnp.zeros(leaf, pool_dt),
+                jnp.zeros(sc, scale_dt), jnp.zeros(sc, scale_dt))
 
     kp, vp, ks, vs = paged_quantize_scatter(k, v, blk, off, *pools())
     rkp, rvp, rks, rvs = pools()
     qk, sk = quantize_kv(k, pool_dt)
     qv, sv = quantize_kv(v, pool_dt)
     rows = (blk.reshape(-1), off.reshape(-1))
-    rkp = rkp.at[rows].set(qk.reshape(-1, h_kv, d))
-    rvp = rvp.at[rows].set(qv.reshape(-1, h_kv, d))
+    rkp = rkp.at[rows].set(qk.reshape(-1, h_kv * d))
+    rvp = rvp.at[rows].set(qv.reshape(-1, h_kv * d))
     rks = rks.at[rows].set(sk.reshape(-1, h_kv))
     rvs = rvs.at[rows].set(sv.reshape(-1, h_kv))
     for got, ref in ((kp, rkp), (vp, rvp), (ks, rks), (vs, rvs)):
@@ -374,8 +382,8 @@ def test_quantize_scatter_bit_equivalence(kv_dtype):
 
 def test_quantize_scatter_rejects_raw_pools():
     z = jnp.zeros((1, 1, 2, 4))
-    pool = jnp.zeros((2, 4, 2, 4), jnp.float32)
-    sc = jnp.zeros((2, 4, 2), jnp.float32)
+    pool = jnp.zeros(pool_leaf_shape(2, 4, 2, 4), jnp.float32)
+    sc = jnp.zeros(pool_leaf_shape(2, 4, 2, 4, scale=True), jnp.float32)
     i = jnp.zeros((1, 1), jnp.int32)
     with pytest.raises(ValueError, match="quantized"):
         paged_quantize_scatter(z, z, i, i, pool, pool, sc, sc)
@@ -421,9 +429,9 @@ def test_split_s_quantized_pool():
     b, h, h_kv, d, bl, w = 2, 4, 2, 16, 4, 12
     for seed, kv_dtype in ((9, "int8"), (10, "fp8")):
         rng = np.random.default_rng(seed)
-        kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
-        qk, ks = quantize_kv(kp, kv_pool_dtype(kv_dtype))
-        qv, vs = quantize_kv(vp, kv_pool_dtype(kv_dtype))
+        qk, qv, tables, sc = random_pool(
+            rng, b, h_kv, d, bl, w, quantize=kv_pool_dtype(kv_dtype))
+        ks, vs = sc["k_scale"], sc["v_scale"]
         q = jnp.asarray(rng.normal(size=(b, 1, h, d)).astype(np.float32))
         pos = jnp.asarray([[41], [19]], jnp.int32)
         one = paged_flash_attention(q, qk, qv, tables, pos,
@@ -582,6 +590,15 @@ def test_chunked_vs_whole_prefill_pallas():
 # ---------------------------------------------------------------------------
 
 
+def _pools_and_scales(cache):
+    """A pool's value leaves and its scale siblings, told apart by NAME:
+    both are rank 3 (``kv_pool.pool_leaf_shape``)."""
+    pools, scales = [], []
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        (scales if path[-1].key.endswith("_scale") else pools).append(leaf)
+    return pools, scales
+
+
 def _drive_batcher(b, prompts, budgets):
     got, slot_of, pending = {}, {}, list(range(len(prompts)))
     while pending or any(b.remaining > 0):
@@ -652,12 +669,10 @@ def test_pallas_batcher_tp_matches_dense(kv_heads, kv_dtype):
                            kv_dtype=kv_dtype)
     assert _drive_batcher(tp, prompts, budgets) == dense_rep
     # the pool — and for int8 its scale siblings — really are sharded
-    leaves = jax.tree.leaves(tp.cache)
-    pools = [x for x in leaves if x.ndim == 4]
+    pools, scales = _pools_and_scales(tp.cache)
     assert next(iter(pools[0].addressable_shards)).data.shape[2] == \
         pools[0].shape[2] // 2
     if kv_dtype == "int8":
-        scales = [x for x in leaves if x.ndim == 3]
         assert scales, "int8 pool should carry scale leaves"
         assert next(iter(scales[0].addressable_shards)).data.shape[2] == \
             scales[0].shape[2] // 2
@@ -694,12 +709,10 @@ def test_pallas_batcher_tp_fp8_matches_single_device():
                            mesh=mesh, gather_impl="pallas",
                            kv_dtype="fp8")
     assert _drive_batcher(tp, prompts, budgets) == single
-    leaves = jax.tree.leaves(tp.cache)
-    pools = [x for x in leaves if x.ndim == 4]
+    pools, scales = _pools_and_scales(tp.cache)
     assert pools[0].dtype == jnp.float8_e4m3fn
     assert next(iter(pools[0].addressable_shards)).data.shape[2] == \
         pools[0].shape[2] // 2
-    scales = [x for x in leaves if x.ndim == 3]
     assert scales and scales[0].dtype == jnp.int8
     assert next(iter(scales[0].addressable_shards)).data.shape[2] == \
         scales[0].shape[2] // 2

@@ -8,11 +8,17 @@ paged kernels passed 60+ of them while refusing every shape here (PR 21).
 Each case lowers with ``interpret=False`` at GPT-2-small serving/training
 widths (H=12, D=64) and asserts the compiled program holds the kernel.
 
+The K/V pool's layout is guarded the same way: the paged engine's decode
+tick and one chunk program compile at the benchmark's serving shapes and
+must keep every pool leaf row-major, with no copy of a whole leaf.
+
 Nothing runs: a compile that passes says nothing about results or time.
 """
 
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -37,6 +43,7 @@ from pytorch_distributed_tpu.ops.ring_flash import ring_flash_attention
 from pytorch_distributed_tpu.parallel.mesh import SEQ_AXIS, shard_map
 from pytorch_distributed_tpu.serving.kv_pool import (
     kv_pool_dtype,
+    pool_leaf_shape,
     pool_scale_dtype,
 )
 
@@ -91,9 +98,10 @@ def _pool_avals(topo, kv_dtype):
     sds = functools.partial(jax.ShapeDtypeStruct,
                             sharding=SingleDeviceSharding(topo.devices[0]))
     pool_dt = jnp.bfloat16 if kv_dtype is None else kv_pool_dtype(kv_dtype)
-    pool = sds((N_BLOCKS, BLOCK_LEN, H, D), pool_dt)
+    pool = sds(pool_leaf_shape(N_BLOCKS, BLOCK_LEN, H, D), pool_dt)
     scale = (None if kv_dtype is None else
-             sds((N_BLOCKS, BLOCK_LEN, H), pool_scale_dtype(pool_dt)))
+             sds(pool_leaf_shape(N_BLOCKS, BLOCK_LEN, H, D, scale=True),
+                 pool_scale_dtype(pool_dt)))
     return sds, pool, scale
 
 
@@ -202,3 +210,89 @@ def test_decode_tick_module_is_named(v5e):
     lowered = eng._decode().lower(*args)
     assert lowered.as_text().startswith("module @jit_decode_tick ")
     assert lowered.compile().as_text().startswith("HloModule jit_decode_tick,")
+
+
+# ---- the K/V pool's layout on the chip (PR 25) -----------------------------
+
+#: gpt2-medium.chat-backlog's attention and pool (perfbench/cells), on 2
+#: layers and a small vocabulary to keep the compile short
+POOL_CELL = dict(heads=16, head_dim=64, slots=64, blocks=2561, block_len=16,
+                 chunk=32, max_seq_len=1024)
+
+
+def _engine_program(v5e, program):
+    """``PagedEngine``'s decode tick or its ``(4, 8)`` chunk program,
+    lowered for the described chip from shapes alone: the engine is built
+    on a two-block pool and the program takes the cell's 2,561-block pool
+    as an aval (a program does not hold the pool's size)."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    c = POOL_CELL
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=2, num_heads=c["heads"],
+        embed_dim=c["heads"] * c["head_dim"], max_seq_len=c["max_seq_len"],
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense",
+    )
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"],
+    )
+    eng = PagedEngine(cfg, params, c["slots"], n_blocks=2,
+                      block_len=c["block_len"], prefill_chunk=c["chunk"])
+    pool = jax.eval_shape(
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"]),
+        params,
+    )
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
+
+    n, i32 = c["slots"], jnp.int32
+    if program == "decode_tick":
+        fn = eng._decode()
+        args = (params, pool, eng.logits, jnp.zeros((n,), i32),
+                jnp.zeros((n,), bool),
+                jnp.zeros((n, eng.table_width), i32), jax.random.key(0))
+    else:
+        k, w = 4, 8
+        fn = eng._chunk_fn(k, w)
+        assert eng.chunk_program_name(k, w) == program
+        args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
+                jnp.zeros((k,), i32), jnp.zeros((k, w), i32),
+                jnp.zeros((k,), i32), jnp.zeros((k,), bool),
+                jnp.zeros((k,), i32))
+    return fn.lower(*jax.tree.map(on_chip, args)), jax.tree.leaves(pool)
+
+
+@pytest.mark.parametrize("program", ["decode_tick", "chunk_prefill[k=4,w=8]"])
+def test_pool_leaves_stay_row_major_and_uncopied(v5e, program):
+    """The layout's guard without a chip. A ``[n_blocks, block_len,
+    H_kv, D]`` leaf enters these programs ``n_blocks``-minor
+    (``{0,3,2,1}``: the compiler avoids padding D=64 to 128 lanes) and
+    every scatter and gather is wrapped in copies of the whole leaf: 8
+    ``copy`` of 84 MB in each of these two-layer programs (PR 24's
+    tree), 40% of the serving cell's device time. ``kv_pool.
+    pool_leaf_shape`` flattens the heads into the row: the leaf enters
+    row-major and nothing the size of a leaf is copied or transposed."""
+    lowered, leaves = _engine_program(v5e, program)
+    text = lowered.compile().as_text()
+    leaf = leaves[0]
+    assert all(x.shape == leaf.shape for x in leaves)
+    dims = ",".join(map(str, leaf.shape))
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
+    layouts = re.findall(r"bf16\[%s\]\{([\d,]+)" % dims, entry.group(1))
+    assert len(layouts) == len(leaves) and set(layouts) == {"2,1,0"}, layouts
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and math.prod(map(int, m.group(2).split(","))) == leaf.size:
+            moved.append(m.group(1))
+    assert not moved, f"{program}: pool-sized copies {moved}"
