@@ -26,7 +26,13 @@ recipes have it, weights random from a seed):
   pool take of the device's memory beside their logical size (a leaf the chip
   pads or keeps in another layout shows here), and the fused gather
   against the dense one on random bf16, int8 and fp8 pools, decode and
-  chunk rows: they must agree to one bf16 ulp.
+  chunk rows: they must agree to one bf16 ulp;
+- ``ouro``: a looped decoder at ``perfbench/configs/ouro-2.6b.json``'s
+  widths on two layers and four passes through ``PagedEngine``: one chunk
+  program and one decode tick, each slot's logits against the plain
+  reference (``perfbench/references/ouro.py``) on the same bf16 weights;
+  then the ``gather_impl="pallas"`` tick compiled at heads of 128, and
+  whether the chip's compiler took it (reported, not required).
 
 ``--multichip`` runs the paths that exist only across chips, each beside
 what it is compared with: data-parallel ResNet against one device,
@@ -456,6 +462,90 @@ def pool_phase() -> None:
                         f"by {diff}, over one bf16 ulp ({ulp})")
 
 
+def ouro_phase() -> None:
+    """The looped stack through the paged engine at full width."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from perfbench.harness.manifest import load_json, merged
+    from perfbench.references import ouro
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import ChunkJob, PagedEngine
+
+    with phase("ouro") as rec:
+        conf = merged(load_json(os.path.join(
+            ROOT, "perfbench", "configs", "ouro-2.6b.json")), ARGS.tiny)
+        dtype = getattr(jnp, conf["dtype"])
+        program = dict(conf["program"], num_layers=2, max_seq_len=128)
+        cfg = TransformerConfig(**program, dropout=0.0, dtype=dtype,
+                                attention="dense")
+        ouro.configure(program)
+        shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                                jnp.zeros((1, 8), jnp.int32))["params"]
+        params = ouro.init_params(ARGS.seed, shapes, dtype)
+        chunk, slots = 32, 4
+        rec.update(layers=cfg.num_layers, passes=cfg.ut_steps,
+                   heads=cfg.num_heads, embed_dim=cfg.embed_dim,
+                   mlp_dim=cfg.mlp_width, vocab=cfg.vocab_size)
+
+        def build(config):
+            return PagedEngine(config, params, slots, n_blocks=33,
+                               block_len=16, prefill_chunk=chunk)
+
+        eng = build(cfg)
+        prompts = make_prompts(cfg, [chunk, 19])
+        jobs = []
+        for slot, prompt in enumerate(prompts):
+            require(rec, eng.admit(slot, len(prompt), 4), "admission")
+            padded = np.zeros((chunk,), np.int32)
+            padded[:len(prompt)] = prompt
+            jobs.append(ChunkJob(slot, padded, 0, True, len(prompt) - 1))
+        eng.run_chunks(jobs)
+        after_chunk = np.asarray(eng.logits)
+        lengths = np.array([len(p) for p in prompts] + [0] * (slots - 2))
+        tokens, _ = eng.decode(lengths.astype(np.int32), lengths > 0,
+                               jax.random.key(ARGS.seed))
+        after_tick = np.asarray(eng.logits)
+        rec["programs"] = eng.compiled_program_names()
+        rec["pool_leaf"] = list(jax.tree.leaves(eng.cache)[0].shape)
+
+        # the reference's full forward over prompt + the decoded token:
+        # row L-1 is what the chunk program left, row L what the tick did
+        worst = scale = 0.0
+        with jax.default_matmul_precision("highest"):
+            for slot, prompt in enumerate(prompts):
+                seq = np.concatenate([prompt, tokens[slot:slot + 1]])
+                want = np.asarray(ouro.logits(params, jnp.asarray(seq)[None]))
+                at = len(prompt)
+                worst = max(worst,
+                            np.abs(after_chunk[slot] - want[0, at - 1]).max(),
+                            np.abs(after_tick[slot] - want[0, at]).max())
+                scale = max(scale, np.abs(want[0, at - 1:]).max())
+        rec.update(logit_max_abs_diff=float(worst),
+                   logit_max_abs=float(scale))
+        # bf16 keeps 8 bits of a logit and of every state on the way to
+        # it; a cache entry read from the wrong pass moves logits by
+        # their own size
+        require(rec, worst <= (1e-4 if ARGS.tiny else 0.04) * scale,
+                f"logits differ from the reference by {worst} (largest "
+                f"logit {scale})")
+
+        # the fused gather at heads of 128: does the chip's compiler
+        # take it inside the four-trip loop? Reported either way.
+        try:
+            fused = build(dataclasses.replace(cfg, gather_impl="pallas"))
+            text = fused.warm_decode(execute=False).as_text()
+            rec["pallas_tick"] = {"compiled": True,
+                                  "kernel_in_program": KERNEL in text}
+        except Exception as e:  # the compiler's refusal is the finding
+            rec["pallas_tick"] = {"compiled": False,
+                                  "error": str(e).splitlines()[0][:300]}
+
+
 # ---- four chips ------------------------------------------------------------
 
 
@@ -635,6 +725,7 @@ def main() -> None:
             lm_phase(workdir)
             server_phase()
             pool_phase()
+            ouro_phase()
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     hits, compile_s = process_compile_totals()

@@ -60,8 +60,9 @@ def _probe_trees():
             ),
         ),
         (
-            "gqa",
-            tiny_config(model_axis="model", tp_size=2, num_kv_heads=2),
+            "gqa+gated_mlp",
+            tiny_config(model_axis="model", tp_size=2, num_kv_heads=2,
+                        mlp="swiglu"),
         ),
     ]
     out = []

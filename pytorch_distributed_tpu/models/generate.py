@@ -326,13 +326,15 @@ def _tp_rules(config):
 
 
 def _cache_specs(config, cache):
-    """KV-cache placement: [B, L, H_kv, D] leaves shard their HEAD dim
-    over the model axis — the same split the TP Attention computes, so
-    each shard's cache slice is exactly the K/V its heads produce."""
+    """KV-cache placement: [B, L, H_kv, D] leaves (a looped config's
+    carry a leading pass axis) shard their HEAD dim over the model axis —
+    the same split the TP Attention computes, so each shard's cache slice
+    is exactly the K/V its heads produce."""
     from jax.sharding import PartitionSpec as P
 
     return jax.tree.map(
-        lambda _: P(None, None, config.model_axis, None), cache
+        lambda leaf: P(*[None] * (leaf.ndim - 2), config.model_axis, None),
+        cache,
     )
 
 
@@ -567,6 +569,12 @@ class ContinuousBatcher:
             self.remaining = np.zeros(n_slots, np.int32)
             self._rng = jax.random.key(seed)
             return
+        if config.ut_steps > 1:
+            raise ValueError(
+                "cache_layout='dense' keeps one cache row a slot on axis 0; "
+                "a looped config's (ut_steps > 1) cache carries a pass axis "
+                "there: serve it with cache_layout='paged'"
+            )
         self.engine = None
         tp = config.model_axis is not None
         # Cache shapes are GLOBAL (full head count — from a collective-free

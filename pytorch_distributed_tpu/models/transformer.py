@@ -118,8 +118,60 @@ class TransformerConfig:
     # config (split_s=) like gather_impl, so the registry fingerprint
     # keys the program shape; dense gathers and training ignore it.
     split_s: Optional[int] = None
+    # The block's description. The defaults are the GPT-2 block this
+    # module always ran (LayerNorm before each sublayer, a GELU MLP of
+    # ``embed_dim * mlp_ratio`` features, biased input projections):
+    # same parameter names, same tree, same programs.
+    # ``norm``: "layernorm" (scale and bias) or "rmsnorm" (scale only),
+    # float32 statistics either way, at ``norm_eps``.
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    # ``mlp``: "gelu" (``mlp_up`` -> GELU -> ``mlp_down``) or "swiglu"
+    # (``SiLU(mlp_gate(x)) * mlp_up(x)`` -> ``mlp_down``). ``mlp_dim`` is
+    # the hidden width in FEATURES (5632/2048 is no integer ratio); None
+    # = ``embed_dim * mlp_ratio``.
+    mlp: str = "gelu"
+    mlp_dim: Optional[int] = None
+    # Sandwich norm: each sublayer's OUTPUT is normed (``ln1_post``,
+    # ``ln2_post``) before it joins the residual stream.
+    post_norm: bool = False
+    # Bias on the input projections (qkv / q / kv, mlp_up, mlp_gate). The
+    # row-parallel output projections never carry one.
+    use_bias: bool = True
+    # Looped (universal) transformer: the stack of ``block{l}`` runs
+    # ``ut_steps`` times over the SAME parameters; ``ln_f`` closes every
+    # pass and the normed state goes on; pass t attends only to the keys
+    # and values pass t wrote, so the cache holds an entry per (pass,
+    # layer). ``exit_gate`` (a logit per position and pass) is in the
+    # tree; every token runs every pass: ``early_exit_threshold`` 1.0 is
+    # the only value accepted (adaptive exit would give the tokens of
+    # one tick different numbers of passes; ROADMAP B-mech).
+    ut_steps: int = 1
+    early_exit_threshold: float = 1.0
 
     def __post_init__(self):
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(
+                f"norm {self.norm!r} must be 'layernorm' or 'rmsnorm'"
+            )
+        if self.mlp not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp {self.mlp!r} must be 'gelu' or 'swiglu'")
+        if self.mlp_dim is not None and self.mlp_dim < 1:
+            raise ValueError(f"mlp_dim must be >= 1, got {self.mlp_dim}")
+        if self.n_experts and (self.mlp != "gelu" or self.mlp_dim is not None):
+            raise ValueError(
+                "the MoE block (models/moe.py) has GELU experts of "
+                "embed_dim * mlp_ratio features: mlp='swiglu' and mlp_dim "
+                "describe the dense MLP only"
+            )
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps must be >= 1, got {self.ut_steps}")
+        if self.early_exit_threshold != 1.0:
+            raise ValueError(
+                f"early_exit_threshold {self.early_exit_threshold} is not "
+                "supported: adaptive exit is not implemented, every token "
+                "runs all ut_steps passes (threshold 1.0)"
+            )
         if self.ring_layout not in ("contiguous", "zigzag"):
             raise ValueError(
                 f"ring_layout {self.ring_layout!r} must be 'contiguous' or "
@@ -192,9 +244,9 @@ class TransformerConfig:
                 "the axis name the TP collectives are skipped and the model "
                 "silently trains with thin local shards"
             )
-        if (self.embed_dim * self.mlp_ratio) % self.tp_size:
+        if self.mlp_width % self.tp_size:
             raise ValueError(
-                f"mlp width {self.embed_dim * self.mlp_ratio} not divisible "
+                f"mlp width {self.mlp_width} not divisible "
                 f"by tp_size {self.tp_size}"
             )
         if not 0.0 <= self.dropout < 1.0:
@@ -211,6 +263,12 @@ class TransformerConfig:
                 f"split_s {self.split_s!r} must be None (auto) or an "
                 "int >= 1 (flash-decoding worker count; ops.paged_flash)"
             )
+
+    @property
+    def mlp_width(self) -> int:
+        """Hidden features of the dense MLP."""
+        return (self.mlp_dim if self.mlp_dim is not None
+                else self.embed_dim * self.mlp_ratio)
 
     def uses_vocab_parallel(self) -> bool:
         """THE vocab-parallel predicate — the one place the condition
@@ -241,6 +299,12 @@ def _rope_rotate(x, positions, theta: float):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def _norm(cfg: TransformerConfig, name: str):
+    """The config's normalisation layer, float32 statistics and output."""
+    kind = nn.RMSNorm if cfg.norm == "rmsnorm" else nn.LayerNorm
+    return kind(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     deterministic: bool = True
@@ -249,10 +313,17 @@ class Attention(nn.Module):
 
     @nn.compact
     def __call__(self, x, position_offset, positions=None,
-                 block_tables=None):
+                 block_tables=None, pass_index=None):
         cfg = self.config
         b, l, e = x.shape
         head_dim = e // cfg.num_heads
+        looped = cfg.ut_steps > 1
+        if looped and pass_index is None:
+            raise ValueError(
+                "a looped config (ut_steps > 1) needs pass_index=: the "
+                "cache holds an entry per (pass, layer); TransformerLM "
+                "provides it"
+            )
         if cfg.model_axis:
             from pytorch_distributed_tpu.parallel.tensor import tp_copy
 
@@ -260,7 +331,8 @@ class Attention(nn.Module):
         heads_local = cfg.num_heads // cfg.tp_size
         if cfg.num_kv_heads is None:
             qkv = nn.DenseGeneral(
-                (3, heads_local, head_dim), dtype=cfg.dtype, name="qkv"
+                (3, heads_local, head_dim), dtype=cfg.dtype, name="qkv",
+                use_bias=cfg.use_bias,
             )(x)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [B,L,H,D]
             kv_group = 1
@@ -271,10 +343,12 @@ class Attention(nn.Module):
             kv_heads_local = cfg.num_kv_heads // cfg.tp_size
             kv_group = heads_local // kv_heads_local
             q = nn.DenseGeneral(
-                (heads_local, head_dim), dtype=cfg.dtype, name="q"
+                (heads_local, head_dim), dtype=cfg.dtype, name="q",
+                use_bias=cfg.use_bias,
             )(x)
             kv = nn.DenseGeneral(
-                (2, kv_heads_local, head_dim), dtype=cfg.dtype, name="kv"
+                (2, kv_heads_local, head_dim), dtype=cfg.dtype, name="kv",
+                use_bias=cfg.use_bias,
             )(x)
             k, v = kv[:, :, 0], kv[:, :, 1]  # [B, L, H_kv_loc, D]
 
@@ -328,7 +402,20 @@ class Attention(nn.Module):
             kv_heads = k.shape[2]
             ck = self.variable("cache", "key", _need_pool)
             cv = self.variable("cache", "value", _need_pool)
-            block_len = ck.value.shape[1]
+            # A looped config's leaf is [n_blocks, passes, block_len,
+            # H_kv*D] (kv_pool.pool_leaf_shape): block b of pass t is row
+            # b*passes + t of the leaf seen as [n_blocks*passes, ...] — a
+            # view, the leaf is row-major — so this pass scatters into and
+            # gathers from the CARRIED buffer in place through shifted
+            # block ids, and never slices its share out of the pool.
+            stored = ck.value.shape
+            block_len = stored[-2]
+
+            def pool_view(x):
+                return x.reshape((-1,) + x.shape[-2:]) if looped else x
+
+            if looped:
+                block_tables = block_tables * cfg.ut_steps + pass_index
             pos = jnp.asarray(position_offset, jnp.int32)
             if pos.ndim != 1:
                 raise ValueError(
@@ -366,16 +453,13 @@ class Attention(nn.Module):
                 # across spellings.
                 cks = self.variable("cache", "key_scale", _need_pool)
                 cvs = self.variable("cache", "value_scale", _need_pool)
+                pools = [pool_view(c.value) for c in (ck, cv, cks, cvs)]
                 if cfg.gather_impl == "pallas":
                     from pytorch_distributed_tpu.ops.paged_flash import (
                         paged_quantize_scatter,
                     )
 
-                    (ck.value, cv.value, cks.value,
-                     cvs.value) = paged_quantize_scatter(
-                        k, v, blk, off, ck.value, cv.value,
-                        cks.value, cvs.value,
-                    )
+                    pools = paged_quantize_scatter(k, v, blk, off, *pools)
                 else:
                     from pytorch_distributed_tpu.serving.kv_pool import (
                         quantize_kv,
@@ -384,34 +468,32 @@ class Attention(nn.Module):
                     kq, ks_rows = quantize_kv(k, ck.value.dtype)
                     vq, vs_rows = quantize_kv(v, cv.value.dtype)
                     rows = (blk.reshape(-1), off.reshape(-1))
-                    ck.value = ck.value.at[rows].set(
-                        kq.reshape(b * l, kv_heads * head_dim)
-                    )
-                    cv.value = cv.value.at[rows].set(
-                        vq.reshape(b * l, kv_heads * head_dim)
-                    )
-                    cks.value = cks.value.at[rows].set(
-                        ks_rows.reshape(b * l, kv_heads)
-                    )
-                    cvs.value = cvs.value.at[rows].set(
-                        vs_rows.reshape(b * l, kv_heads)
-                    )
+                    pools = [
+                        pool.at[rows].set(new.reshape(b * l, -1))
+                        for pool, new in zip(pools,
+                                             (kq, vq, ks_rows, vs_rows))
+                    ]
+                k_pool, v_pool, k_scale, v_scale = pools
                 out = paged_attention(
-                    q, ck.value, cv.value, block_tables, p,
+                    q, k_pool, v_pool, block_tables, p,
                     gather_impl=cfg.gather_impl, split_s=cfg.split_s,
-                    k_scale=cks.value, v_scale=cvs.value,
+                    k_scale=k_scale, v_scale=v_scale,
                 )
+                for c, pool in zip((ck, cv, cks, cvs), pools):
+                    c.value = pool.reshape(c.value.shape)
             else:
-                ck.value = ck.value.at[blk.reshape(-1), off.reshape(-1)].set(
-                    k.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim)
-                )
-                cv.value = cv.value.at[blk.reshape(-1), off.reshape(-1)].set(
-                    v.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim)
-                )
+                k_pool = pool_view(ck.value).at[
+                    blk.reshape(-1), off.reshape(-1)
+                ].set(k.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim))
+                v_pool = pool_view(cv.value).at[
+                    blk.reshape(-1), off.reshape(-1)
+                ].set(v.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim))
                 out = paged_attention(
-                    q, ck.value, cv.value, block_tables, p,
+                    q, k_pool, v_pool, block_tables, p,
                     gather_impl=cfg.gather_impl, split_s=cfg.split_s,
                 )
+                ck.value = k_pool.reshape(stored)
+                cv.value = v_pool.reshape(stored)
             out = nn.DenseGeneral(
                 e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
                 name="proj",
@@ -431,30 +513,32 @@ class Attention(nn.Module):
             # each request writes its own cache slot).
             max_len = cfg.max_seq_len
             kv_heads = k.shape[2]  # H_kv_local under GQA, H_local for MHA
+            # a looped config keeps a [B, max_len, ...] entry per pass,
+            # stacked on a leading axis this pass indexes
+            lead = (cfg.ut_steps,) if looped else ()
+            at = (pass_index,) if looped else ()
+            shape = lead + (b, max_len, kv_heads, head_dim)
             ck = self.variable(
-                "cache", "key",
-                lambda: jnp.zeros((b, max_len, kv_heads, head_dim), cfg.dtype),
+                "cache", "key", lambda: jnp.zeros(shape, cfg.dtype)
             )
             cv = self.variable(
-                "cache", "value",
-                lambda: jnp.zeros((b, max_len, kv_heads, head_dim), cfg.dtype),
+                "cache", "value", lambda: jnp.zeros(shape, cfg.dtype)
             )
             pos = jnp.asarray(position_offset, jnp.int32)
             if self.decode and pos.ndim == 1:
                 # per-request slot write (l == 1, asserted below)
-                rows = jnp.arange(b)
-                ck.value = ck.value.at[rows, pos].set(
-                    k[:, 0].astype(cfg.dtype)
-                )
-                cv.value = cv.value.at[rows, pos].set(
-                    v[:, 0].astype(cfg.dtype)
-                )
+                rows = at + (jnp.arange(b), pos)
+                ck.value = ck.value.at[rows].set(k[:, 0].astype(cfg.dtype))
+                cv.value = cv.value.at[rows].set(v[:, 0].astype(cfg.dtype))
             else:
+                k_new, v_new = k.astype(cfg.dtype), v.astype(cfg.dtype)
+                if looped:
+                    k_new, v_new = k_new[None], v_new[None]
                 ck.value = jax.lax.dynamic_update_slice(
-                    ck.value, k.astype(cfg.dtype), (0, pos, 0, 0)
+                    ck.value, k_new, at + (0, pos, 0, 0)
                 )
                 cv.value = jax.lax.dynamic_update_slice(
-                    cv.value, v.astype(cfg.dtype), (0, pos, 0, 0)
+                    cv.value, v_new, at + (0, pos, 0, 0)
                 )
 
         if self.decode:
@@ -465,6 +549,10 @@ class Attention(nn.Module):
             pos = jnp.asarray(position_offset, jnp.int32)
             pos_b = pos if pos.ndim == 1 else jnp.full((b,), pos)
             scale = head_dim**-0.5
+            k_seen, v_seen = ck.value, cv.value
+            if looped:  # this pass's entry (a copy: the paged pool is
+                # the serving layout, this one serves generate())
+                k_seen, v_seen = k_seen[pass_index], v_seen[pass_index]
             if kv_group > 1:
                 # GQA decode: grouped einsum directly against the NARROW
                 # cache — no widened K/V tensor ever materializes, so the
@@ -477,26 +565,26 @@ class Attention(nn.Module):
                 )
                 s = jnp.einsum(
                     "bqhgd,bkhd->bhgqk", qg,
-                    ck.value.astype(jnp.float32),
+                    k_seen.astype(jnp.float32),
                 )  # [B, H_kv, G, 1, max_len]
                 mask = (jnp.arange(cfg.max_seq_len)[None, None, None, None]
                         <= pos_b[:, None, None, None, None])
                 s = jnp.where(mask, s, -1e30)
                 p = jax.nn.softmax(s, axis=-1)
                 out = jnp.einsum(
-                    "bhgqk,bkhd->bqhgd", p, cv.value.astype(jnp.float32)
+                    "bhgqk,bkhd->bqhgd", p, v_seen.astype(jnp.float32)
                 ).reshape(b, 1, heads_local, head_dim).astype(cfg.dtype)
             else:
                 s = jnp.einsum(
                     "bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale,
-                    ck.value.astype(jnp.float32),
+                    k_seen.astype(jnp.float32),
                 )  # [B, H, 1, max_len]
                 mask = (jnp.arange(cfg.max_seq_len)[None, None, None, :]
                         <= pos_b[:, None, None, None])
                 s = jnp.where(mask, s, -1e30)
                 p = jax.nn.softmax(s, axis=-1)
                 out = jnp.einsum(
-                    "bhqk,bkhd->bqhd", p, cv.value.astype(jnp.float32)
+                    "bhqk,bkhd->bqhd", p, v_seen.astype(jnp.float32)
                 ).astype(cfg.dtype)
             out = nn.DenseGeneral(
                 e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="proj"
@@ -618,14 +706,22 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, position_offset, positions=None,
-                 block_tables=None):
+                 block_tables=None, pass_index=None):
         cfg = self.config
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln1")(x)
-        x = x + Attention(
+
+        def joins(out, name):
+            """A sublayer's output as it joins the residual stream."""
+            if cfg.post_norm:
+                out = _norm(cfg, name)(out).astype(cfg.dtype)
+            return out
+
+        h = _norm(cfg, "ln1")(x)
+        x = x + joins(Attention(
             cfg, deterministic=self.deterministic, decode=self.decode,
             prefill=self.prefill, name="attn",
-        )(h, position_offset, positions, block_tables)
-        h = nn.LayerNorm(dtype=jnp.float32, name="ln2")(x)
+        )(h, position_offset, positions, block_tables, pass_index),
+            "ln1_post")
+        h = _norm(cfg, "ln2")(x)
         if self.use_moe:
             from pytorch_distributed_tpu.models.moe import MoEMLP
 
@@ -644,23 +740,26 @@ class Block(nn.Module):
             )(h)
             if cfg.dropout:  # residual dropout, same placement as dense MLP
                 out = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(out)
-            return x + out
+            return x + joins(out, "ln2_post")
         if cfg.model_axis:
             from pytorch_distributed_tpu.parallel.tensor import tp_copy, tp_reduce
 
             h = tp_copy(h, cfg.model_axis)  # column-parallel mlp_up
-        h = nn.Dense(
-            cfg.embed_dim * cfg.mlp_ratio // cfg.tp_size, dtype=cfg.dtype,
-            name="mlp_up",
-        )(h)
-        h = nn.gelu(h)
+        width = cfg.mlp_width // cfg.tp_size
+        up = nn.Dense(width, use_bias=cfg.use_bias, dtype=cfg.dtype,
+                      name="mlp_up")(h)
+        if cfg.mlp == "swiglu":
+            h = nn.silu(nn.Dense(width, use_bias=cfg.use_bias,
+                                 dtype=cfg.dtype, name="mlp_gate")(h)) * up
+        else:
+            h = nn.gelu(up)
         # Row-parallel mlp_down: bias-free (see Attention.proj).
         h = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype, name="mlp_down")(h)
         if cfg.model_axis:
             h = tp_reduce(h, cfg.model_axis)
         if cfg.dropout:  # after tp_reduce — see Attention
             h = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(h)
-        return x + h
+        return x + joins(h, "ln2_post")
 
 
 class TransformerLM(nn.Module):
@@ -678,8 +777,14 @@ class TransformerLM(nn.Module):
                  train: bool = True, decode: bool = False,
                  prefill: bool = False, positions: jax.Array | None = None,
                  return_hidden: bool = False,
-                 block_tables: jax.Array | None = None):
+                 block_tables: jax.Array | None = None,
+                 return_gates: bool = False):
         cfg = self.config
+        if return_gates and cfg.ut_steps == 1:
+            raise ValueError(
+                "return_gates= is the looped stack's (ut_steps > 1): one "
+                "exit gate a pass"
+            )
         # Dropout is active only when train=True AND an rng is provided
         # (apply(..., rngs={"dropout": key}) — train/lm.py derives the key
         # from (seed, step, shard coords) so resumed runs are bit-identical).
@@ -757,13 +862,65 @@ class TransformerLM(nn.Module):
         # rope: no wpe table — Attention rotates q/k from the same pos
         if cfg.dropout and not inference:
             x = nn.Dropout(cfg.dropout, deterministic=deterministic)(x)
-        for i in range(cfg.num_layers):
-            use_moe = bool(cfg.n_experts) and (i % cfg.moe_every == cfg.moe_every - 1)
-            x = Block(
-                cfg, use_moe=use_moe, deterministic=deterministic,
-                decode=decode, prefill=prefill, name=f"block{i}",
-            )(x, position_offset, pos, block_tables)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
+
+        def stack():
+            """The layer stack's modules, made once where they run."""
+            return [
+                Block(
+                    cfg, deterministic=deterministic, decode=decode,
+                    prefill=prefill, name=f"block{i}",
+                    use_moe=bool(cfg.n_experts)
+                    and i % cfg.moe_every == cfg.moe_every - 1,
+                )
+                for i in range(cfg.num_layers)
+            ], _norm(cfg, "ln_f")
+
+        def one_pass(modules, x, t):
+            blocks, ln_f = modules
+            for block in blocks:
+                x = block(x, position_offset, pos, block_tables, t)
+            return ln_f(x)
+
+        gates = None
+        if cfg.ut_steps == 1:
+            x = one_pass(stack(), x, None)
+        else:
+            # The stack runs ut_steps times over the same parameters;
+            # ln_f closes every pass and the normed state goes on.
+            init = self.is_initializing()
+            want_gates = return_gates or init
+
+            def gate():
+                return nn.Dense(1, dtype=jnp.float32, name="exit_gate")
+
+            def looped_pass(modules, gate, x, t):
+                z = one_pass(modules, x, t)
+                lam = (jax.nn.sigmoid(gate(z)[..., 0]) if want_gates
+                       else None)
+                return z.astype(cfg.dtype), lam
+
+            inference_cache = (decode or prefill) and not self.variables.get(
+                "cache")
+            if init or inference_cache:
+                # parameters, or a dense cache, are made on this call: a
+                # scan cannot make what it carries or broadcasts, so the
+                # passes run unrolled (an init or an eval_shape, once)
+                modules, g = stack(), gate()
+                lams = []
+                for t in range(cfg.ut_steps):
+                    x, lam = looped_pass(modules, g, x, jnp.int32(t))
+                    lams.append(lam)
+                gates = jnp.stack(lams) if want_gates else None
+            else:
+                # ONE body of num_layers blocks under a ut_steps-trip
+                # loop: the parameters are broadcast into it, the cache
+                # is carried whole and indexed by the pass in place
+                # (Attention), never sliced per pass.
+                x, gates = nn.scan(
+                    lambda _, x, t: looped_pass(stack(), gate(), x, t),
+                    variable_broadcast="params", variable_carry="cache",
+                    split_rngs={"params": False, "dropout": True},
+                )(self, x, jnp.arange(cfg.ut_steps, dtype=jnp.int32))
         head = nn.Dense(
             cfg.vocab_size // cfg.tp_size if vp else cfg.vocab_size,
             use_bias=False, dtype=cfg.dtype, name="lm_head",
@@ -778,7 +935,7 @@ class TransformerLM(nn.Module):
             # default return_hidden=False); apply-time skipping merely
             # leaves the existing lm_head params unused, which flax
             # tolerates — checkpoint layout identical either way.
-            return x
+            return (x, gates) if return_gates else x
         if vp:
             # column-parallel head: replicated input, vocab-sharded
             # output — the f-operator (identity fwd, psum bwd) collects
@@ -796,7 +953,8 @@ class TransformerLM(nn.Module):
             from pytorch_distributed_tpu.parallel.tensor import tp_all_gather
 
             logits = tp_all_gather(logits, cfg.model_axis, dim=-1)
-        return logits
+        # gates: [ut_steps, B, L] float32, the exit gate of every pass
+        return (logits, gates) if return_gates else logits
 
 
 def tiny_config(**overrides) -> TransformerConfig:

@@ -159,7 +159,9 @@ class PagedEngine:
                  kv_dtype: Optional[str] = None,
                  prefix_cache: bool = False,
                  split_s: Optional[int] = None,
-                 autotune_dir: Optional[str] = None):
+                 autotune_dir: Optional[str] = None,
+                 chunk_bucket_floor: Tuple[int, int] = (1, 1),
+                 max_chunk_jobs: Optional[int] = None):
         from pytorch_distributed_tpu.models.generate import (
             _validate_sampling,
             _validate_serving_config,
@@ -235,6 +237,27 @@ class PagedEngine:
         self.top_k = top_k
         # Per-slot table width: enough blocks for a full-capacity request.
         self.table_width = -(-config.max_seq_len // block_len)
+        # Fewer chunk buckets. Every bucket is a program to trace,
+        # compile and load, and a chunk program that is bound by reading
+        # the weights pays little for a padded job or a wider slice. No
+        # chunk program is narrower than ``chunk_bucket_floor`` (jobs,
+        # table-slice width), and none runs more than ``max_chunk_jobs``
+        # jobs: the caller plans no more a tick (the scheduler prefills
+        # the oldest prompts first and the rest wait a tick).
+        if max_chunk_jobs is not None and max_chunk_jobs < 1:
+            raise ValueError(
+                f"max_chunk_jobs must be >= 1, got {max_chunk_jobs}")
+        self.max_chunk_jobs = min(max_chunk_jobs or n_slots, n_slots)
+        k_floor, w_floor = chunk_bucket_floor
+        if min(k_floor, w_floor) < 1 or (k_floor, w_floor) != (
+                _pow2_bucket(k_floor), _pow2_bucket(w_floor)):
+            raise ValueError(
+                f"chunk_bucket_floor {chunk_bucket_floor!r} must be two "
+                f"powers of two"
+            )
+        self._chunk_floor = (
+            min(k_floor, _pow2_bucket(self.max_chunk_jobs)),
+            min(w_floor, self.table_width))
         if n_blocks is None:
             # Capacity parity with the dense layout (every slot can hold
             # max_seq_len), plus the trash block.
@@ -250,11 +273,24 @@ class PagedEngine:
         )
         # (the span is here and not in kv_pool: pool_block_bytes traces
         # init_paged_cache under eval_shape)
-        with spans.tracer().span("pool.alloc", blocks=n_blocks):
+        with spans.tracer().span("pool.alloc", blocks=n_blocks) as alloc:
             self.cache = init_paged_cache(init_cfg, params, n_blocks,
                                           block_len, kv_dtype=kv_dtype)
             self.logits = jnp.zeros((n_slots, config.vocab_size),
                                     jnp.float32)
+            # device bytes one block holds across every cache leaf (K +
+            # V + scale siblings). A looped config's cache layers (an
+            # entry per pass and layer) outnumber its weight layers; the
+            # leaves count them all.
+            self._per_block_bytes = sum(
+                leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(self.cache)
+            ) // n_blocks
+            alloc.args.update(
+                weight_layers=config.num_layers,
+                cache_layers=config.num_layers * config.ut_steps,
+                block_bytes=self._per_block_bytes,
+            )
 
         self._chunk_fns: Dict[Tuple[int, int], callable] = {}
         self._decode_fn = None
@@ -290,7 +326,6 @@ class PagedEngine:
         )
         self._copy_fn = None
         self._cow_copies = 0
-        self._per_block_bytes: Optional[int] = None
         # buckets whose program has EXECUTED at least once (call path hot:
         # the next call pays zero compile/load) — run_chunks/decode and the
         # execute-mode warmups add to these; AOT-only warmup does not (the
@@ -481,31 +516,37 @@ class PagedEngine:
         will compile/run for ``jobs`` — THE bucketing definition; the
         registry enumeration and the scheduler's cold-request accounting
         both read it from here."""
-        k_pad = _pow2_bucket(len(jobs))
         max_end = max(j.start + self.chunk for j in jobs)
-        wp = min(_pow2_bucket(-(-max_end // self.block_len)),
-                 self.table_width)
-        return k_pad, wp
+        return self._floored(
+            _pow2_bucket(len(jobs)),
+            min(_pow2_bucket(-(-max_end // self.block_len)),
+                self.table_width))
+
+    def _floored(self, k_pad: int, wp: int) -> Tuple[int, int]:
+        """The bucket that runs a (k_pad, wp) bucket's jobs under
+        ``chunk_bucket_floor``."""
+        k_floor, w_floor = self._chunk_floor
+        return max(k_pad, k_floor), max(wp, w_floor)
 
     def chunk_buckets(self) -> List[Tuple[int, int]]:
         """Every (k_pad, wp) bucket this engine can ever ask for: job
-        counts are 1..n_slots (one chunk job per resident slot, pow2-
-        padded) and table-slice widths are the pow2 widths clipped to
+        counts are 1..max_chunk_jobs (at most one chunk job per resident
+        slot, pow2-padded) and table-slice widths are the pow2 widths clipped to
         ``table_width`` — exactly the values ``bucket_for`` can produce,
         because admission rejects prompts whose padded length exceeds
         ``max_seq_len`` (so ``max_end`` never needs more than
         ``table_width`` blocks)."""
         ks, k = [], 1
-        while k < self.n_slots:
+        while k < self.max_chunk_jobs:
             ks.append(k)
             k <<= 1
-        ks.append(_pow2_bucket(self.n_slots))
+        ks.append(_pow2_bucket(self.max_chunk_jobs))
         ws, w = [], 1
         while w < self.table_width:
             ws.append(w)
             w <<= 1
         ws.append(self.table_width)
-        return [(k, w) for k in ks for w in sorted(set(ws))]
+        return sorted({self._floored(k, w) for k in ks for w in ws})
 
     @staticmethod
     def export_program_name(n_pad: int) -> str:
@@ -714,8 +755,9 @@ class PagedEngine:
         return jax.tree.map(sds, self.cache), sds(self.logits)
 
     def warm_chunk(self, k_pad: int, wp: int, execute: bool = True):
-        """Force the (k_pad, wp) chunk program compiled before traffic
-        needs it. ``execute=False`` returns the ``Compiled`` (cost-card
+        """Force the chunk program that runs a (k_pad, wp) bucket's jobs
+        (the bucket itself unless ``chunk_bucket_floor`` widens it)
+        compiled before traffic needs it. ``execute=False`` returns the ``Compiled`` (cost-card
         statics); the execute branch returns None.
 
         ``execute=True`` runs it once with inert inputs — every job is a
@@ -734,6 +776,7 @@ class PagedEngine:
         bucket's eventual first call from an XLA compile into a disk
         load.
         """
+        k_pad, wp = self._floored(k_pad, wp)
         fn = self._chunk_fn(k_pad, wp)
         c = self.chunk
         tokens = jnp.zeros((k_pad, c), jnp.int32)
@@ -1170,14 +1213,8 @@ class PagedEngine:
         """Device bytes ``n_blocks`` pool blocks hold across every cache
         leaf (K + V + scale siblings) plus one logits row — the payload
         a swap moves, and the byte side of the swap-vs-recompute
-        decision. Pure shape arithmetic on the live pool (computed once,
-        cached)."""
-        if self._per_block_bytes is None:
-            total = sum(
-                leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree.leaves(self.cache)
-            )
-            self._per_block_bytes = total // self.allocator.n_blocks
+        decision. Pure shape arithmetic on the pool as it was
+        allocated."""
         row = self.logits.size * self.logits.dtype.itemsize // self.n_slots
         return n_blocks * self._per_block_bytes + row
 
@@ -1331,6 +1368,11 @@ class PagedEngine:
         (chunk n+1 attends to chunk n's writes through the pool)."""
         if not jobs:
             return
+        if len(jobs) > self.max_chunk_jobs:
+            raise ValueError(
+                f"{len(jobs)} chunk jobs in one call; max_chunk_jobs is "
+                f"{self.max_chunk_jobs}"
+            )
         c = self.chunk
         for j in jobs:
             if len(j.tokens) != c:

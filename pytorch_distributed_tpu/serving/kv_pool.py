@@ -120,17 +120,36 @@ def pool_scale_dtype(pool_dtype):
 
 
 def pool_leaf_shape(n_blocks: int, block_len: int, h_kv: int,
-                    head_dim: int, *, scale: bool = False):
+                    head_dim: int, *, scale: bool = False, passes: int = 1):
     """THE shape of a pool leaf, written once: a ``key``/``value`` leaf
     is ``[n_blocks, block_len, H_kv·D]`` — heads flattened into the row,
     head ``h`` at lanes ``[h·D, (h+1)·D)`` — and its scale sibling
     (``scale=True``, quantized pools) ``[n_blocks, block_len, H_kv]``.
-    Axis 0 is the block axis and axis 2 the (contiguous) head axis TP
-    shards, for both. The TPU compiler keeps a leaf of this shape
+    Axis 0 is the block axis and the LAST axis the (contiguous) head
+    axis TP shards, for both. The TPU compiler keeps a leaf of this shape
     row-major; given ``[n_blocks, block_len, H_kv, D]`` it picks
     ``n_blocks`` minor-most (D=64 would pad to 128 lanes) and wraps
-    every scatter and gather in copies of the whole leaf."""
-    return (n_blocks, block_len, h_kv if scale else h_kv * head_dim)
+    every scatter and gather in copies of the whole leaf.
+
+    A looped config (``TransformerConfig.ut_steps`` = ``passes`` > 1)
+    keeps a K and a V entry per (pass, layer): its leaf is
+    ``[n_blocks, passes, block_len, ...]``, a block ``passes`` times as
+    large, so everything that moves blocks by their index on axis 0
+    (export, import, swap, block copy, the host tier) moves every pass's
+    share with it; the model reads pass ``t`` of block ``b`` as row
+    ``b·passes + t`` of the same row-major buffer
+    (``models.transformer.Attention``)."""
+    lead = (n_blocks,) if passes == 1 else (n_blocks, passes)
+    return lead + (block_len, h_kv if scale else h_kv * head_dim)
+
+
+def _dense_to_pool(shape, n_blocks: int, block_len: int, **kw):
+    """``pool_leaf_shape`` from a dense decode-cache leaf's shape at
+    batch 1: ``[1, max_seq_len, H_kv, D]``, or ``[passes, 1, max_seq_len,
+    H_kv, D]`` from a looped config."""
+    passes = shape[0] if len(shape) == 5 else 1
+    return pool_leaf_shape(n_blocks, block_len, *shape[-2:], passes=passes,
+                           **kw)
 
 
 def scale_factors(scales: jax.Array) -> jax.Array:
@@ -457,7 +476,8 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     Shapes come from ``eval_shape`` on the dense decode cache at batch 1
     (nothing is traced into a compiled program), then every
     ``[1, max_seq_len, H_kv, D]`` leaf is re-shaped into a
-    ``[n_blocks, block_len, H_kv·D]`` pool (``pool_leaf_shape``) — the
+    ``[n_blocks, block_len, H_kv·D]`` pool (``pool_leaf_shape``; a looped
+    config's ``[passes, 1, ...]`` leaf into ``[n_blocks, passes, ...]``) — the
     per-layer head count and dtype (GQA narrows H_kv; TP shards the
     flattened head axis by placement, ``H_kv/tp·D`` contiguous lanes a
     shard) carry over, so the pool works for every config the dense
@@ -491,7 +511,7 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     if kv_dtype is None:
         return jax.tree.map(
             lambda s: jnp.zeros(
-                pool_leaf_shape(n_blocks, block_len, *s.shape[2:]), s.dtype
+                _dense_to_pool(s.shape, n_blocks, block_len), s.dtype
             ),
             shapes,
         )
@@ -510,12 +530,10 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
             for name in ("key", "value"):
                 s = node[name]
                 out[name] = jnp.zeros(
-                    pool_leaf_shape(n_blocks, block_len, *s.shape[2:]),
-                    pool_dt,
+                    _dense_to_pool(s.shape, n_blocks, block_len), pool_dt,
                 )
                 out[name + "_scale"] = jnp.zeros(
-                    pool_leaf_shape(n_blocks, block_len, *s.shape[2:],
-                                    scale=True),
+                    _dense_to_pool(s.shape, n_blocks, block_len, scale=True),
                     sc_dt,
                 )
             return out
@@ -548,15 +566,17 @@ def pool_block_bytes(config, params, block_len: int,
 
 
 def paged_cache_specs(config, cache):
-    """TP placement for the pool: the HEAD axis — axis 2 of every leaf,
-    ``H_kv·D`` lanes of a value leaf (heads contiguous, so a shard holds
-    ``H_kv/tp`` whole heads) and ``H_kv`` of a scale sibling — shards
-    over the model axis, exactly the slice each shard's Attention
+    """TP placement for the pool: the HEAD axis — the last of every
+    leaf, ``H_kv·D`` lanes of a value leaf (heads contiguous, so a shard
+    holds ``H_kv/tp`` whole heads) and ``H_kv`` of a scale sibling —
+    shards over the model axis, exactly the slice each shard's Attention
     computes; the dense cache's rule (``models.generate._cache_specs``)
     with its trailing D entry folded into the head axis."""
     from jax.sharding import PartitionSpec as P
 
-    return jax.tree.map(lambda _: P(None, None, config.model_axis), cache)
+    return jax.tree.map(
+        lambda leaf: P(*[None] * (leaf.ndim - 1), config.model_axis), cache
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -264,7 +264,9 @@ class Scheduler:
                  reqtrace=None, ledger=None, host_pool=None,
                  prefix_cache: bool = False, blocksan=None,
                  split_s: Optional[int] = None,
-                 autotune_dir: Optional[str] = None):
+                 autotune_dir: Optional[str] = None,
+                 chunk_bucket_floor: Tuple[int, int] = (1, 1),
+                 max_chunk_jobs: Optional[int] = None):
         from pytorch_distributed_tpu.serving.engine import PagedEngine
         from pytorch_distributed_tpu.serving.kv_pool import HostBlockStore
 
@@ -291,6 +293,8 @@ class Scheduler:
             gather_impl=gather_impl, kv_dtype=kv_dtype,
             prefix_cache=prefix_cache, split_s=split_s,
             autotune_dir=autotune_dir,
+            chunk_bucket_floor=chunk_bucket_floor,
+            max_chunk_jobs=max_chunk_jobs,
         )
         # ---- prefix-sharing tier (round 17): radix reuse + COW ----
         self.prefix_cache = prefix_cache
@@ -610,12 +614,14 @@ class Scheduler:
         return [s for s in range(self.n_slots)
                 if s not in self.resident and s not in self._swap_slots]
 
-    def _admit(self) -> None:
+    def _admit(self) -> bool:
         """Admit up to ``admit_per_step`` queue-head requests that can be
         served now. Strict FIFO: the first request that cannot get a slot
-        or a chain stops admission for this step."""
+        or a chain stops admission for this step. True where the queue's
+        head had a slot and found too few blocks: the pool, not the
+        slots, held it back."""
         if self.draining:
-            return
+            return False
         free = self._free_slots()
         admitted = 0
         now = time.perf_counter()
@@ -656,7 +662,7 @@ class Scheduler:
                         and self._oom_preempted_for != req.rid):
                     if self.preempt_lru(reason="admission-oom") is not None:
                         self._oom_preempted_for = req.rid
-                break
+                return True
             self.queue.popleft()
             free.pop(0)
             req.slot = slot
@@ -695,6 +701,7 @@ class Scheduler:
                     prefix_covered=req.prefill_done or None,
                 )
             admitted += 1
+        return False
 
     # ---- pressure tier: preempt, park, restore (round 13) ----------------
 
@@ -1085,9 +1092,15 @@ class Scheduler:
 
         c = self.engine.chunk
         jobs = []
-        for slot, req in sorted(self.resident.items()):
-            if req.prefill_done >= req.length:
-                continue
+        pending = [(slot, req) for slot, req in sorted(self.resident.items())
+                   if req.prefill_done < req.length]
+        if len(pending) > self.engine.max_chunk_jobs:
+            # more prompts than one chunk program takes: the oldest
+            # first, the rest wait a tick
+            pending = sorted(pending, key=lambda p: p[1].rid)
+            pending = sorted(pending[:self.engine.max_chunk_jobs],
+                             key=lambda p: p[0])
+        for slot, req in pending:
             start = req.prefill_done
             seg = req.tokens[start:start + c]
             tokens = np.zeros((c,), np.int32)
@@ -1129,9 +1142,10 @@ class Scheduler:
             # ahead of the queue, before its next decode tick
             self._finalize_swaps()
             self._restore_parked()
-        with tr.span("sched.admit", queued=len(self.queue)), \
+        with tr.span("sched.admit", queued=len(self.queue)) as admit, \
                 self.ledger.host("admission/gate", self.replica_id):
-            self._admit()
+            admit.args["waited"] = self._admit()
+            admit.args["free_blocks"] = self.engine.allocator.available
         with tr.span("sched.chunk_plan"):
             jobs = self._chunk_jobs()
         if jobs:

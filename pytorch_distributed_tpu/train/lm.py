@@ -107,6 +107,9 @@ TRANSFORMER_TP_RULES = (
     (r"attn/proj/kernel", P(MODEL_AXIS, None, None)),  # [H,D,E] → H
     (r"mlp_up/kernel", P(None, MODEL_AXIS)),  # [E,4E] → 4E
     (r"mlp_up/bias", P(MODEL_AXIS,)),  # [4E]
+    # the gated MLP's second column-parallel input projection (mlp="swiglu")
+    (r"mlp_gate/kernel", P(None, MODEL_AXIS)),
+    (r"mlp_gate/bias", P(MODEL_AXIS,)),
     (r"mlp_down/kernel", P(MODEL_AXIS, None)),  # [4E,E] → 4E
 )
 

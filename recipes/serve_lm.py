@@ -156,6 +156,17 @@ def _parse(argv=None) -> argparse.Namespace:
                    help="prefill chunk length (paged) / bucket (dense)")
     p.add_argument("--admit-per-step", type=int, default=4,
                    help="max admissions per scheduler tick")
+    p.add_argument("--chunk-bucket-floor", type=int, nargs=2,
+                   default=(1, 1), metavar=("JOBS", "WIDTH"),
+                   help="narrowest chunk-prefill bucket (padded jobs, "
+                        "table-slice width in blocks; powers of two): "
+                        "fewer programs to compile and load at start-up "
+                        "for some padding a chunk")
+    p.add_argument("--max-chunk-jobs", type=int, default=None,
+                   help="most prompts one chunk-prefill program takes a "
+                        "tick (the oldest first; default: every slot): "
+                        "with --chunk-bucket-floor it bounds the chunk "
+                        "programs to compile")
     p.add_argument("--gather-impl", choices=("dense", "pallas"),
                    default=None,
                    help="paged KV gather spelling: 'dense' jnp.take or "
@@ -274,12 +285,27 @@ def _parse(argv=None) -> argparse.Namespace:
     p.add_argument("--http-duration", type=float, default=10.0,
                    help="seconds to keep the front door up "
                         "(--http-port)")
+    p.add_argument("--model-json", default=None, metavar="FILE",
+                   help="build the model from a JSON file's ``program`` "
+                        "block (TransformerConfig's fields by name; with "
+                        "--tiny its ``tiny`` block's), e.g. "
+                        "perfbench/configs/ouro-2.6b.json, instead of the "
+                        "default 12-layer GPT-2 block")
     return p.parse_args(argv)
 
 
 def _model(args):
     tp = dict(model_axis="model", tp_size=args.tp) if args.tp > 1 else {}
-    if args.tiny:
+    if args.model_json:
+        import json
+
+        with open(args.model_json) as f:
+            described = json.load(f)
+        if args.tiny:
+            described = described["tiny"]
+        cfg = TransformerConfig(**described["program"], attention="dense",
+                                dropout=0.0, **tp)
+    elif args.tiny:
         cfg = tiny_config(attention="dense", max_seq_len=128, **tp)
     else:
         cfg = TransformerConfig(
@@ -395,6 +421,8 @@ def main() -> None:
             gather_impl=args.gather_impl, kv_dtype=args.kv_dtype,
             prefix_cache=args.prefix_cache, split_s=args.split_s,
             autotune_dir=args.autotune_dir,
+            chunk_bucket_floor=tuple(args.chunk_bucket_floor),
+            max_chunk_jobs=args.max_chunk_jobs,
             **pressure_kw,
         )
         if args.warmup:
@@ -499,6 +527,8 @@ def main() -> None:
             swap_policy=args.swap_policy,
             prefix_cache=args.prefix_cache, split_s=args.split_s,
             autotune_dir=args.autotune_dir,
+            chunk_bucket_floor=tuple(args.chunk_bucket_floor),
+            max_chunk_jobs=args.max_chunk_jobs,
         )
         if args.warmup:
             # everything foreground + executed inert: the serve loop below

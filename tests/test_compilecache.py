@@ -152,6 +152,103 @@ def test_serving_coverage_guard_passes_after_traffic():
     reg.assert_covers(s.engine.compiled_program_names())
 
 
+@pytest.mark.parametrize("floor,buckets", [
+    ((1, 1), 12),  # k in 1, 2, 4 and w in 1, 2, 4, 6
+    ((2, 4), 4),  # k in 2, 4 and w in 4, 6
+    ((8, 64), 1),  # clipped to the engine's widest bucket: (4, 6)
+])
+def test_a_bucket_floor_leaves_fewer_chunk_programs(floor, buckets):
+    """``chunk_bucket_floor`` widens every bucket to at least (jobs,
+    width): ``bucket_for``, the enumeration, the registry and ``warm_chunk``
+    agree on the program that runs a batch of jobs."""
+    cfg, params = _lm()
+    engine = PagedEngine(cfg, params, n_slots=3, block_len=16,
+                         prefill_chunk=32, chunk_bucket_floor=floor)
+    assert len(engine.chunk_buckets()) == buckets
+    k_floor, w_floor = min(floor[0], 4), min(floor[1], engine.table_width)
+    assert min(k for k, _ in engine.chunk_buckets()) == k_floor
+    assert min(w for _, w in engine.chunk_buckets()) == w_floor
+    reg = serving_registry(engine)
+
+    class _Job:
+        def __init__(self, start):
+            self.start = start
+
+    for k in range(1, engine.n_slots + 1):
+        for start in range(0, cfg.max_seq_len - engine.chunk + 1,
+                           engine.chunk):
+            bucket = engine.bucket_for([_Job(start)] * k)
+            assert bucket in engine.chunk_buckets()
+            assert reg.predicts(engine.chunk_program_name(*bucket))
+    # asked for the narrowest bucket, the warm-up warms the one that runs
+    engine.warm_chunk(1, 1)
+    assert engine.has_chunk_program(*min(engine.chunk_buckets()))
+    assert engine.compiled_program_names() == [
+        engine.chunk_program_name(*min(engine.chunk_buckets()))]
+
+
+def test_a_bucket_floor_serves_the_same_streams():
+    cfg, params = _lm()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (6, 20, 40, 33)]
+    streams = []
+    for floor in ((1, 1), (2, 4)):
+        s = Scheduler(cfg, params, n_slots=2, block_len=16,
+                      prefill_chunk=32, chunk_bucket_floor=floor)
+        rids = [s.submit(p, 4) for p in prompts]
+        out = s.drain()
+        streams.append([[int(t) for t in out[r]] for r in rids])
+        names = [n for n in s.engine.compiled_program_names()
+                 if n.startswith("chunk_prefill")]
+        assert set(names) <= {s.engine.chunk_program_name(*b)
+                              for b in s.engine.chunk_buckets()}
+        assert s.engine.allocator.in_use == 0
+    assert streams[0] == streams[1]
+    assert names == ["chunk_prefill[k=2,w=4]"]
+
+
+def test_a_cap_on_chunk_jobs_bounds_the_programs_and_keeps_the_streams():
+    """``max_chunk_jobs``: the scheduler prefills the oldest prompts first
+    and the rest wait a tick, so no chunk program is wider than the cap
+    and every stream is what it was."""
+    cfg, params = _lm()
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (40, 6, 70, 33, 20)]
+    streams = {}
+    for cap in (None, 1, 2):
+        s = Scheduler(cfg, params, n_slots=4, block_len=16,
+                      prefill_chunk=32, max_chunk_jobs=cap)
+        assert max(k for k, _ in s.engine.chunk_buckets()) == (cap or 4)
+        rids = [s.submit(p, 4) for p in prompts]
+        sizes, run_chunks = [], s.engine.run_chunks
+        s.engine.run_chunks = lambda jobs: (sizes.append(len(jobs)),
+                                            run_chunks(jobs))
+        out = s.drain()
+        streams[cap] = [[int(t) for t in out[r]] for r in rids]
+        assert max(sizes) == (cap or 4)  # four prompts are admitted at once
+        ks = {int(n.split("k=")[1].split(",")[0])
+              for n in s.engine.compiled_program_names()
+              if n.startswith("chunk_prefill")}
+        assert max(ks) == (cap or 4)
+        serving_registry(s.engine).assert_covers(
+            s.engine.compiled_program_names())
+        assert s.engine.allocator.in_use == 0
+    assert streams[None] == streams[1] == streams[2]
+    with pytest.raises(ValueError, match="max_chunk_jobs"):
+        s.engine.run_chunks([None] * 3)
+    with pytest.raises(ValueError, match="max_chunk_jobs"):
+        PagedEngine(cfg, params, n_slots=2, max_chunk_jobs=0)
+
+
+@pytest.mark.parametrize("floor", [(3, 4), (4, 0), (0, 1)])
+def test_a_bucket_floor_is_two_powers_of_two(floor):
+    cfg, params = _lm()
+    with pytest.raises(ValueError, match="chunk_bucket_floor"):
+        PagedEngine(cfg, params, n_slots=2, chunk_bucket_floor=floor)
+
+
 # ---------------------------------------------------------------------------
 # scheduler cold-request honesty + warmup
 # ---------------------------------------------------------------------------

@@ -220,11 +220,12 @@ POOL_CELL = dict(heads=16, head_dim=64, slots=64, blocks=2561, block_len=16,
                  chunk=32, max_seq_len=1024)
 
 
-def _engine_program(v5e, program):
+def _engine_program(v5e, program, cell=None, **block):
     """``PagedEngine``'s decode tick or its ``(4, 8)`` chunk program,
     lowered for the described chip from shapes alone: the engine is built
-    on a two-block pool and the program takes the cell's 2,561-block pool
-    as an aval (a program does not hold the pool's size)."""
+    on a two-block pool and the program takes the cell's whole pool as an
+    aval (a program does not hold the pool's size). ``block`` describes
+    another block kind than GPT-2's."""
     from pytorch_distributed_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -232,11 +233,11 @@ def _engine_program(v5e, program):
     from pytorch_distributed_tpu.serving.engine import PagedEngine
     from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
 
-    c = POOL_CELL
+    c = cell or POOL_CELL
     cfg = TransformerConfig(
         vocab_size=512, num_layers=2, num_heads=c["heads"],
         embed_dim=c["heads"] * c["head_dim"], max_seq_len=c["max_seq_len"],
-        dropout=0.0, dtype=jnp.bfloat16, attention="dense",
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense", **block,
     )
     params = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
@@ -296,3 +297,56 @@ def test_pool_leaves_stay_row_major_and_uncopied(v5e, program):
         if m and math.prod(map(int, m.group(2).split(","))) == leaf.size:
             moved.append(m.group(1))
     assert not moved, f"{program}: pool-sized copies {moved}"
+
+
+# ---- a looped stack's pool and loop on the chip (PR 27) -------------------
+
+#: ouro-2.6b.reason-backlog's attention, pool and block (perfbench/cells,
+#: perfbench/configs), on 2 layers and a small vocabulary
+LOOPED_CELL = dict(heads=16, head_dim=128, slots=16, blocks=289,
+                   block_len=16, chunk=32, max_seq_len=640)
+LOOPED_BLOCK = dict(norm="rmsnorm", mlp="swiglu", mlp_dim=5632,
+                    post_norm=True, use_bias=False, pos_embedding="rope",
+                    rope_theta=1e6)
+PASSES = 4
+
+
+@pytest.mark.parametrize("program", ["decode_tick", "chunk_prefill[k=4,w=8]"])
+def test_looped_programs_index_the_pool_in_place_under_one_loop(v5e, program):
+    """A looped stack's programs hold the layers ONCE, under a loop of
+    ``ut_steps`` trips, and each pass scatters into and gathers from the
+    carried ``[n_blocks, passes, block_len, H_kv*D]`` leaf in place: the
+    leaf enters row-major, nothing the size of a leaf or of one pass's
+    share of it is copied, transposed or sliced out, and the program has
+    fewer than twice the one-pass program's instructions (unrolled it
+    would have four times)."""
+    def compiled(passes):
+        lowered, leaves = _engine_program(v5e, program, LOOPED_CELL,
+                                          ut_steps=passes, **LOOPED_BLOCK)
+        return lowered.compile().as_text(), leaves
+
+    def instructions(text):
+        return sum(" = " in line for line in text.splitlines())
+
+    text, leaves = compiled(PASSES)
+    leaf = leaves[0]
+    c = LOOPED_CELL
+    assert all(x.shape == leaf.shape for x in leaves) and leaf.shape == (
+        c["blocks"], PASSES, c["block_len"], c["heads"] * c["head_dim"])
+    dims = ",".join(map(str, leaf.shape))
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text)
+    layouts = re.findall(r"bf16\[%s\]\{([\d,]+)" % dims, entry.group(1))
+    assert len(layouts) == len(leaves) and set(layouts) == {"3,2,1,0"}, layouts
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose|dynamic-slice|dynamic-update-slice)\(",
+                     line)
+        if m and math.prod(map(int, m.group(2).split(","))) in (
+                leaf.size, leaf.size // PASSES):
+            moved.append(m.group(1))
+    assert not moved, f"{program}: pool-sized moves {moved}"
+    assert len(re.findall(r" while\(", text)) == 1
+    one_pass, _ = compiled(1)
+    assert " while(" not in one_pass
+    assert instructions(text) < 2 * instructions(one_pass)
