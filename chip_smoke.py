@@ -19,9 +19,9 @@ recipes have it, weights random from a seed):
 - ``server``: ``recipes/serve_lm.py``'s model behind ``FleetRouter`` →
   ``Scheduler`` → ``PagedEngine`` and the HTTP gateway, checked token for
   token against an in-process ``Scheduler``, then the same prompts
-  through the ``gather_impl="pallas"`` kernel, which must agree with the
-  dense spelling on every first token and on 90% of tokens decoded from
-  the same context;
+  through the paged read's other spelling (the default is the fused
+  kernel on a TPU, the dense gather elsewhere): the two must agree on
+  every first token and on 90% of tokens decoded from the same context;
 - ``pool``: the server's K/V pool alone. What a bf16, an int8 and an fp8
   pool take of the device's memory beside their logical size (a leaf the chip
   pads or keeps in another layout shows here), and the fused gather
@@ -357,27 +357,37 @@ def server_phase() -> None:
         rec.update(tokens=sum(len(g.get("tokens") or ()) for g in got),
                    programs=len(engine.compiled_program_names()))
 
-        # the fused-gather spelling: same prompts, second Scheduler
-        pallas = Scheduler(cfg, params, gather_impl="pallas", **kw)
-        fused = replay(pallas, prompts, max_new)
-        first_ok = all(f[0] == w[0] for f, w in zip(fused, want))
-        rate = same_context_agreement(pallas, prompts, want, fused)
-        texts = engine_texts(pallas.engine)
-        rec.update(pallas_agreement=round(rate, 4),
-                   pallas_stream_agreement=round(agreement(want, fused), 4),
+        # the paged read's other spelling (unnamed, a decode tick reads
+        # through the fused kernel on a TPU and the dense gather
+        # elsewhere; chunk programs gather dense): same prompts, second
+        # Scheduler, every program of which compiles the named spelling
+        default = engine.gather_impl
+        second = Scheduler(
+            cfg, params,
+            gather_impl="dense" if default == "pallas" else "pallas", **kw)
+        other = replay(second, prompts, max_new)
+        first_ok = all(f[0] == w[0] for f, w in zip(other, want))
+        rate = same_context_agreement(second, prompts, want, other)
+        texts = engine_texts(engine if default == "pallas"
+                             else second.engine)
+        rec.update(default_read=default,
+                   pallas_agreement=round(rate, 4),
+                   pallas_stream_agreement=round(agreement(want, other), 4),
                    pallas_first_tokens_agree=first_ok,
                    pallas_programs={n: KERNEL in t for n, t in texts.items()})
         require(rec, first_ok, "pallas and dense disagree on a first token")
         require(rec, rate >= 0.9, f"pallas/dense agreement {rate:.3f} < 0.9")
-        require(rec, pallas.engine.allocator.in_use == 0,
-                "pallas scheduler leaked blocks")
+        require(rec, second.engine.allocator.in_use == 0,
+                "the second scheduler leaked blocks")
         require(rec, "decode_tick" in texts
                 and any(n.startswith("chunk_prefill") for n in texts),
                 f"pallas run compiled only {sorted(texts)}")
         if not ARGS.tiny:
-            require(rec, all(rec["pallas_programs"].values()),
-                    f"no {KERNEL} in {rec['pallas_programs']}: the paged "
-                    "kernel ran interpreted or not at all")
+            require(rec, default == "pallas"
+                    and rec["pallas_programs"]["decode_tick"],
+                    f"no {KERNEL} in the served tick "
+                    f"({rec['pallas_programs']}, read {default}): the "
+                    "paged kernel ran interpreted or not at all")
 
 
 def pool_phase() -> None:

@@ -166,6 +166,49 @@ def pool_heads(k_pool: jax.Array, h: int, d: int):
     return block_len, h_kv
 
 
+#: the paged read's spellings (``paged_attention``'s ``gather_impl``)
+GATHER_IMPLS = ("dense", "pallas")
+#: most query rows a narrow head brings to a kernel step (group x chunk)
+#: for the fused kernel to be the unnamed read: one sublane tile, a decode
+#: tick's. Measured on a v5e (PERF.md section 6, PR 28): at 8 rows a live
+#: block costs the kernel 1.0 us and a dead one 0.1 us where the dense
+#: gather pays 1.4 us for either, so a decode tick over capacity-wide
+#: tables runs three to five times faster through the kernel; at a
+#: chunk's 32 rows a block costs the kernel 2.8 us, and a chunk program's
+#: table slice is cut to its prompts, so the dense gather wins there.
+KERNEL_MAX_ROWS = 8
+
+
+def default_gather_impl(rows: int = 1) -> str:
+    """The paged read a program compiles when nobody names one, from what
+    the code can see: the fused kernel (``ops.paged_flash``) where the
+    backend is a TPU and a narrow head reads with at most
+    ``KERNEL_MAX_ROWS`` query rows (a decode tick), the dense gather for
+    wider row blocks (chunked prefill) and on every other backend, where
+    the kernel would run in the Pallas interpreter."""
+    if jax.default_backend() == "tpu" and rows <= KERNEL_MAX_ROWS:
+        return "pallas"
+    return "dense"
+
+
+def resolve_gather_impl(gather_impl: Optional[str], rows: int = 1) -> str:
+    """``gather_impl`` as a program compiles it: a named spelling wins,
+    None asks ``default_gather_impl`` with the rows of the read at hand
+    (``rows=1``: a decode tick's). The one rule behind
+    ``TransformerConfig.gather_impl`` and every constructor that
+    forwards it."""
+    if gather_impl is None:
+        return default_gather_impl(rows)
+    if gather_impl not in GATHER_IMPLS:
+        raise ValueError(
+            f"gather_impl {gather_impl!r} must be None (the backend and "
+            "the rows decide), 'dense' (jnp.take gather) or 'pallas' "
+            "(fused ops.paged_flash kernel); see compilecache/registry.py "
+            "for the bucket enumeration both stay in sync with"
+        )
+    return gather_impl
+
+
 def paged_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -174,7 +217,7 @@ def paged_attention(
     q_positions: jax.Array,
     *,
     scale: Optional[float] = None,
-    gather_impl: str = "dense",
+    gather_impl: Optional[str] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     split_s: Optional[int] = None,
@@ -208,17 +251,21 @@ def paged_attention(
         (their logical positions exceed every query position).
       q_positions: ``[B, C]`` int32 absolute positions of the queries;
         key position j is visible to query i iff ``j <= q_positions[i]``.
-      gather_impl: ``"dense"`` — one ``jnp.take`` over the block dim,
-        materializing the gathered KV in HBM (the reference spelling;
-        PERF_NOTES §6's lesson is to change the math XLA sees, not excise
-        ops into custom calls). ``"pallas"`` — the fused gather-attend
-        kernel (``ops.paged_flash``): BlockSpec index maps read the
+      gather_impl: None — the backend and the rows decide
+        (``default_gather_impl``: the fused kernel for a decode tick's
+        rows on a TPU, the dense gather for a chunk's rows and on every
+        other backend); a named spelling wins. ``"pallas"`` — the fused
+        gather-attend kernel (``ops.paged_flash``): BlockSpec index maps read the
         block table directly (scalar prefetch), so pool blocks DMA
-        HBM→VMEM in chain order and the gathered copy never exists;
-        runs the Pallas interpreter on non-TPU backends, so both
-        spellings execute everywhere. Either spelling compiles inside
-        the same engine programs, so the program-registry bucket
-        enumeration (``compilecache.serving_registry`` over
+        HBM→VMEM in chain order, the gathered copy never exists and
+        blocks past a row's frontier do no work; runs the Pallas
+        interpreter on non-TPU backends, so both spellings execute
+        everywhere. ``"dense"`` — one ``jnp.take`` over the block dim,
+        materializing every slot's whole table in HBM as float32 (the
+        reference spelling: 147 of a 148 ms decode tick on a v5e,
+        PERF.md section 5). Either spelling compiles inside the same
+        engine programs, so the program-registry bucket enumeration
+        (``compilecache.serving_registry`` over
         ``PagedEngine.chunk_buckets``) covers both and the warmup
         runtime prewarms whichever the engine was built with.
       k_scale, v_scale: per-(block, slot, head) dequantization scale
@@ -230,8 +277,8 @@ def paged_attention(
         pallas kernel does it block-by-block in VMEM.
       split_s: flash-decoding worker count for the pallas spelling's
         chain sweep (``ops.paged_flash``): None auto-enables when W/B
-        crosses the split threshold, 1 forces the single-worker sweep,
-        S > 1 forces S workers. The dense spelling has no chain sweep
+        crosses the split threshold on a device of two cores, 1 forces
+        the single-worker sweep, S > 1 forces S workers. The dense spelling has no chain sweep
         to split — it ignores this knob.
 
     Returns ``[B, C, H, D]`` in q's dtype. Softmax statistics in fp32.
@@ -241,13 +288,10 @@ def paged_attention(
         scale_factors,
     )
 
-    if gather_impl not in ("dense", "pallas"):
-        raise ValueError(
-            f"gather_impl {gather_impl!r} must be 'dense' (jnp.take "
-            "gather) or 'pallas' (fused ops.paged_flash kernel); see "
-            "compilecache/registry.py for the bucket enumeration both "
-            "stay in sync with"
-        )
+    b, c, h, d = q.shape
+    block_len, h_kv = pool_heads(k_pool, h, d)
+    group = h // h_kv
+    gather_impl = resolve_gather_impl(gather_impl, rows=group * c)
     quantized = is_quantized_pool(k_pool.dtype)
     if bool(quantized) != (k_scale is not None):
         raise ValueError(
@@ -264,9 +308,6 @@ def paged_attention(
             q, k_pool, v_pool, block_tables, q_positions, scale=scale,
             k_scale=k_scale, v_scale=v_scale, split_s=split_s,
         )
-    b, c, h, d = q.shape
-    block_len, h_kv = pool_heads(k_pool, h, d)
-    group = h // h_kv
     w = block_tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
     # Gather the per-request logical KV sequences, then split the

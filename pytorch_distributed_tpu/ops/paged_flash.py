@@ -2,7 +2,9 @@
 KV cache, reading the block tables directly from SMEM.
 
 This is the ``gather_impl="pallas"`` spelling of
-``ops.attention.paged_attention`` (the serving read path). The dense
+``ops.attention.paged_attention`` (the serving read path), and unnamed
+the read of a decode tick on a TPU
+(``ops.attention.default_gather_impl``). The dense
 spelling gathers every request's block chain back into a logical
 ``[B, W·block_len, H_kv, D]`` sequence with ``jnp.take`` — materializing
 the full gathered KV in HBM on every decode tick, the exact cost
@@ -55,8 +57,10 @@ Structure (per the in-tree FlashAttention kernel,
   worker log-sum-exp merge (fp32, outside the kernel) combines them —
   one long-context request (W large, B small) fills the chip instead
   of serializing on the innermost grid axis. ``split_s=None``
-  auto-enables via ``auto_split_s`` when W/B crosses the threshold;
-  ``pl.when`` frontier skipping applies per worker unchanged;
+  auto-enables via ``auto_split_s`` when W/B crosses the threshold on a
+  device of more than one core (a v5e's one core runs the workers in
+  turn: 7-29% slower than the unsplit sweep at the served chunk
+  shapes); ``pl.when`` frontier skipping applies per worker unchanged;
 - the write side has a twin: ``paged_quantize_scatter`` computes
   per-row-per-head scales and the quantized rows in one kernel and
   places them with the in-place ``.at[rows].set`` the raw pools use (a
@@ -96,16 +100,28 @@ SPLIT_THRESHOLD = 8
 MAX_SPLIT = 8
 
 
+def device_cores() -> int:
+    """TensorCores behind one device of the default backend (a TPU
+    device's ``num_cores``: 1 on a v5e, 2 on a megacore chip); 1 where
+    the backend does not say (the CPU)."""
+    return int(getattr(jax.devices()[0], "num_cores", None) or 1)
+
+
 def auto_split_s(w: int, b: int, *, threshold: int = SPLIT_THRESHOLD,
-                 max_split: int = MAX_SPLIT) -> int:
+                 max_split: int = MAX_SPLIT,
+                 cores: Optional[int] = None) -> int:
     """Flash-decoding worker count for a ``[B, W]`` block table: 1 (no
-    split) until ``W / B >= threshold`` — few long chains is the shape
-    where the sequential chain sweep leaves grid workers idle — then
-    ``min(max_split, W)`` so every worker owns at least one block.
-    Static shapes in, static count out: the decision is compiled into
-    the program, and the registry fingerprint keys it via the config's
-    ``split_s`` field."""
-    if w // max(b, 1) < threshold:
+    split) on a device of one core, whose grid workers run one after
+    another so the merge is only extra work (``cores=None`` asks
+    ``device_cores``), and until ``W / B >= threshold`` — few long
+    chains is the shape where the sequential chain sweep leaves a
+    second core idle — then ``min(max_split, W)`` so every worker owns
+    at least one block. Static shapes in, static count out: the
+    decision is compiled into the program, and the registry
+    fingerprint keys it via the config's ``split_s`` field."""
+    if cores is None:
+        cores = device_cores()
+    if cores < 2 or w // max(b, 1) < threshold:
         return 1
     return min(max_split, w)
 
@@ -130,20 +146,21 @@ def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
             ks_all = jnp.exp2(ks_all)
             vs_all = jnp.exp2(vs_all)
     for h in range(h_kv):
-        # Fold the softmax scale into Q (one [R, D] multiply, the flash
-        # kernel's trick), fp32 logits on the MXU.
         q = q_ref[0, h]  # [R, D]
-        q = q * jnp.asarray(scale, q.dtype)
         k = k_ref[0, :, h * d:(h + 1) * d]  # [block_len, D]
         v = v_ref[0, :, h * d:(h + 1) * d]
         if quantized:
             k = k.astype(jnp.float32) * ks_all[:, h:h + 1]
             v = v.astype(jnp.float32) * vs_all[:, h:h + 1]
             q = q.astype(jnp.float32)
+        # fp32 logits on the MXU from the operands as stored, then the
+        # softmax scale on the [R, block_len] logits in fp32: scaling Q
+        # in its own dtype rounds it wherever the scale is no power of
+        # two (D=128), which the dense spelling's fp32 scale never did.
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [R, block_len]
+        ) * scale  # [R, block_len]
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # Frontier mask: key position j visible iff j <= the row's query
         # position. Trash-table entries (unallocated tail) carry logical
@@ -161,6 +178,9 @@ def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
             l_scr[h, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True),
             l_scr.shape[1:],
         )
+        # P rides the MXU in the pool's dtype: fp32 operands here double
+        # a decode step's time on a v5e (1.92 against 1.01 ms a layer at
+        # 64 slots, PERF.md section 6, PR 28)
         acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -253,8 +273,8 @@ def paged_flash_attention(
         for fp8 pools); None for float pools.
       split_s: flash-decoding worker count for the chain sweep. None
         auto-enables (``auto_split_s``: split when W/B crosses the
-        threshold), 1 forces the single-worker sweep, S > 1 splits the
-        chain over S workers with un-normalized (m, l, acc) partials
+        threshold and the device has a second core), 1 forces the
+        single-worker sweep, S > 1 splits the chain over S workers with un-normalized (m, l, acc) partials
         and a second-stage fp32 log-sum-exp merge. The combine is a
         different (but fp32) reduction order than the single sweep, so
         parity is bounded (≤ 1e-3 on fp32 logits), not bit-equal.
@@ -263,6 +283,34 @@ def paged_flash_attention(
 
     Returns ``[B, C, H, D]`` in q's dtype; softmax statistics fp32.
     """
+    b, w, d = q.shape[0], block_tables.shape[1], q.shape[3]
+    if interpret is None:
+        # Mosaic compiles only on TPU; every other backend runs the
+        # interpreter so CPU tier-1 executes this exact call site.
+        interpret = jax.default_backend() != "tpu"
+    if split_s is not None and split_s < 1:
+        raise ValueError(f"split_s must be >= 1, got {split_s}")
+    s_workers = split_s if split_s is not None else auto_split_s(w, b)
+    # What the backend and the device decide is resolved out here and
+    # rides in as static arguments: the traced function is then keyed by
+    # shapes and these alone, so the layers of a program share ONE trace
+    # and ONE lowered function (a program of 24 layers otherwise traces
+    # and lowers the kernel 24 times).
+    return _paged_flash(
+        q, k_pool, v_pool, block_tables, q_positions, k_scale, v_scale,
+        scale=float(scale if scale is not None else d ** -0.5),
+        s_workers=min(s_workers, w),  # every worker owns >= 1 chain block
+        interpret=bool(interpret),
+    )
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "s_workers", "interpret"))
+def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
+                 v_scale, *, scale: float, s_workers: int,
+                 interpret: bool):
+    """``paged_flash_attention`` with everything static decided (a
+    pool that does not fit its queries or scales raises while tracing)."""
     from pytorch_distributed_tpu.serving.kv_pool import is_quantized_pool
 
     b, c, h, d = q.shape
@@ -279,17 +327,8 @@ def paged_flash_attention(
     fp8_scales = bool(
         k_scale is not None and k_scale.dtype == jnp.dtype(jnp.int8)
     )
-    if interpret is None:
-        # Mosaic compiles only on TPU; every other backend runs the
-        # interpreter so CPU tier-1 executes this exact call site.
-        interpret = jax.default_backend() != "tpu"
     group = h // h_kv
     w = block_tables.shape[1]
-    scale = scale if scale is not None else d ** -0.5
-    if split_s is not None and split_s < 1:
-        raise ValueError(f"split_s must be >= 1, got {split_s}")
-    s_workers = split_s if split_s is not None else auto_split_s(w, b)
-    s_workers = min(s_workers, w)  # every worker owns >= 1 chain block
     split = s_workers > 1
     wc = -(-w // s_workers)  # chain blocks per worker (ceil split)
 

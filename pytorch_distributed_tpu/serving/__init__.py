@@ -24,8 +24,10 @@ rows with a fixed pool of KV *blocks* plus per-slot block tables:
 documents the block layout and the admission path.
 
 Round 12: the read path gains its fused Pallas kernel
-(``gather_impl="pallas"`` → ``ops.paged_flash``, no materialized
-gather) and the pool an int8 quantized variant (``kv_dtype="int8"``,
+(``ops.paged_flash``, no materialized gather: the decode tick's read on
+a TPU unless ``gather_impl=`` names a spelling; the ``jnp.take``
+spelling stays the chunk programs' read, and every program's on another
+backend) and the pool an int8 quantized variant (``kv_dtype="int8"``,
 per-row scales, ~2x blocks at fixed bytes) — ANALYSIS.md "Paged
 attention kernel & quantized KV".
 

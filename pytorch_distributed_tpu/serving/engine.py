@@ -16,9 +16,12 @@ every update is in place:
   count.
 - **decode** (``decode``): one token for every slot, exactly the dense
   ``_step_body`` shape but attending through the block table
-  (``ops.attention.paged_attention`` — the dense gather or, with
-  ``gather_impl="pallas"``, the fused ``ops.paged_flash`` kernel; with
-  ``kv_dtype="int8"`` the pool is quantized with per-row scales).
+  (``ops.attention.paged_attention`` — the fused ``ops.paged_flash``
+  kernel where the backend is a TPU and the dense gather elsewhere,
+  unless ``gather_impl=`` names one, which the chunk programs then
+  compile too: unnamed they gather dense, their rows are a chunk's and
+  their tables cut to the prompts; with ``kv_dtype="int8"`` the pool
+  is quantized with per-row scales).
   Inactive lanes' writes are routed to the trash block by host-side
   table masking, so recycled blocks can never be corrupted by a dead
   lane.
@@ -59,6 +62,7 @@ from pytorch_distributed_tpu.compilecache.aot import (
     program_load,
     program_load_if,
 )
+from pytorch_distributed_tpu.ops.attention import resolve_gather_impl
 from pytorch_distributed_tpu.telemetry import spans
 from pytorch_distributed_tpu.telemetry.overlap import NULL_LEDGER
 
@@ -175,9 +179,11 @@ class PagedEngine:
         # KV gather spelling: an explicit gather_impl= overrides the
         # config field (replaced INTO the config so the model, the
         # registry fingerprint, and this engine agree on one value —
-        # TransformerConfig validates it). kv_dtype="int8" swaps the
-        # pool for the quantized layout (kv_pool.init_paged_cache); the
-        # model's scatter path keys off the pool dtype, nothing else.
+        # TransformerConfig validates it); where neither names one each
+        # program asks ops.attention.default_gather_impl with its own
+        # rows. kv_dtype="int8" swaps the pool for the quantized layout
+        # (kv_pool.init_paged_cache); the model's scatter path keys off
+        # the pool dtype, nothing else.
         if gather_impl is not None and gather_impl != config.gather_impl:
             config = dataclasses.replace(config, gather_impl=gather_impl)
         if kv_dtype not in KV_DTYPES:
@@ -286,10 +292,16 @@ class PagedEngine:
                 leaf.size * leaf.dtype.itemsize
                 for leaf in jax.tree.leaves(self.cache)
             ) // n_blocks
+            # ``read``: the paged read the programs compile;
+            # ``table_blocks``: the blocks a decode tick's tables name,
+            # live or not (the fused kernel's grid steps a layer), which
+            # ``engine.decode.launch``'s ``live_blocks`` is a share of
             alloc.args.update(
                 weight_layers=config.num_layers,
                 cache_layers=config.num_layers * config.ut_steps,
                 block_bytes=self._per_block_bytes,
+                read=self.gather_impl,
+                table_blocks=n_slots * self.table_width,
             )
 
         self._chunk_fns: Dict[Tuple[int, int], callable] = {}
@@ -375,9 +387,14 @@ class PagedEngine:
 
     @property
     def gather_impl(self) -> str:
-        """The KV gather spelling the engine's programs compile with
-        (lives on the config so model, fingerprint, and engine agree)."""
-        return self.config.gather_impl
+        """The KV gather spelling the decode tick compiles with: the one
+        named on the config (so model, fingerprint, and engine agree),
+        else what the backend gives a tick's rows
+        (``ops.attention.resolve_gather_impl``; an unnamed chunk program
+        asks with its own, wider, rows)."""
+        kv = self.config.num_kv_heads or self.config.num_heads
+        return resolve_gather_impl(self.config.gather_impl,
+                                   rows=self.config.num_heads // kv)
 
     def tuned_provenance(self) -> Dict[str, object]:
         """Which kernel config actually served: tuned or default.
@@ -1423,6 +1440,7 @@ class PagedEngine:
         token so the caller can pin completion at its own collect site
         (``DispatchLedger.complete``)."""
         masked = np.where(active[:, None], self.tables, TRASH_BLOCK)
+        positions = np.asarray(positions, np.int32)
         fn = self._decode()
         if self.device is not None:
             # keys are computed arrays; pin them next to the replica's
@@ -1437,12 +1455,17 @@ class PagedEngine:
             # loop's host wall, round-16 profile), and a bare-np jit
             # call would be an IMPLICIT transfer the no_recompile guard
             # rightly rejects.
-            with spans.tracer().span("engine.decode.launch",
-                                     lanes=int(np.count_nonzero(active))), \
+            # live_blocks: the blocks up to each active lane's position,
+            # the part of the tables' ``table_blocks`` a tick has to read
+            with spans.tracer().span(
+                    "engine.decode.launch",
+                    lanes=int(np.count_nonzero(active)),
+                    live_blocks=int(np.sum(
+                        positions[active] // self.block_len + 1))), \
                     program_load_if(not self._hot_decode,
                                     self.DECODE_PROGRAM):
                 positions, active, masked = jax.device_put(
-                    (np.asarray(positions, np.int32), active, masked)
+                    (positions, active, masked)
                 )
                 self.cache, self.logits, positions, tokens = fn(
                     self.params, self.cache, self.logits,

@@ -171,7 +171,10 @@ def _parse(argv=None) -> argparse.Namespace:
                    default=None,
                    help="paged KV gather spelling: 'dense' jnp.take or "
                         "'pallas' fused kernel (ops/paged_flash.py; "
-                        "interpret mode off-TPU)")
+                        "interpret mode off-TPU), for every program. "
+                        "Default: the decode tick reads through the "
+                        "kernel on a TPU; chunked prefill, and every "
+                        "program on another backend, gathers dense")
     p.add_argument("--kv-dtype", choices=("int8", "fp8", "fp8_e5m2"),
                    default=None,
                    help="quantize the KV block pool: 'int8' (+fp32 "

@@ -30,6 +30,7 @@ from pytorch_distributed_tpu.models.transformer import (
 from pytorch_distributed_tpu.ops.attention import paged_attention
 from pytorch_distributed_tpu.ops.paged_flash import (
     auto_split_s,
+    device_cores,
     paged_flash_attention,
     paged_quantize_scatter,
 )
@@ -115,6 +116,37 @@ def test_paged_flash_matches_dense_gather(h_kv, c):
     np.testing.assert_allclose(
         np.asarray(pallas), np.asarray(dense), rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("c", [1, 32])
+def test_paged_flash_matches_dense_at_the_served_shapes(c):
+    """The read the serving cells compile on a TPU against the one they
+    compiled before, at gpt2-medium.chat-backlog's attention (16 heads of
+    64 over a bfloat16 pool, blocks of 16): a decode tick (C=1) and a
+    chunk (C=32), frontiers ragged across the rows, and every table
+    padded past its row's allocation with the trash block, which holds
+    garbage. Both spellings compute in float32 from the same stored
+    bfloat16, so they part only in the order of the sums: a bfloat16 ulp
+    of the output at most."""
+    b, h, d, bl, w = 3, 16, 64, 16, 6
+    rng = np.random.default_rng(28)
+    kp, vp, tables, _ = random_pool(rng, b, h, d, bl, w)
+    kp = kp.at[0].set(37.0).astype(jnp.bfloat16)  # the trash block
+    vp = vp.at[0].set(-53.0).astype(jnp.bfloat16)
+    ends = np.array([w * bl - 1, 41, 17 + c])  # last query's position
+    live = ends // bl + 1  # blocks a row was allocated
+    tables = jnp.where(np.arange(w)[None, :] < live[:, None], tables, 0)
+    q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.bfloat16)
+    q_positions = jnp.asarray(
+        ends[:, None] - np.arange(c)[::-1][None, :], jnp.int32)
+    dense = paged_attention(q, kp, vp, tables, q_positions,
+                            gather_impl="dense")
+    pallas = paged_attention(q, kp, vp, tables, q_positions,
+                             gather_impl="pallas")
+    assert pallas.dtype == dense.dtype == jnp.bfloat16
+    got, want = (np.asarray(x, np.float32) for x in (pallas, dense))
+    assert np.abs(want).max() < 10  # nothing of the trash block came in
+    np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
 
 
 @pytest.mark.parametrize("c", [1, 5])
@@ -447,11 +479,15 @@ def test_auto_split_s_policy():
     W/B crosses the threshold (few long chains), then min(MAX_SPLIT, W)
     so every worker owns >= 1 block; split_s=None in the op resolves
     through it, and split_s < 1 is rejected everywhere it can enter."""
-    assert auto_split_s(64, 2) == 8
-    assert auto_split_s(8, 8) == 1
-    assert auto_split_s(16, 1) == 8
-    assert auto_split_s(7, 1) == 1  # 7 // 1 < 8: below threshold
-    assert auto_split_s(160, 1, max_split=4) == 4
+    assert auto_split_s(64, 2, cores=2) == 8
+    assert auto_split_s(8, 8, cores=2) == 1
+    assert auto_split_s(16, 1, cores=2) == 8
+    assert auto_split_s(7, 1, cores=2) == 1  # 7 // 1 < 8: below threshold
+    assert auto_split_s(160, 1, max_split=4, cores=2) == 4
+    # a device of one core runs its workers one after another: no split
+    assert auto_split_s(64, 2, cores=1) == 1
+    assert device_cores() == 1  # the CPU does not say: one
+    assert auto_split_s(64, 2) == 1
     # op-level: None == the policy's pick, bit-for-bit (same program)
     b, h, h_kv, d, bl, w = 2, 4, 2, 8, 4, 3
     rng = np.random.default_rng(11)
@@ -465,6 +501,23 @@ def test_auto_split_s_policy():
         paged_flash_attention(q, kp, vp, tables, pos, split_s=0)
     with pytest.raises(ValueError, match="split_s"):
         dataclasses.replace(setup(max_seq_len=64)[0], split_s=0)
+
+
+@pytest.mark.parametrize("k,w", [
+    pytest.param(1, 8, id="chat-backlog-k1-w8"),
+    pytest.param(2, 16, id="chat-backlog-k2-w16"),
+    pytest.param(4, 32, id="chat-backlog-k4-w32"),
+    pytest.param(2, 16, id="reason-backlog-k2-w16"),
+])
+def test_chunk_buckets_do_not_split_on_a_one_core_chip(k, w):
+    """The serving cells' chunk programs whose tables are eight times
+    wider than their jobs are many crossed the split threshold, and a
+    v5e (one TensorCore a chip) ran the eight workers in turn and then
+    merged them. The automatic count reads the device's cores: 1 there,
+    the old eight where a second core can take half the chain."""
+    assert w // k >= 8
+    assert auto_split_s(w, k, cores=1) == 1
+    assert auto_split_s(w, k, cores=2) == 8
 
 
 # ---------------------------------------------------------------------------

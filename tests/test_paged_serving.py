@@ -139,6 +139,52 @@ def test_paged_attention_matches_masked_reference(h_kv, c):
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("backend,on_config,to_engine,want", [
+    ("cpu", None, None, "dense"),
+    ("gpu", None, None, "dense"),
+    ("tpu", None, None, "pallas"),
+    ("tpu", "dense", None, "dense"),
+    ("tpu", None, "dense", "dense"),
+    ("cpu", "pallas", None, "pallas"),
+    ("cpu", None, "pallas", "pallas"),
+    ("tpu", "pallas", "dense", "dense"),
+])
+def test_the_backend_decides_the_paged_read_unless_one_is_named(
+        monkeypatch, backend, on_config, to_engine, want):
+    """One rule, ``ops.attention.resolve_gather_impl``: unnamed, a decode
+    tick reads through the fused kernel where the backend is a TPU and
+    through the dense gather on every other, and a chunk's wider rows
+    gather dense everywhere; a spelling named on the config or to a
+    constructor wins for every program (the constructor's over the
+    config's). The engine reports its tick's read and keeps a named
+    spelling on its config, where the fingerprint keys it."""
+    from pytorch_distributed_tpu.ops.attention import (
+        KERNEL_MAX_ROWS,
+        default_gather_impl,
+        resolve_gather_impl,
+    )
+
+    cfg, params = setup()
+    assert cfg.gather_impl is None
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    on_tpu = backend == "tpu"
+    assert default_gather_impl() == ("pallas" if on_tpu else "dense")
+    assert default_gather_impl(KERNEL_MAX_ROWS) == default_gather_impl()
+    assert default_gather_impl(rows=32) == "dense"  # a chunk's rows
+    named = to_engine if to_engine is not None else on_config
+    assert resolve_gather_impl(named) == want
+    if named is not None:
+        assert resolve_gather_impl(named, rows=32) == named
+    if on_config is not None:
+        cfg = dataclasses.replace(cfg, gather_impl=on_config)
+    eng = PagedEngine(cfg, params, 2, block_len=8, prefill_chunk=8,
+                      gather_impl=to_engine)
+    assert eng.gather_impl == want
+    assert eng.config.gather_impl == named
+    with pytest.raises(ValueError, match="gather_impl"):
+        dataclasses.replace(cfg, gather_impl="nope")
+
+
 def test_paged_attention_gather_impl_flag():
     z = jnp.zeros((1, 1, 2, 4))
     pool = jnp.zeros(pool_leaf_shape(2, 4, 2, 4))

@@ -274,6 +274,35 @@ def test_serving_tick_emits_the_names_in_order_within_budget(tracer):
     assert len(loaded) == len(set(loaded)) and "decode_tick" in loaded
 
 
+def test_the_read_and_its_live_share_ride_the_spans(tracer):
+    """What sizes the paged read: ``pool.alloc`` says which spelling the
+    programs compile (``read``) and how many blocks a tick's tables name
+    (``table_blocks``: slots x table width, the fused kernel's grid steps
+    a layer), beside the ``blocks`` it had; each ``engine.decode.launch``
+    says how many of them hold a live position (``live_blocks``, beside
+    ``lanes``)."""
+    cfg, router = _tiny_router()
+    engine = router.replicas[0].engine
+    (alloc,) = tracer.events("pool.alloc")
+    assert alloc.args["read"] == engine.gather_impl == "dense"
+    assert alloc.args["table_blocks"] == 4 * (64 // 8) == engine.tables.size
+    assert alloc.args["blocks"] == engine.allocator.n_blocks
+    router.submit(np.arange(1, 21, dtype=np.int32), 6)  # 20 tokens
+    router.submit(np.arange(1, 6, dtype=np.int32), 6)  # 5
+    for _ in range(6):
+        router.step()
+    ticks = [e.args for e in tracer.events("engine.decode.launch")
+             if e.args["lanes"] == 2]
+    assert ticks
+    # both prompts are in: the first tick with two lanes writes positions
+    # 20 and 5 (blocks of 8: three and one), and a lane's count follows
+    # its position from there
+    assert ticks[0]["live_blocks"] == (20 // 8 + 1) + (5 // 8 + 1)
+    assert all(t["lanes"] <= t["live_blocks"] <= alloc.args["table_blocks"]
+               for t in ticks)
+    assert ticks[-1]["live_blocks"] > ticks[0]["live_blocks"]
+
+
 def test_async_collect_books_the_same_wait_span(tracer):
     """dispatch_tick/collect_tick (the async host path) waits in
     decode_collect: the span has the same name there."""

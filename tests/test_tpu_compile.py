@@ -350,3 +350,56 @@ def test_looped_programs_index_the_pool_in_place_under_one_loop(v5e, program):
     one_pass, _ = compiled(1)
     assert " while(" not in one_pass
     assert instructions(text) < 2 * instructions(one_pass)
+
+
+# ---- the read the served programs compile by default (PR 28) ---------------
+
+CELLS = {"chat-backlog": (POOL_CELL, {}),
+         "reason-backlog": (LOOPED_CELL, dict(ut_steps=PASSES,
+                                              **LOOPED_BLOCK))}
+
+
+@pytest.mark.parametrize("backend,cell,program", [
+    ("tpu", "chat-backlog", "decode_tick"),
+    ("tpu", "chat-backlog", "chunk_prefill[k=4,w=8]"),
+    ("tpu", "reason-backlog", "decode_tick"),
+    ("tpu", "reason-backlog", "chunk_prefill[k=4,w=8]"),
+    ("cpu", "chat-backlog", "decode_tick"),
+    ("cpu", "reason-backlog", "decode_tick"),
+])
+def test_the_served_tick_reads_through_the_kernel_where_the_backend_is_a_tpu(
+        v5e, monkeypatch, backend, cell, program):
+    """Nobody names a ``gather_impl`` here, as the benchmark's jobs and
+    ``recipes/serve_lm.py`` name none. Where the program asks
+    ``jax.default_backend()`` and hears ``tpu`` (steered here, in the
+    test: the compile is for a described chip, the process's backend is
+    the CPU), the decode tick holds the fused kernel, once a layer,
+    inside the loop where the stack is looped, traced and lowered ONCE a
+    program (the layers call one function), and no array the shape of a
+    slot's gathered table. A chunk program's rows are a chunk's, so it
+    gathers dense, as every program does on another backend: no kernel,
+    and the gathered tables are there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    c, block = CELLS[cell]
+    lowered, _ = _engine_program(v5e, program, c, **block)
+    text = lowered.compile().as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    b, w = ((c["slots"], -(-c["max_seq_len"] // c["block_len"]))
+            if program == "decode_tick" else (4, 8))
+    bl, h, d = c["block_len"], c["heads"], c["head_dim"]
+    gathered = {(b, w, bl, h * d), (b * w, bl, h * d), (b, w * bl, h, d)}
+    found = {m.group(1) + m.group(2) for m in re.finditer(
+        r" = (\w+)\[([\d,]+)\]", text)
+        if tuple(map(int, m.group(2).split(","))) in gathered}
+    if backend != "tpu" or program != "decode_tick":
+        assert not calls and found, (calls, found)
+        return
+    assert len(calls) == 2 and all("paged_decode_attn" in x for x in calls)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert not found, f"{cell} {program}: gathered tables {sorted(found)}"
+    if block:  # the kernel runs inside the passes' one loop
+        (name,) = re.findall(r" while\(.*?body=(%[\w.\-]+)", text)
+        start = text.index(f"\n{name} (")
+        body = text[start:text.index("\n}\n", start)]
+        assert body.count('custom_call_target="tpu_custom_call"') == 2
