@@ -31,7 +31,7 @@ recipes have it, weights random from a seed):
   widths on two layers and four passes through ``PagedEngine``: one chunk
   program and one decode tick, each slot's logits against the plain
   reference (``perfbench/references/ouro.py``) on the same bf16 weights;
-  then the ``gather_impl="pallas"`` tick compiled at heads of 128, and
+  then the tick compiled through the fused kernel at heads of 128, and
   whether the chip's compiler took it (reported, not required).
 
 ``--multichip`` runs the paths that exist only across chips, each beside
@@ -283,6 +283,22 @@ def engine_texts(engine) -> dict:
             if s.name in live}
 
 
+@contextlib.contextmanager
+def paged_read(spelling: str):
+    """Every program traced inside reads the pool through ``spelling``.
+    No option names a read: ``ops.attention.default_gather_impl`` chooses
+    it, so the other spelling is built by steering that rule, as the
+    tests do."""
+    from pytorch_distributed_tpu.ops import attention
+
+    rule = attention.default_gather_impl
+    attention.default_gather_impl = lambda rows=1: spelling
+    try:
+        yield
+    finally:
+        attention.default_gather_impl = rule
+
+
 def agreement(a: list, b: list) -> float:
     """Share of positions at which two sets of streams agree."""
     same = sum(x == y for s, t in zip(a, b) for x, y in zip(s, t))
@@ -360,16 +376,17 @@ def server_phase() -> None:
         # the paged read's other spelling (unnamed, a decode tick reads
         # through the fused kernel on a TPU and the dense gather
         # elsewhere; chunk programs gather dense): same prompts, second
-        # Scheduler, every program of which compiles the named spelling
+        # Scheduler, every program of which compiles the other spelling
         default = engine.gather_impl
-        second = Scheduler(
-            cfg, params,
-            gather_impl="dense" if default == "pallas" else "pallas", **kw)
-        other = replay(second, prompts, max_new)
+        with paged_read("dense" if default == "pallas" else "pallas"):
+            second = Scheduler(cfg, params, **kw)
+            other = replay(second, prompts, max_new)
+            rate = same_context_agreement(second, prompts, want, other)
+            if default != "pallas":
+                texts = engine_texts(second.engine)
+        if default == "pallas":
+            texts = engine_texts(engine)
         first_ok = all(f[0] == w[0] for f, w in zip(other, want))
-        rate = same_context_agreement(second, prompts, want, other)
-        texts = engine_texts(engine if default == "pallas"
-                             else second.engine)
         rec.update(default_read=default,
                    pallas_agreement=round(rate, 4),
                    pallas_stream_agreement=round(agreement(want, other), 4),
@@ -458,11 +475,13 @@ def pool_phase() -> None:
                     .reshape(b, width).astype(np.int32))
                 start = rng.integers(0, width * bl - c, (b, 1))
                 pos = jnp.asarray((start + np.arange(c)).astype(np.int32))
-                dense, fused = (
-                    np.asarray(paged_attention(
-                        q, layer["key"], layer["value"], tables, pos,
-                        gather_impl=impl, **scales), np.float32)
-                    for impl in ("dense", "pallas"))
+                reads = []
+                for impl in ("dense", "pallas"):
+                    with paged_read(impl):
+                        reads.append(np.asarray(paged_attention(
+                            q, layer["key"], layer["value"], tables, pos,
+                            **scales), np.float32))
+                dense, fused = reads
                 diff = float(np.abs(dense - fused).max())
                 # bf16 keeps 8 bits: one ulp of the largest output
                 ulp = 2.0 ** (math.floor(math.log2(np.abs(dense).max())) - 7)
@@ -474,8 +493,6 @@ def pool_phase() -> None:
 
 def ouro_phase() -> None:
     """The looped stack through the paged engine at full width."""
-    import dataclasses
-
     import jax.numpy as jnp
 
     from perfbench.harness.manifest import load_json, merged
@@ -502,11 +519,11 @@ def ouro_phase() -> None:
                    heads=cfg.num_heads, embed_dim=cfg.embed_dim,
                    mlp_dim=cfg.mlp_width, vocab=cfg.vocab_size)
 
-        def build(config):
-            return PagedEngine(config, params, slots, n_blocks=33,
+        def build():
+            return PagedEngine(cfg, params, slots, n_blocks=33,
                                block_len=16, prefill_chunk=chunk)
 
-        eng = build(cfg)
+        eng = build()
         prompts = make_prompts(cfg, [chunk, 19])
         jobs = []
         for slot, prompt in enumerate(prompts):
@@ -547,8 +564,8 @@ def ouro_phase() -> None:
         # the fused gather at heads of 128: does the chip's compiler
         # take it inside the four-trip loop? Reported either way.
         try:
-            fused = build(dataclasses.replace(cfg, gather_impl="pallas"))
-            text = fused.warm_decode(execute=False).as_text()
+            with paged_read("pallas"):
+                text = build().warm_decode(execute=False).as_text()
             rec["pallas_tick"] = {"compiled": True,
                                   "kernel_in_program": KERNEL in text}
         except Exception as e:  # the compiler's refusal is the finding

@@ -232,6 +232,15 @@ def serving_registry(engine, extra: Iterable = ()) -> ProgramRegistry:
     so a warmup runner can finish them in the background while serving
     has already started.
     """
+    # what the two rules that choose a paged read answer for this
+    # engine's chunk programs and its decode tick (imported here: the
+    # trainers import this module and run none of it)
+    from pytorch_distributed_tpu.ops import attention, paged_flash
+
+    cfg = engine.config
+    group = cfg.num_heads // (cfg.num_kv_heads or cfg.num_heads)
+    chunk_read = attention.default_gather_impl(group * engine.chunk)
+    split = paged_flash.auto_split_s(engine.table_width, engine.n_slots)
     reg = ProgramRegistry(
         run_fingerprint(
             mesh=engine.mesh,
@@ -242,10 +251,14 @@ def serving_registry(engine, extra: Iterable = ()) -> ProgramRegistry:
                 f"chunk={engine.chunk}",
                 f"temperature={engine.temperature}",
                 f"top_k={engine.top_k}",
-                # program-shape variants (ISSUE 10): the gather spelling
-                # rides in via the config repr (gather_impl field); the
-                # pool quantization changes every program's cache avals,
-                # so artifacts must not be interchangeable across it
+                # program-shape variants: the read each program family
+                # compiles and the tick's flash-decoding workers (an
+                # artifact built under a steered rule, as tests build
+                # them, never loads under another); the pool
+                # quantization changes every program's cache avals, so
+                # artifacts must not be interchangeable across it
+                f"read={engine.gather_impl}/{chunk_read}",
+                f"split={split}",
                 f"kv_dtype={getattr(engine, 'kv_dtype', None)}",
                 f"prefix_cache={getattr(engine, 'prefix_cache', False)}",
                 *extra,

@@ -519,10 +519,7 @@ class ContinuousBatcher:
                  eos_id: Optional[int] = None, mesh=None,
                  cache_layout: str = "paged", block_len: int = 16,
                  n_blocks: Optional[int] = None,
-                 gather_impl: Optional[str] = None,
-                 kv_dtype: Optional[str] = None,
-                 split_s: Optional[int] = None,
-                 autotune_dir: Optional[str] = None):
+                 kv_dtype: Optional[str] = None):
         _validate_serving_config(config, mesh)
         _validate_sampling(config, temperature, top_k)
         if eos_id is not None and not 0 <= eos_id < config.vocab_size:
@@ -542,15 +539,10 @@ class ContinuousBatcher:
         self.top_k = top_k
         self.prefill_bucket = prefill_bucket
         self.cache_layout = cache_layout
-        if cache_layout != "paged" and (gather_impl not in (None, "dense")
-                                        or kv_dtype is not None
-                                        or split_s is not None
-                                        or autotune_dir is not None):
+        if cache_layout != "paged" and kv_dtype is not None:
             raise ValueError(
-                "gather_impl=/kv_dtype=/split_s=/autotune_dir= are "
-                "block-pool knobs (the dense layout has no block tables "
-                "to gather through, no quantized pool, and no chain "
-                "sweep to split); use cache_layout='paged'"
+                "kv_dtype= is a block-pool knob (the dense layout has no "
+                "quantized pool); use cache_layout='paged'"
             )
         if cache_layout == "paged":
             from pytorch_distributed_tpu.serving.engine import PagedEngine
@@ -559,10 +551,8 @@ class ContinuousBatcher:
                 config, params, n_slots, n_blocks=n_blocks,
                 block_len=block_len, prefill_chunk=prefill_bucket,
                 temperature=temperature, top_k=top_k, mesh=mesh,
-                gather_impl=gather_impl, kv_dtype=kv_dtype,
-                split_s=split_s, autotune_dir=autotune_dir,
+                kv_dtype=kv_dtype,
             )
-            self.config = self.engine.config  # gather_impl=/split_s= in
             self.mesh = mesh
             self.params = self.engine.params
             self.positions = np.zeros(n_slots, np.int32)

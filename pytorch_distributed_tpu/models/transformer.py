@@ -24,10 +24,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from pytorch_distributed_tpu.ops import attention as attention_ops
 from pytorch_distributed_tpu.ops.attention import (
     blockwise_attention,
     dense_attention,
-    resolve_gather_impl,
 )
 from pytorch_distributed_tpu.parallel.mesh import SEQ_AXIS
 
@@ -101,29 +101,6 @@ class TransformerConfig:
     moe_top_k: int = 1  # 1 = Switch, 2 = GShard top-2
     expert_axis: Optional[str] = None
     ep_size: int = 1
-    # Paged-serving KV gather spelling (ops.attention.paged_attention):
-    # None = the backend and the rows decide, program by program
-    # (ops.attention.default_gather_impl: the fused kernel for a decode
-    # tick on a TPU, the dense gather for chunked prefill and on every
-    # other backend); "pallas" = the fused block-gather kernel
-    # (ops/paged_flash.py — block tables read by BlockSpec index maps,
-    # online softmax in VMEM; interpret mode off-TPU), "dense" =
-    # jnp.take-over-blocks (every slot's whole table materializes in HBM
-    # as float32). Only the block_tables= serving path reads it;
-    # training/dense-decode configs ignore it. Serving constructors
-    # (PagedEngine/Scheduler/ContinuousBatcher gather_impl=) replace a
-    # NAMED spelling into the config, which also folds it into the
-    # registry run fingerprint (the backend is in there beside it).
-    gather_impl: Optional[str] = None
-    # Flash-decoding split (ops.paged_flash, round 20): the pallas
-    # gather's chain sweep splits across this many grid workers with a
-    # cross-worker log-sum-exp merge. None = auto (split when W/B
-    # crosses ops.paged_flash.SPLIT_THRESHOLD on a device of more than
-    # one core: ops.paged_flash.auto_split_s), 1 = single-worker
-    # sweep, S > 1 = forced. Serving constructors replace it into the
-    # config (split_s=) like gather_impl, so the registry fingerprint
-    # keys the program shape; dense gathers and training ignore it.
-    split_s: Optional[int] = None
     # The block's description. The defaults are the GPT-2 block this
     # module always ran (LayerNorm before each sublayer, a GELU MLP of
     # ``embed_dim * mlp_ratio`` features, biased input projections):
@@ -257,15 +234,6 @@ class TransformerConfig:
             )
         if not 0.0 <= self.dropout < 1.0:
             raise ValueError(f"dropout must be in [0, 1), got {self.dropout}")
-        if self.gather_impl is not None:
-            resolve_gather_impl(self.gather_impl)  # raises on a bad name
-        if self.split_s is not None and (
-            not isinstance(self.split_s, int) or self.split_s < 1
-        ):
-            raise ValueError(
-                f"split_s {self.split_s!r} must be None (auto) or an "
-                "int >= 1 (flash-decoding worker count; ops.paged_flash)"
-            )
 
     @property
     def mlp_width(self) -> int:
@@ -413,10 +381,11 @@ class Attention(nn.Module):
             # block ids, and never slices its share out of the pool.
             stored = ck.value.shape
             block_len = stored[-2]
-            # an unnamed read asks the backend and this program's rows,
-            # once for the scatter and the read
-            gather_impl = resolve_gather_impl(
-                cfg.gather_impl, rows=l * (q.shape[2] // kv_heads))
+            # the backend and this program's rows choose the read, once
+            # for the scatter and the read (looked up through the module:
+            # a test steers the rule there)
+            gather_impl = attention_ops.default_gather_impl(
+                rows=l * (q.shape[2] // kv_heads))
 
             def pool_view(x):
                 return x.reshape((-1,) + x.shape[-2:]) if looped else x
@@ -451,7 +420,7 @@ class Attention(nn.Module):
                 # also reads quantized KV — the same values every later
                 # chunk and decode tick will see, so the stream has ONE
                 # consistent quantization, not an exact-then-quantized
-                # seam. With gather_impl="pallas" the quantization is
+                # seam. Where the read is the kernel the quantization is
                 # one kernel (ops.paged_flash.paged_quantize_scatter:
                 # rows and scales together, placed by the same in-place
                 # .at[].set); the jnp spelling below is the dense/
@@ -483,7 +452,7 @@ class Attention(nn.Module):
                 k_pool, v_pool, k_scale, v_scale = pools
                 out = paged_attention(
                     q, k_pool, v_pool, block_tables, p,
-                    gather_impl=gather_impl, split_s=cfg.split_s,
+                    gather_impl=gather_impl,
                     k_scale=k_scale, v_scale=v_scale,
                 )
                 for c, pool in zip((ck, cv, cks, cvs), pools):
@@ -497,7 +466,7 @@ class Attention(nn.Module):
                 ].set(v.astype(cfg.dtype).reshape(b * l, kv_heads * head_dim))
                 out = paged_attention(
                     q, k_pool, v_pool, block_tables, p,
-                    gather_impl=gather_impl, split_s=cfg.split_s,
+                    gather_impl=gather_impl,
                 )
                 ck.value = k_pool.reshape(stored)
                 cv.value = v_pool.reshape(stored)

@@ -191,24 +191,6 @@ def default_gather_impl(rows: int = 1) -> str:
     return "dense"
 
 
-def resolve_gather_impl(gather_impl: Optional[str], rows: int = 1) -> str:
-    """``gather_impl`` as a program compiles it: a named spelling wins,
-    None asks ``default_gather_impl`` with the rows of the read at hand
-    (``rows=1``: a decode tick's). The one rule behind
-    ``TransformerConfig.gather_impl`` and every constructor that
-    forwards it."""
-    if gather_impl is None:
-        return default_gather_impl(rows)
-    if gather_impl not in GATHER_IMPLS:
-        raise ValueError(
-            f"gather_impl {gather_impl!r} must be None (the backend and "
-            "the rows decide), 'dense' (jnp.take gather) or 'pallas' "
-            "(fused ops.paged_flash kernel); see compilecache/registry.py "
-            "for the bucket enumeration both stay in sync with"
-        )
-    return gather_impl
-
-
 def paged_attention(
     q: jax.Array,
     k_pool: jax.Array,
@@ -266,8 +248,8 @@ def paged_attention(
         PERF.md section 5). Either spelling compiles inside the same
         engine programs, so the program-registry bucket enumeration
         (``compilecache.serving_registry`` over
-        ``PagedEngine.chunk_buckets``) covers both and the warmup
-        runtime prewarms whichever the engine was built with.
+        ``PagedEngine.chunk_buckets``) covers both. The model passes
+        the rule's answer; only kernel-level callers and tests name one.
       k_scale, v_scale: per-(block, slot, head) dequantization scale
         siblings ``[n_blocks, block_len, H_kv]`` — required iff the
         pools are quantized (``serving.kv_pool`` ``kv_dtype="int8"``:
@@ -291,7 +273,14 @@ def paged_attention(
     b, c, h, d = q.shape
     block_len, h_kv = pool_heads(k_pool, h, d)
     group = h // h_kv
-    gather_impl = resolve_gather_impl(gather_impl, rows=group * c)
+    if gather_impl is None:
+        gather_impl = default_gather_impl(rows=group * c)
+    elif gather_impl not in GATHER_IMPLS:
+        raise ValueError(
+            f"gather_impl {gather_impl!r} must be None (the backend and "
+            "the rows decide), 'dense' (jnp.take gather) or 'pallas' "
+            "(fused ops.paged_flash kernel)"
+        )
     quantized = is_quantized_pool(k_pool.dtype)
     if bool(quantized) != (k_scale is not None):
         raise ValueError(

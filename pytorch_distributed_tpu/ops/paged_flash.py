@@ -117,8 +117,8 @@ def auto_split_s(w: int, b: int, *, threshold: int = SPLIT_THRESHOLD,
     chains is the shape where the sequential chain sweep leaves a
     second core idle — then ``min(max_split, W)`` so every worker owns
     at least one block. Static shapes in, static count out: the
-    decision is compiled into the program, and the registry
-    fingerprint keys it via the config's ``split_s`` field."""
+    decision is compiled into the program, and the serving registry's
+    fingerprint carries a decode tick's count."""
     if cores is None:
         cores = device_cores()
     if cores < 2 or w // max(b, 1) < threshold:

@@ -25,11 +25,11 @@ documents the block layout and the admission path.
 
 Round 12: the read path gains its fused Pallas kernel
 (``ops.paged_flash``, no materialized gather: the decode tick's read on
-a TPU unless ``gather_impl=`` names a spelling; the ``jnp.take``
-spelling stays the chunk programs' read, and every program's on another
-backend) and the pool an int8 quantized variant (``kv_dtype="int8"``,
-per-row scales, ~2x blocks at fixed bytes) — ANALYSIS.md "Paged
-attention kernel & quantized KV".
+a TPU; the ``jnp.take`` spelling stays the chunk programs' read, and
+every program's on another backend:
+``ops.attention.default_gather_impl`` chooses) and the pool an int8
+quantized variant (``kv_dtype="int8"``, per-row scales, ~2x blocks at
+fixed bytes) — ANALYSIS.md "Paged attention kernel & quantized KV".
 
 Round 16: the async host runtime — ``scheduler`` splits each tick into
 a non-blocking ``dispatch_tick`` and a lagged ``collect_tick``

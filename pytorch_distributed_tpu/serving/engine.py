@@ -17,11 +17,11 @@ every update is in place:
 - **decode** (``decode``): one token for every slot, exactly the dense
   ``_step_body`` shape but attending through the block table
   (``ops.attention.paged_attention`` — the fused ``ops.paged_flash``
-  kernel where the backend is a TPU and the dense gather elsewhere,
-  unless ``gather_impl=`` names one, which the chunk programs then
-  compile too: unnamed they gather dense, their rows are a chunk's and
-  their tables cut to the prompts; with ``kv_dtype="int8"`` the pool
-  is quantized with per-row scales).
+  kernel where the backend is a TPU and the dense gather elsewhere;
+  the chunk programs gather dense everywhere, their rows are a chunk's
+  and their tables cut to the prompts: ``ops.attention.
+  default_gather_impl`` chooses, a program at a time; with
+  ``kv_dtype="int8"`` the pool is quantized with per-row scales).
   Inactive lanes' writes are routed to the trash block by host-side
   table masking, so recycled blocks can never be corrupted by a dead
   lane.
@@ -37,7 +37,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
 import re
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -62,7 +61,7 @@ from pytorch_distributed_tpu.compilecache.aot import (
     program_load,
     program_load_if,
 )
-from pytorch_distributed_tpu.ops.attention import resolve_gather_impl
+from pytorch_distributed_tpu.ops import attention as attention_ops
 from pytorch_distributed_tpu.telemetry import spans
 from pytorch_distributed_tpu.telemetry.overlap import NULL_LEDGER
 
@@ -159,11 +158,8 @@ class PagedEngine:
                  prefill_chunk: int = 128, temperature: float = 0.0,
                  top_k: Optional[int] = None, mesh=None, device=None,
                  handoff: bool = False, swap: bool = False,
-                 gather_impl: Optional[str] = None,
                  kv_dtype: Optional[str] = None,
                  prefix_cache: bool = False,
-                 split_s: Optional[int] = None,
-                 autotune_dir: Optional[str] = None,
                  chunk_bucket_floor: Tuple[int, int] = (1, 1),
                  max_chunk_jobs: Optional[int] = None):
         from pytorch_distributed_tpu.models.generate import (
@@ -176,60 +172,14 @@ class PagedEngine:
         _validate_sampling(config, temperature, top_k)
         if prefill_chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got {prefill_chunk}")
-        # KV gather spelling: an explicit gather_impl= overrides the
-        # config field (replaced INTO the config so the model, the
-        # registry fingerprint, and this engine agree on one value —
-        # TransformerConfig validates it); where neither names one each
-        # program asks ops.attention.default_gather_impl with its own
-        # rows. kv_dtype="int8" swaps the pool for the quantized layout
+        # kv_dtype="int8" swaps the pool for the quantized layout
         # (kv_pool.init_paged_cache); the model's scatter path keys off
         # the pool dtype, nothing else.
-        if gather_impl is not None and gather_impl != config.gather_impl:
-            config = dataclasses.replace(config, gather_impl=gather_impl)
         if kv_dtype not in KV_DTYPES:
             raise ValueError(
                 f"kv_dtype {kv_dtype!r} must be one of {KV_DTYPES}"
             )
         self.kv_dtype = kv_dtype
-        # Autotuned kernel config (telemetry/autotune.py): if a tuned
-        # file exists for this engine's autotune fingerprint — the
-        # registry fingerprint with the TUNED knobs (block_len /
-        # prefill_chunk / split_s) normalized out, so the key never
-        # depends on the values being tuned — load it and let it
-        # override the defaults. Explicit caller arguments win over the
-        # tuned file (you asked for that value, you get it); a missing,
-        # stale, or corrupt tuned file is a clean miss, never an error.
-        self.autotune_dir = (
-            autotune_dir if autotune_dir is not None
-            else os.environ.get("PDT_AUTOTUNE_DIR") or None
-        )
-        self.tuned = None
-        self._tuned_key = None
-        if self.autotune_dir:
-            from pytorch_distributed_tpu.telemetry.autotune import (
-                autotune_fingerprint,
-                load_tuned,
-            )
-
-            self._tuned_key = autotune_fingerprint(
-                config, n_slots, kv_dtype=kv_dtype,
-                temperature=temperature, top_k=top_k,
-                prefix_cache=prefix_cache, mesh=mesh,
-            )
-            self.tuned = load_tuned(self.autotune_dir, self._tuned_key)
-            if self.tuned is not None:
-                if block_len == 16:  # signature default → tunable
-                    block_len = self.tuned.block_len
-                if prefill_chunk == 128:  # signature default → tunable
-                    prefill_chunk = self.tuned.prefill_chunk
-                if split_s is None:
-                    split_s = self.tuned.split_s
-        # The split-S knob lives on the config (like gather_impl) so the
-        # model, the registry fingerprint, and this engine agree on one
-        # value — programs compiled with different splits never share a
-        # cache entry.
-        if split_s is not None and split_s != config.split_s:
-            config = dataclasses.replace(config, split_s=split_s)
         if mesh is not None and device is not None:
             raise ValueError(
                 "pass mesh= (TP sub-mesh) or device= (single-device "
@@ -387,35 +337,12 @@ class PagedEngine:
 
     @property
     def gather_impl(self) -> str:
-        """The KV gather spelling the decode tick compiles with: the one
-        named on the config (so model, fingerprint, and engine agree),
-        else what the backend gives a tick's rows
-        (``ops.attention.resolve_gather_impl``; an unnamed chunk program
-        asks with its own, wider, rows)."""
+        """The paged read the decode tick compiles: what
+        ``ops.attention.default_gather_impl`` answers for a tick's rows
+        (a chunk program asks with its own, wider, rows)."""
         kv = self.config.num_kv_heads or self.config.num_heads
-        return resolve_gather_impl(self.config.gather_impl,
-                                   rows=self.config.num_heads // kv)
-
-    def tuned_provenance(self) -> Dict[str, object]:
-        """Which kernel config actually served: tuned or default.
-
-        Telemetry cost cards carry these keys so forensics
-        (``explain_request`` / ``telemetry_report``) can tell whether a
-        program ran with an autotuned config and whether that config's
-        fingerprint still matches this engine (staleness is a clean
-        miss at load time, so ``tuned_match`` is True whenever a tuned
-        config applied at all).
-        """
-        out: Dict[str, object] = {
-            "tuned": self.tuned is not None,
-            "tuned_block_len": self.block_len,
-            "tuned_prefill_chunk": self.chunk,
-            "tuned_split_s": self.config.split_s,
-        }
-        if self._tuned_key is not None:
-            out["tuned_fingerprint"] = self._tuned_key
-            out["tuned_match"] = self.tuned is not None
-        return out
+        return attention_ops.default_gather_impl(
+            rows=self.config.num_heads // kv)
 
     # ---- program builders (cached per static shape) ----
 

@@ -491,10 +491,10 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     ``"fp8_e5m2"`` are the same layout at 1-byte values with 1-byte
     int8 EXPONENT siblings (``pool_scale_dtype``) — 2D/(D+1) capacity
     vs bf16 where int8+fp32 scales is 2D/(D+4). The attention read path
-    dequantizes (in-VMEM for ``gather_impl="pallas"``, post-take for
-    "dense"); ``models.transformer.Attention`` switches to quantize-on-
-    scatter off the pool dtype alone, so the cache pytree IS the whole
-    contract — no config flag to drift from it.
+    dequantizes (in VMEM where it is the fused kernel, after the take
+    where it gathers dense); ``models.transformer.Attention`` switches
+    to quantize-on-scatter off the pool dtype alone, so the cache pytree
+    IS the whole contract — no config flag to drift from it.
     """
     from pytorch_distributed_tpu.models.generate import init_cache
 
@@ -551,9 +551,9 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
 def pool_block_bytes(config, params, block_len: int,
                      kv_dtype: Optional[str] = None) -> int:
     """HBM bytes ONE pool block costs across every layer (K + V + any
-    scale siblings) — the unit the capacity A/B divides a fixed byte
-    budget by (``scripts/bench_serving.py --gather-ab``). Pure
-    ``eval_shape`` arithmetic; nothing is allocated."""
+    scale siblings) — the unit a fixed byte budget is divided by to
+    compare pool dtypes' capacity. Pure ``eval_shape`` arithmetic;
+    nothing is allocated."""
     shapes = jax.eval_shape(
         lambda p: init_paged_cache(config, p, 2, block_len,
                                    kv_dtype=kv_dtype),

@@ -255,7 +255,6 @@ class Scheduler:
                  prefill_only: bool = False, device=None,
                  handoff: bool = False, flightrec=None,
                  anomaly_threshold: float = 8.0,
-                 gather_impl: Optional[str] = None,
                  kv_dtype: Optional[str] = None,
                  offload: bool = False, preempt_on_oom: bool = False,
                  swap_policy: str = "auto", protect_ticks: int = 2,
@@ -263,8 +262,6 @@ class Scheduler:
                  host_store_max_bytes: Optional[int] = None,
                  reqtrace=None, ledger=None, host_pool=None,
                  prefix_cache: bool = False, blocksan=None,
-                 split_s: Optional[int] = None,
-                 autotune_dir: Optional[str] = None,
                  chunk_bucket_floor: Tuple[int, int] = (1, 1),
                  max_chunk_jobs: Optional[int] = None):
         from pytorch_distributed_tpu.serving.engine import PagedEngine
@@ -290,9 +287,7 @@ class Scheduler:
             prefill_chunk=prefill_chunk, temperature=temperature,
             top_k=top_k, mesh=mesh, device=device,
             handoff=(handoff or prefill_only), swap=offload,
-            gather_impl=gather_impl, kv_dtype=kv_dtype,
-            prefix_cache=prefix_cache, split_s=split_s,
-            autotune_dir=autotune_dir,
+            kv_dtype=kv_dtype, prefix_cache=prefix_cache,
             chunk_bucket_floor=chunk_bucket_floor,
             max_chunk_jobs=max_chunk_jobs,
         )
@@ -330,9 +325,7 @@ class Scheduler:
         self._decision_recompute = 0
         self._oom_preempted_for: Optional[int] = None
         self.swap_lat = LatencySeries("swap")
-        # the engine may have replaced gather_impl= into the config —
-        # read back its copy so scheduler and programs agree
-        self.config = self.engine.config
+        self.config = config
         self.n_slots = n_slots
         self.admit_per_step = admit_per_step
         self.eos_id = eos_id
@@ -1929,7 +1922,7 @@ class Scheduler:
 
         return log_cost_cards(
             serving_registry(self.engine), self.prog_times,
-            self.metrics_log, annotate=self.engine.tuned_provenance(),
+            self.metrics_log,
         )
 
     # ---- metrics ----

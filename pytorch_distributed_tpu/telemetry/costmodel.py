@@ -375,14 +375,11 @@ def build_cost_cards(registry, times: Optional[ProgramTimes] = None,
 
 
 def log_cost_cards(registry, times, metrics_log, *,
-                   fingerprint: Optional[str] = None,
-                   annotate: Optional[dict] = None) -> List[dict]:
+                   fingerprint: Optional[str] = None) -> List[dict]:
     """Build every card, join, and emit one ``kind="program_cost"``
     JSONL record per program. Returns the records (emitted or not — a
     ``metrics_log`` of None still returns them for callers that render
-    directly). ``annotate`` merges extra keys into every record — the
-    scheduler passes the engine's tuned-config provenance so forensics
-    can tell which kernel variant actually served."""
+    directly)."""
     peak_flops, peak_bytes_s = device_ceilings()
     records = []
     for card in build_cost_cards(registry, times):
@@ -390,8 +387,6 @@ def log_cost_cards(registry, times, metrics_log, *,
         rec["fingerprint"] = (
             fingerprint if fingerprint is not None else registry.fingerprint
         )
-        if annotate:
-            rec.update(annotate)
         records.append(rec)
         if metrics_log is not None:
             metrics_log.log(kind="program_cost", **rec)

@@ -167,32 +167,12 @@ def _parse(argv=None) -> argparse.Namespace:
                         "tick (the oldest first; default: every slot): "
                         "with --chunk-bucket-floor it bounds the chunk "
                         "programs to compile")
-    p.add_argument("--gather-impl", choices=("dense", "pallas"),
-                   default=None,
-                   help="paged KV gather spelling: 'dense' jnp.take or "
-                        "'pallas' fused kernel (ops/paged_flash.py; "
-                        "interpret mode off-TPU), for every program. "
-                        "Default: the decode tick reads through the "
-                        "kernel on a TPU; chunked prefill, and every "
-                        "program on another backend, gathers dense")
     p.add_argument("--kv-dtype", choices=("int8", "fp8", "fp8_e5m2"),
                    default=None,
                    help="quantize the KV block pool: 'int8' (+fp32 "
                         "per-row scales, ~2D/(D+4) blocks at fixed pool "
                         "bytes) or 'fp8'/'fp8_e5m2' (e4m3/e5m2 + int8 "
                         "exponent scales, ~2D/(D+1))")
-    p.add_argument("--split-s", type=int, default=None,
-                   help="flash-decoding: split each chain sweep across "
-                        "this many grid workers (log-sum-exp combine). "
-                        "Default auto: splits when table-width/batch "
-                        "crosses the ops.paged_flash threshold; 1 forces "
-                        "the single-worker sweep")
-    p.add_argument("--autotune-dir", default=None,
-                   help="load an autotuned kernel config "
-                        "(scripts/autotune.py output; env fallback "
-                        "PDT_AUTOTUNE_DIR) keyed by this run's "
-                        "fingerprint — a stale or missing file is a "
-                        "clean miss, never an error")
     p.add_argument("--prefix-cache", action="store_true",
                    help="round-17 prefix-sharing KV cache: radix reuse "
                         "of full prompt blocks with copy-on-write — a "
@@ -421,9 +401,7 @@ def main() -> None:
             n_slots=args.slots,
             block_len=args.block_len, prefill_chunk=args.prefill_chunk,
             admit_per_step=args.admit_per_step, n_blocks=args.n_blocks,
-            gather_impl=args.gather_impl, kv_dtype=args.kv_dtype,
-            prefix_cache=args.prefix_cache, split_s=args.split_s,
-            autotune_dir=args.autotune_dir,
+            kv_dtype=args.kv_dtype, prefix_cache=args.prefix_cache,
             chunk_bucket_floor=tuple(args.chunk_bucket_floor),
             max_chunk_jobs=args.max_chunk_jobs,
             **pressure_kw,
@@ -493,10 +471,8 @@ def main() -> None:
             raise SystemExit("--warmup needs the paged layout (the dense "
                              "ContinuousBatcher has no program registry); "
                              "drop --dense")
-        if (args.gather_impl or args.kv_dtype or args.prefix_cache
-                or args.split_s is not None or args.autotune_dir):
-            raise SystemExit("--gather-impl/--kv-dtype/--prefix-cache/"
-                             "--split-s/--autotune-dir are block-pool "
+        if args.kv_dtype or args.prefix_cache:
+            raise SystemExit("--kv-dtype/--prefix-cache are block-pool "
                              "knobs; drop --dense")
         if args.preempt or args.n_blocks is not None:
             raise SystemExit("--preempt/--n-blocks are block-pool knobs "
@@ -525,11 +501,10 @@ def main() -> None:
             admit_per_step=args.admit_per_step, seed=args.seed,
             mesh=mesh, metrics_log=mlog,
             reqtrace=reqtrace,
-            gather_impl=args.gather_impl, kv_dtype=args.kv_dtype,
+            kv_dtype=args.kv_dtype,
             offload=args.preempt, preempt_on_oom=args.preempt,
             swap_policy=args.swap_policy,
-            prefix_cache=args.prefix_cache, split_s=args.split_s,
-            autotune_dir=args.autotune_dir,
+            prefix_cache=args.prefix_cache,
             chunk_bucket_floor=tuple(args.chunk_bucket_floor),
             max_chunk_jobs=args.max_chunk_jobs,
         )

@@ -78,12 +78,6 @@ BAND_OVERRIDES: Tuple[Tuple[str, float], ...] = (
     # census/verdict gates (strings + ci_check --soak-smoke) carry the
     # hard pass/fail instead
     (r"^serving_soak_", 1.5),
-    # round-20 kernel-variant columns (fp8 / split-S / tuned): decode
-    # tok/s over a tiny model is scheduler-noise-dominated even on TPU;
-    # the ratios move with it. On a CPU backend these rows are skipped
-    # entirely (interpreter timing — see the honesty skip in compare()).
-    (r"^serving_kernel_.*_over_", 0.5),
-    (r"^serving_kernel_", 0.35),
     # shared-disk weather moves raw bandwidth 2x day to day (PERF_NOTES
     # §8); anything disk-bound inherits that swing
     (r"^ckpt_", 1.5),
@@ -102,9 +96,6 @@ SKIP_PATTERNS = (
     r"_mode$", r"^host_cores$", r"params_m$", r"bytes_mb$", r"_len$",
     r"slots$", r"_lens$", r"tokens$", r"_frac$", r"vs_baseline",
     r"^probe_",
-    # tuned-config provenance: the CONFIG the autotuner picked, not a
-    # measurement (a different winner is news, not a regression)
-    r"tuned_split_s$", r"tuned_block_len$", r"tuned_loaded$",
 )
 
 _HIGHER_BETTER = re.compile(
@@ -138,10 +129,6 @@ DIRECTION_OVERRIDES: Tuple[Tuple[str, str], ...] = (
     (r"serving_prefix_admit_tok_ratio", "up"),
     (r"serving_prefix_admit_tok_per_req", "down"),
     (r"serving_prefix_fresh_blocks_per_req", "down"),
-    # round-20 kernel columns: variant-over-baseline throughput ratios
-    # regress DOWN when the variant loses ground; plain tok/s and p95
-    # fall through to the suffix patterns (_tok_s up, _ms down)
-    (r"serving_kernel_.*_over_", "up"),
     # round-21 soak slopes: MiB (or ms) per 10k sessions — growth is
     # the regression, shrinking is the win; RSS final rides along.
     # Verdict/census keys are strings (auto-skipped) and *_frac keys
@@ -189,21 +176,11 @@ def compare(current: dict, previous: dict,
     overrides = overrides or {}
     regressions, improvements = [], []
     within = skipped = 0
-    # CPU-interpret honesty skip (PR 10 rule, extended to the round-20
-    # serving_kernel_* columns): when either round's gather A/B ran off
-    # TPU, its pallas-path timings measured the Pallas INTERPRETER —
-    # plumbing, not a performance claim — so kernel-variant rows are
-    # not gated at all rather than gated against noise.
-    interp = (current.get("gather_ab_backend", "tpu") != "tpu"
-              or previous.get("gather_ab_backend", "tpu") != "tpu")
     for key in sorted(set(current) & set(previous)):
         cur, prev = current[key], previous[key]
         if (not isinstance(cur, (int, float))
                 or not isinstance(prev, (int, float))
                 or isinstance(cur, bool) or isinstance(prev, bool)):
-            skipped += 1
-            continue
-        if interp and re.match(r"serving_kernel_", key):
             skipped += 1
             continue
         sense = direction(key)

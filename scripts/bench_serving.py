@@ -24,8 +24,6 @@ Usage: python scripts/bench_serving.py [--slots 32]
                           # sync-vs-async A/B (serving_wallclock_async_*),
                           # extra fleet-size points (--wc-extra N,M), and
                           # median-of-reps quoting (--wc-reps)
-       python scripts/bench_serving.py --gather-ab [--tiny --ab-slots 8
-           --ab-ticks 32 --ab-prompt-len 64]  # pallas-vs-dense + int8 capacity
        python scripts/bench_serving.py --pressure [--pressure-sessions 100000
            --pressure-blocks 13 --pressure-duration 90]  # preempt vs shed-only
        python scripts/bench_serving.py --soak [--soak-requests 100000
@@ -653,149 +651,6 @@ def measure_tp_virtual(slots: int = 8, tp: int = 2) -> dict:
         "serving_tp_degree": tp,
         "serving_tp_note": "virtual CPU mesh: functionality, not perf",
     }
-
-
-def measure_gather_ab(slots: int = 8, ticks: int = 32, prompt_len: int = 64,
-                      tiny: bool = False, block_len: int = 16,
-                      tuned_dir=None) -> dict:
-    """Pallas-vs-dense gather A/B (ISSUE 10) + int8-vs-bf16 pool
-    capacity at fixed bytes, as bench-style JSON for
-    ``bench_regression.py``.
-
-    Decode side: every slot holds a ``prompt_len`` KV chain, then
-    ``ticks`` full decode ticks run per gather spelling on a WARM
-    program (one untimed tick first) — tokens materialize inside
-    ``engine.decode``, so each tick's wall is dispatch + device + sync.
-    Reports decode-tok/s and decode-tick p95 for each spelling plus the
-    pallas/dense ratio. HONESTY: on a non-TPU backend the pallas
-    spelling runs the Pallas INTERPRETER (``gather_ab_backend`` says
-    which); the ratio is a TPU performance claim and a CPU correctness/
-    plumbing exercise — do not regress-gate the CPU ratio
-    (ANALYSIS.md "Paged attention kernel & quantized KV").
-
-    Capacity side: ``kv_pool.pool_block_bytes`` arithmetic on the bf16
-    twin of the same config — blocks a fixed 64 MiB budget fits, raw
-    bf16 vs int8+scales (exactly 2D/(D+4), 1.88x at the GPT-2 head
-    dim)."""
-    import dataclasses
-
-    from pytorch_distributed_tpu.serving import PagedEngine
-    from pytorch_distributed_tpu.serving.engine import ChunkJob
-    from pytorch_distributed_tpu.serving.kv_pool import pool_block_bytes
-
-    if tiny:
-        cfg, params = _tiny_model(max_seq_len=256)
-    else:
-        cfg, params = _gpt2_model(max_seq_len=512)
-    rng = np.random.default_rng(0)
-    prompt = rng.integers(1, cfg.vocab_size, prompt_len).astype(np.int32)
-    chunk = prompt_len  # one prefill program fills every chain
-
-    def decode_side(gather_impl, kv_dtype=None, split_s=None,
-                    autotune_dir=None, bl=None):
-        eng = PagedEngine(cfg, params, slots,
-                          block_len=block_len if bl is None else bl,
-                          prefill_chunk=chunk, gather_impl=gather_impl,
-                          kv_dtype=kv_dtype, split_s=split_s,
-                          autotune_dir=autotune_dir)
-        for s in range(slots):
-            assert eng.admit(s, prompt_len, ticks + 1)
-        eng.run_chunks([
-            ChunkJob(slot=s, tokens=prompt, start=0, is_last=True,
-                     last_idx=prompt_len - 1)
-            for s in range(slots)
-        ])
-        positions = np.full(slots, prompt_len, np.int32)
-        active = np.ones(slots, bool)
-        key = jax.random.key(1)
-        _tokens, positions = eng.decode(positions, active, key)  # warm
-        times = []
-        for _ in range(ticks):
-            t0 = time.perf_counter()
-            _tokens, positions = eng.decode(positions, active, key)
-            times.append(time.perf_counter() - t0)
-        return {
-            "tok_s": round(slots * ticks / sum(times), 1),
-            "tick_p95_ms": round(
-                float(np.percentile(times, 95)) * 1e3, 3
-            ),
-        }
-
-    dense = decode_side("dense")
-    pallas = decode_side("pallas")
-    # round 20 columns: fp8 pool decode, forced split-S decode (the
-    # flash-decoding path even when W/B sits under the auto threshold),
-    # and — with --tuned — the autotuned config vs the defaults.
-    # Same honesty rule as the dense/pallas ratio: off-TPU these time
-    # the Pallas INTERPRETER (gather_ab_backend says which).
-    fp8 = decode_side("pallas", kv_dtype="fp8")
-    split = decode_side("pallas", split_s=2)
-    bf16_cfg = dataclasses.replace(cfg, dtype=jnp.bfloat16)
-    bf16_block = pool_block_bytes(bf16_cfg, params, block_len)
-    int8_block = pool_block_bytes(bf16_cfg, params, block_len,
-                                  kv_dtype="int8")
-    fp8_block = pool_block_bytes(bf16_cfg, params, block_len,
-                                 kv_dtype="fp8")
-    budget = 64 << 20
-    out = {
-        "gather_ab_backend": jax.default_backend(),
-        "gather_ab_slots": slots,
-        "gather_ab_ticks": ticks,
-        "gather_ab_prompt_len": prompt_len,
-        "serving_gather_ab_decode_tok_s_dense": dense["tok_s"],
-        "serving_gather_ab_decode_tok_s_pallas": pallas["tok_s"],
-        "serving_gather_ab_decode_tick_p95_ms_dense": dense["tick_p95_ms"],
-        "serving_gather_ab_decode_tick_p95_ms_pallas": pallas["tick_p95_ms"],
-        "serving_gather_ab_pallas_over_dense": round(
-            pallas["tok_s"] / dense["tok_s"], 3
-        ),
-        "serving_kernel_decode_tok_s_fp8": fp8["tok_s"],
-        "serving_kernel_decode_tick_p95_ms_fp8": fp8["tick_p95_ms"],
-        "serving_kernel_decode_tok_s_split2": split["tok_s"],
-        "serving_kernel_decode_tick_p95_ms_split2": split["tick_p95_ms"],
-        "serving_kernel_fp8_over_pallas": round(
-            fp8["tok_s"] / pallas["tok_s"], 3
-        ),
-        "serving_kernel_split2_over_pallas": round(
-            split["tok_s"] / pallas["tok_s"], 3
-        ),
-        "serving_kv_pool_block_bytes_bf16": bf16_block,
-        "serving_kv_pool_block_bytes_int8": int8_block,
-        "serving_kv_pool_block_bytes_fp8": fp8_block,
-        "serving_kv_pool_blocks_at_64mb_bf16": budget // bf16_block,
-        "serving_kv_pool_blocks_at_64mb_int8": budget // int8_block,
-        "serving_kv_pool_blocks_at_64mb_fp8": budget // fp8_block,
-        "serving_kv_pool_capacity_ratio_int8_over_bf16": round(
-            (budget // int8_block) / (budget // bf16_block), 3
-        ),
-        "serving_kv_pool_capacity_ratio_fp8_over_bf16": round(
-            (budget // fp8_block) / (budget // bf16_block), 3
-        ),
-    }
-    if tuned_dir is not None:
-        # --tuned: A/B the autotuned config (scripts/autotune.py output,
-        # loaded by the engine keyed by fingerprint) against the default
-        # pallas engine timed above. tuned_loaded says whether a tuned
-        # file actually matched — a clean miss A/Bs default-vs-default,
-        # honestly labeled rather than silently skipped.
-        from pytorch_distributed_tpu.serving.engine import PagedEngine
-
-        probe_eng = PagedEngine(cfg, params, slots,
-                                gather_impl="pallas",
-                                autotune_dir=tuned_dir)
-        tuned = decode_side("pallas", autotune_dir=tuned_dir)
-        out.update({
-            "serving_kernel_tuned_loaded": probe_eng.tuned is not None,
-            "serving_kernel_tuned_block_len": probe_eng.block_len,
-            "serving_kernel_tuned_split_s": probe_eng.config.split_s,
-            "serving_kernel_decode_tok_s_tuned": tuned["tok_s"],
-            "serving_kernel_decode_tick_p95_ms_tuned":
-                tuned["tick_p95_ms"],
-            "serving_kernel_tuned_over_default": round(
-                tuned["tok_s"] / pallas["tok_s"], 3
-            ),
-        })
-    return out
 
 
 def measure_pressure(trace=None, slots: int = 4, n_blocks: int = 13,
@@ -1818,16 +1673,6 @@ def main() -> None:
     if "--paged-latency" in sys.argv:
         print(json.dumps({**measure_paged_latency(trace=_cli_trace()),
                           **probe}))
-        return
-    if "--gather-ab" in sys.argv:
-        print(json.dumps({**measure_gather_ab(
-            slots=_argval("--ab-slots", 8, int),
-            ticks=_argval("--ab-ticks", 32, int),
-            prompt_len=_argval("--ab-prompt-len", 64, int),
-            tiny="--tiny" in sys.argv,
-            tuned_dir=(_argval("--autotune-dir", None, str)
-                       if "--tuned" in sys.argv else None),
-        ), **probe}))
         return
     if "--tp-virtual" in sys.argv:
         print(json.dumps({**measure_tp_virtual(), **probe}))

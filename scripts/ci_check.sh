@@ -12,7 +12,7 @@
 #                             --pressure-smoke|--trace-smoke|
 #                             --overlap-smoke|--async-smoke|
 #                             --prefix-smoke|--blocksan-smoke|
-#                             --chaos-smoke|--tune-smoke|
+#                             --chaos-smoke|
 #                             --soak-smoke|--gateway-smoke|
 #                             --bench-regression]
 #
@@ -35,15 +35,7 @@
 #
 # --kernel-smoke: lint, then one pallas-gather serve cycle per pool
 # dtype (int8/fp8; token-identical to generate; Pallas interpreter on
-# CPU) + the int8/fp8 logit-error bounds + the split-S parity bound + a
-# tiny --gather-ab run (A/B plumbing + JSON keys; the throughput claim
-# itself is TPU-only).
-#
-# --tune-smoke: lint, then the round-20 autotuner cycle: the
-# tests/test_autotune.py round-trip (sweep → persist → fresh engine
-# loads by fingerprint with zero new jit-cache entries; stale
-# fingerprint → clean miss), one scripts/autotune.py sweep, and the
-# --gather-ab --tuned A/B consuming it.
+# CPU) + the int8/fp8 logit-error bounds + the split-S parity bound.
 #
 # --telemetry-smoke: lint, then one short LM training run and one
 # paged-serving cycle with --metrics-out, then telemetry_report.py must
@@ -222,11 +214,7 @@ if [[ "${1:-}" == "--kernel-smoke" ]]; then
     echo "== kernel smoke (pallas gather + quantized pools + split-S) =="
     # one full pallas-path serve cycle per pool dtype, token-identical
     # to the generate reference (interpret mode on CPU), the int8/fp8
-    # logit-error bounds, the split-S-vs-single-worker parity bound,
-    # then the gather A/B on the tiny model as a plumbing/JSON-schema
-    # sanity check (the pallas>=dense throughput claim is TPU-only; the
-    # CPU run exercises the same code path through the Pallas
-    # interpreter)
+    # logit-error bounds, the split-S-vs-single-worker parity bound
     JAX_PLATFORMS=cpu python -m pytest \
         tests/test_paged_kernel.py::test_kernel_smoke \
         tests/test_paged_kernel.py::test_int8_pool_logit_error_bound \
@@ -234,28 +222,6 @@ if [[ "${1:-}" == "--kernel-smoke" ]]; then
         tests/test_paged_kernel.py::test_fp8_serve_cycle_split_s \
         tests/test_paged_kernel.py::test_split_s_matches_single_worker -q \
         -p no:cacheprovider -p no:xdist -p no:randomly
-    JAX_PLATFORMS=cpu python scripts/bench_serving.py --gather-ab --tiny \
-        --ab-slots 4 --ab-ticks 8 --ab-prompt-len 32
-    exit 0
-fi
-
-if [[ "${1:-}" == "--tune-smoke" ]]; then
-    echo "== tune smoke (sweep -> tuned reload by fingerprint -> stale miss) =="
-    # one tiny autotune sweep, then: (a) a fresh engine with the same
-    # shape must LOAD the tuned config (tests assert zero new jit-cache
-    # entries + registry coverage), (b) a different shape (stale
-    # fingerprint) must miss CLEANLY — default config, no crash —
-    # then the --tuned gather A/B prints the tuned-vs-default columns
-    smoke=$(mktemp -d)
-    trap 'rm -rf "$smoke"' EXIT
-    JAX_PLATFORMS=cpu python -m pytest tests/test_autotune.py -q \
-        -p no:cacheprovider -p no:xdist -p no:randomly
-    JAX_PLATFORMS=cpu python scripts/autotune.py --tiny \
-        --out-dir "$smoke/tuned" --block-lens 8,16 --split-ss 1,2 \
-        --ticks 4 --prompt-len 16 --slots 4
-    JAX_PLATFORMS=cpu python scripts/bench_serving.py --gather-ab --tiny \
-        --ab-slots 4 --ab-ticks 8 --ab-prompt-len 32 \
-        --tuned --autotune-dir "$smoke/tuned"
     exit 0
 fi
 

@@ -166,13 +166,7 @@ def render_node(node: SpanNode, t_root: float, costs: dict,
         prog = attrs.get("program")
         if prog and prog in costs and costs[prog].get("mean_s"):
             card = costs[prog]
-            # round-20 provenance: was this program serving with an
-            # autotuned kernel config or the defaults? (annotated onto
-            # every cost card by scheduler.log_cost_cards)
-            cfg = ""
-            if "tuned" in card:
-                cfg = ", tuned cfg" if card["tuned"] else ", default cfg"
-            cost = f"  [card: {_fmt_ms(card['mean_s'])}/call{cfg}]"
+            cost = f"  [card: {_fmt_ms(card['mean_s'])}/call]"
         split = ""
         span_id = node.record.get("span")
         if device_splits and span_id in device_splits:

@@ -337,7 +337,7 @@ def cost_section(records: List[dict], out: dict) -> List[str]:
     lines = ["== program cost / roofline =="]
     lines.append(_fmt_row(
         "program", "calls", "mean_ms", "GFLOP/s", "GB/s", "F/B", "MFU",
-        "bound", "cfg",
+        "bound",
     ))
     measured = 0
     # measured programs first (by total time, attribution order), then
@@ -358,28 +358,7 @@ def cost_section(records: List[dict], out: dict) -> List[str]:
             fmt(r.get("intensity_flop_b"), 1.0),
             f"{r['mfu']:.4f}" if r.get("mfu") is not None else "-",
             r.get("bound", "-"),
-            # round-20 tuned-config provenance (the scheduler annotates
-            # every card): which kernel config actually served
-            ("tuned" if r.get("tuned")
-             else "default" if "tuned" in r else "-"),
         ))
-    # one provenance trailer when any card carries the annotation: the
-    # applied knobs + whether the tuned file's fingerprint matched
-    tuned_rows = [r for r in cards.values() if "tuned" in r]
-    if tuned_rows:
-        t = tuned_rows[0]
-        state = ("tuned, fingerprint match" if t.get("tuned_match")
-                 else "tuned" if t.get("tuned")
-                 else "default (no tuned config"
-                      + (" matched)" if t.get("tuned_fingerprint")
-                         else " dir)"))
-        lines.append(
-            f"kernel config: {state} — block_len="
-            f"{t.get('tuned_block_len', '-')} prefill_chunk="
-            f"{t.get('tuned_prefill_chunk', '-')} split_s="
-            f"{t.get('tuned_split_s')}"
-        )
-        out["cost_tuned"] = bool(t.get("tuned"))
     out["cost_programs"] = len(cards)
     out["cost_measured_programs"] = measured
     mfus = [r["mfu"] for r in cards.values() if r.get("mfu") is not None]

@@ -31,6 +31,25 @@ def devices8():
     return devices[:8]
 
 
+@pytest.fixture
+def steer_paged_read(monkeypatch):
+    """Steer the one rule that chooses the paged read,
+    ``ops.attention.default_gather_impl``, for the rest of the test:
+    ``steer_paged_read("pallas")`` answers that spelling for any rows, a
+    ``rows -> spelling`` function answers what it says; a later call
+    steers again. Programs ask the rule as they trace, so steer before an
+    engine's first call and drive it before steering elsewhere. The
+    backend is left alone: off-TPU the kernel runs in the interpreter."""
+    from pytorch_distributed_tpu.ops import attention
+
+    def steer(rule):
+        monkeypatch.setattr(
+            attention, "default_gather_impl",
+            (lambda rows=1: rule) if isinstance(rule, str) else rule)
+
+    return steer
+
+
 def assert_trees_equal(a, b, rtol=0, atol=0):
     """Leaf-wise comparison of two pytrees by path (shared test helper)."""
     import numpy as np

@@ -118,15 +118,16 @@ def test_every_pass_changes_the_result(model):
 
 
 @pytest.mark.parametrize("gather_impl", ["dense", "pallas"])
-def test_chunked_prefill_then_decode_matches_the_full_forward(model,
-                                                              gather_impl):
+def test_chunked_prefill_then_decode_matches_the_full_forward(
+        model, steer_paged_read, gather_impl):
     """Two chunks of prefill and three decode ticks through the paged pool
     (chunk and tick programs, the loop inside each) leave, at every step,
     the logits the reference's one full forward gives at that position."""
     cfg, params = model
     chunk, new = 8, 3
+    steer_paged_read(gather_impl)
     eng = PagedEngine(cfg, params, 3, n_blocks=13, block_len=8,
-                      prefill_chunk=chunk, gather_impl=gather_impl)
+                      prefill_chunk=chunk)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, 128, size=n).astype(np.int32)
                for n in (13, 16)]

@@ -27,6 +27,7 @@ from pytorch_distributed_tpu.models.transformer import (
     TransformerLM,
     tiny_config,
 )
+from pytorch_distributed_tpu.ops import paged_flash
 from pytorch_distributed_tpu.ops.attention import paged_attention
 from pytorch_distributed_tpu.ops.paged_flash import (
     auto_split_s,
@@ -478,7 +479,7 @@ def test_auto_split_s_policy():
     """The threshold policy is static-shape arithmetic: split only when
     W/B crosses the threshold (few long chains), then min(MAX_SPLIT, W)
     so every worker owns >= 1 block; split_s=None in the op resolves
-    through it, and split_s < 1 is rejected everywhere it can enter."""
+    through it, and the op rejects split_s < 1."""
     assert auto_split_s(64, 2, cores=2) == 8
     assert auto_split_s(8, 8, cores=2) == 1
     assert auto_split_s(16, 1, cores=2) == 8
@@ -499,8 +500,6 @@ def test_auto_split_s_policy():
     np.testing.assert_array_equal(np.asarray(auto), np.asarray(one))
     with pytest.raises(ValueError, match="split_s"):
         paged_flash_attention(q, kp, vp, tables, pos, split_s=0)
-    with pytest.raises(ValueError, match="split_s"):
-        dataclasses.replace(setup(max_seq_len=64)[0], split_s=0)
 
 
 @pytest.mark.parametrize("k,w", [
@@ -530,49 +529,50 @@ def test_chunk_buckets_do_not_split_on_a_one_core_chip(k, w):
     pytest.param("pallas", "int8", marks=pytest.mark.slow),
     pytest.param("pallas", "fp8", marks=pytest.mark.slow),
 ])
-def test_registry_covers_kernel_and_quant_variants(gather_impl, kv_dtype):
+def test_registry_covers_kernel_and_quant_variants(steer_paged_read,
+                                                   gather_impl, kv_dtype):
     """The coverage guard keeps its teeth over the new program shapes:
     a pallas/int8 engine's compiled programs are all predicted by its
-    serving registry, and each (gather_impl, kv_dtype) combination keys
-    a DISTINCT run fingerprint (an artifact from one variant can never
-    load as another's program)."""
+    serving registry, and each (read, kv_dtype) combination keys a
+    DISTINCT run fingerprint (an artifact from one variant can never
+    load as another's program): the registry carries what the rule
+    answered, so a steered engine's differs from an unsteered one's."""
     from pytorch_distributed_tpu.compilecache import serving_registry
 
     cfg, params = setup()
+    base = serving_registry(PagedEngine(
+        cfg, params, n_slots=2, block_len=8, prefill_chunk=8,
+    ))
+    steer_paged_read(gather_impl)
     eng = PagedEngine(cfg, params, n_slots=2, block_len=8,
-                      prefill_chunk=8, gather_impl=gather_impl,
-                      kv_dtype=kv_dtype)
+                      prefill_chunk=8, kv_dtype=kv_dtype)
     reg = serving_registry(eng)
     eng.warm_decode()
     eng.warm_chunk(1, 1)
     reg.assert_covers(eng.compiled_program_names())
-    base = serving_registry(PagedEngine(
-        cfg, params, n_slots=2, block_len=8, prefill_chunk=8,
-    ))
     assert reg.fingerprint != base.fingerprint
 
 
-def test_registry_distinct_fingerprints_tier2_variants():
-    """Every tier-2 knob keys a distinct fingerprint: e4m3 vs e5m2 vs
-    int8 pools and split vs unsplit programs can never load each
-    other's compiled artifacts."""
+def test_registry_distinct_fingerprints_tier2_variants(steer_paged_read,
+                                                       monkeypatch):
+    """Every tier-2 variant keys a distinct fingerprint: e4m3 vs e5m2 vs
+    int8 pools and split vs unsplit programs (the tick's worker count,
+    as ``auto_split_s`` answers it) can never load each other's
+    compiled artifacts."""
     from pytorch_distributed_tpu.compilecache import serving_registry
 
     cfg, params = setup()
-    variants = [
-        dict(kv_dtype="int8"),
-        dict(kv_dtype="fp8"),
-        dict(kv_dtype="fp8_e5m2"),
-        dict(kv_dtype="fp8", split_s=2),
-        dict(kv_dtype="fp8", split_s=4),
-    ]
-    fps = [
-        serving_registry(PagedEngine(
+    steer_paged_read("pallas")
+    fps = []
+    for kv_dtype, split in [("int8", None), ("fp8", None),
+                            ("fp8_e5m2", None), ("fp8", 2), ("fp8", 4)]:
+        if split is not None:
+            monkeypatch.setattr(paged_flash, "auto_split_s",
+                                lambda w, b, split=split: split)
+        fps.append(serving_registry(PagedEngine(
             cfg, params, n_slots=2, block_len=8, prefill_chunk=8,
-            gather_impl="pallas", **kw,
-        )).fingerprint
-        for kw in variants
-    ]
+            kv_dtype=kv_dtype,
+        )).fingerprint)
     assert len(set(fps)) == len(fps), fps
 
 
@@ -582,13 +582,14 @@ def test_registry_distinct_fingerprints_tier2_variants():
 
 
 @pytest.mark.slow
-def test_kernel_smoke():
+def test_kernel_smoke(steer_paged_read):
     """One full pallas-path serve cycle on the int8 pool: submit →
     chunked prefill → decode → drain, token-identical to the replicated
     ``generate`` reference, blocks returned to the pool."""
     cfg, params = setup(max_seq_len=64)
+    steer_paged_read("pallas")
     s = Scheduler(cfg, params, n_slots=2, block_len=8, prefill_chunk=8,
-                  gather_impl="pallas", kv_dtype="int8")
+                  kv_dtype="int8")
     assert s.engine.gather_impl == "pallas"
     prompt = np.arange(1, 10, dtype=np.int32)
     rid = s.submit(prompt, 4)
@@ -598,29 +599,36 @@ def test_kernel_smoke():
 
 
 @pytest.mark.slow
-def test_fp8_serve_cycle_split_s():
+def test_fp8_serve_cycle_split_s(steer_paged_read, monkeypatch):
     """One full serve cycle on the fp8 pool with the split decode
-    (pallas gather, split_s=2): token-identical to the DENSE-gather
-    scheduler on the same pool dtype (the shared ``_pool_greedy_streams``
-    fixture — default gather is dense) — equal pools isolate the kernel
+    (pallas gather, two workers: ``auto_split_s`` steered, as a device
+    of two cores would answer a long chain): token-identical to the
+    DENSE-gather scheduler on the same pool dtype (the shared
+    ``_pool_greedy_streams`` fixture, read before the rule is steered —
+    unsteered the CPU gathers dense) — equal pools isolate the kernel
     spellings (quantization error is shared, bit-equal by the scatter
     test), leaving only ~1e-7 reduction-order noise. Blocks return to
     the pool."""
     cfg, params = setup()
+    want = _pool_greedy_streams("fp8")
     rng = np.random.default_rng(6)
     prompts = [rng.integers(1, cfg.vocab_size, (l,)).astype(np.int32)
                for l in (5, 9, 13, 7)]
+    steer_paged_read("pallas")
+    asked = []
+    monkeypatch.setattr(paged_flash, "auto_split_s",
+                        lambda w, b: asked.append((w, b)) or 2)
     s = Scheduler(cfg, params, n_slots=2, block_len=8, prefill_chunk=8,
-                  gather_impl="pallas", kv_dtype="fp8", split_s=2)
-    assert s.engine.config.split_s == 2
+                  kv_dtype="fp8")
     rids = [s.submit(p, 6) for p in prompts]
     out = s.drain()
-    assert tuple(tuple(out[r]) for r in rids) == _pool_greedy_streams("fp8")
+    assert (s.engine.table_width, 2) in asked  # the tick's [B, W] table
+    assert tuple(tuple(out[r]) for r in rids) == want
     assert s.engine.allocator.in_use == 0
 
 
 @pytest.mark.slow
-def test_chunked_vs_whole_prefill_pallas():
+def test_chunked_vs_whole_prefill_pallas(steer_paged_read):
     """Chunk boundaries cannot change the kernel's math: a 29-token
     prompt prefilled in 8-token chunks streams the same greedy tokens
     as whole-prompt prefill (the ``generate`` reference IS the
@@ -629,8 +637,8 @@ def test_chunked_vs_whole_prefill_pallas():
     rng = np.random.default_rng(3)
     prompt = rng.integers(1, cfg.vocab_size, (29,)).astype(np.int32)
     ref = greedy_reference(cfg, params, prompt, 4)
-    b = ContinuousBatcher(cfg, params, n_slots=1, prefill_bucket=8,
-                          gather_impl="pallas")
+    steer_paged_read("pallas")
+    b = ContinuousBatcher(cfg, params, n_slots=1, prefill_bucket=8)
     b.submit(prompt, 4)
     got = []
     while any(b.remaining > 0):
@@ -668,7 +676,7 @@ def _drive_batcher(b, prompts, budgets):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("kv_heads", [None, 2])
-def test_pallas_batcher_matches_dense_gather(kv_heads):
+def test_pallas_batcher_matches_dense_gather(steer_paged_read, kv_heads):
     """Staggered admissions, slot reuse, mixed budgets, MHA and GQA:
     the pallas gather must emit token-identical greedy streams to the
     dense gather over the same block pool."""
@@ -677,14 +685,14 @@ def test_pallas_batcher_matches_dense_gather(kv_heads):
     prompts = [rng.integers(1, cfg.vocab_size, (l,)).astype(np.int32)
                for l in (7, 13, 4, 21)]
     budgets = [6, 10, 8, 5]
+    steer_paged_read("dense")
     dense = _drive_batcher(
-        ContinuousBatcher(cfg, params, n_slots=2, prefill_bucket=8,
-                          gather_impl="dense"),
+        ContinuousBatcher(cfg, params, n_slots=2, prefill_bucket=8),
         prompts, budgets,
     )
+    steer_paged_read("pallas")
     pallas = _drive_batcher(
-        ContinuousBatcher(cfg, params, n_slots=2, prefill_bucket=8,
-                          gather_impl="pallas"),
+        ContinuousBatcher(cfg, params, n_slots=2, prefill_bucket=8),
         prompts, budgets,
     )
     assert dense == pallas
@@ -694,7 +702,8 @@ def test_pallas_batcher_matches_dense_gather(kv_heads):
 @pytest.mark.parametrize("kv_heads,kv_dtype", [
     (None, None), (2, None), (2, "int8"),
 ])
-def test_pallas_batcher_tp_matches_dense(kv_heads, kv_dtype):
+def test_pallas_batcher_tp_matches_dense(steer_paged_read, kv_heads,
+                                         kv_dtype):
     """TP=2 CPU mesh: the pallas kernel under shard_map (head-sharded
     pool AND head-sharded scale siblings for int8) matches the
     replicated DENSE-layout batcher token-for-token, GQA included."""
@@ -717,9 +726,9 @@ def test_pallas_batcher_tp_matches_dense(kv_heads, kv_dtype):
                           cache_layout="dense"),
         prompts, budgets,
     )
+    steer_paged_read("pallas")
     tp = ContinuousBatcher(tpcfg, params, n_slots=2, prefill_bucket=8,
-                           mesh=mesh, gather_impl="pallas",
-                           kv_dtype=kv_dtype)
+                           mesh=mesh, kv_dtype=kv_dtype)
     assert _drive_batcher(tp, prompts, budgets) == dense_rep
     # the pool — and for int8 its scale siblings — really are sharded
     pools, scales = _pools_and_scales(tp.cache)
@@ -732,7 +741,7 @@ def test_pallas_batcher_tp_matches_dense(kv_heads, kv_dtype):
 
 
 @pytest.mark.slow
-def test_pallas_batcher_tp_fp8_matches_single_device():
+def test_pallas_batcher_tp_fp8_matches_single_device(steer_paged_read):
     """TP=2 CPU mesh on the fp8 pool: quantization is per-row-per-head
     (head-local math), so head-sharding cannot change it — the TP
     batcher must match a SINGLE-DEVICE fp8 pallas batcher token-for-
@@ -753,14 +762,14 @@ def test_pallas_batcher_tp_fp8_matches_single_device():
     prompts = [rng.integers(1, rep.vocab_size, (l,)).astype(np.int32)
                for l in (5, 11, 7)]
     budgets = [6, 6, 6]
+    steer_paged_read("pallas")
     single = _drive_batcher(
         ContinuousBatcher(rep, params, n_slots=2, prefill_bucket=8,
-                          gather_impl="pallas", kv_dtype="fp8"),
+                          kv_dtype="fp8"),
         prompts, budgets,
     )
     tp = ContinuousBatcher(tpcfg, params, n_slots=2, prefill_bucket=8,
-                           mesh=mesh, gather_impl="pallas",
-                           kv_dtype="fp8")
+                           mesh=mesh, kv_dtype="fp8")
     assert _drive_batcher(tp, prompts, budgets) == single
     pools, scales = _pools_and_scales(tp.cache)
     assert pools[0].dtype == jnp.float8_e4m3fn

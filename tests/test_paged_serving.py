@@ -139,50 +139,75 @@ def test_paged_attention_matches_masked_reference(h_kv, c):
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("backend,on_config,to_engine,want", [
-    ("cpu", None, None, "dense"),
-    ("gpu", None, None, "dense"),
-    ("tpu", None, None, "pallas"),
-    ("tpu", "dense", None, "dense"),
-    ("tpu", None, "dense", "dense"),
-    ("cpu", "pallas", None, "pallas"),
-    ("cpu", None, "pallas", "pallas"),
-    ("tpu", "pallas", "dense", "dense"),
+@pytest.mark.parametrize("backend,want", [
+    ("cpu", "dense"), ("gpu", "dense"), ("tpu", "pallas"),
 ])
-def test_the_backend_decides_the_paged_read_unless_one_is_named(
-        monkeypatch, backend, on_config, to_engine, want):
-    """One rule, ``ops.attention.resolve_gather_impl``: unnamed, a decode
-    tick reads through the fused kernel where the backend is a TPU and
+def test_the_backend_and_the_rows_decide_the_paged_read(
+        monkeypatch, backend, want):
+    """One rule, ``ops.attention.default_gather_impl``: a decode tick
+    reads through the fused kernel where the backend is a TPU and
     through the dense gather on every other, and a chunk's wider rows
-    gather dense everywhere; a spelling named on the config or to a
-    constructor wins for every program (the constructor's over the
-    config's). The engine reports its tick's read and keeps a named
-    spelling on its config, where the fingerprint keys it."""
+    gather dense everywhere. The engine reports its tick's read."""
     from pytorch_distributed_tpu.ops.attention import (
         KERNEL_MAX_ROWS,
         default_gather_impl,
-        resolve_gather_impl,
     )
 
     cfg, params = setup()
-    assert cfg.gather_impl is None
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    on_tpu = backend == "tpu"
-    assert default_gather_impl() == ("pallas" if on_tpu else "dense")
-    assert default_gather_impl(KERNEL_MAX_ROWS) == default_gather_impl()
+    assert default_gather_impl() == want
+    assert default_gather_impl(KERNEL_MAX_ROWS) == want
     assert default_gather_impl(rows=32) == "dense"  # a chunk's rows
-    named = to_engine if to_engine is not None else on_config
-    assert resolve_gather_impl(named) == want
-    if named is not None:
-        assert resolve_gather_impl(named, rows=32) == named
-    if on_config is not None:
-        cfg = dataclasses.replace(cfg, gather_impl=on_config)
-    eng = PagedEngine(cfg, params, 2, block_len=8, prefill_chunk=8,
-                      gather_impl=to_engine)
+    eng = PagedEngine(cfg, params, 2, block_len=8, prefill_chunk=8)
     assert eng.gather_impl == want
-    assert eng.config.gather_impl == named
-    with pytest.raises(ValueError, match="gather_impl"):
-        dataclasses.replace(cfg, gather_impl="nope")
+
+
+def _serve_lm(monkeypatch):
+    import importlib.util
+    import os
+
+    recipes = os.path.join(os.path.dirname(__file__), os.pardir, "recipes")
+    monkeypatch.syspath_prepend(recipes)
+    spec = importlib.util.spec_from_file_location(
+        "serve_lm", os.path.join(recipes, "serve_lm.py"))
+    serve_lm = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(serve_lm)
+    return serve_lm
+
+
+@pytest.mark.parametrize("taker", [
+    "TransformerConfig", "PagedEngine", "Scheduler", "ContinuousBatcher",
+    "FleetRouter", "serve_lm",
+])
+def test_nothing_takes_a_paged_read_option(taker, monkeypatch, capsys):
+    """Which program reads the pool, and with how many workers, is
+    ``ops.attention.default_gather_impl``'s and
+    ``ops.paged_flash.auto_split_s``'s to say: no config field, no
+    constructor keyword and no flag names a spelling, a worker count or
+    a tuned file."""
+    from pytorch_distributed_tpu.fleet import FleetRouter
+
+    cfg, params = setup()
+    build = {
+        "TransformerConfig": lambda **kw: dataclasses.replace(cfg, **kw),
+        "PagedEngine": lambda **kw: PagedEngine(cfg, params, 2, **kw),
+        "Scheduler": lambda **kw: Scheduler(cfg, params, 2, **kw),
+        "ContinuousBatcher": lambda **kw: ContinuousBatcher(
+            cfg, params, 2, **kw),
+        "FleetRouter": lambda **kw: FleetRouter(
+            cfg, params, n_replicas=1, n_slots=2, **kw),
+    }
+    for option, value in (("gather_impl", "dense"), ("split_s", 1),
+                          ("autotune_dir", "tuned")):
+        if taker == "serve_lm":
+            flag = "--" + option.replace("_", "-")
+            with pytest.raises(SystemExit) as refused:
+                _serve_lm(monkeypatch)._parse(["--tiny", flag, str(value)])
+            assert refused.value.code == 2  # argparse: unrecognized
+            assert flag in capsys.readouterr().err
+        else:
+            with pytest.raises(TypeError, match=option):
+                build[taker](**{option: value})
 
 
 def test_paged_attention_gather_impl_flag():

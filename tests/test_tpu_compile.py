@@ -369,8 +369,8 @@ CELLS = {"chat-backlog": (POOL_CELL, {}),
 ])
 def test_the_served_tick_reads_through_the_kernel_where_the_backend_is_a_tpu(
         v5e, monkeypatch, backend, cell, program):
-    """Nobody names a ``gather_impl`` here, as the benchmark's jobs and
-    ``recipes/serve_lm.py`` name none. Where the program asks
+    """No constructor takes a read: ``ops.attention.default_gather_impl``
+    chooses. Where the program asks
     ``jax.default_backend()`` and hears ``tpu`` (steered here, in the
     test: the compile is for a described chip, the process's backend is
     the CPU), the decode tick holds the fused kernel, once a layer,
@@ -403,3 +403,30 @@ def test_the_served_tick_reads_through_the_kernel_where_the_backend_is_a_tpu(
         start = text.index(f"\n{name} (")
         body = text[start:text.index("\n}\n", start)]
         assert body.count('custom_call_target="tpu_custom_call"') == 2
+
+
+#: a tiny grouped-query engine at gpt2-medium's head width: 16 query
+#: heads over 2 narrow heads is a group of 8 (128 lanes a pool row, a
+#: block Mosaic accepts), over 1 a group of 16
+GROUPED_CELL = dict(heads=16, head_dim=64, slots=8, blocks=65, block_len=16,
+                    chunk=32, max_seq_len=128)
+
+
+@pytest.mark.parametrize("kv_heads,program,kernel", [
+    pytest.param(2, "decode_tick", True, id="group8-tick"),
+    pytest.param(1, "decode_tick", False, id="group16-tick"),
+    pytest.param(2, "chunk_prefill[k=4,w=8]", False, id="group8-chunk"),
+])
+def test_the_rule_counts_the_rows_a_grouped_head_brings(
+        v5e, monkeypatch, kv_heads, program, kernel):
+    """The rule's boundary, read through a program: a narrow head's
+    query group folds into the kernel's rows, so a tick of 8 query heads
+    a narrow head (8 rows, ``KERNEL_MAX_ROWS``) compiles the fused
+    kernel, a tick of 16 a narrow head does not, and a chunk's 8 x 32
+    rows do not."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lowered, _ = _engine_program(v5e, program, GROUPED_CELL,
+                                 num_kv_heads=kv_heads)
+    text = lowered.compile().as_text()
+    assert ("paged_decode_attn" in text) == kernel
+    assert ('custom_call_target="tpu_custom_call"' in text) == kernel
