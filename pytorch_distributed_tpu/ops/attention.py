@@ -20,8 +20,8 @@ TPU-first:
 - ``paged_attention`` is the serving engine's read path: decode/chunk
   queries against a block-pooled KV cache through a block table
   (``serving/kv_pool.py``) — a dense ``jnp.take``-over-blocks gather, or
-  the fused Pallas kernel (``ops/paged_flash.py``) that reads the table
-  from its BlockSpec index maps and never materializes the gather; both
+  the fused Pallas kernel (``ops/paged_flash.py``) that copies the blocks
+  its table names into VMEM itself and never materializes the gather; both
   spellings accept int8 pools with per-row scales.
 
 Shapes follow the JAX convention: ``[batch, length, heads, head_dim]``.
@@ -171,11 +171,14 @@ GATHER_IMPLS = ("dense", "pallas")
 #: most query rows a narrow head brings to a kernel step (group x chunk)
 #: for the fused kernel to be the unnamed read: one sublane tile, a decode
 #: tick's. Measured on a v5e (PERF.md section 6, PR 28): at 8 rows a live
-#: block costs the kernel 1.0 us and a dead one 0.1 us where the dense
+#: block cost the kernel 1.0 us and a dead one 0.1 us where the dense
 #: gather pays 1.4 us for either, so a decode tick over capacity-wide
-#: tables runs three to five times faster through the kernel; at a
-#: chunk's 32 rows a block costs the kernel 2.8 us, and a chunk program's
-#: table slice is cut to its prompts, so the dense gather wins there.
+#: tables ran three to five times faster through the kernel; at a
+#: chunk's 32 rows a block cost the kernel 2.8 us, and a chunk program's
+#: table slice is cut to its prompts, so the dense gather won there.
+#: Since PR 30 a grid step of the kernel is a tile of blocks (128
+#: positions); its chunk-row numbers are in PERF.md section 6, for the
+#: issue that may move this.
 KERNEL_MAX_ROWS = 8
 
 
@@ -237,10 +240,11 @@ def paged_attention(
         (``default_gather_impl``: the fused kernel for a decode tick's
         rows on a TPU, the dense gather for a chunk's rows and on every
         other backend); a named spelling wins. ``"pallas"`` — the fused
-        gather-attend kernel (``ops.paged_flash``): BlockSpec index maps read the
-        block table directly (scalar prefetch), so pool blocks DMA
-        HBM→VMEM in chain order, the gathered copy never exists and
-        blocks past a row's frontier do no work; runs the Pallas
+        gather-attend kernel (``ops.paged_flash``): the kernel reads the
+        block table from SMEM (scalar prefetch) and DMAs pool blocks
+        HBM→VMEM in chain order, a tile of consecutive chain blocks (128
+        positions) a grid step, so the gathered copy never exists and
+        tiles past a row's frontier copy and compute nothing; runs the Pallas
         interpreter on non-TPU backends, so both spellings execute
         everywhere. ``"dense"`` — one ``jnp.take`` over the block dim,
         materializing every slot's whole table in HBM as float32 (the

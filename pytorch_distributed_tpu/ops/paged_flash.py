@@ -10,25 +10,53 @@ spelling gathers every request's block chain back into a logical
 the full gathered KV in HBM on every decode tick, the exact cost
 PagedAttention (Kwon et al., SOSP 2023 — PAPERS.md) exists to avoid.
 Here the gather never materializes: the block table rides in as a
-scalar-prefetch operand (SMEM), and each KV block's BlockSpec *index
-map* resolves ``tables[b, j]`` — so the pipeline DMAs pool blocks
-HBM→VMEM in chain order directly, touching only the chain's blocks.
+scalar-prefetch operand (SMEM), the pools stay in HBM, and the kernel
+copies pool block ``tables[b, chain index]`` HBM→VMEM itself, in chain
+order, touching only the chain's live blocks.
 
 Structure (per the in-tree FlashAttention kernel,
 ``ops/flash_attention.py``, and the TPU Pallas playbook
 ``/opt/skills/guides/pallas_guide.md``):
 
-- grid ``(B, W)`` with the block-chain sweep innermost and sequential
-  ("arbitrary" semantics — it carries the online-softmax recurrence);
-  the running (m, l, acc) state lives in VMEM scratch, persisting across
-  the chain for each batch row, one slab per narrow head;
-- every block's last two dims equal its array's, the one block shape
-  Mosaic's tiling rule accepts at H_kv=12, D=64 (the interpreter does
-  not check it — every shape was refused on the chip until PR 21): a
-  staged K/V block is the whole ``[block_len, H_kv·D]`` pool block and
-  a static loop over narrow heads takes each head's D lanes,
-  ``k_ref[0, :, h·D:(h+1)·D]``; scale blocks are the block's whole
-  ``[block_len, H_kv]`` sibling; positions
+- a grid step is a TILE of ``T`` consecutive chain blocks: grid
+  ``(B, S, ceil(ceil(W/T)/S))`` with the tile sweep innermost and
+  sequential ("arbitrary" semantics — it carries the online-softmax
+  recurrence); the running (m, l, acc) state lives in VMEM scratch,
+  persisting across the chain for each batch row, one slab per narrow
+  head. ``T`` follows from shapes in one place, ``tile_blocks``:
+  ``TILE_POSITIONS`` (128) positions' worth of blocks — a vector
+  register's lanes of logits, so a head's two matrix products and three
+  slab updates are paid once for 128 positions, not once a 16-position
+  block — never more than the table is wide, cut so that the staged tile
+  stays under ``TILE_VMEM_BYTES``; a pool of 128-position blocks gets 1.
+  Nothing can set it: no argument, config field, flag or variable;
+- the tile's blocks reach VMEM by the kernel's own DMAs
+  (``pltpu.make_async_copy``, the design of JAX's ``paged_attention``):
+  two ``[T, block_len, H_kv·D]`` buffers a pool, the next live tile's
+  copies in flight while this one is attended. A tile wholly past its
+  row's query frontier is DEAD: no copy starts, nothing waits, no FLOPs
+  — 0.1 us of grid step. (Staging the pool ``T`` times through the
+  BlockSpec pipeline costs every grid step, dead ones too, 55 ns an
+  operand of bookkeeping: 1 us at ``T`` = 8, PERF.md section 6, PR 30.)
+  Where one core runs the grid in order and the sweep is unsplit, a
+  lane's last live tile starts the NEXT lane's first (``carry``), so
+  only the grid's first copy is waited for in the open;
+- a chain index is clamped to the row's LAST LIVE block
+  (``tile_entry``: ``min(entry, front // block_len, W - 1)``):
+  admission reserves a request's whole chain, so an entry past the
+  frontier names a real block of no use. A live tile's dead slabs hold
+  the last live block again (finite rows of the lane's own, masked by
+  their logical positions);
+- a copied K/V block is the whole ``[block_len, H_kv·D]`` pool block
+  (Mosaic's tiling rule accepts no narrower one at H_kv=12, D=64; the
+  interpreter does not check it — every shape was refused on the chip
+  until PR 21) and a static loop over narrow heads takes each head's D
+  lanes of the tile, ``k_buf[:, :, h·D:(h+1)·D]``, folded to
+  ``[T·block_len, D]``; a quantized pool's ``[block_len, H_kv]`` scale
+  siblings are too narrow a DMA for Mosaic and ride the BlockSpec
+  pipeline, ``T`` operands a sibling under the same clamp (a dead
+  step's index repeats, and the pipeline copies nothing for an index
+  that did not change); positions
   ride as a ``[B, r_pad, 1]`` column and each row's query frontier as a
   second scalar-prefetch operand;
 - GQA is folded into the row dimension: queries regroup to
@@ -40,19 +68,20 @@ Structure (per the in-tree FlashAttention kernel,
 - causal/frontier masking ``k_pos <= q_position`` per row; table
   entries past a request's allocation point at the trash block, whose
   logical positions exceed every live query position, so they mask out
-  exactly like the dense spelling. Blocks entirely past the batch row's
-  query frontier are skipped with ``pl.when`` (no FLOPs, no dequant);
+  exactly like the dense spelling. Tiles entirely past the batch row's
+  query frontier are skipped with ``pl.when`` (no copy, no FLOPs, no
+  dequant);
 - softmax statistics in fp32 regardless of pool/compute dtype;
 - quantized pools (int8 or fp8) dequantize INSIDE the kernel: per-
   (block, slot, head) scale siblings (``serving.kv_pool.quantize_kv``)
-  ride the same index maps as their pool, so the f32 K/V rows exist
-  only in VMEM, block by block — HBM holds 1-byte values + scales (the
+  follow the same table entries as their pool, so the f32 K/V rows exist
+  only in VMEM, a tile at a time — HBM holds 1-byte values + scales (the
   2D/(D+4) int8 / 2D/(D+1) fp8 pool-capacity win). fp8 scale siblings
   are int8 power-of-two exponents: the in-VMEM multiplier is ``2**e``
   (exact), so the fp8 cast is the whole error budget;
 - flash-decoding (round 20; FlashAttention-2's work partitioning,
   PAPERS.md §2, applied to decode): ``split_s`` > 1 splits the chain
-  sweep across S grid workers, each owning ``ceil(W/S)`` chain blocks
+  sweep across S grid workers, each owning ``ceil(ceil(W/T)/S)`` TILES
   with its own (m, l, acc) VMEM partials, and a second-stage cross-
   worker log-sum-exp merge (fp32, outside the kernel) combines them —
   one long-context request (W large, B small) fills the chip instead
@@ -98,6 +127,12 @@ from pytorch_distributed_tpu.ops.attention import NEG_INF, pool_heads
 SPLIT_THRESHOLD = 8
 #: auto policy's worker-count cap (forced ``split_s=`` may exceed it)
 MAX_SPLIT = 8
+#: positions a grid step covers (``tile_blocks``): a vector register's
+#: lanes of logits, so a head's two matrix products and three slab
+#: updates are paid once for 128 positions (PERF.md section 6, PR 30)
+TILE_POSITIONS = 128
+#: most VMEM the staged tile may take (``tile_blocks``)
+TILE_VMEM_BYTES = 4 << 20
 
 
 def device_cores() -> int:
@@ -126,41 +161,86 @@ def auto_split_s(w: int, b: int, *, threshold: int = SPLIT_THRESHOLD,
     return min(max_split, w)
 
 
-def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
-                  m_scr, l_scr, acc_scr, *, scale, k_start, h_kv, d,
-                  quantized, fp8_scales):
-    """One chain block's online-softmax update for every narrow head —
-    the shared inner body of the single-worker and split-S kernels (one
+def tile_blocks(w: int, block_len: int, row_bytes: int) -> int:
+    """``T``, the consecutive chain blocks one grid step stages — the ONE
+    place it is decided, from shapes alone: as many as make
+    ``TILE_POSITIONS`` positions, never more than the table is wide, and
+    cut so that the staged tile (``T`` blocks of ``block_len`` rows of
+    ``row_bytes``: a position's K and V rows and their scale siblings,
+    in two buffers each) stays under ``TILE_VMEM_BYTES``. A pool of 128-position blocks gets 1."""
+    t = min(w, max(1, TILE_POSITIONS // block_len))
+    return max(1, min(t, TILE_VMEM_BYTES // (2 * block_len * row_bytes)))
+
+
+def tile_entry(tables, front, b, entry, *, block_len: int):
+    """The pool block a tile's slab ``entry`` (a chain index of lane
+    ``b``) is copied from — the fused gather: the block table entry names
+    the DMA's source, so pool block ``tables[b, entry]`` goes straight
+    into VMEM and no gathered copy exists in HBM. ``entry`` is clamped to
+    the lane's LAST LIVE block (``front[b] // block_len``) and to the
+    table: admission reserves a request's whole chain, so an entry past
+    the frontier names a real block of no use, and a slab past it holds
+    the last live block again — finite rows, masked by their LOGICAL
+    positions, which lie past the frontier."""
+    live = front[b] // block_len
+    return tables[b, jnp.minimum(jnp.minimum(entry, live),
+                                 tables.shape[1] - 1)]
+
+
+def staged_row_bytes(*pools) -> int:
+    """Bytes one position holds across the leaves a step stages (K, V
+    and, where the pool is quantized, their scale siblings; ``None``
+    entries skipped): ``tile_blocks``' ``row_bytes``."""
+    return sum(x.shape[-1] * x.dtype.itemsize for x in pools
+               if x is not None)
+
+
+def _attend_tile(q_ref, qpos, k_buf, v_buf, ks_refs, vs_refs,
+                 m_scr, l_scr, acc_scr, *, scale, k_start, h_kv, d,
+                 quantized, fp8_scales):
+    """One tile's online-softmax update for every narrow head — the
+    shared inner body of the single-worker and split-S kernels (one
     spelling, so the split path cannot drift from the sweep it
-    partitions). The staged K/V block spans all of ``H_kv`` (the only
-    pool block shape Mosaic's tiling rule accepts); the static head
-    loop reads each head's ``[block_len, D]`` lanes out of it."""
+    partitions). The buffers hold the tile's ``T`` pool blocks as
+    ``[T, block_len, H_kv·D]`` (a DMA moves a whole pool block); the
+    static head loop reads each head's D lanes of all ``T`` and folds
+    them onto the sublanes, so ONE ``[R, D] x [D, T·block_len]`` product,
+    one softmax update and one ``[R, T·block_len] x [T·block_len, D]``
+    product serve the tile."""
+    def rows(x):  # [T, block_len, n] -> [T·block_len, n]
+        return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+
     if quantized:
-        # dequantize THIS block only, in VMEM: per-(slot, head) scale
-        # siblings gathered by the same table-driven index map. fp8
-        # pools carry int8 exponents — multiplier 2**e, exact in fp32
-        # (kv_pool.scale_factors spelling).
-        ks_all = ks_ref[0].astype(jnp.float32)  # [block_len, H_kv]
-        vs_all = vs_ref[0].astype(jnp.float32)
+        # dequantize THIS tile only, in VMEM: the per-(slot, head) scale
+        # siblings of its T blocks, staged by the table-driven index
+        # maps. fp8 pools carry int8 exponents — multiplier 2**e, exact
+        # in fp32 (kv_pool.scale_factors spelling).
+        ks_all, vs_all = (
+            jnp.concatenate([r[0].astype(jnp.float32) for r in refs], 0)
+            for refs in (ks_refs, vs_refs))  # [T·block_len, H_kv]
         if fp8_scales:
             ks_all = jnp.exp2(ks_all)
             vs_all = jnp.exp2(vs_all)
     for h in range(h_kv):
         q = q_ref[0, h]  # [R, D]
-        k = k_ref[0, :, h * d:(h + 1) * d]  # [block_len, D]
-        v = v_ref[0, :, h * d:(h + 1) * d]
+        lanes = slice(h * d, (h + 1) * d)
+        k = k_buf[:, :, lanes]  # [T, block_len, D]
+        v = v_buf[:, :, lanes]
+        if quantized:  # 1-byte rows widen before they fold (8-row tiles)
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        k, v = rows(k), rows(v)  # [T·block_len, D]
         if quantized:
-            k = k.astype(jnp.float32) * ks_all[:, h:h + 1]
-            v = v.astype(jnp.float32) * vs_all[:, h:h + 1]
+            k = k * ks_all[:, h:h + 1]
+            v = v * vs_all[:, h:h + 1]
             q = q.astype(jnp.float32)
         # fp32 logits on the MXU from the operands as stored, then the
-        # softmax scale on the [R, block_len] logits in fp32: scaling Q
+        # softmax scale on the [R, T·block_len] logits in fp32: scaling Q
         # in its own dtype rounds it wherever the scale is no power of
         # two (D=128), which the dense spelling's fp32 scale never did.
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [R, block_len]
+        ) * scale  # [R, T·block_len]
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # Frontier mask: key position j visible iff j <= the row's query
         # position. Trash-table entries (unallocated tail) carry logical
@@ -191,23 +271,59 @@ def _attend_block(q_ref, qpos, k_ref, v_ref, ks_ref, vs_ref,
 def _paged_kernel(
     tables_ref,  # scalar-prefetch [B, W] int32 (SMEM)
     front_ref,  # scalar-prefetch [B] int32: each row's query frontier
-    q_ref, qpos_ref, k_ref, v_ref,  # + (ks_ref, vs_ref) when quantized
-    *refs,
+    q_ref, qpos_ref, k_pool, v_pool,  # the pools, in HBM
+    *refs,  # T K-scale blocks and T V-scale blocks where quantized, ...
     scale: float, block_len: int, h_kv: int, d: int, quantized: bool,
-    fp8_scales: bool, w: int, wc: int, split: bool,
+    fp8_scales: bool, tile: int, n_tiles: int, wc: int, split: bool,
+    carry: bool,
 ):
-    """Grid ``(B, S, ceil(W/S))``: worker s sweeps chain blocks
-    ``[s*wc, min((s+1)*wc, W))`` with its own (m, l, acc) state. The
+    """Grid ``(B, S, ceil(n_tiles/S))``: worker s sweeps tiles
+    ``[s*wc, min((s+1)*wc, n_tiles))`` with its own (m, l, acc) state. The
     single-worker sweep (``split=False``, S == 1) normalizes in place;
     flash-decoding workers (``split=True``) emit their partials UN-
     normalized — the caller's fp32 log-sum-exp merge combines them. One
     kernel, so the split path cannot drift from the sweep it partitions.
-    Past-end grid steps of a ceil split clamp their index map to a real
-    block and are skipped by the ``j < W`` guard."""
-    del tables_ref  # consumed by the index maps
-    ks_ref, vs_ref = refs[:2] if quantized else (None, None)
-    *out_refs, m_scr, l_scr, acc_scr = refs[2:] if quantized else refs
-    jj = pl.program_id(2)
+
+    The pools stay in HBM and the kernel copies a tile's ``T`` blocks
+    into one of two VMEM buffers a pool itself, the next live tile's in
+    flight while this one is attended. (A quantized pool's scale
+    siblings, ``H_kv`` lanes wide, are no DMA Mosaic slices: they ride
+    the pipeline, ``T`` blocks a sibling, under the same clamp.) A tile
+    wholly past its lane's frontier (and the tail of a ceil split) is
+    DEAD: no copy starts for it and nothing waits. With ``carry`` (one
+    worker, grid steps in order on one core) a lane's last live tile
+    starts the NEXT lane's first, so only the grid's very first copy is
+    waited for in the open; without it each sweep starts its own first
+    tile."""
+    ks_refs = vs_refs = None
+    if quantized:
+        ks_refs, vs_refs, refs = refs[:tile], refs[tile:2 * tile], refs[
+            2 * tile:]
+    n_out = 3 if split else 1
+    out_refs, refs = refs[:n_out], refs[n_out:]
+    m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur = refs
+    pools, bufs = (k_pool, v_pool), (k_buf, v_buf)
+    b, jj = pl.program_id(0), pl.program_id(2)
+    j = pl.program_id(1) * wc + jj  # logical tile index of this step
+
+    def live(lane, jt):
+        return (jt < n_tiles) & (jt * tile * block_len <= front_ref[lane])
+
+    def copies(lane, jt, slot, known=True):
+        """The DMAs of tile ``jt`` of ``lane`` into buffer ``slot``;
+        ``known=False`` for a wait, which needs their shapes only."""
+        out = []
+        for t in range(tile):
+            blk = tile_entry(tables_ref, front_ref, lane, jt * tile + t,
+                             block_len=block_len) if known else 0
+            out += [pltpu.make_async_copy(pool.at[blk], buf.at[slot, t],
+                                          sem.at[i, slot])
+                    for i, (pool, buf) in enumerate(zip(pools, bufs))]
+        return out
+
+    def start(lane, jt, slot):
+        for copy in copies(lane, jt, slot):
+            copy.start()
 
     @pl.when(jj == 0)
     def _init():
@@ -215,17 +331,39 @@ def _paged_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    j = pl.program_id(1) * wc + jj  # logical chain index of this step
-    k_start = j * block_len
+    # the copy nobody started for us: the grid's first tile, or without
+    # ``carry`` each sweep's (a worker's range may lie past the frontier)
+    @pl.when((jj == 0) & ((b == 0) if carry else True))
+    def _first():
+        cur[0] = 0
 
-    # A chain block entirely past this batch row's query frontier
-    # contributes nothing — skip its FLOPs (and its dequant) entirely.
-    @pl.when((j < w) & (k_start <= front_ref[pl.program_id(0)]))
-    def _block():
-        _attend_block(q_ref, qpos_ref[0], k_ref, v_ref, ks_ref, vs_ref,
-                      m_scr, l_scr, acc_scr, scale=scale, k_start=k_start,
-                      h_kv=h_kv, d=d, quantized=quantized,
-                      fp8_scales=fp8_scales)
+        @pl.when(live(b, j))
+        def _():
+            start(b, j, 0)
+
+    # A tile entirely past this batch row's query frontier contributes
+    # nothing — no copy, no FLOPs, no dequant.
+    @pl.when(live(b, j))
+    def _tile():
+        slot = cur[0]
+        more = (jj + 1 < wc) & live(b, j + 1)
+
+        @pl.when(more)
+        def _():
+            start(b, j + 1, 1 - slot)
+
+        if carry:  # a lane's first tile is live: front >= 0
+            @pl.when(jnp.logical_not(more) & (b + 1 < pl.num_programs(0)))
+            def _():
+                start(b + 1, 0, 1 - slot)
+
+        for copy in copies(b, j, slot, known=False):
+            copy.wait()
+        _attend_tile(q_ref, qpos_ref[0], k_buf.at[slot], v_buf.at[slot],
+                     ks_refs, vs_refs, m_scr, l_scr, acc_scr, scale=scale,
+                     k_start=j * tile * block_len, h_kv=h_kv, d=d,
+                     quantized=quantized, fp8_scales=fp8_scales)
+        cur[0] = 1 - slot
 
     @pl.when(jj == wc - 1)
     def _finalize():
@@ -290,25 +428,32 @@ def paged_flash_attention(
         interpret = jax.default_backend() != "tpu"
     if split_s is not None and split_s < 1:
         raise ValueError(f"split_s must be >= 1, got {split_s}")
-    s_workers = split_s if split_s is not None else auto_split_s(w, b)
-    # What the backend and the device decide is resolved out here and
-    # rides in as static arguments: the traced function is then keyed by
-    # shapes and these alone, so the layers of a program share ONE trace
-    # and ONE lowered function (a program of 24 layers otherwise traces
-    # and lowers the kernel 24 times).
+    block_len, _ = pool_heads(k_pool, q.shape[2], d)
+    tile = tile_blocks(w, block_len, staged_row_bytes(
+        k_pool, v_pool, k_scale, v_scale))
+    # every worker owns >= 1 tile
+    s_workers = min(split_s if split_s is not None else auto_split_s(w, b),
+                    -(-w // tile))
+    # What the backend, the device and the shapes decide is resolved out
+    # here and rides in as static arguments: the traced function is then
+    # keyed by shapes and these alone, so the layers of a program share
+    # ONE trace and ONE lowered function (a program of 24 layers
+    # otherwise traces and lowers the kernel 24 times).
     return _paged_flash(
         q, k_pool, v_pool, block_tables, q_positions, k_scale, v_scale,
         scale=float(scale if scale is not None else d ** -0.5),
-        s_workers=min(s_workers, w),  # every worker owns >= 1 chain block
+        s_workers=s_workers, tile=tile,
+        # grid steps run in order where one core runs them all
+        carry=s_workers == 1 and device_cores() == 1,
         interpret=bool(interpret),
     )
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "s_workers", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "s_workers", "tile",
+                                             "carry", "interpret"))
 def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
-                 v_scale, *, scale: float, s_workers: int,
-                 interpret: bool):
+                 v_scale, *, scale: float, s_workers: int, tile: int,
+                 carry: bool, interpret: bool):
     """``paged_flash_attention`` with everything static decided (a
     pool that does not fit its queries or scales raises while tracing)."""
     from pytorch_distributed_tpu.serving.kv_pool import is_quantized_pool
@@ -330,7 +475,8 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
     group = h // h_kv
     w = block_tables.shape[1]
     split = s_workers > 1
-    wc = -(-w // s_workers)  # chain blocks per worker (ceil split)
+    n_tiles = -(-w // tile)  # grid steps a chain, T blocks each
+    wc = -(-n_tiles // s_workers)  # tiles per worker (ceil split)
 
     # GQA fold: query head h = kv·group + g reads narrow head kv, so the
     # per-narrow-head row block is its whole query group × chunk. Rows
@@ -348,33 +494,38 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
         qpos = jnp.pad(qpos, ((0, 0), (0, r_pad - r)), constant_values=-1)
 
-    # Every block's last two dims equal its array's (Mosaic's tiling
-    # rule; the interpreter does not check it): positions ride as a
-    # [B, r_pad, 1] column, pool blocks span all of H_kv, scale blocks
-    # are the pool block's whole [block_len, H_kv] sibling. Index maps
-    # take the grid position (b, s, j) and the two scalar-prefetch refs.
-    def pool_block(b, s, j, tables, front):
-        # the fused gather: the block table entry IS the index map —
-        # the pipeline DMAs pool block tables[b, chain index] straight
-        # into VMEM, no gathered copy in HBM. Grid steps past the real
-        # chain (ceil-split tail) clamp to its last block; the kernel's
-        # ``j < w`` guard keeps them out of the statistics.
-        return tables[b, jnp.minimum(s * wc + j, w - 1)]
+    # Queries and positions ride the pipeline, a batch row a block (its
+    # last two dims equal the array's: Mosaic's tiling rule, which the
+    # interpreter does not check; positions are a [B, r_pad, 1] column).
+    # The pools stay where they are: the kernel DMAs whole pool blocks,
+    # [block_len, H_kv·D], into buffers of T blocks, two a pool. A scale
+    # sibling's [block_len, H_kv] block is too narrow for such a DMA and
+    # rides the pipeline: T operands a sibling, operand t staging the
+    # tile's entry t (``tile_entry``: past the frontier its index
+    # repeats, and the pipeline copies nothing for an index that did not
+    # change). Index maps take the grid position (b, s, j) and the two
+    # scalar-prefetch refs.
+    def staged(scales):
+        return [
+            pl.BlockSpec(
+                (1,) + scales.shape[1:],
+                lambda b, s, j, tables, front, t=t: (tile_entry(
+                    tables, front, b, (s * wc + j) * tile + t,
+                    block_len=block_len), 0, 0))
+            for t in range(tile)
+        ]
 
     row_spec = pl.BlockSpec((1, h_kv, r_pad, d),
                             lambda b, s, j, *_: (b, 0, 0, 0))
-    pool_spec = pl.BlockSpec((1, block_len, h_kv * d),
-                             lambda *a: (pool_block(*a), 0, 0))
     in_specs = [
         row_spec,
         pl.BlockSpec((1, r_pad, 1), lambda b, s, j, *_: (b, 0, 0)),
-        pool_spec, pool_spec,
+        pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [q4, qpos[:, :, None], k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, block_len, h_kv),
-                                  lambda *a: (pool_block(*a), 0, 0))] * 2
-        operands += [k_scale, v_scale]
+        in_specs += staged(k_scale) + staged(v_scale)
+        operands += [k_scale] * tile + [v_scale] * tile
     if split:
         # each worker's un-normalized (acc, m, l), merged below
         parts = [(h_kv, r_pad, d), (h_kv, r_pad, 128), (h_kv, r_pad, 128)]
@@ -395,8 +546,8 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, scale=scale, block_len=block_len, h_kv=h_kv,
-            d=d, quantized=bool(quantized), fp8_scales=fp8_scales, w=w,
-            wc=wc, split=split,
+            d=d, quantized=bool(quantized), fp8_scales=fp8_scales,
+            tile=tile, n_tiles=n_tiles, wc=wc, split=split, carry=carry,
         ),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -408,13 +559,20 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
                 pltpu.VMEM((h_kv, r_pad, 128), jnp.float32),  # row max m
                 pltpu.VMEM((h_kv, r_pad, 128), jnp.float32),  # row sum l
                 pltpu.VMEM((h_kv, r_pad, d), jnp.float32),  # un-normalized
+                # a tile of K and of V, twice: [2, T, block_len, H_kv·D]
+                pltpu.VMEM((2, tile) + k_pool.shape[1:], k_pool.dtype),
+                pltpu.VMEM((2, tile) + v_pool.shape[1:], v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),  # [K or V, buffer]
+                pltpu.SMEM((1,), jnp.int32),  # the buffer in use
             ],
         ),
         interpret=interpret,
         name="paged_decode_attn",
         **kwargs,
-    )(block_tables.astype(jnp.int32), jnp.max(q_positions, axis=1),
-      *operands)
+    )(block_tables.astype(jnp.int32),
+      # a lane's first tile is always live (a row of padding alone,
+      # position -1, still masks every key)
+      jnp.maximum(jnp.max(q_positions, axis=1), 0), *operands)
     if not split:
         out4 = out
     else:

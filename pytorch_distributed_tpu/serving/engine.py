@@ -242,16 +242,36 @@ class PagedEngine:
                 leaf.size * leaf.dtype.itemsize
                 for leaf in jax.tree.leaves(self.cache)
             ) // n_blocks
+            cache_layers = config.num_layers * config.ut_steps
+            # ``T``, the chain blocks a grid step of the tick's kernel
+            # stages (``ops.paged_flash.tile_blocks``, from the bytes a
+            # position holds in one cache layer on one shard); 1 where
+            # the tick gathers dense
+            self.tile_blocks = 1
+            if self.gather_impl == "pallas":
+                from pytorch_distributed_tpu.ops.paged_flash import (
+                    tile_blocks,
+                )
+
+                self.tile_blocks = tile_blocks(
+                    self.table_width, block_len,
+                    self._per_block_bytes
+                    // (cache_layers * block_len * config.tp_size))
             # ``read``: the paged read the programs compile;
             # ``table_blocks``: the blocks a decode tick's tables name,
-            # live or not (the fused kernel's grid steps a layer), which
-            # ``engine.decode.launch``'s ``live_blocks`` is a share of
+            # live or not, which ``engine.decode.launch``'s
+            # ``live_blocks`` is a share of; ``table_tiles``: the fused
+            # kernel's grid steps a layer, ``tile_blocks`` entries each,
+            # which ``live_tiles`` is a share of
             alloc.args.update(
                 weight_layers=config.num_layers,
-                cache_layers=config.num_layers * config.ut_steps,
+                cache_layers=cache_layers,
                 block_bytes=self._per_block_bytes,
                 read=self.gather_impl,
                 table_blocks=n_slots * self.table_width,
+                tile_blocks=self.tile_blocks,
+                table_tiles=n_slots * -(-self.table_width
+                                        // self.tile_blocks),
             )
 
         self._chunk_fns: Dict[Tuple[int, int], callable] = {}
@@ -1383,12 +1403,15 @@ class PagedEngine:
             # call would be an IMPLICIT transfer the no_recompile guard
             # rightly rejects.
             # live_blocks: the blocks up to each active lane's position,
-            # the part of the tables' ``table_blocks`` a tick has to read
+            # the part of the tables' ``table_blocks`` a tick has to read;
+            # live_tiles: the kernel's grid steps that hold one of them
+            live = positions[active] // self.block_len
             with spans.tracer().span(
                     "engine.decode.launch",
                     lanes=int(np.count_nonzero(active)),
-                    live_blocks=int(np.sum(
-                        positions[active] // self.block_len + 1))), \
+                    live_blocks=int(np.sum(live + 1)),
+                    live_tiles=int(np.sum(
+                        live // self.tile_blocks + 1))), \
                     program_load_if(not self._hot_decode,
                                     self.DECODE_PROGRAM):
                 positions, active, masked = jax.device_put(

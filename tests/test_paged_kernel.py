@@ -34,6 +34,9 @@ from pytorch_distributed_tpu.ops.paged_flash import (
     device_cores,
     paged_flash_attention,
     paged_quantize_scatter,
+    staged_row_bytes,
+    tile_blocks,
+    tile_entry,
 )
 from pytorch_distributed_tpu.serving import PagedEngine, Scheduler
 from pytorch_distributed_tpu.serving.engine import ChunkJob
@@ -92,17 +95,110 @@ def random_pool(rng, b, h_kv, d, bl, w, quantize=False):
             jnp.asarray(tables), scales)
 
 
+@pytest.fixture
+def tile_of(monkeypatch):
+    """``tile_of(t, block_len)``: make the kernel stage ``t`` chain blocks
+    a grid step for the rest of the test (where the table is that wide).
+    ``T`` follows from shapes and one module constant
+    (``paged_flash.tile_blocks``) and no argument names it, so a test
+    that wants several tiles across a toy table steers the constant.
+    ``t=None`` leaves the rule as it ships."""
+    def steer(t, block_len):
+        if t is not None:
+            monkeypatch.setattr(paged_flash, "TILE_POSITIONS", t * block_len)
+
+    return steer
+
+
+# ---------------------------------------------------------------------------
+# the tile: how many chain blocks a grid step stages, and which
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("w,block_len,row_bytes,want", [
+    pytest.param(64, 16, 4096, 8, id="chat-backlog-bf16"),
+    pytest.param(40, 16, 8192, 8, id="reason-backlog-bf16"),
+    pytest.param(64, 16, 2 * 1024 + 2 * 64, 8, id="chat-backlog-int8"),
+    pytest.param(6, 16, 4096, 6, id="table-narrower-than-a-tile"),
+    pytest.param(8, 128, 4096, 1, id="blocks-of-128-the-parent-grid"),
+    pytest.param(8, 256, 4096, 1, id="blocks-wider-than-a-tile"),
+    pytest.param(64, 16, 32768, 4, id="cut-to-the-vmem-share"),
+    pytest.param(64, 16, 1 << 20, 1, id="never-under-one"),
+])
+def test_tile_blocks_follows_the_shapes(w, block_len, row_bytes, want):
+    """``T`` is 128 positions' worth of blocks, at most the table, cut so
+    that both pipeline buffers of the staged tile stay under 4 MiB."""
+    assert tile_blocks(w, block_len, row_bytes) == want
+    assert 2 * want * block_len * row_bytes <= max(
+        paged_flash.TILE_VMEM_BYTES, 2 * block_len * row_bytes)
+
+
+def test_staged_row_bytes_counts_scale_siblings():
+    pool = jnp.zeros(pool_leaf_shape(3, 16, 16, 64), jnp.int8)
+    scale = jnp.zeros(pool_leaf_shape(3, 16, 16, 64, scale=True))
+    assert staged_row_bytes(pool.astype(jnp.bfloat16),
+                            pool.astype(jnp.bfloat16), None, None) == 4096
+    assert staged_row_bytes(pool, pool, scale, scale) == 2 * 1024 + 2 * 64
+    assert staged_row_bytes(pool, pool, scale.astype(jnp.int8),
+                            scale.astype(jnp.int8)) == 2 * 1024 + 2 * 16
+
+
+@pytest.mark.parametrize("tile", [1, 2, 4, 8])
+def test_a_dead_entry_repeats_the_lanes_last_live_block(tile):
+    """Which pool block a tile's slab is copied from
+    (``paged_flash.tile_entry``), called with concrete tables and
+    frontiers: up to the lane's frontier the chain's own blocks in order;
+    past it (an entry the admission reserved, a trash entry, the slabs of
+    a last tile that overhangs the table) the lane's LAST LIVE block
+    again — so a scale sibling's index map repeats on a dead grid step
+    and the pipeline copies nothing, and a live tile's dead slabs hold
+    finite rows of the lane's own."""
+    bl = 4
+    tables = jnp.asarray([[11, 12, 13, 14, 15, 16],  # whole chain reserved
+                          [21, 22, 23, 0, 0, 0],  # trash past its chain
+                          [0, 0, 0, 0, 0, 0]], jnp.int32)  # inactive lane
+    front = jnp.asarray([9, 2, 17], jnp.int32)  # live blocks: 3, 1, (5)
+    n_tiles = -(-6 // tile)
+    for b, live in enumerate((3, 1, 5)):
+        steps = np.asarray([[int(tile_entry(tables, front, b, j * tile + t,
+                                            block_len=bl))
+                             for t in range(tile)] for j in range(n_tiles)])
+        entries, chain = steps.reshape(-1), np.asarray(tables[b])
+        assert list(entries[:live]) == list(chain[:live])
+        assert set(entries[live - 1:]) == {chain[live - 1]}
+        # a dead grid step asks for exactly what the step before held
+        first_dead = -(-live // tile)
+        for dead in range(first_dead, n_tiles):
+            assert list(steps[dead]) == [chain[live - 1]] * tile
+            if dead > first_dead:
+                assert list(steps[dead]) == list(steps[dead - 1])
+
+
 # ---------------------------------------------------------------------------
 # op-level parity: the fused kernel vs the dense gather (fast tier)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("h_kv,c", [(4, 1), (4, 5), (2, 5), (2, 1)])
-def test_paged_flash_matches_dense_gather(h_kv, c):
+@pytest.mark.parametrize("cores", [1, 2])
+@pytest.mark.parametrize("tile", [1, 2, 8])
+@pytest.mark.parametrize("h,h_kv,c", [(4, 4, 1), (4, 4, 5), (4, 2, 5),
+                                      (4, 2, 1), (16, 2, 1)])
+def test_paged_flash_matches_dense_gather(tile_of, monkeypatch, h, h_kv, c,
+                                          tile, cores):
     """Same pools, same tables, same positions: the pallas spelling must
     reproduce the dense spelling — decode (C=1) and chunk (C=5) rows,
-    MHA and GQA groupings, ragged per-request frontiers."""
-    b, h, d, bl, w = 2, 4, 8, 4, 3
+    MHA and GQA groupings (up to the 8 query rows a narrow head brings to
+    a decode tick), ragged per-request frontiers; one block a grid step
+    (the parent's grid), tiles of two blocks (which do not divide the
+    table's three: the second lane's frontier lies in the middle of its
+    first tile, its only live one) and one tile that holds the table. On
+    a device of one core a lane's last live tile starts the copies of the
+    next lane's first; where a second core may take lanes of its own
+    (``device_cores`` steered: the grid is then not one sequence) every
+    lane starts its own."""
+    b, d, bl, w = 2, 8, 4, 3
+    tile_of(tile, bl)
+    monkeypatch.setattr(paged_flash, "device_cores", lambda: cores)
     rng = np.random.default_rng(0)
     kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
     q = jnp.asarray(rng.normal(size=(b, c, h, d)).astype(np.float32))
@@ -119,23 +215,36 @@ def test_paged_flash_matches_dense_gather(h_kv, c):
     )
 
 
-@pytest.mark.parametrize("c", [1, 32])
-def test_paged_flash_matches_dense_at_the_served_shapes(c):
+@pytest.mark.parametrize("c,bl,w,tile", [
+    (1, 16, 6, 1), (1, 16, 6, 4), (1, 16, 6, None),
+    (32, 16, 6, 1), (32, 16, 6, 4), (32, 16, 6, None),
+    pytest.param(1, 128, 2, None, id="blocks-of-128"),
+])
+def test_paged_flash_matches_dense_at_the_served_shapes(tile_of, c, bl, w,
+                                                        tile):
     """The read the serving cells compile on a TPU against the one they
     compiled before, at gpt2-medium.chat-backlog's attention (16 heads of
     64 over a bfloat16 pool, blocks of 16): a decode tick (C=1) and a
     chunk (C=32), frontiers ragged across the rows, and every table
     padded past its row's allocation with the trash block, which holds
-    garbage. Both spellings compute in float32 from the same stored
-    bfloat16, so they part only in the order of the sums: a bfloat16 ulp
-    of the output at most."""
-    b, h, d, bl, w = 3, 16, 64, 16, 6
+    garbage, and one lane inactive: every entry the trash block under a
+    stale position, as the engine masks a free slot. Both spellings
+    compute in float32 from the same stored bfloat16, so they part only
+    in the order of the sums: a bfloat16 ulp of the output at most. The
+    grid steps a block at a time (the parent's), four blocks at a time
+    (which do not divide the six) and as the rule ships it (128
+    positions: the whole table of six; one block where a block is 128)."""
+    b, h, d = 4, 16, 64
+    tile_of(tile, bl)
+    assert tile_blocks(w, bl, 4 * h * d) == (
+        tile or min(w, max(1, 128 // bl)))
     rng = np.random.default_rng(28)
     kp, vp, tables, _ = random_pool(rng, b, h, d, bl, w)
     kp = kp.at[0].set(37.0).astype(jnp.bfloat16)  # the trash block
     vp = vp.at[0].set(-53.0).astype(jnp.bfloat16)
-    ends = np.array([w * bl - 1, 41, 17 + c])  # last query's position
+    ends = np.array([w * bl - 1, 41, 17 + c, 21 + c])  # last query's
     live = ends // bl + 1  # blocks a row was allocated
+    live[3] = 0  # the inactive lane
     tables = jnp.where(np.arange(w)[None, :] < live[:, None], tables, 0)
     q = jnp.asarray(rng.normal(size=(b, c, h, d)), jnp.bfloat16)
     q_positions = jnp.asarray(
@@ -146,19 +255,24 @@ def test_paged_flash_matches_dense_at_the_served_shapes(c):
                              gather_impl="pallas")
     assert pallas.dtype == dense.dtype == jnp.bfloat16
     got, want = (np.asarray(x, np.float32) for x in (pallas, dense))
-    assert np.abs(want).max() < 10  # nothing of the trash block came in
+    assert np.abs(want[:3]).max() < 10  # nothing of the trash block came in
     np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
 
 
+@pytest.mark.parametrize("tile", [1, 2, 8])
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
 @pytest.mark.parametrize("c", [1, 5])
-def test_paged_flash_int8_matches_dense_int8(c):
+def test_paged_flash_int8_matches_dense_int8(tile_of, c, kv_dtype, tile):
     """Both spellings dequantize the SAME stored rows, so on a quantized
-    pool they must agree to fp tolerance (the quantization error itself
-    is shared, not a divergence between them)."""
+    pool (int8 under float32 multipliers, fp8 under int8 exponents) they
+    must agree to fp tolerance (the quantization error itself is shared,
+    not a divergence between them); the scale siblings ride a tile the
+    way their pools do."""
     b, h, h_kv, d, bl, w = 2, 4, 2, 8, 4, 3
+    tile_of(tile, bl)
     rng = np.random.default_rng(1)
     kq, vq, tables, scales = random_pool(rng, b, h_kv, d, bl, w,
-                                         quantize=True)
+                                         quantize=kv_pool_dtype(kv_dtype))
     q = jnp.asarray(rng.normal(size=(b, c, h, d)).astype(np.float32))
     q_positions = jnp.asarray(
         np.stack([np.arange(c), np.arange(7, 7 + c)])[:b].astype(np.int32)
@@ -427,19 +541,25 @@ def test_quantize_scatter_rejects_raw_pools():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("split_s,c", [
-    (2, 1), (8, 5), (3, 1), (2, 5),
-    pytest.param(8, 1, marks=pytest.mark.slow),
-    pytest.param(3, 5, marks=pytest.mark.slow),
+@pytest.mark.parametrize("split_s,c,tile", [
+    (2, 1, 1), (8, 5, 1), (3, 1, 1), (2, 5, 1),
+    (2, 1, 2), (8, 1, 2), (8, 5, 5), (2, 5, 5), (3, 1, None),
+    pytest.param(8, 1, 1, marks=pytest.mark.slow),
+    pytest.param(3, 5, 1, marks=pytest.mark.slow),
 ])
-def test_split_s_matches_single_worker(split_s, c):
+def test_split_s_matches_single_worker(tile_of, split_s, c, tile):
     """The combine algebra under test: S workers' un-normalized
     (m, l, acc) partials merged by fp32 log-sum-exp must reproduce the
     single-worker sweep to <= 1e-3 (documented bound; measured ~1e-7 —
     the combine is a different fp32 reduction order, not a different
-    function). Decode (C=1) and chunk (C=5) rows, ragged frontiers, a
-    12-block chain so 8 workers leave some workers empty."""
+    function), and the dense gather as well. Decode (C=1) and chunk (C=5)
+    rows, ragged frontiers, a 12-block chain so 8 workers leave some
+    workers empty. Workers own ranges of TILES: six tiles of two blocks
+    under 2 and 8 workers (eight become six), three tiles of five (the
+    last holds two blocks) whose ceil split leaves a tail, and the
+    shipped rule's one tile, which one worker takes whatever was asked."""
     b, h, h_kv, d, bl, w = 2, 4, 2, 16, 4, 12
+    tile_of(tile, bl)
     rng = np.random.default_rng(8)
     kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
     q = jnp.asarray(rng.normal(size=(b, c, h, d)).astype(np.float32))
@@ -453,6 +573,10 @@ def test_split_s_matches_single_worker(split_s, c):
                                   split_s=split_s)
     err = np.abs(np.asarray(split) - np.asarray(single)).max()
     assert err <= 1e-3, f"split_s={split_s} parity err {err}"
+    dense = paged_attention(q, kp, vp, tables, q_positions,
+                            gather_impl="dense")
+    np.testing.assert_allclose(np.asarray(split), np.asarray(dense),
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.slow

@@ -274,18 +274,32 @@ def test_serving_tick_emits_the_names_in_order_within_budget(tracer):
     assert len(loaded) == len(set(loaded)) and "decode_tick" in loaded
 
 
-def test_the_read_and_its_live_share_ride_the_spans(tracer):
+@pytest.mark.parametrize("read,tile", [("dense", 1), ("pallas", 2),
+                                       ("pallas", 8)])
+def test_the_read_and_its_live_share_ride_the_spans(
+        tracer, steer_paged_read, monkeypatch, read, tile):
     """What sizes the paged read: ``pool.alloc`` says which spelling the
-    programs compile (``read``) and how many blocks a tick's tables name
-    (``table_blocks``: slots x table width, the fused kernel's grid steps
-    a layer), beside the ``blocks`` it had; each ``engine.decode.launch``
-    says how many of them hold a live position (``live_blocks``, beside
-    ``lanes``)."""
+    programs compile (``read``), how many blocks a tick's tables name
+    (``table_blocks``: slots x table width), how many of them a grid step
+    of the tick's kernel stages (``tile_blocks``: ``ops.paged_flash.
+    tile_blocks``' answer, 1 where the read is dense) and so how many
+    grid steps a layer takes (``table_tiles``), beside the ``blocks`` it
+    had; each ``engine.decode.launch`` says how many blocks hold a live
+    position (``live_blocks``, beside ``lanes``) and how many tiles do
+    (``live_tiles``)."""
+    from pytorch_distributed_tpu.ops import paged_flash
+
+    steer_paged_read(read)
+    # blocks of 8: a tile of 16 positions is two blocks, of 128 the
+    # whole table of eight
+    monkeypatch.setattr(paged_flash, "TILE_POSITIONS", 8 * tile)
     cfg, router = _tiny_router()
     engine = router.replicas[0].engine
     (alloc,) = tracer.events("pool.alloc")
-    assert alloc.args["read"] == engine.gather_impl == "dense"
+    assert alloc.args["read"] == engine.gather_impl == read
     assert alloc.args["table_blocks"] == 4 * (64 // 8) == engine.tables.size
+    assert alloc.args["tile_blocks"] == engine.tile_blocks == tile
+    assert alloc.args["table_tiles"] == 4 * (8 // tile)
     assert alloc.args["blocks"] == engine.allocator.n_blocks
     router.submit(np.arange(1, 21, dtype=np.int32), 6)  # 20 tokens
     router.submit(np.arange(1, 6, dtype=np.int32), 6)  # 5
@@ -295,11 +309,14 @@ def test_the_read_and_its_live_share_ride_the_spans(tracer):
              if e.args["lanes"] == 2]
     assert ticks
     # both prompts are in: the first tick with two lanes writes positions
-    # 20 and 5 (blocks of 8: three and one), and a lane's count follows
-    # its position from there
+    # 20 and 5 (blocks of 8: three and one; tiles of two blocks: two and
+    # one), and a lane's count follows its position from there
     assert ticks[0]["live_blocks"] == (20 // 8 + 1) + (5 // 8 + 1)
-    assert all(t["lanes"] <= t["live_blocks"] <= alloc.args["table_blocks"]
-               for t in ticks)
+    assert ticks[0]["live_tiles"] == (
+        20 // (8 * tile) + 1) + (5 // (8 * tile) + 1)
+    assert all(t["lanes"] <= t["live_tiles"] <= t["live_blocks"]
+               <= alloc.args["table_blocks"] for t in ticks)
+    assert all(t["live_tiles"] <= alloc.args["table_tiles"] for t in ticks)
     assert ticks[-1]["live_blocks"] > ticks[0]["live_blocks"]
 
 

@@ -220,12 +220,14 @@ POOL_CELL = dict(heads=16, head_dim=64, slots=64, blocks=2561, block_len=16,
                  chunk=32, max_seq_len=1024)
 
 
-def _engine_program(v5e, program, cell=None, **block):
-    """``PagedEngine``'s decode tick or its ``(4, 8)`` chunk program,
-    lowered for the described chip from shapes alone: the engine is built
-    on a two-block pool and the program takes the cell's whole pool as an
-    aval (a program does not hold the pool's size). ``block`` describes
-    another block kind than GPT-2's."""
+def _engine_program(v5e, program, cell=None, kv_dtype=None, bucket=(4, 8),
+                    **block):
+    """``PagedEngine``'s decode tick or a chunk program (the ``(4, 8)``
+    bucket unless told), lowered for the described chip from shapes
+    alone: the engine is built on a two-block pool and the program takes
+    the cell's whole pool as an aval (a program does not hold the pool's
+    size). ``block`` describes another block kind than GPT-2's,
+    ``kv_dtype`` a quantized pool."""
     from pytorch_distributed_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -245,9 +247,11 @@ def _engine_program(v5e, program, cell=None, **block):
                        jnp.zeros((1, 8), jnp.int32))["params"],
     )
     eng = PagedEngine(cfg, params, c["slots"], n_blocks=2,
-                      block_len=c["block_len"], prefill_chunk=c["chunk"])
+                      block_len=c["block_len"], prefill_chunk=c["chunk"],
+                      kv_dtype=kv_dtype)
     pool = jax.eval_shape(
-        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"]),
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
+                                   kv_dtype=kv_dtype),
         params,
     )
     one = SingleDeviceSharding(v5e.devices[0])
@@ -262,7 +266,7 @@ def _engine_program(v5e, program, cell=None, **block):
                 jnp.zeros((n,), bool),
                 jnp.zeros((n, eng.table_width), i32), jax.random.key(0))
     else:
-        k, w = 4, 8
+        k, w = bucket
         fn = eng._chunk_fn(k, w)
         assert eng.chunk_program_name(k, w) == program
         args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
@@ -430,3 +434,71 @@ def test_the_rule_counts_the_rows_a_grouped_head_brings(
     text = lowered.compile().as_text()
     assert ("paged_decode_attn" in text) == kernel
     assert ('custom_call_target="tpu_custom_call"' in text) == kernel
+
+
+# ---- the tile a grid step of the tick's kernel stages (PR 30) --------------
+
+
+@pytest.mark.parametrize("cell,kv_dtype", [
+    ("chat-backlog", None), ("chat-backlog", "int8"),
+    ("reason-backlog", None), ("reason-backlog", "int8"),
+])
+def test_the_tick_compiles_with_a_tile_of_blocks_a_grid_step(
+        v5e, monkeypatch, cell, kv_dtype):
+    """Both serving cells' decode ticks with the kernel as the rule sizes
+    it for their tables: eight blocks of 16 a grid step. Mosaic takes the
+    kernel's DMAs of whole pool blocks at rows of 1,024 and of 2,048
+    lanes, bfloat16 and int8 (whose scale siblings, 16 lanes wide, ride
+    the pipeline: a DMA of a block that narrow is refused), the program is
+    still ``jit_decode_tick`` and the kernel in it ``paged_decode_attn``."""
+    from pytorch_distributed_tpu.ops.paged_flash import (
+        staged_row_bytes,
+        tile_blocks,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c, block = CELLS[cell]
+    lowered, leaves = _engine_program(v5e, "decode_tick", c,
+                                      kv_dtype=kv_dtype, **block)
+    layers = len(leaves) // (4 if kv_dtype else 2)  # the program's two
+    row_bytes = staged_row_bytes(*leaves) // layers
+    w = -(-c["max_seq_len"] // c["block_len"])
+    assert tile_blocks(w, c["block_len"], row_bytes) == 8
+    assert lowered.as_text().startswith("module @jit_decode_tick ")
+    text = lowered.compile().as_text()
+    assert text.startswith("HloModule jit_decode_tick,")
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reads = [x for x in calls if "paged_decode_attn" in x]
+    assert len(reads) == 2, calls  # one a layer
+
+
+#: sha256 (12 hex digits) of the StableHLO text of chunk programs as the
+#: PARENT of PR 30 lowers them for a described v5e where the backend
+#: answers ``tpu`` (``_engine_program``: two layers, 512 tokens; computed
+#: from a clone of de6a59a with this file's helper). The chunk programs
+#: gather dense, so nothing in ``ops/paged_flash.py`` may move them: the
+#: serving cells' 30 + 2 compile-cache entries stay valid and
+#: ``prefill_chunk_device_ms`` is the control that does not move. A PR
+#: that means to change a chunk program records new digests here.
+CHUNK_DIGESTS = {
+    ("chat-backlog", (4, 8)): "37ed79f1586a",
+    ("chat-backlog", (1, 2)): "b63225f480f7",
+    ("reason-backlog", (2, 16)): "738ae8911195",
+}
+
+
+@pytest.mark.parametrize("cell,bucket", sorted(CHUNK_DIGESTS))
+def test_the_chunk_programs_lower_to_the_parents_text(v5e, monkeypatch, cell,
+                                                      bucket):
+    import hashlib
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c, block = CELLS[cell]
+    k, w = bucket
+    lowered, _ = _engine_program(v5e, f"chunk_prefill[k={k},w={w}]", c,
+                                 bucket=bucket, **block)
+    text = lowered.as_text()
+    assert "tpu_custom_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:12] == CHUNK_DIGESTS[
+        cell, bucket]
