@@ -32,7 +32,14 @@ recipes have it, weights random from a seed):
   program and one decode tick, each slot's logits against the plain
   reference (``perfbench/references/ouro.py``) on the same bf16 weights;
   then the tick compiled through the fused kernel at heads of 128, and
-  whether the chip's compiler took it (reported, not required).
+  whether the chip's compiler took it (reported, not required);
+- ``zaya``: the ``zaya`` block at ``perfbench/configs/zaya1-8b.json``'s
+  widths on two layers through ``PagedEngine``: chunked prefill (a prompt
+  of two chunks, one that ends inside its chunk) and then 64 decode
+  ticks, the tick's K/V read by the rule's spelling (the fused kernel's
+  grouped-head fold on a TPU), each slot's logits at every served
+  position against the plain reference (``perfbench/references/zaya.py``)
+  on the same bf16 weights, and the experts' token counts a tick.
 
 ``--multichip`` runs the paths that exist only across chips, each beside
 what it is compared with: data-parallel ResNet against one device,
@@ -110,6 +117,9 @@ from pytorch_distributed_tpu.parallel import (  # noqa: E402
 from pytorch_distributed_tpu.utils.env import enable_compile_cache  # noqa: E402
 
 KERNEL = "tpu_custom_call"  # a compiled (not interpreted) Pallas kernel
+#: the zaya phase judges a row where the reference's routers led by this
+#: much in every layer (a score is a probability)
+MARGIN = 0.02
 
 
 def emit(**record) -> None:
@@ -573,6 +583,119 @@ def ouro_phase() -> None:
                                   "error": str(e).splitlines()[0][:300]}
 
 
+def zaya_phase() -> None:
+    """Attention in a compressed latent with its tail beside the pool, and
+    the dropless expert layer, through the paged engine at full width."""
+    import jax.numpy as jnp
+
+    from perfbench.harness.manifest import load_json, merged
+    from perfbench.references import zaya
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import ChunkJob, PagedEngine
+
+    with phase("zaya") as rec:
+        conf = merged(load_json(os.path.join(
+            ROOT, "perfbench", "configs", "zaya1-8b.json")), ARGS.tiny)
+        dtype = getattr(jnp, conf["dtype"])
+        chunk, slots, ticks = (8, 4, 6) if ARGS.tiny else (128, 8, 64)
+        program = dict(conf["program"], num_layers=2,
+                       max_seq_len=2 * chunk + ticks + 8)
+        cfg = TransformerConfig(**program, dropout=0.0, dtype=dtype,
+                                attention="dense")
+        zaya.configure(program)
+        shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                                jnp.zeros((1, 8), jnp.int32))["params"]
+        params = zaya.init_params(ARGS.seed, shapes, dtype)
+        rec.update(layers=cfg.num_layers, heads=cfg.num_heads,
+                   kv_heads=cfg.num_kv_heads, head_dim=cfg.head_width,
+                   embed_dim=cfg.embed_dim, experts=cfg.n_experts,
+                   expert_dim=cfg.moe_dim, router_dim=cfg.router_dim,
+                   vocab=cfg.vocab_size, tail=cfg.cca_tail_width)
+        eng = PagedEngine(cfg, params, slots, n_blocks=65, block_len=16,
+                          prefill_chunk=chunk)
+        rec.update(read=eng.gather_impl, tile_blocks=eng.tile_blocks)
+        prompts = make_prompts(cfg, [chunk + chunk // 2 + 1, chunk - 3])
+        for slot, prompt in enumerate(prompts):
+            require(rec, eng.admit(slot, len(prompt), ticks), "admission")
+        for start in (0, chunk):
+            jobs = []
+            for slot, prompt in enumerate(prompts):
+                seg = prompt[start:start + chunk]
+                if not len(seg):
+                    continue
+                padded = np.zeros((chunk,), np.int32)
+                padded[:len(seg)] = seg
+                last = start + chunk >= len(prompt)
+                jobs.append(ChunkJob(slot, padded, start, last,
+                                     len(prompt) - 1 - start if last else 0))
+            eng.run_chunks(jobs)
+        served = [[np.asarray(eng.logits[s])] for s in range(len(prompts))]
+        streams = [list(p) for p in prompts]
+        positions = np.array([len(p) for p in prompts]
+                             + [0] * (slots - len(prompts)), np.int32)
+        active = positions > 0
+        hit = []
+        for _ in range(ticks):
+            tokens, positions = eng.decode(positions, active,
+                                           jax.random.key(ARGS.seed))
+            logits = np.asarray(eng.logits)
+            hit.append(eng.tick_expert_counts.sum(axis=1).tolist())
+            for s in range(len(prompts)):
+                streams[s].append(int(tokens[s]))
+                served[s].append(logits[s])
+        rec["programs"] = eng.compiled_program_names()
+        rec["routed_a_layer"] = sorted({tuple(h) for h in hit})
+        require(rec, all(h == [len(prompts)] * cfg.num_layers for h in hit),
+                "a tick routed other than its live lanes")
+
+        # the reference's full forward over prompt + decoded tokens: row
+        # L-1 is what the last chunk left, the rows after it the ticks'.
+        # A row is DECISIVE where every layer's router led its second
+        # choice by MARGIN for the row's own token and the two before it
+        # (the two convolutions reach that far back through the tail);
+        # bf16 may send another token to another expert than float32,
+        # which moves its logits, and its followers', by their own size
+        # and is no fault: those rows are counted, not judged (a flip
+        # further back reaches a row only through its share of the
+        # attention)
+        worst = close = scale = 0.0
+        rows = decisive = 0
+        with jax.default_matmul_precision("highest"):
+            for s, prompt in enumerate(prompts):
+                seq = jnp.asarray(streams[s][:-1], jnp.int32)[None]
+                want = np.asarray(zaya.logits(params, seq))
+                margins = np.asarray(zaya.walk(params, seq)[1])[:, 0]
+                own = margins.min(0)
+                near = np.minimum(own, np.minimum(np.roll(own, 1),
+                                                  np.roll(own, 2)))
+                sure = near >= (
+                    0.0 if ARGS.tiny else MARGIN)  # float32 flips nothing
+                at = len(prompt) - 1
+                gaps = np.abs(np.stack(served[s][:-1]) - want[0, at:]).max(-1)
+                rows += len(gaps)
+                decisive += int(sure[at:].sum())
+                if sure[at:].any():
+                    worst = max(worst, gaps[sure[at:]].max())
+                if not sure[at:].all():
+                    close = max(close, gaps[~sure[at:]].max())
+                scale = max(scale, np.abs(want[0, at:]).max())
+        rec.update(logit_max_abs_diff=float(worst),
+                   logit_max_abs=float(scale), ticks=ticks, rows=rows,
+                   decisive_rows=decisive,
+                   close_call_rows_max_abs_diff=float(close))
+        # two layers in bf16 keep a logit to a few hundredths of the
+        # largest; a tail read from the wrong slot moves every row's
+        # logits by their own size
+        require(rec, decisive >= (rows if ARGS.tiny else rows // 2),
+                f"only {decisive} of {rows} rows are decisive")
+        require(rec, worst <= (1e-4 if ARGS.tiny else 0.06) * scale,
+                f"logits differ from the reference by {worst} (largest "
+                f"logit {scale})")
+
+
 # ---- four chips ------------------------------------------------------------
 
 
@@ -753,6 +876,7 @@ def main() -> None:
             server_phase()
             pool_phase()
             ouro_phase()
+            zaya_phase()
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     hits, compile_s = process_compile_totals()

@@ -208,3 +208,87 @@ class MoEMLP(nn.Module):
 
         out = jnp.einsum("tec,ecd->td", combine.astype(self.dtype), ye)
         return out.reshape(b, l, d)
+
+
+class DroplessMoE(nn.Module):
+    """Top-1 expert layer that drops nothing (``TransformerConfig.
+    moe_kind="dropless"``): SwiGLU experts of ``moe_dim`` features, the
+    program's live tokens sorted by expert, the group sizes taken, and the
+    experts' matrices run as grouped products (``jax.lax.ragged_dot``: on
+    a TPU XLA's own grouped-matmul kernel, which reads an expert's matrix
+    only if a token went to it). Rows that are not ``live`` (a chunk's
+    padding, an inactive decode lane) sort behind every group and join
+    none.
+
+    The router is a small MLP on a ``router_dim``-wide projection of the
+    token that ADDS the previous layer's router state (``router_mix``
+    times it): a second stream carried from block to block beside ``x``.
+    It runs in float32 at the
+    HIGHEST matrix precision whatever the compute dtype (on a TPU a
+    float32 product is otherwise one bfloat16 pass): a top-1 choice
+    between near-equal logits is where a rounding shows, and the router's
+    matrices are a three-hundredth of a layer's arithmetic.
+    ``router_bias`` enters the choice and not the weight.
+
+    Returns ``(out, router_state)``; sows the tokens each expert took
+    (``[n_experts]`` int32) as ``moe_stats/expert_tokens``.
+    """
+
+    n_experts: int
+    moe_dim: int
+    router_dim: int
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, router_state=None, live=None):
+        b, l, d = x.shape
+        t, e, f = b * l, self.n_experts, self.moe_dim
+        f32 = jnp.float32
+        xf = x.reshape(t, d)
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=f32, name=name,
+                            precision=jax.lax.Precision.HIGHEST)
+
+        state = dense(self.router_dim, "router_down")(xf.astype(f32))
+        # in every layer's tree, the first's too (which adds nothing: the
+        # state before the first layer is zero)
+        mix = self.param("router_mix", nn.initializers.ones, (1,))
+        if router_state is not None:
+            state = state + mix.astype(f32) * router_state.reshape(
+                t, self.router_dim)
+        z = nn.RMSNorm(epsilon=self.norm_eps, dtype=f32,
+                       name="router_norm")(state)
+        for i in (1, 2):
+            z = nn.gelu(dense(self.router_dim, f"router_w{i}")(z))
+        z = dense(e, "router_w3")(z)
+        probs = jax.nn.softmax(z, axis=-1)
+        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        choice = jnp.argmax(probs + bias.astype(f32), axis=-1)
+        gate = jnp.take_along_axis(probs, choice[:, None], axis=1)[:, 0]
+        if live is not None:
+            # a dead row's expert is one past the last: it sorts behind
+            # every group and its gate is zero
+            choice = jnp.where(live.reshape(t), choice, e)
+            gate = jnp.where(live.reshape(t), gate, 0.0)
+        sizes = jnp.sum(choice[:, None] == jnp.arange(e)[None, :], axis=0,
+                        dtype=jnp.int32)
+        self.sow("moe_stats", "expert_tokens", sizes)
+        order = jnp.argsort(choice)  # stable: arrival order inside a group
+        xs = xf.astype(self.dtype)[order]
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
+                                                in_axis=1, out_axis=2,
+                                                batch_axis=0)
+        # gate and up side by side: one grouped product for both
+        w_in = self.param("w_gate_up", init, (e, d, 2 * f))
+        w_down = self.param("w_down", init, (e, f, d))
+        gu = jax.lax.ragged_dot(xs, w_in.astype(self.dtype), sizes)
+        hidden = nn.silu(gu[:, :f]) * gu[:, f:]
+        ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype), sizes)
+        out = jnp.zeros_like(ys).at[order].set(ys)
+        out = (out.astype(f32) * gate[:, None]).astype(self.dtype)
+        if live is not None:  # what a grouped product leaves in a row of
+            # no group is not promised to be a number
+            out = jnp.where(live.reshape(t, 1), out, jnp.zeros((), out.dtype))
+        return out.reshape(b, l, d), state.reshape(b, l, self.router_dim)

@@ -131,8 +131,104 @@ class TransformerConfig:
     # one tick different numbers of passes; ROADMAP B-mech).
     ut_steps: int = 1
     early_exit_threshold: float = 1.0
+    # ``head_dim``: the width of one attention head where it is not
+    # ``embed_dim / num_heads`` (attention whose inner width is not the
+    # model's). None = ``embed_dim // num_heads``.
+    head_dim: Optional[int] = None
+    # ``attn_kind``: "mha" (``Attention``: q, k, v straight from the
+    # normed state) or "cca" (``CCAttention``: attention inside a
+    # compressed latent of ``num_heads`` query and ``num_kv_heads`` narrow
+    # heads of ``head_dim``, two causal two-tap convolutions over the q
+    # and k latents, values whose second half comes from the
+    # previous token, L2-normed q and k with a learned key temperature).
+    # Its cache has a second kind of state beside the K/V pool: a TAIL of
+    # ``cca_tail_width`` values a request a layer (the previous token's
+    # latents), which belongs to the request and not to a block.
+    attn_kind: str = "mha"
+    # share of every head's dims that RoPE rotates (the first
+    # ``rotary_share * head_dim``; the rest pass through)
+    rotary_share: float = 1.0
+    # the head is ``wte``'s transpose: no ``lm_head`` leaf
+    tie_embeddings: bool = False
+    # a sublayer joins the stream as (s_x * x + b_x) + (s_f * f + b_f),
+    # four learned vectors of ``embed_dim`` a sublayer (``rs1``, ``rs2``)
+    residual_scaling: bool = False
+    # ``moe_kind``: "capacity" (``moe.MoEMLP``: Switch/GShard dispatch
+    # into ``[T, E, C]`` buffers, GELU experts, tokens over capacity
+    # dropped) or "dropless" (``moe.DroplessMoE``: top-1, the live tokens
+    # sorted by expert and the three SwiGLU matrices of ``moe_dim``
+    # features run as grouped products; no token is dropped). ``router_dim``
+    # is the dropless router's width: an MLP on a ``router_dim``-wide
+    # projection that adds the previous layer's router state, a second
+    # stream carried from block to block beside ``x``. A dropless config
+    # states both widths and has an expert layer in every block.
+    moe_kind: str = "capacity"
+    moe_dim: Optional[int] = None
+    router_dim: Optional[int] = None
 
     def __post_init__(self):
+        if self.attn_kind not in ("mha", "cca"):
+            raise ValueError(
+                f"attn_kind {self.attn_kind!r} must be 'mha' or 'cca'")
+        if self.moe_kind not in ("capacity", "dropless"):
+            raise ValueError(
+                f"moe_kind {self.moe_kind!r} must be 'capacity' or "
+                "'dropless'")
+        if self.head_dim is not None and self.head_dim < 1:
+            raise ValueError(f"head_dim must be >= 1, got {self.head_dim}")
+        if not 0.0 < self.rotary_share <= 1.0 or (
+                self.pos_embedding == "rope"
+                and int(self.rotary_share * self.head_width) % 2):
+            raise ValueError(
+                f"rotary_share {self.rotary_share} must be in (0, 1] and "
+                f"leave an even number of the head's {self.head_width} "
+                "dims to rotate")
+        if self.attn_kind == "cca":
+            if self.num_kv_heads is None or self.pos_embedding != "rope":
+                raise ValueError(
+                    "attn_kind='cca' needs num_kv_heads (the latent's "
+                    "narrow heads) and pos_embedding='rope'")
+            if (self.tp_size > 1 or self.ut_steps > 1
+                    or self.attention not in ("dense", "blockwise", "flash")):
+                raise ValueError(
+                    "attn_kind='cca' runs on one shard and one pass: the "
+                    "convolutions need the previous token, which a "
+                    "sequence shard does not hold, and the tail is one "
+                    f"row a request (tp_size {self.tp_size}, ut_steps "
+                    f"{self.ut_steps}, attention {self.attention!r})")
+        elif self.head_dim is not None and (
+                self.head_dim * self.num_heads != self.embed_dim):
+            raise ValueError(
+                f"head_dim {self.head_dim} x num_heads {self.num_heads} "
+                f"!= embed_dim {self.embed_dim}: an inner width of its "
+                "own is attn_kind='cca''s")
+        if self.moe_kind == "dropless":
+            if not self.n_experts or self.moe_top_k != 1:
+                raise ValueError(
+                    "moe_kind='dropless' is top-1 over n_experts > 0 "
+                    f"experts (n_experts {self.n_experts}, moe_top_k "
+                    f"{self.moe_top_k})")
+            if self.moe_dim is None or self.router_dim is None:
+                raise ValueError(
+                    "moe_kind='dropless' needs moe_dim (an expert's "
+                    "features) and router_dim (the router MLP's width)")
+            if self.moe_every != 1:
+                raise ValueError(
+                    "moe_kind='dropless' has an expert layer in every "
+                    "block (moe_every 1): the router's state runs through "
+                    f"all of them, got moe_every {self.moe_every}")
+            if self.ep_size > 1 or self.tp_size > 1 or self.ut_steps > 1:
+                raise ValueError(
+                    "moe_kind='dropless' holds every expert on one shard "
+                    "and carries its router stream through one pass "
+                    f"(ep_size {self.ep_size}, tp_size {self.tp_size}, "
+                    f"ut_steps {self.ut_steps})")
+        elif self.moe_dim is not None or self.router_dim is not None:
+            raise ValueError(
+                "moe_dim and router_dim describe moe_kind='dropless' only")
+        if self.tie_embeddings and self.vocab_parallel:
+            raise ValueError(
+                "tie_embeddings with vocab_parallel is not supported")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(
                 f"norm {self.norm!r} must be 'layernorm' or 'rmsnorm'"
@@ -141,7 +237,8 @@ class TransformerConfig:
             raise ValueError(f"mlp {self.mlp!r} must be 'gelu' or 'swiglu'")
         if self.mlp_dim is not None and self.mlp_dim < 1:
             raise ValueError(f"mlp_dim must be >= 1, got {self.mlp_dim}")
-        if self.n_experts and (self.mlp != "gelu" or self.mlp_dim is not None):
+        if (self.n_experts and self.moe_kind == "capacity"
+                and (self.mlp != "gelu" or self.mlp_dim is not None)):
             raise ValueError(
                 "the MoE block (models/moe.py) has GELU experts of "
                 "embed_dim * mlp_ratio features: mlp='swiglu' and mlp_dim "
@@ -177,7 +274,7 @@ class TransformerConfig:
                 f"moe_top_k {self.moe_top_k} must be in [1, n_experts="
                 f"{self.n_experts}]"
             )
-        if self.embed_dim % self.num_heads:
+        if self.head_dim is None and self.embed_dim % self.num_heads:
             raise ValueError(
                 f"embed_dim {self.embed_dim} not divisible by num_heads {self.num_heads}"
             )
@@ -190,11 +287,9 @@ class TransformerConfig:
                 f"pos_embedding {self.pos_embedding!r} must be 'learned' "
                 "or 'rope'"
             )
-        if self.pos_embedding == "rope" and (self.embed_dim
-                                             // self.num_heads) % 2:
+        if self.pos_embedding == "rope" and self.head_width % 2:
             raise ValueError(
-                f"rope needs an even head_dim, got "
-                f"{self.embed_dim // self.num_heads}"
+                f"rope needs an even head_dim, got {self.head_width}"
             )
         if self.rope_theta <= 0.0:
             raise ValueError(
@@ -236,6 +331,24 @@ class TransformerConfig:
             raise ValueError(f"dropout must be in [0, 1), got {self.dropout}")
 
     @property
+    def head_width(self) -> int:
+        """Features of one attention head."""
+        return (self.head_dim if self.head_dim is not None
+                else self.embed_dim // self.num_heads)
+
+    @property
+    def cca_tail_width(self) -> int:
+        """Values a request holds a layer beside its K/V blocks where
+        ``attn_kind`` is "cca": the previous token's q and k latents
+        before and after the first convolution, and its shifted value
+        half. 0 for every other attention."""
+        if self.attn_kind != "cca":
+            return 0
+        d = self.head_width
+        latent = (self.num_heads + self.num_kv_heads) * d
+        return 2 * latent + self.num_kv_heads * d // 2
+
+    @property
     def mlp_width(self) -> int:
         """Hidden features of the dense MLP."""
         return (self.mlp_dim if self.mlp_dim is not None
@@ -256,11 +369,17 @@ class TransformerConfig:
         )
 
 
-def _rope_rotate(x, positions, theta: float):
+def _rope_rotate(x, positions, theta: float, share: float = 1.0):
     """Rotary embedding on ``x`` [B, L, H, D] at absolute ``positions``
     ([1, L] shared or [B, L] per-request), interleaved-pair convention.
-    fp32 trig regardless of compute dtype."""
+    fp32 trig regardless of compute dtype. ``share`` < 1 rotates only the
+    first ``share * D`` dims of every head; the rest pass through."""
     d = x.shape[-1]
+    if int(share * d) < d:
+        rot = int(share * d)
+        return jnp.concatenate(
+            [_rope_rotate(x[..., :rot], positions, theta), x[..., rot:]],
+            axis=-1)
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = positions.astype(jnp.float32)[..., None] * inv  # [B?, L, D/2]
     cos = jnp.cos(ang)[:, :, None, :]  # [B?, L, 1, D/2] broadcasts over H
@@ -362,13 +481,6 @@ class Attention(nn.Module):
                     "requires decode or prefill mode"
                 )
             from pytorch_distributed_tpu.ops.attention import paged_attention
-
-            def _need_pool(*_a):
-                raise ValueError(
-                    "paged attention needs the pool cache passed in "
-                    "(apply with {'cache': serving.kv_pool.init_paged_"
-                    "cache(...)}); there is no in-module init for it"
-                )
 
             kv_heads = k.shape[2]
             ck = self.variable("cache", "key", _need_pool)
@@ -673,6 +785,245 @@ class Attention(nn.Module):
         return out
 
 
+def _need_pool(*_a):
+    raise ValueError(
+        "paged attention needs the pool cache passed in (apply with "
+        "{'cache': serving.kv_pool.init_paged_cache(...)}); there is no "
+        "in-module init for it"
+    )
+
+
+class CCAttention(nn.Module):
+    """Compressed convolutional attention (``attn_kind="cca"``; Zyphra,
+    arXiv:2510.04476, as ``perfbench/references/zaya.py`` writes it down).
+
+    One fused projection takes the normed state to the q and k LATENTS
+    ``u`` (``(H + H_kv) * D`` channels) and to the value halves; two
+    causal two-tap convolutions run over ``u`` (depthwise, then one
+    ``D x D`` block a head); q and k are the convolved latents plus the
+    mean of the unconvolved q and k latents of their group, L2-normed a
+    head (k times a learned temperature), RoPE on the first
+    ``rotary_share`` of every head; the second half of a token's value
+    comes from the PREVIOUS token. Attention itself is grouped-head
+    attention over ``H_kv`` narrow heads.
+
+    What the previous token contributes (its ``u``, its first
+    convolution's output, its shifted value half: ``cca_tail_width``
+    values) is the layer's TAIL. The full-sequence forward shifts along
+    the sequence and starts from zeros. With a cache the tail is a
+    second kind of state beside the keys and values: one row a request
+    (``cache/tail``), read by the next chunk or tick, zero for a row that
+    starts at position 0 whatever the row held, and written from the
+    row's last REAL position (``lengths``). In the paged layout the leaf
+    is ``[n_slots + 1, width]`` and ``slots`` names each row's slot (the
+    last row takes what padding jobs and inactive lanes write).
+    """
+
+    #: taps of each convolution: the current token and ONE before it,
+    #: which is all the tail holds
+    TAPS = 2
+
+    config: TransformerConfig
+    deterministic: bool = True
+    decode: bool = False
+    prefill: bool = False
+
+    @nn.compact
+    def __call__(self, x, position_offset, positions=None,
+                 block_tables=None, slots=None, lengths=None):
+        cfg = self.config
+        b, l, e = x.shape
+        f32 = jnp.float32
+        h, h_kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_width
+        group = h // h_kv
+        latent, half = (h + h_kv) * d, h_kv * d // 2
+        if positions is None:
+            raise ValueError("CCAttention needs the resolved positions=")
+        rpos = positions[None] if positions.ndim == 1 else positions
+        cached = self.decode or self.prefill
+        if block_tables is not None and not cached:
+            raise ValueError(
+                "block_tables= is the paged SERVING cache layout; it "
+                "requires decode or prefill mode")
+
+        proj_in = nn.Dense(latent + 2 * half, use_bias=False,
+                           dtype=cfg.dtype, name="qkv")(x)
+        normal = nn.initializers.normal(0.02)
+        conv1_w = self.param("conv1_kernel", normal, (self.TAPS, latent))
+        conv1_b = self.param("conv1_bias", nn.initializers.zeros, (latent,))
+        conv2_w = self.param("conv2_kernel", normal, (h + h_kv, self.TAPS, d, d))
+        conv2_b = self.param("conv2_bias", nn.initializers.zeros, (latent,))
+        k_temp = self.param("k_temp", nn.initializers.ones, (h_kv,))
+
+        # ---- the tail this call starts from ----
+        pos = jnp.asarray(position_offset, jnp.int32)
+        tail_var = None
+        if cached:
+            if block_tables is not None:
+                if pos.ndim != 1 or slots is None or lengths is None:
+                    raise ValueError(
+                        "paged CCAttention takes a [B] position_offset "
+                        "vector and slots= and lengths= (each row's slot "
+                        "in the tail leaf and its real length)")
+                tail_var = self.variable("cache", "tail", _need_pool)
+                held = tail_var.value[slots]
+            else:
+                tail_var = self.variable(
+                    "cache", "tail",
+                    lambda: jnp.zeros((b, cfg.cca_tail_width), cfg.dtype))
+                held = tail_var.value
+            starts = pos if pos.ndim == 1 else jnp.full((b,), pos)
+            prev = jnp.where((starts == 0)[:, None],
+                             jnp.zeros((), held.dtype), held)
+        else:
+            prev = jnp.zeros((b, cfg.cca_tail_width), cfg.dtype)
+        prev = prev.astype(cfg.dtype)[:, None]  # [B, 1, width]
+
+        def shifted(cur, before):
+            """``cur`` one position later, ``before`` in front."""
+            return jnp.concatenate([before, cur[:, :-1]], axis=1)
+
+        # ---- latents, convolutions, values ----
+        u, vv = proj_in[..., :latent], proj_in[..., latent:]
+        u_prev = shifted(u, prev[..., :latent])
+        c1 = (conv1_w[0].astype(f32) * u_prev.astype(f32)
+              + conv1_w[1].astype(f32) * u.astype(f32)
+              + conv1_b.astype(f32)).astype(cfg.dtype)
+        c1_prev = shifted(c1, prev[..., latent:2 * latent])
+        taps = jnp.concatenate(
+            [c1_prev.reshape(b, l, h + h_kv, d),
+             c1.reshape(b, l, h + h_kv, d)], axis=-1)  # [B, L, heads, 2D]
+        c2 = jnp.einsum(
+            "blgk,gkd->blgd", taps,
+            conv2_w.astype(cfg.dtype).reshape(h + h_kv, 2 * d, d),
+            preferred_element_type=f32,
+        ) + conv2_b.astype(f32).reshape(h + h_kv, d)
+        qt = u[..., :h * d].astype(f32).reshape(b, l, h_kv, group, d)
+        kt = u[..., h * d:].astype(f32).reshape(b, l, h_kv, d)
+        mq = 0.5 * (qt + kt[:, :, :, None])
+        mk = 0.5 * (jnp.mean(qt, axis=3) + kt)
+        q = c2[:, :, :h] + mq.reshape(b, l, h, d)
+        k = c2[:, :, h:] + mk
+
+        def unit(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-12)
+
+        q = unit(q) * (d ** 0.5)
+        k = unit(k) * (d ** 0.5) * k_temp.astype(f32)[:, None]
+        q = _rope_rotate(q, rpos, cfg.rope_theta, cfg.rotary_share)
+        k = _rope_rotate(k, rpos, cfg.rope_theta, cfg.rotary_share)
+        q, k = q.astype(cfg.dtype), k.astype(cfg.dtype)
+        v_now = vv[..., :half]
+        v_before = shifted(vv[..., half:], prev[..., 2 * latent:])
+        v = jnp.concatenate([v_now, v_before], axis=-1).reshape(
+            b, l, h_kv, d)
+
+        if tail_var is not None:
+            rows = jnp.concatenate([u, c1, vv[..., half:]], axis=-1)
+            if lengths is None:
+                last = rows[:, -1]
+            else:  # the row's last REAL position, not its padding's
+                at = jnp.maximum(lengths, 1) - 1
+                last = jnp.take_along_axis(rows, at[:, None, None],
+                                           axis=1)[:, 0]
+            last = last.astype(tail_var.value.dtype)
+            if block_tables is not None:
+                tail_var.value = tail_var.value.at[slots].set(last)
+            else:
+                tail_var.value = last
+
+        if block_tables is not None:
+            # the K/V pool, as Attention's paged branch keeps it: the
+            # chunk scattered at its absolute positions, then attention
+            # against the chain through the rule's read (a tick's
+            # ``group`` rows a narrow head take the fused kernel on a TPU)
+            from pytorch_distributed_tpu.ops.attention import paged_attention
+
+            ck = self.variable("cache", "key", _need_pool)
+            cv = self.variable("cache", "value", _need_pool)
+            block_len = ck.value.shape[-2]
+            gather_impl = attention_ops.default_gather_impl(rows=l * group)
+            p = pos[:, None] + jnp.arange(l)
+            blk = jnp.take_along_axis(block_tables, p // block_len, axis=1)
+            at = (blk.reshape(-1), (p % block_len).reshape(-1))
+            ck.value = ck.value.at[at].set(
+                k.astype(ck.value.dtype).reshape(b * l, h_kv * d))
+            cv.value = cv.value.at[at].set(
+                v.astype(cv.value.dtype).reshape(b * l, h_kv * d))
+            out = paged_attention(q, ck.value, cv.value, block_tables, p,
+                                  gather_impl=gather_impl)
+        elif self.decode:
+            # generate()'s dense cache: one token a request against
+            # [B, max_seq_len, H_kv, D]
+            assert l == 1, f"decode mode processes one token/step, got {l}"
+            shape = (b, cfg.max_seq_len, h_kv, d)
+            ck = self.variable("cache", "key",
+                               lambda: jnp.zeros(shape, cfg.dtype))
+            cv = self.variable("cache", "value",
+                               lambda: jnp.zeros(shape, cfg.dtype))
+            at = (jnp.arange(b), starts)
+            ck.value = ck.value.at[at].set(k[:, 0])
+            cv.value = cv.value.at[at].set(v[:, 0])
+            qg = (q.astype(f32) * d ** -0.5).reshape(b, 1, h_kv, group, d)
+            s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck.value.astype(f32))
+            seen = (jnp.arange(cfg.max_seq_len)[None, None, None, None]
+                    <= starts[:, None, None, None, None])
+            pr = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+            out = jnp.einsum("bhgqk,bkhd->bqhgd", pr,
+                             cv.value.astype(f32)).reshape(
+                b, 1, h, d).astype(cfg.dtype)
+        else:
+            if self.prefill:
+                shape = (b, cfg.max_seq_len, h_kv, d)
+                for name, new in (("key", k), ("value", v)):
+                    var = self.variable(
+                        "cache", name, lambda: jnp.zeros(shape, cfg.dtype))
+                    var.value = jax.lax.dynamic_update_slice(
+                        var.value, new.astype(cfg.dtype), (0, pos, 0, 0))
+            kw, vw = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+            if cfg.attention == "blockwise":
+                out = blockwise_attention(
+                    q, kw, vw, causal=True,
+                    block_size=min(cfg.block_size, l),
+                    q_offset=position_offset, k_offset=position_offset)
+            elif cfg.attention == "flash":
+                from pytorch_distributed_tpu.ops.flash_attention import (
+                    flash_attention,
+                )
+
+                out = flash_attention(q, kw, vw, causal=True)
+            else:
+                out = dense_attention(
+                    q, kw, vw, causal=True,
+                    q_offset=position_offset, k_offset=position_offset)
+        out = nn.DenseGeneral(e, axis=(-2, -1), use_bias=False,
+                              dtype=cfg.dtype, name="proj")(out)
+        if cfg.dropout:
+            out = nn.Dropout(cfg.dropout,
+                             deterministic=self.deterministic)(out)
+        return out
+
+
+class ResidualScale(nn.Module):
+    """``(s_x * x + b_x) + (s_f * f + b_f)``: how a sublayer's output
+    ``f`` joins the stream ``x`` under ``residual_scaling``; four learned
+    vectors, float32 arithmetic."""
+
+    @nn.compact
+    def __call__(self, x, f):
+        width = (x.shape[-1],)
+        ones, zeros = nn.initializers.ones, nn.initializers.zeros
+        s_x, b_x = self.param("x_scale", ones, width), self.param(
+            "x_bias", zeros, width)
+        s_f, b_f = self.param("f_scale", ones, width), self.param(
+            "f_bias", zeros, width)
+        f32 = jnp.float32
+        out = ((s_x.astype(f32) * x.astype(f32) + b_x.astype(f32))
+               + (s_f.astype(f32) * f.astype(f32) + b_f.astype(f32)))
+        return out.astype(x.dtype)
+
+
 class Block(nn.Module):
     config: TransformerConfig
     use_moe: bool = False
@@ -682,22 +1033,49 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, position_offset, positions=None,
-                 block_tables=None, pass_index=None):
+                 block_tables=None, pass_index=None, slots=None,
+                 lengths=None, router_state=None):
+        """``slots`` and ``lengths`` ([B] int32) are the paged cache's
+        operands for state that belongs to a request (``CCAttention``'s
+        tail) and for the rows an expert layer may route (``lengths``
+        real positions a row; None: all). A dropless expert block takes
+        the previous block's ``router_state`` and returns ``(x,
+        router_state)``; every other block returns ``x``."""
         cfg = self.config
 
-        def joins(out, name):
-            """A sublayer's output as it joins the residual stream."""
+        def joins(x, out, sublayer: int):
+            """The stream after a sublayer's output has joined it."""
+            if cfg.residual_scaling:
+                return ResidualScale(name=f"rs{sublayer}")(x, out)
             if cfg.post_norm:
-                out = _norm(cfg, name)(out).astype(cfg.dtype)
-            return out
+                out = _norm(cfg, f"ln{sublayer}_post")(out).astype(cfg.dtype)
+            return x + out
 
         h = _norm(cfg, "ln1")(x)
-        x = x + joins(Attention(
-            cfg, deterministic=self.deterministic, decode=self.decode,
-            prefill=self.prefill, name="attn",
-        )(h, position_offset, positions, block_tables, pass_index),
-            "ln1_post")
+        mode = dict(deterministic=self.deterministic, decode=self.decode,
+                    prefill=self.prefill, name="attn")
+        if cfg.attn_kind == "cca":
+            out = CCAttention(cfg, **mode)(
+                h, position_offset, positions, block_tables, slots, lengths)
+        else:
+            out = Attention(cfg, **mode)(
+                h, position_offset, positions, block_tables, pass_index)
+        x = joins(x, out, 1)
         h = _norm(cfg, "ln2")(x)
+        if self.use_moe and cfg.moe_kind == "dropless":
+            from pytorch_distributed_tpu.models.moe import DroplessMoE
+
+            live = (None if lengths is None else
+                    jnp.arange(x.shape[1])[None, :] < lengths[:, None])
+            out, router_state = DroplessMoE(
+                n_experts=cfg.n_experts, moe_dim=cfg.moe_dim,
+                router_dim=cfg.router_dim, norm_eps=cfg.norm_eps,
+                dtype=cfg.dtype, name="moe",
+            )(h, router_state, live)
+            if cfg.dropout:
+                out = nn.Dropout(cfg.dropout,
+                                 deterministic=self.deterministic)(out)
+            return joins(x, out, 2), router_state
         if self.use_moe:
             from pytorch_distributed_tpu.models.moe import MoEMLP
 
@@ -716,7 +1094,7 @@ class Block(nn.Module):
             )(h)
             if cfg.dropout:  # residual dropout, same placement as dense MLP
                 out = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(out)
-            return x + joins(out, "ln2_post")
+            return joins(x, out, 2)
         if cfg.model_axis:
             from pytorch_distributed_tpu.parallel.tensor import tp_copy, tp_reduce
 
@@ -735,7 +1113,7 @@ class Block(nn.Module):
             h = tp_reduce(h, cfg.model_axis)
         if cfg.dropout:  # after tp_reduce — see Attention
             h = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(h)
-        return x + joins(h, "ln2_post")
+        return joins(x, h, 2)
 
 
 class TransformerLM(nn.Module):
@@ -754,7 +1132,18 @@ class TransformerLM(nn.Module):
                  prefill: bool = False, positions: jax.Array | None = None,
                  return_hidden: bool = False,
                  block_tables: jax.Array | None = None,
-                 return_gates: bool = False):
+                 return_gates: bool = False,
+                 slots: jax.Array | None = None,
+                 lengths: jax.Array | None = None,
+                 head_rows: jax.Array | None = None):
+        """``slots`` and ``lengths`` ([B] int32, paged serving): each
+        row's slot in the per-request cache leaves and its real length in
+        this call (0: a padding job or an inactive lane, which an expert
+        layer does not route). Configs whose cache is block chains only
+        ignore both. ``head_rows`` ([B] int32): run the head at that one
+        position of every row and return ``[B, 1, vocab]`` (a chunk
+        program keeps one row a job; a head of 262k tokens over a whole
+        chunk is most of the program's arithmetic)."""
         cfg = self.config
         if return_gates and cfg.ut_steps == 1:
             raise ValueError(
@@ -791,9 +1180,10 @@ class TransformerLM(nn.Module):
                 cfg.model_axis,
             )
         else:
-            x = nn.Embed(
+            wte = nn.Embed(
                 cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype, name="wte"
-            )(tokens)
+            )
+            x = wte(tokens)
         # ``positions`` ([L_local] i32) overrides the contiguous
         # offset+arange convention — required for the zigzag ring layout,
         # whose shards hold non-contiguous chunk pairs (train/lm.py
@@ -853,8 +1243,12 @@ class TransformerLM(nn.Module):
 
         def one_pass(modules, x, t):
             blocks, ln_f = modules
+            router_state = None  # the expert layers' second stream
             for block in blocks:
-                x = block(x, position_offset, pos, block_tables, t)
+                x = block(x, position_offset, pos, block_tables, t, slots,
+                          lengths, router_state)
+                if cfg.moe_kind == "dropless":
+                    x, router_state = x
             return ln_f(x)
 
         gates = None
@@ -897,6 +1291,15 @@ class TransformerLM(nn.Module):
                     variable_broadcast="params", variable_carry="cache",
                     split_rngs={"params": False, "dropout": True},
                 )(self, x, jnp.arange(cfg.ut_steps, dtype=jnp.int32))
+        if head_rows is not None:
+            x = jnp.take_along_axis(x, head_rows[:, None, None], axis=1)
+        if cfg.tie_embeddings:
+            # the head is wte's transpose: the tree has no lm_head leaf
+            if return_hidden:
+                raise ValueError(
+                    "return_hidden= hands the caller lm_head's kernel for "
+                    "the fused loss; a tied head (tie_embeddings) has none")
+            return wte.attend(x.astype(cfg.dtype)).astype(jnp.float32)
         head = nn.Dense(
             cfg.vocab_size // cfg.tp_size if vp else cfg.vocab_size,
             use_bias=False, dtype=cfg.dtype, name="lm_head",

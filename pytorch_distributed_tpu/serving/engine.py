@@ -54,7 +54,10 @@ from pytorch_distributed_tpu.serving.kv_pool import (
     PrefixIndex,
     blocks_needed,
     blocks_needed_suffix,
+    cache_bytes,
     init_paged_cache,
+    is_slot_leaf,
+    map_cache,
     paged_cache_specs,
 )
 from pytorch_distributed_tpu.compilecache.aot import (
@@ -68,6 +71,15 @@ from pytorch_distributed_tpu.telemetry.overlap import NULL_LEDGER
 
 def _pow2_bucket(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
+
+
+def _expert_counts(stats, num_layers: int):
+    """``[expert layers, n_experts]`` int32 from what the expert layers of
+    one ``apply`` sowed (``moe_stats/expert_tokens``), in layer order."""
+    rows = [stats[f"block{i}"]["moe"]["expert_tokens"][0]
+            for i in range(num_layers)
+            if "expert_tokens" in stats.get(f"block{i}", {}).get("moe", {})]
+    return jnp.stack(rows) if rows else jnp.zeros((0, 0), jnp.int32)
 
 
 class ChunkJob(NamedTuple):
@@ -186,6 +198,20 @@ class PagedEngine:
                 "replica placement), not both"
             )
         self.config = config
+        # State that belongs to a request and not to a block (a tail a
+        # layer: kv_pool.SLOT_LEAF) and expert layers that must not route
+        # padding: the programs of such a config take each row's slot and
+        # real length, and return the tokens every expert took.
+        self._per_request = bool(
+            config.cca_tail_width
+            or (config.n_experts and config.moe_kind == "dropless"))
+        if prefix_cache and config.cca_tail_width:
+            raise ValueError(
+                "prefix_cache=True with attn_kind='cca': a request that "
+                "starts behind a shared prefix needs the tail its "
+                "convolutions left at the prefix's last token, and the "
+                "index keeps no such snapshot (ROADMAP B-mech 6); serve "
+                "this config with prefix_cache=False")
         self.n_slots = n_slots
         self.block_len = block_len
         self.chunk = prefill_chunk
@@ -231,17 +257,21 @@ class PagedEngine:
         # init_paged_cache under eval_shape)
         with spans.tracer().span("pool.alloc", blocks=n_blocks) as alloc:
             self.cache = init_paged_cache(init_cfg, params, n_blocks,
-                                          block_len, kv_dtype=kv_dtype)
+                                          block_len, kv_dtype=kv_dtype,
+                                          n_slots=n_slots)
             self.logits = jnp.zeros((n_slots, config.vocab_size),
                                     jnp.float32)
-            # device bytes one block holds across every cache leaf (K +
+            # device bytes one block holds across every pool leaf (K +
             # V + scale siblings). A looped config's cache layers (an
             # entry per pass and layer) outnumber its weight layers; the
             # leaves count them all.
-            self._per_block_bytes = sum(
-                leaf.size * leaf.dtype.itemsize
-                for leaf in jax.tree.leaves(self.cache)
-            ) // n_blocks
+            pool_bytes, tail_bytes = cache_bytes(self.cache)
+            self._per_block_bytes = pool_bytes // n_blocks
+            # and what ONE slot holds in the per-slot leaves
+            self._per_slot_bytes = tail_bytes // (n_slots + 1)
+            slot_leaves = sum(
+                is_slot_leaf(path) for path, _ in
+                jax.tree_util.tree_flatten_with_path(self.cache)[0])
             cache_layers = config.num_layers * config.ut_steps
             # ``T``, the chain blocks a grid step of the tick's kernel
             # stages (``ops.paged_flash.tile_blocks``, from the bytes a
@@ -272,10 +302,19 @@ class PagedEngine:
                 tile_blocks=self.tile_blocks,
                 table_tiles=n_slots * -(-self.table_width
                                         // self.tile_blocks),
+                tail_bytes=tail_bytes,
+                slot_state_leaves=slot_leaves,
             )
 
         self._chunk_fns: Dict[Tuple[int, int], callable] = {}
         self._decode_fn = None
+        # tokens each expert took, [expert layers, n_experts] int32: the
+        # last collected tick's on the host (fetched with its tokens), the
+        # last chunk program's on the device; None where the config has
+        # no such program
+        self.tick_expert_counts: Optional[np.ndarray] = None
+        self.chunk_expert_counts = None
+        self._tick_counts = None
         # host–device overlap ledger (round 15; telemetry/overlap.py):
         # every compiled launch below reports its dispatch wall through
         # it. NULL_LEDGER by default; the scheduler arms it and stamps
@@ -377,27 +416,38 @@ class PagedEngine:
         if fn is not None:
             return fn
         model = self._model()
-        n_slots = self.n_slots
+        layers = self.config.num_layers
 
         def body(params, cache, logits, tokens, starts, tables, slots,
-                 is_last, last_idx):
+                 is_last, last_idx, *lengths):
+            # ``lengths`` (one operand, where the config's state is a
+            # request's: ``_per_request``): each job's real positions in
+            # this chunk, 0 for a padding job, whose slot ``n_slots`` is
+            # the tail leaves' trash row
+            per_request = dict(slots=slots, lengths=lengths[0],
+                               head_rows=last_idx,
+                               mutable=["cache", "moe_stats"]
+                               ) if lengths else dict(mutable=["cache"])
             out, variables = model.apply(
                 {"params": params, "cache": cache},
                 tokens,
                 position_offset=starts,
                 prefill=True,
                 block_tables=tables,
-                mutable=["cache"],
+                **per_request,
             )
             # logits at each prompt's LAST real token — the distribution
             # for its first decoded token; written only for final chunks.
             # Padding jobs carry slot == n_slots: the scatter drops them.
-            row = jnp.take_along_axis(
+            row = out[:, 0] if lengths else jnp.take_along_axis(
                 out, last_idx[:, None, None], axis=1
             )[:, 0]
             new_logits = logits.at[slots].set(
                 jnp.where(is_last[:, None], row, logits[slots])
             )
+            if lengths:
+                return variables["cache"], new_logits, _expert_counts(
+                    variables.get("moe_stats", {}), layers)
             return variables["cache"], new_logits
 
         if self.mesh is not None:
@@ -424,22 +474,36 @@ class PagedEngine:
 
         model = self._model()
         temp, topk = self.temperature, self.top_k
+        n_slots, layers = self.n_slots, self.config.num_layers
+        per_request = self._per_request
 
         def body(params, cache, logits, positions, active, tables, rng):
             tokens = _sample(logits, rng, temp, topk)
+            # a lane that is not active reads and writes the tail leaves'
+            # trash row (a slot in mid-prefill keeps its tail while the
+            # tick runs over it) and is routed to no expert
+            request = dict(
+                slots=jnp.where(active, jnp.arange(n_slots), n_slots),
+                lengths=active.astype(jnp.int32),
+                mutable=["cache", "moe_stats"],
+            ) if per_request else dict(mutable=["cache"])
             out, variables = model.apply(
                 {"params": params, "cache": cache},
                 tokens[:, None],
                 position_offset=positions,
                 decode=True,
                 block_tables=tables,
-                mutable=["cache"],
+                **request,
             )
             # Inactive lanes: cache writes already routed to the trash
             # block (host-masked tables); logits rows are dead state,
             # replaced by the slot's next final prefill chunk before they
             # are read. Positions stay frozen — the caller reads them.
             positions = jnp.where(active, positions + 1, positions)
+            if per_request:
+                return (variables["cache"], out[:, 0], positions, tokens,
+                        _expert_counts(variables.get("moe_stats", {}),
+                                       layers))
             return variables["cache"], out[:, 0], positions, tokens
 
         if self.mesh is not None:
@@ -556,10 +620,7 @@ class PagedEngine:
         out-of-bounds ``n_slots`` sentinel (dropped), so live state is
         untouched. ``execute=False`` returns the ``Compiled``."""
         fn = self._import_fn(n_pad)
-        blocks = jax.tree.map(
-            lambda pool: jnp.zeros((n_pad,) + pool.shape[1:], pool.dtype),
-            self.cache,
-        )
+        blocks = self._zero_chain(n_pad)
         idx = jnp.full((n_pad,), TRASH_BLOCK, jnp.int32)
         slot = jnp.asarray(self.n_slots, jnp.int32)
         row = jnp.zeros((self.config.vocab_size,), self.logits.dtype)
@@ -572,6 +633,15 @@ class PagedEngine:
         return fn.lower(
             cache_aval, logits_aval, blocks, idx, slot, row
         ).compile()
+
+    def _zero_chain(self, n_pad: int):
+        """An all-zero chain of ``n_pad`` blocks (and one zero row of
+        each per-slot leaf) in the cache's tree: the warm-ups' payload."""
+        return map_cache(
+            lambda pool: jnp.zeros((n_pad,) + pool.shape[1:], pool.dtype),
+            lambda rows: jnp.zeros(rows.shape[1:], rows.dtype),
+            self.cache,
+        )
 
     @staticmethod
     def swap_out_program_name(n_pad: int) -> str:
@@ -616,10 +686,7 @@ class PagedEngine:
         the out-of-bounds ``n_slots`` sentinel (dropped) — live state is
         untouched. ``execute=False`` returns the ``Compiled``."""
         fn = self._swap_in_fn(n_pad)
-        blocks = jax.tree.map(
-            lambda pool: jnp.zeros((n_pad,) + pool.shape[1:], pool.dtype),
-            self.cache,
-        )
+        blocks = self._zero_chain(n_pad)
         idx = jnp.full((n_pad,), TRASH_BLOCK, jnp.int32)
         slot = jnp.asarray(self.n_slots, jnp.int32)
         row = jnp.zeros((self.config.vocab_size,), self.logits.dtype)
@@ -749,20 +816,21 @@ class PagedEngine:
         slots = jnp.full((k_pad,), self.n_slots, jnp.int32)
         is_last = jnp.zeros((k_pad,), bool)
         last_idx = jnp.zeros((k_pad,), jnp.int32)
+        operands = (tokens, starts, tables, slots, is_last, last_idx)
+        if self._per_request:  # every job a padding job: no real position
+            operands += (jnp.zeros((k_pad,), jnp.int32),)
         name = self.chunk_program_name(k_pad, wp)
         if execute:
             with program_load_if((k_pad, wp) not in self._hot_chunks, name):
-                self.cache, self.logits = fn(
-                    self.params, self.cache, self.logits, tokens, starts,
-                    tables, slots, is_last, last_idx,
+                self.cache, self.logits, *_ = fn(
+                    self.params, self.cache, self.logits, *operands,
                 )
             self._hot_chunks.add((k_pad, wp))
             return None
         cache_aval, logits_aval = self._cache_logits_avals()
         with program_load(name):
             return fn.lower(
-                self.params, cache_aval, logits_aval, tokens, starts,
-                tables, slots, is_last, last_idx,
+                self.params, cache_aval, logits_aval, *operands,
             ).compile()
 
     def warm_decode(self, execute: bool = True):
@@ -781,7 +849,7 @@ class PagedEngine:
             rng = jax.device_put(rng, self.device)
         if execute:
             with program_load_if(not self._hot_decode, self.DECODE_PROGRAM):
-                self.cache, self.logits, _, _ = fn(
+                self.cache, self.logits, *_ = fn(
                     self.params, self.cache, self.logits, positions, active,
                     tables, rng,
                 )
@@ -1015,7 +1083,9 @@ class PagedEngine:
             return fn
 
         def body(cache, logits, idx, slot):
-            blocks = jax.tree.map(lambda pool: pool[idx], cache)
+            # the chain's blocks, and the slot's row of per-slot state
+            blocks = map_cache(lambda pool: pool[idx],
+                               lambda rows: rows[slot], cache)
             return blocks, logits[slot]
 
         # pure read: nothing donated
@@ -1029,11 +1099,13 @@ class PagedEngine:
             return fn
 
         def body(cache, logits, blocks, idx, slot, row):
-            cache = jax.tree.map(
-                lambda pool, b: pool.at[idx].set(b), cache, blocks
+            cache = map_cache(
+                lambda pool, b: pool.at[idx].set(b),
+                lambda rows, r: rows.at[slot].set(r), cache, blocks
             )
             # out-of-bounds slot (warmup's n_slots sentinel) drops the
             # scatter — same inert trick as the chunk program's padding
+            # (in a per-slot leaf it is the trash row)
             return cache, logits.at[slot].set(row)
 
         fn = jax.jit(self._named(body, self.import_program_name(n_pad)),
@@ -1149,7 +1221,9 @@ class PagedEngine:
             return fn
 
         def body(cache, logits, idx, slot):
-            blocks = jax.tree.map(lambda pool: pool[idx], cache)
+            # the chain's blocks, and the slot's row of per-slot state
+            blocks = map_cache(lambda pool: pool[idx],
+                               lambda rows: rows[slot], cache)
             return blocks, logits[slot]
 
         # pure read: nothing donated
@@ -1163,8 +1237,9 @@ class PagedEngine:
             return fn
 
         def body(cache, logits, blocks, idx, slot, row):
-            cache = jax.tree.map(
-                lambda pool, b: pool.at[idx].set(b), cache, blocks
+            cache = map_cache(
+                lambda pool, b: pool.at[idx].set(b),
+                lambda rows, r: rows.at[slot].set(r), cache, blocks
             )
             return cache, logits.at[slot].set(row)
 
@@ -1175,12 +1250,13 @@ class PagedEngine:
 
     def chain_bytes(self, n_blocks: int) -> int:
         """Device bytes ``n_blocks`` pool blocks hold across every cache
-        leaf (K + V + scale siblings) plus one logits row — the payload
+        leaf (K + V + scale siblings) plus the slot's row of every per-slot
+        leaf and one logits row — the payload
         a swap moves, and the byte side of the swap-vs-recompute
         decision. Pure shape arithmetic on the pool as it was
         allocated."""
         row = self.logits.size * self.logits.dtype.itemsize // self.n_slots
-        return n_blocks * self._per_block_bytes + row
+        return n_blocks * self._per_block_bytes + self._per_slot_bytes + row
 
     def swap_out_begin(self, slot: int) -> PendingSwap:
         """Open a swap-out window on ``slot``'s chain: ONE compiled
@@ -1238,9 +1314,10 @@ class PagedEngine:
         slot = pending.slot
         try:
             fault_point("kv.swap_out_d2h")
-            blocks = jax.tree.map(
+            blocks = map_cache(
                 lambda b: np.asarray(
                     jax.device_get(b))[:pending.chain_len],
+                lambda r: np.asarray(jax.device_get(r)),
                 pending.blocks,
             )
             row = np.asarray(jax.device_get(pending.logits_row))
@@ -1302,7 +1379,9 @@ class PagedEngine:
                     b = np.concatenate([b, pad])
                 return jax.device_put(b, pool.sharding)
 
-            blocks = jax.tree.map(_padded, chain.blocks, self.cache)
+            blocks = map_cache(
+                _padded, lambda r, rows: jax.device_put(r, rows.sharding),
+                chain.blocks, self.cache)
             row = jax.device_put(chain.logits_row, self.logits.sharding)
             with self.ledger.launch(self.ledger_replica,
                                     self.swap_in_program_name(n_pad)):
@@ -1352,6 +1431,9 @@ class PagedEngine:
         slots = np.full((k_pad,), self.n_slots, np.int32)
         is_last = np.zeros((k_pad,), bool)
         last_idx = np.zeros((k_pad,), np.int32)
+        # a job's real positions in this chunk: all of it, or up to the
+        # prompt's last token; a padding job has none
+        lengths = np.zeros((k_pad,), np.int32)
         for i, j in enumerate(jobs):
             tokens[i] = j.tokens
             starts[i] = j.start
@@ -1359,6 +1441,10 @@ class PagedEngine:
             slots[i] = j.slot
             is_last[i] = j.is_last
             last_idx[i] = j.last_idx
+            lengths[i] = j.last_idx + 1 if j.is_last else c
+        host = (tokens, starts, tables, slots, is_last, last_idx)
+        if self._per_request:
+            host += (lengths,)
         fn = self._chunk_fn(k_pad, wp)
         # no fence handle: both outputs are donated into later programs,
         # so completion rides the t1 lower bound tightened by the next
@@ -1371,12 +1457,12 @@ class PagedEngine:
             # ONE batched explicit transfer for the six host-built
             # operands, inside the launch window (dispatch cost; see
             # the decode call's note on the per-operand asarray tax)
-            operands = jax.device_put(
-                (tokens, starts, tables, slots, is_last, last_idx)
-            )
-            self.cache, self.logits = fn(
+            operands = jax.device_put(host)
+            self.cache, self.logits, *counts = fn(
                 self.params, self.cache, self.logits, *operands,
             )
+        if counts:
+            self.chunk_expert_counts = counts[0]
         self._hot_chunks.add((k_pad, wp))
 
     def _decode_call(self, positions, active, rng, sync: bool):
@@ -1417,10 +1503,11 @@ class PagedEngine:
                 positions, active, masked = jax.device_put(
                     (positions, active, masked)
                 )
-                self.cache, self.logits, positions, tokens = fn(
+                self.cache, self.logits, positions, tokens, *counts = fn(
                     self.params, self.cache, self.logits,
                     positions, active, masked, rng,
                 )
+                self._tick_counts = counts[0] if counts else None
             if sync:
                 # the token fetch inside the window materializes the
                 # program's result, so t1 IS device completion — the
@@ -1428,7 +1515,7 @@ class PagedEngine:
                 # against. It is where the host waits for the tick: the
                 # same span the async path books in decode_collect
                 with spans.tracer().span("engine.collect.wait"):
-                    tokens = np.asarray(tokens)
+                    tokens = self._fetch_tick(tokens)
             else:
                 lt.handle = tokens  # non-donated output: fence target
         self._hot_decode = True
@@ -1462,5 +1549,15 @@ class PagedEngine:
         ``(tokens [n_slots], new_positions)`` as ``decode``."""
         with spans.tracer().span("engine.collect.wait"):
             self.ledger.complete(launch_token)
-            tokens = np.asarray(tokens)
+            tokens = self._fetch_tick(tokens)
         return tokens, np.array(positions)
+
+    def _fetch_tick(self, tokens) -> np.ndarray:
+        """The tick's tokens on the host and, in the same fetch (no
+        second wait), the tokens its experts took
+        (``tick_expert_counts``)."""
+        if self._tick_counts is None:
+            return np.asarray(tokens)
+        tokens, self.tick_expert_counts = jax.device_get(
+            (tokens, self._tick_counts))
+        return tokens

@@ -152,6 +152,37 @@ def _dense_to_pool(shape, n_blocks: int, block_len: int, **kw):
                            **kw)
 
 
+#: name of a cache leaf that belongs to a REQUEST and not to a block: one
+#: row a slot, ``[n_slots + 1, width]`` (``models.transformer.CCAttention``:
+#: the previous token's latents). The last row is the TRASH row, the slot
+#: that padding jobs and inactive lanes are given.
+SLOT_LEAF = "tail"
+
+
+def is_slot_leaf(path) -> bool:
+    """Whether a cache leaf's tree path names per-slot state."""
+    return getattr(path[-1], "key", None) == SLOT_LEAF
+
+
+def map_cache(on_blocks, on_slots, cache, *rest):
+    """``jax.tree.map`` over a paged cache that tells its two kinds of
+    leaf apart: ``on_blocks`` for the pools, indexed by block on axis 0,
+    ``on_slots`` for per-slot state, indexed by slot."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf, *more: (
+            on_slots if is_slot_leaf(path) else on_blocks)(leaf, *more),
+        cache, *rest)
+
+
+def cache_bytes(cache) -> tuple:
+    """(bytes in the pools, bytes in per-slot state) of a paged cache, of
+    arrays or of their shapes."""
+    totals = [0, 0]
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        totals[is_slot_leaf(path)] += leaf.size * leaf.dtype.itemsize
+    return tuple(totals)
+
+
 def scale_factors(scales: jax.Array) -> jax.Array:
     """fp32 dequantization multipliers from a scale sibling. int8 scale
     siblings (fp8 pools) hold power-of-two EXPONENTS: the multiplier is
@@ -470,8 +501,14 @@ class BlockAllocator:
 
 
 def init_paged_cache(config, params, n_blocks: int, block_len: int,
-                     kv_dtype: Optional[str] = None):
+                     kv_dtype: Optional[str] = None,
+                     n_slots: Optional[int] = None):
     """Zero block-pooled KV cache for ``TransformerLM(config)``.
+
+    A config whose attention keeps state a REQUEST (``cca_tail_width`` >
+    0) gets one more leaf a layer beside its pools, ``tail``
+    ``[n_slots + 1, width]`` in the model's dtype (``SLOT_LEAF``; the last
+    row is the trash row), and so needs ``n_slots``.
 
     Shapes come from ``eval_shape`` on the dense decode cache at batch 1
     (nothing is traced into a compiled program), then every
@@ -508,13 +545,18 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     shapes = jax.eval_shape(
         lambda p: init_cache(config, p, 1), params
     )
+    if getattr(config, "cca_tail_width", 0) and (
+            n_slots is None or kv_dtype is not None):
+        raise ValueError(
+            "this config keeps a tail a request beside its K/V blocks: "
+            "init_paged_cache needs n_slots=, and a quantized pool "
+            f"(kv_dtype {kv_dtype!r}) is not supported with it")
     if kv_dtype is None:
-        return jax.tree.map(
+        return map_cache(
             lambda s: jnp.zeros(
-                _dense_to_pool(s.shape, n_blocks, block_len), s.dtype
-            ),
-            shapes,
-        )
+                _dense_to_pool(s.shape, n_blocks, block_len), s.dtype),
+            lambda s: jnp.zeros((n_slots + 1,) + s.shape[1:], s.dtype),
+            shapes)
 
     from collections.abc import Mapping
 
@@ -556,13 +598,19 @@ def pool_block_bytes(config, params, block_len: int,
     nothing is allocated."""
     shapes = jax.eval_shape(
         lambda p: init_paged_cache(config, p, 2, block_len,
-                                   kv_dtype=kv_dtype),
+                                   kv_dtype=kv_dtype, n_slots=1),
         params,
     )
-    total = sum(
-        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(shapes)
-    )
-    return total // 2
+    return cache_bytes(shapes)[0] // 2
+
+
+def pool_slot_bytes(config, params) -> int:
+    """HBM bytes ONE slot costs across every layer in state that belongs
+    to a request and not to a block (``SLOT_LEAF``): ``pool_block_bytes``'
+    sibling. 0 for a config whose cache is block chains only."""
+    shapes = jax.eval_shape(
+        lambda p: init_paged_cache(config, p, 2, 1, n_slots=1), params)
+    return cache_bytes(shapes)[1] // 2
 
 
 def paged_cache_specs(config, cache):

@@ -1320,8 +1320,19 @@ class Scheduler:
         # collect-side host work under its own mark: the one-loop async
         # A/B needs "processing replica i's tokens" visible as a cause
         # when it serializes another replica's gap
-        with spans.tracer().span("sched.collect.process"), \
+        with spans.tracer().span("sched.collect.process") as process, \
                 self.ledger.host("tick-collect", self.replica_id):
+            counts = self.engine.tick_expert_counts
+            if counts is not None and counts.size:
+                # what the tick's expert layers took (fetched with its
+                # tokens): the fullest expert's tokens and the experts
+                # with a token, each a mean over the layers, and the live
+                # lanes a layer routed
+                process.args.update(
+                    expert_tokens_peak=float(counts.max(axis=1).mean()),
+                    experts_hit=float((counts > 0).sum(axis=1).mean()),
+                    routed=int(counts[0].sum()),
+                )
             self._process_collected(h, tokens, now, out)
         self._collected.extend(out)
         if (self.host_pool is not None and out

@@ -31,7 +31,7 @@ def test_tiny_smoke_runs_every_phase(tmp_path):
     assert lines[-1]["device"]["platform"] == "cpu"  # never a TPU's line
     phases = [r["phase"] for r in lines[:-1]]
     assert phases == ["env", "resnet", "sync_check", "lm", "server", "pool",
-                      "ouro", "compile_cache"]
+                      "ouro", "zaya", "compile_cache"]
     assert not any(r.get("failed") for r in lines[:-1])
     # the exported directory is the cache, and the only one written
     assert lines[0]["compile_cache_dir"] == lines[-2]["dir"] == str(cache)

@@ -119,6 +119,7 @@ def tile_of(monkeypatch):
     pytest.param(64, 16, 4096, 8, id="chat-backlog-bf16"),
     pytest.param(40, 16, 8192, 8, id="reason-backlog-bf16"),
     pytest.param(64, 16, 2 * 1024 + 2 * 64, 8, id="chat-backlog-int8"),
+    pytest.param(160, 16, 2 * 512, 8, id="reason-long-backlog-512-byte-rows"),
     pytest.param(6, 16, 4096, 6, id="table-narrower-than-a-tile"),
     pytest.param(8, 128, 4096, 1, id="blocks-of-128-the-parent-grid"),
     pytest.param(8, 256, 4096, 1, id="blocks-wider-than-a-tile"),
@@ -257,6 +258,38 @@ def test_paged_flash_matches_dense_at_the_served_shapes(tile_of, c, bl, w,
     got, want = (np.asarray(x, np.float32) for x in (pallas, dense))
     assert np.abs(want[:3]).max() < 10  # nothing of the trash block came in
     np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
+
+
+@pytest.mark.parametrize("tile", [2, None])
+def test_the_grouped_fold_at_two_narrow_heads_matches_dense(tile_of, tile):
+    """zaya1-8b's tick: 8 query heads of 128 over 2 narrow heads (rows of
+    256 lanes, 512 bytes in bfloat16), one position a lane, so a narrow
+    head reads with ``G x C`` = 4 query rows folded into one product.
+    Ragged frontiers, tables padded with the trash block, one lane
+    inactive; against the dense gather to a bfloat16 ulp."""
+    b, h, h_kv, d, bl, w = 4, 8, 2, 128, 16, 10
+    tile_of(tile, bl)
+    assert tile_blocks(w, bl, 2 * 2 * h_kv * d) == (tile or 8)
+    rng = np.random.default_rng(31)
+    kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
+    assert kp.shape[-1] == h_kv * d
+    kp = kp.at[0].set(37.0).astype(jnp.bfloat16)  # the trash block
+    vp = vp.at[0].set(-53.0).astype(jnp.bfloat16)
+    ends = np.array([w * bl - 1, 41, 130, 22])
+    live = ends // bl + 1
+    live[3] = 0  # the inactive lane
+    tables = jnp.where(np.arange(w)[None, :] < live[:, None], tables, 0)
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.bfloat16)
+    q_positions = jnp.asarray(ends[:, None], jnp.int32)
+    dense = paged_attention(q, kp, vp, tables, q_positions,
+                            gather_impl="dense")
+    pallas = paged_attention(q, kp, vp, tables, q_positions,
+                             gather_impl="pallas")
+    got, want = (np.asarray(x, np.float32) for x in (pallas, dense))
+    assert np.abs(want[:3]).max() < 10  # nothing of the trash block came in
+    np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
+    # the heads of a group do read different rows of q
+    assert np.abs(got[0, 0, 0] - got[0, 0, 1]).max() > 1e-3
 
 
 @pytest.mark.parametrize("tile", [1, 2, 8])

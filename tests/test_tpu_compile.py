@@ -502,3 +502,85 @@ def test_the_chunk_programs_lower_to_the_parents_text(v5e, monkeypatch, cell,
     assert "tpu_custom_call" not in text
     assert hashlib.sha256(text.encode()).hexdigest()[:12] == CHUNK_DIGESTS[
         cell, bucket]
+
+
+# ---- the zaya block's served programs (PR 31) ------------------------------
+
+#: zaya1-8b.reason-long-backlog's attention, experts, pool and slots
+#: (perfbench/configs, perfbench/cells), on 2 layers and a small vocabulary
+ZAYA_CELL = dict(slots=128, blocks=9217, block_len=16, chunk=128,
+                 max_seq_len=2560)
+ZAYA_BLOCK = dict(
+    embed_dim=2048, num_heads=8, num_kv_heads=2, head_dim=128,
+    attn_kind="cca", pos_embedding="rope", rope_theta=5e6, rotary_share=0.5,
+    norm="rmsnorm", norm_eps=1e-5, use_bias=False, tie_embeddings=True,
+    residual_scaling=True, n_experts=16, moe_every=1, moe_kind="dropless",
+    moe_dim=2048, router_dim=256)
+
+
+@pytest.mark.parametrize("program", ["decode_tick", "chunk_prefill[k=4,w=32]"])
+def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
+    """The tick reads K/V of 2 narrow heads of 128 through the fused
+    kernel's grouped fold (4 query rows a narrow head, rows of 256 lanes:
+    Mosaic takes the DMAs of its 512-byte pool rows), once a layer, and
+    the chunk program gathers dense; both run the experts as XLA's
+    grouped products (``ragged-dot``, two a layer) and neither moves a
+    pool-sized array."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = ZAYA_CELL
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=2, max_seq_len=c["max_seq_len"],
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense", **ZAYA_BLOCK)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    n = c["slots"]
+    eng = PagedEngine(cfg, params, n, n_blocks=2, block_len=c["block_len"],
+                      prefill_chunk=c["chunk"], chunk_bucket_floor=(2, 32),
+                      max_chunk_jobs=4)
+    assert eng.gather_impl == "pallas" and eng.tile_blocks == 8
+    pool = jax.eval_shape(
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
+                                   n_slots=n), params)
+    one = SingleDeviceSharding(v5e.devices[0])
+    i32 = jnp.int32
+    if program == "decode_tick":
+        fn = eng._decode()
+        args = (params, pool, eng.logits, jnp.zeros((n,), i32),
+                jnp.zeros((n,), bool), jnp.zeros((n, eng.table_width), i32),
+                jax.random.key(0))
+    else:
+        k, w = 4, 32
+        fn = eng._chunk_fn(k, w)
+        assert eng.chunk_program_name(k, w) == program
+        args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
+                jnp.zeros((k,), i32), jnp.zeros((k, w), i32),
+                jnp.zeros((k,), i32), jnp.zeros((k,), bool),
+                jnp.zeros((k,), i32), jnp.zeros((k,), i32))
+    compiled = fn.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        args)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reads = [x for x in calls if "paged_decode_attn" in x]
+    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
+    assert len(reads) == (2 if program == "decode_tick" else 0), calls
+    assert len(grouped) == 4, calls  # gate and up side by side, and down
+    # the counts come back beside what the programs returned before
+    shapes = [tuple(s.shape) for s in jax.tree.leaves(
+        jax.eval_shape(fn, *args))]
+    assert shapes[-1] == (2, 16)
+    leaf = jax.tree.leaves(pool)[0]
+    moved = [m.group(1) for m in re.finditer(
+        r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
+        if math.prod(map(int, m.group(2).split(","))) == leaf.size]
+    assert not moved, moved
