@@ -357,7 +357,7 @@ def server_phase() -> None:
         reference = Scheduler(cfg, params, **kw)
         want = replay(reference, prompts, max_new)
 
-        router = FleetRouter(cfg, params, n_replicas=1, async_host=True,
+        router = FleetRouter(cfg, params, n_replicas=1,
                              retain_results=False, **kw)
         gw = Gateway(router, port=0).start()
         try:

@@ -21,18 +21,22 @@ collectives are a known jaxlib CPU gap). Requests enter through
   queue / shed against the live per-replica TTFT/queue-wait percentiles
   and queue depths; sheds are explicit per-request JSONL records with
   ``rejected: true`` and a reason;
-- **one host loop**: ``step()`` ticks every replica once — decode
-  replicas first (their token sync never waits behind freshly dispatched
-  prefill work), then prefill/mixed replicas, then the handoff pump.
-  **Round 16 (``async_host=True``)** turns that loop into
-  dispatch-then-collect: every replica's compiled tick is LAUNCHED
-  back-to-back (JAX async dispatch — nothing materializes), results are
-  drained one tick LAGGED (the PR 4 metrics-ring idiom), and the
-  per-request host work rides a small ``HostWorkerPool`` — so replica
-  B's device no longer sits idle for replica A's tokenize/JSONL/gate
-  math. Greedy token streams are bit-identical between the two loops
-  (per replica, collect(N−1) → dispatch(N) IS the synchronous
-  schedule); ``async_host=False`` stays the step-domain reference.
+- **one host loop, one tick in flight**: ``step()`` runs every replica
+  once — decode replicas first, then prefill/mixed replicas, then the
+  handoff pump — and for each it COLLECTS the tick launched by the
+  previous step, then LAUNCHES the next (JAX async dispatch: nothing
+  materializes) and returns with it in flight. So ``step()`` N returns
+  tick N−1's tokens (the first returns none; ``drain()``/``idle`` wait
+  for the last), and the device works through ``submit`` and whatever
+  the caller does between two steps, and through the other replicas'
+  host work. Per-request host work (JSONL, the gate's percentile math)
+  rides a small ``HostWorkerPool`` whose threads end with the router.
+  Per replica, collect(N−1) → dispatch(N) IS the schedule of a loop
+  that fetches each tick's tokens inside its launch, so greedy token
+  streams are bit-identical to that loop's. ``async_host=False`` builds
+  it (``Scheduler.step()`` a replica, no pool): the step-domain
+  reference the parity tests compare against, not a serving mode
+  (ROADMAP C1c deletes it).
 
 Disaggregated prefill/decode (``disaggregate=True``): the first
 ``n_prefill`` replicas run ``prefill_only`` schedulers — chunk programs
@@ -85,6 +89,7 @@ from __future__ import annotations
 
 import logging
 import time
+import weakref
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -142,7 +147,7 @@ class FleetRouter:
                  slo: Optional[SLOConfig] = None, devices=None,
                  seed: int = 0, metrics_log=None,
                  flightrec=None, reqtrace=None, ledger=None,
-                 async_host: bool = False, host_threads: int = 2,
+                 async_host: bool = True, host_threads: int = 2,
                  affinity_cap: int = 4096,
                  fail_threshold: int = 2,
                  tick_deadline_s: Optional[float] = None,
@@ -187,11 +192,11 @@ class FleetRouter:
         # attributed to replica A's tick — the one-loop serialization
         # ROADMAP item 3's async refactor must remove
         self.ledger = ledger if ledger is not None else NULL_LEDGER
-        # async host runtime (round 16; ROADMAP item 3): dispatch-then-
-        # collect replica ticks + ONE worker pool shared by every
-        # replica for the off-critical-path host work (JSONL emission,
-        # gate-metric percentile math). async_host=False keeps the
-        # synchronous loop bit-for-bit — the step-domain A/B reference.
+        # the host loop: collect tick N-1, dispatch tick N, return with
+        # N in flight, and ONE worker pool shared by every replica for
+        # the host work off the critical path (JSONL emission, the
+        # gate's percentile math). async_host=False is the tests'
+        # step-domain reference: Scheduler.step() a replica, no pool.
         self.async_host = bool(async_host)
         self.host_pool = None
         if self.async_host:
@@ -200,6 +205,9 @@ class FleetRouter:
             )
 
             self.host_pool = HostWorkerPool(n_threads=host_threads)
+            # the workers end with the router: nothing calls close(), and
+            # a parked thread outlives whoever built the router
+            weakref.finalize(self, self.host_pool.stop)
         # block-lifecycle sanitizer (analysis.blocksan; PDT_BLOCKSAN=1):
         # ONE sanitizer shared by every replica, so handoff pins and
         # violations aggregate fleet-wide and one assert_clean() covers
@@ -835,10 +843,10 @@ class FleetRouter:
     # ---- routing ----
 
     def _group_metrics(self, group: List[int]) -> Dict[int, dict]:
-        # gate_metrics == metrics() on the synchronous loop; under the
-        # async loop it is the worker-refreshed snapshot + live cheap
-        # counters, so per-submit routing stops paying the O(n log n)
-        # percentile math on the critical path
+        # gate_metrics is the worker-refreshed snapshot + live cheap
+        # counters, so a submit does not pay the O(n log n) percentile
+        # math of metrics() on the critical path (the async_host=False
+        # reference has no pool and reads metrics() itself)
         return {i: self.replicas[i].gate_metrics() for i in group}
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int, *,
@@ -1069,20 +1077,22 @@ class FleetRouter:
         return toks
 
     def step(self) -> List[Tuple[int, int]]:
-        """One fleet tick. Synchronous loop: tick each replica fully —
-        decode replicas first (their token sync stays clear of this
-        tick's fresh prefill dispatches), then prefill/mixed replicas,
-        then the handoff pump. Async loop (``async_host=True``):
-        **dispatch-then-collect** — first COLLECT every replica's
-        previous tick (lagged: those ticks have been in flight across
-        the pump and all inter-step host work), then DISPATCH every
-        replica's next tick back-to-back so every compiled program is
-        enqueued before any of this step's host work runs, then the
-        pump. Per replica the order collect(N−1) → dispatch(N) is the
-        synchronous schedule, so greedy token streams are bit-identical
-        between modes; only cross-replica interleaving (and the wall
-        clock) changes."""
-        with spans.tracer().span("router.step"):
+        """One fleet tick: for each replica — decode replicas first,
+        then prefill/mixed — COLLECT the tick the previous step launched
+        (it has been in flight across the pump, the caller's work and
+        its submits), then DISPATCH the next and leave it in flight;
+        then the handoff pump. Returns the collected ticks' tokens, so
+        step N returns tick N−1's. Per replica the order collect(N−1) →
+        dispatch(N) is the schedule of the ``async_host=False``
+        reference, which ticks each replica fully (``Scheduler.step()``:
+        the tokens fetched inside the launch), so greedy token streams
+        are bit-identical between the two; only cross-replica
+        interleaving (and the wall clock) changes. The span's
+        ``in_flight`` is how many replicas entered the step with a
+        token-bearing tick pending: 0 on the reference loop and on the
+        first step, the replicas that decode thereafter."""
+        in_flight = sum(s.tick_in_flight for s in self.replicas)
+        with spans.tracer().span("router.step", in_flight=in_flight):
             if self._start_time is None:
                 self._start_time = time.perf_counter()
             out: List[Tuple[int, int]] = []
@@ -1090,7 +1100,7 @@ class FleetRouter:
             # tick N starts prefilling at tick N (once its backoff elapses)
             # — no extra tick of dead air between death and recovery
             self._pump_redispatch()
-            # note: interleaved collect/dispatch in the async loop — while
+            # note: collect and dispatch interleave across replicas — while
             # replica i's freshly dispatched tick N is in flight, the loop
             # is already collecting replica i+1's tick N−1 and building its
             # tick N, so every replica's dispatch-side host work overlaps
@@ -1113,7 +1123,7 @@ class FleetRouter:
     def idle(self) -> bool:
         # Scheduler.idle counts parked and mid-swap requests as
         # in-flight work, so a drain never strands a preempted stream;
-        # has_uncollected keeps the async loop stepping until every
+        # has_uncollected keeps the loop stepping until every
         # in-flight tick's tokens have been collected AND delivered;
         # pending re-dispatches are in-flight work too — a fleet with a
         # harvested request waiting out its backoff is NOT idle

@@ -53,8 +53,7 @@ thread, before the final token fans out) closes each stream with its
 true outcome, so the terminal SSE event and the span tree always
 agree.
 
-    router = FleetRouter(cfg, params, async_host=True,
-                         retain_results=False, ...)
+    router = FleetRouter(cfg, params, retain_results=False, ...)
     with Gateway(router, port=8000) as gw:
         ...  # curl -N -X POST :8000/v1/generate -d '{"prompt": [1,2]}'
 

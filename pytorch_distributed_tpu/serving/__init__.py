@@ -31,12 +31,13 @@ every program's on another backend:
 quantized variant (``kv_dtype="int8"``, per-row scales, ~2x blocks at
 fixed bytes) — ANALYSIS.md "Paged attention kernel & quantized KV".
 
-Round 16: the async host runtime — ``scheduler`` splits each tick into
-a non-blocking ``dispatch_tick`` and a lagged ``collect_tick``
+Round 16: the host runtime — ``scheduler`` splits each tick into a
+non-blocking ``dispatch_tick`` and a lagged ``collect_tick``
 (``engine.decode_launch``/``decode_collect``), and ``host_worker``
 provides the thread pool the off-critical-path host work (JSONL, gate
-percentile math) runs on; ``fleet.FleetRouter(async_host=True)`` is
-the driver — ANALYSIS.md "Async host runtime".
+percentile math) runs on; ``fleet.FleetRouter`` is the driver, and
+that lagged loop is the one it runs (``async_host=False`` is the
+tests' step-domain reference) — ANALYSIS.md "Async host runtime".
 """
 
 from pytorch_distributed_tpu.serving.kv_pool import (

@@ -52,7 +52,9 @@ class HostWorkerPool:
 
     ``submit(fn)`` enqueues; ``flush()`` blocks until everything
     enqueued so far has run (and re-raises the first worker error);
-    ``close()`` flushes and joins the threads. Thread names
+    ``close()`` flushes and joins the threads; ``stop()`` ends them
+    without waiting (a ``FleetRouter``'s pool stops when the router is
+    collected). Thread names
     (``pdt-host-0`` ...) are load-bearing: ``DispatchLedger.host``
     stamps them into worker-side host marks, which is how
     ``classify_bubbles`` tells overlapped worker work apart from
@@ -88,6 +90,10 @@ class HostWorkerPool:
                 with self._lock:
                     self.errors.append(e)
             finally:
+                # a parked worker must not keep its last closure: it
+                # captures a Scheduler, and through it the engine's
+                # weights and pools
+                fn = None
                 with self._lock:
                     self.completed += 1
                 self._q.task_done()
@@ -122,15 +128,24 @@ class HostWorkerPool:
         with self._lock:
             return self.submitted - self.completed
 
-    def close(self) -> None:
-        """Flush, then stop and join every worker. Idempotent."""
+    def stop(self) -> None:
+        """Refuse new work and let every worker exit once what is queued
+        has run, WITHOUT waiting for it. This is what a router's
+        finalizer calls: the collector may run it on any thread, a
+        worker's own included, where a join would never return.
+        Idempotent."""
         with self._lock:
             if self._closed:
                 return
             self._closed = True
-        self._q.join()
         for _ in self._threads:
             self._q.put(_STOP)
+
+    def close(self) -> None:
+        """``stop()``, then join every worker (each runs what was queued
+        before its sentinel) and re-raise the first worker error.
+        Idempotent."""
+        self.stop()
         for t in self._threads:
             t.join()
         with self._lock:

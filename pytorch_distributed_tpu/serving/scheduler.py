@@ -63,18 +63,21 @@ instead of arming decode, and ``peek_ready``/``complete_handoff`` +
 (``PagedEngine.export_chain``/``import_chain``) — the disaggregated
 prefill/decode split.
 
-Async host runtime (round 16; ANALYSIS.md "Async host runtime"):
-``step()`` is now a thin wrapper over a **dispatch/collect split** —
-``dispatch_tick()`` runs admissions, the chunk program, and a
-NON-BLOCKING decode launch (``PagedEngine.decode_launch``: JAX async
-dispatch returns before device completion), parking a ``TickHandle``;
-``collect_tick()`` materializes the parked tick's tokens and does all
-per-token host work (TTFT, retirement, JSONL). The fleet router's
-``async_host=True`` loop drives the halves LAGGED — collect tick N−1,
-then dispatch tick N back-to-back on every replica — so one replica's
-host work overlaps the others' in-flight device work. Per replica the
-order collect(N−1) → dispatch(N) is exactly the synchronous schedule,
-which is why token streams are bit-identical between modes. Any entry
+Host runtime (round 16; ANALYSIS.md "Async host runtime"): a tick is
+a **dispatch/collect split** — ``dispatch_tick()`` runs admissions,
+the chunk program, and a NON-BLOCKING decode launch
+(``PagedEngine.decode_launch``: JAX async dispatch returns before
+device completion), parking a ``TickHandle``; ``collect_tick()``
+materializes the parked tick's tokens and does all per-token host work
+(TTFT, retirement, JSONL). ``fleet.FleetRouter`` drives the halves
+LAGGED — collect tick N−1, then dispatch tick N, on every replica, and
+return with N in flight — so the device works through the caller's
+submits and the other replicas' host work. ``step()`` is the same two
+halves with the tokens fetched inside the launch: the step-domain
+schedule of a lone ``Scheduler`` and of ``FleetRouter(async_host=
+False)``, which the parity tests hold the lagged loop to. Per replica
+the order collect(N−1) → dispatch(N) is exactly that schedule, which
+is why token streams are bit-identical between the two. Any entry
 point that mutates decode-armed state from OUTSIDE the tick cycle
 (``preempt``/``preempt_lru``/``begin_drain``) collects the pending
 tick first, so an in-flight decode can never race a chain release.
@@ -1278,15 +1281,20 @@ class Scheduler:
         return out
 
     @property
+    def tick_in_flight(self) -> bool:
+        """True while a launched tick's tokens wait on the device for
+        ``collect_tick`` (a tick with no active decode lane parks a
+        handle too, with nothing to collect)."""
+        h = self._pending_tick
+        return h is not None and h.tokens is not None
+
+    @property
     def has_uncollected(self) -> bool:
         """True while a token-bearing tick is in flight or collected
         tokens await delivery — the router's drain loop must keep
         stepping (``idle`` alone reads host state, which a pending tick
         is about to change)."""
-        h = self._pending_tick
-        return bool(self._collected) or (
-            h is not None and h.tokens is not None
-        )
+        return bool(self._collected) or self.tick_in_flight
 
     def _collect_pending_tick(self) -> None:
         h = self._pending_tick
@@ -1964,14 +1972,15 @@ class Scheduler:
         self.host_pool.submit(work)
 
     def gate_metrics(self) -> dict:
-        """The SLO gate's routing view of this replica. Synchronous
-        loop: the full (exact, O(n log n)) ``metrics()``. Async loop:
-        the worker-refreshed percentile snapshot overlaid with LIVE
-        cheap counters — queue depth, occupancy, draining, preemptible,
-        anomaly — so every depth-bound decision the gate makes is
-        byte-identical to what the synchronous loop would decide, and
-        only the wall-clock percentile rungs see (≤ one tick of)
-        staleness."""
+        """The SLO gate's routing view of this replica. Without a
+        worker pool (a lone ``Scheduler``, the ``async_host=False``
+        reference): the full (exact, O(n log n)) ``metrics()``. Under
+        the router's pool: the worker-refreshed percentile snapshot
+        overlaid with LIVE cheap counters — queue depth, occupancy,
+        draining, preemptible, anomaly — so every depth-bound decision
+        the gate makes is byte-identical to what the reference would
+        decide, and only the wall-clock percentile rungs see (at most
+        ``gate_refresh_ticks`` of) staleness."""
         if self.host_pool is None:
             return self.metrics()
         with self._gate_lock:

@@ -228,11 +228,11 @@ def _parse(argv=None) -> argparse.Namespace:
                         "roles with KV-block handoff (needs --replicas "
                         ">= 2)")
     p.add_argument("--async-host", action="store_true",
-                   help="round-16 async host runtime: dispatch-then-"
-                        "collect replica ticks (lagged token collect) "
-                        "+ worker threads for JSONL/gate-metric host "
-                        "work; greedy token streams identical to the "
-                        "synchronous loop")
+                   help="serve through a FleetRouter even at one "
+                        "replica. Its loop (collect the last tick, "
+                        "dispatch the next, worker threads for JSONL/"
+                        "gate-metric host work) is every fleet path's, "
+                        "with or without this flag")
     p.add_argument("--prefill-replicas", type=int, default=1,
                    help="prefill replicas when --disaggregate")
     p.add_argument("--slo-ttft-ms", type=float, default=None,
@@ -394,9 +394,8 @@ def main() -> None:
             disaggregate=args.disaggregate,
             n_prefill=args.prefill_replicas, slo=slo, seed=args.seed,
             metrics_log=mlog, reqtrace=reqtrace,
-            # the front door streams: async host loop, results dropped
-            # at retire (the connection consumed them token by token)
-            async_host=args.async_host or http_mode,
+            # the front door streams: results dropped at retire (the
+            # connection consumed them token by token)
             retain_results=not http_mode,
             n_slots=args.slots,
             block_len=args.block_len, prefill_chunk=args.prefill_chunk,

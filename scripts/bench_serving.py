@@ -1424,7 +1424,7 @@ def measure_http(requests: int = 48, seed: int = 0, slots: int = 4,
     router = FleetRouter(
         cfg, params, n_replicas=replicas, seed=seed, metrics_log=mlog,
         n_slots=slots, block_len=16, prefill_chunk=32,
-        retain_results=False, async_host=True,
+        retain_results=False,
     )
     router.warmup()
     gw = Gateway(router, port=0, metrics_log=mlog)

@@ -606,7 +606,7 @@ params = TransformerLM(cfg).init(
 mlog = MetricsLogger(sys.argv[1])
 router = FleetRouter(
     cfg, params, n_replicas=2, n_slots=3, block_len=8, prefill_chunk=8,
-    async_host=True, retain_results=False, metrics_log=mlog,
+    retain_results=False, metrics_log=mlog,
     reqtrace=ReqTracer(sink=mlog),
 )
 gw = Gateway(router, port=0, metrics_log=mlog)

@@ -15,8 +15,8 @@ preceded the kill, and writes ``result.json`` with every request's token
 stream on a clean finish.
 
 Round 16 (``--fleet-async``): the same seeded workload through a
-2-replica ``FleetRouter(async_host=True)`` — the dispatch-then-collect
-loop with worker threads — so the kill matrix gains an async-loop cell:
+2-replica ``FleetRouter`` — its dispatch-then-collect loop with worker
+threads — so the kill matrix gains an async-loop cell:
 SIGKILL inside a swap window while ticks are in flight and workers hold
 queued JSONL must still leave nothing durable to corrupt, and the
 relaunch must serve token streams identical to the synchronous
@@ -81,7 +81,7 @@ def main() -> int:
         # flight and worker threads hold queued telemetry when the
         # fault plan SIGKILLs inside the swap window
         r = FleetRouter(
-            cfg, params, n_replicas=2, async_host=True,
+            cfg, params, n_replicas=2,
             slo=SLOConfig(spill_queue_depth=2, shed_queue_depth=10**6),
             flightrec=flightrec, n_slots=4, n_blocks=10, block_len=8,
             prefill_chunk=16, offload=True, preempt_on_oom=True,

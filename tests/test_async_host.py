@@ -224,6 +224,126 @@ def test_early_collect_on_preempt_and_drain(model):
 
 
 # ---------------------------------------------------------------------------
+# the default loop: a router built with no loop argument
+# ---------------------------------------------------------------------------
+
+
+def _backlog_router(cfg, params, **kw):
+    """One replica, built as the benchmark's serving cells build it: no
+    loop argument, results dropped at retire."""
+    return FleetRouter(
+        cfg, params, n_replicas=1, retain_results=False,
+        slo=SLOConfig(spill_queue_depth=10**6, shed_queue_depth=10**6),
+        **SCHED_KW, **kw,
+    )
+
+
+def _drive_backlog(router, cfg, *, first=4, total=10, max_new=5):
+    """Drive a router the way the cell does: a closed backlog, a submit
+    between two steps for every request that retired. Returns the
+    streams by rid and what each ``step()`` returned."""
+    stream = iter(_prompts(cfg, lens=(5, 16, 23, 31, 9, 17, 12, 7, 20, 11),
+                           seed=3)[:total])
+    streams, live, per_step = {}, set(), []
+    for _ in range(first):
+        live.add(router.submit(next(stream), max_new))
+    for _ in range(400):
+        out = router.step()
+        per_step.append(out)
+        for rid, tok in out:
+            streams.setdefault(rid, []).append(int(tok))
+            if len(streams[rid]) >= max_new:
+                live.discard(rid)
+                nxt = next(stream, None)
+                if nxt is not None:
+                    live.add(router.submit(nxt, max_new))
+        if not live and router.idle:
+            break
+    assert not live and router.idle
+    return streams, per_step
+
+
+def test_the_default_loop_keeps_a_tick_in_flight(model):
+    """A ``FleetRouter`` that is told nothing about its loop runs the
+    lagged one: the first ``step()`` returns nothing and leaves a tick
+    in flight, step N+1 returns the tokens tick N decoded, the
+    ``router.step`` span says how many replicas entered with a tick
+    pending (0, then 1), and the streams equal, request by request, the
+    step-domain reference's on the same seeded traffic."""
+    from pytorch_distributed_tpu.telemetry import spans
+
+    cfg, params = model
+    tracer = spans.tracer()
+    router = _backlog_router(cfg, params)
+    assert router.async_host and router.host_pool is not None
+    sched = router.replicas[0]
+    t_lo = time.perf_counter()
+    got, per_step = _drive_backlog(router, cfg)
+    assert per_step[0] == []
+    steps = tracer.events("router.step", t_lo=t_lo)[:len(per_step)]
+    flights = [e.args["in_flight"] for e in steps]
+    assert flights[0] == 0
+    # a step that entered with a token-bearing tick pending returns that
+    # tick's tokens, and only such a step returns any
+    assert [bool(out) for out in per_step] == [f == 1 for f in flights]
+    assert sum(flights) >= len(flights) - 3 and set(flights) == {0, 1}
+    # tick N's tokens come out of step N+1: the step that returns a
+    # request's first token is one past the tick that armed its lane
+    # (ROADMAP C1c deletes the option the reference is built with)
+    ref = _backlog_router(cfg, params, async_host=False)
+    want, ref_steps = _drive_backlog(ref, cfg)
+    assert ref.host_pool is None
+    assert {e.args["in_flight"] for e in tracer.events("router.step")[
+        -len(ref_steps):]} == {0}
+    first = next(i for i, out in enumerate(per_step) if out)
+    ref_first = next(i for i, out in enumerate(ref_steps) if out)
+    assert first == ref_first + 1
+    assert per_step[first] == ref_steps[ref_first]
+    assert got == want and len(got) == 10
+    assert all(len(v) == 5 for v in got.values())
+    assert not sched.has_uncollected and not sched.tick_in_flight
+    assert sched.engine.allocator.in_use == 0
+
+
+def test_a_dropped_router_takes_its_threads_and_its_engine_with_it(model):
+    """The pool's threads end with the router, and a tick left in flight
+    goes with its scheduler: after the harness's ``del router;
+    gc.collect()`` no ``pdt-host`` thread of that router is alive and
+    nothing holds its engine (its weights and pools)."""
+    import gc
+    import weakref
+
+    cfg, params = model
+    router = _backlog_router(cfg, params)
+    sched = router.replicas[0]
+    sched.gate_refresh_ticks = 1  # a worker runs closures over the scheduler
+    for p in _prompts(cfg):
+        router.submit(p, 8)
+    for _ in range(6):
+        router.step()
+    assert sched.tick_in_flight  # dropped mid-flight, as the cell drops it
+    assert router.host_pool.submitted > 0
+    deadline = time.monotonic() + 30
+    while router.host_pool.pending and time.monotonic() < deadline:
+        time.sleep(0.01)  # a worker mid-closure still holds the scheduler
+    assert router.host_pool.pending == 0
+    threads = list(router.host_pool._threads)
+    assert threads and all(t.is_alive() for t in threads)
+    assert all(t.name.startswith("pdt-host") for t in threads)
+    engine = weakref.ref(sched.engine)
+    dead_router = weakref.ref(router)
+    router.replicas.clear()
+    del router, sched
+    gc.collect()
+    assert dead_router() is None
+    for t in threads:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    gc.collect()
+    assert engine() is None
+
+
+# ---------------------------------------------------------------------------
 # worker pool semantics
 # ---------------------------------------------------------------------------
 

@@ -73,7 +73,6 @@ def _build_router(cfg, params, **kw):
     kw.setdefault("n_slots", 3)
     kw.setdefault("block_len", 8)
     kw.setdefault("prefill_chunk", 8)
-    kw.setdefault("async_host", True)
     kw.setdefault("retain_results", False)
     return FleetRouter(cfg, params, **kw)
 
@@ -96,6 +95,7 @@ def gw_env(tiny_model, tmp_path_factory):
     # step() directly — exactly what the gateway's driver does.
     # n_replicas=1: routing never changes a request's greedy stream, and
     # one engine init keeps the module fixture cheap in the fast tier.
+    # the step-domain reference (ROADMAP C1c deletes the option)
     ref_router = _build_router(cfg, params, async_host=False, n_replicas=1)
     ref_rids = [ref_router.submit(p, 6) for p in prompts]
     reference = {rid: [] for rid in ref_rids}

@@ -23,10 +23,11 @@ from pytorch_distributed_tpu.telemetry import spans
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: the serving tick's spans, in the order the tick opens them
-TICK_ORDER = ["sched.expire", "sched.admit", "sched.chunk_plan",
-              "engine.chunk.launch", "engine.decode.launch",
-              "engine.collect.wait", "sched.collect.process"]
+#: the serving tick's spans, in the order a ``router.step`` opens them:
+#: it collects the tick the last step launched, then dispatches the next
+TICK_ORDER = ["engine.collect.wait", "sched.collect.process",
+              "sched.expire", "sched.admit", "sched.chunk_plan",
+              "engine.chunk.launch", "engine.decode.launch"]
 
 
 @pytest.fixture
