@@ -211,47 +211,67 @@ class MoEMLP(nn.Module):
 
 
 class DroplessMoE(nn.Module):
-    """Top-1 expert layer that drops nothing (``TransformerConfig.
+    """Expert layer that drops nothing (``TransformerConfig.
     moe_kind="dropless"``): SwiGLU experts of ``moe_dim`` features, the
-    program's live tokens sorted by expert, the group sizes taken, and the
-    experts' matrices run as grouped products (``jax.lax.ragged_dot``: on
-    a TPU XLA's own grouped-matmul kernel, which reads an expert's matrix
-    only if a token went to it). Rows that are not ``live`` (a chunk's
-    padding, an inactive decode lane) sort behind every group and join
-    none.
+    program's live (token, expert) PAIRS sorted by expert, the group sizes
+    taken, and the experts' matrices run as grouped products
+    (``jax.lax.ragged_dot``: on a TPU XLA's own grouped-matmul kernel,
+    which reads an expert's matrix only if a pair went to it). Each live
+    token contributes ``top_k`` pairs; the pairs of rows that are not
+    ``live`` (a chunk's padding, an inactive decode lane), and the pairs
+    whose expert this shard does not hold, sort behind every group and join
+    none. A token's output is the weighted sum of its pairs' rows.
 
-    The router is a small MLP on a ``router_dim``-wide projection of the
-    token that ADDS the previous layer's router state (``router_mix``
-    times it): a second stream carried from block to block beside ``x``.
-    It runs in float32 at the
-    HIGHEST matrix precision whatever the compute dtype (on a TPU a
-    float32 product is otherwise one bfloat16 pass): a top-1 choice
-    between near-equal logits is where a rounding shows, and the router's
-    matrices are a three-hundredth of a layer's arithmetic.
-    ``router_bias`` enters the choice and not the weight.
+    Two routers, both in float32 at the HIGHEST matrix precision whatever
+    the compute dtype (on a TPU a float32 product is otherwise one bfloat16
+    pass: a choice between near-equal scores is where a rounding shows,
+    and a router is a few hundredths of a layer's arithmetic), both with a
+    ``router_bias`` that enters the choice and not the weight:
 
-    Returns ``(out, router_state)``; sows the tokens each expert took
-    (``[n_experts]`` int32) as ``moe_stats/expert_tokens``.
+    ``router="mlp"`` (top-1): a small MLP on a ``router_dim``-wide
+    projection of the token that ADDS the previous layer's router state
+    (``router_mix`` times it), a second stream carried from block to block
+    beside ``x``; softmax probabilities, the chosen one the weight.
+
+    ``router="sigmoid"`` (``top_k`` >= 1): one matrix and a sigmoid, a score
+    an expert; the ``n_experts`` in ``n_group`` groups, a group scored by
+    the sum of its two best, the ``topk_group`` best groups open, the
+    ``top_k`` best experts inside them; weights ``routed_scale * s_i /
+    sum_sel s_j``. ``held`` ``(lo, hi)``: this shard holds experts ``[lo,
+    hi)`` of the ``n_experts`` it scores, its matrices are theirs alone,
+    and the output is ITS part of the routed sum; nothing stands in for the
+    shards that hold the rest. ``shared_dim``: a shared expert of that many
+    features, added for every token.
+
+    Returns ``(out, router_state)`` (the state None behind the sigmoid
+    router); sows the pairs each held expert took (``[experts held]``
+    int32) as ``moe_stats/expert_tokens``.
     """
 
     n_experts: int
     moe_dim: int
-    router_dim: int
+    router_dim: Optional[int] = None
     norm_eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
+    router: str = "mlp"
+    top_k: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    shared_dim: Optional[int] = None
+    held: Optional[tuple] = None
 
-    @nn.compact
-    def __call__(self, x, router_state=None, live=None):
-        b, l, d = x.shape
-        t, e, f = b * l, self.n_experts, self.moe_dim
-        f32 = jnp.float32
-        xf = x.reshape(t, d)
+    @nn.nowrap
+    def _f32_dense(self, width, name):
+        return nn.Dense(width, use_bias=False, dtype=jnp.float32, name=name,
+                        precision=jax.lax.Precision.HIGHEST)
 
-        def dense(width, name):
-            return nn.Dense(width, use_bias=False, dtype=f32, name=name,
-                            precision=jax.lax.Precision.HIGHEST)
-
-        state = dense(self.router_dim, "router_down")(xf.astype(f32))
+    @nn.nowrap
+    def _route_mlp(self, xf, router_state):
+        """(chosen expert [T], its probability [T], router state)."""
+        t, e, f32 = xf.shape[0], self.n_experts, jnp.float32
+        state = self._f32_dense(self.router_dim, "router_down")(
+            xf.astype(f32))
         # in every layer's tree, the first's too (which adds nothing: the
         # state before the first layer is zero)
         mix = self.param("router_mix", nn.initializers.ones, (1,))
@@ -261,22 +281,61 @@ class DroplessMoE(nn.Module):
         z = nn.RMSNorm(epsilon=self.norm_eps, dtype=f32,
                        name="router_norm")(state)
         for i in (1, 2):
-            z = nn.gelu(dense(self.router_dim, f"router_w{i}")(z))
-        z = dense(e, "router_w3")(z)
+            z = nn.gelu(self._f32_dense(self.router_dim, f"router_w{i}")(z))
+        z = self._f32_dense(e, "router_w3")(z)
         probs = jax.nn.softmax(z, axis=-1)
         bias = self.param("router_bias", nn.initializers.zeros, (e,))
         choice = jnp.argmax(probs + bias.astype(f32), axis=-1)
         gate = jnp.take_along_axis(probs, choice[:, None], axis=1)[:, 0]
-        if live is not None:
-            # a dead row's expert is one past the last: it sorts behind
-            # every group and its gate is zero
-            choice = jnp.where(live.reshape(t), choice, e)
-            gate = jnp.where(live.reshape(t), gate, 0.0)
+        return choice, gate, state
+
+    @nn.nowrap
+    def _route_sigmoid(self, xf):
+        """(chosen experts [T, k], their weights [T, k])."""
+        e, g, f32 = self.n_experts, self.n_group, jnp.float32
+        scores = jax.nn.sigmoid(self._f32_dense(e, "router")(xf.astype(f32)))
+        bias = self.param("router_bias", nn.initializers.zeros, (e,))
+        sel = scores + bias.astype(f32)
+        if g > 1:
+            best2 = jax.lax.top_k(sel.reshape(-1, g, e // g), 2)[0]
+            kept = jax.lax.top_k(jnp.sum(best2, -1), self.topk_group)[1]
+            open_ = jnp.any(kept[..., None] == jnp.arange(g), axis=-2)
+            sel = jnp.where(jnp.repeat(open_, e // g, axis=-1), sel,
+                            -jnp.inf)
+        choice = jax.lax.top_k(sel, self.top_k)[1]
+        w = jnp.take_along_axis(scores, choice, axis=-1)
+        return choice, self.routed_scale * w / jnp.sum(w, -1, keepdims=True)
+
+    @nn.compact
+    def __call__(self, x, router_state=None, live=None):
+        b, l, d = x.shape
+        t, f, k = b * l, self.moe_dim, self.top_k
+        f32 = jnp.float32
+        xf = x.reshape(t, d)
+        lo, hi = self.held or (0, self.n_experts)
+        e = hi - lo  # the experts whose matrices are here
+        state = None
+        # a pair that joins no group has its expert one past the last: it
+        # sorts behind every group and its weight is zero
+        if self.router == "mlp":
+            choice, gate, state = self._route_mlp(xf, router_state)
+            if live is not None:
+                choice = jnp.where(live.reshape(t), choice, e)
+                gate = jnp.where(live.reshape(t), gate, 0.0)
+        else:
+            choice, gate = self._route_sigmoid(xf)
+            choice, gate = choice.reshape(t * k) - lo, gate.reshape(t * k)
+            joins = (choice >= 0) & (choice < e)
+            if live is not None:
+                joins = joins & jnp.repeat(live.reshape(t), k)
+            choice = jnp.where(joins, choice, e)
+            gate = jnp.where(joins, gate, 0.0)
         sizes = jnp.sum(choice[:, None] == jnp.arange(e)[None, :], axis=0,
                         dtype=jnp.int32)
         self.sow("moe_stats", "expert_tokens", sizes)
         order = jnp.argsort(choice)  # stable: arrival order inside a group
-        xs = xf.astype(self.dtype)[order]
+        # a pair's token (top-1: the pair is the token)
+        xs = xf.astype(self.dtype)[order if k == 1 else order // k]
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
                                                 in_axis=1, out_axis=2,
                                                 batch_axis=0)
@@ -287,8 +346,21 @@ class DroplessMoE(nn.Module):
         hidden = nn.silu(gu[:, :f]) * gu[:, f:]
         ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype), sizes)
         out = jnp.zeros_like(ys).at[order].set(ys)
-        out = (out.astype(f32) * gate[:, None]).astype(self.dtype)
-        if live is not None:  # what a grouped product leaves in a row of
-            # no group is not promised to be a number
+        out = out.astype(f32) * gate[:, None]
+        if self.router != "mlp":
+            # what a grouped product leaves in a row of no group is not
+            # promised to be a number; then a token's pairs add up
+            out = jnp.where(joins[:, None], out, 0.0)
+            out = jnp.sum(out.reshape(t, k, d), axis=1)
+        out = out.astype(self.dtype)
+        if self.shared_dim is not None:
+            sf = self.shared_dim
+            gu = nn.Dense(2 * sf, use_bias=False, dtype=self.dtype,
+                          name="shared_gate_up")(xf)
+            out = out + nn.Dense(d, use_bias=False, dtype=self.dtype,
+                                 name="shared_down")(
+                nn.silu(gu[:, :sf]) * gu[:, sf:])
+        if live is not None:
             out = jnp.where(live.reshape(t, 1), out, jnp.zeros((), out.dtype))
-        return out.reshape(b, l, d), state.reshape(b, l, self.router_dim)
+        return out.reshape(b, l, d), (
+            None if state is None else state.reshape(b, l, self.router_dim))

@@ -165,11 +165,48 @@ class TransformerConfig:
     moe_kind: str = "capacity"
     moe_dim: Optional[int] = None
     router_dim: Optional[int] = None
+    # Two more attention kinds, whose inner width ``num_heads * head_dim``
+    # is their own and whose layers need RoPE's positions only inside
+    # "mla". "kda" (``KDAttention``): delta-rule linear attention with a
+    # gate a channel bounded below, q, k and v through short depthwise
+    # causal convolutions (the bound and the taps are constants of the
+    # module); its cache is a float32 STATE ``[num_heads, head_dim, head_dim]``
+    # and the convolutions' last inputs, both a request's and not a
+    # block's. "mla" (``MLAttention``): latent attention, whose cache is
+    # ONE row a token for all heads (``kv_lora_rank`` normed latent values
+    # and ``qk_rope_head_dim`` rotated key dims). ``layer_group_size`` > 0
+    # mixes them in one stack: with ``attn_kind="kda"`` every
+    # ``layer_group_size``-th layer is "mla" (``attn_kind_at``).
+    kv_lora_rank: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    layer_group_size: int = 0
+    # A dropless expert layer behind the other router: ``moe_router``
+    # "mlp" (an MLP with state across layers, top-1, softmax) or "sigmoid"
+    # (one matrix, sigmoid scores, a selection bias, the experts in
+    # ``moe_n_group`` groups of which the ``moe_topk_group`` best are open,
+    # ``moe_top_k`` experts a token, their weights renormalised and scaled
+    # by ``moe_routed_scale``). ``moe_shared_dim``: a shared expert of that
+    # many features beside the routed ones. ``experts_held`` ``(lo, hi)``:
+    # this shard holds experts ``[lo, hi)`` of the ``n_experts`` the router
+    # scores and returns its part of the routed sum (None: all of them).
+    # The first ``first_k_dense_replace`` layers keep the dense MLP.
+    moe_router: str = "mlp"
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_routed_scale: float = 1.0
+    moe_shared_dim: Optional[int] = None
+    experts_held: Optional[tuple] = None
+    first_k_dense_replace: int = 0
 
     def __post_init__(self):
-        if self.attn_kind not in ("mha", "cca"):
+        if self.attn_kind not in ("mha", "cca", "kda", "mla"):
             raise ValueError(
-                f"attn_kind {self.attn_kind!r} must be 'mha' or 'cca'")
+                f"attn_kind {self.attn_kind!r} must be 'mha', 'cca', 'kda' "
+                "or 'mla'")
+        if self.experts_held is not None:  # a JSON list hashes as a tuple
+            self.experts_held = tuple(int(i) for i in self.experts_held)
+        self._check_linear_and_latent()
+        self._check_sigmoid_router()
         if self.moe_kind not in ("capacity", "dropless"):
             raise ValueError(
                 f"moe_kind {self.moe_kind!r} must be 'capacity' or "
@@ -196,18 +233,18 @@ class TransformerConfig:
                     "sequence shard does not hold, and the tail is one "
                     f"row a request (tp_size {self.tp_size}, ut_steps "
                     f"{self.ut_steps}, attention {self.attention!r})")
-        elif self.head_dim is not None and (
+        elif self.attn_kind == "mha" and self.head_dim is not None and (
                 self.head_dim * self.num_heads != self.embed_dim):
             raise ValueError(
                 f"head_dim {self.head_dim} x num_heads {self.num_heads} "
                 f"!= embed_dim {self.embed_dim}: an inner width of its "
-                "own is attn_kind='cca''s")
-        if self.moe_kind == "dropless":
+                "own is attn_kind='cca''s, 'kda''s and 'mla''s")
+        if self.moe_kind == "dropless" and self.moe_router == "mlp":
             if not self.n_experts or self.moe_top_k != 1:
                 raise ValueError(
-                    "moe_kind='dropless' is top-1 over n_experts > 0 "
-                    f"experts (n_experts {self.n_experts}, moe_top_k "
-                    f"{self.moe_top_k})")
+                    "moe_kind='dropless' behind the MLP router is top-1 "
+                    f"over n_experts > 0 experts (n_experts "
+                    f"{self.n_experts}, moe_top_k {self.moe_top_k})")
             if self.moe_dim is None or self.router_dim is None:
                 raise ValueError(
                     "moe_kind='dropless' needs moe_dim (an expert's "
@@ -217,6 +254,7 @@ class TransformerConfig:
                     "moe_kind='dropless' has an expert layer in every "
                     "block (moe_every 1): the router's state runs through "
                     f"all of them, got moe_every {self.moe_every}")
+        if self.moe_kind == "dropless":
             if self.ep_size > 1 or self.tp_size > 1 or self.ut_steps > 1:
                 raise ValueError(
                     "moe_kind='dropless' holds every expert on one shard "
@@ -329,6 +367,133 @@ class TransformerConfig:
             )
         if not 0.0 <= self.dropout < 1.0:
             raise ValueError(f"dropout must be in [0, 1), got {self.dropout}")
+
+    def _check_linear_and_latent(self):
+        """What ``attn_kind`` "kda" and "mla" can run, and the keys that
+        describe them only."""
+        kinds = set(self.attn_kinds)
+        if self.layer_group_size < 0 or (
+                self.layer_group_size and self.attn_kind != "kda"):
+            raise ValueError(
+                f"layer_group_size {self.layer_group_size} mixes 'mla' "
+                "layers into a stack of attn_kind='kda' (every "
+                "layer_group_size-th layer), got attn_kind "
+                f"{self.attn_kind!r}")
+        if "mla" in kinds:
+            if not self.kv_lora_rank or not self.qk_rope_head_dim or (
+                    self.qk_rope_head_dim % 2):
+                raise ValueError(
+                    "latent attention ('mla') needs kv_lora_rank (the "
+                    "latent's width) and an even qk_rope_head_dim (the "
+                    f"rotated key dims), got {self.kv_lora_rank} and "
+                    f"{self.qk_rope_head_dim}")
+        elif self.kv_lora_rank is not None or (
+                self.qk_rope_head_dim is not None):
+            raise ValueError(
+                "kv_lora_rank and qk_rope_head_dim describe latent "
+                "attention ('mla' layers) only")
+        if not kinds & {"kda", "mla"}:
+            return
+        if self.head_dim is None or self.num_kv_heads is not None or (
+                self.pos_embedding != "rope"):
+            raise ValueError(
+                f"attn_kind {self.attn_kind!r} needs head_dim (the inner "
+                "width is num_heads * head_dim), no num_kv_heads (a state "
+                "or a latent row serves every head) and "
+                "pos_embedding='rope'")
+        if (self.tp_size > 1 or self.ut_steps > 1
+                or self.attention != "dense"):
+            raise ValueError(
+                f"attn_kind {self.attn_kind!r} runs on one shard, one pass "
+                "and attention='dense': the recurrence and the convolutions "
+                "need every earlier token, the state is one a request, and "
+                "latent keys are wider than their values (tp_size "
+                f"{self.tp_size}, ut_steps {self.ut_steps}, attention "
+                f"{self.attention!r})")
+
+    def _check_sigmoid_router(self):
+        """The sigmoid router's keys, and that nothing else carries them."""
+        if self.moe_router not in ("mlp", "sigmoid"):
+            raise ValueError(
+                f"moe_router {self.moe_router!r} must be 'mlp' or 'sigmoid'")
+        if self.moe_router == "mlp":
+            if (self.moe_n_group, self.moe_topk_group, self.moe_routed_scale,
+                    self.moe_shared_dim, self.experts_held,
+                    self.first_k_dense_replace) != (1, 1, 1.0, None, None, 0):
+                raise ValueError(
+                    "moe_n_group, moe_topk_group, moe_routed_scale, "
+                    "moe_shared_dim, experts_held and first_k_dense_replace "
+                    "describe moe_router='sigmoid' only")
+            return
+        e, g = self.n_experts, self.moe_n_group
+        if self.moe_kind != "dropless" or not e or self.moe_dim is None or (
+                self.router_dim is not None or self.moe_every != 1):
+            raise ValueError(
+                "moe_router='sigmoid' is moe_kind='dropless' over "
+                "n_experts > 0 experts of moe_dim features, an expert layer "
+                "in every block from first_k_dense_replace on (moe_every "
+                "1), and no router_dim (one matrix, no MLP)")
+        if g < 1 or e % g or not 1 <= self.moe_topk_group <= g or not (
+                1 <= self.moe_top_k <= self.moe_topk_group * (e // g)):
+            raise ValueError(
+                f"the router's {e} experts split into moe_n_group {g} "
+                f"groups, 1 <= moe_topk_group {self.moe_topk_group} <= "
+                f"{g} stay open and moe_top_k {self.moe_top_k} experts must "
+                "fit inside them")
+        if e // g < 2 and g > 1:
+            raise ValueError(
+                "a group's score is the sum of its two best experts: "
+                f"moe_n_group {g} leaves {e // g} an expert group")
+        if self.experts_held is not None:
+            lo, hi = self.experts_held if len(
+                self.experts_held) == 2 else (0, 0)
+            if not 0 <= lo < hi <= e:
+                raise ValueError(
+                    f"experts_held {self.experts_held!r} must be (lo, hi) "
+                    f"with 0 <= lo < hi <= n_experts {e}")
+        if self.moe_shared_dim is not None and self.moe_shared_dim < 1:
+            raise ValueError(
+                f"moe_shared_dim must be >= 1, got {self.moe_shared_dim}")
+        if not 0 <= self.first_k_dense_replace <= self.num_layers:
+            raise ValueError(
+                f"first_k_dense_replace {self.first_k_dense_replace} must "
+                f"lie in [0, num_layers {self.num_layers}]")
+
+    def attn_kind_at(self, layer: int) -> str:
+        """The attention of layer ``layer``: ``attn_kind``, but "mla" at
+        every ``layer_group_size``-th layer of a mixed stack."""
+        if self.layer_group_size and (layer + 1) % self.layer_group_size == 0:
+            return "mla"
+        return self.attn_kind
+
+    def moe_at(self, layer: int) -> bool:
+        """Whether layer ``layer``'s MLP is the expert layer."""
+        return (bool(self.n_experts)
+                and layer % self.moe_every == self.moe_every - 1
+                and layer >= self.first_k_dense_replace)
+
+    @property
+    def attn_kinds(self) -> tuple:
+        """The kinds of attention in the stack, a name once."""
+        return tuple(sorted({self.attn_kind_at(i)
+                             for i in range(self.num_layers)}))
+
+    @property
+    def slot_state(self) -> bool:
+        """Whether the cache holds state that is a REQUEST's and not a
+        block's (``serving.kv_pool.SLOT_LEAVES``): "cca"'s tail, "kda"'s
+        state and convolution inputs."""
+        return bool({"cca", "kda"} & set(self.attn_kinds))
+
+    @property
+    def latent_row_width(self) -> int:
+        """Values in a token's ONE cache row where the stack has latent
+        attention: the normed latent, the rotated key dims, and zeros up to
+        the next multiple of 128 lanes (the fused read's products take
+        whole lane tiles; no product sees the padding). 0 elsewhere."""
+        if "mla" not in self.attn_kinds:
+            return 0
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
     def head_width(self) -> int:
@@ -1005,6 +1170,388 @@ class CCAttention(nn.Module):
         return out
 
 
+class KDAttention(nn.Module):
+    """Delta-rule linear attention with a gate a channel (``attn_kind=
+    "kda"``; Kimi Delta Attention, arXiv:2510.26692, as
+    ``perfbench/references/ling.py`` writes it down).
+
+    One fused projection takes the normed state to q~, k~ and v~ (``H * D``
+    channels each); each channel runs through a depthwise causal
+    convolution of ``T`` taps and a SiLU; q and k are L2-normed a head (q
+    scaled by ``D ** -0.5``). A head keeps a ``D x D`` float32 STATE ``S``
+    and a token updates it once::
+
+        S <- Diag(alpha_t) S;  S <- S + beta_t k_t (v_t - k_t^T S)^T
+        o_t = S^T q_t
+
+    with ``alpha_t = exp(L * sigmoid(exp(A_log_h) * (x_t W_f + dt_bias)))``
+    in ``(e^L, 1)`` a channel (``L`` = ``LOWER_BOUND``) and ``beta_t =
+    sigmoid(x_t W_b)`` a head. The output is ``o_t`` RMS-normed a head,
+    gated by ``sigmoid(x_t W_g)`` and projected back. The state's products
+    are elementwise float32 multiplies and sums, never a matrix unit's
+    rounded passes.
+
+    What a request carries from one call to the next is no K/V row but the
+    state (``cache/state``, float32) and the convolutions' last ``T - 1``
+    inputs (``cache/conv``): one row a request, zero for a row that starts
+    at position 0 whatever the row held, advanced over the row's REAL
+    positions only (``lengths``). In the paged layout the leaves are
+    ``[n_slots + 1, ...]``. A chunk program reads and writes the rows
+    ``slots`` names (the last row is the trash row of padding jobs) and
+    runs the recurrence ``BLOCK`` positions a step (``_blocks``); a decode
+    tick's row ``i`` IS slot ``i`` (the engine's tick has a lane a slot),
+    so the tick updates the leaves where they lie, once read and once
+    written, and a lane that is not live (``lengths`` 0: inactive, or in
+    mid-prefill) keeps what it held.
+    """
+
+    #: taps of each depthwise convolution (the published
+    #: ``short_conv_kernel_size``): the current token and three before it
+    TAPS = 4
+    #: the gate's lower bound (the published ``kda_lower_bound``): a
+    #: channel's decay a token lies in ``(exp(LOWER_BOUND), 1)``
+    LOWER_BOUND = -5.0
+    #: positions one step of the sequence recurrence takes (``_blocks``)
+    BLOCK = 16
+
+    config: TransformerConfig
+    deterministic: bool = True
+    decode: bool = False
+    prefill: bool = False
+
+    @nn.nowrap
+    def _blocks(self, s0, q, k, v, g, beta):
+        """The recurrence over a sequence, ``BLOCK`` positions a step, from
+        state ``s0`` [B, H, D, D]: ``q``, ``k``, ``v`` and the log decay
+        ``g`` are [B, L, H, D], ``beta`` [B, L, H], all float32. Returns
+        (the state after position L - 1, every position's ``o`` [B, L, H,
+        D]). The same function as a token at a time: inside a block, with
+        ``G_t`` the running sum of ``g`` and ``u_t = beta_t (v_t - k_t^T
+        Diag(alpha_t) S_{t-1})`` the row a token writes,
+
+            S_t = Diag(e^{G_t}) S_0 + sum_{s<=t} Diag(e^{G_t - G_s}) k_s u_s^T
+            u_t = beta_t (v_t - (k_t e^{G_t})^T S_0
+                          - sum_{s<t} (k_t e^{G_t - G_s} . k_s) u_s)
+
+        so the ``u`` of a block solve a unit lower-triangular system by
+        forward substitution, the state is read and written once a BLOCK
+        and the products with it run on the matrix unit at the highest
+        precision. Every decay is a ratio of a later to an earlier
+        position, at most 1: nothing overflows however strong the gate."""
+        b, l, h, d = q.shape
+        c = min(self.BLOCK, l)
+        n = -(-l // c)
+        lower = jnp.tril(jnp.ones((c, c), bool))
+        high = jax.lax.Precision.HIGHEST
+
+        def blocks(x):  # [B, L, ...] -> [n, B, c, ...], zeros behind L
+            x = jnp.pad(x, ((0, 0), (0, n * c - l)) + ((0, 0),) * (x.ndim - 2))
+            return jnp.moveaxis(x.reshape((b, n, c) + x.shape[2:]), 1, 0)
+
+        def step(s, xs):
+            q, k, v, g, beta = xs
+            run = jnp.cumsum(g, axis=1)  # G_t [B, c, H, D]
+            # e^{G_t - G_s} for s <= t, 0 above the diagonal [B, t, s, H, D]
+            decay = jnp.exp(jnp.where(
+                lower[None, :, :, None, None],
+                run[:, :, None] - run[:, None, :], -jnp.inf))
+            kk = jnp.sum(k[:, :, None] * k[:, None, :] * decay, axis=-1)
+            qk = jnp.sum(q[:, :, None] * k[:, None, :] * decay, axis=-1)
+            grown = jnp.exp(run)
+            rhs = beta[..., None] * (v - jnp.einsum(
+                "bthk,bhkv->bthv", k * grown, s, precision=high))
+            below = beta[:, :, None] * jnp.where(
+                jnp.tril(lower, -1)[None, :, :, None], kk, 0.0)
+            u = jnp.zeros_like(rhs)
+            for t in range(c):  # forward substitution, a row a step
+                u = u.at[:, t].set(rhs[:, t] - jnp.sum(
+                    below[:, t][..., None] * u, axis=1))
+            o = jnp.einsum("bthk,bhkv->bthv", q * grown, s, precision=high
+                           ) + jnp.sum(qk[..., None] * u[:, None], axis=2)
+            s = grown[:, -1][..., None] * s + jnp.einsum(
+                "bshk,bshv->bhkv", k * jnp.exp(run[:, -1:] - run), u,
+                precision=high)
+            return s, o
+
+        s1, o = jax.lax.scan(step, s0,
+                             tuple(blocks(x) for x in (q, k, v, g, beta)))
+        return s1, jnp.moveaxis(o, 0, 1).reshape(b, n * c, h, d)[:, :l]
+
+    @nn.compact
+    def __call__(self, x, position_offset, block_tables=None, slots=None,
+                 lengths=None):
+        cfg = self.config
+        b, l, e = x.shape
+        f32 = jnp.float32
+        h, d, taps = cfg.num_heads, cfg.head_width, self.TAPS
+        inner = h * d
+        cached = self.decode or self.prefill
+        paged = block_tables is not None
+        if paged and not cached:
+            raise ValueError(
+                "block_tables= is the paged SERVING cache layout; it "
+                "requires decode or prefill mode")
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            name=name)(x)
+
+        pre = dense(3 * inner, "qkv")
+        conv_w = self.param("conv_kernel", nn.initializers.normal(0.02),
+                            (taps, 3 * inner))
+        gate_f = dense(inner, "gate_f")
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (inner,))
+        a_log = self.param("A_log", nn.initializers.zeros, (h,))
+        beta = jax.nn.sigmoid(dense(h, "beta").astype(f32))  # [B, L, H]
+        gate_o = dense(inner, "gate_o")
+
+        # ---- the state and the convolution inputs this call starts from
+        pos = jnp.asarray(position_offset, jnp.int32)
+        state_var = conv_var = None
+        tick = paged and self.decode
+        if cached:
+            if paged:
+                if pos.ndim != 1 or lengths is None or (
+                        slots is None and not tick):
+                    raise ValueError(
+                        "paged KDAttention takes a [B] position_offset "
+                        "vector and lengths= (each row's real length), and "
+                        "a chunk program slots= (each row's slot)")
+                state_var = self.variable("cache", "state", _need_pool)
+                conv_var = self.variable("cache", "conv", _need_pool)
+                if tick and state_var.value.shape[0] != b + 1:
+                    raise ValueError(
+                        "a paged decode tick has a lane a slot: row i reads "
+                        f"and writes slot i's state, got {b} rows over "
+                        f"{state_var.value.shape[0] - 1} slots")
+                rows = slice(0, b) if tick else slots
+                held_s, held_c = state_var.value[rows], conv_var.value[rows]
+            else:
+                state_var = self.variable(
+                    "cache", "state", lambda: jnp.zeros((b, h, d, d), f32))
+                conv_var = self.variable(
+                    "cache", "conv",
+                    lambda: jnp.zeros((b, taps - 1, 3 * inner), cfg.dtype))
+                held_s, held_c = state_var.value, conv_var.value
+            starts = pos if pos.ndim == 1 else jnp.full((b,), pos)
+            fresh = starts == 0
+            s0 = jnp.where(fresh[:, None, None, None], 0.0, held_s)
+            c0 = jnp.where(fresh[:, None, None],
+                           jnp.zeros((), held_c.dtype), held_c)
+        else:
+            s0 = jnp.zeros((b, h, d, d), f32)
+            c0 = jnp.zeros((b, taps - 1, 3 * inner), cfg.dtype)
+        real = (jnp.full((b,), l, jnp.int32) if lengths is None
+                else lengths.astype(jnp.int32))
+
+        # ---- convolutions, norms, gates
+        window = jnp.concatenate([c0, pre], axis=1)
+        qkv = nn.silu(sum(conv_w[j].astype(f32)
+                          * window[:, j:j + l].astype(f32)
+                          for j in range(taps)))
+
+        def unit(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+
+        q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(b, l, h, d)
+                   for i in range(3))
+        q, k = unit(q) * d ** -0.5, unit(k)
+        rate = jnp.exp(a_log.astype(f32))[:, None]
+        g = self.LOWER_BOUND * jax.nn.sigmoid(  # log alpha, in (L, 0)
+            rate * (gate_f.astype(f32) + dt_bias.astype(f32)).reshape(
+                b, l, h, d))
+
+        def update(s, q_t, k_t, v_t, a_t, b_t):
+            """One token: ``s`` [B, H, D, D], the rest [B, H, D], beta
+            [B, H]. Float32 multiplies and sums on the vector unit, and
+            the OLD state read twice and the new one written once: what
+            the new state shows the query is what the decayed old one
+            shows it plus the written row's share, ``S'^T q = S^T (alpha *
+            q) + (v - seen) (beta k . q)``, so both reductions read ``s``
+            in one pass and nothing reads the state just written."""
+            seen = jnp.sum((k_t * a_t)[..., None] * s, axis=-2)
+            read = jnp.sum((q_t * a_t)[..., None] * s, axis=-2)
+            write = b_t[..., None] * k_t  # [B, H, D]: beta k
+            new = v_t - seen
+            s = a_t[..., None] * s + write[..., None] * new[..., None, :]
+            return s, read + new * jnp.sum(write * q_t, -1, keepdims=True)
+
+        if l == 1:
+            s1, o = update(s0, q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]),
+                           beta[:, 0])
+            o = o[:, None]
+        else:
+            # a padding position neither decays the state nor writes to it
+            valid = jnp.arange(l)[None, :] < real[:, None]
+            s1, o = self._blocks(
+                s0, q, k, v, jnp.where(valid[..., None, None], g, 0.0),
+                jnp.where(valid[..., None], beta, 0.0))
+
+        if state_var is not None:
+            # the last T - 1 inputs behind the row's last REAL position
+            at = real[:, None] + jnp.arange(taps - 1)[None, :]
+            c1 = jnp.take_along_axis(window, at[:, :, None], axis=1)
+            live = real > 0
+            s1 = jnp.where(live[:, None, None, None], s1, held_s)
+            c1 = jnp.where(live[:, None, None], c1.astype(held_c.dtype),
+                           held_c)
+            if paged:
+                state_var.value = state_var.value.at[rows].set(s1)
+                conv_var.value = conv_var.value.at[rows].set(c1)
+            else:
+                state_var.value, conv_var.value = s1, c1
+
+        o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm")(o)
+        o = (o.reshape(b, l, inner) * jax.nn.sigmoid(
+            gate_o.astype(f32))).astype(cfg.dtype)
+        out = nn.Dense(e, use_bias=False, dtype=cfg.dtype, name="proj")(o)
+        if cfg.dropout:
+            out = nn.Dropout(cfg.dropout,
+                             deterministic=self.deterministic)(out)
+        return out
+
+
+class MLAttention(nn.Module):
+    """Multi-head latent attention without query compression
+    (``attn_kind="mla"``; DeepSeek-V2, arXiv:2405.04434, as
+    ``perfbench/references/ling.py`` writes it down).
+
+    ``H`` heads of ``D`` unrotated and ``R`` rotated query dims; keys and
+    values come from ONE latent a token: ``[c~; k_r] = x W_kva``, ``c =
+    RMSNorm(c~)`` (``kv_lora_rank`` values), ``[k_nope_h; v_h] = c
+    W_kvb,h``; RoPE turns each head's ``R`` query dims and the one shared
+    ``k_r``; scores are scaled by ``(D + R) ** -0.5``; a scalar gate a head
+    (``sigmoid(x W_gh)``) scales the output before the projection.
+
+    Without a cache (and in ``generate``'s dense prefill) keys and values
+    are EXPANDED for every position. With a cache a token keeps one row
+    for all heads, ``[c; rotated k_r; zeros]`` of ``latent_row_width``
+    values (``cache/latent``), and attention runs FOLDED: ``q'_h = W_UK,h^T
+    q_nope_h`` scores against ``c``, the probabilities average ``c`` and
+    ``o_h = W_UV,h`` of that average. The row is its own key and its own
+    value, so the paged read (``ops.attention.paged_attention``) takes the
+    one pool leaf as both pools, one narrow head of ``latent_row_width``
+    read with ``H`` query rows a position; the first ``kv_lora_rank``
+    lanes of its output are kept. One function either way.
+    """
+
+    config: TransformerConfig
+    deterministic: bool = True
+    decode: bool = False
+    prefill: bool = False
+
+    @nn.compact
+    def __call__(self, x, position_offset, positions=None,
+                 block_tables=None):
+        cfg = self.config
+        b, l, e = x.shape
+        f32 = jnp.float32
+        h, d = cfg.num_heads, cfg.head_width
+        c, r, row = cfg.kv_lora_rank, cfg.qk_rope_head_dim, (
+            cfg.latent_row_width)
+        scale = (d + r) ** -0.5
+        if positions is None:
+            raise ValueError("MLAttention needs the resolved positions=")
+        rpos = positions[None] if positions.ndim == 1 else positions
+        cached = self.decode or self.prefill
+        paged = block_tables is not None
+        if paged and not cached:
+            raise ValueError(
+                "block_tables= is the paged SERVING cache layout; it "
+                "requires decode or prefill mode")
+
+        q = nn.DenseGeneral((h, d + r), use_bias=False, dtype=cfg.dtype,
+                            name="q")(x)
+        kva = nn.Dense(c + r, use_bias=False, dtype=cfg.dtype,
+                       name="kv_a")(x)
+        latent = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32,
+                            name="kv_a_norm")(kva[..., :c]).astype(cfg.dtype)
+        w_kvb = self.param("kv_b", nn.initializers.normal(0.02),
+                           (c, h, 2 * d)).astype(cfg.dtype)
+        gate = jax.nn.sigmoid(nn.Dense(h, use_bias=False, dtype=cfg.dtype,
+                                       name="gate")(x).astype(f32))
+        q_nope = q[..., :d]
+        q_rope = _rope_rotate(q[..., d:], rpos, cfg.rope_theta)
+        k_rope = _rope_rotate(kva[..., c:][:, :, None, :], rpos,
+                              cfg.rope_theta)  # [B, L, 1, R]
+
+        pos = jnp.asarray(position_offset, jnp.int32)
+        fold = paged or self.decode
+        if cached:
+            pad = jnp.zeros((b, l, row - c - r), cfg.dtype)
+            new_rows = jnp.concatenate([latent, k_rope[:, :, 0], pad], -1)
+            if paged:
+                var = self.variable("cache", "latent", _need_pool)
+            else:
+                var = self.variable(
+                    "cache", "latent",
+                    lambda: jnp.zeros((b, cfg.max_seq_len, 1, row),
+                                      cfg.dtype))
+        if fold:
+            # the query in the latent's own coordinates
+            q_lat = jnp.einsum("blhd,chd->blhc", q_nope, w_kvb[..., :d],
+                               preferred_element_type=f32).astype(cfg.dtype)
+            q_row = jnp.concatenate(
+                [q_lat, q_rope,
+                 jnp.zeros((b, l, h, row - c - r), cfg.dtype)], -1)
+        if paged:
+            from pytorch_distributed_tpu.ops.attention import paged_attention
+
+            if pos.ndim != 1:
+                raise ValueError(
+                    "paged mode takes a [B] position_offset vector (each "
+                    "request's write start), got a scalar")
+            pool = var.value
+            block_len = pool.shape[-2]
+            gather_impl = attention_ops.default_gather_impl(
+                l * h, attention_ops.dense_gather_bytes(
+                    b, block_tables.shape[1] * block_len, row))
+            p = pos[:, None] + jnp.arange(l)
+            blk = jnp.take_along_axis(block_tables, p // block_len, axis=1)
+            pool = pool.at[blk.reshape(-1), (p % block_len).reshape(-1)].set(
+                new_rows.astype(pool.dtype).reshape(b * l, row))
+            var.value = pool
+            o_lat = paged_attention(q_row, pool, pool, block_tables, p,
+                                    scale=scale, gather_impl=gather_impl)
+        elif self.decode:
+            assert l == 1, f"decode mode processes one token/step, got {l}"
+            starts = pos if pos.ndim == 1 else jnp.full((b,), pos)
+            var.value = var.value.at[jnp.arange(b), starts].set(
+                new_rows[:, 0][:, None])
+            rows = var.value[:, :, 0].astype(f32)  # [B, max_len, row]
+            s = jnp.einsum("bhc,bkc->bhk", q_row[:, 0].astype(f32) * scale,
+                           rows)
+            seen = (jnp.arange(cfg.max_seq_len)[None, None, :]
+                    <= starts[:, None, None])
+            pr = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+            o_lat = jnp.einsum("bhk,bkc->bhc", pr, rows)[:, None].astype(
+                cfg.dtype)
+        else:
+            if self.prefill:
+                var.value = jax.lax.dynamic_update_slice(
+                    var.value, new_rows[:, :, None], (0, pos, 0, 0))
+            kv = jnp.einsum("blc,chd->blhd", latent, w_kvb,
+                            preferred_element_type=f32).astype(cfg.dtype)
+            keys = jnp.concatenate(
+                [kv[..., :d], jnp.broadcast_to(k_rope, (b, l, h, r))], -1)
+            out = dense_attention(
+                jnp.concatenate([q_nope, q_rope], -1), keys, kv[..., d:],
+                causal=True, scale=scale, q_offset=position_offset,
+                k_offset=position_offset)
+        if fold:
+            out = jnp.einsum("blhc,chd->blhd", o_lat[..., :c],
+                             w_kvb[..., d:],
+                             preferred_element_type=f32).astype(cfg.dtype)
+        out = (out.astype(f32) * gate[..., None]).astype(cfg.dtype)
+        out = nn.DenseGeneral(e, axis=(-2, -1), use_bias=False,
+                              dtype=cfg.dtype, name="proj")(out)
+        if cfg.dropout:
+            out = nn.Dropout(cfg.dropout,
+                             deterministic=self.deterministic)(out)
+        return out
+
+
 class ResidualScale(nn.Module):
     """``(s_x * x + b_x) + (s_f * f + b_f)``: how a sublayer's output
     ``f`` joins the stream ``x`` under ``residual_scaling``; four learned
@@ -1030,6 +1577,9 @@ class Block(nn.Module):
     deterministic: bool = True
     decode: bool = False
     prefill: bool = False
+    #: this layer's attention where the stack mixes kinds
+    #: (``TransformerConfig.attn_kind_at``); None: the config's
+    attn_kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, position_offset, positions=None,
@@ -1037,11 +1587,12 @@ class Block(nn.Module):
                  lengths=None, router_state=None):
         """``slots`` and ``lengths`` ([B] int32) are the paged cache's
         operands for state that belongs to a request (``CCAttention``'s
-        tail) and for the rows an expert layer may route (``lengths``
-        real positions a row; None: all). A dropless expert block takes
-        the previous block's ``router_state`` and returns ``(x,
-        router_state)``; every other block returns ``x``."""
+        tail, ``KDAttention``'s state) and for the rows an expert layer may
+        route (``lengths`` real positions a row; None: all). A dropless
+        expert block takes the previous block's ``router_state`` and
+        returns ``(x, router_state)``; every other block returns ``x``."""
         cfg = self.config
+        attn_kind = self.attn_kind or cfg.attn_kind
 
         def joins(x, out, sublayer: int):
             """The stream after a sublayer's output has joined it."""
@@ -1054,9 +1605,15 @@ class Block(nn.Module):
         h = _norm(cfg, "ln1")(x)
         mode = dict(deterministic=self.deterministic, decode=self.decode,
                     prefill=self.prefill, name="attn")
-        if cfg.attn_kind == "cca":
+        if attn_kind == "cca":
             out = CCAttention(cfg, **mode)(
                 h, position_offset, positions, block_tables, slots, lengths)
+        elif attn_kind == "kda":
+            out = KDAttention(cfg, **mode)(
+                h, position_offset, block_tables, slots, lengths)
+        elif attn_kind == "mla":
+            out = MLAttention(cfg, **mode)(
+                h, position_offset, positions, block_tables)
         else:
             out = Attention(cfg, **mode)(
                 h, position_offset, positions, block_tables, pass_index)
@@ -1067,10 +1624,16 @@ class Block(nn.Module):
 
             live = (None if lengths is None else
                     jnp.arange(x.shape[1])[None, :] < lengths[:, None])
+            sigmoid = dict(
+                router="sigmoid", top_k=cfg.moe_top_k,
+                n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+                routed_scale=cfg.moe_routed_scale,
+                shared_dim=cfg.moe_shared_dim, held=cfg.experts_held,
+            ) if cfg.moe_router == "sigmoid" else {}
             out, router_state = DroplessMoE(
                 n_experts=cfg.n_experts, moe_dim=cfg.moe_dim,
                 router_dim=cfg.router_dim, norm_eps=cfg.norm_eps,
-                dtype=cfg.dtype, name="moe",
+                dtype=cfg.dtype, name="moe", **sigmoid,
             )(h, router_state, live)
             if cfg.dropout:
                 out = nn.Dropout(cfg.dropout,
@@ -1235,8 +1798,8 @@ class TransformerLM(nn.Module):
                 Block(
                     cfg, deterministic=deterministic, decode=decode,
                     prefill=prefill, name=f"block{i}",
-                    use_moe=bool(cfg.n_experts)
-                    and i % cfg.moe_every == cfg.moe_every - 1,
+                    use_moe=cfg.moe_at(i),
+                    attn_kind=cfg.attn_kind_at(i),
                 )
                 for i in range(cfg.num_layers)
             ], _norm(cfg, "ln_f")
@@ -1247,7 +1810,7 @@ class TransformerLM(nn.Module):
             for block in blocks:
                 x = block(x, position_offset, pos, block_tables, t, slots,
                           lengths, router_state)
-                if cfg.moe_kind == "dropless":
+                if isinstance(x, tuple):  # a dropless expert block
                     x, router_state = x
             return ln_f(x)
 

@@ -180,16 +180,31 @@ GATHER_IMPLS = ("dense", "pallas")
 #: positions); its chunk-row numbers are in PERF.md section 6, for the
 #: issue that may move this.
 KERNEL_MAX_ROWS = 8
+#: most bytes the dense gather may write for a program to take it whatever
+#: its rows: the gather copies every row's whole table into HBM as float32
+#: (``dense_gather_bytes``), and a tick of 256 lanes over 3,072 positions
+#: of one 640-lane latent row would write 2 GB of it a layer
+DENSE_GATHER_MAX_BYTES = 1 << 30
 
 
-def default_gather_impl(rows: int = 1) -> str:
+def dense_gather_bytes(rows: int, positions: int, row_width: int) -> int:
+    """Bytes the dense spelling's float32 copy of one pool takes: ``rows``
+    table rows of ``positions`` positions of ``row_width`` values."""
+    return 4 * rows * positions * row_width
+
+
+def default_gather_impl(rows: int = 1, dense_bytes: int = 0) -> str:
     """The paged read a program compiles when nobody names one, from what
     the code can see: the fused kernel (``ops.paged_flash``) where the
     backend is a TPU and a narrow head reads with at most
-    ``KERNEL_MAX_ROWS`` query rows (a decode tick), the dense gather for
-    wider row blocks (chunked prefill) and on every other backend, where
-    the kernel would run in the Pallas interpreter."""
-    if jax.default_backend() == "tpu" and rows <= KERNEL_MAX_ROWS:
+    ``KERNEL_MAX_ROWS`` query rows (a decode tick), or where the dense
+    gather would write more than ``DENSE_GATHER_MAX_BYTES`` (a caller that
+    knows its table says so: latent attention's tick reads one narrow head
+    with every query head's row); the dense gather for wider row blocks
+    (chunked prefill) and on every other backend, where the kernel would
+    run in the Pallas interpreter."""
+    if jax.default_backend() == "tpu" and (
+            rows <= KERNEL_MAX_ROWS or dense_bytes > DENSE_GATHER_MAX_BYTES):
         return "pallas"
     return "dense"
 

@@ -198,20 +198,22 @@ class PagedEngine:
                 "replica placement), not both"
             )
         self.config = config
-        # State that belongs to a request and not to a block (a tail a
-        # layer: kv_pool.SLOT_LEAF) and expert layers that must not route
-        # padding: the programs of such a config take each row's slot and
-        # real length, and return the tokens every expert took.
+        # State that belongs to a request and not to a block (a tail or a
+        # recurrent state a layer: kv_pool.SLOT_LEAVES) and expert layers
+        # that must not route padding: the programs of such a config take
+        # each row's slot and real length, and return the pairs every
+        # expert took.
         self._per_request = bool(
-            config.cca_tail_width
+            config.slot_state
             or (config.n_experts and config.moe_kind == "dropless"))
-        if prefix_cache and config.cca_tail_width:
+        if prefix_cache and config.slot_state:
             raise ValueError(
-                "prefix_cache=True with attn_kind='cca': a request that "
-                "starts behind a shared prefix needs the tail its "
-                "convolutions left at the prefix's last token, and the "
-                "index keeps no such snapshot (ROADMAP B-mech 6); serve "
-                "this config with prefix_cache=False")
+                f"prefix_cache=True with attention {config.attn_kinds}: a "
+                "request that starts behind a shared prefix needs the "
+                "state its layers held at the prefix's last token (a "
+                "convolution tail, a recurrent state), and the index keeps "
+                "no such snapshot at block boundaries (ROADMAP B-mech 6); "
+                "serve this config with prefix_cache=False")
         self.n_slots = n_slots
         self.block_len = block_len
         self.chunk = prefill_chunk
@@ -265,18 +267,28 @@ class PagedEngine:
             # V + scale siblings). A looped config's cache layers (an
             # entry per pass and layer) outnumber its weight layers; the
             # leaves count them all.
-            pool_bytes, tail_bytes = cache_bytes(self.cache)
+            pool_bytes, slot_bytes = cache_bytes(self.cache)
             self._per_block_bytes = pool_bytes // n_blocks
             # and what ONE slot holds in the per-slot leaves
-            self._per_slot_bytes = tail_bytes // (n_slots + 1)
-            slot_leaves = sum(
-                is_slot_leaf(path) for path, _ in
-                jax.tree_util.tree_flatten_with_path(self.cache)[0])
-            cache_layers = config.num_layers * config.ut_steps
+            self._per_slot_bytes = slot_bytes // (n_slots + 1)
+            leaves = jax.tree_util.tree_flatten_with_path(self.cache)[0]
+            slot_leaves = sum(is_slot_leaf(path) for path, _ in leaves)
+            # the float32 recurrent states among them, and the rest
+            state_bytes = sum(
+                leaf.size * leaf.dtype.itemsize for path, leaf in leaves
+                if getattr(path[-1], "key", None) == "state")
+            #: bytes ONE slot holds in float32 recurrent state
+            self.slot_state_bytes = state_bytes // (n_slots + 1)
+            # layers that own a pool (a layer of per-slot state alone has
+            # none), and the cache layers they are (an entry a pass)
+            pool_layers = len({path[:-1] for path, _ in leaves
+                               if not is_slot_leaf(path)})
+            cache_layers = pool_layers * config.ut_steps
             # ``T``, the chain blocks a grid step of the tick's kernel
             # stages (``ops.paged_flash.tile_blocks``, from the bytes a
-            # position holds in one cache layer on one shard); 1 where
-            # the tick gathers dense
+            # position holds in one cache layer on one shard: a latent
+            # row is staged twice, as keys and as values); 1 where the
+            # tick gathers dense
             self.tile_blocks = 1
             if self.gather_impl == "pallas":
                 from pytorch_distributed_tpu.ops.paged_flash import (
@@ -285,7 +297,8 @@ class PagedEngine:
 
                 self.tile_blocks = tile_blocks(
                     self.table_width, block_len,
-                    self._per_block_bytes
+                    (2 if config.latent_row_width else 1)
+                    * self._per_block_bytes
                     // (cache_layers * block_len * config.tp_size))
             # ``read``: the paged read the programs compile;
             # ``table_blocks``: the blocks a decode tick's tables name,
@@ -293,16 +306,24 @@ class PagedEngine:
             # ``live_blocks`` is a share of; ``table_tiles``: the fused
             # kernel's grid steps a layer, ``tile_blocks`` entries each,
             # which ``live_tiles`` is a share of
+            # ``tail_bytes``: the per-slot leaves' bytes but for the
+            # float32 recurrent states, which are ``state_bytes``;
+            # ``latent_row_bytes``: a token's ONE row where a layer keeps a
+            # latent pool; ``pool_layers``: the layers that own a pool
             alloc.args.update(
                 weight_layers=config.num_layers,
                 cache_layers=cache_layers,
+                pool_layers=pool_layers,
+                state_bytes=state_bytes,
+                latent_row_bytes=config.latent_row_width
+                * jnp.dtype(config.dtype).itemsize,
                 block_bytes=self._per_block_bytes,
                 read=self.gather_impl,
                 table_blocks=n_slots * self.table_width,
                 tile_blocks=self.tile_blocks,
                 table_tiles=n_slots * -(-self.table_width
                                         // self.tile_blocks),
-                tail_bytes=tail_bytes,
+                tail_bytes=slot_bytes - state_bytes,
                 slot_state_leaves=slot_leaves,
             )
 
@@ -399,9 +420,15 @@ class PagedEngine:
         """The paged read the decode tick compiles: what
         ``ops.attention.default_gather_impl`` answers for a tick's rows
         (a chunk program asks with its own, wider, rows)."""
-        kv = self.config.num_kv_heads or self.config.num_heads
-        return attention_ops.default_gather_impl(
-            rows=self.config.num_heads // kv)
+        cfg = self.config
+        if cfg.latent_row_width:
+            # latent attention: every head's row reads the one narrow head
+            return attention_ops.default_gather_impl(
+                cfg.num_heads, attention_ops.dense_gather_bytes(
+                    self.n_slots, self.table_width * self.block_len,
+                    cfg.latent_row_width))
+        kv = cfg.num_kv_heads or cfg.num_heads
+        return attention_ops.default_gather_impl(rows=cfg.num_heads // kv)
 
     # ---- program builders (cached per static shape) ----
 
@@ -1491,10 +1518,14 @@ class PagedEngine:
             # live_blocks: the blocks up to each active lane's position,
             # the part of the tables' ``table_blocks`` a tick has to read;
             # live_tiles: the kernel's grid steps that hold one of them
+            # state_rows: the lanes whose recurrent state the tick reads
+            # and writes (0 where the cache holds none)
             live = positions[active] // self.block_len
+            lanes = int(np.count_nonzero(active))
             with spans.tracer().span(
                     "engine.decode.launch",
-                    lanes=int(np.count_nonzero(active)),
+                    lanes=lanes,
+                    state_rows=lanes if self.slot_state_bytes else 0,
                     live_blocks=int(np.sum(live + 1)),
                     live_tiles=int(np.sum(
                         live // self.tile_blocks + 1))), \

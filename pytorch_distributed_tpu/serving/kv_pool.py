@@ -12,6 +12,19 @@ blocks and writes ONLY the new prompt's KV (O(prompt)), never touching
 resident requests' blocks, where the dense layout wrote a full
 ``max_seq_len`` row per admission (O(per-slot cache)).
 
+A layer's cache need not be a K and a V pool. Latent attention keeps ONE
+pool leaf, ``latent`` ``[n_blocks, block_len, row]``: a token's one row,
+read as keys and as values. And a layer may keep state that is a
+REQUEST's and not a block's (``SLOT_LEAVES``): per-slot leaves
+``[n_slots + 1, ...]``, each of a dtype and trailing shape of its own —
+the previous token's latents of a convolution tail (``tail``, a few KB in
+the model's dtype), or a linear-attention layer's recurrent state
+(``state``, float32, ``[H, D, D]``: megabytes a request) and its
+convolutions' last inputs (``conv``). A layer of the second kind has no
+pool at all. Whatever moves a request (export, import, swap, the host
+tier) moves its blocks of every pool leaf and its row of every per-slot
+leaf together (``map_cache``).
+
 Block 0 is the TRASH block: never allocated, it absorbs the scatter
 writes of inactive decode lanes (the engine zeroes retired slots' table
 rows) so a recycled block can never be corrupted by a dead lane's
@@ -152,16 +165,18 @@ def _dense_to_pool(shape, n_blocks: int, block_len: int, **kw):
                            **kw)
 
 
-#: name of a cache leaf that belongs to a REQUEST and not to a block: one
-#: row a slot, ``[n_slots + 1, width]`` (``models.transformer.CCAttention``:
-#: the previous token's latents). The last row is the TRASH row, the slot
-#: that padding jobs and inactive lanes are given.
-SLOT_LEAF = "tail"
+#: names of the cache leaves that belong to a REQUEST and not to a block:
+#: one row a slot, ``[n_slots + 1, ...]`` in a dtype and trailing shape of
+#: the leaf's own. ``tail``: ``models.transformer.CCAttention``'s previous
+#: token's latents; ``state`` and ``conv``: ``KDAttention``'s float32
+#: recurrent state and its convolutions' last inputs. The last row is the
+#: TRASH row, the slot that padding jobs and inactive lanes are given.
+SLOT_LEAVES = ("tail", "state", "conv")
 
 
 def is_slot_leaf(path) -> bool:
     """Whether a cache leaf's tree path names per-slot state."""
-    return getattr(path[-1], "key", None) == SLOT_LEAF
+    return getattr(path[-1], "key", None) in SLOT_LEAVES
 
 
 def map_cache(on_blocks, on_slots, cache, *rest):
@@ -505,16 +520,20 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
                      n_slots: Optional[int] = None):
     """Zero block-pooled KV cache for ``TransformerLM(config)``.
 
-    A config whose attention keeps state a REQUEST (``cca_tail_width`` >
-    0) gets one more leaf a layer beside its pools, ``tail``
-    ``[n_slots + 1, width]`` in the model's dtype (``SLOT_LEAF``; the last
-    row is the trash row), and so needs ``n_slots``.
+    A config whose attention keeps state a REQUEST (``config.slot_state``)
+    gets per-slot leaves (``SLOT_LEAVES``) beside or instead of a layer's
+    pools, each ``[n_slots + 1, ...]`` in the dtype and trailing shape the
+    dense cache gives it at batch 1 (a convolution tail in the model's
+    dtype, a float32 recurrent state; the last row is the trash row), and
+    so needs ``n_slots``.
 
     Shapes come from ``eval_shape`` on the dense decode cache at batch 1
     (nothing is traced into a compiled program), then every
     ``[1, max_seq_len, H_kv, D]`` leaf is re-shaped into a
     ``[n_blocks, block_len, H_kv·D]`` pool (``pool_leaf_shape``; a looped
-    config's ``[passes, 1, ...]`` leaf into ``[n_blocks, passes, ...]``) — the
+    config's ``[passes, 1, ...]`` leaf into ``[n_blocks, passes, ...]``; latent
+    attention's one ``[1, max_seq_len, 1, row]`` leaf into ``[n_blocks,
+    block_len, row]``) — the
     per-layer head count and dtype (GQA narrows H_kv; TP shards the
     flattened head axis by placement, ``H_kv/tp·D`` contiguous lanes a
     shard) carry over, so the pool works for every config the dense
@@ -545,12 +564,18 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
     shapes = jax.eval_shape(
         lambda p: init_cache(config, p, 1), params
     )
-    if getattr(config, "cca_tail_width", 0) and (
+    if getattr(config, "slot_state", False) and (
             n_slots is None or kv_dtype is not None):
         raise ValueError(
-            "this config keeps a tail a request beside its K/V blocks: "
-            "init_paged_cache needs n_slots=, and a quantized pool "
-            f"(kv_dtype {kv_dtype!r}) is not supported with it")
+            "this config keeps state a request beside its blocks (a "
+            "convolution tail, a recurrent state): init_paged_cache needs "
+            f"n_slots=, and a quantized pool (kv_dtype {kv_dtype!r}) is not "
+            "supported with it")
+    if getattr(config, "latent_row_width", 0) and kv_dtype is not None:
+        raise ValueError(
+            "latent attention's pool is one row a token, its own key and "
+            f"value: a quantized pool (kv_dtype {kv_dtype!r}) has scale "
+            "siblings a K/V head, which that row has not")
     if kv_dtype is None:
         return map_cache(
             lambda s: jnp.zeros(
@@ -592,10 +617,11 @@ def init_paged_cache(config, params, n_blocks: int, block_len: int,
 
 def pool_block_bytes(config, params, block_len: int,
                      kv_dtype: Optional[str] = None) -> int:
-    """HBM bytes ONE pool block costs across every layer (K + V + any
-    scale siblings) — the unit a fixed byte budget is divided by to
-    compare pool dtypes' capacity. Pure ``eval_shape`` arithmetic;
-    nothing is allocated."""
+    """HBM bytes ONE pool block costs across every layer that owns a pool
+    (K + V + any scale siblings, or latent attention's one row; a layer
+    whose cache is per-slot state alone adds nothing) — the unit a fixed
+    byte budget is divided by to compare pool dtypes' capacity. Pure
+    ``eval_shape`` arithmetic; nothing is allocated."""
     shapes = jax.eval_shape(
         lambda p: init_paged_cache(config, p, 2, block_len,
                                    kv_dtype=kv_dtype, n_slots=1),
@@ -606,8 +632,9 @@ def pool_block_bytes(config, params, block_len: int,
 
 def pool_slot_bytes(config, params) -> int:
     """HBM bytes ONE slot costs across every layer in state that belongs
-    to a request and not to a block (``SLOT_LEAF``): ``pool_block_bytes``'
-    sibling. 0 for a config whose cache is block chains only."""
+    to a request and not to a block (``SLOT_LEAVES``, each in its own
+    dtype): ``pool_block_bytes``' sibling. 0 for a config whose cache is
+    block chains only."""
     shapes = jax.eval_shape(
         lambda p: init_paged_cache(config, p, 2, 1, n_slots=1), params)
     return cache_bytes(shapes)[1] // 2

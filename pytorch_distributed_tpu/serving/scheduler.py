@@ -1333,13 +1333,17 @@ class Scheduler:
             counts = self.engine.tick_expert_counts
             if counts is not None and counts.size:
                 # what the tick's expert layers took (fetched with its
-                # tokens): the fullest expert's tokens and the experts
-                # with a token, each a mean over the layers, and the live
-                # lanes a layer routed
+                # tokens): the fullest expert's pairs and the experts
+                # with a pair, each a mean over the layers; the (lane,
+                # expert) pairs that landed on an expert held here in the
+                # first expert layer (every live lane where all are held
+                # and a lane takes one), and the pairs a layer routed in
+                # all: live lanes x experts a token
                 process.args.update(
                     expert_tokens_peak=float(counts.max(axis=1).mean()),
                     experts_hit=float((counts > 0).sum(axis=1).mean()),
                     routed=int(counts[0].sum()),
+                    pairs=len(h.lanes) * self.engine.config.moe_top_k,
                 )
             self._process_collected(h, tokens, now, out)
         self._collected.extend(out)

@@ -45,7 +45,8 @@ def steer_paged_read(monkeypatch):
     def steer(rule):
         monkeypatch.setattr(
             attention, "default_gather_impl",
-            (lambda rows=1: rule) if isinstance(rule, str) else rule)
+            (lambda rows=1, dense_bytes=0: rule) if isinstance(rule, str)
+            else rule)
 
     return steer
 

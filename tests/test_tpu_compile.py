@@ -584,3 +584,106 @@ def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
         r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
         if math.prod(map(int, m.group(2).split(","))) == leaf.size]
     assert not moved, moved
+
+
+# ---- the ling stack's served programs (PR 36) ------------------------------
+
+#: ling-3.0-flash.doc-reason-backlog's attention, experts, state, pool and
+#: slots (perfbench/configs, perfbench/cells), on a period of 2 layers (one
+#: linear-attention layer with a dense MLP, one latent layer with experts)
+#: and a small vocabulary
+LING_CELL = dict(slots=256, blocks=65537, block_len=16, chunk=128,
+                 max_seq_len=3072)
+LING_BLOCK = dict(
+    embed_dim=2560, num_heads=32, head_dim=128, attn_kind="kda",
+    layer_group_size=2, kv_lora_rank=512, qk_rope_head_dim=64,
+    pos_embedding="rope", rope_theta=6e6,
+    norm="rmsnorm", norm_eps=1e-6, use_bias=False, mlp="swiglu", mlp_dim=6144,
+    n_experts=512, moe_every=1, moe_kind="dropless", moe_router="sigmoid",
+    moe_top_k=8, moe_n_group=8, moe_topk_group=4, moe_routed_scale=2.5,
+    moe_dim=768, moe_shared_dim=768, experts_held=(0, 128),
+    first_k_dense_replace=1)
+
+
+@pytest.mark.parametrize("program", ["decode_tick",
+                                     "chunk_prefill[k=4,w=128]"])
+def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
+    """The tick reads the latent pool through the fused kernel, the one
+    640-lane leaf as keys and as values with 32 query rows on its one
+    narrow head (the rule answers the kernel because the dense gather would
+    write 2 GB), and no float32 array of [lanes, table positions, ...]
+    exists; the chunk program gathers dense over its own table slice. Both
+    run the held experts as two grouped products, update the float32 state
+    where it lies (no copy of a state leaf) and move no pool-sized array."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = LING_CELL
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=2, max_seq_len=c["max_seq_len"],
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense", **LING_BLOCK)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    n = c["slots"]
+    eng = PagedEngine(cfg, params, n, n_blocks=2, block_len=c["block_len"],
+                      prefill_chunk=c["chunk"], chunk_bucket_floor=(2, 128),
+                      max_chunk_jobs=4)
+    assert eng.gather_impl == "pallas" and eng.tile_blocks == 8
+    pool = jax.eval_shape(
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
+                                   n_slots=n), params)
+    state = pool["block0"]["attn"]["state"]
+    latent = pool["block1"]["attn"]["latent"]
+    assert state.shape == (n + 1, 32, 128, 128) and state.dtype == jnp.float32
+    assert latent.shape == (c["blocks"], c["block_len"], 640)
+    one = SingleDeviceSharding(v5e.devices[0])
+    i32 = jnp.int32
+    if program == "decode_tick":
+        fn = eng._decode()
+        args = (params, pool, eng.logits, jnp.zeros((n,), i32),
+                jnp.zeros((n,), bool), jnp.zeros((n, eng.table_width), i32),
+                jax.random.key(0))
+    else:
+        k, w = 4, 128
+        fn = eng._chunk_fn(k, w)
+        assert eng.chunk_program_name(k, w) == program
+        args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
+                jnp.zeros((k,), i32), jnp.zeros((k, w), i32),
+                jnp.zeros((k,), i32), jnp.zeros((k,), bool),
+                jnp.zeros((k,), i32), jnp.zeros((k,), i32))
+    compiled = fn.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        args)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reads = [x for x in calls if "paged_decode_attn" in x]
+    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
+    assert len(reads) == (1 if program == "decode_tick" else 0), calls
+    assert len(grouped) == 2, calls  # gate and up side by side, and down
+    # the counts come back beside what the programs returned before: one
+    # expert layer, the experts held
+    shapes = [tuple(s.shape) for s in jax.tree.leaves(
+        jax.eval_shape(fn, *args))]
+    assert shapes[-1] == (1, 128)
+    # neither a state leaf nor the latent pool is copied or transposed
+    moved = [m.group(1) for m in re.finditer(
+        r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
+        if math.prod(map(int, m.group(2).split(","))) in (state.size,
+                                                          latent.size)]
+    assert not moved, moved
+    # the tick gathers no lane's table: nothing of [lanes, positions, ...]
+    rows = c["max_seq_len"]
+    assert not re.search(rf"f32\[{n},(?:{rows}|{rows // 16},16),", text)
+    # and what it holds beside its arguments is small: the state is
+    # donated and updated in place
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state.size * 4 + latent.size * 2
+    assert memory.temp_size_in_bytes < 1 << 30
