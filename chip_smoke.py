@@ -397,7 +397,8 @@ def server_phase() -> None:
         if default == "pallas":
             texts = engine_texts(engine)
         first_ok = all(f[0] == w[0] for f, w in zip(other, want))
-        rec.update(default_read=default,
+        rec.update(default_read=default, tile_blocks=engine.tile_blocks,
+                   heads_folded=engine.heads_folded,
                    pallas_agreement=round(rate, 4),
                    pallas_stream_agreement=round(agreement(want, other), 4),
                    pallas_first_tokens_agree=first_ok,
@@ -549,6 +550,8 @@ def ouro_phase() -> None:
         after_tick = np.asarray(eng.logits)
         rec["programs"] = eng.compiled_program_names()
         rec["pool_leaf"] = list(jax.tree.leaves(eng.cache)[0].shape)
+        rec.update(read=eng.gather_impl, tile_blocks=eng.tile_blocks,
+                   heads_folded=eng.heads_folded)
 
         # the reference's full forward over prompt + the decoded token:
         # row L-1 is what the chunk program left, row L what the tick did
@@ -616,7 +619,8 @@ def zaya_phase() -> None:
                    vocab=cfg.vocab_size, tail=cfg.cca_tail_width)
         eng = PagedEngine(cfg, params, slots, n_blocks=65, block_len=16,
                           prefill_chunk=chunk)
-        rec.update(read=eng.gather_impl, tile_blocks=eng.tile_blocks)
+        rec.update(read=eng.gather_impl, tile_blocks=eng.tile_blocks,
+                   heads_folded=eng.heads_folded)
         prompts = make_prompts(cfg, [chunk + chunk // 2 + 1, chunk - 3])
         for slot, prompt in enumerate(prompts):
             require(rec, eng.admit(slot, len(prompt), ticks), "admission")

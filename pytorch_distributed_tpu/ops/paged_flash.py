@@ -22,14 +22,42 @@ Structure (per the in-tree FlashAttention kernel,
   ``(B, S, ceil(ceil(W/T)/S))`` with the tile sweep innermost and
   sequential ("arbitrary" semantics — it carries the online-softmax
   recurrence); the running (m, l, acc) state lives in VMEM scratch,
-  persisting across the chain for each batch row, one slab per narrow
-  head. ``T`` follows from shapes in one place, ``tile_blocks``:
-  ``TILE_POSITIONS`` (128) positions' worth of blocks — a vector
-  register's lanes of logits, so a head's two matrix products and three
-  slab updates are paid once for 128 positions, not once a 16-position
-  block — never more than the table is wide, cut so that the staged tile
-  stays under ``TILE_VMEM_BYTES``; a pool of 128-position blocks gets 1.
-  Nothing can set it: no argument, config field, flag or variable;
+  persisting across the chain for each batch row. ``T`` follows from
+  shapes in one place, ``tile_blocks``: ``TILE_POSITIONS`` (128)
+  positions' worth of blocks — a vector register's lanes of logits, so
+  a tile's matrix products and state updates are paid once for 128
+  positions, not once a 16-position block — never more than the table
+  is wide, cut so that the staged tile stays under ``TILE_VMEM_BYTES``;
+  a pool of 128-position blocks gets 1. Nothing can set it: no
+  argument, config field, flag or variable;
+- a live tile's heads are attended IN ONE PASS where there are several
+  (``heads_folded``, the one rule, from the narrow heads, the query rows
+  each brings and the lane tile: ``h_kv > 1 and h_kv * rows <= 128``;
+  nothing can set it either). The staged tile is already
+  ``[T·block_len, H_kv·D]``, every head's lanes side by side, so it is
+  the STREAMED operand of one product against the lane's block-diagonal
+  query (``_attend_tile_folded``): ``S^T = K · Qbd -> [128, N]`` float32,
+  column ``n = r·H_kv + h`` holding head ``h``'s logits for its query
+  row ``r`` — the same bfloat16 products summed in float32, the zeros
+  adding nothing — 128 rows through the MXU a weight tile where a
+  head's ``[8, D] x [D, 128]`` product streamed 8 (one real). Positions
+  on the sublanes, (row, head) columns on the lanes: one frontier mask,
+  one running max, sum and correction for all heads (a ``[128, N]``
+  update and ``[1, N]`` state), ``P^T`` (the pool's dtype) against the
+  whole V tile into one ``[N, H_kv·D]`` float32 accumulator, whose
+  diagonal blocks are taken once a lane, at finalize (``_fold_out``):
+  the output leaves lane-dense, ``[rows, H_kv·D]``. The block-diagonal
+  query is built outside the kernel (``[B, N, H_kv·D]``, 32 KB a lane at
+  16 heads of 64) and padded to 128 columns in VMEM once a lane. Where
+  a static loop ran 16 dependent chains a tile (two 8-row products, a
+  softmax over one vector register and three slab updates a head: 2.65
+  us a live tile at 16 heads whatever the bytes), the folded tile costs
+  0.88 us at 16 heads of 64 and 1.17 at 16 of 128 on a v5e, about what
+  its bytes take to arrive (PERF.md section 6, PR 37). One narrow head
+  (latent attention's tick: 32 rows over one 640-lane row) has no loop
+  to remove and its rows already fill the streamed side: it keeps the
+  loop's body (``_attend_tile``), as do rows too many for the lane tile
+  (a chunk's);
 - the tile's blocks reach VMEM by the kernel's own DMAs
   (``pltpu.make_async_copy``, the design of JAX's ``paged_attention``):
   two ``[T, block_len, H_kv·D]`` buffers a pool, the next live tile's
@@ -50,15 +78,16 @@ Structure (per the in-tree FlashAttention kernel,
 - a copied K/V block is the whole ``[block_len, H_kv·D]`` pool block
   (Mosaic's tiling rule accepts no narrower one at H_kv=12, D=64; the
   interpreter does not check it — every shape was refused on the chip
-  until PR 21) and a static loop over narrow heads takes each head's D
-  lanes of the tile, ``k_buf[:, :, h·D:(h+1)·D]``, folded to
-  ``[T·block_len, D]``; a quantized pool's ``[block_len, H_kv]`` scale
+  until PR 21); the folded body reads the tile whole and the loop's
+  takes each head's D lanes of it, ``k_buf[:, :, h·D:(h+1)·D]``, folded
+  to ``[T·block_len, D]``; a quantized pool's ``[block_len, H_kv]`` scale
   siblings are too narrow a DMA for Mosaic and ride the BlockSpec
   pipeline, ``T`` operands a sibling under the same clamp (a dead
   step's index repeats, and the pipeline copies nothing for an index
   that did not change); positions
-  ride as a ``[B, r_pad, 1]`` column and each row's query frontier as a
-  second scalar-prefetch operand;
+  ride as a ``[B, r_pad, 1]`` column (a ``[B, 1, 128]`` row of the
+  columns' positions for the folded body) and each row's query frontier
+  as a second scalar-prefetch operand;
 - GQA is folded into the row dimension: queries regroup to
   ``[B, H_kv, G·C, D]`` so each narrow head's whole query group shares
   one staged KV block — the widened K/V never exists, mirroring the
@@ -78,7 +107,9 @@ Structure (per the in-tree FlashAttention kernel,
   only in VMEM, a tile at a time — HBM holds 1-byte values + scales (the
   2D/(D+4) int8 / 2D/(D+1) fp8 pool-capacity win). fp8 scale siblings
   are int8 power-of-two exponents: the in-VMEM multiplier is ``2**e``
-  (exact), so the fp8 cast is the whole error budget;
+  (exact), so the fp8 cast is the whole error budget. The folded body
+  multiplies a head's COLUMNS of the float32 logits and of ``P`` by its
+  scales (one per (position, head)) instead of the K and V rows;
 - flash-decoding (round 20; FlashAttention-2's work partitioning,
   PAPERS.md §2, applied to decode): ``split_s`` > 1 splits the chain
   sweep across S grid workers, each owning ``ceil(ceil(W/T)/S)`` TILES
@@ -133,6 +164,9 @@ MAX_SPLIT = 8
 TILE_POSITIONS = 128
 #: most VMEM the staged tile may take (``tile_blocks``)
 TILE_VMEM_BYTES = 4 << 20
+#: the lane tile: the (query row, head) columns one folded product's
+#: logits hold (``heads_folded``)
+FOLD_COLUMNS = 128
 
 
 def device_cores() -> int:
@@ -172,6 +206,19 @@ def tile_blocks(w: int, block_len: int, row_bytes: int) -> int:
     return max(1, min(t, TILE_VMEM_BYTES // (2 * block_len * row_bytes)))
 
 
+def heads_folded(h_kv: int, rows: int) -> int:
+    """The narrow heads ONE product of a tile serves — the ONE place the
+    kernel's body is decided, from shapes alone: all ``h_kv`` of them
+    (the folded body, ``_attend_tile_folded``) where there are several
+    and their ``rows`` query rows each fit the lane tile's
+    ``FOLD_COLUMNS`` columns side by side; 1 (the body that loops over
+    heads, ``_attend_tile``) where there is one narrow head, whose rows
+    already fill the streamed side of its products and which has no loop
+    to remove (latent attention's tick: 32 rows over one 640-lane row),
+    or where the columns would not fit (a chunk's rows)."""
+    return h_kv if h_kv > 1 and h_kv * rows <= FOLD_COLUMNS else 1
+
+
 def tile_entry(tables, front, b, entry, *, block_len: int):
     """The pool block a tile's slab ``entry`` (a chain index of lane
     ``b``) is copied from — the fused gather: the block table entry names
@@ -195,6 +242,20 @@ def staged_row_bytes(*pools) -> int:
                if x is not None)
 
 
+def _rows(x):  # [T, block_len, n] -> [T·block_len, n]
+    return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
+
+
+def _tile_scales(refs, fp8_scales):
+    """The per-(slot, head) scale siblings of a tile's ``T`` blocks,
+    staged by the table-driven index maps, as float32 multipliers
+    ``[T·block_len, H_kv]``: a quantized pool dequantizes THIS tile only,
+    in VMEM. fp8 pools carry int8 exponents — multiplier 2**e, exact in
+    fp32 (kv_pool.scale_factors spelling)."""
+    scales = jnp.concatenate([r[0].astype(jnp.float32) for r in refs], 0)
+    return jnp.exp2(scales) if fp8_scales else scales
+
+
 def _attend_tile(q_ref, qpos, k_buf, v_buf, ks_refs, vs_refs,
                  m_scr, l_scr, acc_scr, *, scale, k_start, h_kv, d,
                  quantized, fp8_scales):
@@ -207,20 +268,9 @@ def _attend_tile(q_ref, qpos, k_buf, v_buf, ks_refs, vs_refs,
     them onto the sublanes, so ONE ``[R, D] x [D, T·block_len]`` product,
     one softmax update and one ``[R, T·block_len] x [T·block_len, D]``
     product serve the tile."""
-    def rows(x):  # [T, block_len, n] -> [T·block_len, n]
-        return x.reshape(x.shape[0] * x.shape[1], x.shape[2])
-
     if quantized:
-        # dequantize THIS tile only, in VMEM: the per-(slot, head) scale
-        # siblings of its T blocks, staged by the table-driven index
-        # maps. fp8 pools carry int8 exponents — multiplier 2**e, exact
-        # in fp32 (kv_pool.scale_factors spelling).
-        ks_all, vs_all = (
-            jnp.concatenate([r[0].astype(jnp.float32) for r in refs], 0)
-            for refs in (ks_refs, vs_refs))  # [T·block_len, H_kv]
-        if fp8_scales:
-            ks_all = jnp.exp2(ks_all)
-            vs_all = jnp.exp2(vs_all)
+        ks_all, vs_all = (_tile_scales(refs, fp8_scales)
+                          for refs in (ks_refs, vs_refs))
     for h in range(h_kv):
         q = q_ref[0, h]  # [R, D]
         lanes = slice(h * d, (h + 1) * d)
@@ -228,7 +278,7 @@ def _attend_tile(q_ref, qpos, k_buf, v_buf, ks_refs, vs_refs,
         v = v_buf[:, :, lanes]
         if quantized:  # 1-byte rows widen before they fold (8-row tiles)
             k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        k, v = rows(k), rows(v)  # [T·block_len, D]
+        k, v = _rows(k), _rows(v)  # [T·block_len, D]
         if quantized:
             k = k * ks_all[:, h:h + 1]
             v = v * vs_all[:, h:h + 1]
@@ -268,6 +318,107 @@ def _attend_tile(q_ref, qpos, k_buf, v_buf, ks_refs, vs_refs,
         m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
 
+def _column(row, n):
+    """``[1, N] -> [n, 1]``: a per-column statistic's first ``n`` lanes
+    laid down the sublanes (the accumulator's rows are the logits'
+    columns), by a select against the diagonal and a lane sum."""
+    shape = (n, row.shape[1])
+    diag = (jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            == jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    return jnp.sum(jnp.where(diag, row, 0.0), axis=1, keepdims=True)
+
+
+def _head_columns(scales, n_cols, h_kv):
+    """``[P, H_kv] -> [P, n_cols]``: narrow head ``n % H_kv``'s scale in
+    column ``n`` (the folded body's column order), one select a head."""
+    head = jax.lax.broadcasted_iota(
+        jnp.int32, (scales.shape[0], n_cols), 1) % h_kv
+    out = jnp.zeros(head.shape, jnp.float32)
+    for h in range(h_kv):
+        out = jnp.where(head == h, scales[:, h:h + 1], out)
+    return out
+
+
+def _attend_tile_folded(qbd_ref, qpos, k_buf, v_buf, ks_refs, vs_refs,
+                        m_scr, l_scr, acc_scr, *, scale, k_start, h_kv,
+                        quantized, fp8_scales):
+    """One tile's online-softmax update for EVERY narrow head in one pass
+    (``heads_folded`` > 1). ``qbd_ref`` holds the lane's block-diagonal
+    query, transposed: row ``n = r·H_kv + h`` is query row ``r`` of narrow
+    head ``h`` in lanes ``[h·D, (h+1)·D)`` and zeros elsewhere, ``N``
+    rows padded to the lane tile. The tile ``[P, H_kv·D]`` (``P`` =
+    ``T·block_len`` positions) is the STREAMED operand of ONE product
+    ``S^T = K · Qbd -> [P, N]`` float32: column ``n`` is head ``h``'s
+    logits for its row ``r`` (the same bfloat16 products summed in
+    float32; the zeros add nothing), where the head loop ran ``H_kv``
+    products of 8 streamed rows each. Positions lie on the sublanes and
+    (row, head) columns on the lanes, so the frontier mask, the running
+    max, the sum and the correction are ONE ``[P, N]`` update and a
+    ``[1, N]`` state. ``P^T`` (in the pool's dtype) against the whole V
+    tile gives ``[N, H_kv·D]``: row ``n``'s lanes ``[h·D, (h+1)·D)`` are
+    head ``h``'s output and the rest is waste the MXU does not feel at
+    so few rows; the float32 accumulator keeps that shape, and the
+    diagonal blocks are taken once a lane (``_fold_out``). A quantized
+    pool's scales, one per (position, head), multiply the logits' and
+    ``P``'s columns in float32 instead of the K and V rows."""
+    k, v = _rows(k_buf[...]), _rows(v_buf[...])  # [P, H_kv·D]
+    qbd = qbd_ref[...]  # [N, H_kv·D]
+    if quantized:
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        qbd = qbd.astype(jnp.float32)
+    # fp32 logits on the MXU from the operands as stored, then the
+    # softmax scale on the logits in fp32 (see ``_attend_tile``)
+    s = jax.lax.dot_general(
+        k, qbd, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [P, N]
+    if quantized:
+        s = s * _head_columns(_tile_scales(ks_refs, fp8_scales),
+                              s.shape[1], h_kv)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    # the frontier mask of ``_attend_tile``, a column a query row:
+    # padding columns (qpos == -1) mask everything -> l stays 0
+    mask = k_pos <= qpos
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=0, keepdims=True)
+    m_scr[...] = m_new
+    if quantized:
+        p = p * _head_columns(_tile_scales(vs_refs, fp8_scales),
+                              p.shape[1], h_kv)
+    # P's live columns ride the MXU in the pool's dtype (fp32 operands
+    # double a decode step on a v5e, PERF.md section 6, PR 28),
+    # contracted with V over the positions of both: P^T · V
+    n_acc = acc_scr.shape[0]
+    acc_scr[...] = acc_scr[...] * _column(corr, n_acc) + jax.lax.dot_general(
+        p[:, :n_acc].astype(v.dtype), v, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _fold_out(acc, h_kv, d, rows, r_pad):
+    """The diagonal blocks of the folded accumulator, once a lane:
+    ``[N, H_kv·D] -> [r_pad, H_kv·D]``, query row ``r``'s output for
+    narrow head ``h`` in lanes ``[h·D, (h+1)·D)`` — accumulator row
+    ``r·H_kv + h``'s own ``D`` lanes. Lane-dense: a sublane sum a query
+    row, no lane moves."""
+    n = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+    out_row = jax.lax.broadcasted_iota(jnp.int32, (r_pad, acc.shape[1]), 0)
+    out = jnp.zeros((r_pad, acc.shape[1]), jnp.float32)
+    for r in range(rows):
+        # lanes past row n's head: 0 <= off < D only on rows
+        # [r·H_kv, (r+1)·H_kv), each at its own head's lanes
+        off = lane - (n - r * h_kv) * d
+        own = jnp.sum(jnp.where((off >= 0) & (off < d), acc, 0.0), axis=0,
+                      keepdims=True)
+        out = jnp.where(out_row == r, own, out)
+    return out
+
+
 def _paged_kernel(
     tables_ref,  # scalar-prefetch [B, W] int32 (SMEM)
     front_ref,  # scalar-prefetch [B] int32: each row's query frontier
@@ -275,7 +426,7 @@ def _paged_kernel(
     *refs,  # T K-scale blocks and T V-scale blocks where quantized, ...
     scale: float, block_len: int, h_kv: int, d: int, quantized: bool,
     fp8_scales: bool, tile: int, n_tiles: int, wc: int, split: bool,
-    carry: bool,
+    carry: bool, fold_rows: int,
 ):
     """Grid ``(B, S, ceil(n_tiles/S))``: worker s sweeps tiles
     ``[s*wc, min((s+1)*wc, n_tiles))`` with its own (m, l, acc) state. The
@@ -294,13 +445,22 @@ def _paged_kernel(
     worker, grid steps in order on one core) a lane's last live tile
     starts the NEXT lane's first, so only the grid's very first copy is
     waited for in the open; without it each sweep starts its own first
-    tile."""
+    tile.
+
+    ``fold_rows`` > 0 (``heads_folded``: several narrow heads of that
+    many query rows each) takes the folded body: ``q_ref`` is then the
+    lane's block-diagonal query ``[N, H_kv·D]`` and ``qpos_ref`` a row of
+    its columns' positions, the state is ``[1, 128]`` rows and one
+    ``[N, H_kv·D]`` accumulator, and the output leaves lane-dense,
+    ``[r_pad, H_kv·D]``; 0 takes the body that loops over heads."""
     ks_refs = vs_refs = None
     if quantized:
         ks_refs, vs_refs, refs = refs[:tile], refs[tile:2 * tile], refs[
             2 * tile:]
     n_out = 3 if split else 1
     out_refs, refs = refs[:n_out], refs[n_out:]
+    if fold_rows:  # the query, padded to the lane tile's columns
+        qbd_scr, refs = refs[0], refs[1:]
     m_scr, l_scr, acc_scr, k_buf, v_buf, sem, cur = refs
     pools, bufs = (k_pool, v_pool), (k_buf, v_buf)
     b, jj = pl.program_id(0), pl.program_id(2)
@@ -330,6 +490,9 @@ def _paged_kernel(
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        if fold_rows:
+            qbd_scr[...] = jnp.zeros_like(qbd_scr)
+            qbd_scr[:q_ref.shape[1]] = q_ref[0]
 
     # the copy nobody started for us: the grid's first tile, or without
     # ``carry`` each sweep's (a worker's range may lie past the frontier)
@@ -359,23 +522,35 @@ def _paged_kernel(
 
         for copy in copies(b, j, slot, known=False):
             copy.wait()
-        _attend_tile(q_ref, qpos_ref[0], k_buf.at[slot], v_buf.at[slot],
-                     ks_refs, vs_refs, m_scr, l_scr, acc_scr, scale=scale,
-                     k_start=j * tile * block_len, h_kv=h_kv, d=d,
-                     quantized=quantized, fp8_scales=fp8_scales)
+        shared = dict(scale=scale, k_start=j * tile * block_len, h_kv=h_kv,
+                      quantized=quantized, fp8_scales=fp8_scales)
+        if fold_rows:
+            _attend_tile_folded(qbd_scr, qpos_ref[0], k_buf.at[slot],
+                                v_buf.at[slot], ks_refs, vs_refs, m_scr,
+                                l_scr, acc_scr, **shared)
+        else:
+            _attend_tile(q_ref, qpos_ref[0], k_buf.at[slot], v_buf.at[slot],
+                         ks_refs, vs_refs, m_scr, l_scr, acc_scr, d=d,
+                         **shared)
         cur[0] = 1 - slot
 
     @pl.when(jj == wc - 1)
     def _finalize():
+        acc = acc_scr[...]
+        if not split:  # normalize in place; workers leave that to the merge
+            l = jnp.maximum(l_scr[...], 1e-37)
+            acc = acc / (_column(l, acc.shape[0]) if fold_rows
+                         else l[:, :, :1])
+        if fold_rows:
+            acc = _fold_out(acc, h_kv, d, fold_rows, out_refs[0].shape[-2])
         if split:
             o_ref, m_ref, l_ref = out_refs
-            o_ref[0, 0] = acc_scr[...]
+            o_ref[0, 0] = acc
             m_ref[0, 0] = m_scr[...]
             l_ref[0, 0] = l_scr[...]
         else:
             (o_ref,) = out_refs
-            l = jnp.maximum(l_scr[:, :, :1], 1e-37)
-            o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+            o_ref[0] = acc.astype(o_ref.dtype)
 
 
 def paged_flash_attention(
@@ -428,7 +603,7 @@ def paged_flash_attention(
         interpret = jax.default_backend() != "tpu"
     if split_s is not None and split_s < 1:
         raise ValueError(f"split_s must be >= 1, got {split_s}")
-    block_len, _ = pool_heads(k_pool, q.shape[2], d)
+    block_len, h_kv = pool_heads(k_pool, q.shape[2], d)
     tile = tile_blocks(w, block_len, staged_row_bytes(
         k_pool, v_pool, k_scale, v_scale))
     # every worker owns >= 1 tile
@@ -443,6 +618,8 @@ def paged_flash_attention(
         q, k_pool, v_pool, block_tables, q_positions, k_scale, v_scale,
         scale=float(scale if scale is not None else d ** -0.5),
         s_workers=s_workers, tile=tile,
+        # every narrow head's rows in one product, or a head at a time
+        fold=heads_folded(h_kv, q.shape[2] // h_kv * q.shape[1]) > 1,
         # grid steps run in order where one core runs them all
         carry=s_workers == 1 and device_cores() == 1,
         interpret=bool(interpret),
@@ -450,10 +627,10 @@ def paged_flash_attention(
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "s_workers", "tile",
-                                             "carry", "interpret"))
+                                             "fold", "carry", "interpret"))
 def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
                  v_scale, *, scale: float, s_workers: int, tile: int,
-                 carry: bool, interpret: bool):
+                 fold: bool, carry: bool, interpret: bool):
     """``paged_flash_attention`` with everything static decided (a
     pool that does not fit its queries or scales raises while tracing)."""
     from pytorch_distributed_tpu.serving.kv_pool import is_quantized_pool
@@ -479,9 +656,7 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
     wc = -(-n_tiles // s_workers)  # tiles per worker (ceil split)
 
     # GQA fold: query head h = kv·group + g reads narrow head kv, so the
-    # per-narrow-head row block is its whole query group × chunk. Rows
-    # pad to a sublane multiple; padding rows carry position -1 (every
-    # key masked → zero rows, sliced away below).
+    # per-narrow-head row block is its whole query group × chunk.
     r = group * c
     r_pad = -(-r // 8) * 8
     q4 = jnp.moveaxis(q.reshape(b, c, h_kv, group, d), 1, 3)  # [B,Hkv,G,C,D]
@@ -490,13 +665,50 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
     qpos = jnp.broadcast_to(
         q_positions[:, None, :], (b, group, c)
     ).reshape(b, r)
-    if r_pad != r:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
-        qpos = jnp.pad(qpos, ((0, 0), (0, r_pad - r)), constant_values=-1)
+    if fold:
+        # The block-diagonal query, transposed: row n = r·H_kv + h holds
+        # head h's query row r in lanes [h·D, (h+1)·D), zeros elsewhere —
+        # the one product's other operand (``_attend_tile_folded``). Its
+        # N = H_kv·R rows pad to the packed sublane tile here and to the
+        # lane tile's columns in VMEM; a column's position rides beside
+        # it as a [B, 1, FOLD_COLUMNS] row, -1 (every key masked -> a
+        # zero column) on the padding.
+        n = h_kv * r
+        n_acc = -(-n // 16) * 16
+        own = jnp.eye(h_kv, dtype=bool)[None, None, :, :, None]
+        qbd = jnp.where(own, jnp.swapaxes(q4, 1, 2)[:, :, :, None, :], 0)
+        q_rows = jnp.pad(qbd.reshape(b, n, h_kv * d),
+                         ((0, 0), (0, n_acc - n), (0, 0)))
+        qpos = jnp.pad(jnp.repeat(qpos, h_kv, axis=1),
+                       ((0, 0), (0, FOLD_COLUMNS - n)),
+                       constant_values=-1)[:, None, :]
+        row_spec = pl.BlockSpec((1, n_acc, h_kv * d),
+                                lambda b, s, j, *_: (b, 0, 0))
+        pos_spec = pl.BlockSpec((1, 1, FOLD_COLUMNS),
+                                lambda b, s, j, *_: (b, 0, 0))
+        # the state: [1, N] rows, one [N, H_kv·D] accumulator; the output
+        # leaves lane-dense, [r_pad, H_kv·D], its diagonal blocks taken
+        stat_block, acc_block, out_block = (
+            (1, FOLD_COLUMNS), (n_acc, h_kv * d), (r_pad, h_kv * d))
+    else:
+        # Rows pad to a sublane multiple; padding rows carry position -1
+        # (every key masked → zero rows, sliced away below).
+        if r_pad != r:
+            q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, r_pad - r), (0, 0)))
+            qpos = jnp.pad(qpos, ((0, 0), (0, r_pad - r)),
+                           constant_values=-1)
+        q_rows, qpos = q4, qpos[:, :, None]
+        row_spec = pl.BlockSpec((1, h_kv, r_pad, d),
+                                lambda b, s, j, *_: (b, 0, 0, 0))
+        pos_spec = pl.BlockSpec((1, r_pad, 1), lambda b, s, j, *_: (b, 0, 0))
+        # a slab per narrow head
+        stat_block, acc_block, out_block = (
+            (h_kv, r_pad, 128), (h_kv, r_pad, d), (h_kv, r_pad, d))
 
     # Queries and positions ride the pipeline, a batch row a block (its
     # last two dims equal the array's: Mosaic's tiling rule, which the
-    # interpreter does not check; positions are a [B, r_pad, 1] column).
+    # interpreter does not check; positions are a [B, r_pad, 1] column,
+    # or a [B, 1, FOLD_COLUMNS] row beside a block-diagonal query).
     # The pools stay where they are: the kernel DMAs whole pool blocks,
     # [block_len, H_kv·D], into buffers of T blocks, two a pool. A scale
     # sibling's [block_len, H_kv] block is too narrow for such a DMA and
@@ -515,29 +727,28 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
             for t in range(tile)
         ]
 
-    row_spec = pl.BlockSpec((1, h_kv, r_pad, d),
-                            lambda b, s, j, *_: (b, 0, 0, 0))
     in_specs = [
-        row_spec,
-        pl.BlockSpec((1, r_pad, 1), lambda b, s, j, *_: (b, 0, 0)),
+        row_spec, pos_spec,
         pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [q4, qpos[:, :, None], k_pool, v_pool]
+    operands = [q_rows, qpos, k_pool, v_pool]
     if quantized:
         in_specs += staged(k_scale) + staged(v_scale)
         operands += [k_scale] * tile + [v_scale] * tile
     if split:
         # each worker's un-normalized (acc, m, l), merged below
-        parts = [(h_kv, r_pad, d), (h_kv, r_pad, 128), (h_kv, r_pad, 128)]
+        parts = [out_block, stat_block, stat_block]
         out_specs = [
-            pl.BlockSpec((1, 1) + p, lambda b, s, j, *_: (b, s, 0, 0, 0))
+            pl.BlockSpec((1, 1) + p, lambda b, s, j, *_, z=(0,) * len(p):
+                         (b, s) + z)
             for p in parts
         ]
         out_shape = [jax.ShapeDtypeStruct((b, s_workers) + p, jnp.float32)
                      for p in parts]
     else:
-        out_specs = row_spec
-        out_shape = jax.ShapeDtypeStruct((b, h_kv, r_pad, d), q.dtype)
+        out_specs = pl.BlockSpec((1,) + out_block, lambda b, s, j, *_,
+                                 z=(0,) * len(out_block): (b,) + z)
+        out_shape = jax.ShapeDtypeStruct((b,) + out_block, q.dtype)
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
@@ -548,6 +759,7 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
             _paged_kernel, scale=scale, block_len=block_len, h_kv=h_kv,
             d=d, quantized=bool(quantized), fp8_scales=fp8_scales,
             tile=tile, n_tiles=n_tiles, wc=wc, split=split, carry=carry,
+            fold_rows=r if fold else 0,
         ),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -555,10 +767,13 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
             grid=(b, s_workers, wc),
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((h_kv, r_pad, 128), jnp.float32),  # row max m
-                pltpu.VMEM((h_kv, r_pad, 128), jnp.float32),  # row sum l
-                pltpu.VMEM((h_kv, r_pad, d), jnp.float32),  # un-normalized
+            scratch_shapes=(
+                # the query's columns, padded to the lane tile
+                [pltpu.VMEM((FOLD_COLUMNS, h_kv * d), q.dtype)] if fold
+                else []) + [
+                pltpu.VMEM(stat_block, jnp.float32),  # running max m
+                pltpu.VMEM(stat_block, jnp.float32),  # running sum l
+                pltpu.VMEM(acc_block, jnp.float32),  # un-normalized
                 # a tile of K and of V, twice: [2, T, block_len, H_kv·D]
                 pltpu.VMEM((2, tile) + k_pool.shape[1:], k_pool.dtype),
                 pltpu.VMEM((2, tile) + v_pool.shape[1:], v_pool.dtype),
@@ -573,9 +788,7 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
       # a lane's first tile is always live (a row of padding alone,
       # position -1, still masks every key)
       jnp.maximum(jnp.max(q_positions, axis=1), 0), *operands)
-    if not split:
-        out4 = out
-    else:
+    if split:
         acc_p, m_p, l_p = out
         # Second stage: cross-worker log-sum-exp merge, fp32. A worker
         # whose every block was masked/skipped holds (m=NEG_INF, l=0,
@@ -583,17 +796,23 @@ def _paged_flash(q, k_pool, v_pool, block_tables, q_positions, k_scale,
         # worst and its zero l/acc contribute nothing — all-masked rows
         # (padding) keep the single-sweep convention l=0 → out 0 via the
         # epsilon.
-        m_w = m_p[..., 0]  # [B, S, H_kv, R] (broadcast columns, take one)
-        l_w = l_p[..., 0]
+        if fold:  # [B, S, 1, N] rows, a column a (row, head)
+            m_w, l_w = (x[:, :, 0, :n].reshape(b, s_workers, r, h_kv)
+                        for x in (m_p, l_p))
+            acc_p = acc_p[:, :, :r].reshape(b, s_workers, r, h_kv, d)
+        else:  # [B, S, H_kv, R] (broadcast columns, take one)
+            m_w, l_w = m_p[..., 0], l_p[..., 0]
         m_star = jnp.max(m_w, axis=1)
-        alpha = jnp.exp(m_w - m_star[:, None])  # [B, S, H_kv, R]
-        l_tot = jnp.sum(l_w * alpha, axis=1)  # [B, H_kv, R]
-        acc = jnp.sum(acc_p * alpha[..., None], axis=1)  # [B, H_kv, R, D]
-        out4 = (acc / jnp.maximum(l_tot, 1e-37)[..., None]).astype(q.dtype)
-    out4 = out4[:, :, :r]  # drop row padding
-    return jnp.moveaxis(
-        out4.reshape(b, h_kv, group, c, d), 3, 1
-    ).reshape(b, c, h, d)
+        alpha = jnp.exp(m_w - m_star[:, None])
+        l_tot = jnp.sum(l_w * alpha, axis=1)
+        acc = jnp.sum(acc_p * alpha[..., None], axis=1)
+        out = (acc / jnp.maximum(l_tot, 1e-37)[..., None]).astype(q.dtype)
+    # [B, R, H_kv, D] folded, [B, H_kv, r_pad, D] looped: drop the row
+    # padding, then rows (g, c) and heads back to [B, C, H, D]
+    out = (out[:, :r].reshape(b, group, c, h_kv, d).transpose(0, 2, 3, 1, 4)
+           if fold else
+           jnp.moveaxis(out[:, :, :r].reshape(b, h_kv, group, c, d), 3, 1))
+    return out.reshape(b, c, h, d)
 
 
 def paged_quantize_scatter(

@@ -290,8 +290,16 @@ class PagedEngine:
             # row is staged twice, as keys and as values); 1 where the
             # tick gathers dense
             self.tile_blocks = 1
+            # and the narrow heads ONE product of a tile serves
+            # (``ops.paged_flash.heads_folded``, from a shard's narrow
+            # heads and the query rows each brings to a tick: all of
+            # them where the kernel folds them, 1 where it loops over
+            # heads, as for a latent row's one head); 1 where the tick
+            # gathers dense
+            self.heads_folded = 1
             if self.gather_impl == "pallas":
                 from pytorch_distributed_tpu.ops.paged_flash import (
+                    heads_folded,
                     tile_blocks,
                 )
 
@@ -300,12 +308,17 @@ class PagedEngine:
                     (2 if config.latent_row_width else 1)
                     * self._per_block_bytes
                     // (cache_layers * block_len * config.tp_size))
+                kv = 1 if config.latent_row_width else (
+                    config.num_kv_heads or config.num_heads)
+                self.heads_folded = heads_folded(
+                    max(1, kv // config.tp_size), config.num_heads // kv)
             # ``read``: the paged read the programs compile;
             # ``table_blocks``: the blocks a decode tick's tables name,
             # live or not, which ``engine.decode.launch``'s
             # ``live_blocks`` is a share of; ``table_tiles``: the fused
             # kernel's grid steps a layer, ``tile_blocks`` entries each,
-            # which ``live_tiles`` is a share of
+            # which ``live_tiles`` is a share of; ``heads_folded``: the
+            # narrow heads one product of a tile serves
             # ``tail_bytes``: the per-slot leaves' bytes but for the
             # float32 recurrent states, which are ``state_bytes``;
             # ``latent_row_bytes``: a token's ONE row where a layer keeps a
@@ -321,6 +334,7 @@ class PagedEngine:
                 read=self.gather_impl,
                 table_blocks=n_slots * self.table_width,
                 tile_blocks=self.tile_blocks,
+                heads_folded=self.heads_folded,
                 table_tiles=n_slots * -(-self.table_width
                                         // self.tile_blocks),
                 tail_bytes=slot_bytes - state_bytes,

@@ -32,6 +32,7 @@ from pytorch_distributed_tpu.ops.attention import paged_attention
 from pytorch_distributed_tpu.ops.paged_flash import (
     auto_split_s,
     device_cores,
+    heads_folded,
     paged_flash_attention,
     paged_quantize_scatter,
     staged_row_bytes,
@@ -144,6 +145,27 @@ def test_staged_row_bytes_counts_scale_siblings():
                             scale.astype(jnp.int8)) == 2 * 1024 + 2 * 16
 
 
+@pytest.mark.parametrize("h_kv,rows,want", [
+    pytest.param(16, 1, 16, id="chat-backlog-16-heads-of-64"),
+    pytest.param(16, 1, 16, id="reason-backlog-16-heads-of-128"),
+    pytest.param(2, 4, 2, id="reason-long-backlog-2-heads-4-rows"),
+    pytest.param(1, 32, 1, id="doc-reason-backlog-one-latent-head"),
+    pytest.param(1, 1, 1, id="one-head-has-no-loop-to-remove"),
+    pytest.param(16, 8, 16, id="128-columns-fill-the-lane-tile"),
+    pytest.param(16, 9, 1, id="144-columns-do-not-fit"),
+    pytest.param(16, 32, 1, id="a-chunks-rows-do-not-fit"),
+])
+def test_heads_folded_follows_the_shapes(h_kv, rows, want):
+    """Which body a tile's heads take (``paged_flash.heads_folded``): one
+    product for all of a shard's narrow heads where there are several and
+    their query rows fit the lane tile's 128 columns side by side — the
+    ticks of gpt2-medium, ouro-2.6b and zaya1-8b — and the loop over
+    heads for ling-3.0-flash's one latent head and for rows too many."""
+    assert heads_folded(h_kv, rows) == want
+    assert want in (1, h_kv) and want * rows <= max(
+        paged_flash.FOLD_COLUMNS, rows)
+
+
 @pytest.mark.parametrize("tile", [1, 2, 4, 8])
 def test_a_dead_entry_repeats_the_lanes_last_live_block(tile):
     """Which pool block a tile's slab is copied from
@@ -183,13 +205,16 @@ def test_a_dead_entry_repeats_the_lanes_last_live_block(tile):
 @pytest.mark.parametrize("cores", [1, 2])
 @pytest.mark.parametrize("tile", [1, 2, 8])
 @pytest.mark.parametrize("h,h_kv,c", [(4, 4, 1), (4, 4, 5), (4, 2, 5),
-                                      (4, 2, 1), (16, 2, 1)])
+                                      (4, 2, 1), (16, 2, 1), (16, 16, 1),
+                                      (4, 1, 1), (4, 1, 5), (4, 2, 33)])
 def test_paged_flash_matches_dense_gather(tile_of, monkeypatch, h, h_kv, c,
                                           tile, cores):
     """Same pools, same tables, same positions: the pallas spelling must
     reproduce the dense spelling — decode (C=1) and chunk (C=5) rows,
     MHA and GQA groupings (up to the 8 query rows a narrow head brings to
-    a decode tick), ragged per-request frontiers; one block a grid step
+    a decode tick), ragged per-request frontiers; every narrow head in
+    one product (``heads_folded``) and, for one narrow head or 132
+    columns of rows, the loop over heads; one block a grid step
     (the parent's grid), tiles of two blocks (which do not divide the
     table's three: the second lane's frontier lies in the middle of its
     first tile, its only live one) and one tile that holds the table. On
@@ -197,9 +222,11 @@ def test_paged_flash_matches_dense_gather(tile_of, monkeypatch, h, h_kv, c,
     next lane's first; where a second core may take lanes of its own
     (``device_cores`` steered: the grid is then not one sequence) every
     lane starts its own."""
-    b, d, bl, w = 2, 8, 4, 3
+    b, d, bl, w = 2, 8, 4, 3 if c < 12 else 12
     tile_of(tile, bl)
     monkeypatch.setattr(paged_flash, "device_cores", lambda: cores)
+    assert (heads_folded(h_kv, h // h_kv * c) > 1) == (
+        h_kv > 1 and h * c <= 128)
     rng = np.random.default_rng(0)
     kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
     q = jnp.asarray(rng.normal(size=(b, c, h, d)).astype(np.float32))
@@ -216,34 +243,47 @@ def test_paged_flash_matches_dense_gather(tile_of, monkeypatch, h, h_kv, c,
     )
 
 
-@pytest.mark.parametrize("c,bl,w,tile", [
-    (1, 16, 6, 1), (1, 16, 6, 4), (1, 16, 6, None),
-    (32, 16, 6, 1), (32, 16, 6, 4), (32, 16, 6, None),
-    pytest.param(1, 128, 2, None, id="blocks-of-128"),
-])
+GPT2, OURO, ZAYA, LING = (16, 16, 64), (16, 16, 128), (8, 2, 128), (32, 1, 128)
+
+
+@pytest.mark.parametrize("c,bl,w,tile,heads", [
+    (1, 16, 6, 1, GPT2), (1, 16, 6, 4, GPT2), (1, 16, 6, None, GPT2),
+    (32, 16, 6, 1, GPT2), (32, 16, 6, 4, GPT2), (32, 16, 6, None, GPT2),
+    pytest.param(1, 128, 2, None, GPT2, id="blocks-of-128"),
+] + [pytest.param(1, 16, 6, tile, heads, id=f"{name}-tick-tile-{tile}")
+     for name, heads in (("ouro", OURO), ("zaya", ZAYA), ("ling", LING))
+     for tile in (1, 4, None)])
 def test_paged_flash_matches_dense_at_the_served_shapes(tile_of, c, bl, w,
-                                                        tile):
+                                                        tile, heads):
     """The read the serving cells compile on a TPU against the one they
-    compiled before, at gpt2-medium.chat-backlog's attention (16 heads of
-    64 over a bfloat16 pool, blocks of 16): a decode tick (C=1) and a
-    chunk (C=32), frontiers ragged across the rows, and every table
-    padded past its row's allocation with the trash block, which holds
-    garbage, and one lane inactive: every entry the trash block under a
-    stale position, as the engine masks a free slot. Both spellings
-    compute in float32 from the same stored bfloat16, so they part only
-    in the order of the sums: a bfloat16 ulp of the output at most. The
-    grid steps a block at a time (the parent's), four blocks at a time
-    (which do not divide the six) and as the rule ships it (128
-    positions: the whole table of six; one block where a block is 128)."""
-    b, h, d = 4, 16, 64
+    compiled before, at the four ticks' attention over a bfloat16 pool,
+    blocks of 16 — gpt2-medium.chat-backlog's 16 heads of 64,
+    ouro-2.6b's 16 of 128, zaya1-8b's 8 query heads over 2 narrow ones of
+    128 (4 rows a narrow head) and ling-3.0-flash's one narrow head under
+    32 query heads (its width cut from 640 lanes): the first three fold
+    every narrow head into one product, the last keeps the loop over
+    heads. A decode tick (C=1) and, at gpt2-medium's, a chunk (C=32:
+    512 columns, the loop), frontiers ragged across the rows, one lane at
+    position 0, and every table padded past its row's allocation with the
+    trash block, which holds garbage, and one lane inactive: every entry
+    the trash block under a stale position, as the engine masks a free
+    slot. Both spellings compute in float32 from the same stored
+    bfloat16, so they part only in the order of the sums: a bfloat16 ulp
+    of the output at most. The grid steps a block at a time (the
+    parent's), four blocks at a time (which do not divide the six) and as
+    the rule ships it (128 positions: the whole table of six; one block
+    where a block is 128)."""
+    b, (h, h_kv, d) = 5, heads
     tile_of(tile, bl)
-    assert tile_blocks(w, bl, 4 * h * d) == (
+    assert tile_blocks(w, bl, 4 * h_kv * d) == (
         tile or min(w, max(1, 128 // bl)))
+    assert heads_folded(h_kv, h // h_kv * c) == (
+        h_kv if heads != LING and c == 1 else 1)
     rng = np.random.default_rng(28)
-    kp, vp, tables, _ = random_pool(rng, b, h, d, bl, w)
+    kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
     kp = kp.at[0].set(37.0).astype(jnp.bfloat16)  # the trash block
     vp = vp.at[0].set(-53.0).astype(jnp.bfloat16)
-    ends = np.array([w * bl - 1, 41, 17 + c, 21 + c])  # last query's
+    ends = np.array([w * bl - 1, 41, 17 + c, 21 + c, c - 1])  # last query's
     live = ends // bl + 1  # blocks a row was allocated
     live[3] = 0  # the inactive lane
     tables = jnp.where(np.arange(w)[None, :] < live[:, None], tables, 0)
@@ -256,8 +296,43 @@ def test_paged_flash_matches_dense_at_the_served_shapes(tile_of, c, bl, w,
                              gather_impl="pallas")
     assert pallas.dtype == dense.dtype == jnp.bfloat16
     got, want = (np.asarray(x, np.float32) for x in (pallas, dense))
-    assert np.abs(want[:3]).max() < 10  # nothing of the trash block came in
+    live_lanes = [0, 1, 2, 4]
+    assert np.abs(want[live_lanes]).max() < 10  # no trash block came in
     np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
+    if c == 1:  # the lane at position 0 reads its one key's value
+        first = np.asarray(vp[tables[4, 0], 0], np.float32).reshape(h_kv, d)
+        np.testing.assert_allclose(
+            got[4, 0].reshape(h_kv, h // h_kv, d),
+            np.broadcast_to(first[:, None], (h_kv, h // h_kv, d)),
+            rtol=2 ** -7)
+
+
+@pytest.mark.parametrize("heads", [GPT2, ZAYA, LING, (6, 3, 8)])
+@pytest.mark.parametrize("split_s", [1, 2])
+def test_no_head_reads_anothers_lanes(tile_of, heads, split_s):
+    """Every narrow head's V a constant of its own: whatever the logits,
+    a softmax's weights sum to one, so each query head's output is its
+    narrow head's constant in every lane. A folded product computes every
+    (query row, head) pair's ``P·V`` over ALL heads' lanes and keeps the
+    diagonal blocks: a wrong diagonal, a column of another head's logits
+    or a statistic laid against the wrong accumulator row brings another
+    constant in. Through the single sweep and two workers' merge, at two
+    tiles a lane; three narrow heads of 8 lanes fill no lane tile."""
+    (h, h_kv, d), b, bl, w = heads, 3, 16, 4
+    tile_of(2, bl)
+    rng = np.random.default_rng(37)
+    kp, _, tables, _ = random_pool(rng, b, h_kv, d, bl, w)
+    consts = np.arange(1, h_kv + 1, dtype=np.float32) * 3.0
+    vp = jnp.broadcast_to(jnp.asarray(np.repeat(consts, d)),
+                          kp.shape).astype(jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.bfloat16)
+    pos = jnp.asarray([[w * bl - 1], [0], [bl + 3]], jnp.int32)
+    got = np.asarray(paged_flash_attention(
+        q, kp.astype(jnp.bfloat16), vp, tables, pos, split_s=split_s),
+        np.float32)
+    want = np.broadcast_to(np.repeat(consts, h // h_kv)[:, None], (h, d))
+    np.testing.assert_allclose(got, np.broadcast_to(want, got.shape),
+                               rtol=2 ** -7)
 
 
 @pytest.mark.parametrize("tile", [2, None])
@@ -294,14 +369,19 @@ def test_the_grouped_fold_at_two_narrow_heads_matches_dense(tile_of, tile):
 
 @pytest.mark.parametrize("tile", [1, 2, 8])
 @pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
-@pytest.mark.parametrize("c", [1, 5])
-def test_paged_flash_int8_matches_dense_int8(tile_of, c, kv_dtype, tile):
+@pytest.mark.parametrize("c,h,h_kv", [(1, 4, 2), (5, 4, 2), (1, 16, 16),
+                                      (1, 6, 3), (1, 4, 1)])
+def test_paged_flash_int8_matches_dense_int8(tile_of, c, h, h_kv, kv_dtype,
+                                             tile):
     """Both spellings dequantize the SAME stored rows, so on a quantized
     pool (int8 under float32 multipliers, fp8 under int8 exponents) they
     must agree to fp tolerance (the quantization error itself is shared,
     not a divergence between them); the scale siblings ride a tile the
-    way their pools do."""
-    b, h, h_kv, d, bl, w = 2, 4, 2, 8, 4, 3
+    way their pools do. Where the heads fold into one product a head's
+    scales multiply its COLUMNS of the logits and of ``P`` in float32
+    (2, 16 and 3 narrow heads: column ``n`` is head ``n % H_kv``'s); one
+    narrow head dequantizes its rows in the loop, as before."""
+    b, d, bl, w = 2, 8, 4, 3
     tile_of(tile, bl)
     rng = np.random.default_rng(1)
     kq, vq, tables, scales = random_pool(rng, b, h_kv, d, bl, w,
@@ -574,13 +654,14 @@ def test_quantize_scatter_rejects_raw_pools():
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("h_kv", [2, 1])
 @pytest.mark.parametrize("split_s,c,tile", [
     (2, 1, 1), (8, 5, 1), (3, 1, 1), (2, 5, 1),
     (2, 1, 2), (8, 1, 2), (8, 5, 5), (2, 5, 5), (3, 1, None),
     pytest.param(8, 1, 1, marks=pytest.mark.slow),
     pytest.param(3, 5, 1, marks=pytest.mark.slow),
 ])
-def test_split_s_matches_single_worker(tile_of, split_s, c, tile):
+def test_split_s_matches_single_worker(tile_of, split_s, c, tile, h_kv):
     """The combine algebra under test: S workers' un-normalized
     (m, l, acc) partials merged by fp32 log-sum-exp must reproduce the
     single-worker sweep to <= 1e-3 (documented bound; measured ~1e-7 —
@@ -590,8 +671,11 @@ def test_split_s_matches_single_worker(tile_of, split_s, c, tile):
     workers empty. Workers own ranges of TILES: six tiles of two blocks
     under 2 and 8 workers (eight become six), three tiles of five (the
     last holds two blocks) whose ceil split leaves a tail, and the
-    shipped rule's one tile, which one worker takes whatever was asked."""
-    b, h, h_kv, d, bl, w = 2, 4, 2, 16, 4, 12
+    shipped rule's one tile, which one worker takes whatever was asked.
+    Two narrow heads fold (the workers' partials are then the
+    accumulator's diagonal blocks, un-normalized, and ``[1, N]`` rows of
+    statistics); one keeps the loop over heads."""
+    b, h, d, bl, w = 2, 4, 16, 4, 12
     tile_of(tile, bl)
     rng = np.random.default_rng(8)
     kp, vp, tables, _ = random_pool(rng, b, h_kv, d, bl, w)

@@ -285,9 +285,12 @@ def test_the_read_and_its_live_share_ride_the_spans(
     of the tick's kernel stages (``tile_blocks``: ``ops.paged_flash.
     tile_blocks``' answer, 1 where the read is dense) and so how many
     grid steps a layer takes (``table_tiles``), beside the ``blocks`` it
-    had; each ``engine.decode.launch`` says how many blocks hold a live
-    position (``live_blocks``, beside ``lanes``) and how many tiles do
-    (``live_tiles``)."""
+    had, and how many narrow heads one product of a tile serves
+    (``heads_folded``: ``ops.paged_flash.heads_folded``' answer for the
+    tick's heads, both of the toy model's where the kernel folds
+    them, 1 where the read is dense); each ``engine.decode.launch`` says
+    how many blocks hold a live position (``live_blocks``, beside
+    ``lanes``) and how many tiles do (``live_tiles``)."""
     from pytorch_distributed_tpu.ops import paged_flash
 
     steer_paged_read(read)
@@ -300,6 +303,9 @@ def test_the_read_and_its_live_share_ride_the_spans(
     assert alloc.args["read"] == engine.gather_impl == read
     assert alloc.args["table_blocks"] == 4 * (64 // 8) == engine.tables.size
     assert alloc.args["tile_blocks"] == engine.tile_blocks == tile
+    assert alloc.args["heads_folded"] == engine.heads_folded == (
+        cfg.num_heads if read == "pallas" else 1)
+    assert cfg.num_heads == 2
     assert alloc.args["table_tiles"] == 4 * (8 // tile)
     assert alloc.args["blocks"] == engine.allocator.n_blocks
     router.submit(np.arange(1, 21, dtype=np.int32), 6)  # 20 tokens

@@ -276,6 +276,13 @@ def _engine_program(v5e, program, cell=None, kv_dtype=None, bucket=(4, 8),
     return fn.lower(*jax.tree.map(on_chip, args)), jax.tree.leaves(pool)
 
 
+def _kernel_reads(text):
+    """The compiled program's ``paged_decode_attn`` calls, a line each."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and "paged_decode_attn" in line.split(" = ")[0]]
+
+
 @pytest.mark.parametrize("program", ["decode_tick", "chunk_prefill[k=4,w=8]"])
 def test_pool_leaves_stay_row_major_and_uncopied(v5e, program):
     """The layout's guard without a chip. A ``[n_blocks, block_len,
@@ -450,8 +457,10 @@ def test_the_tick_compiles_with_a_tile_of_blocks_a_grid_step(
     kernel's DMAs of whole pool blocks at rows of 1,024 and of 2,048
     lanes, bfloat16 and int8 (whose scale siblings, 16 lanes wide, ride
     the pipeline: a DMA of a block that narrow is refused), the program is
-    still ``jit_decode_tick`` and the kernel in it ``paged_decode_attn``."""
+    still ``jit_decode_tick`` and the kernel in it ``paged_decode_attn``,
+    one a layer, its 16 heads folded into one product a tile."""
     from pytorch_distributed_tpu.ops.paged_flash import (
+        heads_folded,
         staged_row_bytes,
         tile_blocks,
     )
@@ -467,10 +476,16 @@ def test_the_tick_compiles_with_a_tile_of_blocks_a_grid_step(
     assert lowered.as_text().startswith("module @jit_decode_tick ")
     text = lowered.compile().as_text()
     assert text.startswith("HloModule jit_decode_tick,")
-    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    reads = [x for x in calls if "paged_decode_attn" in x]
-    assert len(reads) == 2, calls  # one a layer
+    reads = _kernel_reads(text)
+    assert len(reads) == 2, text  # one a layer
+    # every narrow head of a tile in ONE product (``heads_folded``): the
+    # kernel's query operand is the lane's block-diagonal one, 16 (row,
+    # head) columns of all heads' lanes, and its output lane-dense
+    assert heads_folded(c["heads"], 1) == c["heads"] == 16
+    slots, lanes = c["slots"], c["heads"] * c["head_dim"]
+    assert all(f"bf16[{slots},16,{lanes}]" in x
+               and f"bf16[{slots},8,{lanes}]" in x.split(" custom-call(")[0]
+               for x in reads), reads
 
 
 #: sha256 (12 hex digits) of the StableHLO text of chunk programs as the
@@ -575,6 +590,10 @@ def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
     grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
     assert len(reads) == (2 if program == "decode_tick" else 0), calls
     assert len(grouped) == 4, calls  # gate and up side by side, and down
+    # the tick's two narrow heads fold into one product a tile: the
+    # kernel's query is block-diagonal, 2 x 4 (row, head) columns (padded
+    # to 16) of both heads' 256 lanes
+    assert all("bf16[128,16,256]" in x for x in _kernel_reads(text))
     # the counts come back beside what the programs returned before
     shapes = [tuple(s.shape) for s in jax.tree.leaves(
         jax.eval_shape(fn, *args))]
@@ -668,6 +687,9 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
     grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
     assert len(grouped) == 2, calls  # gate and up side by side, and down
+    # the latent row's ONE narrow head keeps the loop's body
+    # (``heads_folded``): its query goes in a slab a head, 32 rows of 640
+    assert all("bf16[256,1,32,640]" in x for x in _kernel_reads(text))
     # the counts come back beside what the programs returned before: one
     # expert layer, the experts held
     shapes = [tuple(s.shape) for s in jax.tree.leaves(
