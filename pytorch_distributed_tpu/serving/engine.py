@@ -73,6 +73,12 @@ def _pow2_bucket(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
+def _put_args(host: tuple) -> dict:
+    """``engine.*.put``'s arguments: how many host arrays the one transfer
+    moves and their bytes (latency or bandwidth)."""
+    return {"arrays": len(host), "bytes": sum(a.nbytes for a in host)}
+
+
 def _expert_counts(stats, num_layers: int):
     """``[expert layers, n_experts]`` int32 from what the expert layers of
     one ``apply`` sowed (``moe_stats/expert_tokens``), in layer order."""
@@ -428,6 +434,11 @@ class PagedEngine:
                 self.params = jax.device_put(self.params, device)
             self.cache = jax.device_put(self.cache, device)
             self.logits = jax.device_put(self.logits, device)
+        # what every program's call flattens beside its own operands; the
+        # trees keep their structure, so it is counted here and not a tick
+        # (``engine.*.call``'s ``leaves``)
+        self._resident_leaves = len(jax.tree.leaves(
+            (self.params, self.cache, self.logits)))
 
     @property
     def gather_impl(self) -> str:
@@ -1464,44 +1475,50 @@ class PagedEngine:
                     f"chunk job for slot {j.slot} has {len(j.tokens)} "
                     f"tokens; engine chunk length is {c}"
                 )
-        k_pad, wp = self.bucket_for(jobs)
-        tokens = np.zeros((k_pad, c), np.int32)
-        starts = np.zeros((k_pad,), np.int32)
-        tables = np.full((k_pad, wp), TRASH_BLOCK, np.int32)
-        # padding jobs scatter to slot n_slots — out of bounds, dropped
-        slots = np.full((k_pad,), self.n_slots, np.int32)
-        is_last = np.zeros((k_pad,), bool)
-        last_idx = np.zeros((k_pad,), np.int32)
-        # a job's real positions in this chunk: all of it, or up to the
-        # prompt's last token; a padding job has none
-        lengths = np.zeros((k_pad,), np.int32)
-        for i, j in enumerate(jobs):
-            tokens[i] = j.tokens
-            starts[i] = j.start
-            tables[i] = self.tables[j.slot, :wp]
-            slots[i] = j.slot
-            is_last[i] = j.is_last
-            last_idx[i] = j.last_idx
-            lengths[i] = j.last_idx + 1 if j.is_last else c
-        host = (tokens, starts, tables, slots, is_last, last_idx)
-        if self._per_request:
-            host += (lengths,)
-        fn = self._chunk_fn(k_pad, wp)
+        tr = spans.tracer()
+        with tr.span("engine.chunk.build"):
+            k_pad, wp = self.bucket_for(jobs)
+            tokens = np.zeros((k_pad, c), np.int32)
+            starts = np.zeros((k_pad,), np.int32)
+            tables = np.full((k_pad, wp), TRASH_BLOCK, np.int32)
+            # padding jobs scatter to slot n_slots — out of bounds, dropped
+            slots = np.full((k_pad,), self.n_slots, np.int32)
+            is_last = np.zeros((k_pad,), bool)
+            last_idx = np.zeros((k_pad,), np.int32)
+            # a job's real positions in this chunk: all of it, or up to the
+            # prompt's last token; a padding job has none
+            lengths = np.zeros((k_pad,), np.int32)
+            for i, j in enumerate(jobs):
+                tokens[i] = j.tokens
+                starts[i] = j.start
+                tables[i] = self.tables[j.slot, :wp]
+                slots[i] = j.slot
+                is_last[i] = j.is_last
+                last_idx[i] = j.last_idx
+                lengths[i] = j.last_idx + 1 if j.is_last else c
+            host = (tokens, starts, tables, slots, is_last, last_idx)
+            if self._per_request:
+                host += (lengths,)
+            fn = self._chunk_fn(k_pad, wp)
+            name = self.chunk_program_name(k_pad, wp)
+            put_args = _put_args(host)
         # no fence handle: both outputs are donated into later programs,
         # so completion rides the t1 lower bound tightened by the next
         # sync launch on this replica stream (the decode tick).
-        name = self.chunk_program_name(k_pad, wp)
-        with spans.tracer().span("engine.chunk.launch", jobs=len(jobs),
-                                 bucket=(k_pad, wp)), \
+        with tr.span("engine.chunk.launch", jobs=len(jobs),
+                     bucket=(k_pad, wp)), \
                 program_load_if((k_pad, wp) not in self._hot_chunks, name), \
                 self.ledger.launch(self.ledger_replica, name):
             # ONE batched explicit transfer for the six host-built
             # operands, inside the launch window (dispatch cost; see
             # the decode call's note on the per-operand asarray tax)
-            operands = jax.device_put(host)
-            self.cache, self.logits, *counts = fn(
-                self.params, self.cache, self.logits, *operands,
-            )
+            with tr.span("engine.chunk.put", **put_args):
+                operands = jax.device_put(host)
+            with tr.span("engine.chunk.call",
+                         leaves=self._resident_leaves + len(host)):
+                self.cache, self.logits, *counts = fn(
+                    self.params, self.cache, self.logits, *operands,
+                )
         if counts:
             self.chunk_expert_counts = counts[0]
         self._hot_chunks.add((k_pad, wp))
@@ -1513,13 +1530,29 @@ class PagedEngine:
         contract); ``sync=False`` returns device arrays plus the launch
         token so the caller can pin completion at its own collect site
         (``DispatchLedger.complete``)."""
-        masked = np.where(active[:, None], self.tables, TRASH_BLOCK)
-        positions = np.asarray(positions, np.int32)
-        fn = self._decode()
-        if self.device is not None:
-            # keys are computed arrays; pin them next to the replica's
-            # committed working set so the program has one placement
-            rng = jax.device_put(rng, self.device)
+        tr = spans.tracer()
+        with tr.span("engine.decode.build"):
+            masked = np.where(active[:, None], self.tables, TRASH_BLOCK)
+            positions = np.asarray(positions, np.int32)
+            fn = self._decode()
+            if self.device is not None:
+                # keys are computed arrays; pin them next to the replica's
+                # committed working set so the program has one placement
+                rng = jax.device_put(rng, self.device)
+            # live_blocks: the blocks up to each active lane's position,
+            # the part of the tables' ``table_blocks`` a tick has to read;
+            # live_tiles: the kernel's grid steps that hold one of them
+            # state_rows: the lanes whose recurrent state the tick reads
+            # and writes (0 where the cache holds none)
+            live = positions[active] // self.block_len
+            lanes = int(np.count_nonzero(active))
+            launch_args = dict(
+                lanes=lanes,
+                state_rows=lanes if self.slot_state_bytes else 0,
+                live_blocks=int(np.sum(live + 1)),
+                live_tiles=int(np.sum(live // self.tile_blocks + 1)))
+            host = (positions, active, masked)
+            put_args = _put_args(host)
         with self.ledger.launch(self.ledger_replica, self.DECODE_PROGRAM,
                                 sync=sync) as lt:
             # ONE batched explicit transfer for the host-built
@@ -1529,29 +1562,18 @@ class PagedEngine:
             # loop's host wall, round-16 profile), and a bare-np jit
             # call would be an IMPLICIT transfer the no_recompile guard
             # rightly rejects.
-            # live_blocks: the blocks up to each active lane's position,
-            # the part of the tables' ``table_blocks`` a tick has to read;
-            # live_tiles: the kernel's grid steps that hold one of them
-            # state_rows: the lanes whose recurrent state the tick reads
-            # and writes (0 where the cache holds none)
-            live = positions[active] // self.block_len
-            lanes = int(np.count_nonzero(active))
-            with spans.tracer().span(
-                    "engine.decode.launch",
-                    lanes=lanes,
-                    state_rows=lanes if self.slot_state_bytes else 0,
-                    live_blocks=int(np.sum(live + 1)),
-                    live_tiles=int(np.sum(
-                        live // self.tile_blocks + 1))), \
+            with tr.span("engine.decode.launch", **launch_args), \
                     program_load_if(not self._hot_decode,
                                     self.DECODE_PROGRAM):
-                positions, active, masked = jax.device_put(
-                    (positions, active, masked)
-                )
-                self.cache, self.logits, positions, tokens, *counts = fn(
-                    self.params, self.cache, self.logits,
-                    positions, active, masked, rng,
-                )
+                with tr.span("engine.decode.put", **put_args):
+                    positions, active, masked = jax.device_put(host)
+                # and the key: the call flattens it with the operands
+                with tr.span("engine.decode.call",
+                             leaves=self._resident_leaves + len(host) + 1):
+                    self.cache, self.logits, positions, tokens, *counts = fn(
+                        self.params, self.cache, self.logits,
+                        positions, active, masked, rng,
+                    )
                 self._tick_counts = counts[0] if counts else None
             if sync:
                 # the token fetch inside the window materializes the

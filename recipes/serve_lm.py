@@ -22,7 +22,8 @@ retirement (queue wait, TTFT, inter-token gaps) plus a final
 feed it to ``scripts/telemetry_report.py`` for TTFT/per-token p50/p95;
 ``--trace-dir DIR`` says where the process's span stream (always
 recorded: ``telemetry.spans``) is written at exit, as a Chrome trace,
-``DIR/spans.trace.json``.
+``DIR/spans.trace.json``; each program's launch lies there split into
+``engine.{chunk,decode}.build``, ``.put`` and ``.call``, no profiler needed.
 
 Elastic load (round 9; ANALYSIS.md "Elastic topology & reshard"):
 ``--restore CKPT`` serves a TRAINER checkpoint — sharded directory or

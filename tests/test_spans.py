@@ -27,7 +27,15 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: it collects the tick the last step launched, then dispatches the next
 TICK_ORDER = ["engine.collect.wait", "sched.collect.process",
               "sched.expire", "sched.admit", "sched.chunk_plan",
-              "engine.chunk.launch", "engine.decode.launch"]
+              "engine.chunk.build", "engine.chunk.launch",
+              "engine.decode.build", "engine.decode.launch"]
+
+#: what a launch span holds, in order: the operands' one transfer, then the
+#: program's call (PR 38)
+LAUNCH_CHILDREN = {
+    "engine.chunk.launch": ["engine.chunk.put", "engine.chunk.call"],
+    "engine.decode.launch": ["engine.decode.put", "engine.decode.call"],
+}
 
 
 @pytest.fixture
@@ -246,12 +254,23 @@ def test_serving_tick_emits_the_names_in_order_within_budget(tracer):
         # the stated order, by when each opened
         opened = [e.name for e in sorted(inside, key=lambda e: e.t0)]
         assert opened == [n for n in TICK_ORDER if n in opened]
+        for launch in (e for e in tick if e.name in LAUNCH_CHILDREN):
+            # a program's first call loads it: ``program.load`` then lies
+            # between the launch span and its two children
+            under = {launch.id} | {e.id for e in tick
+                                   if e.name == "program.load"
+                                   and e.parent_id == launch.id}
+            kids = sorted((e for e in tick if e.parent_id in under
+                           and e.name != "program.load"),
+                          key=lambda e: e.t0)
+            assert [e.name for e in kids] == LAUNCH_CHILDREN[launch.name]
         # budget: 24 a tick, plus 2 a request (its gate was at submit)
         queued = [e for e in tick if e.name == "req.queue"]
         assert len(tick) - len(queued) <= 24
         seen.update(e.name for e in tick)
     assert set(TICK_ORDER) | {"router.step", "req.queue",
                               "program.load"} <= seen
+    assert {n for kids in LAUNCH_CHILDREN.values() for n in kids} <= seen
     # a request's queue wait: from its submit to its admission, by rid
     queue = {e.rid: e for e in tracer.events("req.queue")}
     assert set(queue) == set(rids)
@@ -325,6 +344,128 @@ def test_the_read_and_its_live_share_ride_the_spans(
                <= alloc.args["table_blocks"] for t in ticks)
     assert all(t["live_tiles"] <= alloc.args["table_tiles"] for t in ticks)
     assert ticks[-1]["live_blocks"] > ticks[0]["live_blocks"]
+
+
+def _both_program_ticks(tracer, router, step):
+    """The records of each ``step`` call that launched the chunk program
+    AND the decode tick with both programs loaded: a short prompt decodes
+    throughout, a long one prefills beside it once to load every bucket
+    its chunks meet, then once more."""
+    long = np.arange(1, 41, dtype=np.int32)  # five chunks of 8
+    router.submit(np.arange(1, 6, dtype=np.int32), 40)
+    router.submit(long, 3)
+    for _ in range(8):
+        step()
+    router.submit(long, 3)
+    ticks = []
+    for _ in range(6):
+        n0 = len(tracer.events())
+        step()
+        tick = tracer.events()[n0:]
+        names = {e.name for e in tick}
+        if ({"engine.chunk.launch", "engine.decode.launch"} <= names
+                and "program.load" not in names):
+            ticks.append(tick)
+    assert len(ticks) >= 3
+    return ticks
+
+
+def _one(tick, name):
+    (e,) = [e for e in tick if e.name == name]
+    return e
+
+
+def test_a_tick_that_launches_both_programs_splits_each_launch(tracer):
+    """Each program's launch path, statement by statement: ``build`` (the
+    operands assembled on the host) BEFORE the launch span and, like it,
+    ``router.step``'s child; inside the launch span ``put`` (the one
+    ``jax.device_put``) then ``call`` (the jitted function, to its
+    return), which together cover it but for the statements between."""
+    cfg, router = _tiny_router()
+    uncovered = []
+    for tick in _both_program_ticks(tracer, router, router.step):
+        step = _one(tick, "router.step")
+        opened = [e.name for e in sorted(tick, key=lambda e: e.t0)
+                  if e.name.startswith("engine.")
+                  and e.name != "engine.collect.wait"]
+        assert opened == [f"engine.{prog}.{part}"
+                          for prog in ("chunk", "decode")
+                          for part in ("build", "launch", "put", "call")]
+        for prog in ("chunk", "decode"):
+            build, launch, put, call = (
+                _one(tick, f"engine.{prog}.{part}")
+                for part in ("build", "launch", "put", "call"))
+            assert build.parent_id == launch.parent_id == step.id
+            assert put.parent_id == call.parent_id == launch.id
+            assert build.args is None
+            assert build.t1 <= launch.t0 <= put.t0 <= put.t1 <= call.t0
+            assert call.t1 <= launch.t1
+            uncovered.append((launch.t1 - launch.t0) - (put.t1 - put.t0)
+                             - (call.t1 - call.t0))
+        # a step's spans: itself, the collect's two, expire, admit and
+        # plan, and four a launch: 14 of the budget of 24
+        assert len([e for e in tick if e.name != "req.queue"]) == 14
+    # the launch keeps its extent, from the transfer to the call's return:
+    # what its two children leave uncovered is entering and leaving four
+    # context managers (0.02 ms on this sandbox's CPU, a host count; the
+    # ticks' median, so that a loaded machine's stall does not fail)
+    assert 0 <= min(uncovered) and statistics.median(uncovered) < 1e-3
+
+
+def test_put_and_call_say_what_the_transfer_and_the_call_carry(tracer):
+    """``arrays`` and ``bytes`` are the host tuple's count and ``nbytes``
+    (six arrays a chunk launch, three a decode tick: latency, not
+    bandwidth), ``leaves`` the pytree leaves the call flattens:
+    parameters, cache, the logits buffer and the operands (the decode
+    tick's key among them)."""
+    cfg, router = _tiny_router()
+    engine = router.replicas[0].engine
+    ticks = _both_program_ticks(tracer, router, router.step)
+    resident = len(jax.tree.leaves((engine.params, engine.cache))) + 1
+    n, w = engine.n_slots, engine.table_width
+    assert (n, w, engine.chunk) == (4, 8, 8)
+    for tick in ticks:
+        k, wp = _one(tick, "engine.chunk.launch").args["bucket"]
+        # tokens [k, 8], starts, tables [k, wp], slots, last_idx: int32;
+        # is_last: bool
+        assert _one(tick, "engine.chunk.put").args == {
+            "arrays": 6, "bytes": 4 * k * (8 + 1 + wp + 1 + 1) + k}
+        assert _one(tick, "engine.chunk.call").args == {
+            "leaves": resident + 6}
+        # positions [4] int32, active [4] bool, the masked table [4, 8]
+        assert _one(tick, "engine.decode.put").args == {
+            "arrays": 3, "bytes": 4 * n + n + 4 * n * w}
+        assert _one(tick, "engine.decode.call").args == {
+            "leaves": resident + 3 + 1}
+    assert engine.tables.dtype == np.int32 and engine.tables.shape == (n, w)
+
+
+def test_the_synchronous_step_books_the_same_children(tracer):
+    """``Scheduler.step()`` (the tests' step-domain reference, and
+    ``FleetRouter(async_host=False)``) runs the one launch body: the same
+    build, put and call, and the wait for the tick's tokens after its
+    launch span has closed."""
+    cfg, router = _tiny_router(async_host=False)
+    sched = router.replicas[0]
+    for tick in _both_program_ticks(tracer, router, sched.step):
+        for prog in ("chunk", "decode"):
+            build, launch, put, call = (
+                _one(tick, f"engine.{prog}.{part}")
+                for part in ("build", "launch", "put", "call"))
+            assert build.parent_id == launch.parent_id is None
+            assert put.parent_id == call.parent_id == launch.id
+            assert build.t1 <= launch.t0 <= put.t0 <= put.t1 <= call.t0
+        wait = _one(tick, "engine.collect.wait")
+        assert wait.t0 >= _one(tick, "engine.decode.launch").t1
+    # through the router's reference loop they are router.step's children
+    n0 = len(tracer.events())
+    router.submit(np.arange(1, 6, dtype=np.int32), 3)
+    router.step()
+    tick = tracer.events()[n0:]
+    step = _one(tick, "router.step")
+    assert step.args == {"in_flight": 0}
+    for name in ("engine.chunk.build", "engine.decode.build"):
+        assert _one(tick, name).parent_id == step.id
 
 
 def test_async_collect_books_the_same_wait_span(tracer):
@@ -463,17 +604,22 @@ def test_no_threaded_tracer_and_every_kernel_named():
 # ---- cost, and the profiler's clock -------------------------------------------
 
 
-def test_a_span_costs_microseconds(tracer):
-    """Budget: under 3 us a span on this sandbox's CPU (a host count);
-    the assert is generous for a loaded machine."""
+@pytest.mark.parametrize("name,args", [
+    ("sched.admit", {}),
+    ("engine.chunk.put", {"arrays": 6, "bytes": 16_640}),
+])
+def test_a_span_costs_microseconds(tracer, name, args):
+    """Budget: under 3 us a span on this sandbox's CPU (a host count),
+    with arguments or without; the assert is generous for a loaded
+    machine."""
     costs = []
     for _ in range(10_000):
         t0 = time.perf_counter()
-        with tracer.span("sched.admit"):
+        with tracer.span(name, **args):
             pass
         costs.append(time.perf_counter() - t0)
     assert statistics.median(costs) < 10e-6
-    assert len(tracer.events("sched.admit")) == 10_000
+    assert len(tracer.events(name)) == 10_000
 
 
 def test_spans_reach_the_profilers_host_plane(tracer, tmp_path):
@@ -501,3 +647,6 @@ def test_spans_reach_the_profilers_host_plane(tracer, tmp_path):
     assert host.count("pdt:sched.admit") == 2
     assert {"pdt:router.step", "pdt:router.gate",
             "pdt:engine.decode.launch", "pdt:engine.collect.wait"} <= set(host)
+    # the launch's split lies beside the device's operations too
+    assert {"pdt:engine.decode.build", "pdt:engine.decode.put",
+            "pdt:engine.decode.call"} <= set(host)
