@@ -38,6 +38,13 @@ provides the thread pool the off-critical-path host work (JSONL, gate
 percentile math) runs on; ``fleet.FleetRouter`` is the driver, and
 that lagged loop is the one it runs (``async_host=False`` is the
 tests' step-domain reference) — ANALYSIS.md "Async host runtime".
+
+PR 39: a tick crosses the host/device boundary once each way. A launch
+of either tick program moves ONE packed int32 operand (a row a job or a
+lane: ``engine._chunk_operand``, ``engine._decode_operand``) where it
+moved three to seven arrays, and a collect fetches the tokens alone:
+``decode``/``decode_launch`` hand back the positions as the host counts
+them (launched, plus one on the active lanes).
 """
 
 from pytorch_distributed_tpu.serving.kv_pool import (

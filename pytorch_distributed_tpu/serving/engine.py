@@ -26,6 +26,18 @@ every update is in place:
   table masking, so recycled blocks can never be corrupted by a dead
   lane.
 
+A tick crosses the host/device boundary once each way. Every host-built
+operand of the two programs is int32 or a flag and a row a job or a
+lane, so a launch packs them into ONE int32 matrix (``_chunk_operand``:
+``tokens | table | start, slot, is_last, last_idx (, length)`` a job;
+``_decode_operand``: ``masked table | position, active`` a lane), moves
+it with one explicit ``jax.device_put`` (a transfer costs its arrays,
+not its bytes) and the program slices it apart (``_columns`` is the one
+layout both read); the warm-ups build theirs through the same builders.
+A collect fetches the tick's tokens (and expert counts) in one
+``device_get``; the positions a tick leaves behind are the launched ones
+plus one on the active lanes, which the host counts itself.
+
 Tensor parallelism reuses the dense serving path's machinery: params
 placed by ``models.generate._tp_rules``, the pool head-sharded by
 ``kv_pool.paged_cache_specs``, programs wrapped in ``shard_map`` over the
@@ -38,7 +50,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import re
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,10 +85,33 @@ def _pow2_bucket(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
-def _put_args(host: tuple) -> dict:
+def _columns(matrices: Dict[str, int], scalars: Tuple[str, ...]):
+    """The layout of a launch's ONE int32 operand, a row a job or a lane:
+    ``({field: its columns}, width)``. A matrix field (``{name: columns}``,
+    in order) is a slice of the row, a scalar field the one column behind
+    them. The builder and the program's body both read a row through
+    ``_fields`` with this, so they cannot disagree about a column."""
+    cols, at = {}, 0
+    for name, width in matrices.items():
+        cols[name] = slice(at, at + width)
+        at += width
+    for name in scalars:
+        cols[name] = at
+        at += 1
+    return cols, at
+
+
+def _fields(packed, cols: dict) -> dict:
+    """``{field: packed[:, its columns]}``: views to fill on the host
+    (NumPy), static slices of the operand inside a program."""
+    return {name: packed[:, col] for name, col in cols.items()}
+
+
+def _put_args(host: np.ndarray) -> dict:
     """``engine.*.put``'s arguments: how many host arrays the one transfer
-    moves and their bytes (latency or bandwidth)."""
-    return {"arrays": len(host), "bytes": sum(a.nbytes for a in host)}
+    moves (one: the packed operand) and their bytes (latency or
+    bandwidth)."""
+    return {"arrays": 1, "bytes": host.nbytes}
 
 
 def _expert_counts(stats, num_layers: int):
@@ -425,16 +460,16 @@ class PagedEngine:
         # whole working set — params, pool, logits — to one device carved
         # out of jax.devices(), so N single-process replicas each dispatch
         # onto their own sub-mesh and their programs can overlap. The
-        # compiled programs follow their committed inputs; host-built
-        # operands (tokens, tables) stay uncommitted and are free to land
-        # wherever the committed arguments already live.
+        # compiled programs follow their committed inputs; a launch's
+        # host-built operand (one packed array) stays uncommitted and is
+        # free to land wherever the committed arguments already live.
         self.device = device
         if device is not None:
             with spans.tracer().span("weights.place"):
                 self.params = jax.device_put(self.params, device)
             self.cache = jax.device_put(self.cache, device)
             self.logits = jax.device_put(self.logits, device)
-        # what every program's call flattens beside its own operands; the
+        # what every program's call flattens beside its own operand; the
         # trees keep their structure, so it is counted here and not a tick
         # (``engine.*.call``'s ``leaves``)
         self._resident_leaves = len(jax.tree.leaves(
@@ -462,6 +497,65 @@ class PagedEngine:
 
         return TransformerLM(self.config)
 
+    # A launch moves ONE array to the device: every host-built operand of
+    # the two tick programs is int32 or a flag and a row a job or a lane,
+    # so each launch packs them into one int32 matrix (a transfer costs
+    # its arrays, not its bytes), and the program slices it apart.
+
+    def _chunk_columns(self, wp: int):
+        """A job's row of the chunk program's operand: ``tokens[chunk] |
+        table[wp] | start, slot, is_last, last_idx`` and, where the
+        config's state is a request's, ``length``."""
+        return _columns(
+            dict(tokens=self.chunk, table=wp),
+            ("start", "slot", "is_last", "last_idx")
+            + (("length",) if self._per_request else ()))
+
+    def _decode_columns(self):
+        """A lane's row of the decode tick's operand:
+        ``table[table_width] | position, active``."""
+        return _columns(dict(table=self.table_width),
+                        ("position", "active"))
+
+    def _chunk_operand(self, k_pad: int, wp: int,
+                       jobs: Sequence["ChunkJob"] = ()) -> np.ndarray:
+        """The (k_pad, wp) chunk program's operand on the host, a row a
+        job; the rows past ``jobs`` are padding jobs (all of them in a
+        warm-up): a table of trash blocks, and slot ``n_slots``, which is
+        out of the logits buffer's bounds, so the scatter drops them."""
+        cols, width = self._chunk_columns(wp)
+        host = np.zeros((k_pad, width), np.int32)
+        job = _fields(host, cols)
+        job["table"][:] = TRASH_BLOCK
+        job["slot"][:] = self.n_slots
+        for i, j in enumerate(jobs):
+            job["tokens"][i] = j.tokens
+            job["table"][i] = self.tables[j.slot, :wp]
+            job["start"][i] = j.start
+            job["slot"][i] = j.slot
+            job["is_last"][i] = j.is_last
+            job["last_idx"][i] = j.last_idx
+            if "length" in job:
+                # a job's real positions in this chunk: all of it, or up
+                # to the prompt's last token; a padding job has none
+                job["length"][i] = (j.last_idx + 1 if j.is_last
+                                    else self.chunk)
+        return host
+
+    def _decode_operand(self, positions: np.ndarray,
+                        active: np.ndarray) -> np.ndarray:
+        """The decode tick's operand on the host, a row a lane; a lane
+        that is not active gets a table of trash blocks, so its writes
+        can never touch a recycled block."""
+        cols, width = self._decode_columns()
+        host = np.empty((self.n_slots, width), np.int32)
+        lane = _fields(host, cols)
+        lane["table"][:] = np.where(active[:, None], self.tables,
+                                    TRASH_BLOCK)
+        lane["position"][:] = positions
+        lane["active"][:] = active
+        return host
+
     def _chunk_fn(self, k_pad: int, wp: int):
         key = (k_pad, wp)
         fn = self._chunk_fns.get(key)
@@ -469,38 +563,40 @@ class PagedEngine:
             return fn
         model = self._model()
         layers = self.config.num_layers
+        cols, _ = self._chunk_columns(wp)
 
-        def body(params, cache, logits, tokens, starts, tables, slots,
-                 is_last, last_idx, *lengths):
-            # ``lengths`` (one operand, where the config's state is a
+        def body(params, cache, logits, packed):
+            job = _fields(packed, cols)
+            slots, last_idx = job["slot"], job["last_idx"]
+            # ``length`` (a column where the config's state is a
             # request's: ``_per_request``): each job's real positions in
             # this chunk, 0 for a padding job, whose slot ``n_slots`` is
             # the tail leaves' trash row
-            per_request = dict(slots=slots, lengths=lengths[0],
-                               head_rows=last_idx,
-                               mutable=["cache", "moe_stats"]
-                               ) if lengths else dict(mutable=["cache"])
+            lengths = job.get("length")
+            per_request = dict(mutable=["cache"]) if lengths is None else dict(
+                slots=slots, lengths=lengths, head_rows=last_idx,
+                mutable=["cache", "moe_stats"])
             out, variables = model.apply(
                 {"params": params, "cache": cache},
-                tokens,
-                position_offset=starts,
+                job["tokens"],
+                position_offset=job["start"],
                 prefill=True,
-                block_tables=tables,
+                block_tables=job["table"],
                 **per_request,
             )
             # logits at each prompt's LAST real token — the distribution
             # for its first decoded token; written only for final chunks.
             # Padding jobs carry slot == n_slots: the scatter drops them.
-            row = out[:, 0] if lengths else jnp.take_along_axis(
+            row = jnp.take_along_axis(
                 out, last_idx[:, None, None], axis=1
-            )[:, 0]
+            )[:, 0] if lengths is None else out[:, 0]
             new_logits = logits.at[slots].set(
-                jnp.where(is_last[:, None], row, logits[slots])
+                jnp.where(job["is_last"][:, None] != 0, row, logits[slots])
             )
-            if lengths:
-                return variables["cache"], new_logits, _expert_counts(
-                    variables.get("moe_stats", {}), layers)
-            return variables["cache"], new_logits
+            if lengths is None:
+                return variables["cache"], new_logits
+            return variables["cache"], new_logits, _expert_counts(
+                variables.get("moe_stats", {}), layers)
 
         if self.mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -509,8 +605,7 @@ class PagedEngine:
 
             body = shard_map(
                 body, mesh=self.mesh,
-                in_specs=(self._param_specs, self._cache_specs, P(), P(),
-                          P(), P(), P(), P(), P()),
+                in_specs=(self._param_specs, self._cache_specs, P(), P()),
                 out_specs=(self._cache_specs, P()),
                 check_vma=False,
             )
@@ -529,34 +624,39 @@ class PagedEngine:
         n_slots, layers = self.n_slots, self.config.num_layers
         per_request = self._per_request
 
-        def body(params, cache, logits, positions, active, tables, rng):
+        cols, _ = self._decode_columns()
+
+        def body(params, cache, logits, packed, rng):
+            lane = _fields(packed, cols)
+            active = lane["active"] != 0
             tokens = _sample(logits, rng, temp, topk)
             # a lane that is not active reads and writes the tail leaves'
             # trash row (a slot in mid-prefill keeps its tail while the
             # tick runs over it) and is routed to no expert
             request = dict(
                 slots=jnp.where(active, jnp.arange(n_slots), n_slots),
-                lengths=active.astype(jnp.int32),
+                lengths=lane["active"],
                 mutable=["cache", "moe_stats"],
             ) if per_request else dict(mutable=["cache"])
             out, variables = model.apply(
                 {"params": params, "cache": cache},
                 tokens[:, None],
-                position_offset=positions,
+                position_offset=lane["position"],
                 decode=True,
-                block_tables=tables,
+                block_tables=lane["table"],
                 **request,
             )
             # Inactive lanes: cache writes already routed to the trash
             # block (host-masked tables); logits rows are dead state,
             # replaced by the slot's next final prefill chunk before they
-            # are read. Positions stay frozen — the caller reads them.
-            positions = jnp.where(active, positions + 1, positions)
+            # are read. The positions the tick leaves behind are the
+            # launched ones plus one on the active lanes: the host counts
+            # them itself (``_decode_call``) and the program returns none.
             if per_request:
-                return (variables["cache"], out[:, 0], positions, tokens,
+                return (variables["cache"], out[:, 0], tokens,
                         _expert_counts(variables.get("moe_stats", {}),
                                        layers))
-            return variables["cache"], out[:, 0], positions, tokens
+            return variables["cache"], out[:, 0], tokens
 
         if self.mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -566,8 +666,8 @@ class PagedEngine:
             body = shard_map(
                 body, mesh=self.mesh,
                 in_specs=(self._param_specs, self._cache_specs, P(), P(),
-                          P(), P(), P()),
-                out_specs=(self._cache_specs, P(), P(), P()),
+                          P()),
+                out_specs=(self._cache_specs, P(), P()),
                 check_vma=False,
             )
         self._decode_fn = jax.jit(self._named(body, self.DECODE_PROGRAM),
@@ -861,28 +961,20 @@ class PagedEngine:
         """
         k_pad, wp = self._floored(k_pad, wp)
         fn = self._chunk_fn(k_pad, wp)
-        c = self.chunk
-        tokens = jnp.zeros((k_pad, c), jnp.int32)
-        starts = jnp.zeros((k_pad,), jnp.int32)
-        tables = jnp.full((k_pad, wp), TRASH_BLOCK, jnp.int32)
-        slots = jnp.full((k_pad,), self.n_slots, jnp.int32)
-        is_last = jnp.zeros((k_pad,), bool)
-        last_idx = jnp.zeros((k_pad,), jnp.int32)
-        operands = (tokens, starts, tables, slots, is_last, last_idx)
-        if self._per_request:  # every job a padding job: no real position
-            operands += (jnp.zeros((k_pad,), jnp.int32),)
+        # every job a padding job: the served call's operand with no jobs
+        packed = jax.device_put(self._chunk_operand(k_pad, wp))
         name = self.chunk_program_name(k_pad, wp)
         if execute:
             with program_load_if((k_pad, wp) not in self._hot_chunks, name):
                 self.cache, self.logits, *_ = fn(
-                    self.params, self.cache, self.logits, *operands,
+                    self.params, self.cache, self.logits, packed,
                 )
             self._hot_chunks.add((k_pad, wp))
             return None
         cache_aval, logits_aval = self._cache_logits_avals()
         with program_load(name):
             return fn.lower(
-                self.params, cache_aval, logits_aval, *operands,
+                self.params, cache_aval, logits_aval, packed,
             ).compile()
 
     def warm_decode(self, execute: bool = True):
@@ -892,26 +984,24 @@ class PagedEngine:
         logits buffer's garbage rows are rewritten by each slot's final
         prefill chunk before any real decode reads them."""
         fn = self._decode()
-        positions = jnp.zeros((self.n_slots,), jnp.int32)
-        active = jnp.zeros((self.n_slots,), bool)
-        tables = jnp.full((self.n_slots, self.table_width), TRASH_BLOCK,
-                          jnp.int32)
+        # the served call's operand with no lane active
+        packed = jax.device_put(self._decode_operand(
+            np.zeros((self.n_slots,), np.int32),
+            np.zeros((self.n_slots,), bool)))
         rng = jax.random.key(0)
         if self.device is not None:
             rng = jax.device_put(rng, self.device)
         if execute:
             with program_load_if(not self._hot_decode, self.DECODE_PROGRAM):
                 self.cache, self.logits, *_ = fn(
-                    self.params, self.cache, self.logits, positions, active,
-                    tables, rng,
+                    self.params, self.cache, self.logits, packed, rng,
                 )
             self._hot_decode = True
             return None
         cache_aval, logits_aval = self._cache_logits_avals()
         with program_load(self.DECODE_PROGRAM):
             return fn.lower(
-                self.params, cache_aval, logits_aval, positions, active,
-                tables, rng,
+                self.params, cache_aval, logits_aval, packed, rng,
             ).compile()
 
     # ---- slot-level operations ----
@@ -1478,27 +1568,7 @@ class PagedEngine:
         tr = spans.tracer()
         with tr.span("engine.chunk.build"):
             k_pad, wp = self.bucket_for(jobs)
-            tokens = np.zeros((k_pad, c), np.int32)
-            starts = np.zeros((k_pad,), np.int32)
-            tables = np.full((k_pad, wp), TRASH_BLOCK, np.int32)
-            # padding jobs scatter to slot n_slots — out of bounds, dropped
-            slots = np.full((k_pad,), self.n_slots, np.int32)
-            is_last = np.zeros((k_pad,), bool)
-            last_idx = np.zeros((k_pad,), np.int32)
-            # a job's real positions in this chunk: all of it, or up to the
-            # prompt's last token; a padding job has none
-            lengths = np.zeros((k_pad,), np.int32)
-            for i, j in enumerate(jobs):
-                tokens[i] = j.tokens
-                starts[i] = j.start
-                tables[i] = self.tables[j.slot, :wp]
-                slots[i] = j.slot
-                is_last[i] = j.is_last
-                last_idx[i] = j.last_idx
-                lengths[i] = j.last_idx + 1 if j.is_last else c
-            host = (tokens, starts, tables, slots, is_last, last_idx)
-            if self._per_request:
-                host += (lengths,)
+            host = self._chunk_operand(k_pad, wp, jobs)
             fn = self._chunk_fn(k_pad, wp)
             name = self.chunk_program_name(k_pad, wp)
             put_args = _put_args(host)
@@ -1509,15 +1579,15 @@ class PagedEngine:
                      bucket=(k_pad, wp)), \
                 program_load_if((k_pad, wp) not in self._hot_chunks, name), \
                 self.ledger.launch(self.ledger_replica, name):
-            # ONE batched explicit transfer for the six host-built
-            # operands, inside the launch window (dispatch cost; see
-            # the decode call's note on the per-operand asarray tax)
+            # ONE explicit transfer of the one packed operand, inside
+            # the launch window (dispatch cost; see the decode call's
+            # note on why it is explicit)
             with tr.span("engine.chunk.put", **put_args):
-                operands = jax.device_put(host)
+                packed = jax.device_put(host)
             with tr.span("engine.chunk.call",
-                         leaves=self._resident_leaves + len(host)):
+                         leaves=self._resident_leaves + 1):
                 self.cache, self.logits, *counts = fn(
-                    self.params, self.cache, self.logits, *operands,
+                    self.params, self.cache, self.logits, packed,
                 )
         if counts:
             self.chunk_expert_counts = counts[0]
@@ -1525,15 +1595,20 @@ class PagedEngine:
 
     def _decode_call(self, positions, active, rng, sync: bool):
         """One decode-tick launch, shared by the sync and async host
-        paths. ``sync=True`` materializes the tokens INSIDE the ledger
-        window (t1 is exact completion — the historical ``decode``
-        contract); ``sync=False`` returns device arrays plus the launch
-        token so the caller can pin completion at its own collect site
-        (``DispatchLedger.complete``)."""
+        paths: ``(tokens, new_positions, launch_token)``. ``sync=True``
+        materializes the tokens INSIDE the ledger window (t1 is exact
+        completion — the historical ``decode`` contract); ``sync=False``
+        returns them on the device plus the launch token so the caller
+        can pin completion at its own collect site
+        (``DispatchLedger.complete``). ``new_positions`` is a host array
+        either way: the launched positions plus one on the active lanes,
+        which is all the tick does to them, so nothing is fetched for it."""
         tr = spans.tracer()
         with tr.span("engine.decode.build"):
-            masked = np.where(active[:, None], self.tables, TRASH_BLOCK)
             positions = np.asarray(positions, np.int32)
+            host = self._decode_operand(positions, active)
+            # what the tick leaves behind: one more on a lane that decodes
+            new_positions = positions + active
             fn = self._decode()
             if self.device is not None:
                 # keys are computed arrays; pin them next to the replica's
@@ -1551,28 +1626,25 @@ class PagedEngine:
                 state_rows=lanes if self.slot_state_bytes else 0,
                 live_blocks=int(np.sum(live + 1)),
                 live_tiles=int(np.sum(live // self.tile_blocks + 1)))
-            host = (positions, active, masked)
             put_args = _put_args(host)
         with self.ledger.launch(self.ledger_replica, self.DECODE_PROGRAM,
                                 sync=sync) as lt:
-            # ONE batched explicit transfer for the host-built
-            # operands, inside the launch window — it is dispatch cost.
-            # The per-operand eager jnp.asarray spelling paid python
-            # bind overhead three times per tick (a third of the serve
-            # loop's host wall, round-16 profile), and a bare-np jit
-            # call would be an IMPLICIT transfer the no_recompile guard
-            # rightly rejects.
+            # ONE explicit transfer of the one packed operand, inside
+            # the launch window — it is dispatch cost, and it costs its
+            # arrays, not its bytes (0.2 ms an array on a v5e's host,
+            # PERF.md): table, positions and flags cross as one. A
+            # bare-np jit call would be an IMPLICIT transfer the
+            # no_recompile guard rightly rejects.
             with tr.span("engine.decode.launch", **launch_args), \
                     program_load_if(not self._hot_decode,
                                     self.DECODE_PROGRAM):
                 with tr.span("engine.decode.put", **put_args):
-                    positions, active, masked = jax.device_put(host)
-                # and the key: the call flattens it with the operands
+                    packed = jax.device_put(host)
+                # and the key: the call flattens it with the operand
                 with tr.span("engine.decode.call",
-                             leaves=self._resident_leaves + len(host) + 1):
-                    self.cache, self.logits, positions, tokens, *counts = fn(
-                        self.params, self.cache, self.logits,
-                        positions, active, masked, rng,
+                             leaves=self._resident_leaves + 2):
+                    self.cache, self.logits, tokens, *counts = fn(
+                        self.params, self.cache, self.logits, packed, rng,
                     )
                 self._tick_counts = counts[0] if counts else None
             if sync:
@@ -1586,38 +1658,41 @@ class PagedEngine:
             else:
                 lt.handle = tokens  # non-donated output: fence target
         self._hot_decode = True
-        return tokens, positions, lt
+        return tokens, new_positions, lt
 
     def decode(self, positions: np.ndarray, active: np.ndarray, rng):
         """One decode tick for every slot; samples from the logits
         buffer, writes each active lane's token at its position, returns
-        ``(tokens [n_slots], new_positions)``. Inactive lanes compute
-        dead garbage routed to the trash block."""
-        tokens, positions, _ = self._decode_call(positions, active, rng,
-                                                 sync=True)
-        return tokens, np.array(positions)
+        ``(tokens [n_slots], new_positions)``, both on the host. Inactive
+        lanes compute dead garbage routed to the trash block and keep
+        their position."""
+        tokens, new_positions, _ = self._decode_call(positions, active, rng,
+                                                     sync=True)
+        return tokens, new_positions
 
     def decode_launch(self, positions: np.ndarray, active: np.ndarray,
                       rng):
         """The async host path's non-blocking decode tick (round 16):
         dispatches the SAME compiled program as ``decode`` — identical
         shapes, zero new registry entries — and returns
-        ``(device_tokens, device_positions, launch_token)`` WITHOUT
-        materializing anything. The caller materializes later through
-        ``decode_collect`` while this device (or another replica's) is
-        already running the next program."""
+        ``(device_tokens, new_positions, launch_token)`` WITHOUT
+        materializing the tokens (``new_positions`` is the host's own
+        count, a copy: rows the caller arms before the collect are not in
+        it). The caller materializes later through ``decode_collect``
+        while this device (or another replica's) is already running the
+        next program."""
         return self._decode_call(positions, active, rng, sync=False)
 
-    def decode_collect(self, tokens, positions, launch_token):
+    def decode_collect(self, tokens, new_positions, launch_token):
         """Materialize a ``decode_launch``'s results: pins the launch's
         completion on the ledger (a collect-site fence — by now the
-        work is usually done and the wait is a no-op), then fetches
-        tokens and positions to host. Returns the same
+        work is usually done and the wait is a no-op), then fetches the
+        tokens to host: the one fetch of a tick. Returns the same
         ``(tokens [n_slots], new_positions)`` as ``decode``."""
         with spans.tracer().span("engine.collect.wait"):
             self.ledger.complete(launch_token)
             tokens = self._fetch_tick(tokens)
-        return tokens, np.array(positions)
+        return tokens, new_positions
 
     def _fetch_tick(self, tokens) -> np.ndarray:
         """The tick's tokens on the host and, in the same fetch (no

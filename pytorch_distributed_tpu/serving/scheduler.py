@@ -67,9 +67,11 @@ Host runtime (round 16; ANALYSIS.md "Async host runtime"): a tick is
 a **dispatch/collect split** — ``dispatch_tick()`` runs admissions,
 the chunk program, and a NON-BLOCKING decode launch
 (``PagedEngine.decode_launch``: JAX async dispatch returns before
-device completion), parking a ``TickHandle``; ``collect_tick()``
-materializes the parked tick's tokens and does all per-token host work
-(TTFT, retirement, JSONL). ``fleet.FleetRouter`` drives the halves
+device completion; each launch moves one packed operand to the device),
+parking a ``TickHandle``; ``collect_tick()`` materializes the parked
+tick's tokens (its one fetch: the positions it writes back to the
+decoded lanes are the engine's host count) and does all per-token host
+work (TTFT, retirement, JSONL). ``fleet.FleetRouter`` drives the halves
 LAGGED — collect tick N−1, then dispatch tick N, on every replica, and
 return with N in flight — so the device works through the caller's
 submits and the other replicas' host work. ``step()`` is the same two
@@ -226,7 +228,9 @@ class TickHandle(NamedTuple):
     ``tokens`` is the decode program's token output — a DEVICE array on
     the async path (materialized at collect), an np array on the sync
     path (materialized inside the ledger window), or None when the tick
-    had no active decode lane. ``lanes`` are the slots that were active
+    had no active decode lane. ``positions`` is the engine's host count
+    of every slot's position after the tick, as of the launch (nothing
+    is fetched for it). ``lanes`` are the slots that were active
     at dispatch, in slot order — collect processes exactly these, and
     the no-external-mutation protocol (preempt/drain collect first)
     guarantees each is still resident at collect time."""
@@ -1305,14 +1309,16 @@ class Scheduler:
             self._observe_tick(h.t_step0)
             return
         if h.sync:
-            tokens, positions = h.tokens, np.array(h.positions)
+            tokens, positions = h.tokens, h.positions
         else:
             tokens, positions = self.engine.decode_collect(
                 h.tokens, h.positions, h.launch
             )
-        # write back ONLY the lanes this tick decoded: rows the host
-        # armed since the launch (an adopted handoff chain, a restored
-        # swap) must not be clobbered by the device's frozen copies
+        # ``positions`` is the engine's host count as of the launch (the
+        # launched rows, plus one where the tick decoded): write back
+        # ONLY the lanes this tick decoded, so that rows the host armed
+        # since the launch (an adopted handoff chain, a restored swap)
+        # are not clobbered by the launch's frozen copies
         lanes = np.asarray(h.lanes, np.int64)
         self.positions[lanes] = positions[lanes]
         # tokens materialized above, so this timestamp is

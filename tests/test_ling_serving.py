@@ -355,17 +355,20 @@ def test_the_programs_count_live_lanes_only(model):
 
 #: sha256[:12] of the lowered text of the decode tick and of one chunk
 #: program of each configuration the benchmark had, at its file's ``tiny``
-#: size on the CPU (the dense read), taken on the PARENT of this PR: a
-#: default of the new fields, or a rewrite of the expert layer, that moved
-#: one of their programs moves a digest here. (The chunk programs at the
+#: size on the CPU (the dense read): a default of a new config field, or a
+#: rewrite of the expert layer, that moved one of their programs moves a
+#: digest here. Taken on PR 36's parent, and again in PR 39, whose one
+#: packed operand a launch changed every tick program's signature and so
+#: its text (a PR that means to change them records new digests here, as
+#: ``tests/test_tpu_compile.py::CHUNK_DIGESTS``). (The chunk programs at the
 #: cells' own sizes are in ``tests/test_tpu_compile.py``.)
 PARENT_DIGESTS = {
-    ("gpt2-medium", "decode_tick"): "626bdc3cc3e2",
-    ("gpt2-medium", "chunk_prefill[k=2,w=2]"): "22e3b6bff685",
-    ("ouro-2.6b", "decode_tick"): "d8eefead200e",
-    ("ouro-2.6b", "chunk_prefill[k=2,w=2]"): "ef4c601e5abd",
-    ("zaya1-8b", "decode_tick"): "d69445521173",
-    ("zaya1-8b", "chunk_prefill[k=2,w=2]"): "01e5cc010939",
+    ("gpt2-medium", "decode_tick"): "d060c128c39e",
+    ("gpt2-medium", "chunk_prefill[k=2,w=2]"): "8793f9265d18",
+    ("ouro-2.6b", "decode_tick"): "a1fd72f574b5",
+    ("ouro-2.6b", "chunk_prefill[k=2,w=2]"): "296396567d02",
+    ("zaya1-8b", "decode_tick"): "b6f409948883",
+    ("zaya1-8b", "chunk_prefill[k=2,w=2]"): "c9b84b95eb62",
 }
 
 
@@ -383,22 +386,17 @@ def lowered_digest(name, program):
             attention="dense")
     params = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
                             jnp.zeros((1, 8), jnp.int32))["params"]
-    n, i32 = 4, jnp.int32
+    n = 4
     eng = PagedEngine(cfg, params, n, n_blocks=9, block_len=8,
                       prefill_chunk=8)
     if program == "decode_tick":
         fn = eng._decode()
-        args = (params, eng.cache, eng.logits, jnp.zeros((n,), i32),
-                jnp.zeros((n,), bool), jnp.zeros((n, eng.table_width), i32),
+        args = (params, eng.cache, eng.logits, eng._decode_operand(
+            np.zeros((n,), np.int32), np.zeros((n,), bool)),
                 jax.random.key(0))
     else:
         fn = eng._chunk_fn(2, 2)
-        args = (params, eng.cache, eng.logits, jnp.zeros((2, 8), i32),
-                jnp.zeros((2,), i32), jnp.zeros((2, 2), i32),
-                jnp.zeros((2,), i32), jnp.zeros((2,), bool),
-                jnp.zeros((2,), i32))
-        if eng._per_request:
-            args += (jnp.zeros((2,), i32),)
+        args = (params, eng.cache, eng.logits, eng._chunk_operand(2, 2))
     with jax.default_matmul_precision(None):  # the programs' own
         text = fn.lower(*args).as_text()
     return hashlib.sha256(text.encode()).hexdigest()[:12]

@@ -306,9 +306,8 @@ def test_the_programs_hold_the_stack_once_under_a_loop(model):
     def dots(config, params):
         eng = PagedEngine(config, params, 2, n_blocks=5, block_len=8,
                           prefill_chunk=8)
-        args = (eng.params, eng.cache, eng.logits, jnp.zeros((2,), jnp.int32),
-                jnp.zeros((2,), bool),
-                jnp.zeros((2, eng.table_width), jnp.int32),
+        args = (eng.params, eng.cache, eng.logits, eng._decode_operand(
+            np.zeros((2,), np.int32), np.zeros((2,), bool)),
                 jax.random.key(0))
         scans, count = [], 0
 

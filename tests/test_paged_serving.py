@@ -5,6 +5,7 @@ admission-cost scaling micro-bench (cost-analysis bytes: paged flat in
 pool size, dense growing with it)."""
 
 import dataclasses
+import importlib
 import math
 import re
 
@@ -28,6 +29,7 @@ from pytorch_distributed_tpu.serving import (
 )
 from pytorch_distributed_tpu.serving.engine import ChunkJob
 from pytorch_distributed_tpu.serving.kv_pool import pool_leaf_shape
+from pytorch_distributed_tpu.telemetry import spans
 
 
 def setup(max_seq_len=96, **over):
@@ -291,10 +293,8 @@ def test_admission_cost_paged_flat_dense_grows():
                           prefill_chunk=16)
         assert eng.admit(0, len(prompt), 6)
         paged = eng._chunk_fn(1, 1).lower(
-            params, eng.cache, eng.logits, jnp.asarray(padded),
-            jnp.asarray([0], jnp.int32), jnp.asarray(eng.tables[:1, :1]),
-            jnp.asarray([0], jnp.int32), jnp.asarray([True]),
-            jnp.asarray([len(prompt) - 1], jnp.int32),
+            params, eng.cache, eng.logits, eng._chunk_operand(1, 1, [
+                ChunkJob(0, padded[0], 0, True, len(prompt) - 1)]),
         ).compile()
         paged_temp[max_len] = paged.memory_analysis().temp_size_in_bytes
         leaves = jax.tree.leaves(eng.cache)
@@ -564,3 +564,236 @@ def test_scheduler_interleaves_long_prefill_with_decode():
         produced.setdefault(rid, []).extend(toks)
     assert produced[r_short] == list(greedy_reference(cfg, params, short, 12))
     assert produced[r_long] == list(greedy_reference(cfg, params, long, 2))
+
+
+# ---------------------------------------------------------------------------
+# a tick crosses the host/device boundary once each way (PR 39): a launch
+# moves ONE packed int32 operand, a collect fetches the tokens alone
+# ---------------------------------------------------------------------------
+
+#: the kinds of stack the benchmark's cells serve, at their files' toy
+#: widths: plain configs (gpt2-style, looped) and ones whose state is a
+#: request's (``PagedEngine._per_request``: zaya's tail and dropless
+#: experts, ling's recurrent state), whose chunk operand carries ``length``
+KINDS = ("gpt2", "looped", "zaya", "ling")
+TICK_KW = dict(n_slots=4, block_len=8, prefill_chunk=8)
+#: one chunk program an engine (every launch pads to four jobs over the
+#: whole table): the served backlogs below compile two programs, not ten
+ONE_BUCKET = dict(chunk_bucket_floor=(4, 8), admit_per_step=3, **TICK_KW)
+NEW = 5
+
+
+def _toy(kind):
+    if kind == "gpt2":
+        return setup(max_seq_len=64)
+    module = importlib.import_module(f"test_{kind}_lm")
+    cfg = getattr(module, f"{kind}_config")()
+    return cfg, module.seeded(cfg)
+
+
+def _backlog(cfg):
+    """Multi-chunk prompts among short ones; three admissions a tick, so
+    the first chunk launch pads a job (3 -> 4) and a lane decodes while
+    the others' slots are in mid-prefill."""
+    rng = np.random.default_rng(0)
+    return [rng.integers(1, min(cfg.vocab_size, 128), size=n).astype(np.int32)
+            for n in (5, 13, 9, 20, 3)]
+
+
+@pytest.fixture(scope="module", params=KINDS)
+def served(request):
+    """One backlog through the router's lagged loop and through the
+    synchronous ``Scheduler.step``, beside ``generate``'s greedy streams
+    (the dense cache: no paged program), and the lagged run's spans."""
+    from pytorch_distributed_tpu.fleet import FleetRouter
+
+    with jax.default_matmul_precision("highest"):
+        cfg, params = _toy(request.param)
+        prompts = _backlog(cfg)
+        tr = spans.tracer()
+        n0 = len(tr.events())
+        router = FleetRouter(cfg, params, n_replicas=1, **ONE_BUCKET)
+        rids = [router.submit(p, NEW) for p in prompts]
+        out = router.drain()
+        lagged = [list(map(int, out[r])) for r in rids]
+        events = tr.events()[n0:]
+        sched = Scheduler(cfg, params, **ONE_BUCKET)
+        rids = [sched.submit(p, NEW) for p in prompts]
+        out = sched.drain()
+        sync = [list(map(int, out[r])) for r in rids]
+        reference = [list(map(int, greedy_reference(cfg, params, p, NEW)))
+                     for p in prompts]
+    engine = router.replicas[0].engine
+    assert engine.allocator.in_use == sched.engine.allocator.in_use == 0
+    return dict(kind=request.param, engine=engine, events=events,
+                lagged=lagged, sync=sync, reference=reference)
+
+
+def test_greedy_streams_are_the_same_through_both_loops(served):
+    """Token for token: the lagged loop (a tick in flight, positions
+    written back at the next step's collect), the synchronous step, and
+    the full-sequence reference, over a backlog with multi-chunk prompts,
+    a padding job and inactive lanes."""
+    assert served["lagged"] == served["sync"] == served["reference"]
+    assert all(len(s) == NEW for s in served["lagged"])
+    chunks = [e.args for e in served["events"]
+              if e.name == "engine.chunk.launch"]
+    assert any(a["jobs"] == 3 and a["bucket"][0] == 4 for a in chunks)
+    assert len(chunks) >= 3  # the 20-token prompt alone takes three
+    lanes = [e.args["lanes"] for e in served["events"]
+             if e.name == "engine.decode.launch"]
+    assert min(lanes) < TICK_KW["n_slots"] and max(lanes) > 1
+
+
+def test_every_launch_of_a_served_backlog_moves_one_array(served):
+    """``arrays`` on the put spans is the counter that says the mechanism
+    engaged: 1 on every launch of both programs, for a plain engine and
+    for one whose chunk operand carries ``length``; ``bytes`` is the one
+    int32 matrix's."""
+    engine = served["engine"]
+    assert engine._per_request == (served["kind"] in ("zaya", "ling"))
+    puts = {prog: [e.args for e in served["events"]
+                   if e.name == f"engine.{prog}.put"]
+            for prog in ("chunk", "decode")}
+    assert puts["chunk"] and puts["decode"]
+    assert {a["arrays"] for prog in puts for a in puts[prog]} == {1}
+    n, w, c = engine.n_slots, engine.table_width, engine.chunk
+    assert {a["bytes"] for a in puts["decode"]} == {4 * n * (w + 2)}
+    scalars = 5 if engine._per_request else 4
+    buckets = [e.args["bucket"] for e in served["events"]
+               if e.name == "engine.chunk.launch"]
+    assert [a["bytes"] for a in puts["chunk"]] == [
+        4 * k * (c + wp + scalars) for k, wp in buckets]
+
+
+def test_the_operands_rows_are_the_jobs_and_the_lanes():
+    """The builders' layout, column for column: what the programs slice
+    apart is what the host wrote, bit for bit."""
+    cfg, params = setup(max_seq_len=64)
+    eng = PagedEngine(cfg, params, **TICK_KW)
+    assert eng.admit(2, 11, 4) and eng.admit(0, 3, 4)
+    seg = np.arange(1, 9, dtype=np.int32)
+    host = eng._chunk_operand(4, 2, [
+        ChunkJob(2, seg, 8, True, 2),
+        ChunkJob(0, seg[::-1].copy(), 0, False, 0)])
+    assert host.dtype == np.int32 and host.shape == (4, 8 + 2 + 4)
+    np.testing.assert_array_equal(host[0], [*seg, *eng.tables[2, :2],
+                                            8, 2, 1, 2])
+    np.testing.assert_array_equal(host[1], [*seg[::-1], *eng.tables[0, :2],
+                                            0, 0, 0, 0])
+    # padding jobs: trash tables, and the slot past the logits buffer
+    for row in host[2:]:
+        np.testing.assert_array_equal(
+            row, [0] * 8 + [TRASH_BLOCK] * 2 + [0, eng.n_slots, 0, 0])
+    positions = np.asarray([3, 0, 11, 7], np.int32)
+    active = np.asarray([True, False, True, False])
+    host = eng._decode_operand(positions, active)
+    assert host.dtype == np.int32 and host.shape == (4, eng.table_width + 2)
+    np.testing.assert_array_equal(host[:, :-2][active], eng.tables[active])
+    assert (host[:, :-2][~active] == TRASH_BLOCK).all()
+    np.testing.assert_array_equal(host[:, -2], positions)
+    np.testing.assert_array_equal(host[:, -1], [1, 0, 1, 0])
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "zaya"])
+def test_positions_are_counted_on_the_host(kind):
+    """``decode`` and ``decode_launch``/``decode_collect`` return the
+    launched positions plus one on the active lanes as a host array that
+    exists BEFORE the tokens are fetched: the program returns no
+    positions and the collect fetches the tokens alone."""
+    with jax.default_matmul_precision("highest"):
+        cfg, params = _toy(kind)
+        eng = PagedEngine(cfg, params, **TICK_KW)
+        prompt = _backlog(cfg)[0]
+        assert eng.admit(1, len(prompt), 4)
+        seg = np.zeros((8,), np.int32)
+        seg[:len(prompt)] = prompt
+        eng.run_chunks([ChunkJob(1, seg, 0, True, len(prompt) - 1)])
+        positions = np.asarray([7, len(prompt), 0, 3], np.int32)
+        active = np.asarray([False, True, False, False])
+        launched = positions.copy()
+        tokens, new = eng.decode(positions, active, jax.random.key(0))
+        assert isinstance(tokens, np.ndarray) and isinstance(new, np.ndarray)
+        np.testing.assert_array_equal(new, launched + active)
+        np.testing.assert_array_equal(positions, launched)  # not in place
+        dev_tokens, new2, launch = eng.decode_launch(new, active,
+                                                     jax.random.key(0))
+        assert isinstance(dev_tokens, jax.Array)
+        assert isinstance(new2, np.ndarray) and new2.dtype == np.int32
+        np.testing.assert_array_equal(new2, launched + 2 * active)
+        fetched = []
+        fetch = eng._fetch_tick
+        eng._fetch_tick = lambda t: fetched.append(t) or fetch(t)
+        tokens, new3 = eng.decode_collect(dev_tokens, new2, launch)
+        assert new3 is new2 and fetched == [dev_tokens]
+        assert isinstance(tokens, np.ndarray)
+    # the tick's outputs: cache, logits, tokens (and the expert counts)
+    out = jax.eval_shape(eng._decode(), eng.params, eng.cache, eng.logits,
+                         eng._decode_operand(positions, active),
+                         jax.random.key(0))
+    assert len(out) == (4 if eng._per_request else 3)
+    assert out[2].shape == (eng.n_slots,)
+
+
+def test_a_lane_armed_between_launch_and_collect_keeps_the_hosts_row():
+    """The write-back takes ONLY the lanes the tick decoded from the
+    engine's count: a row the host arms while the tick is in flight (an
+    adopted handoff chain, a restored swap) is not clobbered by the
+    launch's frozen copy."""
+    cfg, params = setup(max_seq_len=64)
+    sched = Scheduler(cfg, params, **TICK_KW)
+    sched.submit(np.arange(1, 6, dtype=np.int32), 4)
+    sched.step()  # prefilled, armed at 5 and decoded once: lane 0 at 6
+    assert sched.positions[0] == 6 and sched.remaining[0] > 0
+    sched.dispatch_tick()
+    handle = sched._pending_tick
+    assert handle.lanes == (0,) and isinstance(handle.positions, np.ndarray)
+    assert handle.positions is not sched.positions
+    assert handle.positions[0] == 7 and sched.positions[0] == 6
+    sched.positions[2] = 17  # armed since the launch
+    sched.collect_tick()
+    assert sched.positions[0] == 7 and sched.positions[2] == 17
+
+
+@pytest.mark.parametrize("execute", [True, False])
+@pytest.mark.parametrize("kind", ["gpt2", "zaya"])
+def test_traffic_behind_a_warm_up_adds_no_program(kind, execute):
+    """The warm-ups build their operand through the served call's
+    builder, so their avals cannot drift: behind ``warm_chunk`` and
+    ``warm_decode`` the served calls hit the entry the warm-up made
+    (``execute=True``) or, behind an AOT compile, make the one entry whose
+    operand the ``Compiled`` was lowered for; every armed call runs
+    under ``no_recompile`` (the transfer stays explicit)."""
+    from pytorch_distributed_tpu.analysis import no_recompile
+
+    with jax.default_matmul_precision("highest"):
+        cfg, params = _toy(kind)
+        sched = Scheduler(cfg, params, **ONE_BUCKET)
+        eng = sched.engine
+        assert eng.chunk_buckets() == [(4, 8)]
+        compiled = {"chunk": eng.warm_chunk(4, 8, execute=execute),
+                    "decode": eng.warm_decode(execute=execute)}
+        fns = {"chunk": eng._chunk_fn(4, 8), "decode": eng._decode()}
+        assert {p: f._cache_size() for p, f in fns.items()} == dict.fromkeys(
+            fns, 1 if execute else 0)
+        eng._chunk_fns[4, 8] = no_recompile(
+            fns["chunk"], warmup_steps=0 if execute else 1)
+        eng._decode_fn = no_recompile(
+            fns["decode"], warmup_steps=0 if execute else 1)
+        for p in _backlog(cfg):
+            sched.submit(p, NEW)
+        out = sched.drain()
+    assert len(out) == 5 and all(len(t) == NEW for t in out.values())
+    assert {p: f._cache_size() for p, f in fns.items()} == dict.fromkeys(
+        fns, 1)
+    assert eng._decode_fn.stats.calls > 2
+    assert eng._chunk_fns[4, 8].stats.calls > 2
+    if not execute:
+        # the AOT programs took the served operands' avals
+        host = {"chunk": eng._chunk_operand(4, 8),
+                "decode": eng._decode_operand(
+                    np.zeros((4,), np.int32), np.zeros((4,), bool))}
+        for prog, c in compiled.items():
+            packed = c.args_info[0][3]
+            assert (packed.shape, packed.dtype) == (
+                host[prog].shape, host[prog].dtype), prog

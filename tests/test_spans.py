@@ -413,11 +413,11 @@ def test_a_tick_that_launches_both_programs_splits_each_launch(tracer):
 
 
 def test_put_and_call_say_what_the_transfer_and_the_call_carry(tracer):
-    """``arrays`` and ``bytes`` are the host tuple's count and ``nbytes``
-    (six arrays a chunk launch, three a decode tick: latency, not
-    bandwidth), ``leaves`` the pytree leaves the call flattens:
-    parameters, cache, the logits buffer and the operands (the decode
-    tick's key among them)."""
+    """``arrays`` is 1 on every launch (the one packed int32 operand, a
+    row a job or a lane) and ``bytes`` its ``nbytes``: latency, not
+    bandwidth; ``leaves`` the pytree leaves the call flattens:
+    parameters, cache, the logits buffer and the operand (and the decode
+    tick's key)."""
     cfg, router = _tiny_router()
     engine = router.replicas[0].engine
     ticks = _both_program_ticks(tracer, router, router.step)
@@ -426,17 +426,17 @@ def test_put_and_call_say_what_the_transfer_and_the_call_carry(tracer):
     assert (n, w, engine.chunk) == (4, 8, 8)
     for tick in ticks:
         k, wp = _one(tick, "engine.chunk.launch").args["bucket"]
-        # tokens [k, 8], starts, tables [k, wp], slots, last_idx: int32;
-        # is_last: bool
+        # a job's row: tokens [8] | table [wp] | start, slot, is_last,
+        # last_idx
         assert _one(tick, "engine.chunk.put").args == {
-            "arrays": 6, "bytes": 4 * k * (8 + 1 + wp + 1 + 1) + k}
+            "arrays": 1, "bytes": 4 * k * (8 + wp + 4)}
         assert _one(tick, "engine.chunk.call").args == {
-            "leaves": resident + 6}
-        # positions [4] int32, active [4] bool, the masked table [4, 8]
+            "leaves": resident + 1}
+        # a lane's row: the masked table [8] | position, active
         assert _one(tick, "engine.decode.put").args == {
-            "arrays": 3, "bytes": 4 * n + n + 4 * n * w}
+            "arrays": 1, "bytes": 4 * n * (w + 2)}
         assert _one(tick, "engine.decode.call").args == {
-            "leaves": resident + 3 + 1}
+            "leaves": resident + 1 + 1}
     assert engine.tables.dtype == np.int32 and engine.tables.shape == (n, w)
 
 
@@ -606,7 +606,7 @@ def test_no_threaded_tracer_and_every_kernel_named():
 
 @pytest.mark.parametrize("name,args", [
     ("sched.admit", {}),
-    ("engine.chunk.put", {"arrays": 6, "bytes": 16_640}),
+    ("engine.chunk.put", {"arrays": 1, "bytes": 16_640}),
 ])
 def test_a_span_costs_microseconds(tracer, name, args):
     """Budget: under 3 us a span on this sandbox's CPU (a host count),

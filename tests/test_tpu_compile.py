@@ -24,6 +24,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental import topologies
 from jax.experimental.compilation_cache import compilation_cache
@@ -203,11 +204,9 @@ def test_decode_tick_module_is_named(v5e):
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
 
-    args = jax.tree.map(on_chip, (
-        eng.params, eng.cache, eng.logits, jnp.zeros((4,), jnp.int32),
-        jnp.zeros((4,), bool), jnp.zeros((4, eng.table_width), jnp.int32),
-        jax.random.key(0)))
-    lowered = eng._decode().lower(*args)
+    fn, operands = _tick_operands(eng)
+    lowered = fn.lower(*jax.tree.map(
+        on_chip, (eng.params, eng.cache, eng.logits) + operands))
     assert lowered.as_text().startswith("module @jit_decode_tick ")
     assert lowered.compile().as_text().startswith("HloModule jit_decode_tick,")
 
@@ -218,6 +217,20 @@ def test_decode_tick_module_is_named(v5e):
 #: layers and a small vocabulary to keep the compile short
 POOL_CELL = dict(heads=16, head_dim=64, slots=64, blocks=2561, block_len=16,
                  chunk=32, max_seq_len=1024)
+
+
+def _tick_operands(eng):
+    """The decode tick and what its call takes behind the logits buffer:
+    the engine's own packed operand (no lane active) and a key."""
+    n = eng.n_slots
+    return eng._decode(), (eng._decode_operand(
+        np.zeros((n,), np.int32), np.zeros((n,), bool)), jax.random.key(0))
+
+
+def _chunk_operands(eng, k, w):
+    """The (k, w) chunk program and its one packed operand (every job a
+    padding job), as ``warm_chunk`` builds it."""
+    return eng._chunk_fn(k, w), (eng._chunk_operand(k, w),)
 
 
 def _engine_program(v5e, program, cell=None, kv_dtype=None, bucket=(4, 8),
@@ -259,20 +272,12 @@ def _engine_program(v5e, program, cell=None, kv_dtype=None, bucket=(4, 8),
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one)
 
-    n, i32 = c["slots"], jnp.int32
     if program == "decode_tick":
-        fn = eng._decode()
-        args = (params, pool, eng.logits, jnp.zeros((n,), i32),
-                jnp.zeros((n,), bool),
-                jnp.zeros((n, eng.table_width), i32), jax.random.key(0))
+        fn, operands = _tick_operands(eng)
     else:
-        k, w = bucket
-        fn = eng._chunk_fn(k, w)
-        assert eng.chunk_program_name(k, w) == program
-        args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
-                jnp.zeros((k,), i32), jnp.zeros((k, w), i32),
-                jnp.zeros((k,), i32), jnp.zeros((k,), bool),
-                jnp.zeros((k,), i32))
+        fn, operands = _chunk_operands(eng, *bucket)
+        assert eng.chunk_program_name(*bucket) == program
+    args = (params, pool, eng.logits) + operands
     return fn.lower(*jax.tree.map(on_chip, args)), jax.tree.leaves(pool)
 
 
@@ -488,18 +493,20 @@ def test_the_tick_compiles_with_a_tile_of_blocks_a_grid_step(
                for x in reads), reads
 
 
-#: sha256 (12 hex digits) of the StableHLO text of chunk programs as the
-#: PARENT of PR 30 lowers them for a described v5e where the backend
-#: answers ``tpu`` (``_engine_program``: two layers, 512 tokens; computed
-#: from a clone of de6a59a with this file's helper). The chunk programs
+#: sha256 (12 hex digits) of the StableHLO text of chunk programs as they
+#: lower for a described v5e where the backend answers ``tpu``
+#: (``_engine_program``: two layers, 512 tokens). Taken again in PR 39,
+#: which gave every tick program ONE packed int32 operand where it took
+#: six (the model's part of the text did not move; PR 30's parent read
+#: 37ed79f1586a, b63225f480f7 and 738ae8911195). The chunk programs
 #: gather dense, so nothing in ``ops/paged_flash.py`` may move them: the
 #: serving cells' 30 + 2 compile-cache entries stay valid and
 #: ``prefill_chunk_device_ms`` is the control that does not move. A PR
 #: that means to change a chunk program records new digests here.
 CHUNK_DIGESTS = {
-    ("chat-backlog", (4, 8)): "37ed79f1586a",
-    ("chat-backlog", (1, 2)): "b63225f480f7",
-    ("reason-backlog", (2, 16)): "738ae8911195",
+    ("chat-backlog", (4, 8)): "99c83f9196da",
+    ("chat-backlog", (1, 2)): "19fad1292ee4",
+    ("reason-backlog", (2, 16)): "17063e500523",
 }
 
 
@@ -566,20 +573,12 @@ def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
         lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
                                    n_slots=n), params)
     one = SingleDeviceSharding(v5e.devices[0])
-    i32 = jnp.int32
     if program == "decode_tick":
-        fn = eng._decode()
-        args = (params, pool, eng.logits, jnp.zeros((n,), i32),
-                jnp.zeros((n,), bool), jnp.zeros((n, eng.table_width), i32),
-                jax.random.key(0))
+        fn, operands = _tick_operands(eng)
     else:
-        k, w = 4, 32
-        fn = eng._chunk_fn(k, w)
-        assert eng.chunk_program_name(k, w) == program
-        args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
-                jnp.zeros((k,), i32), jnp.zeros((k, w), i32),
-                jnp.zeros((k,), i32), jnp.zeros((k,), bool),
-                jnp.zeros((k,), i32), jnp.zeros((k,), i32))
+        fn, operands = _chunk_operands(eng, 4, 32)
+        assert eng.chunk_program_name(4, 32) == program
+    args = (params, pool, eng.logits) + operands
     compiled = fn.lower(*jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         args)).compile()
@@ -663,20 +662,12 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
     assert state.shape == (n + 1, 32, 128, 128) and state.dtype == jnp.float32
     assert latent.shape == (c["blocks"], c["block_len"], 640)
     one = SingleDeviceSharding(v5e.devices[0])
-    i32 = jnp.int32
     if program == "decode_tick":
-        fn = eng._decode()
-        args = (params, pool, eng.logits, jnp.zeros((n,), i32),
-                jnp.zeros((n,), bool), jnp.zeros((n, eng.table_width), i32),
-                jax.random.key(0))
+        fn, operands = _tick_operands(eng)
     else:
-        k, w = 4, 128
-        fn = eng._chunk_fn(k, w)
-        assert eng.chunk_program_name(k, w) == program
-        args = (params, pool, eng.logits, jnp.zeros((k, c["chunk"]), i32),
-                jnp.zeros((k,), i32), jnp.zeros((k, w), i32),
-                jnp.zeros((k,), i32), jnp.zeros((k,), bool),
-                jnp.zeros((k,), i32), jnp.zeros((k,), i32))
+        fn, operands = _chunk_operands(eng, 4, 128)
+        assert eng.chunk_program_name(4, 128) == program
+    args = (params, pool, eng.logits) + operands
     compiled = fn.lower(*jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         args)).compile()
