@@ -222,11 +222,11 @@ class DroplessMoE(nn.Module):
     whose expert this shard does not hold, sort behind every group and join
     none. A token's output is the weighted sum of its pairs' rows.
 
-    Two routers, both in float32 at the HIGHEST matrix precision whatever
+    Three routers, all in float32 at the HIGHEST matrix precision whatever
     the compute dtype (on a TPU a float32 product is otherwise one bfloat16
     pass: a choice between near-equal scores is where a rounding shows,
-    and a router is a few hundredths of a layer's arithmetic), both with a
-    ``router_bias`` that enters the choice and not the weight:
+    and a router is a few hundredths of a layer's arithmetic), the first
+    two with a ``router_bias`` that enters the choice and not the weight:
 
     ``router="mlp"`` (top-1): a small MLP on a ``router_dim``-wide
     projection of the token that ADDS the previous layer's router state
@@ -237,13 +237,20 @@ class DroplessMoE(nn.Module):
     an expert; the ``n_experts`` in ``n_group`` groups, a group scored by
     the sum of its two best, the ``topk_group`` best groups open, the
     ``top_k`` best experts inside them; weights ``routed_scale * s_i /
-    sum_sel s_j``. ``held`` ``(lo, hi)``: this shard holds experts ``[lo,
-    hi)`` of the ``n_experts`` it scores, its matrices are theirs alone,
-    and the output is ITS part of the routed sum; nothing stands in for the
-    shards that hold the rest. ``shared_dim``: a shared expert of that many
-    features, added for every token.
+    sum_sel s_j``.
 
-    Returns ``(out, router_state)`` (the state None behind the sigmoid
+    ``router="softmax"`` (``top_k`` >= 1): one matrix and a softmax over
+    all ``n_experts``, the ``top_k`` most probable, weights ``p_i / sum_sel
+    p_j``; no groups, no bias, no scale.
+
+    Behind either one-matrix router: ``held`` ``(lo, hi)``: this shard
+    holds experts ``[lo, hi)`` of the ``n_experts`` it scores, its matrices
+    are theirs alone, and the output is ITS part of the routed sum; nothing
+    stands in for the shards that hold the rest. ``shared_dim``: a shared
+    expert of that many features, added for every token, scaled by a scalar
+    ``sigmoid(x w)`` a token where ``shared_gate``.
+
+    Returns ``(out, router_state)`` (the state None behind a one-matrix
     router); sows the pairs each held expert took (``[experts held]``
     int32) as ``moe_stats/expert_tokens``.
     """
@@ -260,6 +267,7 @@ class DroplessMoE(nn.Module):
     routed_scale: float = 1.0
     shared_dim: Optional[int] = None
     held: Optional[tuple] = None
+    shared_gate: bool = False
 
     @nn.nowrap
     def _f32_dense(self, width, name):
@@ -306,6 +314,14 @@ class DroplessMoE(nn.Module):
         w = jnp.take_along_axis(scores, choice, axis=-1)
         return choice, self.routed_scale * w / jnp.sum(w, -1, keepdims=True)
 
+    @nn.nowrap
+    def _route_softmax(self, xf):
+        """(chosen experts [T, k], their weights [T, k])."""
+        probs = jax.nn.softmax(self._f32_dense(self.n_experts, "router")(
+            xf.astype(jnp.float32)), axis=-1)
+        w, choice = jax.lax.top_k(probs, self.top_k)
+        return choice, w / jnp.sum(w, -1, keepdims=True)
+
     @nn.compact
     def __call__(self, x, router_state=None, live=None):
         b, l, d = x.shape
@@ -323,7 +339,9 @@ class DroplessMoE(nn.Module):
                 choice = jnp.where(live.reshape(t), choice, e)
                 gate = jnp.where(live.reshape(t), gate, 0.0)
         else:
-            choice, gate = self._route_sigmoid(xf)
+            choice, gate = (self._route_sigmoid(xf)
+                            if self.router == "sigmoid"
+                            else self._route_softmax(xf))
             choice, gate = choice.reshape(t * k) - lo, gate.reshape(t * k)
             joins = (choice >= 0) & (choice < e)
             if live is not None:
@@ -357,9 +375,14 @@ class DroplessMoE(nn.Module):
             sf = self.shared_dim
             gu = nn.Dense(2 * sf, use_bias=False, dtype=self.dtype,
                           name="shared_gate_up")(xf)
-            out = out + nn.Dense(d, use_bias=False, dtype=self.dtype,
-                                 name="shared_down")(
+            shared = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                              name="shared_down")(
                 nn.silu(gu[:, :sf]) * gu[:, sf:])
+            if self.shared_gate:
+                shared = (shared.astype(f32) * jax.nn.sigmoid(nn.Dense(
+                    1, use_bias=False, dtype=self.dtype,
+                    name="shared_gate")(xf).astype(f32))).astype(self.dtype)
+            out = out + shared
         if live is not None:
             out = jnp.where(live.reshape(t, 1), out, jnp.zeros((), out.dtype))
         return out.reshape(b, l, d), (

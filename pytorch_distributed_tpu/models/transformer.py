@@ -175,8 +175,9 @@ class TransformerConfig:
     # block's. "mla" (``MLAttention``): latent attention, whose cache is
     # ONE row a token for all heads (``kv_lora_rank`` normed latent values
     # and ``qk_rope_head_dim`` rotated key dims). ``layer_group_size`` > 0
-    # mixes them in one stack: with ``attn_kind="kda"`` every
-    # ``layer_group_size``-th layer is "mla" (``attn_kind_at``).
+    # mixes kinds in one stack: with a linear ``attn_kind`` ("kda", "gdn")
+    # every ``layer_group_size``-th layer is ``full_attn_kind``, "mla"
+    # unless told (``attn_kind_at``).
     kv_lora_rank: Optional[int] = None
     qk_rope_head_dim: Optional[int] = None
     layer_group_size: int = 0
@@ -197,16 +198,44 @@ class TransformerConfig:
     moe_shared_dim: Optional[int] = None
     experts_held: Optional[tuple] = None
     first_k_dense_replace: int = 0
+    # A second linear kind and a second full kind in a mixed stack.
+    # ``attn_kind="gdn"`` (``GatedDeltaNet``): the delta rule with ONE
+    # decay a head a token, unbounded below; ``linear_num_heads`` state
+    # (value) heads of ``linear_head_dim``, served in equal groups by
+    # ``linear_num_key_heads`` q/k heads (None: the attention's
+    # ``num_heads`` / one key head a state head / ``head_dim``); its cache
+    # is ``KDAttention``'s two per-slot leaves. ``full_attn_kind``: what
+    # every ``layer_group_size``-th layer of a mixed stack is, "mla" or
+    # "mha" (``Attention`` over a real K/V pool: ``num_heads``,
+    # ``num_kv_heads`` and ``head_dim`` describe THAT layer). Three options
+    # of ``Attention``: an inner width of its own (``head_dim``),
+    # ``qk_norm`` (an RMSNorm a head on q and on k, one learned scale of
+    # ``head_dim`` each, before RoPE; RoPE turns the first ``rotary_share``
+    # of a head) and ``attn_gate`` (the q projection is doubled a head, and
+    # the sigmoid of its second half scales the attention's output
+    # elementwise before the output projection).
+    full_attn_kind: str = "mla"
+    linear_num_heads: Optional[int] = None
+    linear_num_key_heads: Optional[int] = None
+    linear_head_dim: Optional[int] = None
+    qk_norm: bool = False
+    attn_gate: bool = False
+    # The third dropless router, ``moe_router="softmax"``: one matrix, a
+    # softmax over all ``n_experts``, the ``moe_top_k`` largest, their
+    # weights renormalised to sum to one; no groups, bias or scale.
+    # ``moe_shared_gate``: the shared expert's output is scaled by a
+    # scalar ``sigmoid(x w)`` a token.
+    moe_shared_gate: bool = False
 
     def __post_init__(self):
-        if self.attn_kind not in ("mha", "cca", "kda", "mla"):
+        if self.attn_kind not in ("mha", "cca", "kda", "mla", "gdn"):
             raise ValueError(
-                f"attn_kind {self.attn_kind!r} must be 'mha', 'cca', 'kda' "
-                "or 'mla'")
+                f"attn_kind {self.attn_kind!r} must be 'mha', 'cca', 'kda', "
+                "'mla' or 'gdn'")
         if self.experts_held is not None:  # a JSON list hashes as a tuple
             self.experts_held = tuple(int(i) for i in self.experts_held)
         self._check_linear_and_latent()
-        self._check_sigmoid_router()
+        self._check_expert_router()
         if self.moe_kind not in ("capacity", "dropless"):
             raise ValueError(
                 f"moe_kind {self.moe_kind!r} must be 'capacity' or "
@@ -233,12 +262,14 @@ class TransformerConfig:
                     "sequence shard does not hold, and the tail is one "
                     f"row a request (tp_size {self.tp_size}, ut_steps "
                     f"{self.ut_steps}, attention {self.attention!r})")
-        elif self.attn_kind == "mha" and self.head_dim is not None and (
-                self.head_dim * self.num_heads != self.embed_dim):
+        if (self.qk_norm or self.attn_gate) and "mha" not in self.attn_kinds:
             raise ValueError(
-                f"head_dim {self.head_dim} x num_heads {self.num_heads} "
-                f"!= embed_dim {self.embed_dim}: an inner width of its "
-                "own is attn_kind='cca''s, 'kda''s and 'mla''s")
+                "qk_norm and attn_gate are options of 'mha' layers "
+                f"(Attention); this stack's are {self.attn_kinds}")
+        if self.attn_gate and self.num_kv_heads is None:
+            raise ValueError(
+                "attn_gate doubles the separate q projection a head: it "
+                "needs num_kv_heads (= num_heads for no grouping)")
         if self.moe_kind == "dropless" and self.moe_router == "mlp":
             if not self.n_experts or self.moe_top_k != 1:
                 raise ValueError(
@@ -369,16 +400,23 @@ class TransformerConfig:
             raise ValueError(f"dropout must be in [0, 1), got {self.dropout}")
 
     def _check_linear_and_latent(self):
-        """What ``attn_kind`` "kda" and "mla" can run, and the keys that
-        describe them only."""
+        """What the linear kinds ("kda", "gdn") and "mla" can run, and the
+        keys that describe them only."""
         kinds = set(self.attn_kinds)
+        linear = self.attn_kind in ("kda", "gdn")
         if self.layer_group_size < 0 or (
-                self.layer_group_size and self.attn_kind != "kda"):
+                self.layer_group_size and not linear):
             raise ValueError(
-                f"layer_group_size {self.layer_group_size} mixes 'mla' "
-                "layers into a stack of attn_kind='kda' (every "
+                f"layer_group_size {self.layer_group_size} mixes full "
+                "layers into a stack of attn_kind='kda' or 'gdn' (every "
                 "layer_group_size-th layer), got attn_kind "
                 f"{self.attn_kind!r}")
+        if self.full_attn_kind not in ("mla", "mha") or (
+                self.full_attn_kind != "mla" and not self.layer_group_size):
+            raise ValueError(
+                f"full_attn_kind {self.full_attn_kind!r} must be 'mla' or "
+                "'mha', and names every layer_group_size-th layer of a "
+                f"mixed stack (layer_group_size {self.layer_group_size})")
         if "mla" in kinds:
             if not self.kv_lora_rank or not self.qk_rope_head_dim or (
                     self.qk_rope_head_dim % 2):
@@ -392,15 +430,29 @@ class TransformerConfig:
             raise ValueError(
                 "kv_lora_rank and qk_rope_head_dim describe latent "
                 "attention ('mla' layers) only")
-        if not kinds & {"kda", "mla"}:
+        described = (self.linear_num_heads, self.linear_num_key_heads,
+                     self.linear_head_dim)
+        if "gdn" not in kinds:
+            if described != (None, None, None):
+                raise ValueError(
+                    "linear_num_heads, linear_num_key_heads and "
+                    "linear_head_dim describe attn_kind='gdn' only")
+        elif (min(self.linear_heads, self.linear_key_heads,
+                  self.linear_head_width) < 1
+              or self.linear_heads % self.linear_key_heads):
+            raise ValueError(
+                f"the gated delta rule's {self.linear_heads} state heads "
+                f"of {self.linear_head_width} must be whole groups of its "
+                f"{self.linear_key_heads} key heads")
+        if not kinds & {"kda", "mla", "gdn"}:
             return
-        if self.head_dim is None or self.num_kv_heads is not None or (
-                self.pos_embedding != "rope"):
+        if self.head_dim is None or self.pos_embedding != "rope" or (
+                self.num_kv_heads is not None and "mha" not in kinds):
             raise ValueError(
                 f"attn_kind {self.attn_kind!r} needs head_dim (the inner "
                 "width is num_heads * head_dim), no num_kv_heads (a state "
-                "or a latent row serves every head) and "
-                "pos_embedding='rope'")
+                "or a latent row serves every head; grouped K/V heads are "
+                "a full_attn_kind='mha' layer's) and pos_embedding='rope'")
         if (self.tp_size > 1 or self.ut_steps > 1
                 or self.attention != "dense"):
             raise ValueError(
@@ -411,26 +463,37 @@ class TransformerConfig:
                 f"{self.tp_size}, ut_steps {self.ut_steps}, attention "
                 f"{self.attention!r})")
 
-    def _check_sigmoid_router(self):
-        """The sigmoid router's keys, and that nothing else carries them."""
-        if self.moe_router not in ("mlp", "sigmoid"):
+    def _check_expert_router(self):
+        """The one-matrix routers' keys ("sigmoid", "softmax"), and that
+        nothing else carries them."""
+        if self.moe_router not in ("mlp", "sigmoid", "softmax"):
             raise ValueError(
-                f"moe_router {self.moe_router!r} must be 'mlp' or 'sigmoid'")
+                f"moe_router {self.moe_router!r} must be 'mlp', 'sigmoid' "
+                "or 'softmax'")
+        groups = (self.moe_n_group, self.moe_topk_group,
+                  self.moe_routed_scale)
         if self.moe_router == "mlp":
-            if (self.moe_n_group, self.moe_topk_group, self.moe_routed_scale,
-                    self.moe_shared_dim, self.experts_held,
-                    self.first_k_dense_replace) != (1, 1, 1.0, None, None, 0):
+            if groups + (self.moe_shared_dim, self.experts_held,
+                         self.first_k_dense_replace, self.moe_shared_gate
+                         ) != (1, 1, 1.0, None, None, 0, False):
                 raise ValueError(
                     "moe_n_group, moe_topk_group, moe_routed_scale, "
-                    "moe_shared_dim, experts_held and first_k_dense_replace "
-                    "describe moe_router='sigmoid' only")
+                    "moe_shared_dim, moe_shared_gate, experts_held and "
+                    "first_k_dense_replace describe the one-matrix routers "
+                    "(moe_router 'sigmoid', 'softmax') only")
             return
+        if self.moe_router == "softmax" and groups != (1, 1, 1.0):
+            raise ValueError(
+                "moe_n_group, moe_topk_group and moe_routed_scale describe "
+                "moe_router='sigmoid' only: the softmax router has no "
+                "groups and its weights sum to one")
         e, g = self.n_experts, self.moe_n_group
         if self.moe_kind != "dropless" or not e or self.moe_dim is None or (
                 self.router_dim is not None or self.moe_every != 1):
             raise ValueError(
-                "moe_router='sigmoid' is moe_kind='dropless' over "
-                "n_experts > 0 experts of moe_dim features, an expert layer "
+                f"moe_router={self.moe_router!r} is moe_kind='dropless' "
+                "over n_experts > 0 experts of moe_dim features (the "
+                "'sigmoid' and 'softmax' routers alike), an expert layer "
                 "in every block from first_k_dense_replace on (moe_every "
                 "1), and no router_dim (one matrix, no MLP)")
         if g < 1 or e % g or not 1 <= self.moe_topk_group <= g or not (
@@ -454,16 +517,21 @@ class TransformerConfig:
         if self.moe_shared_dim is not None and self.moe_shared_dim < 1:
             raise ValueError(
                 f"moe_shared_dim must be >= 1, got {self.moe_shared_dim}")
+        if self.moe_shared_gate and self.moe_shared_dim is None:
+            raise ValueError(
+                "moe_shared_gate scales the shared expert: it needs "
+                "moe_shared_dim")
         if not 0 <= self.first_k_dense_replace <= self.num_layers:
             raise ValueError(
                 f"first_k_dense_replace {self.first_k_dense_replace} must "
                 f"lie in [0, num_layers {self.num_layers}]")
 
     def attn_kind_at(self, layer: int) -> str:
-        """The attention of layer ``layer``: ``attn_kind``, but "mla" at
-        every ``layer_group_size``-th layer of a mixed stack."""
+        """The attention of layer ``layer``: ``attn_kind``, but
+        ``full_attn_kind`` at every ``layer_group_size``-th layer of a
+        mixed stack."""
         if self.layer_group_size and (layer + 1) % self.layer_group_size == 0:
-            return "mla"
+            return self.full_attn_kind
         return self.attn_kind
 
     def moe_at(self, layer: int) -> bool:
@@ -482,8 +550,27 @@ class TransformerConfig:
     def slot_state(self) -> bool:
         """Whether the cache holds state that is a REQUEST's and not a
         block's (``serving.kv_pool.SLOT_LEAVES``): "cca"'s tail, "kda"'s
-        state and convolution inputs."""
-        return bool({"cca", "kda"} & set(self.attn_kinds))
+        state and convolution inputs, and "gdn"'s."""
+        return bool({"cca", "kda", "gdn"} & set(self.attn_kinds))
+
+    @property
+    def linear_heads(self) -> int:
+        """State (value) heads of a "gdn" layer."""
+        return (self.num_heads if self.linear_num_heads is None
+                else self.linear_num_heads)
+
+    @property
+    def linear_key_heads(self) -> int:
+        """q/k heads of a "gdn" layer, each serving an equal group of its
+        state heads."""
+        return (self.linear_heads if self.linear_num_key_heads is None
+                else self.linear_num_key_heads)
+
+    @property
+    def linear_head_width(self) -> int:
+        """Features of one "gdn" head (keys and values alike)."""
+        return (self.head_width if self.linear_head_dim is None
+                else self.linear_head_dim)
 
     @property
     def latent_row_width(self) -> int:
@@ -571,7 +658,7 @@ class Attention(nn.Module):
                  block_tables=None, pass_index=None):
         cfg = self.config
         b, l, e = x.shape
-        head_dim = e // cfg.num_heads
+        head_dim = cfg.head_width
         looped = cfg.ut_steps > 1
         if looped and pass_index is None:
             raise ValueError(
@@ -598,14 +685,36 @@ class Attention(nn.Module):
             kv_heads_local = cfg.num_kv_heads // cfg.tp_size
             kv_group = heads_local // kv_heads_local
             q = nn.DenseGeneral(
-                (heads_local, head_dim), dtype=cfg.dtype, name="q",
-                use_bias=cfg.use_bias,
+                (heads_local, (2 if cfg.attn_gate else 1) * head_dim),
+                dtype=cfg.dtype, name="q", use_bias=cfg.use_bias,
             )(x)
+            if cfg.attn_gate:  # a head's columns are [q | gate]
+                q, gate = q[..., :head_dim], q[..., head_dim:]
             kv = nn.DenseGeneral(
                 (2, kv_heads_local, head_dim), dtype=cfg.dtype, name="kv",
                 use_bias=cfg.use_bias,
             )(x)
             k, v = kv[:, :, 0], kv[:, :, 1]  # [B, L, H_kv_loc, D]
+
+        if cfg.qk_norm:
+            # an RMSNorm a head, one learned scale of head_dim for every
+            # head, float32 statistics; before RoPE and the cache write
+            q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                           name="q_norm")(q).astype(cfg.dtype)
+            k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                           name="k_norm")(k).astype(cfg.dtype)
+
+        def project(out):
+            """The output projection, behind the gate where there is one
+            (row-parallel and bias-free: the TP psum must not add a bias
+            tp times)."""
+            if cfg.attn_gate:
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
+            return nn.DenseGeneral(
+                e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+                name="proj",
+            )(out)
 
         if cfg.pos_embedding == "rope":
             # Rotate BEFORE the cache write and before any attention path
@@ -622,8 +731,8 @@ class Attention(nn.Module):
                     "TransformerLM and train.pp.PPStage provide it"
                 )
             rpos = positions[None] if positions.ndim == 1 else positions
-            q = _rope_rotate(q, rpos, cfg.rope_theta)
-            k = _rope_rotate(k, rpos, cfg.rope_theta)
+            q = _rope_rotate(q, rpos, cfg.rope_theta, cfg.rotary_share)
+            k = _rope_rotate(k, rpos, cfg.rope_theta, cfg.rotary_share)
 
         if block_tables is not None:
             # Paged serving (serving/): the cache is a block POOL
@@ -747,10 +856,7 @@ class Attention(nn.Module):
                 )
                 ck.value = k_pool.reshape(stored)
                 cv.value = v_pool.reshape(stored)
-            out = nn.DenseGeneral(
-                e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
-                name="proj",
-            )(out)
+            out = project(out)
             if cfg.model_axis:
                 from pytorch_distributed_tpu.parallel.tensor import tp_reduce
 
@@ -839,9 +945,7 @@ class Attention(nn.Module):
                 out = jnp.einsum(
                     "bhqk,bkhd->bqhd", p, v_seen.astype(jnp.float32)
                 ).astype(cfg.dtype)
-            out = nn.DenseGeneral(
-                e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="proj"
-            )(out)
+            out = project(out)
             if cfg.model_axis:
                 from pytorch_distributed_tpu.parallel.tensor import tp_reduce
 
@@ -932,11 +1036,7 @@ class Attention(nn.Module):
             )
         else:
             raise ValueError(f"unknown attention {self.config.attention!r}")
-        # Row-parallel output projection: bias-free so the TP psum does not
-        # add the bias tp times.
-        out = nn.DenseGeneral(
-            e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype, name="proj"
-        )(out)
+        out = project(out)
         if cfg.model_axis:
             from pytorch_distributed_tpu.parallel.tensor import tp_reduce
 
@@ -1170,7 +1270,198 @@ class CCAttention(nn.Module):
         return out
 
 
-class KDAttention(nn.Module):
+def delta_rule_update(s, q_t, k_t, v_t, a_t, b_t):
+    """One token of the delta rule: ``s`` [B, H, D, D], ``q_t``, ``k_t``,
+    ``v_t`` [B, H, D], the decay ``a_t`` [B, H, D] a channel or [B, H, 1] a
+    head, beta [B, H]. Returns (the new state, ``o_t``). Float32 multiplies
+    and sums on the vector unit, and the OLD state read twice and the new
+    one written once: what the new state shows the query is what the
+    decayed old one shows it plus the written row's share, ``S'^T q = S^T
+    (alpha * q) + (v - seen) (beta k . q)``, so both reductions read ``s``
+    in one pass and nothing reads the state just written."""
+    seen = jnp.sum((k_t * a_t)[..., None] * s, axis=-2)
+    read = jnp.sum((q_t * a_t)[..., None] * s, axis=-2)
+    write = b_t[..., None] * k_t  # [B, H, D]: beta k
+    new = v_t - seen
+    s = a_t[..., None] * s + write[..., None] * new[..., None, :]
+    return s, read + new * jnp.sum(write * q_t, -1, keepdims=True)
+
+
+def delta_rule_blocks(s0, q, k, v, g, beta, block: int):
+    """The delta rule over a sequence, ``block`` positions a step, from
+    state ``s0`` [B, H, D, D]: ``q``, ``k``, ``v`` are [B, L, H, D], the
+    log decay ``g`` [B, L, H, D] a channel or [B, L, H, 1] a head (the
+    scalar decay is the special case: every product below broadcasts it),
+    ``beta`` [B, L, H], all float32. Returns (the state after position L -
+    1, every position's ``o`` [B, L, H, D]). The same function as a token
+    at a time: inside a block, with ``G_t`` the running sum of ``g`` and
+    ``u_t = beta_t (v_t - k_t^T Diag(alpha_t) S_{t-1})`` the row a token
+    writes,
+
+        S_t = Diag(e^{G_t}) S_0 + sum_{s<=t} Diag(e^{G_t - G_s}) k_s u_s^T
+        u_t = beta_t (v_t - (k_t e^{G_t})^T S_0
+                      - sum_{s<t} (k_t e^{G_t - G_s} . k_s) u_s)
+
+    so the ``u`` of a block solve a unit lower-triangular system by
+    forward substitution, the state is read and written once a block and
+    the products with it run on the matrix unit at the highest precision.
+    Every decay is a ratio of a later to an earlier position, at most 1:
+    nothing overflows however strong the gate."""
+    b, l, h, d = q.shape
+    c = min(block, l)
+    n = -(-l // c)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    high = jax.lax.Precision.HIGHEST
+
+    def blocks(x):  # [B, L, ...] -> [n, B, c, ...], zeros behind L
+        x = jnp.pad(x, ((0, 0), (0, n * c - l)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((b, n, c) + x.shape[2:]), 1, 0)
+
+    def step(s, xs):
+        q, k, v, g, beta = xs
+        run = jnp.cumsum(g, axis=1)  # G_t [B, c, H, D]
+        # e^{G_t - G_s} for s <= t, 0 above the diagonal [B, t, s, H, D]
+        decay = jnp.exp(jnp.where(
+            lower[None, :, :, None, None],
+            run[:, :, None] - run[:, None, :], -jnp.inf))
+        kk = jnp.sum(k[:, :, None] * k[:, None, :] * decay, axis=-1)
+        qk = jnp.sum(q[:, :, None] * k[:, None, :] * decay, axis=-1)
+        grown = jnp.exp(run)
+        rhs = beta[..., None] * (v - jnp.einsum(
+            "bthk,bhkv->bthv", k * grown, s, precision=high))
+        below = beta[:, :, None] * jnp.where(
+            jnp.tril(lower, -1)[None, :, :, None], kk, 0.0)
+        u = jnp.zeros_like(rhs)
+        for t in range(c):  # forward substitution, a row a step
+            u = u.at[:, t].set(rhs[:, t] - jnp.sum(
+                below[:, t][..., None] * u, axis=1))
+        o = jnp.einsum("bthk,bhkv->bthv", q * grown, s, precision=high
+                       ) + jnp.sum(qk[..., None] * u[:, None], axis=2)
+        s = grown[:, -1][..., None] * s + jnp.einsum(
+            "bshk,bshv->bhkv", k * jnp.exp(run[:, -1:] - run), u,
+            precision=high)
+        return s, o
+
+    s1, o = jax.lax.scan(step, s0,
+                         tuple(blocks(x) for x in (q, k, v, g, beta)))
+    return s1, jnp.moveaxis(o, 0, 1).reshape(b, n * c, h, d)[:, :l]
+
+
+class _SlotStateAttention(nn.Module):
+    """What the delta-rule layers (``KDAttention``, ``GatedDeltaNet``)
+    share: a request carries from one call to the next no K/V row but a
+    float32 STATE ``[H, D, D]`` (``cache/state``) and the convolutions'
+    last ``TAPS - 1`` inputs (``cache/conv``): one row a request, zero for
+    a row that starts at position 0 whatever the row held, advanced over
+    the row's REAL positions only (``lengths``). In the paged layout the
+    leaves are ``[n_slots + 1, ...]``. A chunk program reads and writes the
+    rows ``slots`` names (the last row is the trash row of padding jobs)
+    and runs the recurrence ``BLOCK`` positions a step
+    (``delta_rule_blocks``); a decode tick's row ``i`` IS slot ``i`` (the
+    engine's tick has a lane a slot), so the tick updates the leaves where
+    they lie, once read and once written, and a lane that is not live
+    (``lengths`` 0: inactive, or in mid-prefill) keeps what it held."""
+
+    #: taps of the depthwise convolutions: the current token and three
+    #: before it
+    TAPS = 4
+    #: positions one step of the sequence recurrence takes
+    BLOCK = 16
+
+    config: TransformerConfig
+    deterministic: bool = True
+    decode: bool = False
+    prefill: bool = False
+
+    @nn.nowrap
+    def _held(self, b, l, position_offset, block_tables, slots, lengths,
+              heads, d, conv_width):
+        """The state and the convolution inputs this call starts from:
+        ``(s0, c0, real, keep)``, ``real`` each row's real positions and
+        ``keep(window, s1)`` what writes the row's new state and last
+        inputs back (``window``: ``c0`` in front of this call's inputs)."""
+        cfg, f32, taps = self.config, jnp.float32, self.TAPS
+        cached = self.decode or self.prefill
+        paged = block_tables is not None
+        if paged and not cached:
+            raise ValueError(
+                "block_tables= is the paged SERVING cache layout; it "
+                "requires decode or prefill mode")
+        pos = jnp.asarray(position_offset, jnp.int32)
+        state_var = conv_var = None
+        tick = paged and self.decode
+        if cached:
+            if paged:
+                if pos.ndim != 1 or lengths is None or (
+                        slots is None and not tick):
+                    raise ValueError(
+                        f"paged {type(self).__name__} takes a [B] "
+                        "position_offset vector and lengths= (each row's "
+                        "real length), and a chunk program slots= (each "
+                        "row's slot)")
+                state_var = self.variable("cache", "state", _need_pool)
+                conv_var = self.variable("cache", "conv", _need_pool)
+                if tick and state_var.value.shape[0] != b + 1:
+                    raise ValueError(
+                        "a paged decode tick has a lane a slot: row i reads "
+                        f"and writes slot i's state, got {b} rows over "
+                        f"{state_var.value.shape[0] - 1} slots")
+                rows = slice(0, b) if tick else slots
+                held_s, held_c = state_var.value[rows], conv_var.value[rows]
+            else:
+                state_var = self.variable(
+                    "cache", "state",
+                    lambda: jnp.zeros((b, heads, d, d), f32))
+                conv_var = self.variable(
+                    "cache", "conv",
+                    lambda: jnp.zeros((b, taps - 1, conv_width), cfg.dtype))
+                held_s, held_c = state_var.value, conv_var.value
+            starts = pos if pos.ndim == 1 else jnp.full((b,), pos)
+            fresh = starts == 0
+            s0 = jnp.where(fresh[:, None, None, None], 0.0, held_s)
+            c0 = jnp.where(fresh[:, None, None],
+                           jnp.zeros((), held_c.dtype), held_c)
+        else:
+            s0 = jnp.zeros((b, heads, d, d), f32)
+            c0 = jnp.zeros((b, taps - 1, conv_width), cfg.dtype)
+        real = (jnp.full((b,), l, jnp.int32) if lengths is None
+                else lengths.astype(jnp.int32))
+
+        def keep(window, s1):
+            if state_var is None:
+                return
+            # the last T - 1 inputs behind the row's last REAL position
+            at = real[:, None] + jnp.arange(taps - 1)[None, :]
+            c1 = jnp.take_along_axis(window, at[:, :, None], axis=1)
+            live = real > 0
+            s1 = jnp.where(live[:, None, None, None], s1, held_s)
+            c1 = jnp.where(live[:, None, None], c1.astype(held_c.dtype),
+                           held_c)
+            if paged:
+                state_var.value = state_var.value.at[rows].set(s1)
+                conv_var.value = conv_var.value.at[rows].set(c1)
+            else:
+                state_var.value, conv_var.value = s1, c1
+
+        return s0, c0, real, keep
+
+    @nn.nowrap
+    def _convolved(self, c0, pre, conv_w):
+        """(``c0`` in front of ``pre``, the SiLU of the depthwise causal
+        convolution of ``TAPS`` taps over it, float32 [B, L, channels])."""
+        l, f32 = pre.shape[1], jnp.float32
+        window = jnp.concatenate([c0, pre], axis=1)
+        return window, nn.silu(sum(conv_w[j].astype(f32)
+                                   * window[:, j:j + l].astype(f32)
+                                   for j in range(self.TAPS)))
+
+
+def _unit(t):
+    """``t`` L2-normed over its last axis (epsilon 1e-6 under the root)."""
+    return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+
+
+class KDAttention(_SlotStateAttention):
     """Delta-rule linear attention with a gate a channel (``attn_kind=
     "kda"``; Kimi Delta Attention, arXiv:2510.26692, as
     ``perfbench/references/ling.py`` writes it down).
@@ -1189,93 +1480,14 @@ class KDAttention(nn.Module):
     sigmoid(x_t W_b)`` a head. The output is ``o_t`` RMS-normed a head,
     gated by ``sigmoid(x_t W_g)`` and projected back. The state's products
     are elementwise float32 multiplies and sums, never a matrix unit's
-    rounded passes.
-
-    What a request carries from one call to the next is no K/V row but the
-    state (``cache/state``, float32) and the convolutions' last ``T - 1``
-    inputs (``cache/conv``): one row a request, zero for a row that starts
-    at position 0 whatever the row held, advanced over the row's REAL
-    positions only (``lengths``). In the paged layout the leaves are
-    ``[n_slots + 1, ...]``. A chunk program reads and writes the rows
-    ``slots`` names (the last row is the trash row of padding jobs) and
-    runs the recurrence ``BLOCK`` positions a step (``_blocks``); a decode
-    tick's row ``i`` IS slot ``i`` (the engine's tick has a lane a slot),
-    so the tick updates the leaves where they lie, once read and once
-    written, and a lane that is not live (``lengths`` 0: inactive, or in
-    mid-prefill) keeps what it held.
+    rounded passes. The state and the convolutions' last inputs are a
+    request's (``_SlotStateAttention``); ``TAPS`` is the published
+    ``short_conv_kernel_size``.
     """
 
-    #: taps of each depthwise convolution (the published
-    #: ``short_conv_kernel_size``): the current token and three before it
-    TAPS = 4
     #: the gate's lower bound (the published ``kda_lower_bound``): a
     #: channel's decay a token lies in ``(exp(LOWER_BOUND), 1)``
     LOWER_BOUND = -5.0
-    #: positions one step of the sequence recurrence takes (``_blocks``)
-    BLOCK = 16
-
-    config: TransformerConfig
-    deterministic: bool = True
-    decode: bool = False
-    prefill: bool = False
-
-    @nn.nowrap
-    def _blocks(self, s0, q, k, v, g, beta):
-        """The recurrence over a sequence, ``BLOCK`` positions a step, from
-        state ``s0`` [B, H, D, D]: ``q``, ``k``, ``v`` and the log decay
-        ``g`` are [B, L, H, D], ``beta`` [B, L, H], all float32. Returns
-        (the state after position L - 1, every position's ``o`` [B, L, H,
-        D]). The same function as a token at a time: inside a block, with
-        ``G_t`` the running sum of ``g`` and ``u_t = beta_t (v_t - k_t^T
-        Diag(alpha_t) S_{t-1})`` the row a token writes,
-
-            S_t = Diag(e^{G_t}) S_0 + sum_{s<=t} Diag(e^{G_t - G_s}) k_s u_s^T
-            u_t = beta_t (v_t - (k_t e^{G_t})^T S_0
-                          - sum_{s<t} (k_t e^{G_t - G_s} . k_s) u_s)
-
-        so the ``u`` of a block solve a unit lower-triangular system by
-        forward substitution, the state is read and written once a BLOCK
-        and the products with it run on the matrix unit at the highest
-        precision. Every decay is a ratio of a later to an earlier
-        position, at most 1: nothing overflows however strong the gate."""
-        b, l, h, d = q.shape
-        c = min(self.BLOCK, l)
-        n = -(-l // c)
-        lower = jnp.tril(jnp.ones((c, c), bool))
-        high = jax.lax.Precision.HIGHEST
-
-        def blocks(x):  # [B, L, ...] -> [n, B, c, ...], zeros behind L
-            x = jnp.pad(x, ((0, 0), (0, n * c - l)) + ((0, 0),) * (x.ndim - 2))
-            return jnp.moveaxis(x.reshape((b, n, c) + x.shape[2:]), 1, 0)
-
-        def step(s, xs):
-            q, k, v, g, beta = xs
-            run = jnp.cumsum(g, axis=1)  # G_t [B, c, H, D]
-            # e^{G_t - G_s} for s <= t, 0 above the diagonal [B, t, s, H, D]
-            decay = jnp.exp(jnp.where(
-                lower[None, :, :, None, None],
-                run[:, :, None] - run[:, None, :], -jnp.inf))
-            kk = jnp.sum(k[:, :, None] * k[:, None, :] * decay, axis=-1)
-            qk = jnp.sum(q[:, :, None] * k[:, None, :] * decay, axis=-1)
-            grown = jnp.exp(run)
-            rhs = beta[..., None] * (v - jnp.einsum(
-                "bthk,bhkv->bthv", k * grown, s, precision=high))
-            below = beta[:, :, None] * jnp.where(
-                jnp.tril(lower, -1)[None, :, :, None], kk, 0.0)
-            u = jnp.zeros_like(rhs)
-            for t in range(c):  # forward substitution, a row a step
-                u = u.at[:, t].set(rhs[:, t] - jnp.sum(
-                    below[:, t][..., None] * u, axis=1))
-            o = jnp.einsum("bthk,bhkv->bthv", q * grown, s, precision=high
-                           ) + jnp.sum(qk[..., None] * u[:, None], axis=2)
-            s = grown[:, -1][..., None] * s + jnp.einsum(
-                "bshk,bshv->bhkv", k * jnp.exp(run[:, -1:] - run), u,
-                precision=high)
-            return s, o
-
-        s1, o = jax.lax.scan(step, s0,
-                             tuple(blocks(x) for x in (q, k, v, g, beta)))
-        return s1, jnp.moveaxis(o, 0, 1).reshape(b, n * c, h, d)[:, :l]
 
     @nn.compact
     def __call__(self, x, position_offset, block_tables=None, slots=None,
@@ -1283,14 +1495,8 @@ class KDAttention(nn.Module):
         cfg = self.config
         b, l, e = x.shape
         f32 = jnp.float32
-        h, d, taps = cfg.num_heads, cfg.head_width, self.TAPS
+        h, d = cfg.num_heads, cfg.head_width
         inner = h * d
-        cached = self.decode or self.prefill
-        paged = block_tables is not None
-        if paged and not cached:
-            raise ValueError(
-                "block_tables= is the paged SERVING cache layout; it "
-                "requires decode or prefill mode")
 
         def dense(width, name):
             return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
@@ -1298,113 +1504,132 @@ class KDAttention(nn.Module):
 
         pre = dense(3 * inner, "qkv")
         conv_w = self.param("conv_kernel", nn.initializers.normal(0.02),
-                            (taps, 3 * inner))
+                            (self.TAPS, 3 * inner))
         gate_f = dense(inner, "gate_f")
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (inner,))
         a_log = self.param("A_log", nn.initializers.zeros, (h,))
         beta = jax.nn.sigmoid(dense(h, "beta").astype(f32))  # [B, L, H]
         gate_o = dense(inner, "gate_o")
 
-        # ---- the state and the convolution inputs this call starts from
-        pos = jnp.asarray(position_offset, jnp.int32)
-        state_var = conv_var = None
-        tick = paged and self.decode
-        if cached:
-            if paged:
-                if pos.ndim != 1 or lengths is None or (
-                        slots is None and not tick):
-                    raise ValueError(
-                        "paged KDAttention takes a [B] position_offset "
-                        "vector and lengths= (each row's real length), and "
-                        "a chunk program slots= (each row's slot)")
-                state_var = self.variable("cache", "state", _need_pool)
-                conv_var = self.variable("cache", "conv", _need_pool)
-                if tick and state_var.value.shape[0] != b + 1:
-                    raise ValueError(
-                        "a paged decode tick has a lane a slot: row i reads "
-                        f"and writes slot i's state, got {b} rows over "
-                        f"{state_var.value.shape[0] - 1} slots")
-                rows = slice(0, b) if tick else slots
-                held_s, held_c = state_var.value[rows], conv_var.value[rows]
-            else:
-                state_var = self.variable(
-                    "cache", "state", lambda: jnp.zeros((b, h, d, d), f32))
-                conv_var = self.variable(
-                    "cache", "conv",
-                    lambda: jnp.zeros((b, taps - 1, 3 * inner), cfg.dtype))
-                held_s, held_c = state_var.value, conv_var.value
-            starts = pos if pos.ndim == 1 else jnp.full((b,), pos)
-            fresh = starts == 0
-            s0 = jnp.where(fresh[:, None, None, None], 0.0, held_s)
-            c0 = jnp.where(fresh[:, None, None],
-                           jnp.zeros((), held_c.dtype), held_c)
-        else:
-            s0 = jnp.zeros((b, h, d, d), f32)
-            c0 = jnp.zeros((b, taps - 1, 3 * inner), cfg.dtype)
-        real = (jnp.full((b,), l, jnp.int32) if lengths is None
-                else lengths.astype(jnp.int32))
-
-        # ---- convolutions, norms, gates
-        window = jnp.concatenate([c0, pre], axis=1)
-        qkv = nn.silu(sum(conv_w[j].astype(f32)
-                          * window[:, j:j + l].astype(f32)
-                          for j in range(taps)))
-
-        def unit(t):
-            return t * jax.lax.rsqrt(
-                jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
-
+        s0, c0, real, keep = self._held(
+            b, l, position_offset, block_tables, slots, lengths, h, d,
+            3 * inner)
+        window, qkv = self._convolved(c0, pre, conv_w)
         q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(b, l, h, d)
                    for i in range(3))
-        q, k = unit(q) * d ** -0.5, unit(k)
+        q, k = _unit(q) * d ** -0.5, _unit(k)
         rate = jnp.exp(a_log.astype(f32))[:, None]
         g = self.LOWER_BOUND * jax.nn.sigmoid(  # log alpha, in (L, 0)
             rate * (gate_f.astype(f32) + dt_bias.astype(f32)).reshape(
                 b, l, h, d))
 
-        def update(s, q_t, k_t, v_t, a_t, b_t):
-            """One token: ``s`` [B, H, D, D], the rest [B, H, D], beta
-            [B, H]. Float32 multiplies and sums on the vector unit, and
-            the OLD state read twice and the new one written once: what
-            the new state shows the query is what the decayed old one
-            shows it plus the written row's share, ``S'^T q = S^T (alpha *
-            q) + (v - seen) (beta k . q)``, so both reductions read ``s``
-            in one pass and nothing reads the state just written."""
-            seen = jnp.sum((k_t * a_t)[..., None] * s, axis=-2)
-            read = jnp.sum((q_t * a_t)[..., None] * s, axis=-2)
-            write = b_t[..., None] * k_t  # [B, H, D]: beta k
-            new = v_t - seen
-            s = a_t[..., None] * s + write[..., None] * new[..., None, :]
-            return s, read + new * jnp.sum(write * q_t, -1, keepdims=True)
-
         if l == 1:
-            s1, o = update(s0, q[:, 0], k[:, 0], v[:, 0], jnp.exp(g[:, 0]),
-                           beta[:, 0])
+            s1, o = delta_rule_update(s0, q[:, 0], k[:, 0], v[:, 0],
+                                      jnp.exp(g[:, 0]), beta[:, 0])
             o = o[:, None]
         else:
             # a padding position neither decays the state nor writes to it
             valid = jnp.arange(l)[None, :] < real[:, None]
-            s1, o = self._blocks(
+            s1, o = delta_rule_blocks(
                 s0, q, k, v, jnp.where(valid[..., None, None], g, 0.0),
-                jnp.where(valid[..., None], beta, 0.0))
-
-        if state_var is not None:
-            # the last T - 1 inputs behind the row's last REAL position
-            at = real[:, None] + jnp.arange(taps - 1)[None, :]
-            c1 = jnp.take_along_axis(window, at[:, :, None], axis=1)
-            live = real > 0
-            s1 = jnp.where(live[:, None, None, None], s1, held_s)
-            c1 = jnp.where(live[:, None, None], c1.astype(held_c.dtype),
-                           held_c)
-            if paged:
-                state_var.value = state_var.value.at[rows].set(s1)
-                conv_var.value = conv_var.value.at[rows].set(c1)
-            else:
-                state_var.value, conv_var.value = s1, c1
+                jnp.where(valid[..., None], beta, 0.0), self.BLOCK)
+        keep(window, s1)
 
         o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm")(o)
         o = (o.reshape(b, l, inner) * jax.nn.sigmoid(
             gate_o.astype(f32))).astype(cfg.dtype)
+        out = nn.Dense(e, use_bias=False, dtype=cfg.dtype, name="proj")(o)
+        if cfg.dropout:
+            out = nn.Dropout(cfg.dropout,
+                             deterministic=self.deterministic)(out)
+        return out
+
+
+class GatedDeltaNet(_SlotStateAttention):
+    """The gated delta rule (``attn_kind="gdn"``; Gated DeltaNet,
+    arXiv:2412.06464, as ``perfbench/references/qwen3_next.py`` writes it
+    down): ``KDAttention``'s recurrence with ONE decay a head a token,
+    unbounded below.
+
+    One fused projection takes the normed state to ``[q~ | k~ | v~ | z]``
+    (``H_k D``, ``H_k D``, ``H_v D`` and ``H_v D`` channels: ``H_v`` =
+    ``linear_num_heads`` state heads, ``H_k`` = ``linear_num_key_heads``
+    q/k heads, ``D`` = ``linear_head_dim``) and a second to ``[b | a]``
+    (``H_v`` each). ONE depthwise causal convolution of ``TAPS`` taps and a
+    SiLU runs over the concatenated q~, k~, v~ channels; q and k are
+    L2-normed a head (q scaled by ``D ** -0.5``); key head ``j`` serves
+    state heads ``j * H_v / H_k ...``. A state head keeps ``S`` ``[D, D]``
+    float32::
+
+        S <- alpha_t S;  S <- S + beta_t k_t (v_t - S^T k_t)^T
+        o_t = S^T q_t
+
+    with ``beta_t = sigmoid(b_t)`` and ``alpha_t = exp(-exp(A_log_h) *
+    softplus(a_t + dt_bias_h))`` in ``(0, 1)``. The output is ``o_t``
+    RMS-normed a head (one learned scale of ``D``), gated by ``SiLU(z_t)``
+    and projected back. The tick updates the state with float32 multiplies
+    and sums (``delta_rule_update``); a sequence runs ``BLOCK`` positions a
+    step through ``KDAttention``'s block solve, of which a decay shared by
+    a head's channels is the special case (``delta_rule_blocks``). The
+    state and the convolution's last inputs are a request's
+    (``_SlotStateAttention``); ``TAPS`` is the published
+    ``linear_conv_kernel_dim``.
+    """
+
+    @nn.compact
+    def __call__(self, x, position_offset, block_tables=None, slots=None,
+                 lengths=None):
+        cfg = self.config
+        b, l, e = x.shape
+        f32 = jnp.float32
+        hv, hk, d = (cfg.linear_heads, cfg.linear_key_heads,
+                     cfg.linear_head_width)
+        keys, values = hk * d, hv * d
+        conv_width = 2 * keys + values
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            name=name)(x)
+
+        qkvz = dense(conv_width + values, "qkvz")
+        pre, z = qkvz[..., :conv_width], qkvz[..., conv_width:]
+        conv_w = self.param("conv_kernel", nn.initializers.normal(0.02),
+                            (self.TAPS, conv_width))
+        ba = dense(2 * hv, "ba").astype(f32)
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (hv,))
+        a_log = self.param("A_log", nn.initializers.zeros, (hv,))
+        beta = jax.nn.sigmoid(ba[..., :hv])  # [B, L, H_v]
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(  # log alpha < 0
+            ba[..., hv:] + dt_bias.astype(f32))
+
+        s0, c0, real, keep = self._held(
+            b, l, position_offset, block_tables, slots, lengths, hv, d,
+            conv_width)
+        window, qkv = self._convolved(c0, pre, conv_w)
+        q = qkv[..., :keys].reshape(b, l, hk, d)
+        k = qkv[..., keys:2 * keys].reshape(b, l, hk, d)
+        v = qkv[..., 2 * keys:].reshape(b, l, hv, d)
+        q, k = _unit(q) * d ** -0.5, _unit(k)
+        if hv != hk:  # a q/k head is shared by its group of state heads
+            q = jnp.repeat(q, hv // hk, axis=2)
+            k = jnp.repeat(k, hv // hk, axis=2)
+
+        if l == 1:
+            s1, o = delta_rule_update(s0, q[:, 0], k[:, 0], v[:, 0],
+                                      jnp.exp(g[:, 0])[..., None],
+                                      beta[:, 0])
+            o = o[:, None]
+        else:
+            # a padding position neither decays the state nor writes to it
+            valid = jnp.arange(l)[None, :] < real[:, None]
+            s1, o = delta_rule_blocks(
+                s0, q, k, v, jnp.where(valid[..., None], g, 0.0)[..., None],
+                jnp.where(valid[..., None], beta, 0.0), self.BLOCK)
+        keep(window, s1)
+
+        o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm")(o)
+        o = (o.reshape(b, l, values) * nn.silu(z.astype(f32))).astype(
+            cfg.dtype)
         out = nn.Dense(e, use_bias=False, dtype=cfg.dtype, name="proj")(o)
         if cfg.dropout:
             out = nn.Dropout(cfg.dropout,
@@ -1608,8 +1833,9 @@ class Block(nn.Module):
         if attn_kind == "cca":
             out = CCAttention(cfg, **mode)(
                 h, position_offset, positions, block_tables, slots, lengths)
-        elif attn_kind == "kda":
-            out = KDAttention(cfg, **mode)(
+        elif attn_kind in ("kda", "gdn"):
+            linear = KDAttention if attn_kind == "kda" else GatedDeltaNet
+            out = linear(cfg, **mode)(
                 h, position_offset, block_tables, slots, lengths)
         elif attn_kind == "mla":
             out = MLAttention(cfg, **mode)(
@@ -1624,16 +1850,17 @@ class Block(nn.Module):
 
             live = (None if lengths is None else
                     jnp.arange(x.shape[1])[None, :] < lengths[:, None])
-            sigmoid = dict(
-                router="sigmoid", top_k=cfg.moe_top_k,
+            one_matrix = dict(
+                router=cfg.moe_router, top_k=cfg.moe_top_k,
                 n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
                 routed_scale=cfg.moe_routed_scale,
                 shared_dim=cfg.moe_shared_dim, held=cfg.experts_held,
-            ) if cfg.moe_router == "sigmoid" else {}
+                shared_gate=cfg.moe_shared_gate,
+            ) if cfg.moe_router != "mlp" else {}
             out, router_state = DroplessMoE(
                 n_experts=cfg.n_experts, moe_dim=cfg.moe_dim,
                 router_dim=cfg.router_dim, norm_eps=cfg.norm_eps,
-                dtype=cfg.dtype, name="moe", **sigmoid,
+                dtype=cfg.dtype, name="moe", **one_matrix,
             )(h, router_state, live)
             if cfg.dropout:
                 out = nn.Dropout(cfg.dropout,

@@ -325,6 +325,13 @@ class PagedEngine:
             pool_layers = len({path[:-1] for path, _ in leaves
                                if not is_slot_leaf(path)})
             cache_layers = pool_layers * config.ut_steps
+            # a token's bytes in ONE layer that owns a key and a value
+            # pool: twice a row of either (H_kv * D lanes, the same in
+            # every such layer)
+            kv_rows = [leaf.shape[-1] * leaf.dtype.itemsize
+                       for path, leaf in leaves
+                       if getattr(path[-1], "key", None) in ("key", "value")]
+            kv_row_bytes = 2 * sum(kv_rows) // max(len(kv_rows), 1)
             # ``T``, the chain blocks a grid step of the tick's kernel
             # stages (``ops.paged_flash.tile_blocks``, from the bytes a
             # position holds in one cache layer on one shard: a latent
@@ -363,7 +370,9 @@ class PagedEngine:
             # ``tail_bytes``: the per-slot leaves' bytes but for the
             # float32 recurrent states, which are ``state_bytes``;
             # ``latent_row_bytes``: a token's ONE row where a layer keeps a
-            # latent pool; ``pool_layers``: the layers that own a pool
+            # latent pool, ``kv_row_bytes``: a token's key and value rows
+            # where a layer keeps those (0 where none does);
+            # ``pool_layers``: the layers that own a pool
             alloc.args.update(
                 weight_layers=config.num_layers,
                 cache_layers=cache_layers,
@@ -371,6 +380,7 @@ class PagedEngine:
                 state_bytes=state_bytes,
                 latent_row_bytes=config.latent_row_width
                 * jnp.dtype(config.dtype).itemsize,
+                kv_row_bytes=kv_row_bytes,
                 block_bytes=self._per_block_bytes,
                 read=self.gather_impl,
                 table_blocks=n_slots * self.table_width,

@@ -192,7 +192,7 @@ def test_generate_decodes_through_the_dense_cache(model):
 @pytest.mark.parametrize("shift", [0.0, 20.0])
 def test_the_block_recurrence_is_the_references_token_at_a_time(
         model, monkeypatch, block, shift):
-    """``KDAttention._blocks`` takes ``BLOCK`` positions a step; the
+    """``delta_rule_blocks`` takes ``BLOCK`` positions a step; the
     reference runs the recurrence a token at a time. 23 positions in blocks
     of 3 (with two padding positions), 4 and 16, from a zero state; with
     ``dt_bias`` shifted by 20 every channel's gate sits at its bound (alpha
@@ -385,7 +385,8 @@ def test_the_counts_are_a_bincount_of_live_pairs_on_held_experts(model):
     (dict(moe_shared_dim=0), "moe_shared_dim"),
     (dict(first_k_dense_replace=7), "first_k_dense_replace"),
     (dict(moe_kind="capacity", moe_dim=None), "sigmoid"),
-    (dict(moe_router="mlp", router_dim=8, moe_top_k=1), "sigmoid' only"),
+    (dict(moe_router="mlp", router_dim=8, moe_top_k=1),
+     "one-matrix routers .*only"),
 ])
 def test_the_config_refuses_what_it_cannot_run(over, match):
     with pytest.raises(ValueError, match=match):

@@ -700,3 +700,107 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= state.size * 4 + latent.size * 2
     assert memory.temp_size_in_bytes < 1 << 30
+
+
+# ---- the qwen3-next stack's served programs (PR 41) ------------------------
+
+#: qwen3-next-80b-a3b.doc-chat-backlog's attention, experts, state, pool and
+#: slots (perfbench/configs, perfbench/cells), on a period of 2 layers (one
+#: gated delta-rule layer, one full layer) and a small vocabulary
+QWEN_CELL = dict(slots=256, blocks=65537, block_len=16, chunk=128,
+                 max_seq_len=4864)
+QWEN_BLOCK = dict(
+    embed_dim=2048, num_heads=16, num_kv_heads=2, head_dim=256,
+    attn_kind="gdn", layer_group_size=2, full_attn_kind="mha",
+    linear_num_heads=32, linear_num_key_heads=16, linear_head_dim=128,
+    qk_norm=True, attn_gate=True, rotary_share=0.25, pos_embedding="rope",
+    rope_theta=1e7, norm="rmsnorm", norm_eps=1e-6, use_bias=False,
+    mlp="swiglu", n_experts=512, moe_every=1, moe_kind="dropless",
+    moe_router="softmax", moe_top_k=10, moe_dim=512, moe_shared_dim=512,
+    moe_shared_gate=True, experts_held=(0, 256))
+
+
+@pytest.mark.parametrize("program", ["decode_tick",
+                                     "chunk_prefill[k=16,w=256]"])
+def test_the_qwen3_next_programs_compile_for_the_chip(v5e, monkeypatch,
+                                                      program):
+    """The tick reads the full layer's REAL keys and values through the
+    fused kernel's folded body (2 narrow heads x 8 query rows: 16 (row,
+    head) columns over K and V tiles of 512 lanes); the chunk program
+    gathers dense over its own table slice. Both run the held experts as
+    two grouped products a layer, update the float32 state where it lies
+    (no copy of a state leaf) and move no pool-sized array."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = QWEN_CELL
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=2, max_seq_len=c["max_seq_len"],
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense", **QWEN_BLOCK)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    n = c["slots"]
+    eng = PagedEngine(cfg, params, n, n_blocks=2, block_len=c["block_len"],
+                      prefill_chunk=c["chunk"], chunk_bucket_floor=(16, 256),
+                      max_chunk_jobs=16)
+    assert eng.gather_impl == "pallas" and eng.tile_blocks == 8
+    assert eng.heads_folded == 2
+    # ONE chunk program for prompts up to 4,096 positions (a resumed
+    # request past them would take the table's whole width)
+    assert eng.chunk_buckets() == [(16, 256), (16, 304)]
+    pool = jax.eval_shape(
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
+                                   n_slots=n), params)
+    state = pool["block0"]["attn"]["state"]
+    keys = pool["block1"]["attn"]["key"]
+    assert state.shape == (n + 1, 32, 128, 128) and state.dtype == jnp.float32
+    assert pool["block0"]["attn"]["conv"].shape == (n + 1, 3, 8192)
+    assert keys.shape == (c["blocks"], c["block_len"], 512)
+    one = SingleDeviceSharding(v5e.devices[0])
+    if program == "decode_tick":
+        fn, operands = _tick_operands(eng)
+    else:
+        fn, operands = _chunk_operands(eng, 16, 256)
+        assert eng.chunk_program_name(16, 256) == program
+    args = (params, pool, eng.logits) + operands
+    compiled = fn.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        args)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reads = [x for x in calls if "paged_decode_attn" in x]
+    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
+    assert len(reads) == (1 if program == "decode_tick" else 0), calls
+    assert len(grouped) == 4, calls  # gate and up side by side, and down
+    # the folded body: the query is block-diagonal, 2 x 8 (row, head)
+    # columns of both heads' 512 lanes, and the output leaves lane-dense
+    assert all("bf16[256,16,512]" in x and "bf16[256,8,512]" in x
+               for x in _kernel_reads(text))
+    # the counts come back beside what the programs returned before: two
+    # expert layers, the experts held
+    shapes = [tuple(s.shape) for s in jax.tree.leaves(
+        jax.eval_shape(fn, *args))]
+    assert shapes[-1] == (2, 256)
+    # neither a state leaf nor a pool is copied or transposed
+    moved = [m.group(1) for m in re.finditer(
+        r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
+        if math.prod(map(int, m.group(2).split(","))) in (state.size,
+                                                          keys.size)]
+    assert not moved, moved
+    # the tick gathers no lane's table: nothing of [lanes, positions, ...]
+    rows = c["max_seq_len"]
+    assert not re.search(rf"f32\[{n},(?:{rows}|{rows // 16},16),", text)
+    # and what it holds beside its arguments is small: state and pools are
+    # donated and updated in place
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state.size * 4 + 2 * keys.size * 2
+    assert memory.temp_size_in_bytes < (
+        1 << 27 if program == "decode_tick" else 3 << 29)

@@ -495,7 +495,12 @@ def test_the_router_stream_of_a_layer_enters_the_next(model):
     (dict(attn_kind="latent"), "attn_kind"),
     (dict(moe_kind="sorted"), "moe_kind"),
     (dict(moe_kind="capacity"), "moe_dim and router_dim"),
-    (dict(attn_kind="mha", head_dim=8), "head_dim"),
+    # since PR 41 ``Attention`` takes an inner width of its own (4 x 8 in
+    # a model of 48); its gate needs the separate q projection, and the
+    # norm a head and the gate are no options of this attention
+    (dict(attn_kind="mha", head_dim=8, attn_gate=True, num_kv_heads=None),
+     "num_kv_heads"),
+    (dict(qk_norm=True), "qk_norm and attn_gate"),
 ])
 def test_the_config_refuses_what_it_cannot_run(over, match):
     with pytest.raises(ValueError, match=match):
