@@ -18,11 +18,13 @@ this is the framework's long-context workhorse. TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax.linen.dtypes import promote_dtype
 
 from pytorch_distributed_tpu.ops import attention as attention_ops
 from pytorch_distributed_tpu.ops.attention import (
@@ -647,6 +649,40 @@ def _norm(cfg: TransformerConfig, name: str):
     return kind(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
 
 
+class RowsDense(nn.Module):
+    """``nn.DenseGeneral``'s parameters under its names (``kernel``
+    ``in_shape + features``, ``bias`` ``features``, the same initial
+    values from the same key) with the product taken on FLAT rows:
+    ``[..., prod(in_shape)]`` in, ``[..., prod(features)]`` out. The TPU
+    compiler lays a product whose last axis is a head's 64 columns out
+    with the SEQUENCE minor (a 64-wide minor axis half-fills a 128-lane
+    tile), so a consumer that wants rows pays a relayout of the whole
+    array; a product whose last axis is the whole row stays row-major,
+    as the MLP's do."""
+
+    in_shape: tuple
+    features: tuple
+    use_bias: bool = True
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        flat = (math.prod(self.in_shape), math.prod(self.features))
+
+        def kernel_init(rng, shape, dtype=jnp.float32):
+            # DenseGeneral's: drawn on the flat shape, then reshaped
+            return nn.linear.default_kernel_init(rng, flat, dtype).reshape(
+                shape)
+
+        kernel = self.param("kernel", kernel_init,
+                            self.in_shape + self.features)
+        bias = (self.param("bias", nn.initializers.zeros_init(),
+                           self.features) if self.use_bias else None)
+        x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
+        out = x @ kernel.reshape(flat)
+        return out if bias is None else out + bias.reshape(flat[1])
+
+
 class Attention(nn.Module):
     config: TransformerConfig
     deterministic: bool = True
@@ -671,7 +707,24 @@ class Attention(nn.Module):
 
             x = tp_copy(x, cfg.model_axis)  # column-parallel qkv below
         heads_local = cfg.num_heads // cfg.tp_size
-        if cfg.num_kv_heads is None:
+        # The training flash path reads q, k and v where the fused
+        # product lies: [B, L, 3·H·D] rows in, [B, L, H·D] rows out to
+        # ``project``, no [B, L, H, D] array between (``RowsDense`` keeps
+        # both products row-major on the chip). Same parameters as the
+        # other paths, which take the product head by head.
+        packed = (
+            cfg.attention == "flash" and cfg.num_kv_heads is None
+            and block_tables is None and not (self.decode or self.prefill)
+            and not cfg.qk_norm and cfg.pos_embedding != "rope"
+        )
+        if packed:
+            qkv_rows = RowsDense(
+                (e,), (3, heads_local, head_dim), dtype=cfg.dtype,
+                name="qkv", use_bias=cfg.use_bias,
+            )(x)
+            q = k = v = None
+            kv_group = 1
+        elif cfg.num_kv_heads is None:
             qkv = nn.DenseGeneral(
                 (3, heads_local, head_dim), dtype=cfg.dtype, name="qkv",
                 use_bias=cfg.use_bias,
@@ -711,6 +764,11 @@ class Attention(nn.Module):
             if cfg.attn_gate:
                 out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))).astype(cfg.dtype)
+            if packed:  # [B, L, H·D] rows
+                return RowsDense(
+                    (heads_local, head_dim), (e,), use_bias=False,
+                    dtype=cfg.dtype, name="proj",
+                )(out)
             return nn.DenseGeneral(
                 e, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
                 name="proj",
@@ -1022,13 +1080,19 @@ class Attention(nn.Module):
                 q_offset=position_offset, k_offset=position_offset,
             )
         elif cfg.attention == "flash":
-            from pytorch_distributed_tpu.ops.flash_attention import flash_attention
+            from pytorch_distributed_tpu.ops.flash_attention import (
+                flash_attention,
+                flash_attention_qkv,
+            )
 
             # Pallas kernel path. The kernel masks from position 0, which is
             # exact for any equal-offset self-attention: the causal
             # predicate (k_off + j <= q_off + i) is offset-invariant when
             # q_off == k_off, as it is here.
-            out = flash_attention(q, k, v, causal=True)
+            if packed:
+                out = flash_attention_qkv(qkv_rows, heads_local, causal=True)
+            else:
+                out = flash_attention(q, k, v, causal=True)
         elif cfg.attention == "dense":
             out = dense_attention(
                 q, k, v, causal=True,

@@ -21,8 +21,9 @@ merge weights depend on the per-shard LSEs, and differentiating through
 them naively would need an lse-cotangent rule the kernel doesn't define):
   with the FINAL output O and GLOBAL row LSE saved as residuals, the
   FlashAttention-2 decomposition applies per KV shard independently:
-  Δ = rowsum(dO ⊙ O) once, then each visiting shard's (dQ-contribution,
-  dK, dV) comes from the flash BACKWARD kernels with the global LSE. dQ
+  each visiting shard's (dQ-contribution, dK, dV) comes from the flash
+  BACKWARD kernels with the global LSE and Δ = rowsum(dO ⊙ O), which the
+  kernels take from the dO and final-O rows they stage. dQ
   accumulates locally; dK/dV accumulators TRAVEL WITH their shard around
   the ring, so after a full circle every shard's gradients are complete
   and home (one collective permutation per step, same overlap story as
@@ -58,13 +59,12 @@ from pytorch_distributed_tpu.ops.flash_attention import (
     _flash_fwd,
     _from3,
     _to3,
-    compute_delta,
 )
 from pytorch_distributed_tpu.parallel.mesh import SEQ_AXIS
 
 
 def _visit_bwd(q3, k_cur, v_cur, o3, lse3, do3, scale, causal_block,
-               block_q, block_k, interpret, delta3, bwd_impl):
+               block_q, block_k, interpret, bwd_impl):
     """One visiting shard's (dQ-contribution, dK, dV) — the r5 fused
     single-pass kernel by default (5 big matmuls + one input pass per
     visit vs the split kernels' 7 and two; +20-29% measured standalone,
@@ -72,12 +72,12 @@ def _visit_bwd(q3, k_cur, v_cur, o3, lse3, do3, scale, causal_block,
     if bwd_impl == "fused":
         return _flash_bwd_fused(
             q3, k_cur, v_cur, o3, lse3, do3, scale, causal_block,
-            (block_q, block_k), k_cur.shape[1], interpret, delta3=delta3,
+            (block_q, block_k), k_cur.shape[1], interpret,
         )
     return _flash_bwd(
         q3, k_cur, v_cur, o3, lse3, do3, scale, causal_block,
         (block_q, block_k), (block_q, block_k), k_cur.shape[1],
-        interpret, delta3=delta3,
+        interpret,
     )
 
 
@@ -206,13 +206,12 @@ def _ring_flash_bwd(axis, causal, scale, block_q, block_k, interpret, layout,
     q3, k3, v3, do3 = _to3(q), _to3(k), _to3(v), _to3(g.astype(q.dtype))
     bh = q3.shape[0]
     lse3 = jnp.broadcast_to(lse, (bh, lq, 128))
-    delta3 = compute_delta(do3, o3)  # shard-invariant: once, not per step
     perm = [(i, (i + 1) % s) for i in range(s)]
 
     def shard_bwd(k_cur, v_cur, causal_block):
         return _visit_bwd(
             q3, k_cur, v_cur, o3, lse3, do3, scale, causal_block,
-            block_q, block_k, interpret, delta3, bwd_impl,
+            block_q, block_k, interpret, bwd_impl,
         )
 
     def fold(dq_acc, dk_cur, dv_cur, k_cur, v_cur, step):
@@ -381,19 +380,18 @@ def _ring_flash_zigzag_bwd(axis, scale, block_q, block_k, interpret, res, g,
     q3, k3, v3, do3 = _to3(q), _to3(k), _to3(v), _to3(g.astype(q.dtype))
     bh = q3.shape[0]
     lse3 = jnp.broadcast_to(lse, (bh, lq, 128))
-    delta3 = compute_delta(do3, o3)
     perm = [(i, (i + 1) % s) for i in range(s)]
 
     chunks = {
-        "lo": (q3[:, :c], o3[:, :c], lse3[:, :c], do3[:, :c], delta3[:, :c]),
-        "hi": (q3[:, c:], o3[:, c:], lse3[:, c:], do3[:, c:], delta3[:, c:]),
+        "lo": (q3[:, :c], o3[:, :c], lse3[:, :c], do3[:, :c]),
+        "hi": (q3[:, c:], o3[:, c:], lse3[:, c:], do3[:, c:]),
     }
 
     def pair_bwd(which, kc, vc, causal_block):
-        qc, oc, lsec, doc, dc = chunks[which]
+        qc, oc, lsec, doc = chunks[which]
         return _visit_bwd(
             qc, kc, vc, oc, lsec, doc, scale, causal_block,
-            block_q, block_k, interpret, dc, bwd_impl,
+            block_q, block_k, interpret, bwd_impl,
         )
 
     def fold(dq_acc, dkv_cur, k_cur, v_cur, step):

@@ -201,6 +201,23 @@ def test_flash_lm_forward_matches_dense():
         np.asarray(out_f), np.asarray(out_d), rtol=2e-4, atol=2e-5
     )
 
+    # and backward: every parameter's gradient (the training path reads
+    # q, k and v out of the fused product: flash_attention_qkv)
+    def grads(cfg):
+        return jax.grad(lambda p: jnp.sum(
+            TransformerLM(cfg).apply({"params": p}, tokens) ** 2
+        ))(variables["params"])
+
+    for (path, g_f), g_d in zip(
+        jax.tree_util.tree_leaves_with_path(grads(cfg_f)),
+        jax.tree.leaves(grads(cfg_d)),
+    ):
+        scale = float(jnp.abs(g_d).max()) + 1e-6
+        np.testing.assert_allclose(
+            np.asarray(g_f) / scale, np.asarray(g_d) / scale, atol=2e-4,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
 
 def test_flash_fused_backward_matches_split():
     """The single-pass backward (bwd_impl='fused') must produce the same
@@ -265,3 +282,125 @@ def test_flash_partials_f32_knob():
     err = lambda x: np.abs(x - dq_split).max()
     assert err(dq_f32) <= err(dq_bf16) + 1e-6
     np.testing.assert_allclose(dq_f32, dq_split, rtol=2e-2, atol=2e-2)
+
+
+# ---- the kernels on [B, L, heads·D] rows, g = 128 // D heads a block --------
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("d", [32, 64, 128])  # g = 4, 2, 1 heads a block
+def test_flash_qkv_matches_dense(d, causal):
+    """``flash_attention_qkv`` reads q, k and v at their columns of ONE
+    ``[B, L, 3·H·D]`` array and returns ``[B, L, H·D]``: output AND the
+    packed operand's gradient against ``dense_attention``, at a length
+    that is no multiple of the block (zero rows padded, keys masked)."""
+    from pytorch_distributed_tpu.ops.flash_attention import (
+        _heads_a_block,
+        flash_attention_qkv,
+    )
+
+    b, l, h = 2, 40, 256 // d
+    assert _heads_a_block(h, d) == max(128 // d, 1)
+    rows = jnp.asarray(
+        np.random.default_rng(d).normal(size=(b, l, 3 * h * d)), jnp.float32)
+
+    def heads(x):  # the three [B, L, H, D] views of the packed rows
+        return [x[..., i * h * d:(i + 1) * h * d].reshape(b, l, h, d)
+                for i in range(3)]
+
+    def flash(x):
+        return flash_attention_qkv(x, h, causal=causal, block_q=16,
+                                   block_k=16, interpret=True)
+
+    def dense(x):
+        return dense_attention(*heads(x), causal=causal).reshape(b, l, h * d)
+
+    np.testing.assert_allclose(np.asarray(flash(rows)),
+                               np.asarray(dense(rows)), rtol=1e-5, atol=1e-5)
+    g_f = jax.grad(lambda x: jnp.sum(flash(x) ** 2))(rows)
+    g_d = jax.grad(lambda x: jnp.sum(dense(x) ** 2))(rows)
+    np.testing.assert_allclose(np.asarray(g_f), np.asarray(g_d), rtol=2e-4,
+                               atol=5e-5)
+
+
+@pytest.mark.parametrize("h,d,g", [
+    (16, 64, 2), (12, 64, 2), (8, 32, 4), (2, 128, 1), (1, 64, 1),
+    (2, 16, 2), (3, 64, 0), (16, 80, 0), (1, 80, 1),
+])
+def test_heads_a_block_follows_from_the_shapes(h, d, g):
+    """128 // D heads where they fill a 128-lane block and the row cuts
+    into such blocks, one head of whole lane tiles, the whole of a row no
+    wider than 128 lanes, and 0 (heads to the batch axis) elsewhere."""
+    from pytorch_distributed_tpu.ops.flash_attention import _heads_a_block
+
+    assert _heads_a_block(h, d) == g
+
+
+def test_flash_rows_that_do_not_cut_into_lane_blocks():
+    """H·D = 192 is no multiple of 128 and wider than a block: the public
+    entry moves the heads to the batch axis and still matches dense."""
+    from pytorch_distributed_tpu.ops.flash_attention import flash_attention
+
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 24, 3, 64)), jnp.float32)
+               for _ in range(3))
+    out = flash_attention(q, k, v, causal=True, block_q=8, block_k=8,
+                          interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dense_attention(q, k, v, causal=True)),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_training_attention_holds_no_relayout():
+    """Between the qkv product and the output projection the training
+    path (``attention="flash"``, fused qkv) moves no activation: the
+    lowered forward-and-backward of ``Attention`` transposes only weight
+    gradients (rank 2), where the parent's text held twelve rank-4
+    ``[B, L, H, D] <-> [B, H, L, D]`` transposes."""
+    import re
+
+    from pytorch_distributed_tpu.models.transformer import (
+        Attention,
+        tiny_config,
+    )
+
+    cfg = tiny_config(attention="flash")
+    att = Attention(cfg)
+    x = jnp.ones((2, 32, cfg.embed_dim), jnp.float32)
+    params = att.init(jax.random.key(0), x, 0)
+    text = jax.jit(jax.grad(
+        lambda p, x: att.apply(p, x, 0).sum(), argnums=(0, 1)
+    )).lower(params, x).as_text()
+    moved = re.findall(r"stablehlo\.transpose .*: \(tensor<([0-9x]+)x\w+>\)",
+                       text)
+    assert moved, "the weight gradients' transposes should be in the text"
+    assert all(len(shape.split("x")) == 2 for shape in moved), moved
+
+
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_lm_parameter_tree_is_the_checkpoints(attention):
+    """The packed path takes its products on flat rows (``RowsDense``)
+    under ``nn.DenseGeneral``'s names, shapes AND initial values: the
+    tree a ``TransformerLM`` initialises is the one every checkpoint
+    holds (``qkv/kernel`` [E, 3, H, D], ``qkv/bias`` [3, H, D],
+    ``proj/kernel`` [H, D, E]), whichever attention initialises it."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerLM,
+        tiny_config,
+    )
+
+    tokens = jnp.ones((1, 16), jnp.int32)
+    cfg = tiny_config(attention=attention)
+    params = TransformerLM(cfg).init(jax.random.key(0), tokens)["params"]
+    e, h, d = cfg.embed_dim, cfg.num_heads, cfg.head_width
+    attn = params["block0"]["attn"]
+    assert {k: {n: p.shape for n, p in v.items()} for k, v in attn.items()} \
+        == {"qkv": {"kernel": (e, 3, h, d), "bias": (3, h, d)},
+            "proj": {"kernel": (h, d, e)}}
+    # value for value what the head-by-head products initialise
+    ref = TransformerLM(tiny_config(attention="dense")).init(
+        jax.random.key(0), tokens)["params"]
+    assert jax.tree.structure(params) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(ref)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

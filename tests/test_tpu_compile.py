@@ -35,7 +35,10 @@ from jax.sharding import (
     SingleDeviceSharding,
 )
 
-from pytorch_distributed_tpu.ops.flash_attention import flash_attention
+from pytorch_distributed_tpu.ops.flash_attention import (
+    flash_attention,
+    flash_attention_qkv,
+)
 from pytorch_distributed_tpu.ops.paged_flash import (
     paged_flash_attention,
     paged_quantize_scatter,
@@ -80,6 +83,17 @@ def _flash(topo, grad):
                              sharding=SingleDeviceSharding(topo.devices[0]))
     fn = functools.partial(flash_attention, causal=True, interpret=False)
     return jax.jit(_loss_grad(fn) if grad else fn).lower(x, x, x)
+
+
+def _flash_qkv(topo):
+    """The pretrain cell's attention (16 heads of 64 at L = 1,024) off the
+    packed qkv rows, forward and backward: two heads a 128-lane block."""
+    x = jax.ShapeDtypeStruct((4, 1024, 3 * 16 * 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    fn = functools.partial(flash_attention_qkv, heads=16, causal=True,
+                           interpret=False)
+    return jax.jit(jax.grad(
+        lambda x: fn(x).astype(jnp.float32).sum())).lower(x)
 
 
 def _ring_flash(topo):
@@ -136,6 +150,7 @@ def _scatter(topo, kv_dtype):
 CASES = {
     "flash_fwd": lambda t: _flash(t, grad=False),
     "flash_fwd_bwd": lambda t: _flash(t, grad=True),
+    "flash_qkv_fwd_bwd": _flash_qkv,
     "ring_flash_fwd_bwd_seq4": _ring_flash,
     "paged_decode_bf16": lambda t: _paged(t, 32, 1),
     "paged_chunk_bf16": lambda t: _paged(t, 4, 128),
@@ -153,6 +168,7 @@ CASES = {
 KERNELS = {
     "flash_fwd": ("flash_fwd",),
     "flash_fwd_bwd": ("flash_fwd", "flash_bwd_fused"),
+    "flash_qkv_fwd_bwd": ("flash_fwd", "flash_bwd_fused"),
     "ring_flash_fwd_bwd_seq4": ("flash_fwd", "flash_bwd_fused"),
     "paged_decode_bf16": ("paged_decode_attn",),
     "paged_chunk_bf16": ("paged_decode_attn",),
@@ -184,6 +200,42 @@ def test_split_backward_kernels_are_named(v5e):
                            bwd_impl="split")
     text = jax.jit(_loss_grad(fn)).lower(x, x, x).compile().as_text()
     assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+
+
+def test_training_attention_compiles_without_a_relayout(v5e, monkeypatch):
+    """``Attention`` as the pretrain cell trains it (``attention="flash"``,
+    fused qkv, gpt2-medium's widths in bfloat16), forward and backward,
+    compiled for the chip: the kernels take the qkv product's rows and
+    give the projection its rows, and the compiler puts NO copy or
+    transpose of a ``[B, L, ...]`` activation between them. (It laid the
+    head-by-head product ``[B, L, 3, H, 64]`` out with the sequence minor
+    and copied it for any consumer that wanted rows: ``RowsDense``.)"""
+    from pytorch_distributed_tpu.models.transformer import (
+        Attention,
+        TransformerConfig,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, l, e = 4, 1024, 1024
+    cfg = TransformerConfig(vocab_size=512, num_layers=1, num_heads=16,
+                            embed_dim=e, max_seq_len=l, dropout=0.0,
+                            dtype=jnp.bfloat16, attention="flash")
+    att = Attention(cfg)
+    one = SingleDeviceSharding(v5e.devices[0])
+    x = jax.ShapeDtypeStruct((b, l, e), jnp.bfloat16, sharding=one)
+    params = jax.tree.map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=one),
+        jax.eval_shape(att.init, jax.random.key(0),
+                       jnp.zeros((b, l, e), jnp.bfloat16), 0))
+    text = jax.jit(jax.grad(
+        lambda p, x: att.apply(p, x, 0).astype(jnp.float32).sum(),
+        argnums=(0, 1),
+    )).lower(params, x).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd_fused" in text
+    moved = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if re.search(rf" = \w+\[{b},{l},[0-9,]+\]\S* (copy|transpose)\(",
+                          line)]
+    assert not moved, moved
 
 
 def test_decode_tick_module_is_named(v5e):
