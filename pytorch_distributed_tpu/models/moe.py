@@ -210,9 +210,40 @@ class MoEMLP(nn.Module):
         return out.reshape(b, l, d)
 
 
+#: widths of the tiles XLA's grouped-product kernel takes on a TPU: a
+#: ``ragged_dot`` whose contracted and output widths are whole tiles of 512
+#: runs them 512 x 512 a grid step, any other width 128 x 128: sixteen times
+#: the steps, and a step costs its fixed half microsecond whatever it moves
+#: (64 experts of 2,688 x 1,856: 9.6 ms a product where its bytes take 0.8,
+#: PERF.md section 6, PR 44)
+GROUPED_TILE = 512
+
+
+def grouped_width(width: int) -> int:
+    """The width a two-matrix expert stack is HELD at: ``width`` rounded up
+    to whole ``GROUPED_TILE``s, zeros beyond it; a width under one tile (a
+    toy's) stays as it is."""
+    if width < GROUPED_TILE:
+        return width
+    return -(-width // GROUPED_TILE) * GROUPED_TILE
+
+
+def _zero_padded(init, shape):
+    """``init`` drawn at ``shape`` and laid into the corner of an array of
+    zeros: ``(rng, held shape, dtype) -> array``."""
+    def padded(rng, held, dtype=jnp.float32):
+        return jnp.pad(init(rng, shape, dtype),
+                       [(0, h - s) for h, s in zip(held, shape)])
+    return padded
+
+
 class DroplessMoE(nn.Module):
     """Expert layer that drops nothing (``TransformerConfig.
-    moe_kind="dropless"``): SwiGLU experts of ``moe_dim`` features, the
+    moe_kind="dropless"``): SwiGLU experts of ``moe_dim`` features (or,
+    with ``relu2``, experts of TWO matrices, ``relu(x W_up)^2 W_down``, the
+    shared expert alike: ``TransformerConfig.mlp="relu2"``; their stacks
+    are held ``[experts, grouped_width(d), grouped_width(moe_dim)]`` and its
+    transpose, zero beyond the model's own widths), the
     program's live (token, expert) PAIRS sorted by expert, the group sizes
     taken, and the experts' matrices run as grouped products
     (``jax.lax.ragged_dot``: on a TPU XLA's own grouped-matmul kernel,
@@ -268,6 +299,7 @@ class DroplessMoE(nn.Module):
     shared_dim: Optional[int] = None
     held: Optional[tuple] = None
     shared_gate: bool = False
+    relu2: bool = False
 
     @nn.nowrap
     def _f32_dense(self, width, name):
@@ -357,12 +389,29 @@ class DroplessMoE(nn.Module):
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
                                                 in_axis=1, out_axis=2,
                                                 batch_axis=0)
-        # gate and up side by side: one grouped product for both
-        w_in = self.param("w_gate_up", init, (e, d, 2 * f))
-        w_down = self.param("w_down", init, (e, f, d))
-        gu = jax.lax.ragged_dot(xs, w_in.astype(self.dtype), sizes)
-        hidden = nn.silu(gu[:, :f]) * gu[:, f:]
-        ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype), sizes)
+        if self.relu2:
+            # two matrices an expert, no gate; both stacks held in whole
+            # tiles of the grouped product (``grouped_width``: zeros beyond
+            # d and f, which neither product sees: the padded columns of
+            # ``xs`` are zero, relu(0)^2 is zero, and the padded outputs
+            # are cut)
+            dh, fh = grouped_width(d), grouped_width(f)
+            w_in = self.param("w_up", _zero_padded(init, (e, d, f)),
+                              (e, dh, fh))
+            w_down = self.param("w_down", _zero_padded(init, (e, f, d)),
+                                (e, fh, dh))
+            hidden = jnp.square(nn.relu(jax.lax.ragged_dot(
+                jnp.pad(xs, ((0, 0), (0, dh - d))), w_in.astype(self.dtype),
+                sizes)))
+            ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype),
+                                    sizes)[:, :d]
+        else:
+            # gate and up side by side: one grouped product for both
+            w_in = self.param("w_gate_up", init, (e, d, 2 * f))
+            w_down = self.param("w_down", init, (e, f, d))
+            gu = jax.lax.ragged_dot(xs, w_in.astype(self.dtype), sizes)
+            hidden = nn.silu(gu[:, :f]) * gu[:, f:]
+            ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype), sizes)
         out = jnp.zeros_like(ys).at[order].set(ys)
         out = out.astype(f32) * gate[:, None]
         if self.router != "mlp":
@@ -373,11 +422,16 @@ class DroplessMoE(nn.Module):
         out = out.astype(self.dtype)
         if self.shared_dim is not None:
             sf = self.shared_dim
-            gu = nn.Dense(2 * sf, use_bias=False, dtype=self.dtype,
-                          name="shared_gate_up")(xf)
+            if self.relu2:
+                hidden = jnp.square(nn.relu(nn.Dense(
+                    sf, use_bias=False, dtype=self.dtype,
+                    name="shared_up")(xf)))
+            else:
+                gu = nn.Dense(2 * sf, use_bias=False, dtype=self.dtype,
+                              name="shared_gate_up")(xf)
+                hidden = nn.silu(gu[:, :sf]) * gu[:, sf:]
             shared = nn.Dense(d, use_bias=False, dtype=self.dtype,
-                              name="shared_down")(
-                nn.silu(gu[:, :sf]) * gu[:, sf:])
+                              name="shared_down")(hidden)
             if self.shared_gate:
                 shared = (shared.astype(f32) * jax.nn.sigmoid(nn.Dense(
                     1, use_bias=False, dtype=self.dtype,

@@ -228,6 +228,25 @@ class TransformerConfig:
     # ``moe_shared_gate``: the shared expert's output is scaled by a
     # scalar ``sigmoid(x w)`` a token.
     moe_shared_gate: bool = False
+    # A stack whose blocks are ONE sublayer each behind one norm, ``x + f(
+    # ln1(x))``, in an order that is data: ``layer_pattern`` holds a letter
+    # a layer, ``num_layers`` of them: "M" a Mamba-2 mixer (``Mamba2Mixer``:
+    # ``mamba_num_heads`` heads of ``mamba_head_dim``, a float32 state
+    # ``[mamba_head_dim, mamba_state_size]`` a head that is a request's,
+    # B and C shared by the heads of each of ``mamba_n_groups`` groups), "*"
+    # ``Attention`` (``num_heads`` / ``num_kv_heads`` / ``head_dim`` describe
+    # it), "E" the dropless expert layer behind a one-matrix router, "-" the
+    # dense MLP. ``attn_kind_at``, ``moe_at``, ``attn_kinds`` and
+    # ``slot_state`` answer from the string; "E" and "-" layers own no cache.
+    # ``mlp="relu2"``: ``relu(mlp_up(x))^2 -> mlp_down``, two matrices, for
+    # the dense MLP, the routed and the shared experts alike.
+    # ``pos_embedding="none"``: no position table and no rotation (the
+    # state-space layers carry the order).
+    layer_pattern: Optional[str] = None
+    mamba_num_heads: Optional[int] = None
+    mamba_head_dim: Optional[int] = None
+    mamba_state_size: Optional[int] = None
+    mamba_n_groups: Optional[int] = None
 
     def __post_init__(self):
         if self.attn_kind not in ("mha", "cca", "kda", "mla", "gdn"):
@@ -236,6 +255,7 @@ class TransformerConfig:
                 "'mla' or 'gdn'")
         if self.experts_held is not None:  # a JSON list hashes as a tuple
             self.experts_held = tuple(int(i) for i in self.experts_held)
+        self._check_layer_pattern()
         self._check_linear_and_latent()
         self._check_expert_router()
         if self.moe_kind not in ("capacity", "dropless"):
@@ -304,8 +324,9 @@ class TransformerConfig:
             raise ValueError(
                 f"norm {self.norm!r} must be 'layernorm' or 'rmsnorm'"
             )
-        if self.mlp not in ("gelu", "swiglu"):
-            raise ValueError(f"mlp {self.mlp!r} must be 'gelu' or 'swiglu'")
+        if self.mlp not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(
+                f"mlp {self.mlp!r} must be 'gelu', 'swiglu' or 'relu2'")
         if self.mlp_dim is not None and self.mlp_dim < 1:
             raise ValueError(f"mlp_dim must be >= 1, got {self.mlp_dim}")
         if (self.n_experts and self.moe_kind == "capacity"
@@ -353,10 +374,10 @@ class TransformerConfig:
             raise ValueError(
                 f"num_heads {self.num_heads} not divisible by tp_size {self.tp_size}"
             )
-        if self.pos_embedding not in ("learned", "rope"):
+        if self.pos_embedding not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_embedding {self.pos_embedding!r} must be 'learned' "
-                "or 'rope'"
+                f"pos_embedding {self.pos_embedding!r} must be 'learned', "
+                "'rope' or 'none'"
             )
         if self.pos_embedding == "rope" and self.head_width % 2:
             raise ValueError(
@@ -400,6 +421,56 @@ class TransformerConfig:
             )
         if not 0.0 <= self.dropout < 1.0:
             raise ValueError(f"dropout must be in [0, 1), got {self.dropout}")
+
+    def _check_layer_pattern(self):
+        """What a stack of one-sublayer blocks (``layer_pattern``) can run,
+        and the keys that describe its "M" layers only."""
+        widths = (self.mamba_num_heads, self.mamba_head_dim,
+                  self.mamba_state_size, self.mamba_n_groups)
+        pattern = self.layer_pattern
+        if "M" not in (pattern or "") and widths != (None,) * 4:
+            raise ValueError(
+                "mamba_num_heads, mamba_head_dim, mamba_state_size and "
+                "mamba_n_groups describe the 'M' layers of a layer_pattern "
+                "only")
+        if pattern is None:
+            return
+        if set(pattern) - set("ME*-") or len(pattern) != self.num_layers:
+            raise ValueError(
+                f"layer_pattern {pattern!r} must hold one of 'M' (Mamba-2), "
+                "'E' (experts), '*' (attention) or '-' (dense MLP) for each "
+                f"of num_layers {self.num_layers} layers")
+        if (self.attn_kind != "mha" or self.layer_group_size
+                or self.first_k_dense_replace):
+            raise ValueError(
+                "layer_pattern gives every layer's kind itself: attn_kind "
+                "stays 'mha' (the '*' layers), layer_group_size and "
+                "first_k_dense_replace 0")
+        if ("E" in pattern) != bool(self.n_experts) or (
+                "E" in pattern and (self.moe_kind != "dropless"
+                                    or self.moe_router == "mlp")):
+            raise ValueError(
+                "the 'E' layers of a layer_pattern are moe_kind='dropless' "
+                "behind a one-matrix router over n_experts > 0 experts, and "
+                f"a pattern without one has none (n_experts {self.n_experts}, "
+                f"moe_kind {self.moe_kind!r}, moe_router {self.moe_router!r})")
+        if "M" not in pattern:
+            return
+        if None in widths or min(widths) < 1 or (
+                self.mamba_num_heads % self.mamba_n_groups):
+            raise ValueError(
+                f"an 'M' layer's {self.mamba_num_heads} heads of "
+                f"{self.mamba_head_dim} with a state of "
+                f"{self.mamba_state_size} must be whole groups of its "
+                f"{self.mamba_n_groups} B/C groups")
+        if (self.tp_size > 1 or self.ut_steps > 1
+                or self.attention != "dense"):
+            raise ValueError(
+                "an 'M' layer runs on one shard, one pass and "
+                "attention='dense': the recurrence and the convolution need "
+                "every earlier token and the state is one a request "
+                f"(tp_size {self.tp_size}, ut_steps {self.ut_steps}, "
+                f"attention {self.attention!r})")
 
     def _check_linear_and_latent(self):
         """What the linear kinds ("kda", "gdn") and "mla" can run, and the
@@ -491,13 +562,15 @@ class TransformerConfig:
                 "groups and its weights sum to one")
         e, g = self.n_experts, self.moe_n_group
         if self.moe_kind != "dropless" or not e or self.moe_dim is None or (
-                self.router_dim is not None or self.moe_every != 1):
+                self.router_dim is not None
+                or (self.moe_every != 1 and self.layer_pattern is None)):
             raise ValueError(
                 f"moe_router={self.moe_router!r} is moe_kind='dropless' "
                 "over n_experts > 0 experts of moe_dim features (the "
                 "'sigmoid' and 'softmax' routers alike), an expert layer "
                 "in every block from first_k_dense_replace on (moe_every "
-                "1), and no router_dim (one matrix, no MLP)")
+                "1) or where a layer_pattern says, and no router_dim (one "
+                "matrix, no MLP)")
         if g < 1 or e % g or not 1 <= self.moe_topk_group <= g or not (
                 1 <= self.moe_top_k <= self.moe_topk_group * (e // g)):
             raise ValueError(
@@ -531,13 +604,18 @@ class TransformerConfig:
     def attn_kind_at(self, layer: int) -> str:
         """The attention of layer ``layer``: ``attn_kind``, but
         ``full_attn_kind`` at every ``layer_group_size``-th layer of a
-        mixed stack."""
+        mixed stack; under a ``layer_pattern`` what its letter says, None
+        for a layer that has none."""
+        if self.layer_pattern is not None:  # None: an "E" or "-" layer
+            return {"M": "mamba2", "*": "mha"}.get(self.layer_pattern[layer])
         if self.layer_group_size and (layer + 1) % self.layer_group_size == 0:
             return self.full_attn_kind
         return self.attn_kind
 
     def moe_at(self, layer: int) -> bool:
         """Whether layer ``layer``'s MLP is the expert layer."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern[layer] == "E"
         return (bool(self.n_experts)
                 and layer % self.moe_every == self.moe_every - 1
                 and layer >= self.first_k_dense_replace)
@@ -546,14 +624,14 @@ class TransformerConfig:
     def attn_kinds(self) -> tuple:
         """The kinds of attention in the stack, a name once."""
         return tuple(sorted({self.attn_kind_at(i)
-                             for i in range(self.num_layers)}))
+                             for i in range(self.num_layers)} - {None}))
 
     @property
     def slot_state(self) -> bool:
         """Whether the cache holds state that is a REQUEST's and not a
         block's (``serving.kv_pool.SLOT_LEAVES``): "cca"'s tail, "kda"'s
-        state and convolution inputs, and "gdn"'s."""
-        return bool({"cca", "kda", "gdn"} & set(self.attn_kinds))
+        state and convolution inputs, "gdn"'s and "mamba2"'s."""
+        return bool({"cca", "kda", "gdn", "mamba2"} & set(self.attn_kinds))
 
     @property
     def linear_heads(self) -> int:
@@ -1351,6 +1429,14 @@ def delta_rule_update(s, q_t, k_t, v_t, a_t, b_t):
     return s, read + new * jnp.sum(write * q_t, -1, keepdims=True)
 
 
+def _in_blocks(x, n: int, c: int):
+    """``x`` [B, L, ...] as ``n`` blocks of ``c`` positions, the scan's
+    axis first: [n, B, c, ...], zeros behind L."""
+    b, l = x.shape[:2]
+    x = jnp.pad(x, ((0, 0), (0, n * c - l)) + ((0, 0),) * (x.ndim - 2))
+    return jnp.moveaxis(x.reshape((b, n, c) + x.shape[2:]), 1, 0)
+
+
 def delta_rule_blocks(s0, q, k, v, g, beta, block: int):
     """The delta rule over a sequence, ``block`` positions a step, from
     state ``s0`` [B, H, D, D]: ``q``, ``k``, ``v`` are [B, L, H, D], the
@@ -1377,10 +1463,6 @@ def delta_rule_blocks(s0, q, k, v, g, beta, block: int):
     lower = jnp.tril(jnp.ones((c, c), bool))
     high = jax.lax.Precision.HIGHEST
 
-    def blocks(x):  # [B, L, ...] -> [n, B, c, ...], zeros behind L
-        x = jnp.pad(x, ((0, 0), (0, n * c - l)) + ((0, 0),) * (x.ndim - 2))
-        return jnp.moveaxis(x.reshape((b, n, c) + x.shape[2:]), 1, 0)
-
     def step(s, xs):
         q, k, v, g, beta = xs
         run = jnp.cumsum(g, axis=1)  # G_t [B, c, H, D]
@@ -1406,22 +1488,23 @@ def delta_rule_blocks(s0, q, k, v, g, beta, block: int):
             precision=high)
         return s, o
 
-    s1, o = jax.lax.scan(step, s0,
-                         tuple(blocks(x) for x in (q, k, v, g, beta)))
+    s1, o = jax.lax.scan(step, s0, tuple(_in_blocks(x, n, c)
+                                         for x in (q, k, v, g, beta)))
     return s1, jnp.moveaxis(o, 0, 1).reshape(b, n * c, h, d)[:, :l]
 
 
 class _SlotStateAttention(nn.Module):
-    """What the delta-rule layers (``KDAttention``, ``GatedDeltaNet``)
-    share: a request carries from one call to the next no K/V row but a
-    float32 STATE ``[H, D, D]`` (``cache/state``) and the convolutions'
+    """What the recurrent layers (``KDAttention``, ``GatedDeltaNet``,
+    ``Mamba2Mixer``) share: a request carries from one call to the next no
+    K/V row but a float32 STATE (``cache/state``: ``[H, D, D]`` for the
+    delta rule, ``[H, P, N]`` for Mamba-2) and the convolutions'
     last ``TAPS - 1`` inputs (``cache/conv``): one row a request, zero for
     a row that starts at position 0 whatever the row held, advanced over
     the row's REAL positions only (``lengths``). In the paged layout the
     leaves are ``[n_slots + 1, ...]``. A chunk program reads and writes the
     rows ``slots`` names (the last row is the trash row of padding jobs)
     and runs the recurrence ``BLOCK`` positions a step
-    (``delta_rule_blocks``); a decode tick's row ``i`` IS slot ``i`` (the
+    (``delta_rule_blocks``, ``ssm_blocks``); a decode tick's row ``i`` IS slot ``i`` (the
     engine's tick has a lane a slot), so the tick updates the leaves where
     they lie, once read and once written, and a lane that is not live
     (``lengths`` 0: inactive, or in mid-prefill) keeps what it held."""
@@ -1439,11 +1522,13 @@ class _SlotStateAttention(nn.Module):
 
     @nn.nowrap
     def _held(self, b, l, position_offset, block_tables, slots, lengths,
-              heads, d, conv_width):
+              state, conv_width):
         """The state and the convolution inputs this call starts from:
         ``(s0, c0, real, keep)``, ``real`` each row's real positions and
         ``keep(window, s1)`` what writes the row's new state and last
-        inputs back (``window``: ``c0`` in front of this call's inputs)."""
+        inputs back (``window``: ``c0`` in front of this call's inputs).
+        ``state`` is the shape of ONE row's state, the subclass's own
+        (``[H, D, D]`` for the delta rule, ``[H, P, N]`` for Mamba-2)."""
         cfg, f32, taps = self.config, jnp.float32, self.TAPS
         cached = self.decode or self.prefill
         paged = block_tables is not None
@@ -1475,7 +1560,7 @@ class _SlotStateAttention(nn.Module):
             else:
                 state_var = self.variable(
                     "cache", "state",
-                    lambda: jnp.zeros((b, heads, d, d), f32))
+                    lambda: jnp.zeros((b,) + state, f32))
                 conv_var = self.variable(
                     "cache", "conv",
                     lambda: jnp.zeros((b, taps - 1, conv_width), cfg.dtype))
@@ -1486,7 +1571,7 @@ class _SlotStateAttention(nn.Module):
             c0 = jnp.where(fresh[:, None, None],
                            jnp.zeros((), held_c.dtype), held_c)
         else:
-            s0 = jnp.zeros((b, heads, d, d), f32)
+            s0 = jnp.zeros((b,) + state, f32)
             c0 = jnp.zeros((b, taps - 1, conv_width), cfg.dtype)
         real = (jnp.full((b,), l, jnp.int32) if lengths is None
                 else lengths.astype(jnp.int32))
@@ -1510,14 +1595,16 @@ class _SlotStateAttention(nn.Module):
         return s0, c0, real, keep
 
     @nn.nowrap
-    def _convolved(self, c0, pre, conv_w):
+    def _convolved(self, c0, pre, conv_w, bias=None):
         """(``c0`` in front of ``pre``, the SiLU of the depthwise causal
-        convolution of ``TAPS`` taps over it, float32 [B, L, channels])."""
+        convolution of ``TAPS`` taps over it, plus ``bias`` a channel where
+        the layer has one, float32 [B, L, channels])."""
         l, f32 = pre.shape[1], jnp.float32
         window = jnp.concatenate([c0, pre], axis=1)
-        return window, nn.silu(sum(conv_w[j].astype(f32)
-                                   * window[:, j:j + l].astype(f32)
-                                   for j in range(self.TAPS)))
+        conv = sum(conv_w[j].astype(f32) * window[:, j:j + l].astype(f32)
+                   for j in range(self.TAPS))
+        return window, nn.silu(conv if bias is None
+                               else conv + bias.astype(f32))
 
 
 def _unit(t):
@@ -1576,7 +1663,7 @@ class KDAttention(_SlotStateAttention):
         gate_o = dense(inner, "gate_o")
 
         s0, c0, real, keep = self._held(
-            b, l, position_offset, block_tables, slots, lengths, h, d,
+            b, l, position_offset, block_tables, slots, lengths, (h, d, d),
             3 * inner)
         window, qkv = self._convolved(c0, pre, conv_w)
         q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(b, l, h, d)
@@ -1667,7 +1754,7 @@ class GatedDeltaNet(_SlotStateAttention):
             ba[..., hv:] + dt_bias.astype(f32))
 
         s0, c0, real, keep = self._held(
-            b, l, position_offset, block_tables, slots, lengths, hv, d,
+            b, l, position_offset, block_tables, slots, lengths, (hv, d, d),
             conv_width)
         window, qkv = self._convolved(c0, pre, conv_w)
         q = qkv[..., :keys].reshape(b, l, hk, d)
@@ -1695,6 +1782,163 @@ class GatedDeltaNet(_SlotStateAttention):
         o = (o.reshape(b, l, values) * nn.silu(z.astype(f32))).astype(
             cfg.dtype)
         out = nn.Dense(e, use_bias=False, dtype=cfg.dtype, name="proj")(o)
+        if cfg.dropout:
+            out = nn.Dropout(cfg.dropout,
+                             deterministic=self.deterministic)(out)
+        return out
+
+
+def ssm_update(s, x_t, b_t, c_t, a_t, dt_t):
+    """One token of the Mamba-2 recurrence: ``s`` [B, H, P, N], ``x_t``
+    [B, H, P], ``b_t``, ``c_t`` [B, G, N] (a group of ``H / G`` heads
+    shares them), the decay ``a_t`` and the step ``dt_t`` [B, H]. Returns (the new
+    state ``a S + dt x B^T``, ``y_t = S' C``). Float32 multiplies and sums on
+    the vector unit; both results read the OLD state and the new one is
+    written once: ``S' C = a (S C) + dt x (B . C)``, so nothing reads the
+    state just written (``delta_rule_update``'s form; XLA keeps the read
+    and the update in two fusions, two passes over the old state)."""
+    share = s.shape[1] // b_t.shape[1]
+    b_t, c_t = (jnp.repeat(t, share, axis=1) for t in (b_t, c_t))
+    write = dt_t[..., None] * x_t  # [B, H, P]
+    read = jnp.sum(s * c_t[..., None, :], axis=-1)
+    s = a_t[..., None, None] * s + write[..., None] * b_t[..., None, :]
+    return s, a_t[..., None] * read + write * jnp.sum(
+        b_t * c_t, -1, keepdims=True)
+
+
+def ssm_blocks(s0, x, bm, cm, g, dt, block: int):
+    """The Mamba-2 recurrence over a sequence, ``block`` positions a step,
+    from state ``s0`` [B, H, P, N]: ``x`` [B, L, H, P], ``bm``, ``cm``
+    [B, L, G, N] (a group of ``H / G`` heads shares them), the log decay
+    ``g`` and the step ``dt`` [B, L, H], all float32. Returns (the state
+    after position L - 1, every position's ``y`` [B, L, H, P]). The same
+    function as a token at a time: nothing is subtracted from what the state
+    holds, so inside a block, with ``l_t`` the running sum of ``g``,
+
+        y_t = sum_{s<=t} e^{l_t - l_s} (C_t . B_s) dt_s x_s + e^{l_t} S_0 C_t
+        S_end = e^{l_last} S_0 + sum_s e^{l_last - l_s} dt_s x_s B_s^T
+
+    and no system is solved: the state is read and written once a block and
+    the products run on the matrix unit at the highest precision. Every
+    exponent is a later position's sum less an earlier one's, at most 0:
+    nothing overflows however fast a head forgets. A position with ``g`` 0
+    and ``dt`` 0 (padding) neither decays the state nor writes to it."""
+    b, l, h, p = x.shape
+    groups = bm.shape[2]
+    c = min(block, l)
+    n = -(-l // c)
+    lower = jnp.tril(jnp.ones((c, c), bool))
+    high = jax.lax.Precision.HIGHEST
+
+    def heads(t, axis=2):  # a group's values for each of its heads
+        return jnp.repeat(t, h // groups, axis=axis)
+
+    def step(s, xs):
+        x, bm, cm, g, dt = xs
+        run = jnp.cumsum(g, axis=1)  # l_t [B, c, H]
+        # e^{l_t - l_s} for s <= t, 0 above the diagonal [B, t, s, H]
+        decay = jnp.exp(jnp.where(lower[None, :, :, None],
+                                  run[:, :, None] - run[:, None, :],
+                                  -jnp.inf))
+        cb = jnp.einsum("btgn,bsgn->btsg", cm, bm, precision=high)
+        write = dt[..., None] * x  # [B, c, H, P]
+        y = jnp.einsum("btsh,bshp->bthp", heads(cb, 3) * decay, write,
+                       precision=high) + jnp.exp(run)[..., None] * jnp.einsum(
+            "bthn,bhpn->bthp", heads(cm), s, precision=high)
+        left = jnp.exp(run[:, -1:] - run)  # e^{l_last - l_s} [B, c, H]
+        s = jnp.exp(run[:, -1])[..., None, None] * s + jnp.einsum(
+            "bshp,bshn->bhpn", left[..., None] * write, heads(bm),
+            precision=high)
+        return s, y
+
+    s1, y = jax.lax.scan(step, s0, tuple(_in_blocks(t, n, c)
+                                         for t in (x, bm, cm, g, dt)))
+    return s1, jnp.moveaxis(y, 0, 1).reshape(b, n * c, h, p)[:, :l]
+
+
+class Mamba2Mixer(_SlotStateAttention):
+    """The Mamba-2 state-space mixer (the "M" layers of a
+    ``layer_pattern``; arXiv:2405.21060, as
+    ``perfbench/references/nemotron_h.py`` writes it down).
+
+    One projection takes the normed state to ``[z | x~ B~ C~ | dt]``
+    (``H P``, ``H P + 2 G N`` and ``H`` channels: ``H`` =
+    ``mamba_num_heads`` heads of ``P`` = ``mamba_head_dim``, ``G`` =
+    ``mamba_n_groups`` groups whose heads share ``B`` and ``C`` of ``N`` =
+    ``mamba_state_size``). ONE depthwise causal convolution of ``TAPS``
+    taps WITH a bias and a SiLU runs over the x~, B~, C~ channels. A head
+    keeps ``S`` ``[P, N]`` float32::
+
+        S <- a_t S + Delta_t x_t B_t^T;   y_t = S C_t + D_h x_t
+
+    with ``Delta_t = softplus(dt_t + dt_bias_h)`` and ``a_t =
+    exp(-exp(A_log_h) Delta_t)``: the step scales the decay and the write,
+    and nothing is subtracted from what the state holds. The output is ``y *
+    SiLU(z)`` RMS-normed over each group's ``H P / G`` channels (one learned
+    scale a channel) and projected back. The tick updates the state with
+    float32 multiplies and sums (``ssm_update``); a sequence runs ``BLOCK``
+    positions a step (``ssm_blocks``). The state and the convolution's last
+    inputs are a request's (``_SlotStateAttention``); ``TAPS`` is the
+    published ``conv_kernel``.
+    """
+
+    #: no triangular solve bounds a block, so a step takes four times the
+    #: delta rule's positions
+    BLOCK = 64
+
+    @nn.compact
+    def __call__(self, x, position_offset, block_tables=None, slots=None,
+                 lengths=None):
+        cfg = self.config
+        b, l, e = x.shape
+        f32 = jnp.float32
+        h, p, n, groups = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                           cfg.mamba_state_size, cfg.mamba_n_groups)
+        inner, bc = h * p, groups * n
+        conv_width = inner + 2 * bc
+
+        zxbcdt = nn.Dense(2 * inner + 2 * bc + h, use_bias=False,
+                          dtype=cfg.dtype, name="in_proj")(x)
+        z = zxbcdt[..., :inner]
+        pre = zxbcdt[..., inner:inner + conv_width]
+        conv_w = self.param("conv_kernel", nn.initializers.normal(0.02),
+                            (self.TAPS, conv_width))
+        conv_b = self.param("conv_bias", nn.initializers.zeros,
+                            (conv_width,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (h,))
+        a_log = self.param("A_log", nn.initializers.zeros, (h,))
+        skip = self.param("D", nn.initializers.ones, (h,))
+        dt = jax.nn.softplus(zxbcdt[..., inner + conv_width:].astype(f32)
+                             + dt_bias.astype(f32))  # [B, L, H]
+        g = -jnp.exp(a_log.astype(f32)) * dt  # log a < 0
+
+        s0, c0, real, keep = self._held(
+            b, l, position_offset, block_tables, slots, lengths, (h, p, n),
+            conv_width)
+        window, xbc = self._convolved(c0, pre, conv_w, conv_b)
+        xs = xbc[..., :inner].reshape(b, l, h, p)
+        bm = xbc[..., inner:inner + bc].reshape(b, l, groups, n)
+        cm = xbc[..., inner + bc:].reshape(b, l, groups, n)
+
+        if l == 1:
+            s1, y = ssm_update(s0, xs[:, 0], bm[:, 0], cm[:, 0],
+                               jnp.exp(g[:, 0]), dt[:, 0])
+            y = y[:, None]
+        else:
+            # a padding position neither decays the state nor writes to it
+            valid = (jnp.arange(l)[None, :] < real[:, None])[..., None]
+            s1, y = ssm_blocks(s0, xs, bm, cm, jnp.where(valid, g, 0.0),
+                               jnp.where(valid, dt, 0.0), self.BLOCK)
+        keep(window, s1)
+
+        y = y + skip.astype(f32)[:, None] * xs
+        y = y.reshape(b, l, inner) * nn.silu(z.astype(f32))
+        # an RMS norm a group of channels, one learned scale a channel
+        y = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm",
+                       feature_axes=(-2, -1), reduction_axes=-1)(
+            y.reshape(b, l, groups, inner // groups))
+        out = nn.Dense(e, use_bias=False, dtype=cfg.dtype, name="proj")(
+            y.reshape(b, l, inner).astype(cfg.dtype))
         if cfg.dropout:
             out = nn.Dropout(cfg.dropout,
                              deterministic=self.deterministic)(out)
@@ -1867,7 +2111,8 @@ class Block(nn.Module):
     decode: bool = False
     prefill: bool = False
     #: this layer's attention where the stack mixes kinds
-    #: (``TransformerConfig.attn_kind_at``); None: the config's
+    #: (``TransformerConfig.attn_kind_at``); None: the config's, or under a
+    #: ``layer_pattern`` a layer that is no attention
     attn_kind: Optional[str] = None
 
     @nn.compact
@@ -1879,9 +2124,14 @@ class Block(nn.Module):
         tail, ``KDAttention``'s state) and for the rows an expert layer may
         route (``lengths`` real positions a row; None: all). A dropless
         expert block takes the previous block's ``router_state`` and
-        returns ``(x, router_state)``; every other block returns ``x``."""
+        returns ``(x, router_state)``; every other block returns ``x``.
+        Under a ``layer_pattern`` the block is ``x + f(ln1(x))`` with ``f``
+        ONE of the sublayers (``attn``, ``moe``, ``mlp_*``)."""
         cfg = self.config
-        attn_kind = self.attn_kind or cfg.attn_kind
+        # under a ``layer_pattern`` the block is ONE sublayer behind ``ln1``
+        # and ``attn_kind`` None says that it is not an attention
+        single = cfg.layer_pattern is not None
+        attn_kind = self.attn_kind or (None if single else cfg.attn_kind)
 
         def joins(x, out, sublayer: int):
             """The stream after a sublayer's output has joined it."""
@@ -1891,25 +2141,26 @@ class Block(nn.Module):
                 out = _norm(cfg, f"ln{sublayer}_post")(out).astype(cfg.dtype)
             return x + out
 
-        h = _norm(cfg, "ln1")(x)
-        mode = dict(deterministic=self.deterministic, decode=self.decode,
-                    prefill=self.prefill, name="attn")
-        if attn_kind == "cca":
-            out = CCAttention(cfg, **mode)(
-                h, position_offset, positions, block_tables, slots, lengths)
-        elif attn_kind in ("kda", "gdn"):
-            linear = KDAttention if attn_kind == "kda" else GatedDeltaNet
-            out = linear(cfg, **mode)(
-                h, position_offset, block_tables, slots, lengths)
-        elif attn_kind == "mla":
-            out = MLAttention(cfg, **mode)(
-                h, position_offset, positions, block_tables)
-        else:
-            out = Attention(cfg, **mode)(
+        def attend(h):
+            mode = dict(deterministic=self.deterministic, decode=self.decode,
+                        prefill=self.prefill, name="attn")
+            if attn_kind == "cca":
+                return CCAttention(cfg, **mode)(
+                    h, position_offset, positions, block_tables, slots,
+                    lengths)
+            if attn_kind in ("kda", "gdn", "mamba2"):
+                linear = {"kda": KDAttention, "gdn": GatedDeltaNet,
+                          "mamba2": Mamba2Mixer}[attn_kind]
+                return linear(cfg, **mode)(
+                    h, position_offset, block_tables, slots, lengths)
+            if attn_kind == "mla":
+                return MLAttention(cfg, **mode)(
+                    h, position_offset, positions, block_tables)
+            return Attention(cfg, **mode)(
                 h, position_offset, positions, block_tables, pass_index)
-        x = joins(x, out, 1)
-        h = _norm(cfg, "ln2")(x)
-        if self.use_moe and cfg.moe_kind == "dropless":
+
+        def dropless(h):
+            """(the dropless expert layer's output, its router state)"""
             from pytorch_distributed_tpu.models.moe import DroplessMoE
 
             live = (None if lengths is None else
@@ -1920,8 +2171,9 @@ class Block(nn.Module):
                 routed_scale=cfg.moe_routed_scale,
                 shared_dim=cfg.moe_shared_dim, held=cfg.experts_held,
                 shared_gate=cfg.moe_shared_gate,
+                relu2=cfg.mlp == "relu2",
             ) if cfg.moe_router != "mlp" else {}
-            out, router_state = DroplessMoE(
+            out, state = DroplessMoE(
                 n_experts=cfg.n_experts, moe_dim=cfg.moe_dim,
                 router_dim=cfg.router_dim, norm_eps=cfg.norm_eps,
                 dtype=cfg.dtype, name="moe", **one_matrix,
@@ -1929,7 +2181,49 @@ class Block(nn.Module):
             if cfg.dropout:
                 out = nn.Dropout(cfg.dropout,
                                  deterministic=self.deterministic)(out)
-            return joins(x, out, 2), router_state
+            return out, state
+
+        def dense_mlp(h):
+            if cfg.model_axis:
+                from pytorch_distributed_tpu.parallel.tensor import (
+                    tp_copy,
+                    tp_reduce,
+                )
+
+                h = tp_copy(h, cfg.model_axis)  # column-parallel mlp_up
+            width = cfg.mlp_width // cfg.tp_size
+            up = nn.Dense(width, use_bias=cfg.use_bias, dtype=cfg.dtype,
+                          name="mlp_up")(h)
+            if cfg.mlp == "swiglu":
+                h = nn.silu(nn.Dense(width, use_bias=cfg.use_bias,
+                                     dtype=cfg.dtype, name="mlp_gate")(h)) * up
+            elif cfg.mlp == "relu2":
+                h = jnp.square(nn.relu(up))
+            else:
+                h = nn.gelu(up)
+            # Row-parallel mlp_down: bias-free (see Attention.proj).
+            h = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                         name="mlp_down")(h)
+            if cfg.model_axis:
+                h = tp_reduce(h, cfg.model_axis)
+            if cfg.dropout:  # after tp_reduce — see Attention
+                h = nn.Dropout(cfg.dropout,
+                               deterministic=self.deterministic)(h)
+            return h
+
+        h = _norm(cfg, "ln1")(x)
+        if single:
+            if attn_kind is not None:
+                return joins(x, attend(h), 1)
+            if self.use_moe:
+                out, state = dropless(h)
+                return joins(x, out, 1), state
+            return joins(x, dense_mlp(h), 1)
+        x = joins(x, attend(h), 1)
+        h = _norm(cfg, "ln2")(x)
+        if self.use_moe and cfg.moe_kind == "dropless":
+            out, state = dropless(h)
+            return joins(x, out, 2), state
         if self.use_moe:
             from pytorch_distributed_tpu.models.moe import MoEMLP
 
@@ -1949,25 +2243,7 @@ class Block(nn.Module):
             if cfg.dropout:  # residual dropout, same placement as dense MLP
                 out = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(out)
             return joins(x, out, 2)
-        if cfg.model_axis:
-            from pytorch_distributed_tpu.parallel.tensor import tp_copy, tp_reduce
-
-            h = tp_copy(h, cfg.model_axis)  # column-parallel mlp_up
-        width = cfg.mlp_width // cfg.tp_size
-        up = nn.Dense(width, use_bias=cfg.use_bias, dtype=cfg.dtype,
-                      name="mlp_up")(h)
-        if cfg.mlp == "swiglu":
-            h = nn.silu(nn.Dense(width, use_bias=cfg.use_bias,
-                                 dtype=cfg.dtype, name="mlp_gate")(h)) * up
-        else:
-            h = nn.gelu(up)
-        # Row-parallel mlp_down: bias-free (see Attention.proj).
-        h = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype, name="mlp_down")(h)
-        if cfg.model_axis:
-            h = tp_reduce(h, cfg.model_axis)
-        if cfg.dropout:  # after tp_reduce — see Attention
-            h = nn.Dropout(cfg.dropout, deterministic=self.deterministic)(h)
-        return joins(x, h, 2)
+        return joins(x, dense_mlp(h), 2)
 
 
 class TransformerLM(nn.Module):
@@ -2079,7 +2355,8 @@ class TransformerLM(nn.Module):
             x = x + nn.Embed(
                 cfg.max_seq_len, cfg.embed_dim, dtype=cfg.dtype, name="wpe"
             )(pos)
-        # rope: no wpe table — Attention rotates q/k from the same pos
+        # rope: no wpe table — Attention rotates q/k from the same pos;
+        # none: neither (the stack's recurrent layers carry the order)
         if cfg.dropout and not inference:
             x = nn.Dropout(cfg.dropout, deterministic=deterministic)(x)
 

@@ -169,8 +169,8 @@ def pool_heads(k_pool: jax.Array, h: int, d: int):
 #: the paged read's spellings (``paged_attention``'s ``gather_impl``)
 GATHER_IMPLS = ("dense", "pallas")
 #: most query rows a narrow head brings to a kernel step (group x chunk)
-#: for the fused kernel to be the unnamed read: one sublane tile, a decode
-#: tick's. Measured on a v5e (PERF.md section 6, PR 28): at 8 rows a live
+#: for the fused kernel to be the unnamed read: a decode tick's. Measured on
+#: a v5e (PERF.md section 6, PR 28): at 8 rows a live
 #: block cost the kernel 1.0 us and a dead one 0.1 us where the dense
 #: gather pays 1.4 us for either, so a decode tick over capacity-wide
 #: tables ran three to five times faster through the kernel; at a
@@ -178,8 +178,14 @@ GATHER_IMPLS = ("dense", "pallas")
 #: table slice is cut to its prompts, so the dense gather won there.
 #: Since PR 30 a grid step of the kernel is a tile of blocks (128
 #: positions); its chunk-row numbers are in PERF.md section 6, for the
-#: issue that may move this.
-KERNEL_MAX_ROWS = 8
+#: issue that may move this. At 16 rows (PR 44: 32 query heads over 2 K/V
+#: heads of 128, the folded body's 32 columns; 256 lanes over tables of
+#: 2,560 positions, 580 of them live in the mean) a whole decode tick took
+#: 34.75 ms through the kernel and 43.60 ms through the dense gather, which
+#: copies 2 x 671 MB of float32 a tick (one seed, one traced run each,
+#: PERF.md section 6): the bound moved from 8 to 16. Nothing between 17 and
+#: 31 rows has been read.
+KERNEL_MAX_ROWS = 16
 #: most bytes the dense gather may write for a program to take it whatever
 #: its rows: the gather copies every row's whole table into HBM as float32
 #: (``dense_gather_bytes``), and a tick of 256 lanes over 3,072 positions

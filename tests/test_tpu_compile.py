@@ -475,25 +475,26 @@ def test_the_served_tick_reads_through_the_kernel_where_the_backend_is_a_tpu(
 
 #: a tiny grouped-query engine at gpt2-medium's head width: 16 query
 #: heads over 2 narrow heads is a group of 8 (128 lanes a pool row, a
-#: block Mosaic accepts), over 1 a group of 16
+#: block Mosaic accepts), 32 over 2 a group of 16, 64 over 2 of 32
 GROUPED_CELL = dict(heads=16, head_dim=64, slots=8, blocks=65, block_len=16,
                     chunk=32, max_seq_len=128)
 
 
-@pytest.mark.parametrize("kv_heads,program,kernel", [
-    pytest.param(2, "decode_tick", True, id="group8-tick"),
-    pytest.param(1, "decode_tick", False, id="group16-tick"),
-    pytest.param(2, "chunk_prefill[k=4,w=8]", False, id="group8-chunk"),
+@pytest.mark.parametrize("heads,kv_heads,program,kernel", [
+    pytest.param(16, 2, "decode_tick", True, id="group8-tick"),
+    pytest.param(32, 2, "decode_tick", True, id="group16-tick"),
+    pytest.param(64, 2, "decode_tick", False, id="group32-tick"),
+    pytest.param(16, 2, "chunk_prefill[k=4,w=8]", False, id="group8-chunk"),
 ])
 def test_the_rule_counts_the_rows_a_grouped_head_brings(
-        v5e, monkeypatch, kv_heads, program, kernel):
+        v5e, monkeypatch, heads, kv_heads, program, kernel):
     """The rule's boundary, read through a program: a narrow head's
-    query group folds into the kernel's rows, so a tick of 8 query heads
-    a narrow head (8 rows, ``KERNEL_MAX_ROWS``) compiles the fused
-    kernel, a tick of 16 a narrow head does not, and a chunk's 8 x 32
-    rows do not."""
+    query group folds into the kernel's rows, so a tick of 8 or of 16 query
+    heads a narrow head (16 rows, ``KERNEL_MAX_ROWS`` since PR 44's
+    reading) compiles the fused kernel, a tick of 32 a narrow head does
+    not, and a chunk's 8 x 32 rows do not."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    lowered, _ = _engine_program(v5e, program, GROUPED_CELL,
+    lowered, _ = _engine_program(v5e, program, dict(GROUPED_CELL, heads=heads),
                                  num_kv_heads=kv_heads)
     text = lowered.compile().as_text()
     assert ("paged_decode_attn" in text) == kernel
@@ -856,3 +857,117 @@ def test_the_qwen3_next_programs_compile_for_the_chip(v5e, monkeypatch,
     assert memory.alias_size_in_bytes >= state.size * 4 + 2 * keys.size * 2
     assert memory.temp_size_in_bytes < (
         1 << 27 if program == "decode_tick" else 3 << 29)
+
+
+# ---- the nemotron-h stack's served programs (PR 44) ------------------------
+
+#: nemotron-3-nano-30b-a3b.assistant-backlog's Mamba-2 mixer, experts,
+#: attention, state, pool and slots (perfbench/configs, perfbench/cells), on
+#: one block of each kind and a small vocabulary
+NEMO_CELL = dict(slots=256, blocks=40961, block_len=16, chunk=128,
+                 max_seq_len=2560)
+NEMO_BLOCK = dict(
+    layer_pattern="ME*", embed_dim=2688, num_heads=32, num_kv_heads=2,
+    head_dim=128, pos_embedding="none", norm="rmsnorm", norm_eps=1e-5,
+    use_bias=False, mlp="relu2", mamba_num_heads=64, mamba_head_dim=64,
+    mamba_state_size=128, mamba_n_groups=8, n_experts=128,
+    moe_kind="dropless", moe_router="sigmoid", moe_top_k=6,
+    moe_routed_scale=2.5, moe_dim=1856, moe_shared_dim=3712,
+    experts_held=(0, 64))
+
+
+@pytest.mark.parametrize("program", ["decode_tick",
+                                     "chunk_prefill[k=8,w=128]"])
+def test_the_nemotron_h_programs_compile_for_the_chip(v5e, monkeypatch,
+                                                      program):
+    """The tick reads the attention block's REAL keys and values through the
+    fused kernel's folded body (2 narrow heads x 16 query rows: 32 (row,
+    head) columns over K and V tiles of 256 lanes); the chunk program
+    gathers dense over its own table slice. Both run the held experts as
+    TWO grouped products a block, each in 512 x 512 tiles (the stacks are
+    held 3,072 x 2,048: at 2,688 x 1,856 XLA takes 128 x 128 tiles and
+    copies a stack a call), update the float32 state where it lies (no copy
+    of a state leaf) and move no pool-sized array; the expert block owns no
+    cache leaf."""
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = NEMO_CELL
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=3, max_seq_len=c["max_seq_len"],
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense", **NEMO_BLOCK)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    assert params["block1"]["moe"]["w_up"].shape == (64, 3072, 2048)
+    assert params["block1"]["moe"]["w_down"].shape == (64, 2048, 3072)
+    n = c["slots"]
+    eng = PagedEngine(cfg, params, n, n_blocks=2, block_len=c["block_len"],
+                      prefill_chunk=c["chunk"], chunk_bucket_floor=(8, 128),
+                      max_chunk_jobs=8)
+    assert eng.gather_impl == "pallas" and eng.tile_blocks == 8
+    assert eng.heads_folded == 2
+    # ONE chunk program for prompts up to 2,048 positions (a resumed
+    # request past them would take the table's whole width)
+    assert eng.chunk_buckets() == [(8, 128), (8, 160)]
+    pool = jax.eval_shape(
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
+                                   n_slots=n), params)
+    assert sorted(pool) == ["block0", "block2"]  # the experts hold nothing
+    state = pool["block0"]["attn"]["state"]
+    keys = pool["block2"]["attn"]["key"]
+    assert state.shape == (n + 1, 64, 64, 128) and state.dtype == jnp.float32
+    assert pool["block0"]["attn"]["conv"].shape == (n + 1, 3, 6144)
+    assert keys.shape == (c["blocks"], c["block_len"], 256)
+    one = SingleDeviceSharding(v5e.devices[0])
+    if program == "decode_tick":
+        fn, operands = _tick_operands(eng)
+    else:
+        fn, operands = _chunk_operands(eng, 8, 128)
+        assert eng.chunk_program_name(8, 128) == program
+    args = (params, pool, eng.logits) + operands
+    compiled = fn.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        args)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    reads = [x for x in calls if "paged_decode_attn" in x]
+    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
+    assert len(reads) == (1 if program == "decode_tick" else 0), calls
+    assert len(grouped) == 2, calls  # up, and down: no gate matrix
+    assert re.findall(r'ragged_dot_tiling="([\d,]+)"', text) == [
+        "512,512,512"] * 2
+    # the folded body: the query is block-diagonal, 2 x 16 (row, head)
+    # columns of both heads' 256 lanes, and the output leaves lane-dense
+    assert all("bf16[256,32,256]" in x and "bf16[256,16,256]" in x
+               for x in _kernel_reads(text))
+    # the counts come back beside what the programs returned before: one
+    # expert block, the experts held
+    shapes = [tuple(s.shape) for s in jax.tree.leaves(
+        jax.eval_shape(fn, *args))]
+    assert shapes[-1] == (1, 64)
+    # neither a state leaf, a pool nor an expert stack is copied or
+    # transposed
+    stack = params["block1"]["moe"]["w_up"]
+    moved = [m.group(1) for m in re.finditer(
+        r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
+        if math.prod(map(int, m.group(2).split(","))) in (
+            state.size, keys.size, stack.size)]
+    assert not moved, moved
+    # the tick gathers no lane's table: nothing of [lanes, positions, ...]
+    rows = c["max_seq_len"]
+    if program == "decode_tick":
+        assert not re.search(rf"f32\[{n},(?:{rows}|{rows // 16},16),", text)
+    # and what it holds beside its arguments is small: state and pools are
+    # donated and updated in place
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state.size * 4 + 2 * keys.size * 2
+    assert memory.temp_size_in_bytes < (
+        1 << 28 if program == "decode_tick" else 3 << 29)
