@@ -399,10 +399,13 @@ def server_phase() -> None:
         first_ok = all(f[0] == w[0] for f, w in zip(other, want))
         rec.update(default_read=default, tile_blocks=engine.tile_blocks,
                    heads_folded=engine.heads_folded,
+                   grouped_rows=engine.grouped_rows,
                    pallas_agreement=round(rate, 4),
                    pallas_stream_agreement=round(agreement(want, other), 4),
                    pallas_first_tokens_agree=first_ok,
                    pallas_programs={n: KERNEL in t for n, t in texts.items()})
+        require(rec, engine.grouped_rows == 0,
+                "a model without experts reports a grouped product")
         require(rec, first_ok, "pallas and dense disagree on a first token")
         require(rec, rate >= 0.9, f"pallas/dense agreement {rate:.3f} < 0.9")
         require(rec, second.engine.allocator.in_use == 0,
@@ -620,7 +623,15 @@ def zaya_phase() -> None:
         eng = PagedEngine(cfg, params, slots, n_blocks=65, block_len=16,
                           prefill_chunk=chunk)
         rec.update(read=eng.gather_impl, tile_blocks=eng.tile_blocks,
-                   heads_folded=eng.heads_folded)
+                   heads_folded=eng.heads_folded,
+                   grouped_rows=eng.grouped_rows)
+        # the experts' products are the repo's kernel on the chip, a row
+        # tile of all the tick's rows (top-1: a pair a slot), XLA's elsewhere
+        from pytorch_distributed_tpu.ops.grouped_matmul import row_tile
+
+        require(rec, eng.grouped_rows == (
+            row_tile(slots) if jax.default_backend() == "tpu" else 0),
+            f"grouped_rows {eng.grouped_rows} for {slots} slots")
         prompts = make_prompts(cfg, [chunk + chunk // 2 + 1, chunk - 3])
         for slot, prompt in enumerate(prompts):
             require(rec, eng.admit(slot, len(prompt), ticks), "admission")

@@ -215,7 +215,9 @@ class MoEMLP(nn.Module):
 #: runs them 512 x 512 a grid step, any other width 128 x 128: sixteen times
 #: the steps, and a step costs its fixed half microsecond whatever it moves
 #: (64 experts of 2,688 x 1,856: 9.6 ms a product where its bytes take 0.8,
-#: PERF.md section 6, PR 44)
+#: PERF.md section 6, PR 44). The two-matrix stacks are still HELD in these
+#: tiles though the chip's product is ``ops.grouped_matmul`` since PR 45,
+#: which takes any width: dropping the zeros changes the held shapes
 GROUPED_TILE = 512
 
 
@@ -226,6 +228,32 @@ def grouped_width(width: int) -> int:
     if width < GROUPED_TILE:
         return width
     return -(-width // GROUPED_TILE) * GROUPED_TILE
+
+
+def grouped_rows(tokens: int, top_k: int) -> int:
+    """The row tile the grouped products of a program of ``tokens`` rows,
+    ``top_k`` pairs a row, compile with, and so WHICH product they are: the
+    one place it is decided, from the backend alone. On a TPU
+    ``ops.grouped_matmul``'s (``row_tile`` of the pair rows: 128, or all of
+    them where there are fewer); 0 on every other backend, which keeps
+    ``jax.lax.ragged_dot`` (the kernel would run in the Pallas interpreter
+    there)."""
+    if jax.default_backend() != "tpu":
+        return 0
+    from pytorch_distributed_tpu.ops.grouped_matmul import row_tile
+
+    return row_tile(tokens * top_k)
+
+
+def program_grouped_rows(config, tokens: int) -> int:
+    """``grouped_rows`` of the ``DroplessMoE`` layers a ``TransformerConfig``
+    builds, in a program of ``tokens`` rows; 0 where it builds none.
+    ``PagedEngine`` puts the tick's answer on ``pool.alloc``'s span."""
+    if not config.n_experts or config.moe_kind != "dropless":
+        return 0
+    # the MLP router is top-1 and takes no ``top_k``
+    return grouped_rows(
+        tokens, 1 if config.moe_router == "mlp" else config.moe_top_k)
 
 
 def _zero_padded(init, shape):
@@ -246,8 +274,10 @@ class DroplessMoE(nn.Module):
     transpose, zero beyond the model's own widths), the
     program's live (token, expert) PAIRS sorted by expert, the group sizes
     taken, and the experts' matrices run as grouped products
-    (``jax.lax.ragged_dot``: on a TPU XLA's own grouped-matmul kernel,
-    which reads an expert's matrix only if a pair went to it). Each live
+    (``grouped_rows``: on a TPU ``ops.grouped_matmul``, a kernel whose row
+    tile fits a group, elsewhere ``jax.lax.ragged_dot``; either reads an
+    expert's matrix only if a pair went to it and promises nothing of a row
+    that joined no group). Each live
     token contributes ``top_k`` pairs; the pairs of rows that are not
     ``live`` (a chunk's padding, an inactive decode lane), and the pairs
     whose expert this shard does not hold, sort behind every group and join
@@ -384,6 +414,12 @@ class DroplessMoE(nn.Module):
                         dtype=jnp.int32)
         self.sow("moe_stats", "expert_tokens", sizes)
         order = jnp.argsort(choice)  # stable: arrival order inside a group
+        if grouped_rows(t, k):
+            from pytorch_distributed_tpu.ops.grouped_matmul import (
+                grouped_matmul as product,
+            )
+        else:
+            product = jax.lax.ragged_dot
         # a pair's token (top-1: the pair is the token)
         xs = xf.astype(self.dtype)[order if k == 1 else order // k]
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal",
@@ -400,23 +436,27 @@ class DroplessMoE(nn.Module):
                               (e, dh, fh))
             w_down = self.param("w_down", _zero_padded(init, (e, f, d)),
                                 (e, fh, dh))
-            hidden = jnp.square(nn.relu(jax.lax.ragged_dot(
+            hidden = jnp.square(nn.relu(product(
                 jnp.pad(xs, ((0, 0), (0, dh - d))), w_in.astype(self.dtype),
                 sizes)))
-            ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype),
-                                    sizes)[:, :d]
+            ys = product(hidden, w_down.astype(self.dtype), sizes)[:, :d]
         else:
             # gate and up side by side: one grouped product for both
             w_in = self.param("w_gate_up", init, (e, d, 2 * f))
             w_down = self.param("w_down", init, (e, f, d))
-            gu = jax.lax.ragged_dot(xs, w_in.astype(self.dtype), sizes)
+            gu = product(xs, w_in.astype(self.dtype), sizes)
             hidden = nn.silu(gu[:, :f]) * gu[:, f:]
-            ys = jax.lax.ragged_dot(hidden, w_down.astype(self.dtype), sizes)
+            ys = product(hidden, w_down.astype(self.dtype), sizes)
         out = jnp.zeros_like(ys).at[order].set(ys)
+        # what a grouped product leaves in a row of no group is not promised
+        # to be a number (the chip's kernel never writes it), and a zero
+        # weight does not make it one: every path SELECTS such rows away.
+        # Behind the MLP router the rows of no group are exactly the rows
+        # that are not ``live`` (every expert is held), which the last
+        # statement of this method selects to zero
         out = out.astype(f32) * gate[:, None]
         if self.router != "mlp":
-            # what a grouped product leaves in a row of no group is not
-            # promised to be a number; then a token's pairs add up
+            # then a token's pairs add up
             out = jnp.where(joins[:, None], out, 0.0)
             out = jnp.sum(out.reshape(t, k, d), axis=1)
         out = out.astype(self.dtype)

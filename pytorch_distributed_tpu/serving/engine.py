@@ -360,13 +360,23 @@ class PagedEngine:
                     config.num_kv_heads or config.num_heads)
                 self.heads_folded = heads_folded(
                     max(1, kv // config.tp_size), config.num_heads // kv)
+            # the row tile the tick's grouped products compiled with, as
+            # the model's module reckons it for a row a slot; 0 where the
+            # config has no dropless experts or the product is XLA's
+            # ``ragged_dot``
+            from pytorch_distributed_tpu.models.moe import (
+                program_grouped_rows,
+            )
+
+            self.grouped_rows = program_grouped_rows(config, n_slots)
             # ``read``: the paged read the programs compile;
             # ``table_blocks``: the blocks a decode tick's tables name,
             # live or not, which ``engine.decode.launch``'s
             # ``live_blocks`` is a share of; ``table_tiles``: the fused
             # kernel's grid steps a layer, ``tile_blocks`` entries each,
             # which ``live_tiles`` is a share of; ``heads_folded``: the
-            # narrow heads one product of a tile serves
+            # narrow heads one product of a tile serves; ``grouped_rows``:
+            # the row tile of the tick's grouped products
             # ``tail_bytes``: the per-slot leaves' bytes but for the
             # float32 recurrent states, which are ``state_bytes``;
             # ``latent_row_bytes``: a token's ONE row where a layer keeps a
@@ -386,6 +396,7 @@ class PagedEngine:
                 table_blocks=n_slots * self.table_width,
                 tile_blocks=self.tile_blocks,
                 heads_folded=self.heads_folded,
+                grouped_rows=self.grouped_rows,
                 table_tiles=n_slots * -(-self.table_width
                                         // self.tile_blocks),
                 tail_bytes=slot_bytes - state_bytes,

@@ -325,6 +325,8 @@ def test_the_read_and_its_live_share_ride_the_spans(
     assert alloc.args["heads_folded"] == engine.heads_folded == (
         cfg.num_heads if read == "pallas" else 1)
     assert cfg.num_heads == 2
+    # no expert layer: no grouped product, whatever the read
+    assert alloc.args["grouped_rows"] == engine.grouped_rows == 0
     assert alloc.args["table_tiles"] == 4 * (8 // tile)
     assert alloc.args["blocks"] == engine.allocator.n_blocks
     router.submit(np.arange(1, 21, dtype=np.int32), 6)  # 20 tokens
@@ -344,6 +346,33 @@ def test_the_read_and_its_live_share_ride_the_spans(
                <= alloc.args["table_blocks"] for t in ticks)
     assert all(t["live_tiles"] <= alloc.args["table_tiles"] for t in ticks)
     assert ticks[-1]["live_blocks"] > ticks[0]["live_blocks"]
+
+
+@pytest.mark.parametrize("backend,slots,rows", [
+    ("cpu", 3, 0), ("tpu", 3, 16), ("tpu", 64, 128)])
+def test_the_grouped_products_row_tile_rides_pool_alloc(
+        tracer, monkeypatch, backend, slots, rows):
+    """An expert configuration's ``pool.alloc`` says which grouped product
+    its tick compiled (``grouped_rows``: ``models.moe.program_grouped_rows``
+    of the config and the tick's rows, ``top_k`` pairs a slot): 0 where it
+    is XLA's ``ragged_dot`` (every backend but a TPU), else the kernel's row tile: all the rows in
+    whole sixteens where the tick has fewer than 128 (3 slots x 3 pairs),
+    128 from there on. Only the engine is built: nothing compiles."""
+    from test_nemotron_h_lm import nemo_config
+
+    from pytorch_distributed_tpu.models.transformer import TransformerLM
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+
+    cfg = nemo_config()
+    assert cfg.moe_top_k == 3
+    params = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    params = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    engine = PagedEngine(cfg, params, slots, n_blocks=9, block_len=8,
+                         prefill_chunk=8)
+    (alloc,) = tracer.events("pool.alloc")
+    assert alloc.args["grouped_rows"] == engine.grouped_rows == rows
 
 
 def _both_program_ticks(tracer, router, step):

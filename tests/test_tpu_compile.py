@@ -340,6 +340,38 @@ def _kernel_reads(text):
             and "paged_decode_attn" in line.split(" = ")[0]]
 
 
+def _grouped_products(text, pairs, experts):
+    """``[(K, N)]`` of the compiled program's ``grouped_matmul`` calls
+    (``ops/grouped_matmul.py``, the experts' products on a TPU since PR 45),
+    in program order, having checked of each that it multiplies the
+    program's ``pairs`` rows by the ``experts`` held matrices where they lie
+    (row-major, no operand a copy's result) and that the visit lists it
+    prefetches are as long as ``row_tile`` makes them (``pairs // tm
+    + experts - 1``: the row tile is not in the text, the lists are)."""
+    from pytorch_distributed_tpu.ops.grouped_matmul import row_tile
+
+    products = []
+    for line in text.splitlines():
+        if ('custom_call_target="tpu_custom_call"' not in line
+                or "grouped_matmul" not in line.split(" = ")[0]):
+            continue
+        lists = re.search(r"operand_layout_constraints=\{s32\[\], "
+                          r"s32\[(\d+)\]\{0\}, s32\[(\d+)\]\{0\}, "
+                          r"s32\[(\d+)\]\{0\}, bf16\[(\d+),(\d+)\]\{1,0\}, "
+                          r"bf16\[(\d+),(\d+),(\d+)\]\{2,1,0\}\}", line)
+        assert lists, line
+        offsets, groups, rows, m, k, g, k2, n = map(int, lists.groups())
+        assert (m, g, k2, offsets) == (pairs, experts, k, experts + 1), line
+        tm = row_tile(m)
+        assert tm == min(128, pairs) and m % tm == 0
+        assert groups == rows == m // tm + g - 1, line
+        operands = line.split(" custom-call(")[1].split(")")[0]
+        assert "copy" not in operands and "transpose" not in operands, line
+        products.append((k, n))
+    assert "ragged-dot" not in text
+    return products
+
+
 @pytest.mark.parametrize("program", ["decode_tick", "chunk_prefill[k=4,w=8]"])
 def test_pool_leaves_stay_row_major_and_uncopied(v5e, program):
     """The layout's guard without a chip. A ``[n_blocks, block_len,
@@ -598,9 +630,11 @@ def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
     """The tick reads K/V of 2 narrow heads of 128 through the fused
     kernel's grouped fold (4 query rows a narrow head, rows of 256 lanes:
     Mosaic takes the DMAs of its 512-byte pool rows), once a layer, and
-    the chunk program gathers dense; both run the experts as XLA's
-    grouped products (``ragged-dot``, two a layer) and neither moves a
-    pool-sized array."""
+    the chunk program gathers dense; both run the experts as the repo's
+    grouped products (``grouped_matmul``, two a layer: gate and up side by
+    side, and down; the tick's 128 pair rows ONE row tile, the chunk
+    program's 512 four) and neither moves a pool-sized array or an
+    expert stack."""
     from pytorch_distributed_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -639,9 +673,11 @@ def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
-    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
     assert len(reads) == (2 if program == "decode_tick" else 0), calls
-    assert len(grouped) == 4, calls  # gate and up side by side, and down
+    # gate and up side by side, and down, in each of the two layers
+    assert _grouped_products(
+        text, n if program == "decode_tick" else 4 * c["chunk"], 16) == [
+            (2048, 4096), (2048, 2048)] * 2, calls
     # the tick's two narrow heads fold into one product a tile: the
     # kernel's query is block-diagonal, 2 x 4 (row, head) columns (padded
     # to 16) of both heads' 256 lanes
@@ -651,9 +687,11 @@ def test_the_zaya_programs_compile_for_the_chip(v5e, monkeypatch, program):
         jax.eval_shape(fn, *args))]
     assert shapes[-1] == (2, 16)
     leaf = jax.tree.leaves(pool)[0]
+    stacks = params["block0"]["moe"]
     moved = [m.group(1) for m in re.finditer(
         r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
-        if math.prod(map(int, m.group(2).split(","))) == leaf.size]
+        if math.prod(map(int, m.group(2).split(","))) in (
+            leaf.size, stacks["w_gate_up"].size, stacks["w_down"].size)]
     assert not moved, moved
 
 
@@ -684,8 +722,10 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
     narrow head (the rule answers the kernel because the dense gather would
     write 2 GB), and no float32 array of [lanes, table positions, ...]
     exists; the chunk program gathers dense over its own table slice. Both
-    run the held experts as two grouped products, update the float32 state
-    where it lies (no copy of a state leaf) and move no pool-sized array."""
+    run the held experts as two ``grouped_matmul`` calls (row tiles of 128
+    of the 2,048 or 4,096 pair rows), update the float32 state where it lies
+    (no copy of a state leaf) and move no pool-sized array and no expert
+    stack."""
     from pytorch_distributed_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -728,9 +768,11 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
-    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
-    assert len(grouped) == 2, calls  # gate and up side by side, and down
+    # gate and up side by side, and down; eight pairs a token
+    assert _grouped_products(
+        text, 8 * (n if program == "decode_tick" else 4 * c["chunk"]),
+        128) == [(2560, 1536), (768, 2560)], calls
     # the latent row's ONE narrow head keeps the loop's body
     # (``heads_folded``): its query goes in a slab a head, 32 rows of 640
     assert all("bf16[256,1,32,640]" in x for x in _kernel_reads(text))
@@ -739,11 +781,14 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
     shapes = [tuple(s.shape) for s in jax.tree.leaves(
         jax.eval_shape(fn, *args))]
     assert shapes[-1] == (1, 128)
-    # neither a state leaf nor the latent pool is copied or transposed
+    # neither a state leaf, the latent pool nor an expert stack is copied
+    # or transposed
+    stacks = params["block1"]["moe"]
     moved = [m.group(1) for m in re.finditer(
         r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
-        if math.prod(map(int, m.group(2).split(","))) in (state.size,
-                                                          latent.size)]
+        if math.prod(map(int, m.group(2).split(","))) in (
+            state.size, latent.size, stacks["w_gate_up"].size,
+            stacks["w_down"].size)]
     assert not moved, moved
     # the tick gathers no lane's table: nothing of [lanes, positions, ...]
     rows = c["max_seq_len"]
@@ -781,8 +826,9 @@ def test_the_qwen3_next_programs_compile_for_the_chip(v5e, monkeypatch,
     fused kernel's folded body (2 narrow heads x 8 query rows: 16 (row,
     head) columns over K and V tiles of 512 lanes); the chunk program
     gathers dense over its own table slice. Both run the held experts as
-    two grouped products a layer, update the float32 state where it lies
-    (no copy of a state leaf) and move no pool-sized array."""
+    two ``grouped_matmul`` calls a layer (row tiles of 128 of the 2,560 or
+    20,480 pair rows), update the float32 state where it lies (no copy of a
+    state leaf) and move no pool-sized array and no expert stack."""
     from pytorch_distributed_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -830,9 +876,12 @@ def test_the_qwen3_next_programs_compile_for_the_chip(v5e, monkeypatch,
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
-    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
-    assert len(grouped) == 4, calls  # gate and up side by side, and down
+    # gate and up side by side, and down, in each of the two layers; ten
+    # pairs a token
+    assert _grouped_products(
+        text, 10 * (n if program == "decode_tick" else 16 * c["chunk"]),
+        256) == [(2048, 1024), (512, 2048)] * 2, calls
     # the folded body: the query is block-diagonal, 2 x 8 (row, head)
     # columns of both heads' 512 lanes, and the output leaves lane-dense
     assert all("bf16[256,16,512]" in x and "bf16[256,8,512]" in x
@@ -842,11 +891,14 @@ def test_the_qwen3_next_programs_compile_for_the_chip(v5e, monkeypatch,
     shapes = [tuple(s.shape) for s in jax.tree.leaves(
         jax.eval_shape(fn, *args))]
     assert shapes[-1] == (2, 256)
-    # neither a state leaf nor a pool is copied or transposed
+    # neither a state leaf, a pool nor an expert stack is copied or
+    # transposed
+    stacks = params["block0"]["moe"]
     moved = [m.group(1) for m in re.finditer(
         r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
-        if math.prod(map(int, m.group(2).split(","))) in (state.size,
-                                                          keys.size)]
+        if math.prod(map(int, m.group(2).split(","))) in (
+            state.size, keys.size, stacks["w_gate_up"].size,
+            stacks["w_down"].size)]
     assert not moved, moved
     # the tick gathers no lane's table: nothing of [lanes, positions, ...]
     rows = c["max_seq_len"]
@@ -884,11 +936,11 @@ def test_the_nemotron_h_programs_compile_for_the_chip(v5e, monkeypatch,
     fused kernel's folded body (2 narrow heads x 16 query rows: 32 (row,
     head) columns over K and V tiles of 256 lanes); the chunk program
     gathers dense over its own table slice. Both run the held experts as
-    TWO grouped products a block, each in 512 x 512 tiles (the stacks are
-    held 3,072 x 2,048: at 2,688 x 1,856 XLA takes 128 x 128 tiles and
-    copies a stack a call), update the float32 state where it lies (no copy
-    of a state leaf) and move no pool-sized array; the expert block owns no
-    cache leaf."""
+    TWO ``grouped_matmul`` calls a block (row tiles of 128 of the 1,536 or
+    6,144 pair rows; the stacks are still held 3,072 x 2,048, the widths
+    XLA's product wanted: ``grouped_width``), update the float32 state where
+    it lies (no copy of a state leaf) and move no pool-sized array and no
+    expert stack; the expert block owns no cache leaf."""
     from pytorch_distributed_tpu.models.transformer import (
         TransformerConfig,
         TransformerLM,
@@ -939,11 +991,11 @@ def test_the_nemotron_h_programs_compile_for_the_chip(v5e, monkeypatch,
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
-    grouped = [x for x in calls if x.lstrip("%").startswith("ragged-dot-none")]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
-    assert len(grouped) == 2, calls  # up, and down: no gate matrix
-    assert re.findall(r'ragged_dot_tiling="([\d,]+)"', text) == [
-        "512,512,512"] * 2
+    # up, and down: no gate matrix; six pairs a token
+    assert _grouped_products(
+        text, 6 * (n if program == "decode_tick" else 8 * c["chunk"]),
+        64) == [(3072, 2048), (2048, 3072)], calls
     # the folded body: the query is block-diagonal, 2 x 16 (row, head)
     # columns of both heads' 256 lanes, and the output leaves lane-dense
     assert all("bf16[256,32,256]" in x and "bf16[256,16,256]" in x
