@@ -1412,15 +1412,48 @@ class CCAttention(nn.Module):
         return out
 
 
+#: the ``attn_kind``s whose layer runs the delta rule, of those that keep a
+#: float32 recurrent state a request (``SLOT_STATE_LAYERS``)
+DELTA_RULE_KINDS = frozenset({"kda", "gdn"})
+
+
+def slot_state_update(kinds) -> str:
+    """Which update of the recurrent state a paged decode tick compiles for
+    layers of the ``attn_kind``s ``kinds`` (a layer asks with its own, the
+    engine with the stack's: ``PagedEngine`` puts the answer on
+    ``pool.alloc``'s span): the ONE place it is decided, from the backend
+    and the layer kind. ``"pallas"``: ``ops/state_update.py``'s kernel on
+    the cache leaf where it lies, a head's state read once and written
+    once, for the delta rule (``KDAttention``, ``GatedDeltaNet``) on a TPU.
+    ``"xla"``: the ``jax.numpy`` update (``delta_rule_update``,
+    ``ssm_update``) around a gather, two selects and a scatter, on every
+    other backend (the kernel would run in the Pallas interpreter there) and
+    for ``Mamba2Mixer`` everywhere: its reductions run over the state's
+    lanes, and such a kernel read 21% SLOWER on the chip than XLA's fusions
+    (PERF.md section 6, PR 46 / PR 47). ``""``: no layer keeps a state.
+    Every other program (a chunk program, ``generate``'s own cache) runs the
+    ``jax.numpy`` spelling everywhere."""
+    kinds = set(kinds)
+    if not kinds & set(SLOT_STATE_LAYERS):
+        return ""
+    on_chip = jax.default_backend() == "tpu"
+    return "pallas" if on_chip and kinds & DELTA_RULE_KINDS else "xla"
+
+
 def delta_rule_update(s, q_t, k_t, v_t, a_t, b_t):
     """One token of the delta rule: ``s`` [B, H, D, D], ``q_t``, ``k_t``,
     ``v_t`` [B, H, D], the decay ``a_t`` [B, H, D] a channel or [B, H, 1] a
     head, beta [B, H]. Returns (the new state, ``o_t``). Float32 multiplies
-    and sums on the vector unit, and the OLD state read twice and the new
-    one written once: what the new state shows the query is what the
-    decayed old one shows it plus the written row's share, ``S'^T q = S^T
-    (alpha * q) + (v - seen) (beta k . q)``, so both reductions read ``s``
-    in one pass and nothing reads the state just written."""
+    and sums on the vector unit: what the new state shows the query is what
+    the decayed old one shows it plus the written row's share, ``S'^T q =
+    S^T (alpha * q) + (v - seen) (beta k . q)``, so both reductions read the
+    OLD state and nothing reads the state just written. This spelling is the
+    specification and what every program but a TPU's paged decode tick
+    runs; XLA compiles it as THREE passes over the state (a fusion reads it
+    for both reductions, a second reads it again and writes the new one), so
+    that tick runs ``ops/state_update.py::delta_rule_tick`` instead
+    (``slot_state_update``): the same sums on the cache leaf, once read and
+    once written."""
     seen = jnp.sum((k_t * a_t)[..., None] * s, axis=-2)
     read = jnp.sum((q_t * a_t)[..., None] * s, axis=-2)
     write = b_t[..., None] * k_t  # [B, H, D]: beta k
@@ -1504,11 +1537,17 @@ class _SlotStateAttention(nn.Module):
     leaves are ``[n_slots + 1, ...]``. A chunk program reads and writes the
     rows ``slots`` names (the last row is the trash row of padding jobs)
     and runs the recurrence ``BLOCK`` positions a step
-    (``delta_rule_blocks``, ``ssm_blocks``); a decode tick's row ``i`` IS slot ``i`` (the
-    engine's tick has a lane a slot), so the tick updates the leaves where
-    they lie, once read and once written, and a lane that is not live
-    (``lengths`` 0: inactive, or in mid-prefill) keeps what it held."""
+    (``delta_rule_blocks``, ``ssm_blocks``); a decode tick's row ``i`` IS
+    slot ``i`` (the engine's tick has a lane a slot), so the tick updates
+    the leaves where they lie, and a lane that is not live (``lengths`` 0:
+    inactive, or in mid-prefill) keeps what it held. Where
+    ``slot_state_update`` answers ``"pallas"`` for the subclass's ``KIND``
+    the tick's update is ``ops/state_update.py``'s kernel: it takes the leaf
+    itself, aliased onto its output, and the two selects (a fresh row's
+    zeros, a dead lane's held bits) are its flags a lane."""
 
+    #: the subclass's ``attn_kind``
+    KIND = None
     #: taps of the depthwise convolutions: the current token and three
     #: before it
     TAPS = 4
@@ -1528,7 +1567,12 @@ class _SlotStateAttention(nn.Module):
         ``keep(window, s1)`` what writes the row's new state and last
         inputs back (``window``: ``c0`` in front of this call's inputs).
         ``state`` is the shape of ONE row's state, the subclass's own
-        (``[H, D, D]`` for the delta rule, ``[H, P, N]`` for Mamba-2)."""
+        (``[H, D, D]`` for the delta rule, ``[H, P, N]`` for Mamba-2).
+        Where the call is a paged decode tick's token and
+        ``slot_state_update`` answers ``"pallas"``, ``s0`` is None: nothing
+        gathers the rows, and ``keep`` takes for ``s1`` the kernel's update
+        ``(leaf, fresh=, live=) -> (leaf, out)`` (``ops/state_update.py``)
+        and returns its ``out``."""
         cfg, f32, taps = self.config, jnp.float32, self.TAPS
         cached = self.decode or self.prefill
         paged = block_tables is not None
@@ -1539,6 +1583,9 @@ class _SlotStateAttention(nn.Module):
         pos = jnp.asarray(position_offset, jnp.int32)
         state_var = conv_var = None
         tick = paged and self.decode
+        # a tick's token where the kernel works on the leaf
+        kernel = tick and l == 1 and slot_state_update(
+            (self.KIND,)) == "pallas"
         if cached:
             if paged:
                 if pos.ndim != 1 or lengths is None or (
@@ -1556,7 +1603,8 @@ class _SlotStateAttention(nn.Module):
                         f"and writes slot i's state, got {b} rows over "
                         f"{state_var.value.shape[0] - 1} slots")
                 rows = slice(0, b) if tick else slots
-                held_s, held_c = state_var.value[rows], conv_var.value[rows]
+                held_s = None if kernel else state_var.value[rows]
+                held_c = conv_var.value[rows]
             else:
                 state_var = self.variable(
                     "cache", "state",
@@ -1567,7 +1615,8 @@ class _SlotStateAttention(nn.Module):
                 held_s, held_c = state_var.value, conv_var.value
             starts = pos if pos.ndim == 1 else jnp.full((b,), pos)
             fresh = starts == 0
-            s0 = jnp.where(fresh[:, None, None, None], 0.0, held_s)
+            s0 = None if kernel else jnp.where(
+                fresh[:, None, None, None], 0.0, held_s)
             c0 = jnp.where(fresh[:, None, None],
                            jnp.zeros((), held_c.dtype), held_c)
         else:
@@ -1578,19 +1627,25 @@ class _SlotStateAttention(nn.Module):
 
         def keep(window, s1):
             if state_var is None:
-                return
+                return None
             # the last T - 1 inputs behind the row's last REAL position
             at = real[:, None] + jnp.arange(taps - 1)[None, :]
             c1 = jnp.take_along_axis(window, at[:, :, None], axis=1)
             live = real > 0
-            s1 = jnp.where(live[:, None, None, None], s1, held_s)
+            out = None
+            if kernel:  # both selects are the kernel's
+                leaf, out = s1(state_var.value, fresh=fresh, live=live)
+            else:
+                s1 = jnp.where(live[:, None, None, None], s1, held_s)
             c1 = jnp.where(live[:, None, None], c1.astype(held_c.dtype),
                            held_c)
             if paged:
-                state_var.value = state_var.value.at[rows].set(s1)
+                state_var.value = (leaf if kernel
+                                   else state_var.value.at[rows].set(s1))
                 conv_var.value = conv_var.value.at[rows].set(c1)
             else:
                 state_var.value, conv_var.value = s1, c1
+            return out
 
         return s0, c0, real, keep
 
@@ -1610,6 +1665,22 @@ class _SlotStateAttention(nn.Module):
 def _unit(t):
     """``t`` L2-normed over its last axis (epsilon 1e-6 under the root)."""
     return t * jax.lax.rsqrt(jnp.sum(jnp.square(t), -1, keepdims=True) + 1e-6)
+
+
+def _delta_rule_token(s0, keep, window, *token):
+    """One token of the delta rule from what ``_held`` gave, the state kept:
+    ``token`` is ``delta_rule_update``'s operands behind the state. Returns
+    ``o`` [B, 1, H, D]. ``s0`` None is a paged decode tick on the kernel:
+    the update runs on the cache leaf inside ``keep``."""
+    if s0 is None:
+        from pytorch_distributed_tpu.ops.state_update import delta_rule_tick
+
+        return keep(window, lambda leaf, **flags: delta_rule_tick(
+            leaf, *token, **flags))[:, None]
+    s1, o = delta_rule_update(s0, *token)
+    o = o[:, None]
+    keep(window, s1)
+    return o
 
 
 class KDAttention(_SlotStateAttention):
@@ -1639,6 +1710,7 @@ class KDAttention(_SlotStateAttention):
     #: the gate's lower bound (the published ``kda_lower_bound``): a
     #: channel's decay a token lies in ``(exp(LOWER_BOUND), 1)``
     LOWER_BOUND = -5.0
+    KIND = "kda"
 
     @nn.compact
     def __call__(self, x, position_offset, block_tables=None, slots=None,
@@ -1675,16 +1747,15 @@ class KDAttention(_SlotStateAttention):
                 b, l, h, d))
 
         if l == 1:
-            s1, o = delta_rule_update(s0, q[:, 0], k[:, 0], v[:, 0],
-                                      jnp.exp(g[:, 0]), beta[:, 0])
-            o = o[:, None]
+            o = _delta_rule_token(s0, keep, window, q[:, 0], k[:, 0],
+                                  v[:, 0], jnp.exp(g[:, 0]), beta[:, 0])
         else:
             # a padding position neither decays the state nor writes to it
             valid = jnp.arange(l)[None, :] < real[:, None]
             s1, o = delta_rule_blocks(
                 s0, q, k, v, jnp.where(valid[..., None, None], g, 0.0),
                 jnp.where(valid[..., None], beta, 0.0), self.BLOCK)
-        keep(window, s1)
+            keep(window, s1)
 
         o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm")(o)
         o = (o.reshape(b, l, inner) * jax.nn.sigmoid(
@@ -1727,6 +1798,8 @@ class GatedDeltaNet(_SlotStateAttention):
     ``linear_conv_kernel_dim``.
     """
 
+    KIND = "gdn"
+
     @nn.compact
     def __call__(self, x, position_offset, block_tables=None, slots=None,
                  lengths=None):
@@ -1766,17 +1839,16 @@ class GatedDeltaNet(_SlotStateAttention):
             k = jnp.repeat(k, hv // hk, axis=2)
 
         if l == 1:
-            s1, o = delta_rule_update(s0, q[:, 0], k[:, 0], v[:, 0],
-                                      jnp.exp(g[:, 0])[..., None],
-                                      beta[:, 0])
-            o = o[:, None]
+            o = _delta_rule_token(s0, keep, window, q[:, 0], k[:, 0],
+                                  v[:, 0], jnp.exp(g[:, 0])[..., None],
+                                  beta[:, 0])
         else:
             # a padding position neither decays the state nor writes to it
             valid = jnp.arange(l)[None, :] < real[:, None]
             s1, o = delta_rule_blocks(
                 s0, q, k, v, jnp.where(valid[..., None], g, 0.0)[..., None],
                 jnp.where(valid[..., None], beta, 0.0), self.BLOCK)
-        keep(window, s1)
+            keep(window, s1)
 
         o = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="o_norm")(o)
         o = (o.reshape(b, l, values) * nn.silu(z.astype(f32))).astype(
@@ -1885,6 +1957,7 @@ class Mamba2Mixer(_SlotStateAttention):
     #: no triangular solve bounds a block, so a step takes four times the
     #: delta rule's positions
     BLOCK = 64
+    KIND = "mamba2"
 
     @nn.compact
     def __call__(self, x, position_offset, block_tables=None, slots=None,
@@ -1943,6 +2016,12 @@ class Mamba2Mixer(_SlotStateAttention):
             out = nn.Dropout(cfg.dropout,
                              deterministic=self.deterministic)(out)
         return out
+
+
+#: the layers that keep a float32 recurrent state a request, by
+#: ``attn_kind``
+SLOT_STATE_LAYERS = {
+    c.KIND: c for c in (KDAttention, GatedDeltaNet, Mamba2Mixer)}
 
 
 class MLAttention(nn.Module):
@@ -2148,10 +2227,8 @@ class Block(nn.Module):
                 return CCAttention(cfg, **mode)(
                     h, position_offset, positions, block_tables, slots,
                     lengths)
-            if attn_kind in ("kda", "gdn", "mamba2"):
-                linear = {"kda": KDAttention, "gdn": GatedDeltaNet,
-                          "mamba2": Mamba2Mixer}[attn_kind]
-                return linear(cfg, **mode)(
+            if attn_kind in SLOT_STATE_LAYERS:
+                return SLOT_STATE_LAYERS[attn_kind](cfg, **mode)(
                     h, position_offset, block_tables, slots, lengths)
             if attn_kind == "mla":
                 return MLAttention(cfg, **mode)(

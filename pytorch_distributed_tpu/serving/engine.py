@@ -369,6 +369,15 @@ class PagedEngine:
             )
 
             self.grouped_rows = program_grouped_rows(config, n_slots)
+            # which update of the recurrent states the tick compiles, as
+            # the model's module decides it for the stack's layer kinds
+            # (``"pallas"``: the kernel on the leaf, ``"xla"``: the
+            # ``jax.numpy`` spelling, ``""``: the cache holds no state)
+            from pytorch_distributed_tpu.models.transformer import (
+                slot_state_update,
+            )
+
+            self.state_update = slot_state_update(config.attn_kinds)
             # ``read``: the paged read the programs compile;
             # ``table_blocks``: the blocks a decode tick's tables name,
             # live or not, which ``engine.decode.launch``'s
@@ -376,7 +385,8 @@ class PagedEngine:
             # kernel's grid steps a layer, ``tile_blocks`` entries each,
             # which ``live_tiles`` is a share of; ``heads_folded``: the
             # narrow heads one product of a tile serves; ``grouped_rows``:
-            # the row tile of the tick's grouped products
+            # the row tile of the tick's grouped products;
+            # ``state_update``: the tick's update of the recurrent states;
             # ``tail_bytes``: the per-slot leaves' bytes but for the
             # float32 recurrent states, which are ``state_bytes``;
             # ``latent_row_bytes``: a token's ONE row where a layer keeps a
@@ -397,6 +407,7 @@ class PagedEngine:
                 tile_blocks=self.tile_blocks,
                 heads_folded=self.heads_folded,
                 grouped_rows=self.grouped_rows,
+                state_update=self.state_update,
                 table_tiles=n_slots * -(-self.table_width
                                         // self.tile_blocks),
                 tail_bytes=slot_bytes - state_bytes,

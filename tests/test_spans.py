@@ -327,6 +327,8 @@ def test_the_read_and_its_live_share_ride_the_spans(
     assert cfg.num_heads == 2
     # no expert layer: no grouped product, whatever the read
     assert alloc.args["grouped_rows"] == engine.grouped_rows == 0
+    # no recurrent state in the cache: no update of one, whatever the read
+    assert alloc.args["state_update"] == engine.state_update == ""
     assert alloc.args["table_tiles"] == 4 * (8 // tile)
     assert alloc.args["blocks"] == engine.allocator.n_blocks
     router.submit(np.arange(1, 21, dtype=np.int32), 6)  # 20 tokens
@@ -373,6 +375,38 @@ def test_the_grouped_products_row_tile_rides_pool_alloc(
                          prefill_chunk=8)
     (alloc,) = tracer.events("pool.alloc")
     assert alloc.args["grouped_rows"] == engine.grouped_rows == rows
+
+
+@pytest.mark.parametrize("name,backend,update", [
+    ("ling", "cpu", "xla"), ("ling", "tpu", "pallas"),
+    ("qwen3-next", "cpu", "xla"), ("qwen3-next", "tpu", "pallas"),
+    ("nemotron-h", "cpu", "xla"), ("nemotron-h", "tpu", "xla")])
+def test_the_state_update_rides_pool_alloc(tracer, monkeypatch, name,
+                                           backend, update):
+    """A configuration whose cache holds a recurrent state says on
+    ``pool.alloc`` which update of it its tick compiles (``state_update``:
+    ``models.transformer.slot_state_update``, the rule the layers ask):
+    ``ops/state_update.py``'s kernel for the delta rule on a TPU, the
+    ``jax.numpy`` spelling on every other backend and for Mamba-2
+    everywhere. Only the engine is built: nothing compiles."""
+    from test_ling_lm import ling_config
+    from test_nemotron_h_lm import nemo_config
+    from test_qwen3_next_lm import qwen_config
+
+    from pytorch_distributed_tpu.models.transformer import TransformerLM
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+
+    cfg = {"ling": ling_config, "qwen3-next": qwen_config,
+           "nemotron-h": nemo_config}[name]()
+    params = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    params = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    engine = PagedEngine(cfg, params, 3, n_blocks=9, block_len=8,
+                         prefill_chunk=8)
+    (alloc,) = tracer.events("pool.alloc")
+    assert alloc.args["state_bytes"] > 0
+    assert alloc.args["state_update"] == engine.state_update == update
 
 
 def _both_program_ticks(tracer, router, step):

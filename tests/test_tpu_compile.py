@@ -372,6 +372,61 @@ def _grouped_products(text, pairs, experts):
     return products
 
 
+def _state_updates(text, state, lanes):
+    """How many ``slot_state_update`` calls the compiled program holds
+    (``ops/state_update.py``, a paged decode tick's update of the delta
+    rule's state on a TPU since PR 47), having checked of each that it takes
+    the ``state`` leaf where it lies (row-major float32, no operand a copy's
+    result) behind a flag a lane, and returns it."""
+    leaf = "f32[%s]{3,2,1,0" % ",".join(map(str, state.shape))
+    updates = 0
+    for line in text.splitlines():
+        if ('custom_call_target="tpu_custom_call"' not in line
+                or "slot_state_update" not in line.split(" = ")[0]):
+            continue
+        updates += 1
+        layouts = line.split("operand_layout_constraints={")[1].split(
+            "}}")[0].split(", ")
+        assert layouts[:2] == [f"s32[{lanes}]{{0}}"] * 2, line
+        assert layouts[-1] == leaf, line
+        assert line.split(" = (")[1].startswith(leaf), line
+        # the leaf is the last operand and IS the first result
+        assert "output_to_operand_aliasing={{0}: (%d, {})}" % (
+            len(layouts) - 1) in line, line
+        came = line.split(" custom-call(")[1].split(")")[0].split(", ")[-1]
+        assert "copy" not in came and "transpose" not in came, line
+    return updates
+
+
+#: sha256 (12 hex digits) of the StableHLO text of the state cells' programs
+#: that PR 47 must NOT move, as they lower for a described v5e where the
+#: backend answers ``tpu`` (the tests below: one period of layers, 512
+#: tokens): every chunk program (the kernel is the tick's), and nemotron's
+#: tick (``Mamba2Mixer`` keeps ``ssm_update``: ``slot_state_update``). Taken
+#: on PR 47's parent, with each kernel's serialized body blanked (a
+#: ``tpu_custom_call``'s ``backend_config`` carries its source's path and
+#: line numbers; the kernels have their own tests). A PR that means to
+#: change one records a new digest.
+STATE_DIGESTS = {
+    ("ling", "chunk_prefill[k=4,w=128]"): "08f5d25a3dce",
+    ("qwen3-next", "chunk_prefill[k=16,w=256]"): "5d13c20be4c5",
+    ("nemotron-h", "chunk_prefill[k=8,w=128]"): "a88f1d2ffbbc",
+    ("nemotron-h", "decode_tick"): "504f1ae852fc",
+}
+
+
+def _lowers_to_the_parents_text(lowered, stack, program):
+    """Whether ``STATE_DIGESTS`` holds the program and its text is that."""
+    import hashlib
+
+    want = STATE_DIGESTS.get((stack, program))
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""',
+                  lowered.as_text())
+    got = hashlib.sha256(text.encode()).hexdigest()[:12]
+    assert want is None or got == want, (stack, program, got)
+    return want is not None
+
+
 @pytest.mark.parametrize("program", ["decode_tick", "chunk_prefill[k=4,w=8]"])
 def test_pool_leaves_stay_row_major_and_uncopied(v5e, program):
     """The layout's guard without a chip. A ``[n_blocks, block_len,
@@ -761,14 +816,22 @@ def test_the_ling_programs_compile_for_the_chip(v5e, monkeypatch, program):
         fn, operands = _chunk_operands(eng, 4, 128)
         assert eng.chunk_program_name(4, 128) == program
     args = (params, pool, eng.logits) + operands
-    compiled = fn.lower(*jax.tree.map(
+    lowered = fn.lower(*jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
-        args)).compile()
+        args))
+    compiled = lowered.compile()
     text = compiled.as_text()
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
+    # the tick's state layer updates its leaf through the kernel, ONE call
+    # whose result aliases the leaf; the chunk program, which runs ``BLOCK``
+    # positions a step, lowers to the parent's text
+    assert _lowers_to_the_parents_text(lowered, "ling", program) == (
+        program != "decode_tick")
+    assert eng.state_update == "pallas"
+    assert _state_updates(text, state, n) == (program == "decode_tick"), calls
     # gate and up side by side, and down; eight pairs a token
     assert _grouped_products(
         text, 8 * (n if program == "decode_tick" else 4 * c["chunk"]),
@@ -869,14 +932,22 @@ def test_the_qwen3_next_programs_compile_for_the_chip(v5e, monkeypatch,
         fn, operands = _chunk_operands(eng, 16, 256)
         assert eng.chunk_program_name(16, 256) == program
     args = (params, pool, eng.logits) + operands
-    compiled = fn.lower(*jax.tree.map(
+    lowered = fn.lower(*jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
-        args)).compile()
+        args))
+    compiled = lowered.compile()
     text = compiled.as_text()
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
+    # the tick's state layer updates its leaf through the kernel, ONE call
+    # whose result aliases the leaf; the chunk program, which runs ``BLOCK``
+    # positions a step, lowers to the parent's text
+    assert _lowers_to_the_parents_text(lowered, "qwen3-next", program) == (
+        program != "decode_tick")
+    assert eng.state_update == "pallas"
+    assert _state_updates(text, state, n) == (program == "decode_tick"), calls
     # gate and up side by side, and down, in each of the two layers; ten
     # pairs a token
     assert _grouped_products(
@@ -984,14 +1055,20 @@ def test_the_nemotron_h_programs_compile_for_the_chip(v5e, monkeypatch,
         fn, operands = _chunk_operands(eng, 8, 128)
         assert eng.chunk_program_name(8, 128) == program
     args = (params, pool, eng.logits) + operands
-    compiled = fn.lower(*jax.tree.map(
+    lowered = fn.lower(*jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
-        args)).compile()
+        args))
+    compiled = lowered.compile()
     text = compiled.as_text()
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     reads = [x for x in calls if "paged_decode_attn" in x]
     assert len(reads) == (1 if program == "decode_tick" else 0), calls
+    # ``Mamba2Mixer`` keeps ``ssm_update``: both programs lower to the
+    # parent's text and hold no kernel of the state's
+    assert _lowers_to_the_parents_text(lowered, "nemotron-h", program)
+    assert eng.state_update == "xla"
+    assert _state_updates(text, state, n) == 0, calls
     # up, and down: no gate matrix; six pairs a token
     assert _grouped_products(
         text, 6 * (n if program == "decode_tick" else 8 * c["chunk"]),
