@@ -824,34 +824,30 @@ def fleet_phase() -> None:
 
         everything = range(len(prompts))
         _, want, _ = run(everything, n_replicas=1)
-        alone = {}  # one replica's streams for a subset of the requests
-        for mode in ("sync", "async"):
-            router, got, home = run(everything, n_replicas=n,
-                                    async_host=mode == "async")
-            placed = [sorted(device_ids((s.engine.params, s.engine.cache)))
-                      for s in router.replicas]
-            identical = True
-            for i in range(n):
-                mine = tuple(j for j in everything if home[j] == i)
-                if mine not in alone:
-                    alone[mine] = run(mine, n_replicas=1)[1]
-                identical &= alone[mine] == [got[j] for j in mine]
-            rate = same_context_agreement(router, prompts, want, got)
-            rec[mode] = {
-                "replica_device_ids": placed,
-                "requests_per_replica": [home.count(i) for i in range(n)],
-                "identical_to_one_replica_on_the_same_requests": identical,
-                "agreement_with_one_replica_on_all": round(rate, 4),
-                "stream_agreement_with_one_replica_on_all":
-                    round(agreement(want, got), 4),
-            }
-            require(rec, identical, f"{mode}: a replica streams otherwise "
-                    "than one replica given the same requests")
-            require(rec, rate >= 0.9, f"{mode}: agreement with one replica "
-                    f"serving every request {rate:.3f} < 0.9")
-            require(rec, len({tuple(p) for p in placed}) == n
-                    and all(len(p) == 1 for p in placed),
-                    f"{mode}: replicas placed on {placed}")
+        router, got, home = run(everything, n_replicas=n)
+        placed = [sorted(device_ids((s.engine.params, s.engine.cache)))
+                  for s in router.replicas]
+        identical = True
+        for i in range(n):
+            mine = tuple(j for j in everything if home[j] == i)
+            alone = run(mine, n_replicas=1)[1]  # one replica, its requests
+            identical &= alone == [got[j] for j in mine]
+        rate = same_context_agreement(router, prompts, want, got)
+        rec.update(
+            replica_device_ids=placed,
+            requests_per_replica=[home.count(i) for i in range(n)],
+            identical_to_one_replica_on_the_same_requests=identical,
+            agreement_with_one_replica_on_all=round(rate, 4),
+            stream_agreement_with_one_replica_on_all=round(
+                agreement(want, got), 4),
+        )
+        require(rec, identical, "a replica streams otherwise than one "
+                "replica given the same requests")
+        require(rec, rate >= 0.9, "agreement with one replica serving "
+                f"every request {rate:.3f} < 0.9")
+        require(rec, len({tuple(p) for p in placed}) == n
+                and all(len(p) == 1 for p in placed),
+                f"replicas placed on {placed}")
         rec.update(prompts=len(prompts), max_new=max_new)
 
 

@@ -31,12 +31,10 @@ collectives are a known jaxlib CPU gap). Requests enter through
   the caller does between two steps, and through the other replicas'
   host work. Per-request host work (JSONL, the gate's percentile math)
   rides a small ``HostWorkerPool`` whose threads end with the router.
-  Per replica, collect(N−1) → dispatch(N) IS the schedule of a loop
-  that fetches each tick's tokens inside its launch, so greedy token
-  streams are bit-identical to that loop's. ``async_host=False`` builds
-  it (``Scheduler.step()`` a replica, no pool): the step-domain
-  reference the parity tests compare against, not a serving mode
-  (ROADMAP C1c deletes it).
+  Per replica, collect(N−1) → dispatch(N) is the order in which a
+  lone ``Scheduler.step()`` launches and collects its ticks, so greedy
+  token streams are bit-identical to a lone scheduler's: that is what
+  the parity tests hold the router to.
 
 Disaggregated prefill/decode (``disaggregate=True``): the first
 ``n_prefill`` replicas run ``prefill_only`` schedulers — chunk programs
@@ -146,8 +144,7 @@ class FleetRouter:
                  handoffs_per_tick: Optional[int] = None,
                  slo: Optional[SLOConfig] = None, devices=None,
                  seed: int = 0, metrics_log=None,
-                 flightrec=None, reqtrace=None, ledger=None,
-                 async_host: bool = True, host_threads: int = 2,
+                 flightrec=None, reqtrace=None, host_threads: int = 2,
                  affinity_cap: int = 4096,
                  fail_threshold: int = 2,
                  tick_deadline_s: Optional[float] = None,
@@ -157,8 +154,10 @@ class FleetRouter:
                  **scheduler_kwargs):
         import jax
 
+        from pytorch_distributed_tpu.serving.host_worker import (
+            HostWorkerPool,
+        )
         from pytorch_distributed_tpu.telemetry import (
-            NULL_LEDGER,
             NULL_RECORDER,
             NULL_REQTRACER,
         )
@@ -186,28 +185,14 @@ class FleetRouter:
         # crosses the admission gate, the prefill replica, the handoff,
         # and the decode replica
         self.reqtrace = reqtrace if reqtrace is not None else NULL_REQTRACER
-        # host–device overlap ledger (round 15): ONE shared
-        # DispatchLedger across the fleet, so every replica's launches
-        # land on one wall-clock axis and a gap on replica B can be
-        # attributed to replica A's tick — the one-loop serialization
-        # ROADMAP item 3's async refactor must remove
-        self.ledger = ledger if ledger is not None else NULL_LEDGER
         # the host loop: collect tick N-1, dispatch tick N, return with
         # N in flight, and ONE worker pool shared by every replica for
         # the host work off the critical path (JSONL emission, the
-        # gate's percentile math). async_host=False is the tests'
-        # step-domain reference: Scheduler.step() a replica, no pool.
-        self.async_host = bool(async_host)
-        self.host_pool = None
-        if self.async_host:
-            from pytorch_distributed_tpu.serving.host_worker import (
-                HostWorkerPool,
-            )
-
-            self.host_pool = HostWorkerPool(n_threads=host_threads)
-            # the workers end with the router: nothing calls close(), and
-            # a parked thread outlives whoever built the router
-            weakref.finalize(self, self.host_pool.stop)
+        # gate's percentile math)
+        self.host_pool = HostWorkerPool(n_threads=host_threads)
+        # the workers end with the router: nothing calls close(), and
+        # a parked thread outlives whoever built the router
+        weakref.finalize(self, self.host_pool.stop)
         # block-lifecycle sanitizer (analysis.blocksan; PDT_BLOCKSAN=1):
         # ONE sanitizer shared by every replica, so handoff pins and
         # violations aggregate fleet-wide and one assert_clean() covers
@@ -410,7 +395,7 @@ class FleetRouter:
                 device=dev, handoff=self._disaggregate,
                 metrics_log=self.metrics_log,
                 flightrec=self.flightrec, reqtrace=self.reqtrace,
-                ledger=self.ledger, host_pool=self.host_pool,
+                host_pool=self.host_pool,
                 blocksan=self.blocksan, **kw,
             )
         s.on_retire = self._note_retire
@@ -598,8 +583,6 @@ class FleetRouter:
             owners.append(("reqtrace", self.reqtrace))
         if self.flightrec.enabled:
             owners.append(("flightrec", self.flightrec))
-        if self.ledger.enabled:
-            owners.append(("ledger", self.ledger))
         return owners
 
     def _note_failure(self, i: int, exc: BaseException,
@@ -845,8 +828,7 @@ class FleetRouter:
     def _group_metrics(self, group: List[int]) -> Dict[int, dict]:
         # gate_metrics is the worker-refreshed snapshot + live cheap
         # counters, so a submit does not pay the O(n log n) percentile
-        # math of metrics() on the critical path (the async_host=False
-        # reference has no pool and reads metrics() itself)
+        # math of metrics() on the critical path
         return {i: self.replicas[i].gate_metrics() for i in group}
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int, *,
@@ -875,8 +857,7 @@ class FleetRouter:
         if not alive:
             decision = Decision(SHED, -1, "fleet-unavailable")
         else:
-            with spans.tracer().span("router.gate", rid=rid), \
-                    self.ledger.host("admission/gate"):
+            with spans.tracer().span("router.gate", rid=rid):
                 decision = self.gate.route(
                     self._group_metrics(alive), preferred,
                     deadline_s=deadline_s,
@@ -1053,11 +1034,8 @@ class FleetRouter:
             self.watchdog.beat(f"replica{i}")
         t0 = time.perf_counter()
         try:
-            if self.async_host:
-                toks.extend(s.collect_tick())
-                s.dispatch_tick()
-            else:
-                toks.extend(s.step())
+            toks.extend(s.collect_tick())
+            s.dispatch_tick()
         except Exception as e:  # noqa: BLE001 — the fault boundary
             self._note_failure(i, e, site="tick")
         else:
@@ -1083,14 +1061,12 @@ class FleetRouter:
         its submits), then DISPATCH the next and leave it in flight;
         then the handoff pump. Returns the collected ticks' tokens, so
         step N returns tick N−1's. Per replica the order collect(N−1) →
-        dispatch(N) is the schedule of the ``async_host=False``
-        reference, which ticks each replica fully (``Scheduler.step()``:
-        the tokens fetched inside the launch), so greedy token streams
-        are bit-identical between the two; only cross-replica
-        interleaving (and the wall clock) changes. The span's
-        ``in_flight`` is how many replicas entered the step with a
-        token-bearing tick pending: 0 on the reference loop and on the
-        first step, the replicas that decode thereafter."""
+        dispatch(N) is the order of a lone ``Scheduler.step()``'s
+        launches and collects, so greedy token streams are bit-identical
+        to a lone scheduler's; only cross-replica interleaving (and the
+        wall clock) differs. The span's ``in_flight`` is how many
+        replicas entered the step with a token-bearing tick pending: 0
+        on the first step, the replicas that decode thereafter."""
         in_flight = sum(s.tick_in_flight for s in self.replicas)
         with spans.tracer().span("router.step", in_flight=in_flight):
             if self._start_time is None:
@@ -1108,8 +1084,7 @@ class FleetRouter:
             for i in self._alive(self.decode_group + self.entry_group):
                 out.extend(self._run_tick(i))
             if self.decode_group:
-                with self.ledger.host("handoff-pump"):
-                    self._pump_handoffs()
+                self._pump_handoffs()
             for rid, tok in out:
                 self.results.setdefault(rid, []).append(tok)
             self._drop_retired()
@@ -1132,17 +1107,20 @@ class FleetRouter:
             and not self._pending_redispatch
         )
 
+    def _settle_host_work(self) -> None:
+        """Barrier: every replica's buffered observations shipped, and
+        everything the workers were handed (JSONL, metric refreshes)
+        run; a worker's error re-raises here."""
+        for s in self.replicas:
+            s.flush_host_work()
+        self.host_pool.flush()
+
     def drain(self, max_steps: int = 100_000) -> Dict[int, List[int]]:
         """Step until every replica is empty; returns ``{rid: [tokens]}``
         for every request that produced output (shed rids absent)."""
         for _ in range(max_steps):
             if self.idle:
-                if self.host_pool is not None:
-                    # barrier: offloaded JSONL/metric work settles with
-                    # the drain, same as the synchronous loop's contract
-                    for s in self.replicas:
-                        s.flush_host_work()
-                    self.host_pool.flush()
+                self._settle_host_work()
                 if self.blocksan is not None:
                     # fleet quiesce: every replica's ledger must equal
                     # its allocator with no chains, swap windows, or
@@ -1306,27 +1284,7 @@ class FleetRouter:
                if self.blocksan is not None else {}),
             "recommended_replicas": self.recommend_replicas(),
             "recommended_replicas_peak": self._recommend_peak,
-            "async_host": self.async_host,
         }
-        # host–device overlap rollup (round 16): per-replica device-busy
-        # fractions PLUS the interval-union fraction. On a shared device
-        # (the CPU simulation) a replica's dispatch→completion window
-        # includes time queued behind the other replicas, so per-replica
-        # fractions overlap and must not be summed — the union is true
-        # device utilization, backend-marked (gather_ab_backend pattern)
-        if self.ledger.enabled:
-            from pytorch_distributed_tpu.telemetry.overlap import (
-                fleet_busy_summary,
-            )
-
-            fb = fleet_busy_summary(self.ledger.snapshot())
-            if fb["replicas"]:
-                import jax
-
-                out["device_busy_frac_union"] = fb["union_busy_frac"]
-                out["device_busy_backend"] = jax.default_backend()
-                for rep, frac in sorted(fb["replicas"].items()):
-                    out[f"r{rep}_device_busy_frac"] = frac
         out.update(self.handoff_lat.summary("handoff"))
         for name in ("ttft", "token_lat", "queue_wait"):
             vals: List[float] = []
@@ -1348,13 +1306,9 @@ class FleetRouter:
 
     def log_summary(self) -> None:
         """One ``kind="fleet_summary"`` JSONL record — the fleet half of
-        what ``scripts/telemetry_report.py`` renders. Flushes the async
-        host workers first so every offloaded per-request record lands
+        what ``scripts/telemetry_report.py`` renders. Settles the host
+        workers first so every offloaded per-request record lands
         before the summary that aggregates them."""
-        if self.host_pool is not None:
-            for s in self.replicas:
-                s.flush_host_work()
-            self.host_pool.flush()
+        self._settle_host_work()
         if self.metrics_log is not None:
-            with self.ledger.host("jsonl-emit"):
-                self.metrics_log.log(kind="fleet_summary", **self.metrics())
+            self.metrics_log.log(kind="fleet_summary", **self.metrics())

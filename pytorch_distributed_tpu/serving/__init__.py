@@ -35,9 +35,9 @@ Round 16: the host runtime — ``scheduler`` splits each tick into a
 non-blocking ``dispatch_tick`` and a lagged ``collect_tick``
 (``engine.decode_launch``/``decode_collect``), and ``host_worker``
 provides the thread pool the off-critical-path host work (JSONL, gate
-percentile math) runs on; ``fleet.FleetRouter`` is the driver, and
-that lagged loop is the one it runs (``async_host=False`` is the
-tests' step-domain reference) — ANALYSIS.md "Async host runtime".
+percentile math) runs on; ``fleet.FleetRouter`` drives the halves
+lagged (collect N−1, dispatch N), a lone ``Scheduler.step()`` back to
+back — ANALYSIS.md "Async host runtime".
 
 PR 39: a tick crosses the host/device boundary once each way. A launch
 of either tick program moves ONE packed int32 operand (a row a job or a

@@ -78,7 +78,6 @@ from pytorch_distributed_tpu.compilecache.aot import (
 )
 from pytorch_distributed_tpu.ops import attention as attention_ops
 from pytorch_distributed_tpu.telemetry import spans
-from pytorch_distributed_tpu.telemetry.overlap import NULL_LEDGER
 
 
 def _pow2_bucket(n: int) -> int:
@@ -423,12 +422,6 @@ class PagedEngine:
         self.tick_expert_counts: Optional[np.ndarray] = None
         self.chunk_expert_counts = None
         self._tick_counts = None
-        # host–device overlap ledger (round 15; telemetry/overlap.py):
-        # every compiled launch below reports its dispatch wall through
-        # it. NULL_LEDGER by default; the scheduler arms it and stamps
-        # the replica id so fleet timelines attribute per replica.
-        self.ledger = NULL_LEDGER
-        self.ledger_replica = 0
         # prefill→decode handoff programs (fleet disaggregation), one
         # per pow2 chain-length bucket. Gated by ``handoff=`` so engines
         # that never hand off predict no kv_export/kv_import programs
@@ -683,7 +676,7 @@ class PagedEngine:
             # replaced by the slot's next final prefill chunk before they
             # are read. The positions the tick leaves behind are the
             # launched ones plus one on the active lanes: the host counts
-            # them itself (``_decode_call``) and the program returns none.
+            # them itself (``decode_launch``) and the program returns none.
             if per_request:
                 return (variables["cache"], out[:, 0], tokens,
                         _expert_counts(variables.get("moe_stats", {}),
@@ -1166,13 +1159,11 @@ class PagedEngine:
             # duplicate the boundary block BEFORE any write lands in it:
             # positions [n_shared*bl, L-1) must be readable from a block
             # this chain owns exclusively
-            with self.ledger.launch(self.ledger_replica,
-                                    self.BLOCK_COPY_PROGRAM):
-                self.cache = self._block_copy_fn()(
-                    self.cache,
-                    jnp.asarray(matched[n_shared], jnp.int32),
-                    jnp.asarray(chain[n_shared], jnp.int32),
-                )
+            self.cache = self._block_copy_fn()(
+                self.cache,
+                jnp.asarray(matched[n_shared], jnp.int32),
+                jnp.asarray(chain[n_shared], jnp.int32),
+            )
             self._cow_copies += 1
             if self.allocator.sanitizer is not None:
                 self.allocator.sanitizer.note_cow(
@@ -1310,13 +1301,10 @@ class PagedEngine:
         n_pad = self._chain_bucket(len(chain))
         idx = np.full((n_pad,), TRASH_BLOCK, np.int32)
         idx[:len(chain)] = chain
-        with self.ledger.launch(self.ledger_replica,
-                                self.export_program_name(n_pad)) as lt:
-            blocks, row = self._export_fn(n_pad)(
-                self.cache, self.logits, jnp.asarray(idx),
-                jnp.asarray(slot, jnp.int32),
-            )
-            lt.handle = row  # pure-read output: safe to fence lagged
+        blocks, row = self._export_fn(n_pad)(
+            self.cache, self.logits, jnp.asarray(idx),
+            jnp.asarray(slot, jnp.int32),
+        )
         return KVExport(
             blocks=blocks,
             logits_row=row,
@@ -1360,12 +1348,10 @@ class PagedEngine:
                 export.blocks, self.cache,
             )
             row = jax.device_put(export.logits_row, self.logits.sharding)
-            with self.ledger.launch(self.ledger_replica,
-                                    self.import_program_name(n_pad)):
-                self.cache, self.logits = self._import_fn(n_pad)(
-                    self.cache, self.logits, blocks, jnp.asarray(idx),
-                    jnp.asarray(slot, jnp.int32), row,
-                )
+            self.cache, self.logits = self._import_fn(n_pad)(
+                self.cache, self.logits, blocks, jnp.asarray(idx),
+                jnp.asarray(slot, jnp.int32), row,
+            )
         except BaseException:
             # the fresh chain was allocated but never committed to the
             # table: free it, or a failed cross-device transfer leaks
@@ -1450,13 +1436,10 @@ class PagedEngine:
             n_pad = self._chain_bucket(len(chain))
             idx = np.full((n_pad,), TRASH_BLOCK, np.int32)
             idx[:len(chain)] = chain
-            with self.ledger.launch(self.ledger_replica,
-                                    self.swap_out_program_name(n_pad)) as lt:
-                blocks, row = self._swap_out_fn(n_pad)(
-                    self.cache, self.logits, jnp.asarray(idx),
-                    jnp.asarray(slot, jnp.int32),
-                )
-                lt.handle = row  # pure-read output: safe to fence lagged
+            blocks, row = self._swap_out_fn(n_pad)(
+                self.cache, self.logits, jnp.asarray(idx),
+                jnp.asarray(slot, jnp.int32),
+            )
             for leaf in jax.tree.leaves(blocks) + [row]:
                 try:
                     leaf.copy_to_host_async()  # overlap d2h with serving
@@ -1557,12 +1540,10 @@ class PagedEngine:
                 _padded, lambda r, rows: jax.device_put(r, rows.sharding),
                 chain.blocks, self.cache)
             row = jax.device_put(chain.logits_row, self.logits.sharding)
-            with self.ledger.launch(self.ledger_replica,
-                                    self.swap_in_program_name(n_pad)):
-                self.cache, self.logits = self._swap_in_fn(n_pad)(
-                    self.cache, self.logits, blocks, jnp.asarray(idx),
-                    jnp.asarray(slot, jnp.int32), row,
-                )
+            self.cache, self.logits = self._swap_in_fn(n_pad)(
+                self.cache, self.logits, blocks, jnp.asarray(idx),
+                jnp.asarray(slot, jnp.int32), row,
+            )
         except BaseException:
             with self._san_site("swap-in"):
                 self.allocator.clear_state(slot)
@@ -1604,15 +1585,11 @@ class PagedEngine:
             fn = self._chunk_fn(k_pad, wp)
             name = self.chunk_program_name(k_pad, wp)
             put_args = _put_args(host)
-        # no fence handle: both outputs are donated into later programs,
-        # so completion rides the t1 lower bound tightened by the next
-        # sync launch on this replica stream (the decode tick).
         with tr.span("engine.chunk.launch", jobs=len(jobs),
                      bucket=(k_pad, wp)), \
-                program_load_if((k_pad, wp) not in self._hot_chunks, name), \
-                self.ledger.launch(self.ledger_replica, name):
+                program_load_if((k_pad, wp) not in self._hot_chunks, name):
             # ONE explicit transfer of the one packed operand, inside
-            # the launch window (dispatch cost; see the decode call's
+            # the launch span (dispatch cost; see the decode launch's
             # note on why it is explicit)
             with tr.span("engine.chunk.put", **put_args):
                 packed = jax.device_put(host)
@@ -1625,16 +1602,19 @@ class PagedEngine:
             self.chunk_expert_counts = counts[0]
         self._hot_chunks.add((k_pad, wp))
 
-    def _decode_call(self, positions, active, rng, sync: bool):
-        """One decode-tick launch, shared by the sync and async host
-        paths: ``(tokens, new_positions, launch_token)``. ``sync=True``
-        materializes the tokens INSIDE the ledger window (t1 is exact
-        completion — the historical ``decode`` contract); ``sync=False``
-        returns them on the device plus the launch token so the caller
-        can pin completion at its own collect site
-        (``DispatchLedger.complete``). ``new_positions`` is a host array
-        either way: the launched positions plus one on the active lanes,
-        which is all the tick does to them, so nothing is fetched for it."""
+    def decode_launch(self, positions: np.ndarray, active: np.ndarray,
+                      rng):
+        """Launch one decode tick for every slot WITHOUT waiting for it:
+        ``(device_tokens, new_positions)``. The program samples from the
+        logits buffer and writes each active lane's token at its position;
+        inactive lanes compute dead garbage routed to the trash block and
+        keep their position. ``new_positions`` is a host array, a copy:
+        the launched positions plus one on the active lanes, which is all
+        the tick does to them, so nothing is fetched for it (rows the
+        caller arms before the collect are not in it). The caller
+        materializes the tokens later through ``decode_collect``, while
+        this device (or another replica's) is already running the next
+        program."""
         tr = spans.tracer()
         with tr.span("engine.decode.build"):
             positions = np.asarray(positions, np.int32)
@@ -1659,72 +1639,40 @@ class PagedEngine:
                 live_blocks=int(np.sum(live + 1)),
                 live_tiles=int(np.sum(live // self.tile_blocks + 1)))
             put_args = _put_args(host)
-        with self.ledger.launch(self.ledger_replica, self.DECODE_PROGRAM,
-                                sync=sync) as lt:
-            # ONE explicit transfer of the one packed operand, inside
-            # the launch window — it is dispatch cost, and it costs its
-            # arrays, not its bytes (0.2 ms an array on a v5e's host,
-            # PERF.md): table, positions and flags cross as one. A
-            # bare-np jit call would be an IMPLICIT transfer the
-            # no_recompile guard rightly rejects.
-            with tr.span("engine.decode.launch", **launch_args), \
-                    program_load_if(not self._hot_decode,
-                                    self.DECODE_PROGRAM):
-                with tr.span("engine.decode.put", **put_args):
-                    packed = jax.device_put(host)
-                # and the key: the call flattens it with the operand
-                with tr.span("engine.decode.call",
-                             leaves=self._resident_leaves + 2):
-                    self.cache, self.logits, tokens, *counts = fn(
-                        self.params, self.cache, self.logits, packed, rng,
-                    )
-                self._tick_counts = counts[0] if counts else None
-            if sync:
-                # the token fetch inside the window materializes the
-                # program's result, so t1 IS device completion — the
-                # exact anchor the chunk launches' lower bounds tighten
-                # against. It is where the host waits for the tick: the
-                # same span the async path books in decode_collect
-                with spans.tracer().span("engine.collect.wait"):
-                    tokens = self._fetch_tick(tokens)
-            else:
-                lt.handle = tokens  # non-donated output: fence target
+        # ONE explicit transfer of the one packed operand, inside the
+        # launch span — it is dispatch cost, and it costs its arrays, not
+        # its bytes (0.2 ms an array on a v5e's host, PERF.md): table,
+        # positions and flags cross as one. A bare-np jit call would be an
+        # IMPLICIT transfer the no_recompile guard rightly rejects.
+        with tr.span("engine.decode.launch", **launch_args), \
+                program_load_if(not self._hot_decode,
+                                self.DECODE_PROGRAM):
+            with tr.span("engine.decode.put", **put_args):
+                packed = jax.device_put(host)
+            # and the key: the call flattens it with the operand
+            with tr.span("engine.decode.call",
+                         leaves=self._resident_leaves + 2):
+                self.cache, self.logits, tokens, *counts = fn(
+                    self.params, self.cache, self.logits, packed, rng,
+                )
+            self._tick_counts = counts[0] if counts else None
         self._hot_decode = True
-        return tokens, new_positions, lt
-
-    def decode(self, positions: np.ndarray, active: np.ndarray, rng):
-        """One decode tick for every slot; samples from the logits
-        buffer, writes each active lane's token at its position, returns
-        ``(tokens [n_slots], new_positions)``, both on the host. Inactive
-        lanes compute dead garbage routed to the trash block and keep
-        their position."""
-        tokens, new_positions, _ = self._decode_call(positions, active, rng,
-                                                     sync=True)
         return tokens, new_positions
 
-    def decode_launch(self, positions: np.ndarray, active: np.ndarray,
-                      rng):
-        """The async host path's non-blocking decode tick (round 16):
-        dispatches the SAME compiled program as ``decode`` — identical
-        shapes, zero new registry entries — and returns
-        ``(device_tokens, new_positions, launch_token)`` WITHOUT
-        materializing the tokens (``new_positions`` is the host's own
-        count, a copy: rows the caller arms before the collect are not in
-        it). The caller materializes later through ``decode_collect``
-        while this device (or another replica's) is already running the
-        next program."""
-        return self._decode_call(positions, active, rng, sync=False)
-
-    def decode_collect(self, tokens, new_positions, launch_token):
-        """Materialize a ``decode_launch``'s results: pins the launch's
-        completion on the ledger (a collect-site fence — by now the
-        work is usually done and the wait is a no-op), then fetches the
-        tokens to host: the one fetch of a tick. Returns the same
-        ``(tokens [n_slots], new_positions)`` as ``decode``."""
+    def decode_collect(self, tokens, new_positions):
+        """Materialize a ``decode_launch``'s results: fetches the tokens
+        to the host, the one fetch of a tick and where the host waits for
+        the device. Returns ``(tokens [n_slots], new_positions)``, both on
+        the host."""
         with spans.tracer().span("engine.collect.wait"):
-            self.ledger.complete(launch_token)
             tokens = self._fetch_tick(tokens)
         return tokens, new_positions
+
+    def decode(self, positions: np.ndarray, active: np.ndarray, rng):
+        """One whole decode tick, launched then collected: ``(tokens
+        [n_slots], new_positions)``, both on the host."""
+        return self.decode_collect(
+            *self.decode_launch(positions, active, rng))
 
     def _fetch_tick(self, tokens) -> np.ndarray:
         """The tick's tokens on the host and, in the same fetch (no

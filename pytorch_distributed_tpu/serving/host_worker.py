@@ -1,19 +1,16 @@
-"""Worker-thread pool for the async host runtime (round 16).
+"""Worker-thread pool for the router's host loop (round 16).
 
-The one-loop fleet serialized every replica's host work — JSONL
-emission, gate-metric percentile math, tokenize — onto the critical
-path between device dispatches; ``telemetry/overlap.py`` measured that
-serialization as the dominant bubble cause (96% ``other-replica-tick``
-at 2 replicas, ``BENCH_r06.json``). The async refactor moves that work
-here: a small pool of named daemon threads draining a FIFO queue of
-closures, so the main loop's only job between ticks is dispatch and
-collect.
+A fleet's one host loop would otherwise run every replica's host work —
+JSONL emission, gate-metric percentile math — on the critical path
+between device dispatches. That work runs here: a small pool of named
+daemon threads draining a FIFO queue of closures, so the main loop's
+only job between ticks is dispatch and collect.
 
 Thread-safety contract (the ``rules_threads`` inventory for this round;
 ANALYSIS.md "Async host runtime" carries the full table):
 
 - work items may touch ONLY (a) objects with their own locks
-  (``MetricsLogger``, ``ReqTracer``, ``DispatchLedger``), (b) data
+  (``MetricsLogger``, ``ReqTracer``), (b) data
   copied onto the closure at enqueue time (the retired ``Request``,
   copied latency-series value lists), and (c) caches guarded by a
   dedicated lock (the scheduler's gate-metrics snapshot). Scheduler and
@@ -54,11 +51,8 @@ class HostWorkerPool:
     enqueued so far has run (and re-raises the first worker error);
     ``close()`` flushes and joins the threads; ``stop()`` ends them
     without waiting (a ``FleetRouter``'s pool stops when the router is
-    collected). Thread names
-    (``pdt-host-0`` ...) are load-bearing: ``DispatchLedger.host``
-    stamps them into worker-side host marks, which is how
-    ``classify_bubbles`` tells overlapped worker work apart from
-    ``idle-no-work``.
+    collected). The threads are named ``pdt-host-0`` ... so a thread
+    dump tells them from the loop's.
     """
 
     def __init__(self, n_threads: int = 2, name: str = "pdt-host"):

@@ -75,14 +75,14 @@ work (TTFT, retirement, JSONL). ``fleet.FleetRouter`` drives the halves
 LAGGED — collect tick N−1, then dispatch tick N, on every replica, and
 return with N in flight — so the device works through the caller's
 submits and the other replicas' host work. ``step()`` is the same two
-halves with the tokens fetched inside the launch: the step-domain
-schedule of a lone ``Scheduler`` and of ``FleetRouter(async_host=
-False)``, which the parity tests hold the lagged loop to. Per replica
-the order collect(N−1) → dispatch(N) is exactly that schedule, which
-is why token streams are bit-identical between the two. Any entry
-point that mutates decode-armed state from OUTSIDE the tick cycle
-(``preempt``/``preempt_lru``/``begin_drain``) collects the pending
-tick first, so an in-flight decode can never race a chain release.
+halves back to back, collect(N−1) → dispatch(N) → collect(N): a lone
+``Scheduler``'s tick, which returns tick N's tokens from the call that
+launched it. Per replica the order of dispatches and collects is the
+same in both, which is why their token streams are bit-identical. Any
+entry point that mutates decode-armed state from OUTSIDE the tick
+cycle (``preempt``/``preempt_lru``/``begin_drain``) collects the
+pending tick first, so an in-flight decode can never race a chain
+release.
 ``host_pool`` (a ``serving.host_worker.HostWorkerPool``) moves
 per-request JSONL emission and the gate-metrics percentile math onto
 worker threads; ``gate_metrics()`` is the router's routing view —
@@ -132,7 +132,6 @@ import numpy as np
 from pytorch_distributed_tpu.compilecache.aot import attribute_compile
 from pytorch_distributed_tpu.resilience.faults import fault_point
 from pytorch_distributed_tpu.telemetry import (
-    NULL_LEDGER,
     NULL_RECORDER,
     NULL_REQTRACER,
     AnomalySentinel,
@@ -225,10 +224,9 @@ class Request:
 class TickHandle(NamedTuple):
     """One dispatched-but-uncollected scheduler tick (round 16).
 
-    ``tokens`` is the decode program's token output — a DEVICE array on
-    the async path (materialized at collect), an np array on the sync
-    path (materialized inside the ledger window), or None when the tick
-    had no active decode lane. ``positions`` is the engine's host count
+    ``tokens`` is the decode program's token output — a DEVICE array,
+    materialized at collect — or None when the tick had no active
+    decode lane. ``positions`` is the engine's host count
     of every slot's position after the tick, as of the launch (nothing
     is fetched for it). ``lanes`` are the slots that were active
     at dispatch, in slot order — collect processes exactly these, and
@@ -237,12 +235,10 @@ class TickHandle(NamedTuple):
 
     tokens: object
     positions: object
-    launch: object  # engine launch token (None for sync / no-decode)
     lanes: Tuple[int, ...]
     t_step0: float
     t_dec: float
     cold_decode: bool
-    sync: bool
 
 
 class Scheduler:
@@ -267,7 +263,7 @@ class Scheduler:
                  swap_policy: str = "auto", protect_ticks: int = 2,
                  host_store=None,
                  host_store_max_bytes: Optional[int] = None,
-                 reqtrace=None, ledger=None, host_pool=None,
+                 reqtrace=None, host_pool=None,
                  prefix_cache: bool = False, blocksan=None,
                  chunk_bucket_floor: Tuple[int, int] = (1, 1),
                  max_chunk_jobs: Optional[int] = None):
@@ -422,13 +418,6 @@ class Scheduler:
         )
         self._cancelled = 0
         self._deadline_misses = 0
-        # host–device overlap ledger (round 15; telemetry/overlap.py):
-        # the engine reports every compiled launch through it, and the
-        # host marks below (admission, JSONL emit, swap decision) are
-        # the attribution targets its bubble classifier resolves to
-        self.ledger = ledger if ledger is not None else NULL_LEDGER
-        self.engine.ledger = self.ledger
-        self.engine.ledger_replica = replica_id
         # ---- async host runtime (round 16) ----
         # the dispatched-but-uncollected tick (main-thread-only state:
         # only dispatch_tick/collect_tick and the early-collect hooks
@@ -441,7 +430,7 @@ class Scheduler:
         # optional worker pool (serving.host_worker.HostWorkerPool):
         # per-request JSONL emission and the gate-metrics percentile
         # math run there; everything a worker touches is either
-        # self-locked (logger/tracer/ledger), copied at enqueue, or the
+        # self-locked (logger/tracer), copied at enqueue, or the
         # snapshot below under its dedicated lock
         self.host_pool = host_pool
         self._gate_cache: Optional[dict] = None
@@ -817,8 +806,7 @@ class Scheduler:
         req = self.resident[slot]
         if req.prefill_done < req.length:
             raise ValueError(f"rid {rid} is mid-prefill: not preemptible")
-        with self.ledger.host("swap-decision", self.replica_id):
-            decision = self._swap_decision(req, slot)
+        decision = self._swap_decision(req, slot)
         if decision is None:
             return None
         if self.reqtrace.enabled:
@@ -1112,14 +1100,11 @@ class Scheduler:
             ))
         return jobs
 
-    def dispatch_tick(self, sync: bool = False) -> None:
+    def dispatch_tick(self) -> None:
         """The non-blocking half of one tick: restores/admissions → one
         prefill chunk per unfinished prompt (ONE compiled program) →
         the decode program LAUNCHED (not materialized). Parks a
-        ``TickHandle`` for ``collect_tick``. ``sync=True`` (the
-        synchronous loop, via ``step``) materializes the tokens inside
-        the launch window instead — the historical exact-completion
-        ledger anchor."""
+        ``TickHandle`` for ``collect_tick``."""
         if self._pending_tick is not None:
             raise RuntimeError(
                 "collect_tick() must drain the pending tick before "
@@ -1142,8 +1127,7 @@ class Scheduler:
             # ahead of the queue, before its next decode tick
             self._finalize_swaps()
             self._restore_parked()
-        with tr.span("sched.admit", queued=len(self.queue)) as admit, \
-                self.ledger.host("admission/gate", self.replica_id):
+        with tr.span("sched.admit", queued=len(self.queue)) as admit:
             admit.args["waited"] = self._admit()
             admit.args["free_blocks"] = self.engine.allocator.available
         with tr.span("sched.chunk_plan"):
@@ -1164,7 +1148,7 @@ class Scheduler:
             if not cold_bucket:
                 # cost-card join: warm dispatch wall attributed to THIS
                 # bucket's program (cold calls excluded — their wall is
-                # compile, already booked to the ledger above)
+                # compile, already booked to goodput above)
                 self.prog_times.observe(
                     self.engine.chunk_program_name(*bucket),
                     time.perf_counter() - t_chunk,
@@ -1231,7 +1215,7 @@ class Scheduler:
         self._step_count += 1
         if not active.any():
             self._pending_tick = TickHandle(
-                None, None, None, (), t_step0, t_step0, False, sync,
+                None, None, (), t_step0, t_step0, False,
             )
             return
         if self.engine.temperature == 0.0:
@@ -1242,11 +1226,7 @@ class Scheduler:
             # signature, zero recompiles); sampled runs split as ever.
             sub = self._rng
         else:
-            with self.ledger.host("sampling-prep", self.replica_id):
-                # sampling-param prep: the per-tick key split (host-side
-                # dispatch of a tiny program) — marked so its share of
-                # any bubble is attributable
-                self._rng, sub = jax.random.split(self._rng)
+            self._rng, sub = jax.random.split(self._rng)
         cold_decode = not self.engine.has_decode_program
         if cold_decode:
             # every active lane's token this tick arrives through the
@@ -1255,19 +1235,12 @@ class Scheduler:
                 self.resident[int(slot)].cold = True
         t_dec = time.perf_counter()
         with attribute_compile(self.goodput if cold_decode else None):
-            if sync:
-                tokens, positions = self.engine.decode(
-                    self.positions, active, sub
-                )
-                launch = None
-            else:
-                tokens, positions, launch = self.engine.decode_launch(
-                    self.positions, active, sub
-                )
+            tokens, positions = self.engine.decode_launch(
+                self.positions, active, sub
+            )
         lanes = tuple(int(s) for s in np.nonzero(active)[0])
         self._pending_tick = TickHandle(
-            tokens, positions, launch, lanes, t_step0, t_dec,
-            cold_decode, sync,
+            tokens, positions, lanes, t_step0, t_dec, cold_decode,
         )
 
     def collect_tick(self) -> List[Tuple[int, int]]:
@@ -1308,12 +1281,9 @@ class Scheduler:
         if h.tokens is None:
             self._observe_tick(h.t_step0)
             return
-        if h.sync:
-            tokens, positions = h.tokens, h.positions
-        else:
-            tokens, positions = self.engine.decode_collect(
-                h.tokens, h.positions, h.launch
-            )
+        tokens, positions = self.engine.decode_collect(
+            h.tokens, h.positions
+        )
         # ``positions`` is the engine's host count as of the launch (the
         # launched rows, plus one where the tick decoded): write back
         # ONLY the lanes this tick decoded, so that rows the host armed
@@ -1326,16 +1296,12 @@ class Scheduler:
         now = time.perf_counter()
         if not h.cold_decode:
             # cost-card join: dispatch + device + sync — the honest
-            # decode-tick cost (on the async path the sync lands here,
-            # at collect, where the stream actually pays it)
+            # decode-tick cost (the sync lands here, at collect, where
+            # the stream actually pays it)
             self.prog_times.observe(self.engine.DECODE_PROGRAM,
                                     now - h.t_dec)
         out: List[Tuple[int, int]] = []
-        # collect-side host work under its own mark: the one-loop async
-        # A/B needs "processing replica i's tokens" visible as a cause
-        # when it serializes another replica's gap
-        with spans.tracer().span("sched.collect.process") as process, \
-                self.ledger.host("tick-collect", self.replica_id):
+        with spans.tracer().span("sched.collect.process") as process:
             counts = self.engine.tick_expert_counts
             if counts is not None and counts.size:
                 # what the tick's expert layers took (fetched with its
@@ -1431,14 +1397,13 @@ class Scheduler:
         self._observe_tick(h.t_step0)
 
     def step(self) -> List[Tuple[int, int]]:
-        """One synchronous tick: dispatch + same-tick collect (the
-        historical contract — admissions → one prefill chunk per
-        unfinished prompt → one decode token per ready lane →
-        retirements). Returns ``[(rid, token)]``. Any tick left pending
-        by an async driver is collected first, so mode mixing never
-        drops a token."""
+        """One whole tick, launched then collected in the same call
+        (admissions → one prefill chunk per unfinished prompt → one
+        decode token per ready lane → retirements). Returns ``[(rid,
+        token)]``. A tick a lagged driver left pending is collected
+        first, so mixing the two never drops a token."""
         out = self.collect_tick()
-        self.dispatch_tick(sync=True)
+        self.dispatch_tick()
         return out + self.collect_tick()
 
     def _note_anomaly(self, hit: Optional[dict]) -> None:
@@ -1481,17 +1446,15 @@ class Scheduler:
         batch, self._tick_obs = self._tick_obs, []
 
         def work():
-            with self.ledger.host("metrics-refresh", self.replica_id):
-                last_hit = None
-                for wall, depth, tick in batch:
-                    h1 = self.sentinel.observe("tick_time", wall,
-                                               tick=tick)
-                    h2 = self.sentinel.observe("queue_depth", depth,
-                                               tick=tick)
-                    if h1 is not None or h2 is not None:
-                        last_hit = tick
-                if last_hit is not None:
-                    self._last_anomaly_step = last_hit
+            last_hit = None
+            for wall, depth, tick in batch:
+                h1 = self.sentinel.observe("tick_time", wall, tick=tick)
+                h2 = self.sentinel.observe("queue_depth", depth,
+                                           tick=tick)
+                if h1 is not None or h2 is not None:
+                    last_hit = tick
+            if last_hit is not None:
+                self._last_anomaly_step = last_hit
 
         self.host_pool.submit(work)
 
@@ -1516,22 +1479,14 @@ class Scheduler:
         ``host_pool`` the serialization+write runs on a worker thread:
         a retired ``Request`` is never mutated again (it left
         ``resident`` in the same collect that enqueues this), so the
-        closure captures an effectively-frozen object; the logger and
-        ledger are self-locked."""
+        closure captures an effectively-frozen object; the logger is
+        self-locked."""
         if self.metrics_log is None:
             return
         if self.host_pool is not None:
-            self.host_pool.submit(lambda: self._emit_request_record(req))
+            self.host_pool.submit(lambda: self._log_request_record(req))
             return
-        with self.ledger.host("jsonl-emit", self.replica_id):
-            self._log_request_record(req)
-
-    def _emit_request_record(self, req: Request) -> None:
-        # worker-side: the ledger stamps the worker thread's name on
-        # the mark, so classify_bubbles sees offloaded JSONL work as
-        # "jsonl-emit@pdt-host-N", not idle-no-work
-        with self.ledger.host("jsonl-emit", self.replica_id):
-            self._log_request_record(req)
+        self._log_request_record(req)
 
     def _log_request_record(self, req: Request) -> None:
         self.metrics_log.log(
@@ -1971,26 +1926,24 @@ class Scheduler:
         goodput_frac = self.goodput.report()["goodput_frac"]
 
         def work():
-            with self.ledger.host("metrics-refresh", self.replica_id):
-                snap = {"goodput_frac": goodput_frac}
-                for name, v in vals.items():
-                    for q, val in percentiles(v, qs=(95,)).items():
-                        snap[f"{name}_{q}_s"] = val
-                with self._gate_lock:
-                    self._gate_cache = snap
+            snap = {"goodput_frac": goodput_frac}
+            for name, v in vals.items():
+                for q, val in percentiles(v, qs=(95,)).items():
+                    snap[f"{name}_{q}_s"] = val
+            with self._gate_lock:
+                self._gate_cache = snap
 
         self.host_pool.submit(work)
 
     def gate_metrics(self) -> dict:
         """The SLO gate's routing view of this replica. Without a
-        worker pool (a lone ``Scheduler``, the ``async_host=False``
-        reference): the full (exact, O(n log n)) ``metrics()``. Under
-        the router's pool: the worker-refreshed percentile snapshot
-        overlaid with LIVE cheap counters — queue depth, occupancy,
-        draining, preemptible, anomaly — so every depth-bound decision
-        the gate makes is byte-identical to what the reference would
-        decide, and only the wall-clock percentile rungs see (at most
-        ``gate_refresh_ticks`` of) staleness."""
+        worker pool (a lone ``Scheduler``): the full (exact, O(n log n))
+        ``metrics()``. Under the router's pool: the worker-refreshed
+        percentile snapshot overlaid with LIVE cheap counters — queue
+        depth, occupancy, draining, preemptible, anomaly — so every
+        depth-bound decision the gate makes is byte-identical to what a
+        lone scheduler's would be, and only the wall-clock percentile
+        rungs see (at most ``gate_refresh_ticks`` of) staleness."""
         if self.host_pool is None:
             return self.metrics()
         with self._gate_lock:

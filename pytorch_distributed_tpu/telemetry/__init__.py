@@ -55,18 +55,6 @@ tracing"):
 - ``schema`` — the JSONL record-kind registry: required keys per kind
   with a validator, so emitter drift breaks CI instead of the report.
 
-Round 15 adds the host–device overlap layer (ANALYSIS.md "Host–device
-overlap"):
-
-- ``overlap`` — a dispatch ledger wrapping every compiled call site
-  (engine chunk/decode/export/import/swap, trainer train/eval steps):
-  host dispatch walls, lagged device-completion fences (never a sync on
-  the hot path), a per-replica device timeline, and every inter-launch
-  gap classified as a bubble attributed to its host cause by joining
-  the span stream's logical clock (``kind="overlap"`` JSONL;
-  ``scripts/bench_serving.py --wall-clock`` is the fleet bench ROADMAP
-  item 3's async refactor gates against).
-
 Round 21 adds the scale observatory (ANALYSIS.md "Scale observatory"):
 
 - ``hostprof`` — a ``ResourceMonitor`` sampling host RSS
@@ -128,16 +116,6 @@ from pytorch_distributed_tpu.telemetry.hostprof import (
     rss_mib,
 )
 from pytorch_distributed_tpu.telemetry.latency import LatencySeries, percentiles
-from pytorch_distributed_tpu.telemetry.overlap import (
-    NULL_LEDGER,
-    DispatchLedger,
-    busy_summary,
-    busy_within,
-    cause_histogram,
-    classify_bubbles,
-    device_timeline,
-    fleet_busy_summary,
-)
 from pytorch_distributed_tpu.telemetry.reqtrace import (
     NULL_REQTRACER,
     SPAN_SCHEMA_VERSION,
@@ -191,14 +169,6 @@ __all__ = [
     "GoodputLedger",
     "LatencySeries",
     "percentiles",
-    "NULL_LEDGER",
-    "DispatchLedger",
-    "busy_summary",
-    "busy_within",
-    "cause_histogram",
-    "classify_bubbles",
-    "device_timeline",
-    "fleet_busy_summary",
     "NULL_REQTRACER",
     "SPAN_SCHEMA_VERSION",
     "ReqTracer",

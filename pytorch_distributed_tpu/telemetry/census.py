@@ -25,10 +25,9 @@ Bound classes (``Decl.kind``):
     O(fleet size).  Audited against ``replicas`` when given.
 ``unbounded``
     Unbounded *by design* (the scheduler queue under admission
-    backpressure, ``ReqTracer.records`` in keep-mode tests, the
-    dispatch ledger's profiling log).  Never flags; the declaration
-    exists so the ``why`` is written down and the meta-test knows the
-    container was considered, not missed.
+    backpressure, ``ReqTracer.records`` in keep-mode tests).  Never
+    flags; the declaration exists so the ``why`` is written down and
+    the meta-test knows the container was considered, not missed.
 
 ``kind`` and ``cap`` may be callables of the owner so a declaration
 can depend on runtime mode — ``FleetRouter.results`` is

@@ -23,9 +23,8 @@ What a sample carries:
 - ``live`` / ``cumulative`` — the load axes the growth sentinel
   regresses against (live in-flight requests; sessions ever served).
 - ``tick_wall_ms_mean`` — mean host wall per tick over the window
-  since the previous sample, fed by ``tick(wall_s=...)``.  This is the
-  per-tick host-wall series for the scaling fit without requiring the
-  O(launches) dispatch ledger to be live during a soak.
+  since the previous sample, fed by ``tick(wall_s=...)``: the
+  per-tick host-wall series for the scaling fit.
 - optional ``tracemalloc`` top allocation sites every
   ``tracemalloc_every`` samples (0 = never start tracemalloc).
 """

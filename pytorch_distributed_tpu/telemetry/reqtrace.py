@@ -105,19 +105,6 @@ class ReqTracer:
 
     # -- emission ----------------------------------------------------------
 
-    def claim_seq(self) -> int:
-        """Allocate one tick of the logical clock WITHOUT emitting a
-        record — the round-15 dispatch ledger (``telemetry.overlap``)
-        stamps its launch windows from the same clock as the span
-        stream, which is what makes "what spans landed between launch N
-        and N+1" a pure seq-range query. Claimed seqs appear as gaps in
-        the span stream's numbering; ``validate_trace`` only requires
-        monotonicity, so gaps are legal."""
-        with self._lock:
-            s = self._seq
-            self._seq += 1
-            return s
-
     def _emit(self, record: dict) -> None:
         # caller holds the lock: seq order and sink order must agree
         record["seq"] = self._seq
@@ -487,31 +474,13 @@ def chrome_trace(records: Iterable[dict]) -> dict:
     endpoint spans. Instant events render as thread-scoped ``i``
     events. Spans still open at export time render to the stream's last
     timestamp with ``open: true`` — a crashed run's last phase stays
-    visible instead of vanishing.
-
-    When the stream also carries ``kind="overlap"`` launch records
-    (round 15, ``telemetry.overlap``), each replica additionally gets a
-    synthetic "device r<N>" process (pid ``DEVICE_PID_BASE + N``) with
-    a **device** track of estimated busy slices and a **dispatch**
-    track of host dispatch walls, joined by flow arrows — the
-    host-vs-device overlap view next to the per-request span trees."""
-    records = list(records)
+    visible instead of vanishing."""
     recs = span_records(records)
-    from pytorch_distributed_tpu.telemetry.overlap import (
-        DEVICE_PID_BASE,
-        device_timeline,
-    )
-
-    timelines = device_timeline(records)
-    launch_ts = [
-        t for slices in timelines.values() for s in slices
-        for t in (s.get("t0", 0.0), s["end"])
-    ]
-    if not recs and not launch_ts:
+    if not recs:
         return {"traceEvents": [], "displayTimeUnit": "ms"}
     span_ts = [r.get("t", 0.0) for r in recs]
-    t0 = min(span_ts + launch_ts)
-    t_last = max(span_ts + launch_ts)
+    t0 = min(span_ts)
+    t_last = max(span_ts)
 
     def us(t: float) -> float:
         return (t - t0) * 1e6
@@ -573,52 +542,6 @@ def chrome_trace(records: Iterable[dict]) -> dict:
                 "name": r.get("name", "flow"), "cat": "handoff",
                 "ph": "f", "bp": "e", "id": flow_id, "ts": us(dst["t"]),
                 "pid": trace, "tid": dst.get("replica", 0) or 0,
-            })
-    # device tracks (round 15): one synthetic process per replica with a
-    # device row (estimated busy slices) and a dispatch row (host
-    # dispatch walls), flow arrows dispatch → device slice per launch
-    for rep, slices in sorted(timelines.items()):
-        pid = DEVICE_PID_BASE + rep
-        events.append({
-            "name": "process_name", "ph": "M", "pid": pid,
-            "args": {"name": f"device r{rep}"},
-        })
-        events.append({
-            "name": "thread_name", "ph": "M", "pid": pid, "tid": 0,
-            "args": {"name": "device"},
-        })
-        events.append({
-            "name": "thread_name", "ph": "M", "pid": pid, "tid": 1,
-            "args": {"name": "dispatch"},
-        })
-        for s in slices:
-            prog = s.get("program", "?")
-            args = {"seq0": s.get("seq0"), "seq1": s.get("seq1")}
-            if "done" not in s:
-                args["completion"] = "t1-lower-bound"
-            events.append({
-                "name": prog, "ph": "X", "pid": pid, "tid": 1,
-                "ts": us(s.get("t0", 0.0)),
-                "dur": max(us(s.get("t1", 0.0)) - us(s.get("t0", 0.0)),
-                           0.0),
-                "args": args,
-            })
-            events.append({
-                "name": prog, "ph": "X", "pid": pid, "tid": 0,
-                "ts": us(s["start"]),
-                "dur": max(us(s["end"]) - us(s["start"]), 0.0),
-                "args": args,
-            })
-            flow_id = DEVICE_PID_BASE + int(s.get("seq0", 0) or 0)
-            events.append({
-                "name": prog, "cat": "dispatch", "ph": "s",
-                "id": flow_id, "ts": us(s.get("t0", 0.0)),
-                "pid": pid, "tid": 1,
-            })
-            events.append({
-                "name": prog, "cat": "dispatch", "ph": "f", "bp": "e",
-                "id": flow_id, "ts": us(s["start"]),
-                "pid": pid, "tid": 0,
             })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 

@@ -46,9 +46,6 @@ REQUIRED_KEYS: Dict[str, FrozenSet[str]] = {
     ),
     # telemetry/reqtrace.py lifecycle spans (round 14)
     "span": frozenset({"v", "ev", "trace", "span", "seq", "t"}),
-    # telemetry/overlap.py dispatch ledger (round 15); per-``ev`` shapes
-    # refined by ``_OVERLAP_EV_KEYS`` below
-    "overlap": frozenset({"ev", "replica"}),
     # telemetry/goodput.py ledger report
     "goodput": frozenset({"goodput_frac", "productive_s", "wall_s"}),
     # telemetry/anomaly.py sentinel hits
@@ -99,14 +96,6 @@ _SPAN_EV_KEYS: Dict[str, FrozenSet[str]] = {
     "link": frozenset({"dst", "name"}),
 }
 
-#: additional required keys per overlap ``ev`` (see overlap module docs)
-_OVERLAP_EV_KEYS: Dict[str, FrozenSet[str]] = {
-    "launch": frozenset({"program", "t0", "t1", "seq0", "seq1"}),
-    "host": frozenset({"name", "t0", "t1", "seq0", "seq1"}),
-    "bubble": frozenset({"cause", "gap_s", "t0", "t1"}),
-    "summary": frozenset({"launches", "busy_s", "span_s", "busy_frac"}),
-}
-
 #: additional required keys per sanitizer ``ev`` (analysis/blocksan.py)
 _SANITIZER_EV_KEYS: Dict[str, FrozenSet[str]] = {
     "violation": frozenset({"class", "block", "owner", "site"}),
@@ -128,7 +117,6 @@ def validate_record(record: dict, strict: bool = False) -> List[str]:
         for k in sorted(required) if k not in record
     ]
     for refined, table in (("span", _SPAN_EV_KEYS),
-                           ("overlap", _OVERLAP_EV_KEYS),
                            ("sanitizer", _SANITIZER_EV_KEYS)):
         if kind != refined:
             continue

@@ -11,8 +11,8 @@ argument of every ``program.load``):
   unique in the process, ``parent_id`` the enclosing span on that thread
   (or the explicit ``cause=``), ``rid`` the request where there is one,
   ``t0``/``t1`` absolute ``time.perf_counter()`` seconds: the clock of
-  ``telemetry.overlap``, ``telemetry.reqtrace`` and of whoever drives
-  the program, so intervals can be laid beside theirs;
+  ``telemetry.reqtrace`` and of whoever drives the program, so
+  intervals can be laid beside theirs;
 - records live in a ring of ``RING_RECORDS`` (the flight-recorder
   pattern of ``telemetry/flightrec.py``): a server that runs for days
   holds a bounded window, the newest;

@@ -30,7 +30,6 @@ from pytorch_distributed_tpu.resilience.stepguard import (
 )
 from pytorch_distributed_tpu.resilience.watchdog import Watchdog
 from pytorch_distributed_tpu.telemetry import (
-    NULL_LEDGER,
     NULL_RECORDER,
     AnomalySentinel,
     GoodputLedger,
@@ -58,9 +57,6 @@ class SuspendableTrainer:
     exporter = None
     prog_times = None
     _last_step_t = None
-    # host–device overlap ledger (round 15; telemetry/overlap.py):
-    # _bind_observability arms it when config.overlap is set
-    ledger = NULL_LEDGER
 
     # ---- resilience plumbing (resilience/: stepguard, watchdog, faults).
     # Both trainers call _init_resilience from __init__ and bracket each
@@ -137,18 +133,11 @@ class SuspendableTrainer:
 
     def _bind_observability(self) -> None:
         """Called by the trainers once ``self.metrics_log`` exists:
-        attach the sentinel's JSONL stream, arm the overlap dispatch
-        ledger (``config.overlap``; round 15) over the same JSONL, and
-        start the live Prometheus exporter when the config asks for one
+        attach the sentinel's JSONL stream and start the live
+        Prometheus exporter when the config asks for one
         (``metrics_port``)."""
         if self.sentinel is not None:
             self.sentinel.metrics_log = getattr(self, "metrics_log", None)
-        if getattr(self.config, "overlap", False):
-            from pytorch_distributed_tpu.telemetry import DispatchLedger
-
-            self.ledger = DispatchLedger(
-                getattr(self, "metrics_log", None)
-            )
         port = getattr(self.config, "metrics_port", None)
         if port is not None and jax.process_index() == 0:
             from pytorch_distributed_tpu.telemetry import MetricsExporter
@@ -302,11 +291,7 @@ class SuspendableTrainer:
         return {}
 
     def _log_goodput(self) -> None:
-        """Emit the run-level goodput record (fit end / pre-suspend),
-        finalizing the overlap ledger first — its end-of-run fence +
-        bubble classification must land in the same JSONL (idempotent,
-        so the suspend path and fit end can both call this)."""
-        self.ledger.finalize()
+        """Emit the run-level goodput record (fit end / pre-suspend)."""
         if self.goodput is not None and getattr(self, "metrics_log", None):
             self.metrics_log.log(kind="goodput", **self.goodput.report())
 

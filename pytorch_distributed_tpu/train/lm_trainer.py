@@ -175,10 +175,6 @@ class LMTrainerConfig:
     flightrec: bool = True
     cost_cards: bool = False
     metrics_port: Optional[int] = None
-    # Host–device overlap profiling — see TrainerConfig.overlap: the
-    # dispatch ledger (kind="overlap" JSONL) over train/eval launches,
-    # lagged-fenced on the step's metrics outputs.
-    overlap: bool = False
 
 
 class LMTrainer(SuspendableTrainer):
@@ -478,16 +474,13 @@ class LMTrainer(SuspendableTrainer):
             )
             # the run's first dispatch traces + compiles the step: split
             # its wall into compile (XLA backend / cache load) and trace
-            # (Python lowering) so a warm start's ledger shows the cache
+            # (Python lowering) so a warm start's goodput shows the cache
             # win; later recompiles are a guarded hazard, not steady state
             first = self._dispatched == 0
             with spans.tracer().step("train.step_dispatch", step), \
                     program_load_if(first, "lm_train_step"), \
-                    attribute_compile(self.goodput if first else None), \
-                    self.ledger.launch(0, "lm_train_step") as launch:
+                    attribute_compile(self.goodput if first else None):
                 self.state, metrics = self.train_step(self.state, batch)
-                # fresh (non-donated) outputs: the lagged fence target
-                launch.handle = metrics
             self._dispatched += 1
             self._post_step(metrics)
             steps_done += 1
@@ -556,10 +549,7 @@ class LMTrainer(SuspendableTrainer):
                     )
                     for k, v in host_batch.items()
                 }
-            # no fence handle: the accumulator is donated into the next
-            # eval call, so completion rides the t1 lower bound
-            with program_load_if(self._evaluated == 0, "lm_eval_step"), \
-                    self.ledger.launch(0, "lm_eval_step"):
+            with program_load_if(self._evaluated == 0, "lm_eval_step"):
                 acc = self.eval_step(
                     self.state,
                     shard_lm_batch(self.mesh, host_batch,

@@ -141,13 +141,6 @@ class TrainerConfig:
     flightrec: bool = True
     cost_cards: bool = False
     metrics_port: Optional[int] = None
-    # Host–device overlap profiling (round 15; telemetry/overlap.py,
-    # ANALYSIS.md "Host–device overlap"): the dispatch ledger records
-    # every train/eval step launch's host dispatch wall, bounds device
-    # completion with lagged fences (metrics outputs, k steps behind —
-    # never a sync on the hot path), and classifies inter-launch gaps
-    # into attributed bubbles as kind="overlap" JSONL.
-    overlap: bool = False
 
 
 class Trainer(SuspendableTrainer):
@@ -395,18 +388,13 @@ class Trainer(SuspendableTrainer):
             batch = mesh_lib.shard_batch(self.mesh, host_batch)
             # the run's first dispatch traces + compiles the step: split
             # its wall into compile (XLA backend / cache load) and trace
-            # (Python lowering) so a warm start's ledger shows the cache
+            # (Python lowering) so a warm start's goodput shows the cache
             # win; later recompiles are a guarded hazard, not steady state
             first = self._dispatched == 0
             with spans.tracer().step("train.step_dispatch", step), \
                     program_load_if(first, "train_step"), \
-                    attribute_compile(self.goodput if first else None), \
-                    self.ledger.launch(0, "train_step") as launch:
+                    attribute_compile(self.goodput if first else None):
                 self.state, metrics = self.train_step(self.state, batch)
-                # metrics are fresh (non-donated) outputs every step —
-                # the lagged fence blocks on them k steps later, the
-                # exact PR 4 ring idiom
-                launch.handle = metrics
             self._dispatched += 1
             self._post_step(metrics)
             steps_done += 1
@@ -469,10 +457,7 @@ class Trainer(SuspendableTrainer):
                     for k, v in host_batch.items()
                 }
             batch = mesh_lib.shard_batch(self.mesh, host_batch)
-            # no fence handle: the accumulator is donated into the next
-            # eval call, so completion rides the t1 lower bound
-            with program_load_if(self._evaluated == 0, "eval_step"), \
-                    self.ledger.launch(0, "eval_step"):
+            with program_load_if(self._evaluated == 0, "eval_step"):
                 metrics = self.eval_step(self.state, batch, metrics)
             self._evaluated += 1
         return jax.device_get(metrics).summary()

@@ -228,12 +228,6 @@ def _parse(argv=None) -> argparse.Namespace:
                    help="split replicas into prefill-only and decode "
                         "roles with KV-block handoff (needs --replicas "
                         ">= 2)")
-    p.add_argument("--async-host", action="store_true",
-                   help="serve through a FleetRouter even at one "
-                        "replica. Its loop (collect the last tick, "
-                        "dispatch the next, worker threads for JSONL/"
-                        "gate-metric host work) is every fleet path's, "
-                        "with or without this flag")
     p.add_argument("--prefill-replicas", type=int, default=1,
                    help="prefill replicas when --disaggregate")
     p.add_argument("--slo-ttft-ms", type=float, default=None,
@@ -264,8 +258,7 @@ def _parse(argv=None) -> argparse.Namespace:
                         "synthetic workload: POST /v1/generate streams "
                         "tokens, GET /v1/health is the health plane, "
                         "/metrics the Prometheus text; implies the "
-                        "fleet layout with the async host loop and "
-                        "streaming retention")
+                        "fleet layout and streaming retention")
     p.add_argument("--http-duration", type=float, default=10.0,
                    help="seconds to keep the front door up "
                         "(--http-port)")
@@ -355,7 +348,7 @@ def main() -> None:
     t0 = time.perf_counter()
     http_mode = args.http_port is not None
     fleet_mode = (args.replicas > 1 or args.disaggregate or args.trace
-                  or args.async_host or http_mode)
+                  or http_mode)
     if args.dense and (args.cost_cards or args.metrics_port is not None):
         raise SystemExit("--cost-cards/--metrics-port need the paged "
                          "layout (program registry + scheduler metrics); "

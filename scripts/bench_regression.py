@@ -54,23 +54,11 @@ REPO_DEFAULT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_BAND = 0.10
 #: key-pattern bands for known-noisy measurements (first match wins)
 BAND_OVERRIDES: Tuple[Tuple[str, float], ...] = (
-    # direction-aware fractions (round 15): bounded in [0, 1], so the
-    # wall-clock catch-all's 150% band below would make them
-    # unflaggable — a halved device-busy fraction IS the regression the
-    # async-refactor A/B exists to catch. Ordered first: first match
-    # wins.
-    (r"device_busy_frac", 0.5),
-    (r"gap_accounted_frac", 0.10),
     # prefix-cache keys (round 17): token accounting is deterministic
     # per trace but the ratio moves with trace mix; hit rate is bounded
-    # in [0, 1] like the busy fractions above
+    # in [0, 1]
     (r"serving_prefix_hit_rate", 0.25),
     (r"^serving_prefix_", 0.5),
-    # the wall-clock fleet bench (round 15) measures MACHINE wall on a
-    # shared box — the same weather class as the disk keys; its CPU
-    # magnitudes are additionally backend-marked as not-a-claim
-    # (PERF_NOTES §11)
-    (r"^serving_wallclock_", 1.5),
     # round-21 soak keys: the growth SLOPES are the claim (down is
     # good; direction overrides below), but their magnitudes ride the
     # same shared-box weather as the wall-clock bench — a slope near
@@ -114,13 +102,10 @@ def band_for(key: str, overrides: Dict[str, float]) -> float:
     return DEFAULT_BAND
 
 
-#: direction overrides checked BEFORE the skip list: fractions are
-#: normally configuration-like and skipped, but device-busy fraction is
-#: a direction-aware measurement (higher = less idle device) — the
-#: round-15 overlap keys the async-refactor A/B will move
+#: direction overrides checked BEFORE the skip list: rates and ratios
+#: are normally configuration-like and skipped, but these are
+#: direction-aware measurements
 DIRECTION_OVERRIDES: Tuple[Tuple[str, str], ...] = (
-    (r"device_busy_frac", "up"),
-    (r"gap_accounted_frac", "up"),
     # prefix-cache keys (round 17): hit rate and the off/on token ratio
     # regress DOWN (less sharing); admitted tokens and fresh blocks per
     # request regress UP (sharing doing less work per request is the

@@ -18,12 +18,6 @@ Usage: python scripts/bench_serving.py [--slots 32]
            --trace-max-new-median 12 --trace-prefill-heavy]
        python scripts/bench_serving.py --fleet [--trace T.jsonl]   # 1r vs 2r
        python scripts/bench_serving.py --disagg [--trace T.jsonl]  # colo vs PD
-       python scripts/bench_serving.py --wall-clock [--trace T.jsonl
-           --wc-replicas 2 --wc-slots 4 --wc-out overlap.jsonl
-           --wc-extra 4 --wc-reps 3]  # round 15; round 16 adds the
-                          # sync-vs-async A/B (serving_wallclock_async_*),
-                          # extra fleet-size points (--wc-extra N,M), and
-                          # median-of-reps quoting (--wc-reps)
        python scripts/bench_serving.py --pressure [--pressure-sessions 100000
            --pressure-blocks 13 --pressure-duration 90]  # preempt vs shed-only
        python scripts/bench_serving.py --soak [--soak-requests 100000
@@ -35,15 +29,6 @@ Usage: python scripts/bench_serving.py [--slots 32]
            # round 22 front door: real sockets against gateway.Gateway —
            # over-the-wire TTFT, SSE gap p95, 429 rate at the door, and
            # cancel-to-block-free latency (serving_http_*)
-
-Round 15 (overlap profiler): ``--wall-clock`` is the ROADMAP-item-3
-fleet bench — ONE trace served saturated (no nominal tick) by 1 replica
-vs N with the dispatch ledger armed, reporting aggregate tok/s both
-sides, per-replica device-busy fraction, and the bubble-cause histogram
-that must account for >=90% of the measured 1→N efficiency gap
-(``serving_wallclock_*``; backend-marked, CPU magnitudes not
-regression-gated). ``--wc-out`` keeps the run's span+overlap JSONL for
-``telemetry_report.py --require overlap`` / ``explain_request.py``.
 
 Round 13 (pressure tier): ``--pressure`` replays one over-committed
 bursty trace (default 100k session ids on a pool holding ~3 chains per
@@ -869,330 +854,6 @@ def measure_prefix(trace=None, slots: int = 8, prefix_len: int = 64,
 
 
 # ---------------------------------------------------------------------------
-# wall-clock fleet bench (round 15): the overlap profiler's headline —
-# the measurement contract ROADMAP item 3's async host refactor gates on
-# ---------------------------------------------------------------------------
-
-
-def _wallclock_side(cfg, params, trace, n_replicas, slots, out_path=None,
-                    async_host=False):
-    """One saturated wall-clock run: every arrival submitted up front
-    (tokenized under a ledger mark), then the fleet loop cranked
-    back-to-back until idle — no nominal tick. Unlike the step-domain
-    benches this measures MACHINE wall, which is exactly the point: the
-    one-loop router serializes replica host work, and the ledger's
-    per-replica device timeline attributes every second of it.
-
-    ``async_host=True`` (round 16) runs the dispatch-then-collect loop:
-    tokenization fans out over the router's ``HostWorkerPool`` (the
-    marks carry worker-thread names), replica ticks launch back-to-back
-    and collect lagged, per-request JSONL rides the workers."""
-    from pytorch_distributed_tpu.fleet import (
-        FleetRouter,
-        SLOConfig,
-        prompt_for,
-    )
-    from pytorch_distributed_tpu.telemetry import (
-        DispatchLedger,
-        ReqTracer,
-        busy_summary,
-        cause_histogram,
-        fleet_busy_summary,
-    )
-    from pytorch_distributed_tpu.utils.profiling import MetricsLogger
-
-    mlog = MetricsLogger(out_path)
-    reqtrace = ReqTracer(mlog)
-    ledger = DispatchLedger(mlog, seq_source=reqtrace)
-    router = FleetRouter(
-        cfg, params, n_replicas=n_replicas,
-        # saturation bench: spills balance load, sheds would change the
-        # served token count between the 1r and Nr sides
-        slo=SLOConfig(spill_queue_depth=4, shed_queue_depth=10**6),
-        metrics_log=mlog, reqtrace=reqtrace, ledger=ledger,
-        async_host=async_host,
-        n_slots=slots, block_len=16, prefill_chunk=32, admit_per_step=4,
-    )
-    router.warmup()  # the A/B compares serving, not compile stalls
-    ordered = sorted(trace, key=lambda r: (r.t, r.rid))
-    t0 = time.perf_counter()
-    if async_host:
-        # threaded tokenize: the per-request token-stream builds fan out
-        # over the worker pool (deterministic per request — order of
-        # COMPLETION is free), then submission happens in trace order so
-        # routing matches the synchronous side request-for-request
-        prompts = [None] * len(ordered)
-
-        def _tok(idx, r):
-            def work():
-                with ledger.host("tokenize/detokenize"):
-                    prompts[idx] = prompt_for(r, cfg.vocab_size)
-            return work
-
-        for idx, r in enumerate(ordered):
-            router.host_pool.submit(_tok(idx, r))
-        router.host_pool.flush()
-        for idx, r in enumerate(ordered):
-            router.submit(prompts[idx], r.max_new, session=r.session)
-    else:
-        for r in ordered:
-            with ledger.host("tokenize/detokenize"):
-                prompt = prompt_for(r, cfg.vocab_size)
-            router.submit(prompt, r.max_new, session=r.session)
-    while not router.idle:
-        router.step()
-    wall = time.perf_counter() - t0
-    router.log_summary()
-    ledger.finalize()
-    mlog.close()
-    m = router.metrics()
-    records = ledger.snapshot()
-    return {
-        "wall_s": wall,
-        "tokens": m["tokens_out"],
-        "tok_s": m["tokens_out"] / max(wall, 1e-9),
-        "shed": m["shed"],
-        "busy": busy_summary(records),
-        "union": fleet_busy_summary(records),
-        "causes": cause_histogram(records),
-    }
-
-
-def _async_gap_decomposition(side_async, side_1, n: int) -> dict:
-    """The async loop's efficiency-gap accounting (round 16). The sync
-    loop's gap was host serialization, and per-replica bubbles covered
-    it; under dispatch-then-collect the per-replica dispatch→completion
-    windows legitimately overlap on a shared device, so the honest
-    accounting decomposes the remaining gap into measured, attributable
-    parts (aggregate stream-seconds, gap = n × (wall − ideal)):
-
-    - ``idle``: the UNION-timeline device-idle seconds — true bubbles,
-      the only part more host-overlap engineering could still remove;
-    - ``overwork``: union busy beyond the 1-replica device seconds per
-      token — N half-empty replicas each run their own tick programs,
-      burning more device time per token than one full replica;
-    - ``shared_device``: the floor from N replicas sharing ONE device —
-      1r device busy per token × N exceeds the perfect-scaling wall by
-      construction whenever 1r busy fraction > 1/N. Vanishes on real
-      N-device hardware (the CPU-backend honesty term);
-    - ``edge``: host wall outside the ledger window (tokenize/submit
-      before the first dispatch, finalize after the last completion).
-
-    The four parts tile the gap algebraically; reporting them measured
-    keeps ``gap_accounted_frac`` an identity-check (≈1.0 up to clock
-    noise), with the SPLIT as the actionable number."""
-    wall = side_async["wall_s"]
-    window = side_async["union"]["window_s"]
-    union_busy = side_async["union"]["union_busy_s"]
-    tokens = side_async["tokens"]
-    rate1 = side_1["tok_s"]
-    busy_1r = sum(s["busy_s"] for s in side_1["busy"].values())
-    ideal_wall = tokens / max(n * rate1, 1e-9)
-    busy_per_tok_1r = busy_1r / max(side_1["tokens"], 1)
-    ideal_busy = tokens * busy_per_tok_1r
-    gap = n * max(wall - ideal_wall, 0.0)
-    idle = n * max(window - union_busy, 0.0)
-    overwork = n * (union_busy - ideal_busy)
-    shared = n * (ideal_busy - ideal_wall)
-    edge = n * max(wall - window, 0.0)
-    accounted = (
-        min(1.0, max(0.0, idle + overwork + shared + edge) / gap)
-        if gap > 1e-9 else 1.0
-    )
-    return {
-        "gap_s": round(gap, 3),
-        "gap_idle_s": round(idle, 3),
-        "gap_overwork_s": round(overwork, 3),
-        "gap_shared_device_s": round(shared, 3),
-        "gap_edge_s": round(edge, 3),
-        "gap_accounted_frac": round(accounted, 4),
-    }
-
-
-def _wallclock_median(cfg, params, trace, n_replicas, slots, reps,
-                      out_path=None, async_host=False):
-    """``reps`` independent serves of one side; returns the run whose
-    tok/s is the median, WHOLE (rate, busy, causes stay one consistent
-    run — a spliced median would mix timelines). The shared noisy box
-    moves single runs ±20-30%; the recorded rounds quote medians
-    (``--wc-reps``), the smokes stay single-run for speed."""
-    sides = [
-        _wallclock_side(cfg, params, trace, n_replicas, slots,
-                        out_path=(out_path if i == 0 else None),
-                        async_host=async_host)
-        for i in range(max(1, reps))
-    ]
-    sides.sort(key=lambda s: s["tok_s"])
-    return sides[len(sides) // 2]
-
-
-def measure_wallclock(trace=None, n_replicas: int = 2, slots: int = 4,
-                      out_path: str | None = None,
-                      extra_replicas=(), reps: int = 1) -> dict:
-    """The ROADMAP-item-3 wall-clock fleet bench: ONE trace served by 1
-    replica vs ``n_replicas``, as fast as the host can crank the loop.
-    Reports aggregate tok/s both sides, per-replica device-busy
-    fraction, and the bubble-cause histogram — which must account for
-    >=90% of the measured 1→N efficiency gap
-    (``serving_wallclock_gap_accounted_frac``; the gap in seconds is
-    ``N x (wallN - tokensN / (N x rate1))``, i.e. the extra aggregate
-    stream-seconds the N-replica run spent vs perfect scaling of the
-    1-replica rate).
-
-    Round 16 (the async host runtime): the bench is now a THREE-way —
-    the synchronous loop keys keep their r06 meanings (the legacy
-    baseline), and the ``serving_wallclock_async_*`` keys measure the
-    dispatch-then-collect loop on the same trace: tok/s, efficiency,
-    the decomposed gap accounting (``_async_gap_decomposition``), the
-    per-replica AND union busy fractions, the bubble-cause histogram
-    (worker-thread marks included), and the other-replica-tick share
-    the refactor exists to shrink. ``extra_replicas`` adds compact
-    sync-vs-async points at other fleet sizes
-    (``serving_wallclock_r{N}_*``). ``--wc-out`` keeps the ASYNC
-    N-replica run's JSONL — the surface ``ci_check.sh --async-smoke``
-    replays through report/explain.
-
-    HONESTY (``serving_wallclock_backend``): on CPU all replicas share
-    one device, so N replicas CANNOT beat one — the sync bench measures
-    pure host-loop serialization, and even a perfect async loop is
-    floored by the shared device (the ``gap_shared_device_s`` term).
-    Per-replica busy fractions under the async loop include time queued
-    behind the other replica (dispatch→completion windows overlap);
-    ``_union`` is true device utilization. Do not regression-gate CPU
-    magnitudes; the wall-clock keys carry a wide noise band in
-    ``bench_regression.py``."""
-    cfg, params = _tiny_model()
-    if trace is None:
-        trace = default_fleet_trace()
-    side_async = _wallclock_median(cfg, params, trace, n_replicas, slots,
-                                   reps, out_path=out_path,
-                                   async_host=True)
-    side_n = _wallclock_median(cfg, params, trace, n_replicas, slots,
-                               reps)
-    side_1 = _wallclock_median(cfg, params, trace, 1, slots, reps)
-    rate1 = side_1["tok_s"]
-    rate_n = side_n["tok_s"]
-    n = n_replicas
-    efficiency = rate_n / max(n * rate1, 1e-9)
-    # the efficiency gap in aggregate stream-seconds: how much longer
-    # the N run's N streams ran vs perfect scaling of the 1r rate
-    ideal_wall = side_n["tokens"] / max(n * rate1, 1e-9)
-    gap_s = n * max(side_n["wall_s"] - ideal_wall, 0.0)
-    bubble_s = sum(c["gap_s"] for c in side_n["causes"].values())
-    accounted = (
-        min(1.0, bubble_s / gap_s) if gap_s > 1e-9 else 1.0
-    )
-    out = {
-        "serving_wallclock_backend": jax.default_backend(),
-        "serving_wallclock_replicas": n,
-        "serving_wallclock_trace_requests": len(trace),
-        "serving_wallclock_slots_per_replica": slots,
-        "serving_wallclock_tokens": side_n["tokens"],
-        "serving_wallclock_wall_s_1r": round(side_1["wall_s"], 3),
-        "serving_wallclock_wall_s_nr": round(side_n["wall_s"], 3),
-        "serving_wallclock_tok_s_1r": round(rate1, 2),
-        "serving_wallclock_tok_s_nr": round(rate_n, 2),
-        "serving_wallclock_ratio_nr_over_1r": round(
-            rate_n / max(rate1, 1e-9), 3
-        ),
-        "serving_wallclock_efficiency_frac": round(efficiency, 4),
-        "serving_wallclock_gap_s": round(gap_s, 3),
-        "serving_wallclock_bubble_s_total": round(bubble_s, 3),
-        "serving_wallclock_bubble_over_gap": round(
-            bubble_s / gap_s, 3
-        ) if gap_s > 1e-9 else None,
-        "serving_wallclock_gap_accounted_frac": round(accounted, 4),
-        "device": str(jax.devices()[0]),
-    }
-    busies = []
-    for rep, s in sorted(side_n["busy"].items()):
-        out[f"serving_wallclock_device_busy_frac_r{rep}"] = s["busy_frac"]
-        busies.append(s["busy_frac"])
-    if busies:
-        out["serving_wallclock_device_busy_frac_mean"] = round(
-            sum(busies) / len(busies), 6
-        )
-    for rep, s in sorted(side_1["busy"].items()):
-        out["serving_wallclock_device_busy_frac_1r"] = s["busy_frac"]
-    for cause, h in sorted(side_n["causes"].items()):
-        key = cause.replace("/", "_").replace("-", "_")
-        out[f"serving_wallclock_bubble_{key}_s"] = round(h["gap_s"], 3)
-        out[f"serving_wallclock_bubble_{key}_count"] = h["count"]
-    # ---- the async host runtime side (round 16) ----
-    rate_a = side_async["tok_s"]
-    out["serving_wallclock_async_tokens"] = side_async["tokens"]
-    out["serving_wallclock_async_wall_s_nr"] = round(
-        side_async["wall_s"], 3
-    )
-    out["serving_wallclock_async_tok_s_nr"] = round(rate_a, 2)
-    out["serving_wallclock_async_efficiency_frac"] = round(
-        rate_a / max(n * rate1, 1e-9), 4
-    )
-    out["serving_wallclock_ratio_async_over_sync"] = round(
-        rate_a / max(rate_n, 1e-9), 3
-    )
-    for k, v in _async_gap_decomposition(side_async, side_1, n).items():
-        out[f"serving_wallclock_async_{k}"] = v
-    for rep, s in sorted(side_async["busy"].items()):
-        out[f"serving_wallclock_async_device_busy_frac_r{rep}"] = (
-            s["busy_frac"]
-        )
-    out["serving_wallclock_async_device_busy_frac_union"] = (
-        side_async["union"]["union_busy_frac"]
-    )
-    total_bubble_a = sum(
-        c["gap_s"] for c in side_async["causes"].values()
-    )
-    other_a = side_async["causes"].get(
-        "other-replica-tick", {"gap_s": 0.0}
-    )["gap_s"]
-    out["serving_wallclock_async_bubble_s_total"] = round(
-        total_bubble_a, 3
-    )
-    # the acceptance headline: the sync loop attributed 96% of its
-    # bubbles to other-replica-tick; the async loop must make it a
-    # minority cause
-    out["serving_wallclock_async_other_replica_share"] = round(
-        other_a / total_bubble_a, 4
-    ) if total_bubble_a > 1e-9 else 0.0
-    for cause, h in sorted(side_async["causes"].items()):
-        key = (cause.replace("/", "_").replace("-", "_")
-               .replace("@", "_at_"))
-        out[f"serving_wallclock_async_bubble_{key}_s"] = round(
-            h["gap_s"], 3
-        )
-        out[f"serving_wallclock_async_bubble_{key}_count"] = h["count"]
-    # compact sync-vs-async points at other fleet sizes (the r07
-    # --wc-extra 4 point): efficiency uses the SAME 1-replica sync rate
-    for m in extra_replicas:
-        sa = _wallclock_median(cfg, params, trace, m, slots, reps,
-                               async_host=True)
-        ss = _wallclock_median(cfg, params, trace, m, slots, reps)
-        p = f"serving_wallclock_r{m}"
-        out[f"{p}_tok_s_sync"] = round(ss["tok_s"], 2)
-        out[f"{p}_tok_s_async"] = round(sa["tok_s"], 2)
-        out[f"{p}_efficiency_sync_frac"] = round(
-            ss["tok_s"] / max(m * rate1, 1e-9), 4
-        )
-        out[f"{p}_efficiency_async_frac"] = round(
-            sa["tok_s"] / max(m * rate1, 1e-9), 4
-        )
-        out[f"{p}_ratio_async_over_sync"] = round(
-            sa["tok_s"] / max(ss["tok_s"], 1e-9), 3
-        )
-        out[f"{p}_device_busy_frac_union_async"] = (
-            sa["union"]["union_busy_frac"]
-        )
-        t_b = sum(c["gap_s"] for c in sa["causes"].values())
-        o_b = sa["causes"].get("other-replica-tick",
-                               {"gap_s": 0.0})["gap_s"]
-        out[f"{p}_async_other_replica_share"] = round(
-            o_b / t_b, 4
-        ) if t_b > 1e-9 else 0.0
-    return out
-
-
-# ---------------------------------------------------------------------------
 # scale observatory soak (round 21): the ROADMAP-item-5 100k-session run
 # ---------------------------------------------------------------------------
 
@@ -1224,8 +885,8 @@ def measure_soak(requests: int = 100_000, out_path: str | None = None,
     wall slope is a smoke alarm (neighbors steal the core; the MAD
     floor absorbs it), while the RSS slope and the census verdict are
     real host-memory claims on any backend — see ANALYSIS.md "Scale
-    observatory". Profiling that is O(launches) stays OFF (no dispatch
-    ledger, no reqtrace): per-tick wall comes from the monitor.
+    observatory". Profiling that is O(launches) stays OFF (no
+    reqtrace): per-tick wall comes from the monitor.
     """
     import tempfile
 
@@ -1613,19 +1274,6 @@ def main() -> None:
         return
     if "--disagg" in sys.argv:
         print(json.dumps({**measure_disagg(trace=_cli_trace()), **probe}))
-        return
-    if "--wall-clock" in sys.argv:
-        extra = _argval("--wc-extra", "", str)
-        print(json.dumps({**measure_wallclock(
-            trace=_cli_trace(),
-            n_replicas=_argval("--wc-replicas", 2, int),
-            slots=_argval("--wc-slots", 4, int),
-            out_path=_argval("--wc-out", None, str),
-            extra_replicas=tuple(
-                int(x) for x in extra.split(",") if x.strip()
-            ),
-            reps=_argval("--wc-reps", 1, int),
-        ), **probe}))
         return
     if "--prefix" in sys.argv:
         print(json.dumps({**measure_prefix(

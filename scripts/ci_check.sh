@@ -10,7 +10,6 @@
 #                             --telemetry-smoke|--warmup-smoke|--reshard-smoke|
 #                             --fleet-smoke|--obs-smoke|--kernel-smoke|
 #                             --pressure-smoke|--trace-smoke|
-#                             --overlap-smoke|--async-smoke|
 #                             --prefix-smoke|--blocksan-smoke|
 #                             --chaos-smoke|
 #                             --soak-smoke|--gateway-smoke|
@@ -83,27 +82,6 @@
 # AND a handed-off rid (found by predicate, not hard-coded), a
 # Perfetto-loadable Chrome trace must parse, and telemetry_report.py
 # must render the request-trace section (--require spans) (~20 s).
-#
-# --overlap-smoke: lint, then the round-15 host–device overlap cycle:
-# a short seeded trace through the wall-clock fleet driver
-# (bench_serving.py --wall-clock: 2-replica vs 1-replica saturated
-# throughput with the dispatch ledger armed) must report per-replica
-# device-busy fractions and a bubble-cause histogram accounting for
-# >=90% of the measured 1→2 efficiency gap; telemetry_report.py must
-# render the overlap section (--require overlap) from the kept JSONL;
-# and explain_request.py must show a decode window's device-busy vs
-# bubble split on a complete trace (~30 s).
-#
-# --async-smoke: lint, then the round-16 async host runtime cycle:
-# a short seeded trace through bench_serving.py --wall-clock (which now
-# A/Bs the synchronous loop against the dispatch-then-collect loop on
-# the same trace) must report the async side's decomposed gap
-# accounting >=90% with the other-replica-tick share of the apportioned
-# bubble histogram below 0.6 (the sync one-loop baseline attributed
-# ~all bubble seconds to it); then explain_request.py
-# --assert-complete must close a span tree from the ASYNC run's JSONL
-# (worker-thread emission must not tear traces) and telemetry_report.py
-# must render both the overlap and spans sections from it (~40 s).
 #
 # --prefix-smoke: lint, then the round-17 prefix-sharing cycle: one
 # short seeded shared-system-prompt trace through the 2-replica
@@ -312,80 +290,6 @@ print(f"perfetto trace: {len(events)} events OK")
 PY
     JAX_PLATFORMS=cpu python scripts/telemetry_report.py \
         "$smoke/spans.jsonl" --json --require spans
-    exit 0
-fi
-
-if [[ "${1:-}" == "--overlap-smoke" ]]; then
-    echo "== overlap smoke (wall-clock 1r-vs-2r -> bubbles account the gap) =="
-    smoke=$(mktemp -d)
-    trap 'rm -rf "$smoke"' EXIT
-    JAX_PLATFORMS=cpu python scripts/bench_serving.py \
-        --gen-trace "$smoke/trace.jsonl" --trace-duration 30 \
-        --trace-base-rate 0.5 --trace-prompt-max 88
-    JAX_PLATFORMS=cpu python scripts/bench_serving.py --wall-clock \
-        --trace "$smoke/trace.jsonl" --wc-out "$smoke/overlap.jsonl" \
-        > "$smoke/wallclock.json"
-    python - "$smoke/wallclock.json" <<'PY'
-import json, sys
-row = json.loads(open(sys.argv[1]).read().strip().splitlines()[-1])
-assert row["serving_wallclock_tok_s_1r"] > 0, row
-assert row["serving_wallclock_tok_s_nr"] > 0, row
-assert "serving_wallclock_device_busy_frac_r0" in row, sorted(row)
-assert "serving_wallclock_device_busy_frac_r1" in row, sorted(row)
-acc = row["serving_wallclock_gap_accounted_frac"]
-assert acc >= 0.9, f"bubbles account for only {acc:.0%} of the gap"
-causes = [k for k in row if k.startswith("serving_wallclock_bubble_")
-          and k.endswith("_s")]
-assert causes, "no bubble-cause histogram keys"
-print(f"wall-clock: {row['serving_wallclock_tok_s_1r']} tok/s 1r vs "
-      f"{row['serving_wallclock_tok_s_nr']} tok/s 2r "
-      f"(backend={row['serving_wallclock_backend']}), "
-      f"gap accounted {acc:.0%}, causes={len(causes)}")
-PY
-    JAX_PLATFORMS=cpu python scripts/telemetry_report.py \
-        "$smoke/overlap.jsonl" --json --require overlap
-    JAX_PLATFORMS=cpu python scripts/explain_request.py \
-        "$smoke/overlap.jsonl" --find any --assert-complete \
-        | tee "$smoke/explain.txt"
-    grep -q "busy /" "$smoke/explain.txt" \
-        || { echo "explain output missing the device busy/bubble split"; exit 1; }
-    exit 0
-fi
-
-if [[ "${1:-}" == "--async-smoke" ]]; then
-    echo "== async smoke (sync-vs-async wall-clock A/B -> honest histogram -> traces) =="
-    smoke=$(mktemp -d)
-    trap 'rm -rf "$smoke"' EXIT
-    JAX_PLATFORMS=cpu python scripts/bench_serving.py \
-        --gen-trace "$smoke/trace.jsonl" --trace-duration 30 \
-        --trace-base-rate 0.5 --trace-prompt-max 88
-    JAX_PLATFORMS=cpu python scripts/bench_serving.py --wall-clock \
-        --trace "$smoke/trace.jsonl" --wc-out "$smoke/async.jsonl" \
-        > "$smoke/wallclock.json"
-    python - "$smoke/wallclock.json" <<'PY'
-import json, sys
-row = json.loads(open(sys.argv[1]).read().strip().splitlines()[-1])
-assert row["serving_wallclock_async_tok_s_nr"] > 0, row
-acc = row["serving_wallclock_async_gap_accounted_frac"]
-assert acc >= 0.9, f"async gap accounted only {acc:.0%}"
-share = row["serving_wallclock_async_other_replica_share"]
-# the sync one-loop attributed ~all bubble seconds to the other
-# replica's host work; the async loop's apportioned histogram must
-# keep it below this threshold (at 2 replicas the irreducible
-# shared-loop floor is ~half of the remaining host-bound bubbles)
-assert share < 0.6, f"other-replica-tick still {share:.0%} of bubbles"
-assert "serving_wallclock_async_device_busy_frac_union" in row, sorted(row)
-print(f"async smoke: sync {row['serving_wallclock_tok_s_nr']} tok/s vs "
-      f"async {row['serving_wallclock_async_tok_s_nr']} tok/s "
-      f"(ratio {row['serving_wallclock_ratio_async_over_sync']}), "
-      f"other-replica share {share:.0%}, gap accounted {acc:.0%}, "
-      f"backend={row['serving_wallclock_backend']}")
-PY
-    JAX_PLATFORMS=cpu python scripts/explain_request.py \
-        "$smoke/async.jsonl" --find any --assert-complete > /dev/null
-    JAX_PLATFORMS=cpu python scripts/telemetry_report.py \
-        "$smoke/async.jsonl" --json --require overlap,spans > /dev/null
-    echo "async smoke OK"
     exit 0
 fi
 

@@ -7,12 +7,7 @@ a tree — where it waited, which replica served each phase, whether it
 was handed off prefill→decode, whether it was preempted and why the
 decision chose swap over recompute (predicted vs measured wall), and
 each phase's wall next to the measured per-program cost cards
-(``kind="program_cost"``, PR 8) where one applies. When the run also
-carried the round-15 dispatch ledger (``kind="overlap"``), every decode
-window is annotated with its device-busy vs bubble split — a SLOW
-request (busy-dominated windows) reads differently from a STARVED one
-(bubble-dominated: the device sat idle while its replica waited on the
-host loop):
+(``kind="program_cost"``, PR 8) where one applies:
 
     python scripts/explain_request.py serve.jsonl --rid 17
     python scripts/explain_request.py serve.jsonl --find preempted
@@ -146,8 +141,7 @@ def _fmt_ms(seconds) -> str:
 
 
 def render_node(node: SpanNode, t_root: float, costs: dict,
-                lines: List[str], depth: int = 0,
-                device_splits: Optional[dict] = None) -> None:
+                lines: List[str], depth: int = 0) -> None:
     pad = "  " * depth
     rep = node.record.get("replica")
     where = f" [r{rep}]" if rep is not None else ""
@@ -167,22 +161,12 @@ def render_node(node: SpanNode, t_root: float, costs: dict,
         if prog and prog in costs and costs[prog].get("mean_s"):
             card = costs[prog]
             cost = f"  [card: {_fmt_ms(card['mean_s'])}/call]"
-        split = ""
-        span_id = node.record.get("span")
-        if device_splits and span_id in device_splits:
-            # the round-15 overlap join: this window's wall split into
-            # device-busy vs bubble — a slow request (busy-dominated)
-            # reads differently from a starved one (bubble-dominated)
-            busy, bubble = device_splits[span_id]
-            split = (f"  [device {_fmt_ms(busy)} busy / "
-                     f"{_fmt_ms(bubble)} bubble]")
         lines.append(
             f"{pad}- {node.name}{where} +{_fmt_ms(node.t0 - t_root)}"
-            f"{dur}" + (f"  {detail}" if detail else "") + cost + split
+            f"{dur}" + (f"  {detail}" if detail else "") + cost
         )
     for child in node.children:
-        render_node(child, t_root, costs, lines, depth + 1,
-                    device_splits)
+        render_node(child, t_root, costs, lines, depth + 1)
 
 
 def phase_walls(root: SpanNode) -> dict:
@@ -200,35 +184,6 @@ def phase_walls(root: SpanNode) -> dict:
     return acc
 
 
-def _device_splits(records: List[dict], rid: int) -> dict:
-    """``{span_id: (busy_s, bubble_s)}`` for the rid's decode windows
-    (round-15 overlap join): each window's wall intersected with its
-    replica's device timeline. Empty when the run carried no
-    ``kind="overlap"`` records — the annotation degrades away."""
-    from pytorch_distributed_tpu.telemetry.overlap import (
-        busy_within,
-        overlap_records,
-    )
-
-    if not overlap_records(records, "launch"):
-        return {}
-    recs = span_records(records, rid)
-    ends = {r["span"]: r for r in recs if r.get("ev") == "end"}
-    splits = {}
-    for r in recs:
-        if r.get("ev") != "begin" or r.get("name") != "decode":
-            continue
-        end = ends.get(r["span"])
-        if end is None:
-            continue
-        busy, bubble = busy_within(
-            records, r.get("replica", 0), r.get("t", 0.0),
-            end.get("t", 0.0),
-        )
-        splits[r["span"]] = (busy, bubble)
-    return splits
-
-
 def explain(records: List[dict], rid: int, out=None) -> int:
     """Render rid's causal story; returns 0, or 2 when the trace is
     missing entirely. ``out`` defaults to the CURRENT sys.stdout (late
@@ -244,7 +199,6 @@ def explain(records: List[dict], rid: int, out=None) -> int:
     errors = validate_trace(records, rid)
     root = build_tree(records, rid)
     costs = _program_costs(records)
-    device_splits = _device_splits(records, rid)
     lines = [
         f"== request {rid} =="
         + (f"  [{len(errors)} completeness issue(s)]" if errors else
@@ -256,25 +210,13 @@ def explain(records: List[dict], rid: int, out=None) -> int:
         for r in recs:
             lines.append(f"  {r}")
     else:
-        render_node(root, root.t0, costs, lines,
-                    device_splits=device_splits)
+        render_node(root, root.t0, costs, lines)
         walls = phase_walls(root)
         if walls:
             lines.append("per-phase wall: " + ", ".join(
                 f"{name} {_fmt_ms(s)}" for name, s in
                 sorted(walls.items(), key=lambda kv: -kv[1])
             ))
-        if device_splits:
-            busy = sum(b for b, _ in device_splits.values())
-            bubble = sum(g for _, g in device_splits.values())
-            total = busy + bubble
-            lines.append(
-                f"decode device split: {_fmt_ms(busy)} busy / "
-                f"{_fmt_ms(bubble)} bubble"
-                + (f" ({busy / total:.0%} busy)" if total > 0 else "")
-                + " — a starved request is bubble-dominated, a slow "
-                "one busy-dominated"
-            )
         # the preempt audit: predicted vs measured, per sub-tree
         def preempts(n):
             if n.name == "preempt" and not n.is_event:

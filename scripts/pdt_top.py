@@ -126,12 +126,6 @@ class View:
         # newest census sweep's verdict
         self.resources: List[dict] = []
         self.census_violations = 0
-        # host–device overlap (round 15; kind="overlap"): newest summary
-        # per replica plus a rolling tail of bubbles — busy % and the
-        # top recent bubble cause per replica
-        self.overlap_summary: Dict[int, dict] = {}
-        self.overlap_launches = 0
-        self.recent_bubbles: List[dict] = []
         # HTTP front door (round 22; kind="http" per-connection
         # records): lifetime counters plus the newest record's live
         # open/queued gauges and the worst inter-token stream gap
@@ -188,16 +182,6 @@ class View:
                     self.resources.pop(0)
             elif kind == "census":
                 self.census_violations += r.get("violations", 0)
-            elif kind == "overlap":
-                ev = r.get("ev")
-                if ev == "launch":
-                    self.overlap_launches += 1
-                elif ev == "summary":
-                    self.overlap_summary[r.get("replica", 0)] = r
-                elif ev == "bubble":
-                    self.recent_bubbles.append(r)
-                    if len(self.recent_bubbles) > self.window:
-                        self.recent_bubbles.pop(0)
             elif kind == "http":
                 self.http_conns += 1
                 status = r.get("status", 0)
@@ -220,20 +204,6 @@ class View:
                 elif r.get("ev") == "end":
                     self.open_spans.discard(key)
                     self.open_roots.discard(key)
-
-    def _top_cause(self, replica: int) -> str:
-        """The dominant bubble cause (by gap seconds) in the recent
-        window for one replica — the live "what is this replica waiting
-        on" cell."""
-        by_cause: Dict[str, float] = {}
-        for b in self.recent_bubbles:
-            if b.get("replica") != replica:
-                continue
-            c = b.get("cause", "?")
-            by_cause[c] = by_cause.get(c, 0.0) + b.get("gap_s", 0.0)
-        if not by_cause:
-            return ""
-        return max(by_cause.items(), key=lambda kv: kv[1])[0]
 
     # ---- rendering -------------------------------------------------------
 
@@ -329,27 +299,6 @@ class View:
                 f"covered {self.prefix_covered}/{self.prefix_prompt} tok "
                 f"({self.prefix_covered / max(self.prefix_prompt, 1):.0%})"
                 + (f"  cow={self.prefix_cows}" if self.prefix_cows else "")
-            )
-        if self.overlap_summary or self.overlap_launches:
-            cells = []
-            for rep, s in sorted(self.overlap_summary.items()):
-                if rep == -1:
-                    # the round-16 union summary: true device
-                    # utilization when replicas share a device —
-                    # per-replica fractions overlap and must not be
-                    # summed (shared-device honesty)
-                    cells.append(
-                        f"union busy {s.get('busy_frac', 0.0):.0%}"
-                    )
-                    continue
-                top = self._top_cause(rep)
-                cells.append(
-                    f"r{rep} busy {s.get('busy_frac', 0.0):.0%}"
-                    + (f" ({top})" if top else "")
-                )
-            out.append(
-                f"overlap  {self.overlap_launches} launches  "
-                + "  ".join(cells)
             )
         if self.resources:
             # live host-resource row (round 21): newest RSS + the slope

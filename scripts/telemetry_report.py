@@ -35,11 +35,6 @@ produces, from the JSONL alone:
   rate, covered-prefix fraction, shared-blocks-per-hit percentiles,
   COW copies and admission-path evictions, from ``kind="prefix"``
   per-admission records plus the fleet rollup;
-- the **overlap section** (round 15; ``telemetry/overlap.py``) —
-  per-replica device-busy fraction, the bubble-cause histogram
-  (other-replica-tick / tokenize / admission / JSONL / handoff / swap /
-  idle), and dispatch-to-completion p50/p95 per program, from
-  ``kind="overlap"`` dispatch-ledger records;
 - the **host-resource section** (round 21; ``telemetry/hostprof.py``)
   — RSS and per-tick host-wall growth fits against cumulative sessions
   (slopes per 10k, flat/linear/superlinear verdicts), gc population and
@@ -493,79 +488,6 @@ def prefix_section(records: List[dict], out: dict) -> List[str]:
     return lines
 
 
-def overlap_section(records: List[dict], out: dict) -> List[str]:
-    """Host–device overlap (round 15; ``telemetry/overlap.py``):
-    per-replica device-busy fraction, the bubble-cause histogram, and
-    dispatch-to-completion p50/p95 per program, from ``kind="overlap"``
-    records (``scripts/bench_serving.py --wall-clock`` produces them;
-    any ledger-armed run does)."""
-    from pytorch_distributed_tpu.telemetry.overlap import (
-        busy_summary,
-        cause_histogram,
-        fleet_busy_summary,
-        overlap_records,
-    )
-
-    launches = overlap_records(records, "launch")
-    if not launches:
-        return []
-    lines = ["== overlap & bubbles =="]
-    summary = busy_summary(records)
-    lines.append(_fmt_row("replica", "launches", "busy", "window",
-                          "busy_frac"))
-    for rep, s in sorted(summary.items()):
-        lines.append(_fmt_row(
-            f"r{rep}", s["launches"], f"{s['busy_s'] * 1e3:.1f}ms",
-            f"{s.get('window_s', s['span_s']) * 1e3:.1f}ms",
-            f"{s['busy_frac']:.3f}",
-        ))
-        out[f"overlap_busy_frac_r{rep}"] = s["busy_frac"]
-    if len(summary) > 1:
-        # shared-device honesty (round 16): per-replica busy windows
-        # overlap on a shared device; the interval union is true device
-        # utilization and must be reported next to them
-        fb = fleet_busy_summary(records)
-        lines.append(_fmt_row(
-            "union", "-", f"{fb['union_busy_s'] * 1e3:.1f}ms",
-            f"{fb['window_s'] * 1e3:.1f}ms",
-            f"{fb['union_busy_frac']:.3f}",
-        ))
-        out["overlap_busy_frac_union"] = fb["union_busy_frac"]
-    hist = cause_histogram(records)
-    total = sum(h["gap_s"] for h in hist.values())
-    if hist:
-        lines.append("  bubbles: " + ", ".join(
-            f"{cause}={h['gap_s'] * 1e3:.1f}ms({h['count']})"
-            for cause, h in sorted(hist.items(),
-                                   key=lambda kv: -kv[1]["gap_s"])
-        ))
-    out["overlap_replicas"] = len(summary)
-    out["overlap_launches"] = len(launches)
-    out["overlap_bubble_s_total"] = round(total, 6)
-    for cause, h in hist.items():
-        key = cause.replace("/", "_").replace("-", "_")
-        out[f"overlap_bubble_{key}_s"] = round(h["gap_s"], 6)
-    # dispatch-to-completion per program: exact for sync/blocking-fenced
-    # launches ("done"), the dispatch-return lower bound otherwise
-    by_prog: dict = {}
-    for r in launches:
-        end = r.get("done", r.get("t1", 0.0))
-        by_prog.setdefault(r.get("program", "?"), []).append(
-            end - r.get("t0", 0.0)
-        )
-    lines.append(_fmt_row("program", "launches", "d2c p50", "d2c p95"))
-    for prog, vals in sorted(by_prog.items(),
-                             key=lambda kv: -sum(kv[1]))[:10]:
-        ps = percentiles(vals, qs=(50, 95))
-        lines.append(_fmt_row(
-            prog[:20], len(vals),
-            f"{ps['p50'] * 1e3:.3f}ms", f"{ps['p95'] * 1e3:.3f}ms",
-        ))
-        out[f"overlap_d2c_p95_ms_{prog}"] = round(ps["p95"] * 1e3, 4)
-    out["overlap_programs"] = len(by_prog)
-    return lines
-
-
 def span_section(records: List[dict], out: dict) -> List[str]:
     """Request-lifecycle traces (round 14; ``kind="span"`` from
     ``telemetry.reqtrace``): trace count, completeness, open (in-flight
@@ -807,12 +729,12 @@ def main(argv=None) -> int:
     p.add_argument("--require", default=None,
                    help="comma list of sections that MUST be present "
                         "(goodput, serving, warmup, fleet, pressure, "
-                        "prefix, overlap, spans, cost, resource, "
+                        "prefix, spans, cost, resource, "
                         "census, http, anomaly) — exit non-zero "
                         "otherwise; the ci_check.sh --telemetry-smoke, "
                         "--warmup-smoke, --fleet-smoke, --obs-smoke, "
                         "--pressure-smoke, --trace-smoke, "
-                        "--overlap-smoke, --prefix-smoke, --soak-smoke "
+                        "--prefix-smoke, --soak-smoke "
                         "and --gateway-smoke gates")
     args = p.parse_args(argv)
 
@@ -826,7 +748,6 @@ def main(argv=None) -> int:
     lines += fleet_section(records, out)
     lines += pressure_section(records, out)
     lines += prefix_section(records, out)
-    lines += overlap_section(records, out)
     lines += span_section(records, out)
     lines += cost_section(records, out)
     lines += resource_section(records, out)
@@ -844,7 +765,6 @@ def main(argv=None) -> int:
         "fleet": "fleet_replicas" in out,
         "pressure": out.get("pressure_preempts", 0) > 0,
         "prefix": out.get("prefix_admissions", 0) > 0,
-        "overlap": out.get("overlap_launches", 0) > 0,
         "spans": out.get("span_traces", 0) > 0,
         "cost": out.get("cost_programs", 0) > 0,
         "resource": out.get("resource_samples", 0) > 0,

@@ -1,11 +1,10 @@
-"""Async fleet host runtime (round 16 tentpole): dispatch-then-collect
-token identity vs the synchronous loop (plain, disaggregated, and
+"""The fleet's host loop (round 16): the router's dispatch-then-collect
+token identity with a lone ``Scheduler`` (plain, disaggregated, and
 pressure fleets), lagged-collect ordering, the early-collect protocol on
-preempt/drain, the worker pool's barrier semantics, worker-thread host
-marks in the bubble classifier, the ledger's collect-site completion,
-the union busy rollup, the no-hot-sync + no_recompile guards with the
-async loop armed, a SIGKILL-mid-swap async-loop kill-matrix cell, and a
-rules_threads-clean gate on every module the refactor touched."""
+preempt/drain, the worker pool's barrier semantics, the no_recompile
+guard under the lagged loop, a SIGKILL-mid-swap kill-matrix cell, and a
+rules_threads-clean gate on every module with threads or thread-shared
+state."""
 
 import json
 import os
@@ -34,13 +33,7 @@ from pytorch_distributed_tpu.models.transformer import (
 from pytorch_distributed_tpu.resilience import faults
 from pytorch_distributed_tpu.resilience.faults import FaultPlan, FaultSpec
 from pytorch_distributed_tpu.serving import HostWorkerPool, Scheduler
-from pytorch_distributed_tpu.telemetry import (
-    DispatchLedger,
-    ReqTracer,
-    classify_bubbles,
-    fleet_busy_summary,
-    validate_stream,
-)
+from pytorch_distributed_tpu.telemetry import ReqTracer, validate_stream
 from pytorch_distributed_tpu.utils.profiling import MetricsLogger
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -64,18 +57,29 @@ def _prompts(cfg, lens=(5, 16, 23, 31, 9, 17), seed=0):
             for l in lens]
 
 
-def _fleet(cfg, params, async_host, **extra):
+def _fleet(cfg, params, **extra):
     kw = dict(SCHED_KW)
     kw.update(extra.pop("sched_kw", {}))
     return FleetRouter(
-        cfg, params, n_replicas=2, async_host=async_host,
+        cfg, params, n_replicas=2,
         slo=SLOConfig(spill_queue_depth=2, shed_queue_depth=10**6),
         **extra, **kw,
     )
 
 
+def _lone_streams(cfg, params, prompts, max_new):
+    """The step-domain reference: one ``Scheduler`` with an ample pool,
+    each ``step()`` a tick launched and collected in the same call.
+    Greedy streams do not depend on the schedule, so a fleet of any shape
+    must serve these, rid for rid in submit order."""
+    lone = Scheduler(cfg, params, **SCHED_KW)
+    for p in prompts:
+        lone.submit(p, max_new)
+    return lone.drain()
+
+
 # ---------------------------------------------------------------------------
-# token identity: async vs sync, across fleet modes
+# token identity: the router vs a lone scheduler, across fleet modes
 # ---------------------------------------------------------------------------
 
 
@@ -85,10 +89,11 @@ def _fleet(cfg, params, async_host, **extra):
     pytest.param("pressure", marks=pytest.mark.slow),
 ])
 def test_async_sync_token_identity(model, mode):
-    """Bit-identical greedy token streams between the synchronous loop
-    and dispatch-then-collect, on the plain fleet, the disaggregated
-    prefill/decode fleet, and the over-committed pressure fleet (where
-    preempt/restore fires under the async loop too)."""
+    """Bit-identical greedy token streams between a lone scheduler's
+    ``step()`` and the router's dispatch-then-collect, on the plain
+    fleet, the disaggregated prefill/decode fleet, and the
+    over-committed pressure fleet (where preempt/restore fires under a
+    tick in flight)."""
     cfg, params = model
     extra = {}
     if mode == "disagg":
@@ -98,23 +103,20 @@ def test_async_sync_token_identity(model, mode):
         extra = dict(offload=True, preempt_on_oom=True,
                      swap_policy="swap", protect_ticks=0,
                      sched_kw=dict(n_blocks=10))
-    results = {}
-    for async_host in (False, True):
-        r = _fleet(cfg, params, async_host, **extra)
-        for i, p in enumerate(_prompts(cfg)):
-            r.submit(p, 5, session=i % 3)
-        results[async_host] = (r.drain(), r)
-    sync_out, _ = results[False]
-    async_out, ra = results[True]
-    assert set(sync_out) == set(async_out)
-    for rid in sync_out:
-        assert sync_out[rid] == async_out[rid], f"stream {rid} diverged"
+    want = _lone_streams(cfg, params, _prompts(cfg), 5)
+    ra = _fleet(cfg, params, **extra)
+    for i, p in enumerate(_prompts(cfg)):
+        ra.submit(p, 5, session=i % 3)
+    got = ra.drain()
+    assert set(want) == set(got)
+    for rid in want:
+        assert want[rid] == got[rid], f"stream {rid} diverged"
     assert not ra.rejected
     if mode == "pressure":
         assert ra.metrics()["preempts"] >= 1
         assert ra.metrics()["restores"] >= 1
     if mode == "disagg":
-        assert ra.metrics()["handoffs"] == len(sync_out)
+        assert ra.metrics()["handoffs"] == len(want)
     # every pool block freed, worker pool drained
     for s in ra.replicas:
         assert s.engine.allocator.in_use == 0
@@ -124,8 +126,8 @@ def test_async_sync_token_identity(model, mode):
 @pytest.mark.slow
 def test_async_identity_on_bursty_trace(model):
     """The smoke-trace identity gate: a seeded bursty trace replayed
-    through both loops at the same per-tick load — same served rid set,
-    same token values."""
+    through the router serves, rid for rid, what a lone scheduler
+    serves of the same requests in the same order."""
     from pytorch_distributed_tpu.fleet import (
         clamp_trace,
         generate_trace,
@@ -139,18 +141,17 @@ def test_async_identity_on_bursty_trace(model):
                        sessions=8, prompt_max=48, max_new_max=8),
         cfg.max_seq_len, SCHED_KW["prefill_chunk"],
     )
-    outs = {}
-    for async_host in (False, True):
-        r = _fleet(cfg, params, async_host)
-        replay_trace(
-            trace,
-            lambda req: r.submit(prompt_for(req, cfg.vocab_size),
-                                 req.max_new, session=req.session),
-            r.step,
-            lambda: r.idle,
-        )
-        outs[async_host] = dict(r.results)
-    assert outs[False] == outs[True]
+    r = _fleet(cfg, params)
+    lone = Scheduler(cfg, params, **SCHED_KW)
+
+    def submit(req):
+        prompt = prompt_for(req, cfg.vocab_size)
+        lone.submit(prompt, req.max_new)
+        return r.submit(prompt, req.max_new, session=req.session)
+
+    replay_trace(trace, submit, r.step, lambda: r.idle)
+    assert not r.rejected
+    assert dict(r.results) == lone.drain()
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +165,7 @@ def test_lagged_collect_one_tick_behind(model):
     pending, uncollected ``TickHandle`` exists between steps, and the
     per-rid stream order is preserved."""
     cfg, params = model
-    r = _fleet(cfg, params, True)
+    r = _fleet(cfg, params)
     rid = r.submit(np.arange(1, 10, dtype=np.int32), 3)
     first_out = r.step()
     # step 1 dispatched tick 1 (admission + first chunk); nothing was
@@ -180,10 +181,9 @@ def test_lagged_collect_one_tick_behind(model):
             break
     assert pending_seen > 0, "no tick was ever left in flight"
     assert r.results[rid] == seen[:len(r.results[rid])]
-    # sync reference: same values
-    ref = _fleet(cfg, params, False)
-    ref.submit(np.arange(1, 10, dtype=np.int32), 3)
-    assert ref.drain()[0] == r.results[rid]
+    # the lone scheduler's stream: same values
+    assert _lone_streams(cfg, params, [np.arange(1, 10, dtype=np.int32)],
+                         3)[0] == r.results[rid]
 
 
 def test_early_collect_on_preempt_and_drain(model):
@@ -191,7 +191,7 @@ def test_early_collect_on_preempt_and_drain(model):
     mid-flight loses no tokens (they stash and deliver at the next
     collect), and begin_drain starts from settled state."""
     cfg, params = model
-    r = _fleet(cfg, params, True, offload=True, preempt_on_oom=True,
+    r = _fleet(cfg, params, offload=True, preempt_on_oom=True,
                swap_policy="recompute", protect_ticks=0)
     rids = [r.submit(p, 4) for p in _prompts(cfg, lens=(9, 12, 7))]
     for _ in range(4):
@@ -203,14 +203,11 @@ def test_early_collect_on_preempt_and_drain(model):
     assert target._pending_tick is None
     out = r.drain()
     assert victim is None or victim in out
-    # token identity with the synchronous reference, preemption included
-    ref = _fleet(cfg, params, False)
-    for p in _prompts(cfg, lens=(9, 12, 7)):
-        ref.submit(p, 4)
-    want = ref.drain()
-    assert out == want
-    # graceful drain under the async loop: settled, zero leaked blocks
-    r2 = _fleet(cfg, params, True)
+    # token identity with the unpreempted lone scheduler
+    assert out == _lone_streams(cfg, params,
+                                _prompts(cfg, lens=(9, 12, 7)), 4)
+    # graceful drain under the lagged loop: settled, zero leaked blocks
+    r2 = _fleet(cfg, params)
     for p in _prompts(cfg, lens=(9, 12, 7)):
         r2.submit(p, 4)
     r2.step(); r2.step()
@@ -268,14 +265,14 @@ def test_the_default_loop_keeps_a_tick_in_flight(model):
     lagged one: the first ``step()`` returns nothing and leaves a tick
     in flight, step N+1 returns the tokens tick N decoded, the
     ``router.step`` span says how many replicas entered with a tick
-    pending (0, then 1), and the streams equal, request by request, the
-    step-domain reference's on the same seeded traffic."""
+    pending (0, then 1), and the streams equal, request by request, a
+    lone scheduler's on the same seeded traffic."""
     from pytorch_distributed_tpu.telemetry import spans
 
     cfg, params = model
     tracer = spans.tracer()
     router = _backlog_router(cfg, params)
-    assert router.async_host and router.host_pool is not None
+    assert router.host_pool is not None
     sched = router.replicas[0]
     t_lo = time.perf_counter()
     got, per_step = _drive_backlog(router, cfg)
@@ -289,12 +286,9 @@ def test_the_default_loop_keeps_a_tick_in_flight(model):
     assert sum(flights) >= len(flights) - 3 and set(flights) == {0, 1}
     # tick N's tokens come out of step N+1: the step that returns a
     # request's first token is one past the tick that armed its lane
-    # (ROADMAP C1c deletes the option the reference is built with)
-    ref = _backlog_router(cfg, params, async_host=False)
+    ref = Scheduler(cfg, params, **SCHED_KW)
     want, ref_steps = _drive_backlog(ref, cfg)
     assert ref.host_pool is None
-    assert {e.args["in_flight"] for e in tracer.events("router.step")[
-        -len(ref_steps):]} == {0}
     first = next(i for i, out in enumerate(per_step) if out)
     ref_first = next(i for i, out in enumerate(ref_steps) if out)
     assert first == ref_first + 1
@@ -374,146 +368,46 @@ def test_host_worker_pool_fifo_flush_and_errors():
 
 
 def test_worker_offloads_jsonl_and_gate_snapshot(model, tmp_path):
-    """With the async loop armed, per-request JSONL emission rides the
-    worker pool (marks carry thread names), the gate snapshot refresh
-    runs off-thread, and gate_metrics overlays live counters so
-    depth-bound routing state is never stale."""
+    """Behind the router per-request JSONL emission rides the worker
+    pool, the gate snapshot refresh runs off-thread, and gate_metrics
+    overlays live counters so depth-bound routing state is never
+    stale."""
     cfg, params = model
     path = str(tmp_path / "async.jsonl")
     with MetricsLogger(path) as mlog:
-        reqtrace = ReqTracer(mlog)
-        ledger = DispatchLedger(mlog, seq_source=reqtrace, emit_every=16)
-        r = _fleet(cfg, params, True, metrics_log=mlog,
-                   reqtrace=reqtrace, ledger=ledger)
+        r = _fleet(cfg, params, metrics_log=mlog, reqtrace=ReqTracer(mlog))
         for s in r.replicas:
             s.gate_refresh_ticks = 1  # force a refresh on every collect
         for i, p in enumerate(_prompts(cfg)):
             r.submit(p, 4, session=i % 2)
         r.drain()
         r.log_summary()
-        ledger.finalize()
     records = [json.loads(l) for l in open(path) if l.strip()]
     assert validate_stream(records) == []
     reqs = [rec for rec in records if rec.get("kind") == "request"]
     assert len(reqs) == len(_prompts(cfg))
-    worker_marks = [
-        rec for rec in records
-        if rec.get("kind") == "overlap" and rec.get("ev") == "host"
-        and rec.get("thread", "").startswith("pdt-host")
-    ]
-    assert any(m["name"] == "jsonl-emit" for m in worker_marks)
-    assert any(m["name"] == "metrics-refresh" for m in worker_marks)
+    # a closure a retired request and a closure a refresh, all run
+    assert r.host_pool.submitted > len(reqs)
+    assert r.host_pool.completed == r.host_pool.submitted
     # gate snapshot landed, and the overlay carries the live counters
     gm = r.replicas[0].gate_metrics()
     assert gm["queue_depth"] == 0 and "preemptible" in gm
     assert "ttft_p95_s" in gm  # the worker-refreshed percentile side
-    # the union summary record (replica=-1) reached the stream
-    unions = [rec for rec in records if rec.get("kind") == "overlap"
-              and rec.get("ev") == "summary" and rec.get("replica") == -1]
-    assert len(unions) == 1 and 0 < unions[0]["busy_frac"] <= 1.0
-
-
-def test_worker_thread_marks_classify_not_idle():
-    """Satellite: a gap overlapped only by a worker-thread host mark
-    attributes to ``<name>@<thread>`` — overlapped host work is visible,
-    not ``idle-no-work`` (and not other-replica serialization)."""
-    recs = [
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "decode_tick", "t0": 0.0, "t1": 1.0, "seq0": 0,
-         "seq1": 1, "done": 1.0},
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "decode_tick", "t0": 2.0, "t1": 3.0, "seq0": 4,
-         "seq1": 5, "done": 3.0},
-        {"kind": "overlap", "ev": "host", "replica": 0,
-         "name": "jsonl-emit", "thread": "pdt-host-0",
-         "t0": 1.1, "t1": 1.9, "seq0": 2, "seq1": 3},
-    ]
-    bubbles = classify_bubbles(recs)
-    assert len(bubbles) == 1
-    assert bubbles[0]["cause"] == "jsonl-emit@pdt-host-0"
-    # apportioned shares: the worker mark's measured seconds plus the
-    # uncovered remainder as idle
-    shares = bubbles[0]["shares"]
-    assert shares["jsonl-emit@pdt-host-0"] == pytest.approx(0.8)
-    assert shares["idle-no-work"] == pytest.approx(0.2)
-
-
-def test_other_replica_host_marks_count_as_serialization():
-    """A gap overlapped by ANOTHER replica's main-thread host mark is
-    the one loop doing that replica's tick — other-replica-tick."""
-    recs = [
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "decode_tick", "t0": 0.0, "t1": 1.0, "seq0": 0,
-         "seq1": 1, "done": 1.0},
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "decode_tick", "t0": 2.0, "t1": 3.0, "seq0": 6,
-         "seq1": 7, "done": 3.0},
-        {"kind": "overlap", "ev": "host", "replica": 1,
-         "name": "tick-collect", "t0": 1.0, "t1": 2.0,
-         "seq0": 2, "seq1": 3},
-    ]
-    bubbles = classify_bubbles(recs)
-    assert bubbles[0]["cause"] == "other-replica-tick"
-    assert bubbles[0]["shares"]["other-replica-tick"] == pytest.approx(1.0)
-
-
-def test_shared_device_wait_split():
-    """Round 16: the other replica's EXECUTION beyond its dispatch wall
-    classifies as shared-device-wait, while a sync launch (wall contains
-    execution) still reads other-replica-tick — the backend-honesty
-    split."""
-    recs = [
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "decode_tick", "t0": 0.0, "t1": 1.0, "seq0": 0,
-         "seq1": 1, "done": 1.0},
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "decode_tick", "t0": 3.0, "t1": 4.0, "seq0": 6,
-         "seq1": 7, "done": 4.0},
-        # an ASYNC launch on replica 1: thin dispatch wall [1.0, 1.1],
-        # execution pinned by a blocking fence to [1.1, 3.0]
-        {"kind": "overlap", "ev": "launch", "replica": 1,
-         "program": "decode_tick", "t0": 1.0, "t1": 1.1, "seq0": 2,
-         "seq1": 3, "done": 3.0},
-    ]
-    bubbles = [b for b in classify_bubbles(recs) if b["replica"] == 0]
-    shares = bubbles[0]["shares"]
-    assert shares["other-replica-tick"] == pytest.approx(0.1, abs=1e-6)
-    assert shares["shared-device-wait"] == pytest.approx(1.9, abs=1e-6)
-
-
-def test_fleet_busy_summary_union():
-    """Overlapping busy slices across replicas merge: the union never
-    double-counts the shared window."""
-    recs = [
-        {"kind": "overlap", "ev": "launch", "replica": 0,
-         "program": "p", "t0": 0.0, "t1": 2.0, "seq0": 0, "seq1": 1,
-         "done": 2.0},
-        {"kind": "overlap", "ev": "launch", "replica": 1,
-         "program": "p", "t0": 1.0, "t1": 3.0, "seq0": 2, "seq1": 3,
-         "done": 3.0},
-    ]
-    fb = fleet_busy_summary(recs)
-    assert fb["union_busy_s"] == pytest.approx(3.0)
-    assert fb["window_s"] == pytest.approx(3.0)
-    assert fb["union_busy_frac"] == pytest.approx(1.0)
-    # per-replica fractions sum past the union (the double-count the
-    # union exists to avoid)
-    assert sum(fb["replicas"].values()) > fb["union_busy_frac"]
+    summaries = [rec for rec in records
+                 if rec.get("kind") == "fleet_summary"]
+    assert len(summaries) == 1 and summaries[0]["completed"] == len(reqs)
 
 
 # ---------------------------------------------------------------------------
-# guards: no hot sync, no recompiles, collect-site completion
+# guards: no recompiles, registry coverage
 # ---------------------------------------------------------------------------
 
 
-def test_async_loop_no_hot_sync_and_no_recompile(model):
-    """Acceptance: the ledger's no-hot-sync guard and ``no_recompile``
-    stay green with the async loop armed — dispatch-then-collect adds
-    zero program variants and never fences a launch newer than the
-    lag."""
+def test_async_loop_no_recompile(model):
+    """``no_recompile`` stays green under the lagged loop:
+    dispatch-then-collect adds zero program variants."""
     cfg, params = model
-    ledger = DispatchLedger(lag=2)
-    r = _fleet(cfg, params, True, ledger=ledger)
+    r = _fleet(cfg, params)
     for i, p in enumerate(_prompts(cfg)):
         r.submit(p, 4, session=i % 2)
     for _ in range(6):
@@ -527,19 +421,11 @@ def test_async_loop_no_hot_sync_and_no_recompile(model):
     for s in r.replicas:
         stats = s.engine._decode_fn.stats
         assert stats.recompiles_after_warmup == 0
-    assert ledger.hot_fences == 0
-    assert ledger.dead_fences == 0
-    # async decode launches were pinned at their collect site
-    launches = [rec for rec in ledger.records
-                if rec.get("ev") == "launch"
-                and rec.get("program") == "decode_tick"]
-    assert any(rec.get("collected") or rec.get("fenced")
-               for rec in launches)
 
 
 def test_registry_coverage_with_async_loop(model):
     cfg, params = model
-    r = _fleet(cfg, params, True)
+    r = _fleet(cfg, params)
     for p in _prompts(cfg):
         r.submit(p, 3)
     r.drain()
@@ -602,8 +488,8 @@ def test_kill_matrix_async_loop_sigkill_mid_swap(tmp_path, model):
 
 
 def test_rules_threads_clean_on_async_modules():
-    """Satellite gate: every module the async refactor gave threads or
-    thread-shared state to passes the concurrency lints with zero
+    """Every module with threads or thread-shared state on the serving
+    path passes the concurrency lints with zero
     findings — locks (or documented lock-free protocols) on every
     shared structure."""
     ctx = LintContext(modules=[], mesh_axes=set(), axis_constants={})
@@ -611,7 +497,6 @@ def test_rules_threads_clean_on_async_modules():
         "pytorch_distributed_tpu/serving/host_worker.py",
         "pytorch_distributed_tpu/serving/scheduler.py",
         "pytorch_distributed_tpu/fleet/router.py",
-        "pytorch_distributed_tpu/telemetry/overlap.py",
         "pytorch_distributed_tpu/telemetry/anomaly.py",
         "pytorch_distributed_tpu/utils/profiling.py",
     ):

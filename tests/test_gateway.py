@@ -95,8 +95,7 @@ def gw_env(tiny_model, tmp_path_factory):
     # step() directly — exactly what the gateway's driver does.
     # n_replicas=1: routing never changes a request's greedy stream, and
     # one engine init keeps the module fixture cheap in the fast tier.
-    # the step-domain reference (ROADMAP C1c deletes the option)
-    ref_router = _build_router(cfg, params, async_host=False, n_replicas=1)
+    ref_router = _build_router(cfg, params, n_replicas=1)
     ref_rids = [ref_router.submit(p, 6) for p in prompts]
     reference = {rid: [] for rid in ref_rids}
     for _ in range(4000):

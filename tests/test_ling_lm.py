@@ -26,6 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from conftest import SERVED_TINY, seeded_params  # noqa: E402
+
 from perfbench.harness.weights import CASTS  # noqa: E402
 from perfbench.references import ling  # noqa: E402
 from pytorch_distributed_tpu.models.generate import generate  # noqa: E402
@@ -40,23 +42,19 @@ from pytorch_distributed_tpu.models.transformer import (  # noqa: E402
 from pytorch_distributed_tpu.ops import attention as attention_ops  # noqa: E402
 
 TOL = 1e-5
-LAYERS, GROUP, DENSE = 6, 3, 2  # layers 2 and 5 are latent, 0 and 1 dense
-HEADS, D, TAPS = 4, 8, KDAttention.TAPS
-EXPERTS, HELD, TOP_K = 16, (0, 8), 4
-LATENT, ROPE, ROW = 16, 4, 128  # a row of 20 values padded to a lane tile
-KDA_LAYERS = [0, 1, 3, 4]
 #: the published stack at toy widths: an inner width (4 x 8) that is not the
 #: model's (48), a period of 3, 16 experts in 4 groups of which 2 stay open,
 #: 4 a token, the first 8 held here
-LING = dict(
-    num_layers=LAYERS, embed_dim=48, num_heads=HEADS, head_dim=D,
-    attn_kind="kda", layer_group_size=GROUP, kv_lora_rank=LATENT,
-    qk_rope_head_dim=ROPE, pos_embedding="rope", rope_theta=6e6, norm="rmsnorm", norm_eps=1e-6,
-    use_bias=False, mlp="swiglu", mlp_dim=64, n_experts=EXPERTS, moe_every=1,
-    moe_kind="dropless", moe_router="sigmoid", moe_top_k=TOP_K, moe_n_group=4,
-    moe_topk_group=2, moe_routed_scale=2.5, moe_dim=24, moe_shared_dim=24,
-    experts_held=HELD, first_k_dense_replace=DENSE, max_seq_len=64,
-)
+LING = SERVED_TINY["ling"]
+# 6, 3, 2: layers 2 and 5 are latent, 0 and 1 dense
+LAYERS, GROUP, DENSE = (LING[k] for k in (
+    "num_layers", "layer_group_size", "first_k_dense_replace"))
+HEADS, D, TAPS = LING["num_heads"], LING["head_dim"], KDAttention.TAPS
+EXPERTS, HELD, TOP_K = (LING[k] for k in (
+    "n_experts", "experts_held", "moe_top_k"))
+# a row of 16 + 4 values padded to a lane tile
+LATENT, ROPE, ROW = LING["kv_lora_rank"], LING["qk_rope_head_dim"], 128
+KDA_LAYERS = [0, 1, 3, 4]
 
 
 def ling_config(**over) -> TransformerConfig:
@@ -64,9 +62,7 @@ def ling_config(**over) -> TransformerConfig:
 
 
 def seeded(cfg, seed=5):
-    shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    return ling.init_params(seed, shapes)
+    return seeded_params(ling, cfg, seed)
 
 
 PAD = 48  # one compiled reference pass and one full forward serve them all

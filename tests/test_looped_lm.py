@@ -26,6 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from conftest import SERVED_TINY, seeded_params  # noqa: E402
+
 from perfbench.harness.weights import CASTS  # noqa: E402
 from perfbench.references import ouro  # noqa: E402
 from pytorch_distributed_tpu.models.generate import generate, init_cache  # noqa: E402
@@ -45,10 +47,8 @@ from pytorch_distributed_tpu.serving.kv_pool import (  # noqa: E402
 from pytorch_distributed_tpu.telemetry import spans  # noqa: E402
 
 TOL = 2e-5
-U, THETA = 3, 1e6
-LOOPED = dict(norm="rmsnorm", mlp="swiglu", mlp_dim=48, post_norm=True,
-              use_bias=False, pos_embedding="rope", rope_theta=THETA,
-              ut_steps=U, max_seq_len=64)
+LOOPED = SERVED_TINY["ouro"]
+U, THETA = LOOPED["ut_steps"], LOOPED["rope_theta"]
 
 
 def looped_config(**over) -> TransformerConfig:
@@ -56,9 +56,7 @@ def looped_config(**over) -> TransformerConfig:
 
 
 def seeded(cfg, seed=5):
-    shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    params = ouro.init_params(seed, shapes)
+    params = seeded_params(ouro, cfg, seed)
     # a gate that is not 1/2 everywhere
     params["exit_gate"]["bias"] = params["exit_gate"]["bias"] + 0.3
     return params

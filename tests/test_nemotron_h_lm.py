@@ -28,6 +28,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from conftest import SERVED_TINY, seeded_params  # noqa: E402
+
 from perfbench.harness.weights import CASTS  # noqa: E402
 from perfbench.references import nemotron_h as ref  # noqa: E402
 from pytorch_distributed_tpu.models.generate import generate  # noqa: E402
@@ -44,27 +46,24 @@ from pytorch_distributed_tpu.models.transformer import (  # noqa: E402
 )
 
 TOL = 2e-5
-PATTERN = "MEMEM*EME"  # the published pattern's first nine letters
+#: the published stack at toy widths: two heads a B/C group, two query heads
+#: a K/V head, 16 experts of which the first 8 are held, 3 a token
+NEMO = SERVED_TINY["nemotron-h"]
+PATTERN = NEMO["layer_pattern"]  # the published pattern's first nine letters
 LAYERS = len(PATTERN)
 M_LAYERS = [i for i, c in enumerate(PATTERN) if c == "M"]
 E_LAYERS = [i for i, c in enumerate(PATTERN) if c == "E"]
 FULL = PATTERN.index("*")
-H, P, N, G, TAPS = 4, 8, 16, 2, Mamba2Mixer.TAPS
+H, P, N, G, TAPS = (NEMO["mamba_num_heads"], NEMO["mamba_head_dim"],
+                    NEMO["mamba_state_size"], NEMO["mamba_n_groups"],
+                    Mamba2Mixer.TAPS)
 INNER = H * P
 CONV = INNER + 2 * G * N  # channels under the one convolution
-HEADS, KV_HEADS, A = 4, 2, 16  # an inner width (4 x 16), not the model's
-EXPERTS, HELD, TOP_K, F, SHARED = 16, (0, 8), 3, 24, 40
-#: the published stack at toy widths: two heads a B/C group, two query heads
-#: a K/V head, 16 experts of which the first 8 are held, 3 a token
-NEMO = dict(
-    num_layers=LAYERS, layer_pattern=PATTERN, embed_dim=48, num_heads=HEADS,
-    num_kv_heads=KV_HEADS, head_dim=A, pos_embedding="none", norm="rmsnorm",
-    norm_eps=1e-5, use_bias=False, mlp="relu2", mamba_num_heads=H,
-    mamba_head_dim=P, mamba_state_size=N, mamba_n_groups=G,
-    n_experts=EXPERTS, moe_kind="dropless", moe_router="sigmoid",
-    moe_top_k=TOP_K, moe_routed_scale=2.5, moe_dim=F, moe_shared_dim=SHARED,
-    experts_held=HELD, max_seq_len=64,
-)
+# an inner width (4 x 16), not the model's
+HEADS, KV_HEADS, A = (NEMO[k] for k in (
+    "num_heads", "num_kv_heads", "head_dim"))
+EXPERTS, HELD, TOP_K, F, SHARED = (NEMO[k] for k in (
+    "n_experts", "experts_held", "moe_top_k", "moe_dim", "moe_shared_dim"))
 
 
 def nemo_config(**over) -> TransformerConfig:
@@ -72,9 +71,7 @@ def nemo_config(**over) -> TransformerConfig:
 
 
 def seeded(cfg, seed=5):
-    shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    return ref.init_params(seed, shapes)
+    return seeded_params(ref, cfg, seed)
 
 
 PAD = 48  # one compiled reference pass and one full forward serve them all

@@ -716,15 +716,15 @@ def test_positions_are_counted_on_the_host(kind):
         assert isinstance(tokens, np.ndarray) and isinstance(new, np.ndarray)
         np.testing.assert_array_equal(new, launched + active)
         np.testing.assert_array_equal(positions, launched)  # not in place
-        dev_tokens, new2, launch = eng.decode_launch(new, active,
-                                                     jax.random.key(0))
+        dev_tokens, new2 = eng.decode_launch(new, active,
+                                             jax.random.key(0))
         assert isinstance(dev_tokens, jax.Array)
         assert isinstance(new2, np.ndarray) and new2.dtype == np.int32
         np.testing.assert_array_equal(new2, launched + 2 * active)
         fetched = []
         fetch = eng._fetch_tick
         eng._fetch_tick = lambda t: fetched.append(t) or fetch(t)
-        tokens, new3 = eng.decode_collect(dev_tokens, new2, launch)
+        tokens, new3 = eng.decode_collect(dev_tokens, new2)
         assert new3 is new2 and fetched == [dev_tokens]
         assert isinstance(tokens, np.ndarray)
     # the tick's outputs: cache, logits, tokens (and the expert counts)

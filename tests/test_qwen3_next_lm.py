@@ -26,6 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from conftest import SERVED_TINY, seeded_params  # noqa: E402
+
 from perfbench.harness.weights import CASTS  # noqa: E402
 from perfbench.references import qwen3_next as ref  # noqa: E402
 from pytorch_distributed_tpu.models.generate import generate  # noqa: E402
@@ -39,26 +41,21 @@ from pytorch_distributed_tpu.models.transformer import (  # noqa: E402
 )
 
 TOL = 1e-5
-LAYERS, GROUP = 4, 4  # layers 0-2 the delta rule, layer 3 full attention
-HV, HK, D, TAPS = 4, 2, 8, GatedDeltaNet.TAPS
-HEADS, KV_HEADS, A = 4, 2, 16  # an inner width (4 x 16), not the model's
-EXPERTS, HELD, TOP_K = 16, (0, 8), 4
-GDN_LAYERS = [0, 1, 2]
-CONV = 2 * HK * D + HV * D  # channels under the one convolution
 #: the published stack at toy widths: two state heads a key head, a doubled
 #: q projection, a quarter of a head rotated, 16 experts of which the first
 #: 8 are held, 4 a token
-QWEN = dict(
-    num_layers=LAYERS, embed_dim=48, num_heads=HEADS, num_kv_heads=KV_HEADS,
-    head_dim=A, attn_kind="gdn", layer_group_size=GROUP,
-    full_attn_kind="mha", linear_num_heads=HV, linear_num_key_heads=HK,
-    linear_head_dim=D, qk_norm=True, attn_gate=True, rotary_share=0.25,
-    pos_embedding="rope", rope_theta=1e7, norm="rmsnorm", norm_eps=1e-6,
-    use_bias=False, mlp="swiglu", n_experts=EXPERTS, moe_every=1,
-    moe_kind="dropless", moe_router="softmax", moe_top_k=TOP_K, moe_dim=24,
-    moe_shared_dim=24, moe_shared_gate=True, experts_held=HELD,
-    max_seq_len=64,
-)
+QWEN = SERVED_TINY["qwen3-next"]
+# 4, 4: layers 0-2 the delta rule, layer 3 full attention
+LAYERS, GROUP = QWEN["num_layers"], QWEN["layer_group_size"]
+HV, HK, D, TAPS = (QWEN["linear_num_heads"], QWEN["linear_num_key_heads"],
+                   QWEN["linear_head_dim"], GatedDeltaNet.TAPS)
+# an inner width (4 x 16), not the model's
+HEADS, KV_HEADS, A = (QWEN[k] for k in (
+    "num_heads", "num_kv_heads", "head_dim"))
+EXPERTS, HELD, TOP_K = (QWEN[k] for k in (
+    "n_experts", "experts_held", "moe_top_k"))
+GDN_LAYERS = [0, 1, 2]
+CONV = 2 * HK * D + HV * D  # channels under the one convolution
 
 
 def qwen_config(**over) -> TransformerConfig:
@@ -66,9 +63,7 @@ def qwen_config(**over) -> TransformerConfig:
 
 
 def seeded(cfg, seed=5):
-    shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    return ref.init_params(seed, shapes)
+    return seeded_params(ref, cfg, seed)
 
 
 PAD = 48  # one compiled reference pass and one full forward serve them all

@@ -504,13 +504,15 @@ def test_put_and_call_say_what_the_transfer_and_the_call_carry(tracer):
 
 
 def test_the_synchronous_step_books_the_same_children(tracer):
-    """``Scheduler.step()`` (the tests' step-domain reference, and
-    ``FleetRouter(async_host=False)``) runs the one launch body: the same
+    """A lone ``Scheduler.step()`` runs the one launch body: the same
     build, put and call, and the wait for the tick's tokens after its
     launch span has closed."""
-    cfg, router = _tiny_router(async_host=False)
-    sched = router.replicas[0]
-    for tick in _both_program_ticks(tracer, router, sched.step):
+    from pytorch_distributed_tpu.serving import Scheduler
+
+    cfg, router = _tiny_router()
+    sched = Scheduler(cfg, router.replicas[0].engine.params, n_slots=4,
+                      block_len=8, prefill_chunk=8)
+    for tick in _both_program_ticks(tracer, sched, sched.step):
         for prog in ("chunk", "decode"):
             build, launch, put, call = (
                 _one(tick, f"engine.{prog}.{part}")
@@ -520,7 +522,8 @@ def test_the_synchronous_step_books_the_same_children(tracer):
             assert build.t1 <= launch.t0 <= put.t0 <= put.t1 <= call.t0
         wait = _one(tick, "engine.collect.wait")
         assert wait.t0 >= _one(tick, "engine.decode.launch").t1
-    # through the router's reference loop they are router.step's children
+    # through the router they are router.step's children, and its first
+    # step enters with nothing in flight
     n0 = len(tracer.events())
     router.submit(np.arange(1, 6, dtype=np.int32), 3)
     router.step()

@@ -24,6 +24,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from conftest import SERVED_TINY, seeded_params  # noqa: E402
+
 from perfbench.harness.weights import CASTS  # noqa: E402
 from perfbench.references import zaya  # noqa: E402
 from pytorch_distributed_tpu.models.generate import generate  # noqa: E402
@@ -45,16 +47,11 @@ from pytorch_distributed_tpu.telemetry import spans  # noqa: E402
 
 TOL = 1e-5
 CHUNK, BLOCK = 8, 8
-LAYERS, EXPERTS, ROUTER = 2, 4, 8
 #: the published block at toy widths: an inner width (4 x 8) that is not
 #: the model's (48), 2 narrow heads, 4 experts of 24 behind a router of 8
-ZAYA = dict(
-    num_layers=LAYERS, embed_dim=48, num_heads=4, num_kv_heads=2, head_dim=8,
-    attn_kind="cca", pos_embedding="rope", rope_theta=5e6, rotary_share=0.5,
-    norm="rmsnorm", norm_eps=1e-5, use_bias=False, tie_embeddings=True,
-    residual_scaling=True, n_experts=EXPERTS, moe_every=1,
-    moe_kind="dropless", moe_dim=24, router_dim=ROUTER, max_seq_len=64,
-)
+ZAYA = SERVED_TINY["zaya"]
+LAYERS, EXPERTS, ROUTER = (ZAYA[k] for k in (
+    "num_layers", "n_experts", "router_dim"))
 TAIL = 2 * (4 + 2) * 8 + 8  # u, c1 and the shifted value half
 
 
@@ -63,9 +60,7 @@ def zaya_config(**over) -> TransformerConfig:
 
 
 def seeded(cfg, seed=5):
-    shapes = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
-                            jnp.zeros((1, 8), jnp.int32))["params"]
-    return zaya.init_params(seed, shapes)
+    return seeded_params(zaya, cfg, seed)
 
 
 def reference_logits(params, tokens, cast=None):
