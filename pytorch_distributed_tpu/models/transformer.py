@@ -176,7 +176,13 @@ class TransformerConfig:
     # and the convolutions' last inputs, both a request's and not a
     # block's. "mla" (``MLAttention``): latent attention, whose cache is
     # ONE row a token for all heads (``kv_lora_rank`` normed latent values
-    # and ``qk_rope_head_dim`` rotated key dims). ``layer_group_size`` > 0
+    # and ``qk_rope_head_dim`` rotated key dims), in either published
+    # spelling: queries straight from the token, values as wide as the
+    # unrotated keys and a gate a head, or a compressed query
+    # (``q_lora_rank``), values of a width of their own (``v_head_dim``)
+    # and no gate (``mla_head_gate``; the three keys are further down).
+    # ``attn_kind="mla"`` alone makes EVERY layer latent: the pool of rows
+    # is then the only cache. ``layer_group_size`` > 0
     # mixes kinds in one stack: with a linear ``attn_kind`` ("kda", "gdn")
     # every ``layer_group_size``-th layer is ``full_attn_kind``, "mla"
     # unless told (``attn_kind_at``).
@@ -247,6 +253,18 @@ class TransformerConfig:
     mamba_head_dim: Optional[int] = None
     mamba_state_size: Optional[int] = None
     mamba_n_groups: Optional[int] = None
+    # Three more keys of "mla" layers, each defaulting to what the layer ran
+    # before it had them. ``q_lora_rank``: the query is compressed too,
+    # ``c_q = RMSNorm(x W_qa)`` of that many values, and the heads' queries
+    # come from it (``q_a``, ``q_a_norm``, ``q_b``; None: one matrix ``q``
+    # straight from the token). ``v_head_dim``: a head's VALUE width where
+    # it is not ``head_dim``, the width of its unrotated keys (the fold's
+    # ``W_UK`` and ``W_UV`` are then slices of different widths of
+    # ``kv_b``). ``mla_head_gate``: a scalar gate a head, ``sigmoid(x
+    # W_gh)``, scales the layer's output before its projection.
+    q_lora_rank: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    mla_head_gate: bool = True
 
     def __post_init__(self):
         if self.attn_kind not in ("mha", "cca", "kda", "mla", "gdn"):
@@ -498,11 +516,18 @@ class TransformerConfig:
                     "latent's width) and an even qk_rope_head_dim (the "
                     f"rotated key dims), got {self.kv_lora_rank} and "
                     f"{self.qk_rope_head_dim}")
-        elif self.kv_lora_rank is not None or (
-                self.qk_rope_head_dim is not None):
+            if any(w is not None and w < 1
+                   for w in (self.q_lora_rank, self.v_head_dim)):
+                raise ValueError(
+                    "q_lora_rank (the compressed query's width) and "
+                    "v_head_dim (a head's value width) must be >= 1, got "
+                    f"{self.q_lora_rank} and {self.v_head_dim}")
+        elif (self.kv_lora_rank, self.qk_rope_head_dim, self.q_lora_rank,
+              self.v_head_dim, self.mla_head_gate) != (None,) * 4 + (True,):
             raise ValueError(
                 "kv_lora_rank and qk_rope_head_dim describe latent "
-                "attention ('mla' layers) only")
+                "attention ('mla' layers) only, as do q_lora_rank, "
+                "v_head_dim and mla_head_gate")
         described = (self.linear_num_heads, self.linear_num_key_heads,
                      self.linear_head_dim)
         if "gdn" not in kinds:
@@ -667,6 +692,13 @@ class TransformerConfig:
         """Features of one attention head."""
         return (self.head_dim if self.head_dim is not None
                 else self.embed_dim // self.num_heads)
+
+    @property
+    def value_head_width(self) -> int:
+        """Features of one head's VALUES in an "mla" layer: ``v_head_dim``,
+        or the head's unrotated key width where none is given."""
+        return (self.v_head_dim if self.v_head_dim is not None
+                else self.head_width)
 
     @property
     def cca_tail_width(self) -> int:
@@ -2025,23 +2057,31 @@ SLOT_STATE_LAYERS = {
 
 
 class MLAttention(nn.Module):
-    """Multi-head latent attention without query compression
-    (``attn_kind="mla"``; DeepSeek-V2, arXiv:2405.04434, as
-    ``perfbench/references/ling.py`` writes it down).
+    """Multi-head latent attention (``attn_kind="mla"``; DeepSeek-V2,
+    arXiv:2405.04434), in the two spellings the served configurations
+    publish: ``perfbench/references/ling.py``'s (no query compression,
+    values as wide as the unrotated keys, a gate a head) and
+    ``perfbench/references/glm4_moe_lite.py``'s (a compressed query, values
+    wider than the unrotated keys, no gate).
 
-    ``H`` heads of ``D`` unrotated and ``R`` rotated query dims; keys and
-    values come from ONE latent a token: ``[c~; k_r] = x W_kva``, ``c =
+    ``H`` heads of ``D`` unrotated and ``R`` rotated query dims and ``V``
+    value dims (``v_head_dim``; None: ``D``). The queries come straight
+    from the token (``q``) or, with ``q_lora_rank``, from a compressed one:
+    ``c_q = RMSNorm(x W_qa)``, ``[q_nope_h; q_rope_h] = c_q W_qb,h``. Keys
+    and values come from ONE latent a token: ``[c~; k_r] = x W_kva``, ``c =
     RMSNorm(c~)`` (``kv_lora_rank`` values), ``[k_nope_h; v_h] = c
-    W_kvb,h``; RoPE turns each head's ``R`` query dims and the one shared
-    ``k_r``; scores are scaled by ``(D + R) ** -0.5``; a scalar gate a head
-    (``sigmoid(x W_gh)``) scales the output before the projection.
+    W_kvb,h`` (``D`` key columns, then ``V`` value columns a head); RoPE
+    turns each head's ``R`` query dims and the one shared ``k_r``; scores
+    are scaled by ``(D + R) ** -0.5``; with ``mla_head_gate`` a scalar gate
+    a head (``sigmoid(x W_gh)``) scales the output before the projection.
 
     Without a cache (and in ``generate``'s dense prefill) keys and values
     are EXPANDED for every position. With a cache a token keeps one row
     for all heads, ``[c; rotated k_r; zeros]`` of ``latent_row_width``
     values (``cache/latent``), and attention runs FOLDED: ``q'_h = W_UK,h^T
-    q_nope_h`` scores against ``c``, the probabilities average ``c`` and
-    ``o_h = W_UV,h`` of that average. The row is its own key and its own
+    q_nope_h`` (``W_UK`` the ``D`` key columns of ``kv_b``) scores against
+    ``c``, the probabilities average ``c`` and ``o_h = W_UV,h`` (its ``V``
+    value columns) of that average. The row is its own key and its own
     value, so the paged read (``ops.attention.paged_attention``) takes the
     one pool leaf as both pools, one narrow head of ``latent_row_width``
     read with ``H`` query rows a position; the first ``kv_lora_rank``
@@ -2059,7 +2099,7 @@ class MLAttention(nn.Module):
         cfg = self.config
         b, l, e = x.shape
         f32 = jnp.float32
-        h, d = cfg.num_heads, cfg.head_width
+        h, d, v = cfg.num_heads, cfg.head_width, cfg.value_head_width
         c, r, row = cfg.kv_lora_rank, cfg.qk_rope_head_dim, (
             cfg.latent_row_width)
         scale = (d + r) ** -0.5
@@ -2073,16 +2113,26 @@ class MLAttention(nn.Module):
                 "block_tables= is the paged SERVING cache layout; it "
                 "requires decode or prefill mode")
 
-        q = nn.DenseGeneral((h, d + r), use_bias=False, dtype=cfg.dtype,
-                            name="q")(x)
+        if cfg.q_lora_rank is not None:
+            c_q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32, name="q_a_norm")(
+                nn.Dense(cfg.q_lora_rank, use_bias=False, dtype=cfg.dtype,
+                         name="q_a")(x)).astype(cfg.dtype)
+            q = nn.DenseGeneral((h, d + r), use_bias=False, dtype=cfg.dtype,
+                                name="q_b")(c_q)
+        else:
+            q = nn.DenseGeneral((h, d + r), use_bias=False, dtype=cfg.dtype,
+                                name="q")(x)
         kva = nn.Dense(c + r, use_bias=False, dtype=cfg.dtype,
                        name="kv_a")(x)
         latent = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=f32,
                             name="kv_a_norm")(kva[..., :c]).astype(cfg.dtype)
+        # a head's D key columns (W_UK), then its V value columns (W_UV)
         w_kvb = self.param("kv_b", nn.initializers.normal(0.02),
-                           (c, h, 2 * d)).astype(cfg.dtype)
-        gate = jax.nn.sigmoid(nn.Dense(h, use_bias=False, dtype=cfg.dtype,
-                                       name="gate")(x).astype(f32))
+                           (c, h, d + v)).astype(cfg.dtype)
+        if cfg.mla_head_gate:
+            gate = jax.nn.sigmoid(nn.Dense(
+                h, use_bias=False, dtype=cfg.dtype,
+                name="gate")(x).astype(f32))
         q_nope = q[..., :d]
         q_rope = _rope_rotate(q[..., d:], rpos, cfg.rope_theta)
         k_rope = _rope_rotate(kva[..., c:][:, :, None, :], rpos,
@@ -2155,7 +2205,8 @@ class MLAttention(nn.Module):
             out = jnp.einsum("blhc,chd->blhd", o_lat[..., :c],
                              w_kvb[..., d:],
                              preferred_element_type=f32).astype(cfg.dtype)
-        out = (out.astype(f32) * gate[..., None]).astype(cfg.dtype)
+        if cfg.mla_head_gate:
+            out = (out.astype(f32) * gate[..., None]).astype(cfg.dtype)
         out = nn.DenseGeneral(e, axis=(-2, -1), use_bias=False,
                               dtype=cfg.dtype, name="proj")(out)
         if cfg.dropout:

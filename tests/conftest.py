@@ -67,7 +67,7 @@ def assert_trees_equal(a, b, rtol=0, atol=0):
 #: the served configurations at toy widths, as overrides of ``tiny_config``
 #: (gpt2 is the tiny config itself). Each model's own test files read their
 #: entry (``tests/test_ling_lm.py::LING`` and so on) and say what the widths
-#: stand for; ``tests/test_fleet_configs.py`` drives all six through
+#: stand for; ``tests/test_fleet_configs.py`` drives all seven through
 #: ``FleetRouter``.
 SERVED_TINY = {
     "gpt2": dict(attention="dense", max_seq_len=64),
@@ -117,6 +117,17 @@ SERVED_TINY = {
         n_experts=16, moe_kind="dropless", moe_router="sigmoid", moe_top_k=3,
         moe_routed_scale=2.5, moe_dim=24, moe_shared_dim=40,
         experts_held=(0, 8), max_seq_len=64),
+    # latent attention in EVERY layer (a compressed query, values wider than
+    # the unrotated keys, no gate) over a pool that is the only cache; one
+    # leading dense MLP, then sigmoid top-4 experts, every one held
+    "glm": dict(
+        num_layers=3, embed_dim=48, num_heads=5, head_dim=12, attn_kind="mla",
+        q_lora_rank=24, kv_lora_rank=16, qk_rope_head_dim=4, v_head_dim=16,
+        mla_head_gate=False, pos_embedding="rope", rope_theta=1e6,
+        norm="rmsnorm", norm_eps=1e-5, use_bias=False, mlp="swiglu",
+        mlp_dim=64, n_experts=16, moe_every=1, moe_kind="dropless",
+        moe_router="sigmoid", moe_top_k=4, moe_routed_scale=1.8, moe_dim=24,
+        moe_shared_dim=24, first_k_dense_replace=1, max_seq_len=64),
 }
 
 

@@ -1,8 +1,9 @@
 """Every served configuration through ``FleetRouter``, the loop the chip
 measures: a tick in flight while rows are armed, admitted and retired. The
-six toy configurations of ``conftest.SERVED_TINY`` (gpt2, the looped
+seven toy configurations of ``conftest.SERVED_TINY`` (gpt2, the looped
 decoder, zaya's latent attention with a convolution tail a request, ling's
-and qwen3-next's delta-rule state, nemotron-h's Mamba-2 state) serve the same
+and qwen3-next's delta-rule state, nemotron-h's Mamba-2 state, glm's latent
+pool that is the only cache) serve the same
 greedy streams behind the router, lagged, as out of a lone ``Scheduler``
 whose ``step()`` launches and collects in one call; and a slot whose request
 was cancelled under a tick in flight hands its successor nothing of the
@@ -33,7 +34,8 @@ from pytorch_distributed_tpu.serving import Scheduler  # noqa: E402
 
 #: the module of ``perfbench/references`` that seeds each configuration
 REFERENCES = {"ouro": "ouro", "zaya": "zaya", "ling": "ling",
-              "qwen3-next": "qwen3_next", "nemotron-h": "nemotron_h"}
+              "qwen3-next": "qwen3_next", "nemotron-h": "nemotron_h",
+              "glm": "glm4_moe_lite"}
 #: one chunk program an engine: every bucket floored to the widest
 SCHED_KW = dict(n_blocks=25, block_len=8, prefill_chunk=8,
                 chunk_bucket_floor=(4, 8))

@@ -409,6 +409,48 @@ def test_the_state_update_rides_pool_alloc(tracer, monkeypatch, name,
     assert alloc.args["state_update"] == engine.state_update == update
 
 
+@pytest.mark.parametrize("backend,read,rows", [
+    ("cpu", "dense", 0), ("tpu", "pallas", 16)])
+def test_a_latent_only_stacks_pool_alloc_holds_no_slot_state(
+        tracer, monkeypatch, backend, read, rows):
+    """A stack whose EVERY layer is latent attention says so on
+    ``pool.alloc``: a latent leaf a layer (``pool_layers`` = the layers,
+    ``latent_row_bytes`` a token's ONE row in each, no key or value rows),
+    no per-slot leaf and no state to update; its tick reads one narrow head
+    with every head's query row (``heads_folded`` 1: the loop's body), on a
+    TPU through the kernel, and every expert is held, so the row tile is
+    that of ``slots x top_k`` pairs. Only the engine is built: nothing
+    compiles. (PR 50 adds no span argument: ``sched.collect.process``
+    already carries ``pairs`` beside ``routed`` where nothing is held back,
+    ``tests/test_glm_serving.py``.)"""
+    from test_glm_lm import glm_config
+
+    from pytorch_distributed_tpu.models.transformer import TransformerLM
+    from pytorch_distributed_tpu.ops import attention
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+
+    cfg = glm_config()
+    params = jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+    params = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    # five query rows are a tick's; a toy table's dense gather is small
+    assert attention.default_gather_impl(cfg.num_heads) == read
+    engine = PagedEngine(cfg, params, 3, n_blocks=9, block_len=8,
+                         prefill_chunk=8)
+    (alloc,) = tracer.events("pool.alloc")
+    args = alloc.args
+    assert args["read"] == engine.gather_impl == read
+    assert args["pool_layers"] == args["cache_layers"] == cfg.num_layers == 3
+    assert args["latent_row_bytes"] == cfg.latent_row_width * 4 == 512
+    assert args["kv_row_bytes"] == 0
+    assert args["block_bytes"] == 3 * 8 * 512
+    assert args["slot_state_leaves"] == args["state_bytes"] == 0
+    assert args["tail_bytes"] == 0 and args["state_update"] == ""
+    assert args["heads_folded"] == engine.heads_folded == 1
+    assert args["grouped_rows"] == engine.grouped_rows == rows
+
+
 def _both_program_ticks(tracer, router, step):
     """The records of each ``step`` call that launched the chunk program
     AND the decode tick with both programs loaded: a short prompt decodes

@@ -1100,3 +1100,118 @@ def test_the_nemotron_h_programs_compile_for_the_chip(v5e, monkeypatch,
     assert memory.alias_size_in_bytes >= state.size * 4 + 2 * keys.size * 2
     assert memory.temp_size_in_bytes < (
         1 << 28 if program == "decode_tick" else 3 << 29)
+
+
+# ---- the glm stack's served programs (PR 50) -------------------------------
+
+#: glm-4.7-flash.doc-chat-backlog's attention, experts, pool and slots
+#: (perfbench/configs, perfbench/cells), on 2 layers (the leading dense one and
+#: one expert layer, every expert held) and a small vocabulary
+GLM_CELL = dict(slots=192, blocks=32769, block_len=16, chunk=128,
+                max_seq_len=4864)
+GLM_BLOCK = dict(
+    embed_dim=2048, num_heads=20, head_dim=192, attn_kind="mla",
+    q_lora_rank=768, kv_lora_rank=512, qk_rope_head_dim=64, v_head_dim=256,
+    mla_head_gate=False, pos_embedding="rope", rope_theta=1e6,
+    norm="rmsnorm", norm_eps=1e-5, use_bias=False, mlp="swiglu",
+    mlp_dim=10240, n_experts=64, moe_every=1, moe_kind="dropless",
+    moe_router="sigmoid", moe_top_k=4, moe_routed_scale=1.8, moe_dim=1536,
+    moe_shared_dim=1536, first_k_dense_replace=1)
+#: sha256 (12 hex digits) of the StableHLO text of its two programs, as
+#: ``_lowers_to_the_parents_text`` takes it (each kernel's serialized body
+#: blanked), recorded by the PR that brought the configuration: a PR that
+#: means to change one records a new digest.
+GLM_DIGESTS = {
+    "decode_tick": "cb10e5e875c7",
+    "chunk_prefill[k=16,w=256]": "77d5d83d1842",
+}
+
+
+@pytest.mark.parametrize("program", sorted(GLM_DIGESTS))
+def test_the_glm_programs_compile_for_the_chip(v5e, monkeypatch, program):
+    """Both layers are latent: the tick reads each layer's 640-lane leaf
+    through the fused kernel, as keys and as values, with 20 query rows on
+    its one narrow head, which the kernel pads to 24 (whole 8-row sublane
+    tiles; Mosaic takes the 24-row slab though a bfloat16 tile packs 16), and
+    no float32 array of [lanes, table positions, ...] exists; the 16-job
+    chunk program gathers dense over its own table slice. Both run all 64
+    experts as two ``grouped_matmul`` calls (gate and up side by side, and
+    down; row tiles of 128 of the 768 or 8,192 pair rows), hold no per-slot
+    leaf and move no pool-sized array and no expert stack."""
+    import hashlib
+
+    from pytorch_distributed_tpu.models.transformer import (
+        TransformerConfig,
+        TransformerLM,
+    )
+    from pytorch_distributed_tpu.serving.engine import PagedEngine
+    from pytorch_distributed_tpu.serving.kv_pool import init_paged_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = GLM_CELL
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=2, max_seq_len=c["max_seq_len"],
+        dropout=0.0, dtype=jnp.bfloat16, attention="dense", **GLM_BLOCK)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
+        jax.eval_shape(TransformerLM(cfg).init, jax.random.key(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"])
+    n = c["slots"]
+    eng = PagedEngine(cfg, params, n, n_blocks=2, block_len=c["block_len"],
+                      prefill_chunk=c["chunk"], chunk_bucket_floor=(16, 256),
+                      max_chunk_jobs=16)
+    assert eng.gather_impl == "pallas" and eng.tile_blocks == 8
+    assert eng.heads_folded == 1 and eng.state_update == ""
+    assert eng.grouped_rows == 128
+    pool = jax.eval_shape(
+        lambda p: init_paged_cache(cfg, p, c["blocks"], c["block_len"],
+                                   n_slots=n), params)
+    leaves = jax.tree.leaves(pool)
+    assert [x.shape for x in leaves] == [
+        (c["blocks"], c["block_len"], 640)] * 2  # and no per-slot leaf
+    one = SingleDeviceSharding(v5e.devices[0])
+    if program == "decode_tick":
+        fn, operands = _tick_operands(eng)
+    else:
+        fn, operands = _chunk_operands(eng, 16, 256)
+        assert eng.chunk_program_name(16, 256) == program
+    args = (params, pool, eng.logits) + operands
+    lowered = fn.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        args))
+    digest = hashlib.sha256(re.sub(
+        r'backend_config = "[^"]*"', 'backend_config = ""',
+        lowered.as_text()).encode()).hexdigest()[:12]
+    assert digest == GLM_DIGESTS[program], (program, digest)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    reads = _kernel_reads(text)
+    assert len(reads) == (2 if program == "decode_tick" else 0), reads
+    # the row's ONE narrow head keeps the loop's body (``heads_folded``):
+    # its query goes in a slab a head, 20 rows padded to 24, of 640 lanes
+    assert all("bf16[192,1,24,640]" in x for x in reads)
+    # gate and up side by side, and down; four pairs a token, 64 experts
+    assert _grouped_products(
+        text, 4 * (n if program == "decode_tick" else 16 * c["chunk"]),
+        64) == [(2048, 3072), (1536, 2048)]
+    # the counts come back beside what the programs returned before: one
+    # expert layer, every expert
+    shapes = [tuple(s.shape) for s in jax.tree.leaves(
+        jax.eval_shape(fn, *args))]
+    assert shapes[-1] == (1, 64)
+    stacks = params["block1"]["moe"]
+    moved = [m.group(1) for m in re.finditer(
+        r"(\S+) = \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)
+        if math.prod(map(int, m.group(2).split(","))) in (
+            leaves[0].size, stacks["w_gate_up"].size, stacks["w_down"].size)]
+    assert not moved, moved
+    # the tick gathers no lane's table: nothing of [lanes, positions, ...]
+    rows = c["max_seq_len"]
+    if program == "decode_tick":
+        assert not re.search(rf"f32\[{n},(?:{rows}|{rows // 16},16),", text)
+    # and what it holds beside its arguments is small: the pools are donated
+    # and updated in place
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * leaves[0].size * 2
+    assert memory.temp_size_in_bytes < (
+        1 << 28 if program == "decode_tick" else 1 << 30)
